@@ -22,6 +22,7 @@ from .runtime.engine import DeepSpeedTPUEngine, TrainState  # noqa: F401
 from .runtime.module import ModelSpec  # noqa: F401
 from .parallel.mesh import MeshTopology, initialize_topology, get_topology  # noqa: F401
 from .utils.logging import logger  # noqa: F401
+from .utils.platform import ensure_compile_cache
 
 
 def initialize(args: Any = None,
@@ -53,6 +54,7 @@ def initialize(args: Any = None,
         config = args.deepspeed_config
 
     comm.init_distributed()
+    ensure_compile_cache()
     ds_config = config if isinstance(config, DeepSpeedConfig) else DeepSpeedConfig(config)
     # MiCS (reference zero/mics.py): shard within groups of mics_shard_size,
     # replicate across — expressed as data=mics_shard_size, repl=remainder
@@ -157,6 +159,7 @@ def init_inference(model: Any = None, config: Any = None, **kwargs):
 
     from .inference.engine import InferenceEngine, InferenceConfig
 
+    ensure_compile_cache()
     cfg = config if isinstance(config, InferenceConfig) else InferenceConfig.from_dict(
         config if isinstance(config, dict) else {})
     for k, v in kwargs.items():

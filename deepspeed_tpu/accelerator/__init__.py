@@ -24,7 +24,7 @@ the subset that has real meaning — but kept name-compatible where it exists:
                        (op_builder/builder.py:116).
 
 Detection order (mirrors real_accelerator.py:59): explicit ``DS_ACCELERATOR``
-env var, else probe ``jax.default_backend()``.
+env var, else the platform JAX selected (``utils/platform.py``).
 """
 
 from __future__ import annotations
@@ -342,13 +342,14 @@ def get_accelerator() -> Accelerator:
         if _accelerator is not None:
             return _accelerator
         name = os.environ.get("DS_ACCELERATOR", "").lower()
+        if name not in ("", "tpu", "cpu"):
+            raise ValueError(f"DS_ACCELERATOR={name!r}: expected tpu or cpu")
         if not name:
-            try:
-                import jax
+            # a backend that fails to initialize propagates: turning it
+            # into CPUAccelerator would hide a missing chip
+            from ..utils.platform import on_tpu
 
-                name = jax.default_backend()
-            except Exception:
-                name = "cpu"
+            name = "tpu" if on_tpu() else "cpu"
         _accelerator = TPUAccelerator() if name == "tpu" else CPUAccelerator()
         return _accelerator
 

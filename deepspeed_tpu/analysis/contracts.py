@@ -9,8 +9,8 @@ programs on CPU (``jax.jit(...).lower().compile()``, 8 virtual devices,
 the same harness as tier-1) and stored as golden JSON under
 ``tests/contracts/``.
 
-Why: BENCH_r03–r05 recorded a CPU fallback and nothing caught it;
-an extra all-gather, a lost fusion, or a steady-state recompile is
+Why: an earlier round recorded CPU runs as chip numbers and nothing
+caught it; an extra all-gather, a lost fusion, or a steady-state recompile is
 invisible until someone eyeballs a trace (ROADMAP item 5).  With the
 goldens in tier-1, "stage-3 train step grew all-gather 24→26" is a
 named test failure at review time — and the upcoming overlap /
@@ -616,7 +616,7 @@ def extract_program(name: str) -> Dict[str, Any]:
         "tolerances": dict(DEFAULT_TOLERANCES),
         "info": {
             "description": description,
-            "backend": jax.default_backend(),
+            "backend": jax.devices()[0].platform,
             "device_count": jax.device_count(),
             "jax_version": jax.__version__,
         },

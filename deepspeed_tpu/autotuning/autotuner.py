@@ -201,12 +201,27 @@ class Autotuner:
         one trial — use ``SubprocessTrialRunner`` for real out-of-process
         experiments.  mode="model" proposes each candidate from the
         previous results, which is inherently sequential — use tune()."""
-        from .scheduler import Node, ResourceManager
+        from ..utils.platform import holds_tpu
+        from .scheduler import (_LOCAL_HOSTS, Node, ResourceManager,
+                                SubprocessTrialRunner)
 
         if self.mode == "model":
             raise ValueError("model-based tuning is sequential; use tune()")
-        pool = self._pruned_pool()[:self.max_trials]
-        rm = ResourceManager(nodes or [Node("localhost", 1)], runner,
+        nodes = nodes or [Node("localhost", 1)]
+        if isinstance(runner, SubprocessTrialRunner):
+            # out-of-process trials need the chip, and a chip belongs to
+            # one process: the parent stays off JAX (no HBM pruning — an
+            # oversized candidate fails inside its own trial instead)
+            if holds_tpu() and any(n.host in _LOCAL_HOSTS for n in nodes):
+                raise RuntimeError(
+                    "tune_parallel: this process has initialized the TPU "
+                    "and holds the chip, so local subprocess trials would "
+                    "fail or hang at start-up; call tune_parallel before "
+                    "anything touches JAX, or use tune() (in-process)")
+            pool = self._candidates()[:self.max_trials]
+        else:
+            pool = self._pruned_pool()[:self.max_trials]
+        rm = ResourceManager(nodes, runner,
                              slots_per_exp=slots_per_exp,
                              max_parallel=max_parallel)
         rm.schedule_experiments([

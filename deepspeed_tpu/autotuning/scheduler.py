@@ -244,7 +244,7 @@ class SubprocessTrialRunner:
             # a local ssh kill cannot orphan a trial that still holds the
             # reserved chips.
             remote = ["env", *[f"{k}={v}" for k, v in trial_env.items()],
-                      # -k: escalate to SIGKILL — a trial wedged in
+                      # -k: escalate to SIGKILL — a trial stuck in
                       # uninterruptible TPU backend init ignores SIGTERM,
                       # and an unkilled remote is exactly the orphaned-
                       # chips failure the remote timer exists to prevent

@@ -38,8 +38,8 @@ import jax.numpy as jnp
 #: default quantization block (reference csrc/quantization group size)
 DEFAULT_BLOCK = 128
 
-#: fp8 code dtype, when this jax build has one
-FP8_DTYPE = getattr(jnp, "float8_e4m3fn", None)
+#: fp8 code dtype
+FP8_DTYPE = jnp.float8_e4m3fn
 _FP8_MAX = 448.0  # e4m3fn largest finite
 
 _FORMATS = ("int8", "fp8")
@@ -73,9 +73,6 @@ class CompressionSpec:
         if self.block <= 0:
             raise ValueError(f"CompressionSpec.block must be > 0, "
                              f"got {self.block}")
-        if self.format == "fp8" and FP8_DTYPE is None:
-            raise ValueError("CompressionSpec(format='fp8') needs a jax "
-                             "build with jnp.float8_e4m3fn; use 'int8'")
 
     @classmethod
     def parse(cls, value: Union[None, str, dict, "CompressionSpec"]

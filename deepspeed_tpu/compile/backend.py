@@ -38,94 +38,6 @@ from ..utils.logging import logger
 PassFn = Callable[[Any], None]
 PASS_REGISTRY: Dict[str, PassFn] = {}
 
-#: XLA flags that let the TPU scheduler actually hide the in-loop
-#: collectives the overlap wrap issues (runtime/zero/overlap.py): the
-#: latency-hiding scheduler reorders collective-starts ahead of
-#: consuming compute, and async collective fusion keeps the gather /
-#: reduce-scatter wavefronts asynchronous.  These are the BACKSTOP for
-#: whatever XLA can already reorder — pinned (not merely hoped for) by
-#: bench.py for TPU child processes and validated by the engine when an
-#: overlap plan is active.  Flag set, not behavior, is asserted: the
-#: values only take effect when present in XLA_FLAGS before backend
-#: init.
-LATENCY_HIDING_FLAGS: Dict[str, str] = {
-    "--xla_tpu_enable_latency_hiding_scheduler": "true",
-    "--xla_tpu_enable_async_collective_fusion": "true",
-    "--xla_tpu_enable_async_collective_fusion_fuse_all_gather": "true",
-}
-
-
-def parse_xla_flags(flags: Optional[str]) -> Dict[str, str]:
-    """``XLA_FLAGS`` string -> {flag: value} (bare flags map to "true")."""
-    out: Dict[str, str] = {}
-    for tok in (flags or "").split():
-        if "=" in tok:
-            k, v = tok.split("=", 1)
-            out[k] = v
-        elif tok.startswith("--"):
-            out[tok] = "true"
-    return out
-
-
-def latency_hiding_flag_status(env: Optional[Dict[str, str]] = None
-                               ) -> Dict[str, str]:
-    """Per-flag status against :data:`LATENCY_HIDING_FLAGS`:
-    ``"pinned"`` (present with the recommended value), ``"missing"``,
-    or ``"overridden=<value>"`` (present with another value — an
-    explicit operator choice, reported but never clobbered)."""
-    import os
-
-    env = os.environ if env is None else env
-    current = parse_xla_flags(env.get("XLA_FLAGS", ""))
-    status = {}
-    for flag, want in LATENCY_HIDING_FLAGS.items():
-        if flag not in current:
-            status[flag] = "missing"
-        elif current[flag].lower() == want:
-            status[flag] = "pinned"
-        else:
-            status[flag] = f"overridden={current[flag]}"
-    return status
-
-
-def pin_latency_hiding_flags(env: Optional[Dict[str, str]] = None
-                             ) -> List[str]:
-    """Append the missing latency-hiding flags to ``env["XLA_FLAGS"]``
-    and return what was added.  Only meaningful BEFORE the XLA backend
-    initializes (bench.py pins for its TPU child processes); explicit
-    operator overrides are left alone.  TPU-only flags — never pin into
-    a CPU process, where unknown flags abort backend init."""
-    import os
-
-    env = os.environ if env is None else env
-    status = latency_hiding_flag_status(env)
-    added = [f"{flag}={want}" for flag, want in LATENCY_HIDING_FLAGS.items()
-             if status[flag] == "missing"]
-    if added:
-        env["XLA_FLAGS"] = " ".join(
-            [env.get("XLA_FLAGS", "").strip()] + added).strip()
-    return added
-
-
-def validate_latency_hiding_flags() -> Dict[str, str]:
-    """Engine-side check (the backend is already up, so this can only
-    REPORT): warn when an overlap plan is active on TPU but the
-    scheduler flags are not pinned — the in-loop collectives would then
-    rely on default scheduling to hide."""
-    import jax
-
-    status = latency_hiding_flag_status()
-    if jax.default_backend() != "tpu":
-        return status
-    missing = [f for f, s in status.items() if s == "missing"]
-    if missing:
-        logger.warning(
-            "compute/collective overlap is active but the XLA "
-            f"latency-hiding flags are not pinned ({missing}); set them "
-            "in XLA_FLAGS before process start (bench.py pins them for "
-            "its TPU children; see docs/COMM.md 'Overlap & scheduling')")
-    return status
-
 
 def shape_signature(*trees: Any) -> tuple:
     """Hashable ``(shape, dtype)`` signature of the array leaves of

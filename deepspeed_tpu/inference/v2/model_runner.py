@@ -30,13 +30,21 @@ from ...models.transformer import (MODEL_AXIS, TransformerConfig, _mm,
 
 
 def _use_paged_kernel() -> bool:
-    """Pallas kernels on TPU by default; DSTPU_PAGED_KERNEL=0/1 forces
-    either path (read at trace time — tests force the kernel in interpret
-    mode on CPU)."""
+    """Pallas kernels, always, on TPU.  On the CPU test tier the XLA
+    gather path is the default and DSTPU_PAGED_KERNEL=1 forces the
+    kernels in interpret mode (read at trace time)."""
     import os
 
-    default = "1" if jax.default_backend() == "tpu" else "0"
-    return os.environ.get("DSTPU_PAGED_KERNEL", default) == "1"
+    from ...utils.platform import on_tpu
+
+    forced = os.environ.get("DSTPU_PAGED_KERNEL")
+    if on_tpu():
+        if forced == "0":
+            raise RuntimeError(
+                "DSTPU_PAGED_KERNEL=0 asks for the XLA gather path on TPU; "
+                "the chip serves through the paged kernel only")
+        return True
+    return forced == "1"
 
 
 def _kv_quantize(x):

@@ -81,9 +81,23 @@ def build_launch_commands(hosts: "OrderedDict[str, int]", script: str,
                           master_port: int = DEFAULT_COORD_PORT,
                           export_env: Optional[Dict[str, str]] = None,
                           ssh_port: int = 22) -> List[List[str]]:
-    """One command per host (reference PDSHRunner.get_cmd equivalent)."""
+    """One command per host (reference PDSHRunner.get_cmd equivalent).
+
+    A host's TPU chips belong to ONE process (which drives all of them),
+    and nothing here divides chips between ranks — so two ranks on this
+    machine are refused unless the job is pinned to the CPU platform (the
+    rendezvous tests)."""
     master_addr = master_addr or next(iter(hosts))
     n = len(hosts)
+    n_local = sum(h in ("localhost", "127.0.0.1") for h in hosts)
+    platforms = (export_env or {}).get(
+        "JAX_PLATFORMS", os.environ.get("JAX_PLATFORMS", ""))
+    if n_local > 1 and platforms != "cpu":
+        raise ValueError(
+            f"{n_local} ranks on this host would each claim every local TPU "
+            "chip, and a chip belongs to one process: launch one rank per "
+            "host (one process drives all local chips), or set "
+            "JAX_PLATFORMS=cpu for a CPU-only rendezvous")
     cmds = []
     for pid, host in enumerate(hosts):
         env = {

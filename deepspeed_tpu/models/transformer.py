@@ -386,47 +386,68 @@ def _repeat_kv(k, n_rep: int):
     return jnp.repeat(k, n_rep, axis=2)
 
 
+def flash_on_mesh(q, k, v, causal, mask=None, alibi=None,
+                  head_axes=(MODEL_AXIS,)):
+    """The Pallas flash kernel on this device's block of the mesh.
+
+    GSPMD cannot partition a Mosaic kernel (lowering refuses anything but
+    one device or a fully-manual region), so on a mesh the call runs under
+    ``shard_map``: batch over the batch axes, heads over ``head_axes``
+    (the model axis; Ulysses adds the sequence axis), the sequence whole.
+    Inside an enclosing partial ``shard_map`` (ZeRO overlap wrap, pipe
+    stages) only the axes it left automatic are mapped here."""
+    from ..ops.pallas.flash_attention import flash_attention
+    from ..parallel.mesh import BATCH_AXES, peek_topology
+
+    def call(q, k, v, mask, alibi):
+        return flash_attention(q, k, v, causal=causal, segment_mask=mask,
+                               alibi_slopes=alibi)
+
+    topo = peek_topology()
+    if topo is None or topo.mesh.size == 1:
+        return call(q, k, v, mask, alibi)
+    from ..utils.jax_compat import shard_map
+
+    # inside an enclosing shard_map the context mesh carries its manual axes
+    ctx = jax.sharding.get_abstract_mesh()
+    bound = frozenset() if ctx.empty else frozenset(ctx.manual_axes)
+    free = frozenset(topo.mesh.axis_names) - bound
+    if not free:  # already fully manual: the arrays ARE the local block
+        return call(q, k, v, mask, alibi)
+    b_ax = tuple(a for a in BATCH_AXES if a in free) or None
+    h_ax = tuple(a for a in head_axes if a in free) or None
+    qkv = P(b_ax, None, h_ax, None)
+    return shard_map(
+        call, ctx if bound else topo.mesh,
+        in_specs=(qkv, qkv, qkv, None if mask is None else P(b_ax, None),
+                  None if alibi is None else P(h_ax)),
+        out_specs=qkv, check_vma=False, axis_names=free)(q, k, v, mask, alibi)
+
+
+# GQA-native (reads grouped kv heads via index maps) and builds the ALiBi
+# bias from block indices (no [S, S] materialization)
+flash_on_mesh.handles_gqa = True
+flash_on_mesh.handles_alibi = True
+
+
 def _pick_attn(cfg: TransformerConfig) -> Callable:
     impl = cfg.attn_impl
+    if impl == "auto":
+        # on the chip "auto" can only mean the kernel; a kernel that does
+        # not lower fails the step instead of yielding to xla_attention
+        from ..utils.platform import on_tpu
+
+        impl = "flash" if on_tpu() else "xla"
+    if impl == "flash":
+        return flash_on_mesh
     if cfg.position == "alibi":
-        # the flash kernels build the ALiBi bias from block indices (no
-        # [S, S] materialization); ulysses/ring carry no bias input
-        if impl == "flash" or (impl == "auto"
-                               and jax.default_backend() == "tpu"):
-            try:
-                from ..ops.pallas.flash_attention import flash_attention
-
-                fn = lambda q, k, v, causal, mask=None, alibi=None: \
-                    flash_attention(q, k, v, causal=causal,  # noqa: E731
-                                    segment_mask=mask, alibi_slopes=alibi)
-                fn.handles_gqa = True
-                fn.handles_alibi = True
-                return fn
-            except Exception:
-                from ..utils.logging import warning_once
-
-                warning_once(
-                    "flash attention unavailable; ALiBi falls back to the "
-                    "XLA path, which MATERIALIZES the [B, NH, S, S] bias — "
-                    "expect much higher memory at long context")
-        if impl not in ("auto", "xla", "flash"):
+        # ulysses/ring carry no bias input
+        if impl != "xla":
             from ..utils.logging import warning_once
 
             warning_once(f"attn_impl={impl!r} has no ALiBi bias input; "
                          "using the XLA attention path")
         return xla_attention
-    if impl == "auto":
-        impl = "flash" if jax.default_backend() == "tpu" else "xla"
-    if impl == "flash":
-        try:
-            from ..ops.pallas.flash_attention import flash_attention
-
-            fn = lambda q, k, v, causal, mask=None: flash_attention(  # noqa: E731
-                q, k, v, causal=causal, segment_mask=mask)
-            fn.handles_gqa = True  # reads grouped kv heads via index maps
-            return fn
-        except Exception:
-            return xla_attention
     if impl == "ulysses":
         from ..sequence.ulysses import ulysses_attention
 
@@ -769,8 +790,8 @@ def causal_lm_loss(cfg: TransformerConfig, params, batch, rng=None):
 def nll_pick(logp: jnp.ndarray, targets: jnp.ndarray) -> jnp.ndarray:
     """-logp[target] as a one-hot contraction, NOT take_along_axis: the
     gather's transpose is a vocab-dim scatter-add the SPMD partitioner can
-    only reshard by full rematerialization under sequence sharding
-    (docs/PERF_NOTES.md); the contraction transposes to a broadcast
+    only reshard by full rematerialization under sequence sharding; the
+    contraction transposes to a broadcast
     multiply, which shards cleanly.  XLA fuses the one-hot (iota+compare)
     into the reduction — no materialized [.., V] buffer."""
     onehot = jax.nn.one_hot(targets, logp.shape[-1], dtype=logp.dtype)

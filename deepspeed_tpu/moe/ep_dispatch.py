@@ -10,8 +10,7 @@ Why this exists (vs leaving dispatch to SPMD, sharded_moe.py): under
 EP + ZeRO-2/3 the backward of the SPMD dropless path produces
 expert-weight grads in a token-sharded layout and XLA's SPMD partitioner
 replicates them to reach the expert-sharded target ("involuntary full
-rematerialization", a tracked SPMD scatter limitation — see
-docs/PERF_NOTES.md).  Running the expert FFN inside ``shard_map`` over
+rematerialization", a tracked SPMD scatter limitation).  Running the expert FFN inside ``shard_map`` over
 the ``expert`` axis sidesteps the partitioner: each shard computes the
 cotangent of ITS local expert slab only, so the grad is [E/ep, ...] by
 construction and the wire traffic is exactly the two all-to-alls.
@@ -81,27 +80,12 @@ def _ep_a2a(x, a2a_spec):
 
 def _inside_manual_axes() -> bool:
     """True when tracing inside shard_map/pmap (named axes bound) — the EP
-    shard_map cannot nest there (e.g. under the pipeline's manual map)."""
-    try:
-        from jax._src.core import get_axis_env
+    shard_map cannot nest there (e.g. under the pipeline's manual map).
+    Reads a private jax API (the pinned 0.9.0 has it); if it moves, this
+    import fails loudly rather than switching MoE dispatch to SPMD."""
+    from jax._src.core import get_axis_env
 
-        return bool(get_axis_env().axis_sizes)
-    except Exception:
-        # Unknown (private API moved): claim "inside" so callers fall back
-        # to the always-correct SPMD path rather than crash on a nested
-        # shard_map; log once so the silent perf regression is visible.
-        global _WARNED_AXIS_ENV
-        if not _WARNED_AXIS_ENV:
-            _WARNED_AXIS_ENV = True
-            from ..utils.logging import logger
-
-            logger.warning(
-                "jax axis-env introspection unavailable; EP all-to-all "
-                "dispatch disabled (falling back to SPMD MoE dispatch)")
-        return True
-
-
-_WARNED_AXIS_ENV = False
+    return bool(get_axis_env().axis_sizes)
 
 
 def ep_dispatch_active(cfg) -> bool:

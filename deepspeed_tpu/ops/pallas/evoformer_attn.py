@@ -35,11 +35,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ...utils.platform import pallas_interpret
+
 NEG_INF = -1e30
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -134,7 +132,7 @@ def _fwd(q5, k5, v5, b1, b2, sm_scale, block_q, block_k):
             jax.ShapeDtypeStruct((B, S, H, Qp, D), q5.dtype),
             jax.ShapeDtypeStruct((B, S, H, Qp, 1), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(*args)
     return out[:, :, :, :Q], lse[:, :, :, :Q]
 
@@ -308,7 +306,7 @@ def _bwd(sm_scale, block_q, block_k, has_b1, has_b2, res, do5):
         ] + bias_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(q5p, k5p, v5p, do5p, lse_p, delta_p, *bias_args)
     # out_shape is a list, so pallas_call returns a list even with one entry
     dq = res_a[0][:, :, :, :Q]
@@ -350,7 +348,7 @@ def _bwd(sm_scale, block_q, block_k, has_b1, has_b2, res, do5):
         ] + bias_specs_b,
         out_specs=out_specs_b,
         out_shape=out_shape_b,
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(q5p, k5p, v5p, do5p, lse_p, delta_p, *bias_args_b)
     dk = res_b[0][:, :, :, :K]
     dv = res_b[1][:, :, :, :K]

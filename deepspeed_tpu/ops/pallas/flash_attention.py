@@ -8,10 +8,12 @@ recompute-based backward (dq / dk / dv kernels) using the saved
 log-sum-exp — the memory behavior that makes long sequences feasible.
 
 Layout: kernels work on [BH, S, D] (batch*heads merged); the public API
-takes [B, S, NH, D] to match models/transformer.py.  Falls back to the
-stock jax pallas kernel (``jax.experimental.pallas.ops.tpu.flash_attention``)
-via ``impl="jax"``, and runs in interpreter mode off-TPU so the same tests
-cover CPU CI.
+takes [B, S, NH, D] to match models/transformer.py.  Every kernel walks a
+(q tile, k tile) grid with its running state in VMEM scratch, so VMEM
+holds tiles only and the sequence length is bounded by HBM, not VMEM.
+``impl="jax"`` selects the stock jax pallas kernel
+(``jax.experimental.pallas.ops.tpu.flash_attention``) for comparison.
+Compiled on TPU, interpreted on the CPU test tier (utils/platform.py).
 """
 
 from __future__ import annotations
@@ -25,201 +27,225 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...utils.platform import pallas_interpret
+
 NEG_INF = -1e30
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def _params(*semantics):
+    return pltpu.CompilerParams(dimension_semantics=semantics)
+
+
+def _dot(a, b, contract):
+    """MXU matmul in the operands' dtype, fp32 accumulate."""
+    return jax.lax.dot_general(a, b, ((contract[0], contract[1]), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _scores(q, k, sl_ref, head, rows, cols, *, sm_scale, causal, alibi,
+            seq_q, seq_k):
+    """Masked fp32 scores [bq, bk] of one tile + the validity mask.
+    ``rows``/``cols`` are absolute positions; rows past ``seq_q`` and cols
+    past ``seq_k`` are block padding."""
+    s = _dot(q, k, ((1,), (1,))) * sm_scale
+    if alibi:
+        # ALiBi from block indices: no [S, S] bias materialization
+        s = s - sl_ref[head] * (rows - cols).astype(jnp.float32)
+    valid = cols < seq_k
+    if seq_q is not None:
+        valid = valid & (rows < seq_q)
+    if causal:
+        valid = valid & (rows >= cols)
+    return jnp.where(valid, s, NEG_INF), valid
 
 
 # ---------------------------------------------------------------------------
 # forward kernel
 # ---------------------------------------------------------------------------
-def _fwd_kernel(q_ref, k_ref, v_ref, sl_ref, off_ref, o_ref, lse_ref, *,
-                sm_scale, causal, block_k, seq_k, alibi, offset):
-    q = q_ref[0].astype(jnp.float32) * sm_scale  # [bq, D]
-    bq, d = q.shape
-    iq = pl.program_id(1)
-    q_start = iq * bq
-    slope = sl_ref[0, 0] if alibi else 0.0
-    if offset:
-        # chunked prefill: query i is GLOBAL position off + q_start + i
-        # (keys are pool slots at their global positions); the causal
-        # k-block bound stays the full window — the offset is runtime
-        # data, and callers pass a window bucketed near off + seq_q
-        q_start = q_start + off_ref[0]
+def _fwd_kernel(off_ref, sl_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                m_scr, l_scr, acc_scr, *, sm_scale, causal, seq_k, alibi):
+    """Grid (B*NH, nq, nk), k innermost: one [bq, bk] score tile per step,
+    the online-softmax state (m, l, acc) carried in VMEM scratch across
+    the k axis — VMEM holds tiles, never a whole sequence."""
+    bq, bk = q_ref.shape[1], k_ref.shape[1]
+    # program ids are read at the top level: the interpreter does not
+    # resolve them inside a pl.when body
+    head, jk = pl.program_id(0), pl.program_id(2)
+    # chunked prefill: query i is GLOBAL position off + q_start + i (keys
+    # are pool slots at their global positions); off is 0 in training
+    q_start = off_ref[0] + pl.program_id(1) * bq
+    k_start = jk * bk
 
-    nk = pl.cdiv(seq_k, block_k)
-    if causal and not offset:
-        # only k blocks whose start is <= last q row
-        nk = pl.cdiv(iq * bq + bq, block_k)
+    @pl.when(jk == 0)
+    def _():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def body(j, carry):
-        acc, m_prev, l_prev = carry
-        k_blk = k_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        s = q @ k_blk.T  # [bq, bk]
-        rows = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
-        cols = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)
-        if alibi:
-            # ALiBi from block indices: no [S, S] bias materialization
-            s = s - slope * (rows - cols).astype(jnp.float32)
-        valid = cols < seq_k  # last k block may be padded
-        if causal:
-            valid = valid & (rows >= cols)
-        s = jnp.where(valid, s, NEG_INF)
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
+    def tile():
+        rows = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        cols = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        s, _ = _scores(q_ref[0], k_ref[0], sl_ref, head, rows, cols, sm_scale=sm_scale, causal=causal, alibi=alibi,
+                       seq_q=None, seq_k=seq_k)
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * alpha + p @ v_blk
-        return acc, m_new, l_new
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + _dot(
+            p.astype(v_ref.dtype), v_ref[0], ((1,), (0,)))
+        m_scr[...] = m_new
 
-    acc = jnp.zeros((bq, d), jnp.float32)
-    m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq, 1), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(0, nk, body, (acc, m0, l0))
+    if causal:
+        # k tiles wholly above the diagonal contribute nothing (and the
+        # index map re-uses the diagonal tile for them: no DMA either)
+        pl.when(k_start <= q_start + bq - 1)(tile)
+    else:
+        tile()
 
-    l = jnp.maximum(l, 1e-30)
-    o_ref[0] = (acc / l).astype(o_ref.dtype)
-    lse_ref[0] = (m + jnp.log(l)).astype(jnp.float32)  # [bq, 1]
+    @pl.when(jk == pl.num_programs(2) - 1)
+    def _():
+        l = jnp.maximum(l_scr[...], 1e-30)
+        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        lse_ref[0] = m_scr[...] + jnp.log(l)  # [bq, 1]
 
 
-def _fwd(q, k, v, alibi_arr, sm_scale, causal, block_q, block_k,
-         valid_q=None, valid_k=None, q_per_kv=1, alibi=False,
-         offset_arr=None, offset=False):
+def _fwd(q, k, v, alibi_arr, offset_arr, sm_scale, causal, block_q, block_k,
+         valid_k=None, q_per_kv=1, alibi=False):
     """q: [B*NH, Sq, D]; k/v: [B*KVH, Sk, D] with NH = KVH * q_per_kv —
     GQA reads each kv head once via the index map instead of materializing
-    the repeat (the reference's kv-replication copy)."""
+    the repeat (the reference's kv-replication copy).  ``alibi_arr``:
+    [B*NH] fp32 slopes and ``offset_arr``: [1] int32 query offset, both
+    scalar memory."""
     bh, seq_q, d = q.shape
     seq_k = k.shape[1]
     valid_k = valid_k if valid_k is not None else seq_k
     bq = min(block_q, seq_q)
     bk = min(block_k, seq_k)
-    grid = (bh, pl.cdiv(seq_q, bq))
+    nk = pl.cdiv(seq_k, bk)
     g = q_per_kv
-    if offset_arr is None:
-        offset_arr = jnp.zeros((1,), jnp.int32)
+
+    def kv_map(b, i, j, off):
+        if causal:  # clamp to the diagonal tile: skipped tiles move no data
+            j = jnp.minimum(j, jnp.minimum(
+                (off[0] + i * bq + bq - 1) // bk, nk - 1))
+        return (b // g, j, 0)
+
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
-                          block_k=bk, seq_k=valid_k, alibi=alibi,
-                          offset=offset),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, seq_k, d), lambda b, i: (b // g, 0, 0)),
-            pl.BlockSpec((1, seq_k, d), lambda b, i: (b // g, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b, i: (b, 0)),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i: (b, i, 0)),
-        ],
+                          seq_k=valid_k, alibi=alibi),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, pl.cdiv(seq_q, bq), nk),
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec((1, bq, d), lambda b, i, j, off: (b, i, 0)),
+                pl.BlockSpec((1, bk, d), kv_map),
+                pl.BlockSpec((1, bk, d), kv_map),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, bq, d), lambda b, i, j, off: (b, i, 0)),
+                pl.BlockSpec((1, bq, 1), lambda b, i, j, off: (b, i, 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bq, 1), jnp.float32),
+                pltpu.VMEM((bq, 1), jnp.float32),
+                pltpu.VMEM((bq, d), jnp.float32),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
             jax.ShapeDtypeStruct((bh, seq_q, 1), jnp.float32),
         ],
-        interpret=_interpret(),
-    )(q, k, v, alibi_arr, offset_arr)
+        compiler_params=_params("parallel", "parallel", "arbitrary"),
+        interpret=pallas_interpret(),
+        name="dstpu_flash_fwd",
+    )(offset_arr, alibi_arr, q, k, v)
     return out, lse
 
 
 # ---------------------------------------------------------------------------
 # backward kernels (recompute p from q,k + lse)
 # ---------------------------------------------------------------------------
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sl_ref,
-                   dq_ref, *, sm_scale, causal, block_k, seq_k, alibi):
-    q = q_ref[0].astype(jnp.float32)  # [bq, D]
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]  # [bq, 1]
-    delta = delta_ref[0]
-    bq, d = q.shape
-    iq = pl.program_id(1)
-    q_start = iq * bq
-    nk = pl.cdiv(q_start + bq, block_k) if causal else pl.cdiv(seq_k, block_k)
-    slope = sl_ref[0, 0] if alibi else 0.0
-
-    def body(j, dq):
-        k_blk = k_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        s = (q @ k_blk.T) * sm_scale
-        rows = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
-        cols = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)
-        if alibi:
-            s = s - slope * (rows - cols).astype(jnp.float32)
-        valid = cols < seq_k
-        if causal:
-            valid = valid & (rows >= cols)
-        s = jnp.where(valid, s, NEG_INF)
-        p = jnp.where(valid, jnp.exp(s - lse), 0.0)  # [bq, bk]
-        dp = do @ v_blk.T
-        ds = p * (dp - delta) * sm_scale
-        return dq + ds @ k_blk
-
-    dq = jax.lax.fori_loop(0, nk, body, jnp.zeros((bq, d), jnp.float32))
-    dq_ref[0] = dq.astype(dq_ref.dtype)
+def _bwd_tile(q, k, v, do, lse, delta, sl_ref, head, q_start, k_start, *,
+              sm_scale, causal, alibi, seq_q, seq_k):
+    """(p, ds) of one tile, both fp32 [bq, bk].  Padded q rows carry
+    garbage q/lse and padded k cols garbage k — both masked to zero."""
+    bq, bk = q.shape[0], k.shape[0]
+    rows = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+    cols = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    s, valid = _scores(q, k, sl_ref, head, rows, cols, sm_scale=sm_scale,
+                       causal=causal, alibi=alibi, seq_q=seq_q, seq_k=seq_k)
+    p = jnp.where(valid, jnp.exp(s - lse), 0.0)
+    dp = _dot(do, v, ((1,), (1,)))
+    return p, p * (dp - delta) * sm_scale
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sl_ref,
-                    dk_ref, dv_ref, dk_scr, dv_scr, *, sm_scale, causal,
-                    block_q, seq_q, seq_k, q_per_kv, alibi):
-    """Grid (B*KVH, nk, q_per_kv) — group index fastest, so the dk/dv
-    output block (indexed (bkv, jk), ignoring the group axis) is revisited
-    consecutively; each grouped q head's contribution accumulates in fp32
-    VMEM scratch (not the output dtype — bf16 accumulation would lose
-    precision across the group) and the cast happens once at the end."""
-    k_blk = k_ref[0].astype(jnp.float32)  # [bk, D]
-    v_blk = v_ref[0].astype(jnp.float32)
-    bk, d = k_blk.shape
-    jk = pl.program_id(1)
-    gi = pl.program_id(2)
+def _bwd_dq_kernel(sl_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                   dq_ref, dq_scr, *, sm_scale, causal, seq_q, seq_k, alibi):
+    """Grid (B*NH, nq, nk), k innermost; dq accumulates in fp32 scratch."""
+    bq, bk = q_ref.shape[1], k_ref.shape[1]
+    head, jk = pl.program_id(0), pl.program_id(2)
+    q_start = pl.program_id(1) * bq
     k_start = jk * bk
-    k_valid_until = seq_k
-    nq = pl.cdiv(seq_q, block_q)
-    slope = sl_ref[0, 0] if alibi else 0.0
 
-    def body(i, carry):
-        dk, dv = carry
-        q = q_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-        do = do_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(i * block_q, block_q), :]  # [bq, 1]
-        delta = delta_ref[0, pl.ds(i * block_q, block_q), :]
-        s = (q @ k_blk.T) * sm_scale  # [bq, bk]
-        rows = i * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 0)
-        cols = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 1)
-        if alibi:
-            s = s - slope * (rows - cols).astype(jnp.float32)
-        # guard padded q rows (garbage q/lse) and padded k cols
-        valid = (rows < seq_q) & (cols < k_valid_until)
-        if causal:
-            valid = valid & (rows >= cols)
-        s = jnp.where(valid, s, NEG_INF)
-        p = jnp.where(valid, jnp.exp(s - lse), 0.0)
-        dv = dv + p.T @ do
-        dp = do @ v_blk.T
-        ds = p * (dp - delta) * sm_scale
-        dk = dk + ds.T @ q
-        return dk, dv
+    @pl.when(jk == 0)
+    def _():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    start = 0
+    def tile():
+        _, ds = _bwd_tile(q_ref[0], k_ref[0], v_ref[0], do_ref[0],
+                          lse_ref[0], delta_ref[0], sl_ref, head, q_start,
+                          k_start,
+                          sm_scale=sm_scale, causal=causal, alibi=alibi,
+                          seq_q=seq_q, seq_k=seq_k)
+        dq_scr[...] += _dot(ds.astype(k_ref.dtype), k_ref[0], ((1,), (0,)))
+
     if causal:
-        # q blocks strictly before this k block contribute nothing
-        start = k_start // block_q
-    dk0 = jnp.zeros((bk, d), jnp.float32)
-    dv0 = jnp.zeros((bk, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(start, nq, body, (dk0, dv0))
+        pl.when(k_start <= q_start + bq - 1)(tile)
+    else:
+        tile()
 
-    @pl.when(gi == 0)
+    @pl.when(jk == pl.num_programs(2) - 1)
+    def _():
+        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+
+
+def _bwd_dkv_kernel(sl_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                    dk_ref, dv_ref, dk_scr, dv_scr, *, sm_scale, causal,
+                    seq_q, seq_k, q_per_kv, alibi):
+    """Grid (B*KVH, nk, q_per_kv, nq): the dk/dv output block (indexed
+    (bkv, jk)) is revisited across the two inner axes — every grouped q
+    head and every q tile accumulates into fp32 VMEM scratch (not the
+    output dtype — bf16 accumulation would lose precision across the
+    group) and the cast happens once at the end."""
+    bq, bk = q_ref.shape[1], k_ref.shape[1]
+    gi, iq = pl.program_id(2), pl.program_id(3)
+    head = pl.program_id(0) * q_per_kv + gi
+    q_start = iq * bq
+    k_start = pl.program_id(1) * bk
+
+    @pl.when((gi == 0) & (iq == 0))
     def _():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    dk_scr[...] += dk
-    dv_scr[...] += dv
+    def tile():
+        p, ds = _bwd_tile(q_ref[0], k_ref[0], v_ref[0], do_ref[0],
+                          lse_ref[0], delta_ref[0], sl_ref, head, q_start,
+                          k_start,
+                          sm_scale=sm_scale, causal=causal, alibi=alibi,
+                          seq_q=seq_q, seq_k=seq_k)
+        dv_scr[...] += _dot(p.astype(do_ref.dtype), do_ref[0], ((0,), (0,)))
+        dk_scr[...] += _dot(ds.astype(q_ref.dtype), q_ref[0], ((0,), (0,)))
 
-    @pl.when(gi == q_per_kv - 1)
+    if causal:
+        # q tiles wholly before this k tile contribute nothing
+        pl.when(q_start + bq - 1 >= k_start)(tile)
+    else:
+        tile()
+
+    @pl.when((gi == q_per_kv - 1) & (iq == pl.num_programs(3) - 1))
     def _():
         dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
@@ -238,57 +264,63 @@ def _bwd(sm_scale, causal, block_q, block_k, valid_q, valid_k, q_per_kv,
     # effective tile here must match it so every block divides the padding
     bq = min(bwd_block_q or block_q, valid_q, seq_q)
     bk = min(bwd_block_k or block_k, valid_k, seq_k)
+    nq, nk = pl.cdiv(seq_q, bq), pl.cdiv(seq_k, bk)
     g = q_per_kv
 
     delta = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32), axis=-1,
                     keepdims=True)  # [BH, Sq, 1]
+    kw = dict(sm_scale=sm_scale, causal=causal, seq_q=valid_q, seq_k=valid_k,
+              alibi=alibi)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
 
+    def kv_map(b, i, j):
+        if causal:  # as in the forward: skipped tiles re-use the diagonal's
+            j = jnp.minimum(j, jnp.minimum((i * bq + bq - 1) // bk, nk - 1))
+        return (b // g, j, 0)
+
+    q_spec = pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0))
+    row_spec = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0))
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-                          block_k=bk, seq_k=valid_k, alibi=alibi),
-        grid=(bh, pl.cdiv(seq_q, bq)),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, seq_k, d), lambda b, i: (b // g, 0, 0)),
-            pl.BlockSpec((1, seq_k, d), lambda b, i: (b // g, 0, 0)),
-            pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, 1), lambda b, i: (b, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0)),
+        functools.partial(_bwd_dq_kernel, **kw),
+        grid=(bh, nq, nk),
+        in_specs=[smem, q_spec, pl.BlockSpec((1, bk, d), kv_map),
+                  pl.BlockSpec((1, bk, d), kv_map), q_spec, row_spec,
+                  row_spec],
+        out_specs=q_spec,
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=_interpret(),
-    )(q, k, v, do, lse, delta, alibi_arr)
+        compiler_params=_params("parallel", "parallel", "arbitrary"),
+        interpret=pallas_interpret(),
+        name="dstpu_flash_bwd_dq",
+    )(alibi_arr, q, k, v, do, lse, delta)
 
+    def q_map(b, j, gi, i):
+        if causal:  # first q tile that reaches this k tile
+            i = jnp.maximum(i, jnp.minimum((j * bk) // bq, nq - 1))
+        return (b * g + gi, i, 0)
+
+    kv_spec = pl.BlockSpec((1, bk, d), lambda b, j, gi, i: (b, j, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=bq, seq_q=valid_q, seq_k=valid_k,
-                          q_per_kv=g, alibi=alibi),
-        grid=(bkv, pl.cdiv(seq_k, bk), g),
+        functools.partial(_bwd_dkv_kernel, q_per_kv=g, **kw),
+        grid=(bkv, nk, g, nq),
+        in_specs=[smem, pl.BlockSpec((1, bq, d), q_map), kv_spec, kv_spec,
+                  pl.BlockSpec((1, bq, d), q_map),
+                  pl.BlockSpec((1, bq, 1), q_map),
+                  pl.BlockSpec((1, bq, 1), q_map)],
+        out_specs=[kv_spec, kv_spec],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
-        ],
-        in_specs=[
-            pl.BlockSpec((1, seq_q, d), lambda b, j, gi: (b * g + gi, 0, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, j, gi: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, j, gi: (b, j, 0)),
-            pl.BlockSpec((1, seq_q, d), lambda b, j, gi: (b * g + gi, 0, 0)),
-            pl.BlockSpec((1, seq_q, 1), lambda b, j, gi: (b * g + gi, 0, 0)),
-            pl.BlockSpec((1, seq_q, 1), lambda b, j, gi: (b * g + gi, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b, j, gi: (b * g + gi, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bk, d), lambda b, j, gi: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, j, gi: (b, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
-        interpret=_interpret(),
-    )(q, k, v, do, lse, delta, alibi_arr)
+        compiler_params=_params("parallel", "parallel", "arbitrary",
+                                "arbitrary"),
+        interpret=pallas_interpret(),
+        name="dstpu_flash_bwd_dkv",
+    )(alibi_arr, q, k, v, do, lse, delta)
     # alibi slopes are fixed constants: zero cotangent
     return dq, dk, dv, jnp.zeros_like(alibi_arr)
 
@@ -297,16 +329,16 @@ def _bwd(sm_scale, causal, block_q, block_k, valid_q, valid_k, q_per_kv,
                                                     11, 12, 13))
 def _flash_bhsd(q, k, v, alibi_arr, sm_scale, causal, block_q, block_k,
                 valid_q, valid_k, q_per_kv, bwd_block_q, bwd_block_k, alibi):
-    out, _ = _fwd(q, k, v, alibi_arr, sm_scale, causal, block_q, block_k,
-                  valid_q, valid_k, q_per_kv, alibi=alibi)
+    out, _ = _fwd(q, k, v, alibi_arr, jnp.zeros((1,), jnp.int32), sm_scale,
+                  causal, block_q, block_k, valid_k, q_per_kv, alibi=alibi)
     return out
 
 
 def _flash_fwd_rule(q, k, v, alibi_arr, sm_scale, causal, block_q, block_k,
                     valid_q, valid_k, q_per_kv, bwd_block_q, bwd_block_k,
                     alibi):
-    out, lse = _fwd(q, k, v, alibi_arr, sm_scale, causal, block_q, block_k,
-                    valid_q, valid_k, q_per_kv, alibi=alibi)
+    out, lse = _fwd(q, k, v, alibi_arr, jnp.zeros((1,), jnp.int32), sm_scale,
+                    causal, block_q, block_k, valid_k, q_per_kv, alibi=alibi)
     return out, (q, k, v, alibi_arr, out, lse)
 
 
@@ -334,8 +366,8 @@ def flash_attention(q, k, v, causal: bool = True, segment_mask=None,
     of the forward (0 = inherit): the dq/dkv kernels keep different
     residents in VMEM, so the fwd-optimal tiling need not be bwd-optimal.
 
-    ``segment_mask``: optional [B, S_k] padding mask (1 = keep); falls back
-    to the XLA path when given (masked flash variant: future work).
+    ``segment_mask``: optional [B, S_k] padding mask (1 = keep); runs the
+    XLA path when given, with a warning (masked flash variant: future work).
 
     ``alibi_slopes``: optional [NH] per-head ALiBi slopes — the bias is
     built INSIDE the kernels from block indices (score -= slope*(i-j)),
@@ -353,7 +385,12 @@ def flash_attention(q, k, v, causal: bool = True, segment_mask=None,
     KVH = k.shape[2]
     if segment_mask is not None:
         from ...models.transformer import _repeat_kv, xla_attention
+        from ...utils.logging import warning_once
 
+        warning_once(
+            "flash_attention: a padding mask was given and the kernel has no "
+            "masked variant — this call runs xla_attention, which "
+            "materializes the [B, NH, S, S] scores")
         bias = None
         if alibi_slopes is not None:
             # END-align queries like xla_attention's causal mask (tril with
@@ -403,16 +440,15 @@ def flash_attention(q, k, v, causal: bool = True, segment_mask=None,
         kh = jnp.pad(kh, ((0, 0), (0, pad_k), (0, 0)))
         vh = jnp.pad(vh, ((0, 0), (0, pad_k), (0, 0)))
     if alibi_slopes is not None:
-        sl = jnp.tile(jnp.asarray(alibi_slopes, jnp.float32), B)[:, None]
+        sl = jnp.tile(jnp.asarray(alibi_slopes, jnp.float32), B)
     else:
-        sl = jnp.zeros((B * NH, 1), jnp.float32)
+        sl = jnp.zeros((B * NH,), jnp.float32)
     if q_offset is not None:
         # forward-only inference path (no custom VJP)
-        out, _ = _fwd(qh, kh, vh, sl, scale, causal, block_q, block_k,
-                      Sq, Sk, q_per_kv, alibi=alibi_slopes is not None,
-                      offset_arr=jnp.asarray(q_offset,
-                                             jnp.int32).reshape(1),
-                      offset=True)
+        out, _ = _fwd(qh, kh, vh, sl,
+                      jnp.asarray(q_offset, jnp.int32).reshape(1), scale,
+                      causal, block_q, block_k, Sk, q_per_kv,
+                      alibi=alibi_slopes is not None)
     else:
         out = _flash_bhsd(qh, kh, vh, sl, scale, causal, block_q, block_k,
                           Sq, Sk, q_per_kv, bwd_block_q, bwd_block_k,

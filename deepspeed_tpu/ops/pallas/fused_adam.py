@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...utils.platform import pallas_interpret
+
 
 def _adam_kernel(p_ref, g_ref, m_ref, v_ref, sc_ref,
                  p_out, m_out, v_out, *, beta1, beta2, eps, weight_decay,
@@ -91,7 +93,7 @@ def fused_adam_update(params: jnp.ndarray, grads: jnp.ndarray,
             jax.ShapeDtypeStruct(shape2d, exp_avg.dtype),
             jax.ShapeDtypeStruct(shape2d, exp_avg_sq.dtype),
         ],
-        interpret=jax.default_backend() != "tpu",
+        interpret=pallas_interpret(),
     )(*args, scalars)
     p, m, v = (o.reshape(total)[:n] for o in out)
     return p, m, v

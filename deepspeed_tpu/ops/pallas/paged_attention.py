@@ -8,7 +8,7 @@ scalar-prefetch operand and each grid step's K/V block is addressed
 gather the XLA fallback materializes per layer per token never exists.
 
 Layout: q [B, KVH, G, D] (GQA groups folded next to their kv head);
-pools [P, ps, KVH, D]; page_table [B, MP] int32 (trash-filled past each
+pools [P, ps, KVH, D], read as [P, ps, KVH*D] page blocks; page_table [B, MP] int32 (trash-filled past each
 sequence's pages); positions [B] int32 (slot of the CURRENT token —
 slots > position are masked, so trash pages beyond the length are
 harmless).  Online softmax accumulates across the page grid axis in VMEM
@@ -25,23 +25,26 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...utils.platform import pallas_interpret
+
 NEG_INF = -1e30
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def _decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
-                   ps, scale, n_pages, quant, alibi):
+                   ps, scale, kvh, quant, alibi):
+    """One (sequence, page) grid step: every kv head of the page against
+    its query group.  The page block is [ps, KVH*D] — head ``h`` is the
+    lane slice ``[h*D, (h+1)*D)``, a tile-aligned view (D a multiple of
+    128), where a per-head block ``(1, ps, 1, D)`` over the pool would put
+    a size-1 block on the second-minor (KVH) dim, which Mosaic refuses."""
     rest = list(rest)
     sl_ref = rest.pop(0) if alibi else None
-    if quant:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
-    else:
-        o_ref, m_scr, l_scr, acc_scr = rest
-    b = pl.program_id(0)
-    jp = pl.program_id(2)
+    ks_ref, vs_ref = (rest.pop(0), rest.pop(0)) if quant else (None, None)
+    o_ref, m_scr, l_scr, acc_scr = rest
+    b, jp = pl.program_id(0), pl.program_id(1)
+    d = q_ref.shape[-1]
+    g = q_ref.shape[-2]
+    pos = pos_ref[b]
 
     @pl.when(jp == 0)
     def _():
@@ -49,36 +52,40 @@ def _decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale      # [G, D]
-    k = k_ref[0, :, 0, :].astype(jnp.float32)        # [ps, D]
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    if quant:  # int8 codes * per-(slot, head) scale, dequantized in VMEM.
-        # Scales ride as [P, ps, KVH, 1] blocks mirroring K/V's rank so the
-        # in-kernel loads stay the 2-D shapes Mosaic provably lowers.
-        k = k * ks_ref[0, :, 0, :]                   # [ps, 1] broadcast
-        v = v * vs_ref[0, :, 0, :]
-    s = q @ k.T                                      # [G, ps]
-    pos = pos_ref[b]
-    slots = jp * ps + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    if alibi:
-        # ALiBi distance penalty from page-slot indices (bloom decode)
-        s = s - sl_ref[0] * (pos - slots).astype(jnp.float32)
-    s = jnp.where(slots <= pos, s, NEG_INF)
-
-    m_prev, l_prev = m_scr[...], l_scr[...]
-    m_cur = jnp.max(s, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    p = jnp.exp(s - m_new)
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + p @ v
-    m_scr[...] = m_new
-    l_scr[...] = l_new
-
-    @pl.when(jp == n_pages - 1)
+    # pages wholly past the current token hold nothing visible (trash
+    # rows of the table all name one page, so they move no data either)
+    @pl.when(jp * ps <= pos)
     def _():
-        o_ref[0, 0] = (acc_scr[...] /
-                       jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+        slots = jp * ps + jax.lax.broadcasted_iota(jnp.int32, (g, ps), 1)
+        for h in range(kvh):
+            q = q_ref[0, h]                           # [G, D]
+            k = k_ref[0, :, h * d:(h + 1) * d]        # [ps, D]
+            v = v_ref[0, :, h * d:(h + 1) * d]
+            if quant:  # int8 codes * per-(slot, head) scale, in VMEM
+                k = (k.astype(jnp.float32)
+                     * ks_ref[0, :, h:h + 1]).astype(q.dtype)
+                v = (v.astype(jnp.float32)
+                     * vs_ref[0, :, h:h + 1]).astype(q.dtype)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # [G, ps]
+            if alibi:
+                # ALiBi distance penalty from page-slot indices (bloom)
+                s = s - sl_ref[h] * (pos - slots).astype(jnp.float32)
+            s = jnp.where(slots <= pos, s, NEG_INF)
+            m_prev = m_scr[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * alpha + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            m_scr[h] = m_new
+
+    @pl.when(jp == pl.num_programs(1) - 1)
+    def _():
+        o_ref[0] = (acc_scr[...] /
+                    jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q, k_pool, v_pool, page_table, positions,
@@ -97,49 +104,44 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, positions,
     qg = q.reshape(B, KVH, G, D)
 
     alibi = alibi_slopes is not None
-    in_specs = [
-        pl.BlockSpec((1, 1, G, D),
-                     lambda b, h, jp, pt, pos: (b, h, 0, 0)),
-        # the page-table lookup: this block IS the page
-        pl.BlockSpec((1, ps, 1, D),
-                     lambda b, h, jp, pt, pos: (pt[b, jp], 0, h, 0)),
-        pl.BlockSpec((1, ps, 1, D),
-                     lambda b, h, jp, pt, pos: (pt[b, jp], 0, h, 0)),
-    ]
-    args = [qg, k_pool, v_pool]
+    q_spec = pl.BlockSpec((1, KVH, G, D), lambda b, jp, pt, pos: (b, 0, 0, 0))
+    # the page-table lookup: this block IS the page (all kv heads of it;
+    # the [P, ps, KVH*D] view of the pool is a free reshape)
+    page_spec = pl.BlockSpec((1, ps, KVH * D),
+                             lambda b, jp, pt, pos: (pt[b, jp], 0, 0))
+    in_specs = [q_spec, page_spec, page_spec]
+    args = [qg, k_pool.reshape(P, ps, KVH * D), v_pool.reshape(P, ps, KVH * D)]
     if alibi:
         # rides right after k/v so the kernel pops it off *rest first
         in_specs.append(pl.BlockSpec(
-            (1, G, 1), lambda b, h, jp, pt, pos: (h, 0, 0)))
+            (KVH, G, 1), lambda b, jp, pt, pos: (0, 0, 0)))
         args.append(jnp.asarray(alibi_slopes, jnp.float32)
                     .reshape(KVH, G, 1))
     if quant:
-        in_specs += [
-            pl.BlockSpec((1, ps, 1, 1),
-                         lambda b, h, jp, pt, pos: (pt[b, jp], 0, h, 0)),
-            pl.BlockSpec((1, ps, 1, 1),
-                         lambda b, h, jp, pt, pos: (pt[b, jp], 0, h, 0)),
-        ]
-        args += [k_scale[..., None], v_scale[..., None]]
+        scale_spec = pl.BlockSpec((1, ps, KVH),
+                                  lambda b, jp, pt, pos: (pt[b, jp], 0, 0))
+        in_specs += [scale_spec, scale_spec]
+        args += [k_scale, v_scale]
 
-    grid = (B, KVH, MP)
     kernel = pl.pallas_call(
-        functools.partial(_decode_kernel, ps=ps, scale=scale, n_pages=MP,
+        functools.partial(_decode_kernel, ps=ps, scale=scale, kvh=KVH,
                           quant=quant, alibi=alibi),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=grid,
+            grid=(B, MP),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, G, D),
-                                   lambda b, h, jp, pt, pos: (b, h, 0, 0)),
+            out_specs=q_spec,
             scratch_shapes=[
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, D), jnp.float32),
+                pltpu.VMEM((KVH, G, 1), jnp.float32),
+                pltpu.VMEM((KVH, G, 1), jnp.float32),
+                pltpu.VMEM((KVH, G, D), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, KVH, G, D), q.dtype),
-        interpret=_interpret(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=pallas_interpret(),
+        name="dstpu_paged_decode",
     )
     out = kernel(page_table, positions, *args)
     return out.reshape(B, NH, D)

@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ...utils.platform import pallas_interpret
+
 
 def _quant_kernel(x_ref, q_ref, s_ref):
     x = x_ref[...].astype(jnp.float32)  # [rows, 128]
@@ -48,7 +50,7 @@ def quantize_int8(x: jnp.ndarray, block_rows: int = 256) -> Tuple[jnp.ndarray, j
                    pl.BlockSpec((br, 1), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((rows, 128), jnp.int8),
                    jax.ShapeDtypeStruct((rows, 1), jnp.float32)],
-        interpret=jax.default_backend() != "tpu",
+        interpret=pallas_interpret(),
     )(x2)
     return q, s, n
 
@@ -64,6 +66,6 @@ def dequantize_int8(q: jnp.ndarray, s: jnp.ndarray, orig_len: int,
                   pl.BlockSpec((br, 1), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, 128), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, 128), dtype),
-        interpret=jax.default_backend() != "tpu",
+        interpret=pallas_interpret(),
     )(q, s)
     return x.reshape(-1)[:orig_len]

@@ -26,6 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from ...utils.platform import pallas_interpret
+
 
 # --------------------------------------------------------------- layouts
 @dataclasses.dataclass
@@ -218,6 +220,6 @@ def sparse_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((1, block, D), lambda bh, _, qi: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
-        interpret=jax.default_backend() != "tpu",
+        interpret=pallas_interpret(),
     )(lay_bh, qt, kt, vt)
     return out.reshape(B, H, S, D).transpose(0, 2, 1, 3)

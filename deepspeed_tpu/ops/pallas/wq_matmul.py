@@ -24,10 +24,9 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from ...utils.platform import on_tpu, pallas_interpret
 
 
 # ---------------------------------------------------------------------------
@@ -75,26 +74,44 @@ def dequantize_weight(codes: jnp.ndarray, scale: jnp.ndarray, *, bits: int,
 # ---------------------------------------------------------------------------
 # Pallas kernel
 # ---------------------------------------------------------------------------
-def _wq_kernel(x_ref, w_ref, s_ref, o_ref, *, group, bits, n_groups):
-    x = x_ref[0].astype(jnp.float32)  # [bm, Kp]
-    bm = x.shape[0]
-    bn = o_ref.shape[-1]
+def _wq_kernel(*refs, group, bits, groups_per_tile):
+    """Grid (M tiles, N tiles, K tiles), K innermost; one K tile spans
+    ``groups_per_tile`` quantization groups, each dequantized in VMEM
+    right before its MXU contribution (static slices: Mosaic has no
+    dynamic_slice on values).
 
-    def body(g, acc):
-        xg = jax.lax.dynamic_slice_in_dim(x, g * group, group, 1)  # [bm, G]
+    int4 packs rows (2i, 2i+1) into one byte, so the wrapper hands the
+    kernel x's even and odd columns separately and each nibble plane
+    meets its own half — no in-kernel row interleave."""
+    n_x = 1 if bits == 8 else 2
+    x_refs, (w_ref, s_ref, o_ref, acc_ref) = refs[:n_x], refs[n_x:]
+    k = pl.program_id(2)
+    rows = group if bits == 8 else group // 2  # code rows per group
+
+    @pl.when(k == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    dt = x_refs[0].dtype
+    acc = acc_ref[...]
+    for g in range(groups_per_tile):
+        sg = s_ref[g:g + 1, :]  # [1, bn]
+        codes = w_ref[g * rows:(g + 1) * rows, :]
         if bits == 8:
-            wg = jax.lax.dynamic_slice_in_dim(w_ref[0], g * group, group, 0)
-            wg = wg.astype(jnp.float32)
+            planes = (codes.astype(jnp.float32),)
         else:
-            packed = jax.lax.dynamic_slice_in_dim(
-                w_ref[0], g * (group // 2), group // 2, 0)  # [G/2, bn]
-            wg = _unpack_int4(packed)  # [G, bn]
-        sg = s_ref[0, g]  # [bn]
-        return acc + xg @ (wg * sg[None, :])
+            c = codes.astype(jnp.int32)
+            planes = (((c & 0xF) - 8).astype(jnp.float32),
+                      ((c >> 4) - 8).astype(jnp.float32))
+        for x_ref, plane in zip(x_refs, planes):
+            acc = acc + jnp.dot(x_ref[:, g * rows:(g + 1) * rows],
+                                (plane * sg).astype(dt),
+                                preferred_element_type=jnp.float32)
+    acc_ref[...] = acc
 
-    acc = jax.lax.fori_loop(0, n_groups, body,
-                            jnp.zeros((bm, bn), jnp.float32))
-    o_ref[0] = acc.astype(o_ref.dtype)
+    @pl.when(k == pl.num_programs(2) - 1)
+    def _():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
 def wq_matmul(x: jnp.ndarray, codes: jnp.ndarray, scale: jnp.ndarray, *,
@@ -103,7 +120,9 @@ def wq_matmul(x: jnp.ndarray, codes: jnp.ndarray, scale: jnp.ndarray, *,
     """``x @ W`` with W stored quantized.  x: [..., K]; returns [..., N].
 
     int8/int4 codes are what crosses HBM; dequantization happens in VMEM
-    per K-group right before the MXU contribution."""
+    per K-group right before the MXU contribution.  ``impl="auto"`` is
+    the kernel on TPU — never the XLA dequant — and the XLA dequant on
+    the CPU test tier, where interpreting would only slow the tests."""
     K = x.shape[-1]
     Kp = codes.shape[0] * (2 if bits == 4 else 1)
     N = codes.shape[1]
@@ -112,7 +131,7 @@ def wq_matmul(x: jnp.ndarray, codes: jnp.ndarray, scale: jnp.ndarray, *,
     xm = x.reshape(-1, K)
     M = xm.shape[0]
 
-    if impl == "xla" or (impl == "auto" and _interpret()):
+    if impl == "xla" or (impl == "auto" and not on_tpu()):
         w = dequantize_weight(codes, scale, bits=bits, group=group, k=K,
                               dtype=jnp.float32)
         out = (xm.astype(jnp.float32) @ w).astype(x.dtype)
@@ -121,7 +140,7 @@ def wq_matmul(x: jnp.ndarray, codes: jnp.ndarray, scale: jnp.ndarray, *,
     if K != Kp:  # padded packing: extend x with zeros (pad weights are 0)
         xm = jnp.pad(xm, ((0, 0), (0, Kp - K)))
 
-    bm = min(block_m, max(M, 8))
+    bm = min(block_m, -(-M // 8) * 8)
     bn = min(block_n, N)
     pad_m = (-M) % bm
     pad_n = (-N) % bn
@@ -132,19 +151,26 @@ def wq_matmul(x: jnp.ndarray, codes: jnp.ndarray, scale: jnp.ndarray, *,
         w = jnp.pad(w, ((0, 0), (0, pad_n)))
         s = jnp.pad(s, ((0, 0), (0, pad_n)))
     n_groups = Kp // group
-    rows = w.shape[0]  # Kp (int8) or Kp/2 (int4)
+    # a K tile is 8 groups (the scale block's sublane tile) when that
+    # divides K, else all of K
+    gpt = 8 if n_groups % 8 == 0 else n_groups
+    tk = gpt * group
+    xs = (xm,) if bits == 8 else (xm[:, 0::2], xm[:, 1::2])
+    xk = tk if bits == 8 else tk // 2  # x / code columns-rows per K tile
 
     out = pl.pallas_call(
         functools.partial(_wq_kernel, group=group, bits=bits,
-                          n_groups=n_groups),
-        grid=(pl.cdiv(M + pad_m, bm), pl.cdiv(N + pad_n, bn)),
-        in_specs=[
-            pl.BlockSpec((1, bm, Kp), lambda i, j: (0, i, 0)),
-            pl.BlockSpec((1, rows, bn), lambda i, j: (0, 0, j)),
-            pl.BlockSpec((1, n_groups, bn), lambda i, j: (0, 0, j)),
-        ],
-        out_specs=pl.BlockSpec((1, bm, bn), lambda i, j: (0, i, j)),
-        out_shape=jax.ShapeDtypeStruct((1, M + pad_m, N + pad_n), x.dtype),
-        interpret=_interpret(),
-    )(xm[None], w[None], s[None])[0]
+                          groups_per_tile=gpt),
+        grid=((M + pad_m) // bm, (N + pad_n) // bn, n_groups // gpt),
+        in_specs=[pl.BlockSpec((bm, xk), lambda i, j, k: (i, k))] * len(xs)
+        + [pl.BlockSpec((xk, bn), lambda i, j, k: (k, j)),
+           pl.BlockSpec((gpt, bn), lambda i, j, k: (k, j))],
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        out_shape=jax.ShapeDtypeStruct((M + pad_m, N + pad_n), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=pallas_interpret(),
+        name="dstpu_wq_matmul",
+    )(*xs, w, s)
     return out[:M, :N].reshape(*lead, N)

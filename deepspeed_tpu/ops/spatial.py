@@ -20,6 +20,8 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from ..utils.platform import on_tpu
+
 
 # ---------------------------------------------------------------------------
 # fused bias-add family (reference opt_bias_add.cu: add / add_add /
@@ -83,7 +85,7 @@ def diffusers_attention(x: jnp.ndarray, params: Dict[str, Any], n_heads: int,
     k = proj(ctx, "wk", "bk").reshape(B, ctx.shape[1], n_heads, D)
     v = proj(ctx, "wv", "bv").reshape(B, ctx.shape[1], n_heads, D)
 
-    if jax.default_backend() == "tpu" and D in (64, 128) \
+    if on_tpu() and D in (64, 128) \
             and HW % 128 == 0 and ctx.shape[1] % 128 == 0:
         from .pallas.flash_attention import flash_attention
 

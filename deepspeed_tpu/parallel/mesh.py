@@ -26,7 +26,6 @@ import math
 from typing import Optional, Sequence, Tuple
 
 import jax
-import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..runtime.config import MeshConfig
@@ -81,12 +80,11 @@ class MeshTopology:
             raise ValueError(f"Mesh axis product {fixed} != device count {n}")
 
         shape = tuple(sizes[a] for a in ALL_AXES)
-        try:
-            from jax.experimental import mesh_utils
+        from jax.experimental import mesh_utils
 
-            device_array = mesh_utils.create_device_mesh(shape, devices=devices)
-        except Exception:  # pragma: no cover - fallback for odd topologies
-            device_array = np.asarray(devices).reshape(shape)
+        # no enumeration-order fallback: a mesh that ignores the physical
+        # topology runs, slowly, and nothing says why
+        device_array = mesh_utils.create_device_mesh(shape, devices=devices)
         self.mesh = Mesh(device_array, ALL_AXES)
         self.axis_sizes = sizes
         logger.info(f"MeshTopology: {sizes} over {n} devices")

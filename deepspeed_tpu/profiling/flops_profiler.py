@@ -28,10 +28,7 @@ def cost_analysis_of(fn, *args) -> Dict[str, float]:
     try:
         lowered = fn.lower(*args)
         compiled = lowered.compile()
-        costs = compiled.cost_analysis()
-        if isinstance(costs, list):  # older jax returns [dict]
-            costs = costs[0] if costs else {}
-        return dict(costs or {})
+        return dict(compiled.cost_analysis() or {})
     except Exception as e:  # pragma: no cover
         logger.warning(f"cost_analysis failed: {e}")
         return {}

@@ -41,6 +41,7 @@ from ..telemetry.flight import dump_on_exception
 from ..telemetry.spans import record_event, span
 from ..utils.jax_compat import shard_map
 from ..utils.logging import log_dist, logger
+from ..utils.platform import on_tpu
 from ..utils.timer import (BACKWARD_GLOBAL_TIMER, FORWARD_GLOBAL_TIMER,
                            STEP_GLOBAL_TIMER, SynchronizedWallClockTimer,
                            ThroughputTimer)
@@ -581,10 +582,6 @@ class DeepSpeedTPUEngine:
                 num_micro=int(self.model.num_microbatches),
                 grad_dtype=self.grad_accum_dtype,
                 compression=comp)
-            if self._pipe_plan is not None:
-                from ..compile.backend import validate_latency_hiding_flags
-
-                validate_latency_hiding_flags()
         elif wanted and reason is None:
             from ..parallel.mesh import DATA_AXIS
             from .zero.overlap import build_overlap_plan
@@ -596,12 +593,6 @@ class DeepSpeedTPUEngine:
                 grad_dtype=self.grad_accum_dtype,
                 compression=self._overlap_spec,
                 hier_inner=getattr(self, "_hier_inner", 0))
-            if self._overlap_plan is not None:
-                from ..compile.backend import validate_latency_hiding_flags
-
-                # the XLA backstop: warn when the scheduler flags that
-                # actually hide the in-loop collectives aren't pinned
-                validate_latency_hiding_flags()
         if not has_layers:
             return
         # structural exposure split: grad-exchange bytes per micro-step,
@@ -1240,8 +1231,14 @@ class DeepSpeedTPUEngine:
         # unconditional zeros_like here would be a model-sized HBM memset on
         # the hot path, since the donated output buffer must really be
         # written for the next step to read.
+        # The fresh zeros carry the buffer's planned sharding: left to the
+        # compiler they come back replicated (seen on four chips under
+        # {model: 2, data: 2}: 1/1 instead of 1/2 per device, and one extra
+        # compile when step 2 meets the changed input sharding).
         zero_acc = (state.grad_acc if grads_src is not None
-                    else jax.tree_util.tree_map(jnp.zeros_like, state.grad_acc))
+                    else self.zero_plan.constrain(
+                        jax.tree_util.tree_map(jnp.zeros_like, state.grad_acc),
+                        "grad"))
         return dataclasses.replace(
             state,
             params=new_params,
@@ -1398,7 +1395,7 @@ class DeepSpeedTPUEngine:
             # kind "device").
             import os as _os
 
-            if (jax.default_backend() == "tpu"
+            if (on_tpu()
                     and _os.environ.get("DSTPU_OFFLOAD_HOST_GRADS") == "1"):
                 state_sh = jax.tree_util.tree_map(
                     lambda x: x.sharding if hasattr(x, "sharding") else None,
@@ -1436,7 +1433,7 @@ class DeepSpeedTPUEngine:
                     if hasattr(x, "sharding") and getattr(x, "ndim", 0) >= 1
                     else "keep",
                     self.state.params)
-            if jax.default_backend() == "tpu":
+            if on_tpu():
                 state_sh = jax.tree_util.tree_map(
                     lambda x: x.sharding if hasattr(x, "sharding") else None,
                     self.state)

@@ -19,6 +19,8 @@ are dropped after the gather.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
@@ -47,16 +49,15 @@ def ulysses_attention(q, k, v, causal: bool = True, mask=None, inner=None):
     sp = topo.seq_parallel_size
     nh = q.shape[2]
     if inner is None:
-        from ..models.transformer import xla_attention
+        from ..utils.platform import on_tpu
 
-        try:
-            from ..ops.pallas.flash_attention import flash_attention
+        if on_tpu():
+            from ..models.transformer import flash_on_mesh
 
-            inner = (lambda q, k, v, causal, mask=None:
-                     flash_attention(q, k, v, causal=causal, segment_mask=mask)) \
-                if jax.default_backend() == "tpu" else xla_attention
-        except Exception:
-            inner = xla_attention
+            # heads ride the sequence axis between the two all-to-alls
+            inner = functools.partial(flash_on_mesh, head_axes=(SEQ_AXIS,))
+        else:
+            from ..models.transformer import xla_attention as inner
     if sp <= 1:
         return inner(q, k, v, causal, mask)
     if k.shape[2] != nh and (nh % sp or k.shape[2] % sp or v.shape[2] % sp):

@@ -377,7 +377,7 @@ class FleetRouter:
                 target, via = placed, "engine_full_fallback"
         except BaseException:
             # the request was never admitted anywhere: a ghost record
-            # with done=False would wedge has_work() True forever
+            # with done=False would pin has_work() True forever
             # (the shed path above already finished the ledger entry —
             # discard is a no-op for it)
             self._requests.pop(uid, None)

@@ -717,8 +717,9 @@ class EngineServer:
 def _server_main(spec: Dict[str, Any], address: str) -> None:
     """Child-process entry: rebuild an identical engine from the spec
     (weights re-derived from ``init_params(PRNGKey(seed))`` — never
-    shipped), bind, serve.  Top-level so ``spawn`` can import it."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    shipped), bind, serve.  Top-level so ``spawn`` can import it.  The
+    platform is the one the caller named in the spec, never a default."""
+    os.environ["JAX_PLATFORMS"] = spec["platform"]
     import jax
 
     from ..inference.v2 import InferenceEngineV2, RaggedInferenceConfig
@@ -746,9 +747,30 @@ def spawn_engine_server(spec: Dict[str, Any], *,
     built, so the bounded wait here doubles as the ready handshake
     (cold JAX import + engine construction can take tens of seconds on
     a busy box); the transport's own bounded backoff then covers only
-    genuine transport faults."""
+    genuine transport faults.
+
+    ``spec["platform"]`` (``"cpu"`` or ``"tpu"``) is required: the child
+    runs where the caller says, not on a silent CPU.  A chip belongs to
+    one process, so a ``"tpu"`` child is refused when this process has
+    already initialized the TPU backend — it would fail or hang at
+    start-up; spawn from a parent that stays off JAX (ROADMAP R2 gives
+    each replica its own chip)."""
     import multiprocessing
 
+    platform = spec.get("platform")
+    if platform not in ("cpu", "tpu"):
+        raise ValueError(
+            "spawn_engine_server: spec['platform'] must be 'cpu' or 'tpu', "
+            f"got {platform!r}")
+    if platform == "tpu":
+        from ..utils.platform import holds_tpu
+
+        if holds_tpu():
+            raise TransportError(
+                "spawn_engine_server: this process holds the TPU; a child "
+                "replica that needs the chip would fail or hang.  Spawn "
+                "'tpu' replicas from a parent that has not touched JAX, or "
+                "pass platform='cpu'")
     cfg = spec.get("engine_config")
     if cfg is not None and dataclasses.is_dataclass(cfg):
         spec = dict(spec)

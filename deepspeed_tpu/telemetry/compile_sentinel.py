@@ -158,7 +158,7 @@ class RecompileSentinel:
         #: steady, or it could never reach the warn threshold
         self._steady_steps = 0
         #: incident-edge latch: a sustained steady-recompile run counts
-        #: every step but logs once (a wedged loop must not flood the log)
+        #: every step but logs once (a stuck loop must not flood the log)
         self._in_steady = False
         self._expected: Optional[str] = None
         _SENTINELS.add(self)

@@ -11,7 +11,7 @@ Dumps fire:
 * when the stall watchdog trips (``Telemetry`` wires the watchdog's
   ``on_stall`` callback here),
 
-so a wedged collective or a mid-step crash leaves a reconstructable
+so a hung collective or a mid-step crash leaves a reconstructable
 timeline instead of an empty log.  The recorder itself only ever
 appends to host-side rings — no I/O, no device syncs — until a dump is
 actually requested.

@@ -6,9 +6,10 @@ chip) shared by the telemetry gauges, ``bench.py`` and
 numbers stop being comparable.  Sources: published TPU specs (v4 275T,
 v5e 197T, v5p 459T, v6e "Trillium" 918T bf16).
 
-``DSTPU_PEAK_FLOPS`` overrides the lookup (useful on CPU smoke runs or
-unlisted hardware).  The CPU entry is a nominal 1 TFLOP/s so host runs
-still report a non-zero, clearly-not-a-chip number.
+``DSTPU_PEAK_FLOPS`` overrides the lookup.  The CPU entry is a nominal
+1 TFLOP/s so the test tier's gauges stay non-zero and clearly-not-a-chip.
+A device kind that is not in the table is an error, never a default: an
+assumed peak makes every utilization number derived from it fiction.
 """
 
 from __future__ import annotations
@@ -41,7 +42,9 @@ def peak_flops_for_kind(device_kind: str) -> float:
     for name, peak in PEAK_BF16_FLOPS.items():
         if name.lower() in kind:
             return peak
-    return PEAK_BF16_FLOPS["cpu"]
+    raise ValueError(
+        f"no peak-FLOPs entry for device kind {device_kind!r}; add its row "
+        f"to PEAK_BF16_FLOPS (known: {sorted(PEAK_BF16_FLOPS)})")
 
 
 def peak_flops_for_device(device=None) -> float:

@@ -1,11 +1,11 @@
 """Stall watchdog.
 
 Flags training/serving steps whose wall time exceeds a multiple of the
-rolling median — the cheap host-side tripwire for wedged collectives,
+rolling median — the cheap host-side tripwire for hung collectives,
 background-thread convoys, host-offload hiccups, or a preemption storm.
 A stall increments ``deepspeed_tpu_stalled_steps_total``, records the
 overrun ratio, and logs once per incident (not once per slow step in a
-sustained stall — a wedged chip would otherwise flood the log).
+sustained stall — a stalled chip would otherwise flood the log).
 """
 
 from __future__ import annotations

@@ -1,14 +1,6 @@
-"""Version compatibility shims over moved/renamed jax APIs.
-
-One place to absorb jax API churn instead of try/except at every call
-site.  Currently: ``shard_map``, which graduated from
-``jax.experimental.shard_map.shard_map(f, mesh, in_specs, out_specs,
-check_rep=..., auto=...)`` to ``jax.shard_map(f, mesh=..., in_specs=...,
-out_specs=..., check_vma=..., axis_names=...)``.  Callers use the NEW
-spelling; on older jax the kwargs are translated (``check_vma`` ->
-``check_rep``; ``axis_names`` — the axes handled manually — becomes its
-complement ``auto``).
-"""
+"""``shard_map`` under the positional ``(f, mesh, in_specs, out_specs)``
+call shape the package uses, over ``jax.shard_map`` (the one installed JAX:
+see pyproject.toml's pin)."""
 
 from __future__ import annotations
 
@@ -17,19 +9,9 @@ import jax
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True,
               axis_names=None):
-    """``jax.shard_map`` with old-jax fallback (new-API kwargs)."""
-    try:
-        sm = jax.shard_map
-    except AttributeError:
-        sm = None
-    if sm is not None:
-        kw = {"check_vma": check_vma}
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as legacy
-
-    kw = {"check_rep": check_vma}
+    """``jax.shard_map``; ``axis_names`` = the axes handled manually."""
+    kw = {"check_vma": check_vma}
     if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return legacy(f, mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+        kw["axis_names"] = axis_names
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)

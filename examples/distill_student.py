@@ -17,12 +17,6 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
-
-if os.environ.get("JAX_PLATFORMS"):
-    # a site plugin may have pinned another platform via jax.config; the
-    # env var alone does not override it
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 import jax.numpy as jnp
 import numpy as np
 

@@ -206,3 +206,34 @@ def test_flash_alibi_matches_xla_bias_fwd_bwd():
                           _repeat_kv(v, NH // KVH), True)
     np.testing.assert_allclose(np.asarray(o_plain), np.asarray(o_xla),
                                atol=2e-5, rtol=2e-4)
+
+
+def test_flash_on_mesh_matches_xla(devices8):
+    """The mesh wrap (Mosaic kernels cannot be GSPMD-partitioned, so the
+    kernel runs under shard_map: batch over the batch axes, heads over the
+    model axis): same values as plain attention, from a jit over 4 devices
+    and from inside an enclosing shard_map that already made the data axis
+    manual (the ZeRO overlap wrap / pipe stage case)."""
+    from jax.sharding import PartitionSpec as P
+
+    from deepspeed_tpu.models.transformer import _repeat_kv, flash_on_mesh
+    from deepspeed_tpu.parallel.mesh import MeshConfig, initialize_topology
+    from deepspeed_tpu.utils.jax_compat import shard_map
+
+    topo = initialize_topology(MeshConfig(data=2, model=2), devices8[:4])
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(ks[0], (4, 64, 4, 16), jnp.float32)
+    k = jax.random.normal(ks[1], (4, 64, 2, 16), jnp.float32)
+    v = jax.random.normal(ks[2], (4, 64, 2, 16), jnp.float32)
+    ref = xla_attention(q, _repeat_kv(k, 2), _repeat_kv(v, 2), True)
+    with topo.mesh:
+        out = jax.jit(lambda q, k, v: flash_on_mesh(q, k, v, True))(q, k, v)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5, rtol=1e-4)
+        spec = P("data", None, None, None)
+        nested = jax.jit(shard_map(
+            lambda q, k, v: flash_on_mesh(q, k, v, True), topo.mesh,
+            in_specs=(spec, spec, spec), out_specs=spec, check_vma=False,
+            axis_names={"data"}))(q, k, v)
+        np.testing.assert_allclose(np.asarray(nested), np.asarray(ref),
+                                   atol=2e-5, rtol=1e-4)

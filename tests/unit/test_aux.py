@@ -355,6 +355,33 @@ def test_autotuner_tune_parallel_picks_best(devices8):
         make("model").tune_parallel(runner)
 
 
+def test_tune_parallel_refuses_local_subprocess_trials_when_holding_tpu(
+        monkeypatch, tmp_path):
+    """A parent that has touched the TPU holds the chip; a local trial
+    subprocess that needs it would fail or hang — refused up front, and a
+    parent that has not touched it skips HBM pruning to stay off JAX."""
+    from deepspeed_tpu.autotuning.autotuner import Autotuner
+    from deepspeed_tpu.autotuning.scheduler import (Node,
+                                                    SubprocessTrialRunner)
+    from deepspeed_tpu.utils import platform as plat
+    from tests.unit.simple_model import simple_mlp_spec
+
+    tuner = Autotuner(model_factory=simple_mlp_spec, base_config={},
+                      batch_factory=lambda bs: None,
+                      tuning_space={"micro_batch": [1, 2]}, mode="grid")
+    runner = SubprocessTrialRunner(str(tmp_path / "trial.py"),
+                                   results_dir=str(tmp_path / "res"))
+    monkeypatch.setattr(plat, "holds_tpu", lambda: True)
+    with pytest.raises(RuntimeError, match="holds the chip"):
+        tuner.tune_parallel(runner)
+    # remote-only trials do not need this host's chips
+    monkeypatch.setattr(tuner, "_pruned_pool", lambda: pytest.fail(
+        "pruning would touch jax.devices() in the parent"))
+    monkeypatch.setattr(SubprocessTrialRunner, "__call__",
+                        lambda self, exp, res: 1.0)
+    assert tuner.tune_parallel(runner, nodes=[Node("far", 1)])["best"]
+
+
 def test_subprocess_trial_runner(tmp_path):
     """Real out-of-process trial: config handed via JSON file, metrics read
     from the last JSON stdout line (reference user_script contract)."""

@@ -288,88 +288,57 @@ def _load_bench():
     return mod
 
 
-class _Proc:
-    def __init__(self, rc=0, out="", err=""):
-        self.returncode, self.stdout, self.stderr = rc, out, err
-
-
-def test_bench_parent_ladder_classification(monkeypatch):
-    """The hang-proof ladder: OOM steps down the bs ladder, a hang kills
-    the child and re-probes, a wedged lease goes straight to the CPU
-    fallback, and Pallas lowering failures enter the XLA phase."""
-    import subprocess as sp
-    bench = _load_bench()
-    monkeypatch.setenv("DSTPU_BENCH_RUNG_TIMEOUT", "7")
-    calls = []
-
-    def run_script(script):
-        def fake_run(cmd, **kw):
-            if "--cpu" in cmd:
-                calls.append(("cpu", kw["env"].get(
-                    "DSTPU_BENCH_FALLBACK_REASON", "")))
-                return _Proc(rc=0)
-            ev = kw["env"]
-            calls.append((ev["DSTPU_BENCH_ATTN"], ev["DSTPU_BENCH_BS"]))
-            act = script.pop(0)
-            if act == "hang":
-                raise sp.TimeoutExpired(cmd, kw["timeout"])
-            if act == "oom":  # real child contract: marker on stdout
-                return _Proc(rc=1, out='{"child_error": "JaxRuntimeError: RESOURCE_EXHAUSTED: out of memory"}\n')
-            if act == "mosaic":
-                return _Proc(rc=1, out='{"child_error": "MosaicError: Mosaic lowering failed: op xyz"}\n')
-            if act == "sigkill":  # no marker: stderr tail is the fallback
-                return _Proc(rc=-9, err="Killed")
-            return _Proc(rc=0, out='{"value": 1}\n')
-
-        monkeypatch.setattr(bench.subprocess, "run", fake_run)
-        calls.clear()
-        return bench._parent_ladder()
-
-    # OOM at 32 and 16, success at 8 — stays in the flash phase
-    assert run_script(["oom", "oom", "ok"]) == 0
-    assert calls == [("flash", "32"), ("flash", "16"), ("flash", "8")]
-
-    # hang at 32, probe says lease ok -> next rung succeeds
-    monkeypatch.setattr(bench, "_backend_usable",
-                        lambda: (True, "", "TPU v0"))
-    assert run_script(["hang", "ok"]) == 0
-    assert calls == [("flash", "32"), ("flash", "16")]
-
-    # hang at 32, kill wedged the lease -> one CPU fallback, reason recorded
-    monkeypatch.setattr(bench, "_backend_usable", lambda: (False, "dead", ""))
-    assert run_script(["hang"]) == 0
-    assert calls[-1][0] == "cpu" and "wedged" in calls[-1][1]
-    assert len(calls) == 2
-
-    # mosaic failure -> xla phase with the bs ladder capped at 8
-    assert run_script(["mosaic", "ok"]) == 0
-    assert calls == [("flash", "32"), ("xla", "8")]
-
-    # OOM all the way down -> CPU fallback, no pointless xla phase
-    assert run_script(["oom", "oom", "oom"]) == 0
-    assert calls[-1][0] == "cpu" and "smallest rung" in calls[-1][1]
-    assert len(calls) == 4
-
-
-def test_bench_child_error_marker_contract():
-    """A failing --child exits nonzero with a machine-readable marker as
-    its last stdout line — what the parent ladder classifies on."""
-    import json as _json
+def test_bench_exits_nonzero_without_chip():
+    """No accelerator is a failure, not a fallback: a bare `python bench.py`
+    on the CPU platform exits non-zero, names the platform it found, and
+    prints no JSON line."""
     import subprocess as sp
 
     repo = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
-    env = dict(os.environ, DSTPU_BENCH_MODEL="not-a-family",
-               JAX_PLATFORMS="cpu", DSTPU_BENCH_BS="1",
-               DSTPU_BENCH_SIZE="tiny", DSTPU_BENCH_SEQ="16",
-               DSTPU_BENCH_STEPS="1", DSTPU_BENCH_ATTN="xla")
-    proc = sp.run([sys.executable, os.path.join(repo, "bench.py"),
-                   "--cpu", "--child"], capture_output=True, text=True,
-                  env=env, timeout=240)
+    env = {k: v for k, v in os.environ.items()
+           if not k.startswith("DSTPU_BENCH_")}
+    env["JAX_PLATFORMS"] = "cpu"
+    proc = sp.run([sys.executable, os.path.join(repo, "bench.py")],
+                  capture_output=True, text=True, env=env, timeout=240)
     assert proc.returncode != 0
-    marker = _json.loads(proc.stdout.strip().splitlines()[-1])
-    assert "ValueError" in marker["child_error"]
-    assert "not-a-family" in marker["child_error"]
+    assert "platform is 'cpu'" in proc.stderr
+    assert not proc.stdout.strip()
+
+
+def test_bench_kernel_lowering_failure_is_fatal(monkeypatch):
+    """A Pallas/Mosaic lowering failure propagates (non-zero exit) — there
+    is no retry with attn_impl=xla; only an OOM steps down the bs ladder."""
+    bench = _load_bench()
+    monkeypatch.delenv("DSTPU_BENCH_ATTN", raising=False)
+    calls = []
+
+    def lowering_fails(size, seq, bs, steps, attn_impl=None):
+        calls.append((bs, attn_impl))
+        raise RuntimeError("MosaicError: Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(bench, "_run", lowering_fails)
+    with pytest.raises(RuntimeError, match="Mosaic"):
+        bench.main(allow_cpu=True)
+    assert calls == [(2, None)]  # one attempt, default kernels, no xla phase
+
+    calls.clear()
+    monkeypatch.setenv("DSTPU_BENCH_BS", "")
+    script = ["oom", "ok"]
+
+    def oom_then_ok(size, seq, bs, steps, attn_impl=None):
+        calls.append(bs)
+        if script.pop(0) == "oom":
+            raise RuntimeError("RESOURCE_EXHAUSTED: out of memory")
+        return {"value": 1}
+
+    monkeypatch.setattr(bench, "_run", oom_then_ok)
+    # the real one deletes every live device array in this pytest process
+    monkeypatch.setattr(bench, "_release_device_memory", lambda: None)
+    monkeypatch.setattr(bench, "_device_or_exit", lambda allow_cpu: type(
+        "Dev", (), {"platform": "tpu"})())
+    assert bench.main() == 0
+    assert calls == [32, 16]
 
 
 def test_layer_reduction_student_init():

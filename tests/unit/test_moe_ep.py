@@ -5,7 +5,7 @@ Reference behavior being pinned: expert compute runs behind an all-to-all
 inside the expert-parallel group (deepspeed/moe/sharded_moe.py:96
 ``_AllToAll``) so expert-weight grads are BORN expert-sharded — the SPMD
 formulation instead hits XLA's "involuntary full rematerialization" on
-the expert-weight grad scatter under EP + ZeRO-2/3 (docs/PERF_NOTES.md).
+the expert-weight grad scatter under EP + ZeRO-2/3.
 """
 
 import dataclasses
@@ -33,13 +33,19 @@ def _inputs(seed=0):
     return x, gate_w, experts
 
 
+def _moe(cfg, **kw):
+    """moe_ffn as ONE compiled program: called eagerly, the dispatch is
+    hundreds of separately-compiled ops (45 s a test on the CPU tier)."""
+    return jax.jit(lambda x, g, e: moe_ffn(x, g, e, cfg, **kw))
+
+
 def _spmd_then_ep(cfg, devices, mesh_cfg=None):
     x, gate_w, experts = _inputs()
     reset_topology()
-    out_s, aux_s = moe_ffn(x, gate_w, experts,
-                           dataclasses.replace(cfg, ep_dispatch="spmd"))
+    out_s, aux_s = _moe(dataclasses.replace(cfg, ep_dispatch="spmd"))(
+        x, gate_w, experts)
     initialize_topology(mesh_cfg or MeshConfig(expert=2, data=2), devices[:4])
-    out_e, aux_e = moe_ffn(x, gate_w, experts, cfg)
+    out_e, aux_e = _moe(cfg)(x, gate_w, experts)
     return out_s, aux_s, out_e, aux_e
 
 
@@ -71,11 +77,10 @@ def test_ep_gelu_no_wgate(devices8):
     experts = {k: experts[k] for k in ("w_up", "w_down")}
     cfg = MoEConfig(num_experts=E, top_k=1, drop_tokens=False)
     reset_topology()
-    out_s, _ = moe_ffn(x, gate_w, experts,
-                       dataclasses.replace(cfg, ep_dispatch="spmd"),
-                       activation="gelu")
+    out_s, _ = _moe(dataclasses.replace(cfg, ep_dispatch="spmd"),
+                    activation="gelu")(x, gate_w, experts)
     initialize_topology(MeshConfig(expert=2, data=2), devices8[:4])
-    out_e, _ = moe_ffn(x, gate_w, experts, cfg, activation="gelu")
+    out_e, _ = _moe(cfg, activation="gelu")(x, gate_w, experts)
     np.testing.assert_allclose(np.asarray(out_e), np.asarray(out_s),
                                rtol=1e-5, atol=1e-5)
 

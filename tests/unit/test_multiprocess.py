@@ -196,7 +196,7 @@ def test_launcher_cli_end_to_end(tmp_path):
     # the launcher passes the environment through for all-local jobs:
     # strip the pytest harness's 8-virtual-device XLA_FLAGS and stale
     # contract vars so each worker sees 1 local device.  Run the CLI in a
-    # subprocess session so a hung worker can't wedge pytest.
+    # subprocess session so a hung worker can't hang pytest.
     env = {k: v for k, v in os.environ.items()
            if not (k.startswith("DSTPU_") or k == "XLA_FLAGS")}
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")

@@ -88,44 +88,6 @@ def test_coalesce_split_roundtrip():
     assert leaf_bytes(jnp.zeros((3, 4), jnp.bfloat16)) == 24
 
 
-# ---------------------------------------------------------- flag helpers
-def test_latency_hiding_flag_helpers():
-    from deepspeed_tpu.compile.backend import (LATENCY_HIDING_FLAGS,
-                                               latency_hiding_flag_status,
-                                               parse_xla_flags,
-                                               pin_latency_hiding_flags)
-
-    env = {"XLA_FLAGS": "--xla_force_host_platform_device_count=8"}
-    st = latency_hiding_flag_status(env)
-    assert all(v == "missing" for v in st.values())
-    added = pin_latency_hiding_flags(env)
-    assert len(added) == len(LATENCY_HIDING_FLAGS)
-    assert all(v == "pinned"
-               for v in latency_hiding_flag_status(env).values())
-    # idempotent; explicit operator overrides are reported, never clobbered
-    assert pin_latency_hiding_flags(env) == []
-    flag = next(iter(LATENCY_HIDING_FLAGS))
-    env2 = {"XLA_FLAGS": f"{flag}=false"}
-    assert latency_hiding_flag_status(env2)[flag] == "overridden=false"
-    pin_latency_hiding_flags(env2)
-    assert parse_xla_flags(env2["XLA_FLAGS"])[flag] == "false"
-
-
-def test_bench_flag_copy_in_sync():
-    """bench.py's parent deliberately never imports the package, so it
-    carries a copy of the flag set — this pin keeps the copies equal."""
-    import importlib.util
-    import os
-
-    from deepspeed_tpu.compile.backend import LATENCY_HIDING_FLAGS
-
-    root = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    src = open(os.path.join(root, "bench.py")).read()
-    for flag, val in LATENCY_HIDING_FLAGS.items():
-        assert f'"{flag}"' in src, f"bench.py lost pinned flag {flag}"
-
-
 # ------------------------------------------------------------- plan build
 def test_overlap_plan_build_and_struct(devices8):
     e = _engine({"stage": 1, "overlap_grad_reduce": True})

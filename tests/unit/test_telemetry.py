@@ -271,7 +271,10 @@ def test_stall_watchdog_flags_outlier():
 def test_mfu_helpers(monkeypatch):
     assert peak_flops_for_kind("TPU v4") == 275e12
     assert peak_flops_for_kind("TPU v5e") == 197e12
-    assert peak_flops_for_kind("whatever") == 1e12  # cpu fallback
+    assert peak_flops_for_kind("TPU v5 lite") == 197e12  # what a v5e reports
+    assert peak_flops_for_kind("cpu") == 1e12  # nominal, test tier only
+    with pytest.raises(ValueError, match="made-up"):
+        peak_flops_for_kind("made-up")  # an assumed peak is an error
     monkeypatch.setenv("DSTPU_PEAK_FLOPS", "2e12")
     assert peak_flops_for_kind("TPU v4") == 2e12
     monkeypatch.delenv("DSTPU_PEAK_FLOPS")

@@ -241,7 +241,7 @@ def test_capture_exception_propagates_without_torn_record():
             raise Boom("step died mid-capture")
     # the failed capture never publishes a half-built record
     assert tl.last_record() == before
-    # and the timeline is reusable afterwards (not wedged "active")
+    # and the timeline is reusable afterwards (not stuck "active")
     assert tl.should_capture(0) is False
     tl.force_next()
     assert tl.should_capture(0) is True

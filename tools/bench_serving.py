@@ -63,7 +63,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np
 
-from bench import _backend_usable, _int_env as _int, _pin_cpu
+from bench import _device_or_exit, _int_env as _int, _pin_cpu
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -297,9 +297,6 @@ def main() -> None:
     result.update(_observability_sections(
         tl_rec, gp, warm_off + warm_on, dt_off + dt_on, measured_steps=2))
     result.update(_reqtrace_annex(model, params, page))
-    reason = os.environ.get("DSTPU_BENCH_FALLBACK_REASON", "")
-    if reason and jax.default_backend() == "cpu":
-        result["fallback_reason"] = reason
     print(json.dumps(_stamp_contract_hash(result)))
     # hard identity gate on CPU only: XLA-CPU is deterministic across the
     # two paths, while kernel backends may flip a near-tie greedy pick at
@@ -454,9 +451,6 @@ def main_speculative() -> None:
     result.update(_observability_sections(
         tl_rec, gp, warm_off + warm_on,
         (dt_off + dt_on) * repeats, measured_steps=2 * repeats))
-    reason = os.environ.get("DSTPU_BENCH_FALLBACK_REASON", "")
-    if reason and jax.default_backend() == "cpu":
-        result["fallback_reason"] = reason
     print(json.dumps(_stamp_contract_hash(result)))
     # lossless contract: greedy speculative decoding must be
     # bit-identical to the baseline — hard gate on CPU (XLA-CPU is
@@ -600,9 +594,6 @@ def main_multistep() -> None:
     result.update(_observability_sections(
         tl_rec, gp, warm_off + warm_on,
         (dt_off + dt_on) * repeats, measured_steps=2 * repeats))
-    reason = os.environ.get("DSTPU_BENCH_FALLBACK_REASON", "")
-    if reason and jax.default_backend() == "cpu":
-        result["fallback_reason"] = reason
     print(json.dumps(_stamp_contract_hash(result)))
     # hard gates on the deterministic CPU tier: bit-identity (the fused
     # scan's headline contract), the >= 3x host-sync bar at K=8, and
@@ -808,9 +799,6 @@ def main_kv_tier() -> None:
         tl_rec, gp, warm_off + warm_on,
         (dt_off + dt_on) * repeats,
         measured_steps=2 * repeats * (rounds - 1)))
-    reason = os.environ.get("DSTPU_BENCH_FALLBACK_REASON", "")
-    if reason and jax.default_backend() == "cpu":
-        result["fallback_reason"] = reason
     print(json.dumps(_stamp_contract_hash(result)))
     # hard gates on the deterministic CPU tier: bit-identity, the
     # >= 1.5x acceptance bar, and zero steady-state recompiles — the
@@ -828,18 +816,11 @@ def main_kv_tier() -> None:
 
 
 if __name__ == "__main__":
-    # same wedged-chip discipline as bench.py: probe the backend in a
-    # subprocess (a hung TPU lease hangs backend init uninterruptibly
-    # in-process) and fall back to a self-describing CPU run
+    # in-process on the platform JAX selects; a CPU nobody asked for is
+    # a non-zero exit, not a fallback (bench._device_or_exit)
     if "--cpu" in sys.argv:
         _pin_cpu()
-    else:
-        usable, reason, _backend = _backend_usable()
-        if not usable:
-            os.environ["DSTPU_BENCH_FALLBACK_REASON"] = reason
-            _pin_cpu()
-        elif _backend == "cpu":
-            _pin_cpu()
+    _device_or_exit(allow_cpu="--cpu" in sys.argv)
     if "--ab-speculative" in sys.argv:
         main_speculative()
     elif "--ab-kv-tier" in sys.argv:

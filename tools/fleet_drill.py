@@ -251,7 +251,7 @@ def _build(n_requests: int, new_tokens: int, seed: int = 7):
         local = EngineReplica("local0", Eng(model, base, params=params))
         fl = FleetRouter([local], mp_serving)
         spec = {"model": "tiny", "max_seq_len": 128, "seed": 0,
-                "engine_config": base}
+                "engine_config": base, "platform": "cpu"}
         return fl, spec
 
     def build_nvme_fleet(nvme_dir):

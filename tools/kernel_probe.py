@@ -1,0 +1,171 @@
+#!/usr/bin/env python3
+"""Compile every Pallas kernel once at a realistic shape and say what happened.
+
+    python tools/kernel_probe.py              # on the chip: compile, run, compare
+    python tools/kernel_probe.py --aot        # no chip: compile only, for v5e
+
+``--aot`` compiles against a ``v5e:2x2`` topology description through libtpu,
+which works on a machine with no TPU: Mosaic refusals, illegal block shapes and
+VMEM overflows surface exactly as on the chip, so kernel bring-up costs no chip
+time.  It says nothing about numerics — only a run on the chip does.
+
+One line per kernel: ``compiles`` (and, on the chip, the error against its XLA
+reference) or ``refused`` with the compiler's message.  Exit code 1 if any
+kernel was refused or disagreed.  ``sparse_attention`` and ``evoformer_attn``
+are not probed: nothing calls them (ROADMAP D8).
+"""
+
+from __future__ import annotations
+
+import argparse
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+
+def cases():
+    """(name, fn, arg specs [(shape, dtype)], reference fn or None, tol)."""
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+    from deepspeed_tpu.ops.pallas.fused_adam import fused_adam_update
+    from deepspeed_tpu.ops.pallas.grouped_matmul import grouped_matmul
+    from deepspeed_tpu.ops.pallas.quantization import (dequantize_int8,
+                                                       quantize_int8)
+    from deepspeed_tpu.ops.pallas.wq_matmul import (dequantize_weight,
+                                                    wq_matmul)
+
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    out = []
+
+    # flash fwd+bwd at the smoke's shape, then at 8k (VMEM must not scale
+    # with the sequence)
+    def flash_grads(q, k, v):
+        return jax.grad(lambda *a: flash_attention(*a, causal=True)
+                        .astype(f32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    for s in (4096, 8192):
+        out.append((f"flash_attention fwd+bwd seq={s} 32/8 heads D=128",
+                    flash_grads, [((1, s, 32, 128), bf), ((1, s, 8, 128), bf),
+                                  ((1, s, 8, 128), bf)], None, 0))
+
+    # Mixtral-8x7B expert matrices: 4096 x 14336, 8 experts, 4096 rows
+    def gmm(x, w, be):
+        return grouped_matmul(x, w, be % w.shape[0], impl="pallas")
+
+    def gmm_ref(x, w, be):
+        return grouped_matmul(x, w, be % w.shape[0], impl="xla")
+
+    out.append(("grouped_matmul E=8 H=4096 F=14336 rows=4096", gmm,
+                [((4096, 4096), bf), ((8, 4096, 14336), bf), ((32,), i32)],
+                gmm_ref, 2.0 ** -7))
+
+    # Mistral-7B down projection, decode batch of 4
+    for bits in (8, 4):
+        rows = 14336 if bits == 8 else 14336 // 2
+
+        def wq(x, c, s, bits=bits):
+            return wq_matmul(x, c, s, bits=bits, group=128, impl="pallas")
+
+        def wq_ref(x, c, s, bits=bits):
+            w = dequantize_weight(c, s, bits=bits, group=128, k=14336,
+                                  dtype=f32)
+            return (x.astype(f32) @ w).astype(x.dtype)
+
+        out.append((f"wq_matmul int{bits} M=4 K=14336 N=4096", wq,
+                    [((4, 14336), bf),
+                     ((rows, 4096), jnp.int8 if bits == 8 else jnp.uint8),
+                     ((112, 4096), f32)], wq_ref, 2.0 ** -6))
+
+    # one Mistral-7B layer's parameters as a flat fp32 buffer
+    n = 218_112_000
+
+    def adam(p, g, m, v):
+        return fused_adam_update(p, g, m, v, jnp.asarray(3, i32), 1e-3,
+                                 weight_decay=0.01)
+
+    def adam_ref(p, g, m, v):
+        m2 = 0.9 * m + 0.1 * g
+        v2 = 0.999 * v + 0.001 * g * g
+        up = (m2 / (1 - 0.9 ** 3)) / (jnp.sqrt(v2 / (1 - 0.999 ** 3)) + 1e-8)
+        return p - 1e-3 * (up + 0.01 * p), m2, v2
+
+    out.append((f"fused_adam n={n}", adam, [((n,), f32)] * 4, adam_ref, 1e-5))
+
+    def quant_roundtrip(x):
+        q, s, length = quantize_int8(x)
+        return dequantize_int8(q, s, length, x.dtype)
+
+    out.append(("quantization int8 round trip n=64Mi", quant_roundtrip,
+                [((64 * 2 ** 20,), bf)], lambda x: x, 2.0 ** -6))
+    return out
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--aot", action="store_true",
+                    help="compile only, against a v5e:2x2 topology (no chip)")
+    args = ap.parse_args()
+
+    sharding = None
+    if args.aot:
+        from jax.experimental import topologies
+        from jax.sharding import SingleDeviceSharding
+
+        import deepspeed_tpu.utils.platform as plat
+
+        plat.platform = lambda: "tpu"  # compile the kernels, not interpret
+        dev = topologies.get_topology_desc("v5e:2x2", "tpu").devices[0]
+        sharding = SingleDeviceSharding(dev)
+        print(f"kernel_probe: AOT for {dev.device_kind!r}, compile only")
+    else:
+        dev = jax.devices()[0]
+        print(f"kernel_probe: platform={dev.platform} "
+              f"device_kind={dev.device_kind!r}")
+        if dev.platform != "tpu":
+            print("kernel_probe: no chip (use --aot to compile without one)",
+                  file=sys.stderr)
+            return 2
+
+    bad = 0
+    for name, fn, specs, ref, tol in cases():
+        t0 = time.time()
+        abstract = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+                    for s, d in specs]
+        try:
+            compiled = jax.jit(fn).lower(*abstract).compile()
+        # the probe's whole job is to report a compiler refusal per kernel
+        except Exception as e:  # noqa: BLE001
+            bad += 1
+            print(f"refused   {name}: {type(e).__name__}: "
+                  f"{' '.join(str(e).split())[:600]}", flush=True)
+            continue
+        line = f"compiles  {name} ({time.time() - t0:.1f} s)"
+        if not args.aot and ref is not None:
+            keys = jax.random.split(jax.random.PRNGKey(0), len(specs))
+            vals = [jax.random.normal(k, s, jnp.float32).astype(d)
+                    if jnp.issubdtype(d, jnp.floating)
+                    else jax.random.randint(k, s, 0, 127).astype(d)
+                    for k, (s, d) in zip(keys, specs)]
+            if "adam" in name:  # second moments are non-negative
+                vals[3] = jnp.abs(vals[3])
+            got = jax.tree_util.tree_leaves(compiled(*vals))
+            want = jax.tree_util.tree_leaves(jax.jit(ref)(*vals))
+            err = max(float(jnp.max(jnp.abs(g.astype(jnp.float32)
+                                            - w.astype(jnp.float32)))
+                            / jnp.max(jnp.abs(w.astype(jnp.float32))))
+                      for g, w in zip(got, want))
+            ok = err < tol
+            bad += not ok
+            line += (f", rel err vs XLA reference {err:.2e} "
+                     f"{'<' if ok else '>= (DISAGREES)'} {tol:.2e}")
+            del vals, got, want
+        print(line, flush=True)
+    return 1 if bad else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

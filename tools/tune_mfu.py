@@ -3,7 +3,7 @@
 Usage: python tools/tune_mfu.py [variant ...]   (no args = all)
 Prints one line per variant: name, step_ms, tok/s/chip, mfu.
 
-Findings are recorded in docs/PERF_NOTES.md.
+Findings go in PERF.md, with their origin.
 """
 
 from __future__ import annotations
@@ -88,14 +88,12 @@ VARIANTS = {
     "160m-bs32": ("160m", 1024, 32, {}),
     "160m-bs16": ("160m", 1024, 16, {}),
     # bwd-tile decoupling: fwd stays 512/512 (the measured optimum), bwd
-    # kernels sweep their own tiles — targets the 27ms bwd/fwd slack in
-    # docs/PERF_NOTES.md's decomposition
+    # kernels sweep their own tiles (ROADMAP S3)
     "160m-bwd256x256": ("160m", 1024, 16, {"attn_impl": "flash_bwd256x256"}),
     "160m-bwd256x512": ("160m", 1024, 16, {"attn_impl": "flash_bwd256x512"}),
     "160m-bwd512x256": ("160m", 1024, 16, {"attn_impl": "flash_bwd512x256"}),
     "160m-bwd1024x512": ("160m", 1024, 16, {"attn_impl": "flash_bwd1024x512"}),
-    # single-pass Pallas Adam vs the XLA-fused optax chain (~10ms of the
-    # 195ms step is optimizer+clip in PERF_NOTES' decomposition)
+    # single-pass Pallas Adam vs the XLA-fused optax chain (ROADMAP S4)
     "160m-fusedadam": ("160m", 1024, 16, {"fused_opt": True}),
     "1b-bs8-remat": ("1b", 1024, 8, {"remat": True}),
     "1b-bs4": ("1b", 1024, 4, {}),
@@ -123,31 +121,7 @@ VARIANTS = {
 }
 
 
-def _tpu_expected() -> bool:
-    """Whether a TPU backend will initialize in this process — the
-    latency-hiding flags are TPU-only and abort CPU/GPU XLA startup, so
-    pin them only when a TPU plugin is actually present (an unset
-    JAX_PLATFORMS is the common case on CPU boxes and must NOT pin)."""
-    import importlib.util
-
-    plat = os.environ.get("JAX_PLATFORMS", "")
-    if "cpu" in plat:
-        return False
-    if "tpu" in plat:
-        return True
-    return importlib.util.find_spec("libtpu") is not None
-
-
 def main():
-    # pin the latency-hiding scheduler flags BEFORE the backend comes up
-    # (compile/backend.py; the overlap variants are meaningless without
-    # them)
-    if _tpu_expected():
-        from deepspeed_tpu.compile.backend import pin_latency_hiding_flags
-
-        added = pin_latency_hiding_flags()
-        if added:
-            print(f"tune_mfu: pinned XLA flags {added}", flush=True)
     names = sys.argv[1:] or list(VARIANTS)
     # patch the special attn impl variants in via TransformerConfig.attn_impl
     import deepspeed_tpu.models.transformer as T
