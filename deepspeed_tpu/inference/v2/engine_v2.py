@@ -29,13 +29,14 @@ import numpy as np
 from ...models.transformer import TransformerConfig
 from ...runtime.config_utils import ConfigModel
 from ...telemetry import get_registry
-from ...telemetry.compile_sentinel import RecompileSentinel
+from ...telemetry.compile_sentinel import RecompileSentinel, compile_counts
 from ...telemetry.compile_sentinel import \
     expect_recompile as sentinel_expect_recompile
-from ...telemetry.flight import dump_on_exception
+from ...telemetry.flight import dump_on_exception, get_flight_recorder
 from ...telemetry.reqtrace import get_reqtrace_ledger, slo_exemplar
 from ...telemetry.spans import begin_span, end_span, record_event, span
 from ...telemetry.tracing import PhaseTimer
+from ...telemetry.watchdog import StallWatchdog
 from ...utils.logging import logger
 from ...utils.platform import ensure_compile_cache
 from .model_runner import (pad_pages_pow2, paged_copy_page, paged_decode,
@@ -183,6 +184,11 @@ class RaggedRequest:
     #: lifecycle trace event, and the KV-migration wire so one request
     #: is ONE connected trace across replicas
     trace_id: Optional[str] = None
+
+
+#: what a ``serve_step`` span carries at its end
+_STEP_COUNTS = ("chunks", "prefill_tokens", "decode_rows", "admitted",
+                "preempted", "queue_len")
 
 
 def _horizon_pages_needed(length: int, budget: int, page_size: int) -> int:
@@ -433,6 +439,19 @@ class InferenceEngineV2:
         # components — a compile during a step that introduced no new
         # component after warmup is a steady-state recompilation
         self._step_parts: set = set()
+        #: ``step()`` calls so far: the ``step`` every span and event of a
+        #: step carries (``_decode_steps`` counts decode dispatches only)
+        self._step_id = 0
+        #: this step's seconds by span name, and the part of each that a
+        #: child span (``device_wait``) covers: span less child = self time
+        self._phase_s: Dict[str, float] = {}
+        self._child_s: Dict[str, float] = {}
+        self._step_counts: Dict[str, int] = dict.fromkeys(_STEP_COUNTS, 0)
+        #: rates decode-only steps (a decode pull, no prefill chunk), whose
+        #: ``serve_step`` is one decode program and the host round it; a
+        #: step that carries chunks is several programs long and no stall,
+        #: one that only dispatches returns in milliseconds
+        self._watchdog = StallWatchdog(name="serve", on_stall=self._on_stall)
         self._sentinel = (RecompileSentinel(
             loop="serve", steady_after=self.config.sentinel_steady_after)
             if self.config.recompile_sentinel else None)
@@ -507,11 +526,24 @@ class InferenceEngineV2:
             "occupied decode slots / max_seqs")
         self._m_prefill_h = reg.histogram(
             "deepspeed_tpu_serving_prefill_seconds",
-            "per-sequence prefill program wall time (one chunk or whole "
-            "prompt, incl. the prefix-end sample)")
+            "host wall time of one prefill call (a chunk or a whole "
+            "prompt): input building (its jnp.int32 scalars are tiny device "
+            "programs that wait behind the programs in flight), uploads and "
+            "an asynchronous dispatch; a prompt's last call also waits for "
+            "the device and samples the first token")
         self._m_decode_h = reg.histogram(
             "deepspeed_tpu_serving_decode_seconds",
-            "one batched decode step wall time (dispatch + token fetch)")
+            "host wall time of one decode dispatch: input upload, "
+            "dispatch, restore-prefetch and the wait for the tokens (the "
+            "whole horizon when decode_horizon > 1)")
+        self._m_step_phase_h = reg.histogram(
+            "deepspeed_tpu_serving_step_phase_seconds",
+            "host wall time per phase of one engine step, summed over the "
+            "step's spans of that name: serve_step (the whole step), "
+            "step_admit, prefill, decode / multi_decode / spec_verify, "
+            "dispatch and device_wait (inside prefill and decode), "
+            "step_emit",
+            labelnames=("phase",))
         self._m_requests = reg.counter(
             "deepspeed_tpu_serving_requests_total", "requests enqueued")
         self._m_gen_tokens = reg.counter(
@@ -638,7 +670,58 @@ class InferenceEngineV2:
         """Profiler annotation + wall-time histogram + trace-ring span
         for one serving phase (prefill/decode); ``attrs`` land on the
         span only."""
-        return PhaseTimer(name, sink=lambda _n, dt: hist.observe(dt), **attrs)
+        def sink(_n, dt):
+            hist.observe(dt)
+            self._phase_s[name] = self._phase_s.get(name, 0.0) + dt
+
+        return PhaseTimer(name, sink=sink, step=self._step_id, **attrs)
+
+    def _step_span(self, name: str, parent: str = "", **attrs) -> PhaseTimer:
+        """One span (cat ``serve``) of the current step: ring + profiler
+        annotation, its seconds folded into the step's totals whether or
+        not the ring is on.  ``parent`` names the phase this span sits
+        inside, whose self time is its own less this."""
+        def sink(_n, dt):
+            self._phase_s[name] = self._phase_s.get(name, 0.0) + dt
+            if parent:
+                self._child_s[parent] = self._child_s.get(parent, 0.0) + dt
+
+        return PhaseTimer(name, sink=sink, cat="serve", step=self._step_id,
+                          **attrs)
+
+    def _on_stall(self, _loop: str, step, ratio: float) -> None:
+        """Watchdog incident edge: the decomposition of the stalled step
+        from the spans just recorded, largest self time first.  A stall in
+        ``device_wait`` is the device's or the runtime's.  Only decode-only
+        steps are rated, and in those nothing is in flight when the inputs
+        are uploaded and the program is called, so a stall anywhere else is
+        the host's.  (With programs in flight a phase's self time is not
+        all host work: a chunk call's ``jnp.int32`` scalars each run a tiny
+        device program that waits its turn behind them, most of ``prefill``
+        self in a chunked-prefill step, which is one reason such steps are
+        not rated.)"""
+        ph, counts = self._phase_s, self._step_counts
+        total = ph["serve_step"]
+        self_ms = {(n + " self" if n in self._child_s else n):
+                   1e3 * (v - self._child_s.get(n, 0.0))
+                   for n, v in ph.items() if n != "serve_step"}
+        self_ms["other"] = 1e3 * total - sum(self_ms.values())
+        order = sorted(self_ms, key=self_ms.get, reverse=True)
+        median_ms = 1e3 * total / ratio
+        logger.warning(
+            f"serve step {step}: {1e3 * total:.0f} ms (median "
+            f"{median_ms:.0f}): "
+            + ", ".join(f"{n} {self_ms[n]:.0f}" for n in order)
+            + f"; {counts['chunks']} chunks, {counts['decode_rows']} rows")
+        fields = dict(step=step, phase=order[0].replace(" self", ""),
+                      ms=1e3 * total, median_ms=median_ms,
+                      chunks=counts["chunks"], rows=counts["decode_rows"],
+                      **{n.replace(" ", "_") + "_ms": v
+                         for n, v in self_ms.items()})
+        record_event("serve_stall", cat="serve", **fields)
+        flight = get_flight_recorder()
+        if flight is not None:
+            flight.note("serve_stall", **fields)
 
     # -- request lifecycle bookkeeping ---------------------------------------
     def _reqtrace(self, seq: SequenceState):
@@ -1302,6 +1385,7 @@ class InferenceEngineV2:
         seq.queued_at = time.perf_counter()
         self._queue.insert(0, seq)
         self._m_preemptions.inc()
+        self._step_counts["preempted"] += 1
         tr = self._reqtrace(seq)
         if tr is not None:
             # back to queue_wait; the re-run prefill chunks will ledger
@@ -1309,7 +1393,7 @@ class InferenceEngineV2:
             tr.note_preempt(getattr(self, "trace_owner", "engine"),
                             seq.queued_at)
         occ = self._pool_occupancy()
-        record_event("preempt", cat="serve", uid=seq.uid,
+        record_event("preempt", cat="serve", step=self._step_id, uid=seq.uid,
                      prefix_tokens=seq.length,
                      **({} if seq.trace_id is None
                         else {"trace_id": seq.trace_id}), **occ)
@@ -1445,7 +1529,8 @@ class InferenceEngineV2:
                 # recompute after a preemption or re-dispatch
                 tr.transition("prefill",
                               getattr(self, "trace_owner", "engine"))
-            record_event("admit", cat="serve", uid=seq.uid, slot=i,
+            record_event("admit", cat="serve", step=self._step_id,
+                         uid=seq.uid, slot=i,
                          cache_hit_pages=m, new_pages=len(fresh),
                          full_hit=full_hit,
                          **({} if seq.trace_id is None
@@ -1476,9 +1561,12 @@ class InferenceEngineV2:
     def _emit_sampled(self, seq: SequenceState, logits, out) -> None:
         """Sample off prefix-end logits, append, record, maybe retire —
         shared by the whole-prompt and final-chunk prefill paths."""
-        # dstpu-lint: allow[host-sync] host sampling of the prefix-end
-        # logits: one [vocab] row per ADMISSION, not per decode step
-        tok = self._sample(seq, np.asarray(logits, np.float32))
+        with self._step_span("device_wait", parent="prefill",
+                             what="first_token", uid=seq.uid):
+            # dstpu-lint: allow[host-sync] host sampling of the prefix-end
+            # logits: one [vocab] row per ADMISSION, not per decode step
+            logits = np.asarray(logits, np.float32)
+        tok = self._sample(seq, logits)
         seq.tokens.append(tok)
         self._note_tokens(seq)
         out[seq.uid] = {"tokens": [tok], "done": False}
@@ -1526,7 +1614,8 @@ class InferenceEngineV2:
         self._m_deadline.inc()
         slo_exemplar("deepspeed_tpu_serving_slo_deadline_exceeded_total",
                      seq.trace_id, uid=seq.uid, generated=seq.generated)
-        record_event("deadline_expired", cat="serve", uid=seq.uid,
+        record_event("deadline_expired", cat="serve", step=self._step_id,
+                     uid=seq.uid,
                      generated=seq.generated, priority=seq.priority,
                      **({} if seq.trace_id is None
                         else {"trace_id": seq.trace_id}))
@@ -1602,10 +1691,11 @@ class InferenceEngineV2:
         prev = self._page_table[seq.slot][:min(
             b, self.block.max_pages_per_seq)]
         self._step_parts.add(("prefill_chunk", C, int(prev.shape[0])))
-        logits, self._pools = self._prefill_chunk(
-            self.params, self._pools, jnp.asarray(ids),
-            jnp.asarray(rows), jnp.asarray(prev),
-            jnp.int32(start), jnp.int32(c_n))
+        args = (jnp.asarray(ids), jnp.asarray(rows), jnp.asarray(prev),
+                jnp.int32(start), jnp.int32(c_n))
+        with self._step_span("dispatch", parent="prefill"):
+            logits, self._pools = self._prefill_chunk(
+                self.params, self._pools, *args)
         seq.prefilled = start + c_n
         self._register_pages(seq)
         return logits
@@ -1626,24 +1716,44 @@ class InferenceEngineV2:
         dispatched (prefill buckets/chunks, decode, page copies)."""
         self._step_parts = set()
         self._prefetched = False
+        self._step_id += 1
+        self._phase_s, self._child_s = {}, {}
+        counts = self._step_counts = dict.fromkeys(_STEP_COUNTS, 0)
+        compiles0 = compile_counts()[0]
+        captured = self._timeline.should_capture(self._decode_steps)
+        t0 = time.perf_counter()
         try:
-            if self._timeline.should_capture(self._decode_steps):
-                # periodic step-time attribution: only this step pays
-                # the profiler start/stop + parse (capture context is
-                # exception-safe; a failed step still propagates)
-                with self._timeline.capture(self._decode_steps):
+            with span("serve_step", cat="serve",
+                      step=self._step_id) as step_attrs:
+                if captured:
+                    # periodic step-time attribution: only this step pays
+                    # the profiler start/stop + parse (capture context is
+                    # exception-safe; a failed step still propagates)
+                    with self._timeline.capture(self._decode_steps):
+                        out = self._step_impl()
+                else:
                     out = self._step_impl()
-            else:
-                out = self._step_impl()
-            # idle / prefill-only steps still restore-prefetch for the
-            # queue head (the decode-overlap call site won if it ran)
-            self._prefetch_restores()
+                # idle / prefill-only steps still restore-prefetch for the
+                # queue head (the decode-overlap call site won if it ran)
+                self._prefetch_restores()
+                counts["queue_len"] = len(self._queue)
+                step_attrs.update(counts)
         except Exception as e:
             dump_on_exception("engine_v2.step", e)
             raise
+        self._phase_s["serve_step"] = time.perf_counter() - t0
         if self._step_parts and self._sentinel is not None:
             self._sentinel.observe_step(frozenset(self._step_parts),
                                         step=self._decode_steps)
+        for name, secs in self._phase_s.items():
+            self._m_step_phase_h.observe(secs, phase=name)
+        if (counts["decode_rows"] and not counts["chunks"] and not captured
+                and compile_counts()[0] == compiles0):
+            # decode-only steps alone are rated (__init__ says why); one
+            # that compiled, or that a timeline capture wrapped, is no
+            # measure of a step: out of the median, never a stall
+            self._watchdog.observe(self._phase_s["serve_step"],
+                                   step=self._step_id)
         return out
 
     def force_timeline_capture(self) -> None:
@@ -1660,15 +1770,18 @@ class InferenceEngineV2:
         out: Dict[int, Dict[str, Any]] = {}
         ps = self.block.page_size
 
-        # step boundary: commit last step's captured evictions to the
-        # host tier (one batched D2H gather) and unpin their pages
-        self._drain_spills()
-        self._expire_deadlines(out)
-        admitted = self._admit()
-        self._m_queue.set(len(self._queue))
-        self._m_occupancy.set(
-            sum(1 for s in self._slots if s is not None)
-            / max(1, self.block.max_seqs))
+        with self._step_span("step_admit"):
+            # step boundary: commit last step's captured evictions to the
+            # host tier (one batched D2H gather) and unpin their pages
+            self._drain_spills()
+            self._expire_deadlines(out)
+            admitted = self._admit()
+            self._m_queue.set(len(self._queue))
+            self._m_occupancy.set(
+                sum(1 for s in self._slots if s is not None)
+                / max(1, self.block.max_seqs))
+        counts = self._step_counts
+        counts["admitted"] = len(admitted)
         if self._chunk:
             # Dynamic-SplitFuse-style chunked prefill: ONE chunk per
             # pending-prefill sequence per step; decode for ready
@@ -1681,6 +1794,8 @@ class InferenceEngineV2:
             for seq in pending:
                 start = seq.prefilled  # page-aligned: chunk % ps == 0
                 c_n = min(self._chunk, seq.length - start)
+                counts["chunks"] += 1
+                counts["prefill_tokens"] += c_n
                 with self._phase("prefill", self._m_prefill_h, uid=seq.uid,
                                  start=start, tokens=c_n):
                     logits = self._run_prefill_chunk(seq, start, c_n,
@@ -1696,6 +1811,8 @@ class InferenceEngineV2:
                     # start-offset program, bucketed like whole prompts
                     # so the shape set stays fixed
                     n_suf = seq.length - seq.prefilled
+                    counts["chunks"] += 1
+                    counts["prefill_tokens"] += n_suf
                     with self._phase("prefill", self._m_prefill_h,
                                      uid=seq.uid, start=seq.prefilled,
                                      tokens=n_suf):
@@ -1714,11 +1831,14 @@ class InferenceEngineV2:
                                np.int32)
                 rows[:len(seq.pages)] = seq.pages
                 self._step_parts.add(("prefill", bucket))
+                counts["chunks"] += 1
+                counts["prefill_tokens"] += n
                 with self._phase("prefill", self._m_prefill_h, uid=seq.uid,
                                  tokens=n, bucket=bucket):
-                    logits, self._pools = self._prefill(
-                        self.params, self._pools,
-                        jnp.asarray(ids), jnp.asarray(rows), jnp.int32(n))
+                    args = (jnp.asarray(ids), jnp.asarray(rows), jnp.int32(n))
+                    with self._step_span("dispatch", parent="prefill"):
+                        logits, self._pools = self._prefill(
+                            self.params, self._pools, *args)
                     seq.prefilled = n
                     self._register_pages(seq)
                     self._emit_sampled(seq, logits, out)
@@ -1800,25 +1920,28 @@ class InferenceEngineV2:
             last, pos, act, temps, sids = self._decode_inputs(decode_seqs)
             self._decode_steps += 1
             self._step_parts.add("decode")
+            counts["decode_rows"] += len(decode_seqs)
             with self._phase("decode", self._m_decode_h,
                              batch=len(decode_seqs)):
-                tokens, self._pools = self._decode(
-                    self.params, self._pools,
-                    jnp.asarray(last), jnp.asarray(pos),
-                    jnp.asarray(self._page_table), jnp.asarray(act),
-                    jnp.asarray(temps), jnp.asarray(sids),
-                    self._sample_key)
+                args = (jnp.asarray(last), jnp.asarray(pos),
+                        jnp.asarray(self._page_table), jnp.asarray(act),
+                        jnp.asarray(temps), jnp.asarray(sids))
+                with self._step_span("dispatch", parent="decode"):
+                    tokens, self._pools = self._decode(
+                        self.params, self._pools, *args, self._sample_key)
                 # restore-prefetch rides the in-flight decode: the host
                 # walks queued prefixes into the host tier while the
                 # device decodes, and the H2D scatter chains behind the
                 # decode program; the token fetch below waits only on
                 # decode's own output
                 self._prefetch_restores()
-                # dstpu-lint: allow[host-sync] THE designed sync of the
-                # K=1 decode path: [B] int32 tokens cross, never
-                # [B,vocab] logits; decode_horizon > 1 amortizes this
-                # to one [B,K] pull per horizon (_multi_decode)
-                tokens = np.asarray(tokens)
+                with self._step_span("device_wait", parent="decode",
+                                     what="decode_tokens"):
+                    # dstpu-lint: allow[host-sync] THE designed sync of the
+                    # K=1 decode path: [B] int32 tokens cross, never
+                    # [B,vocab] logits; decode_horizon > 1 amortizes this
+                    # to one [B,K] pull per horizon (_multi_decode)
+                    tokens = np.asarray(tokens)
             self._m_gen_tokens.inc(len(decode_seqs))
             self._m_invocations.inc()
             self._m_host_syncs.inc()
@@ -1827,24 +1950,30 @@ class InferenceEngineV2:
             self._dstats["decode_host_syncs"] += 1
             self._dstats["decode_tokens"] += len(decode_seqs)
 
-            for seq in decode_seqs:
-                tok = int(tokens[seq.slot])
-                seq.tokens.append(tok)
-                self._note_tokens(seq)
-                # the decode step wrote KV for the token it consumed
-                seq.prefilled = seq.length - 1
-                if self.prefix_cache is not None and seq.prefilled % ps == 0:
-                    # the decode write completed a page: publish it so a
-                    # preempted-then-readmitted (or forked) sequence can
-                    # remap instead of recomputing
-                    self._register_pages(seq)
-                rec = out.setdefault(seq.uid, {"tokens": [], "done": False})
-                rec["tokens"].append(tok)
-                self._maybe_finish(seq, tok)
-                rec["done"] = seq.done
-                if seq.done:
-                    rec["finish_reason"] = seq.finish_reason
-        self._sync_cache_counters()
+            with self._step_span("step_emit"):
+                for seq in decode_seqs:
+                    tok = int(tokens[seq.slot])
+                    seq.tokens.append(tok)
+                    self._note_tokens(seq)
+                    # the decode step wrote KV for the token it consumed
+                    seq.prefilled = seq.length - 1
+                    if (self.prefix_cache is not None
+                            and seq.prefilled % ps == 0):
+                        # the decode write completed a page: publish it so
+                        # a preempted-then-readmitted (or forked) sequence
+                        # can remap instead of recomputing
+                        self._register_pages(seq)
+                    rec = out.setdefault(seq.uid,
+                                         {"tokens": [], "done": False})
+                    rec["tokens"].append(tok)
+                    self._maybe_finish(seq, tok)
+                    rec["done"] = seq.done
+                    if seq.done:
+                        rec["finish_reason"] = seq.finish_reason
+                self._sync_cache_counters()
+            return out
+        with self._step_span("step_emit"):
+            self._sync_cache_counters()
         return out
 
     def _decode_inputs(self, seqs: List[SequenceState]):
@@ -1918,7 +2047,8 @@ class InferenceEngineV2:
         if k < self._horizon:
             self._m_horizon_shrink.inc()
             self._dstats["decode_horizon_shrinks"] += 1
-            record_event("horizon_shrink", cat="serve", horizon=k,
+            record_event("horizon_shrink", cat="serve", step=self._step_id,
+                         horizon=k,
                          configured=self._horizon,
                          **self._pool_occupancy())
 
@@ -1950,24 +2080,28 @@ class InferenceEngineV2:
 
         self._decode_steps += 1
         self._step_parts.add(("multi_decode", k))
+        self._step_counts["decode_rows"] += len(seqs)
         warm = k in self._warm_horizons
         self._warm_horizons.add(k)
         t0 = time.perf_counter()
         with self._phase("multi_decode", self._m_decode_h,
                          batch=len(seqs), horizon=k):
-            toks, produced, self._pools = self._multi(
-                self.params, self._pools,
-                jnp.asarray(last), jnp.asarray(pos),
-                jnp.asarray(self._page_table), jnp.asarray(act),
-                jnp.asarray(temps), jnp.asarray(eos), jnp.asarray(budg),
-                jnp.asarray(sids), self._sample_key, k)
+            args = (jnp.asarray(last), jnp.asarray(pos),
+                    jnp.asarray(self._page_table), jnp.asarray(act),
+                    jnp.asarray(temps), jnp.asarray(eos), jnp.asarray(budg),
+                    jnp.asarray(sids))
+            with self._step_span("dispatch", parent="multi_decode"):
+                toks, produced, self._pools = self._multi(
+                    self.params, self._pools, *args, self._sample_key, k)
             # restore-prefetch rides the in-flight scan, like K=1
             self._prefetch_restores()
-            # dstpu-lint: allow[host-sync] THE designed sync per decode horizon
-            # [B,K] int32 tokens + [B] produced counts cross the link
-            # once per K tokens — the fused form of the per-step decode
-            # sync, amortized K-fold
-            toks, produced = np.asarray(toks), np.asarray(produced)
+            with self._step_span("device_wait", parent="multi_decode",
+                                 what="decode_tokens"):
+                # dstpu-lint: allow[host-sync] THE designed sync per horizon
+                # [B,K] int32 tokens + [B] produced counts cross the link
+                # once per K tokens — the fused form of the per-step
+                # decode sync, amortized K-fold
+                toks, produced = np.asarray(toks), np.asarray(produced)
         t1 = time.perf_counter()
 
         # the scan ALWAYS executes k iterations (finished rows run
@@ -1990,32 +2124,33 @@ class InferenceEngineV2:
         self._dstats["decode_host_syncs"] += 1
         self._dstats["decode_tokens"] += total
 
-        for seq in seqs:
-            n = int(produced[seq.slot])
-            rec = out.setdefault(seq.uid, {"tokens": [], "done": False})
-            reason = ""
-            for j in range(n):
-                tok = int(toks[seq.slot, j])
-                seq.tokens.append(tok)
-                rec["tokens"].append(tok)
-                # token j landed ~(j+1) device steps into the dispatch:
-                # reconstructed per-token emit timestamps, so
-                # TTFT/TPOT and the SLO-violation checks never see a
-                # K-token burst stamped at one instant
-                self._note_tokens(seq, t=t0 + (j + 1) * per_step)
-                reason = self._finish_reason_for(seq, tok)
+        with self._step_span("step_emit"):
+            for seq in seqs:
+                n = int(produced[seq.slot])
+                rec = out.setdefault(seq.uid, {"tokens": [], "done": False})
+                reason = ""
+                for j in range(n):
+                    tok = int(toks[seq.slot, j])
+                    seq.tokens.append(tok)
+                    rec["tokens"].append(tok)
+                    # token j landed ~(j+1) device steps into the dispatch:
+                    # reconstructed per-token emit timestamps, so
+                    # TTFT/TPOT and the SLO-violation checks never see a
+                    # K-token burst stamped at one instant
+                    self._note_tokens(seq, t=t0 + (j + 1) * per_step)
+                    reason = self._finish_reason_for(seq, tok)
+                    if reason:
+                        break  # the scan stopped the row here by contract
+                # the scan wrote KV for every token it consumed; the last
+                # emitted token is the pending one, exactly like K=1
+                seq.prefilled = seq.length - 1
+                self._register_pages(seq)
                 if reason:
-                    break  # the scan stopped the row here by contract
-            # the scan wrote KV for every token it consumed; the last
-            # emitted token is the pending one, exactly like K=1
-            seq.prefilled = seq.length - 1
-            self._register_pages(seq)
-            if reason:
-                seq.finish_reason = reason
-                self._retire(seq)  # frees unused horizon headroom too
-            rec["done"] = seq.done
-            if seq.done:
-                rec["finish_reason"] = seq.finish_reason
+                    seq.finish_reason = reason
+                    self._retire(seq)  # frees unused horizon headroom too
+                rec["done"] = seq.done
+                if seq.done:
+                    rec["finish_reason"] = seq.finish_reason
 
     # -- speculative decoding ------------------------------------------------
     def _spec_step(self, seqs: List[SequenceState],
@@ -2040,7 +2175,7 @@ class InferenceEngineV2:
 
         # -- propose + reserve (host) --
         drafts: Dict[int, List[int]] = {}
-        with span("spec_propose", cat="serve", seqs=len(seqs)):
+        with self._step_span("spec_propose", seqs=len(seqs)):
             for seq in seqs:
                 d = list(self._proposer.propose(seq.tokens, k))[:k]
                 # cap to the model window, the page-table width, and the
@@ -2093,15 +2228,20 @@ class InferenceEngineV2:
             act[seq.slot] = True
             nv[seq.slot] = len(row)
         self._step_parts.add(("verify", W))
+        self._step_counts["decode_rows"] += len(seqs)
         with self._phase("spec_verify", self._m_spec_verify_h,
                          batch=len(seqs), width=W):
-            greedy, self._pools = self._verify(
-                self.params, self._pools, jnp.asarray(ids),
-                jnp.asarray(pos), jnp.asarray(self._page_table),
-                jnp.asarray(act), jnp.asarray(nv))
-            # dstpu-lint: allow[host-sync] one [B,W] int32 pull per verify
-            # round; acceptance is per-row host logic by design
-            greedy = np.asarray(greedy)  # [B, W] argmax per position
+            args = (jnp.asarray(ids), jnp.asarray(pos),
+                    jnp.asarray(self._page_table), jnp.asarray(act),
+                    jnp.asarray(nv))
+            with self._step_span("dispatch", parent="spec_verify"):
+                greedy, self._pools = self._verify(
+                    self.params, self._pools, *args)
+            with self._step_span("device_wait", parent="spec_verify",
+                                 what="decode_tokens"):
+                # dstpu-lint: allow[host-sync] one [B,W] int32 pull per
+                # verify round; acceptance is per-row host logic by design
+                greedy = np.asarray(greedy)  # [B, W] argmax per position
         self._m_invocations.inc()
         self._m_host_syncs.inc()
         self._dstats["decode_model_invocations"] += 1
@@ -2110,49 +2250,50 @@ class InferenceEngineV2:
 
         # -- accept + emit + rollback (host) --
         rollback_pages = 0
-        for seq in seqs:
-            accepted, bonus = longest_accepted(drafts[seq.uid],
-                                               greedy[seq.slot])
-            base_len = seq.length  # L: tokens before this round
-            self._dstats["spec_accepted_tokens"] += len(accepted)
-            self._m_spec_accepted.inc(len(accepted))
-            rec = out.setdefault(seq.uid, {"tokens": [], "done": False})
-            emitted = 0
-            for tok in accepted + [bonus]:
-                seq.tokens.append(tok)
-                emitted += 1
-                rec["tokens"].append(tok)
-                self._note_tokens(seq)
-                if self._should_finish(seq, tok):
-                    break  # drop accepted tokens past a finish boundary
-            self._m_gen_tokens.inc(emitted)
-            self._dstats["decode_tokens"] += emitted
-            self._m_spec_tps.observe(emitted)
-            # KV is valid through the accepted region (the bonus token is
-            # the pending one, exactly like a plain decode step)
-            seq.prefilled = min(seq.length - 1,
-                                base_len + len(accepted))
-            self._register_pages(seq)
-            self._maybe_finish(seq, seq.tokens[-1])
-            rec["done"] = seq.done
-            if seq.done:
-                rec["finish_reason"] = seq.finish_reason
-            if not seq.done:
-                # rollback: pages reserved for rejected draft tokens are
-                # released; rejected KV inside kept pages is overwritten
-                # by the next window before any query can attend it
-                needed = (seq.prefilled - 1) // ps + 1
-                if needed < len(seq.pages):
-                    drop = seq.pages[needed:]
-                    self.allocator.free(drop)
-                    del seq.pages[needed:]
-                    self._page_table[seq.slot, needed:] = \
-                        self.block.trash_page
-                    rollback_pages += len(drop)
+        with self._step_span("step_emit"):
+            for seq in seqs:
+                accepted, bonus = longest_accepted(drafts[seq.uid],
+                                                   greedy[seq.slot])
+                base_len = seq.length  # L: tokens before this round
+                self._dstats["spec_accepted_tokens"] += len(accepted)
+                self._m_spec_accepted.inc(len(accepted))
+                rec = out.setdefault(seq.uid, {"tokens": [], "done": False})
+                emitted = 0
+                for tok in accepted + [bonus]:
+                    seq.tokens.append(tok)
+                    emitted += 1
+                    rec["tokens"].append(tok)
+                    self._note_tokens(seq)
+                    if self._should_finish(seq, tok):
+                        break  # drop accepted tokens past a finish boundary
+                self._m_gen_tokens.inc(emitted)
+                self._dstats["decode_tokens"] += emitted
+                self._m_spec_tps.observe(emitted)
+                # KV is valid through the accepted region (the bonus token is
+                # the pending one, exactly like a plain decode step)
+                seq.prefilled = min(seq.length - 1,
+                                    base_len + len(accepted))
+                self._register_pages(seq)
+                self._maybe_finish(seq, seq.tokens[-1])
+                rec["done"] = seq.done
+                if seq.done:
+                    rec["finish_reason"] = seq.finish_reason
+                if not seq.done:
+                    # rollback: pages reserved for rejected draft tokens are
+                    # released; rejected KV inside kept pages is overwritten
+                    # by the next window before any query can attend it
+                    needed = (seq.prefilled - 1) // ps + 1
+                    if needed < len(seq.pages):
+                        drop = seq.pages[needed:]
+                        self.allocator.free(drop)
+                        del seq.pages[needed:]
+                        self._page_table[seq.slot, needed:] = \
+                            self.block.trash_page
+                        rollback_pages += len(drop)
         if rollback_pages:
             self._dstats["spec_rollback_pages"] += rollback_pages
             self._m_spec_rollback.inc(rollback_pages)
-            record_event("spec_rollback", cat="serve",
+            record_event("spec_rollback", cat="serve", step=self._step_id,
                          pages=rollback_pages, seqs=len(seqs))
         prop = self._dstats["spec_proposed_tokens"]
         if prop:

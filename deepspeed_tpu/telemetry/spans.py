@@ -183,14 +183,16 @@ class SpanRecorder:
     @contextlib.contextmanager
     def span(self, name: str, cat: str = "", **attrs):
         """Record the enclosed block; nests a profiler annotation so the
-        same range is attributable in an XProf capture."""
+        same range is attributable in an XProf capture.  Yields the
+        attribute dict the span will be recorded with, so a caller can add
+        what it only knows at the end (a step's counts)."""
         if not self.enabled:
             # the phase watch (memory watermarks) is orthogonal to span
             # RECORDING: notify it even with the ring off, as event() and
             # PhaseTimer already do
             _notify_phase(name, "enter")
             try:
-                yield
+                yield attrs
             finally:
                 _notify_phase(name, "exit")
             return
@@ -203,7 +205,7 @@ class SpanRecorder:
         _notify_phase(name, "enter")
         t0 = _now_us()
         try:
-            yield
+            yield attrs
         finally:
             dur = _now_us() - t0
             if ann is not None:

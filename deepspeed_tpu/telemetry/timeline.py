@@ -53,8 +53,8 @@ CATEGORY_PRIORITY = COMPUTE_CATEGORIES + COLLECTIVE_CATEGORIES
 ALL_CATEGORIES = CATEGORY_PRIORITY + ("host_compute", "host_gap",
                                       "pipe_bubble")
 
-_ATTENTION_PAT = ("attention", "flash", "splash", "paged_attn", "mha",
-                  "softmax")
+_ATTENTION_PAT = ("attention", "flash", "splash", "paged_attn",
+                  "paged_decode", "mha", "softmax")
 _GEMM_PAT = ("dot", "gemm", "matmul", "einsum", "conv")
 _COPY_PAT = ("copy", "transpose", "bitcast", "memcpy", "d2d", "h2d", "d2h")
 
@@ -166,45 +166,63 @@ def decompose_events(events: Sequence[Dict[str, Any]], wall_s: float,
 
 
 # ---------------------------------------------------------- xplane parse
+#: control-flow operations contain their bodies' events: counted, a
+#: ``while`` (other_compute) would shadow every collective inside it
+_CONTAINER_OPS = ("while", "conditional", "call")
+
+
 def _device_trace_events(log_dir: str) -> Tuple[List[Dict[str, Any]],
                                                 List[Dict[str, Any]]]:
-    """Parse the newest ``xplane.pb`` under ``log_dir`` into normalized
-    device events (seconds) plus the raw Chrome events for the merged
-    artifact. Returns ``([], [])`` whenever anything is missing — the
-    caller treats that as "no device trace" and falls back."""
+    """The newest ``xplane.pb`` under ``log_dir``, parsed; ``([], [])``
+    when there is none — the caller treats that as "no device trace" and
+    falls back."""
     planes = sorted(glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
                               recursive=True), key=os.path.getmtime)
-    if not planes:
-        return [], []
-    from tensorflow.python.profiler.internal import _pywrap_profiler_plugin
+    return parse_xplane(planes[-1]) if planes else ([], [])
 
-    raw = _pywrap_profiler_plugin.xspace_to_tools_data(
-        [planes[-1]], "trace_viewer")
-    data = raw[0] if isinstance(raw, tuple) else raw
-    if isinstance(data, bytes):
-        data = data.decode("utf-8", "replace")
-    parsed = json.loads(data)
-    chrome = parsed.get("traceEvents", []) or []
-    pid_name: Dict[Any, str] = {}
-    for ev in chrome:
-        if ev.get("ph") == "M" and ev.get("name") == "process_name":
-            pid_name[ev.get("pid")] = str((ev.get("args") or {}).get("name", ""))
-    device_pids = {pid for pid, name in pid_name.items()
-                   if "/device:" in name.lower() and "cpu" not in name.lower()}
+
+def parse_xplane(path: str) -> Tuple[List[Dict[str, Any]],
+                                     List[Dict[str, Any]]]:
+    """One ``.xplane.pb``, read with ``jax.profiler.ProfileData``, as
+    normalized device events (seconds) plus Chrome events for the merged
+    artifact; ``([], [])`` when it holds no device plane.
+
+    A device is a plane ``/device:<kind>:<n>`` other than the host CPU's.
+    On a TPU its line ``XLA Ops`` holds one event per executed HLO
+    operation, named by the instruction's text (``%fusion.3 = bf16[..]
+    fusion(..)``: the name kept is ``fusion.3``), and ``Async XLA Ops``
+    the DMA side of asynchronous collectives, which overlaps compute;
+    a backend without those lines contributes every line of the plane."""
+    from jax.profiler import ProfileData
+
     events, artifact = [], []
-    for ev in chrome:
-        pid = ev.get("pid")
-        if pid not in device_pids:
+    profile = ProfileData.from_file(path)
+    for pid, plane in enumerate(profile.planes):
+        name = plane.name
+        if not name.startswith("/device:") or ":CPU" in name.upper():
             continue
-        artifact.append(ev)
-        if ev.get("ph") == "X" and ev.get("dur"):
-            events.append({"name": ev.get("name", ""),
-                           "ts": float(ev["ts"]) / 1e6,
-                           "dur": float(ev["dur"]) / 1e6})
-    # carry the device process/thread names into the merged artifact
-    artifact.extend(ev for ev in chrome
-                    if ev.get("ph") == "M" and ev.get("pid") in device_pids)
-    return events, artifact
+        lines = list(plane.lines)
+        has_ops = any(ln.name == "XLA Ops" for ln in lines)
+        artifact.append({"ph": "M", "name": "process_name", "pid": pid,
+                         "args": {"name": name}})
+        for tid, line in enumerate(lines):
+            if has_ops and line.name not in ("XLA Ops", "Async XLA Ops"):
+                continue
+            dma = line.name == "Async XLA Ops"
+            artifact.append({"ph": "M", "name": "thread_name", "pid": pid,
+                             "tid": tid, "args": {"name": line.name}})
+            for ev in line.events:
+                op = ev.name.split(" = ", 1)[0].strip().lstrip("%")
+                if ev.duration_ns <= 0 or op.split(".")[0] in _CONTAINER_OPS:
+                    continue
+                if dma and categorize_op(op) not in COLLECTIVE_CATEGORIES:
+                    continue  # an async copy is a DMA, not compute
+                artifact.append({"ph": "X", "name": op, "pid": pid,
+                                 "tid": tid, "ts": ev.start_ns / 1e3,
+                                 "dur": ev.duration_ns / 1e3})
+                events.append({"name": op, "ts": ev.start_ns / 1e9,
+                               "dur": ev.duration_ns / 1e9})
+    return (events, artifact) if events else ([], [])
 
 
 # ----------------------------------------------------- last-record slot
@@ -401,8 +419,8 @@ class StepTimeline:
 
             ivals = []
             for sp in get_span_recorder().spans():
-                a = max(float(sp.ts), t0_us)
-                b = min(float(sp.ts) + float(sp.dur), t1_us)
+                a = max(sp.ts_us, t0_us)
+                b = min(sp.ts_us + sp.dur_us, t1_us)
                 if b > a:
                     ivals.append((a, b))
             ivals.sort()

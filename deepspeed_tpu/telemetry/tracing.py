@@ -77,12 +77,14 @@ def stop_trace() -> None:
 class PhaseTimer:
     """Context manager that annotates a phase for the profiler, reports
     its host wall time to a callback (usually a histogram ``observe``),
-    and records the range as a span (cat ``phase``) in the trace ring —
-    one context, three sinks.  ``attrs`` ride on the span only."""
+    and records the range as a span (cat ``phase`` unless ``cat`` says
+    otherwise) in the trace ring — one context, three sinks.  ``attrs``
+    ride on the span only."""
 
-    def __init__(self, name: str, sink=None, **attrs):
+    def __init__(self, name: str, sink=None, cat: str = "phase", **attrs):
         self.name = name
         self.sink = sink
+        self.cat = cat
         self.attrs = attrs
         self._ann = None
         self._t0: Optional[float] = None
@@ -112,6 +114,6 @@ class PhaseTimer:
         _notify_phase(self.name, "exit")
         rec = get_span_recorder()
         if rec.enabled:
-            rec.record(self.name, self._t0_us, dt * 1e6, cat="phase",
+            rec.record(self.name, self._t0_us, dt * 1e6, cat=self.cat,
                        **self.attrs)
         return False

@@ -40,6 +40,8 @@ from deepspeed_tpu.telemetry.timeline import (StepTimeline, capture_thunk,
     ("fusion.matmul", "gemm"),
     ("custom-call.flash_attention", "attention"),
     ("softmax.12", "attention"),
+    ("dstpu_flash_fwd.3", "attention"),     # the names the kernels have
+    ("dstpu_paged_decode.6", "attention"),  # in a v5e trace
     ("copy.4", "copy"),
     ("transpose.8", "copy"),
     ("dynamic-update-slice.2", "other_compute"),
