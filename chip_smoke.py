@@ -161,13 +161,14 @@ def leg_kernels(sz, on_chip: bool) -> None:
     check(e < TOL_FWD, f"flash q_offset forward chunk={C} window={W} "
           f"offset={off}: rel err {e:.2e} < {TOL_FWD:.2e}")
 
-    # paged decode vs the XLA gather path of the decode program
-    ps, MP = 16, sz["pages_per_seq"]
+    # paged decode vs the XLA gather path of the decode program, on a
+    # three-layer pool in the engine's layout, read at the middle layer
+    ps, MP, L, lyr = 16, sz["pages_per_seq"], 3, 1
     pos = jnp.asarray(sz["decode_positions"], jnp.int32)
     B = pos.shape[0]
     P = B * MP + 1  # + the trash page
-    k_pool = jax.random.normal(ks[4], (P, ps, KVH, D), jnp.bfloat16)
-    v_pool = jax.random.normal(ks[5], (P, ps, KVH, D), jnp.bfloat16)
+    k_pool = jax.random.normal(ks[4], (L, P, ps, KVH * D), jnp.bfloat16)
+    v_pool = jax.random.normal(ks[5], (L, P, ps, KVH * D), jnp.bfloat16)
     table = jax.random.permutation(ks[6], P - 1)[:B * MP].reshape(B, MP)
     used = pos[:, None] // ps >= jnp.arange(MP)[None, :]
     table = jnp.where(used, table, P - 1).astype(jnp.int32)
@@ -177,16 +178,24 @@ def leg_kernels(sz, on_chip: bool) -> None:
     vis = jnp.arange(MP * ps)[None, None, :] <= pos[:, None, None]
     with jax.default_matmul_precision("highest"):
         ref = _gather_window_attend(
-            cfg, False, qd.astype(f32)[:, None], k_pool.astype(f32),
-            v_pool.astype(f32), None, None, table, pos[:, None], vis)
-    got = jax.jit(paged_decode_attention)(qd, k_pool, v_pool, table, pos)
+            cfg, qd.astype(f32)[:, None],
+            {"k": k_pool.astype(f32), "v": v_pool.astype(f32)}, lyr, table,
+            pos[:, None], vis)
+    layered = jax.jit(lambda *a: paged_decode_attention(*a, layer=lyr))
+    got = layered(qd, k_pool, v_pool, table, pos)
     e = rel_err(got.reshape(B, -1), ref[:, 0])
-    check(e < TOL_FWD, f"paged decode vs _gather_window_attend page={ps} "
-          f"KVH={KVH} G={G} D={D} positions={sz['decode_positions']}: "
-          f"rel err {e:.2e} < {TOL_FWD:.2e}")
+    check(e < TOL_FWD, f"paged decode at layer {lyr} of {L} vs "
+          f"_gather_window_attend page={ps} KVH={KVH} G={G} D={D} "
+          f"positions={sz['decode_positions']}: rel err {e:.2e} < "
+          f"{TOL_FWD:.2e}")
+    one = jax.jit(paged_decode_attention)(
+        qd, k_pool[lyr].reshape(P, ps, KVH, D),
+        v_pool[lyr].reshape(P, ps, KVH, D), table, pos)
+    check(bool(jnp.array_equal(one, got)),
+          "paged decode over that layer alone as [P, ps, KVH, D] == the "
+          "layer-addressed read")
     if on_chip:
-        text = jax.jit(paged_decode_attention).lower(
-            qd, k_pool, v_pool, table, pos).as_text()
+        text = layered.lower(qd, k_pool, v_pool, table, pos).as_text()
         check("tpu_custom_call" in text and "dstpu_paged_decode" in text,
               "paged decode lowers to the Mosaic custom call")
 

@@ -41,7 +41,8 @@ COLLECTIVE_KINDS = ("all-reduce", "all-gather", "reduce-scatter",
 #: deterministic for an identical program, but minor layout/fusion
 #: nondeterminism must not flap tier-1; collectives/donation/shapes
 #: compare EXACTLY
-DEFAULT_TOLERANCES = {"flops": 0.05, "bytes_accessed": 0.10}
+DEFAULT_TOLERANCES = {"flops": 0.05, "bytes_accessed": 0.10,
+                      "temp_bytes": 0.10}
 
 #: goldens live here, relative to the repo root
 CONTRACTS_DIR = os.path.join("tests", "contracts")
@@ -101,12 +102,17 @@ def _cost_dict(compiled) -> Dict[str, float]:
 
 def extract_contract(jit_fn, args: Sequence[Any],
                      mesh: Any = None,
-                     want_s8: bool = False) -> Dict[str, Any]:
+                     want_s8: bool = False,
+                     want_temp: bool = False) -> Dict[str, Any]:
     """Lower + compile ``jit_fn(*args)`` and extract its contract dict
     (the compared section only; callers add replay/state fields).
     ``want_s8``: also pin :func:`s8_collective_count` from the SAME
     compile (the compressed-overlap programs; opt-in so pre-existing
-    goldens keep their key set byte-identical)."""
+    goldens keep their key set byte-identical).  ``want_temp``: also pin
+    XLA's temporaries (``memory_analysis().temp_size_in_bytes``) — the
+    serving programs, whose KV pools must be updated in place: a pool
+    handed through the layer scan as operand and stacked output shows up
+    here as a second pool."""
     import contextlib
 
     ctx = mesh if mesh is not None else contextlib.nullcontext()
@@ -124,6 +130,8 @@ def extract_contract(jit_fn, args: Sequence[Any],
     }
     if want_s8:
         out["s8_collectives"] = s8_collective_count(hlo)
+    if want_temp:
+        out["temp_bytes"] = int(compiled.memory_analysis().temp_size_in_bytes)
     return out
 
 
@@ -262,7 +270,7 @@ def _prefill_program() -> Dict[str, Any]:
     args = (eng.params, eng._pools, jnp.asarray(ids), jnp.asarray(rows),
             jnp.int32(13))
     return {"fn": eng._prefill, "args": args, "mesh": None,
-            "extras": _v2_extras(eng), "replay": None}
+            "want_temp": True, "extras": _v2_extras(eng), "replay": None}
 
 
 def _decode_program() -> Dict[str, Any]:
@@ -281,7 +289,7 @@ def _decode_program() -> Dict[str, Any]:
             jnp.asarray(np.zeros((B,), np.int32)),
             jax.random.PRNGKey(0))
     return {"fn": eng._decode, "args": args, "mesh": None,
-            "extras": _v2_extras(eng), "replay": None}
+            "want_temp": True, "extras": _v2_extras(eng), "replay": None}
 
 
 def _multi_decode_program() -> Dict[str, Any]:
@@ -308,7 +316,7 @@ def _multi_decode_program() -> Dict[str, Any]:
             jnp.asarray(np.zeros((B,), np.int32)),
             jax.random.PRNGKey(0), K)
     return {"fn": eng._multi, "args": args, "mesh": None,
-            "extras": _v2_extras(eng),
+            "want_temp": True, "extras": _v2_extras(eng),
             "replay": lambda: _replay_multi_decode(eng, K)}
 
 
@@ -365,7 +373,7 @@ def _verify_program() -> Dict[str, Any]:
             jnp.asarray(np.zeros((B,), bool)),
             jnp.asarray(np.ones((B,), np.int32)))
     return {"fn": eng._verify, "args": args, "mesh": None,
-            "extras": _v2_extras(eng), "replay": None}
+            "want_temp": True, "extras": _v2_extras(eng), "replay": None}
 
 
 def _moe_dispatch_program() -> Dict[str, Any]:
@@ -606,14 +614,16 @@ def extract_program(name: str) -> Dict[str, Any]:
     builder, description = PROGRAM_BUILDERS[name]
     prog = builder()
     contract = extract_contract(prog["fn"], prog["args"], prog["mesh"],
-                                want_s8=prog.get("want_s8", False))
+                                want_s8=prog.get("want_s8", False),
+                                want_temp=prog.get("want_temp", False))
     contract.update(prog["extras"])
     if prog["replay"] is not None:
         contract["replay"] = prog["replay"]()
     return {
         "program": name,
         "contract": contract,
-        "tolerances": dict(DEFAULT_TOLERANCES),
+        "tolerances": {k: v for k, v in DEFAULT_TOLERANCES.items()
+                       if k in contract},
         "info": {
             "description": description,
             "backend": jax.devices()[0].platform,
@@ -656,7 +666,9 @@ def diff_contract(name: str, golden: Dict[str, Any],
             verb = "grew" if b > a else "dropped"
             errs.append(f"{name}: {verb} {kind} {a} -> {b} "
                         f"({b - a:+d} collective(s) vs the golden contract)")
-    for field in ("flops", "bytes_accessed"):
+    for field in ("flops", "bytes_accessed", "temp_bytes"):
+        if field not in g and field not in n:
+            continue  # temp_bytes: the serving programs only
         a, b = float(g.get(field, 0.0)), float(n.get(field, 0.0))
         if not (math.isfinite(a) and math.isfinite(b)
                 and _rel_close(a, b, tol.get(field, 0.0))):

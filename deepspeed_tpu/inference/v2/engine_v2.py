@@ -943,7 +943,8 @@ class InferenceEngineV2:
             decode_entry=seq.decode_entry, page_size=ps, page_keys=keys,
             priority=seq.priority, deadline=seq.deadline,
             src_pages=self.allocator.export_meta(seq.pages),
-            arrays=paged_gather_pages(self._pools, seq.pages),
+            arrays=paged_gather_pages(self._pools, seq.pages,
+                                      self.cfg.kv_heads),
             model_sig=(self.cfg.n_layers, self.cfg.kv_heads,
                        self.cfg.head_dim),
             kv_quant=bool(self.config.kv_quant), dtype=self.config.dtype)
@@ -1151,7 +1152,7 @@ class InferenceEngineV2:
         rows = pad_pages_pow2([p for p, _ in pend], self.block.trash_page)
         self._step_parts.add(("kv_spill", len(rows)))
         sentinel_expect_recompile("kv_tier_spill")
-        arrays = paged_gather_pages(self._pools, rows)
+        arrays = paged_gather_pages(self._pools, rows, self.cfg.kv_heads)
         arrays = {n: a[:, :len(pend)] for n, a in arrays.items()}
         crcs = batch_page_crcs(arrays)
         for j, (page, key) in enumerate(pend):

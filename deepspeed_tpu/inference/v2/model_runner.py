@@ -14,6 +14,15 @@ two XLA programs:
 
 Scatters are unconditional: inactive slots and pad chunks write to the
 trash page (ragged.py) instead of branching.
+
+The pools are ``[L, P+1, ps, KVH*D]`` (scales ``[L, P+1, ps, KVH]``): the
+page block the decode kernel reads, so nothing relayouts them.  Every
+program runs its layers through ``_scan_layers``, which carries the whole
+pools through the loop; a layer writes ``pool.at[l, ...]`` and reads
+``pool[l, table]`` on the carry, and with the pools donated at the jit
+boundary XLA updates the one buffer in place.  No operation of a serving
+program is pool-sized; the only reshapes between ``KVH*D`` and
+``[KVH, D]`` are on the fresh K/V of a call and on a gathered window.
 """
 
 from __future__ import annotations
@@ -55,17 +64,53 @@ def _kv_quantize(x):
     return q, s.astype(jnp.float32)
 
 
-def _pools_per_layer(pools):
-    """Split the pools dict into per-layer scan operands (None-safe)."""
-    return (pools["k"], pools["v"],
-            pools.get("k_scale"), pools.get("v_scale"))
+def _scan_layers(params, pools, x, layer_fn):
+    """The layer loop of every paged program: ``layer_fn(layer, l, x,
+    pools) -> (x, pools)`` over ``params["layers"]`` with the WHOLE pools
+    in the carry.  The pools are never a per-layer operand or a stacked
+    output of the scan — that form makes XLA slice a layer out, update
+    the slice and write it into a second pool-sized buffer — so with the
+    pools donated the writes land in the caller's buffer."""
+    n_layers = pools["k"].shape[0]
+
+    def body(carry, inputs):
+        return layer_fn(*inputs, *carry), None
+
+    (x, pools), _ = jax.lax.scan(
+        body, (x, pools), (params["layers"], jnp.arange(n_layers)))
+    return x, pools
 
 
-def _pools_from_scan(new_pools):
-    """Inverse of _pools_per_layer over the scan outputs."""
-    out = {"k": new_pools[0], "v": new_pools[1]}
-    if new_pools[2] is not None:
-        out["k_scale"], out["v_scale"] = new_pools[2], new_pools[3]
+def _pool_write(pools, l, idx, k, v):
+    """Write fresh K/V ``[..., KVH, D]`` into layer ``l`` of the carried
+    pools at ``idx`` — ``(rows,)`` for whole pages ``[n, ps, KVH, D]``,
+    ``(page_idx, off)`` for single tokens — quantizing when the pool is
+    int8.  The ``KVH*D`` merge is on the call's own K/V, never the pool."""
+    out = dict(pools)
+    for name, x in (("k", k), ("v", v)):
+        if name + "_scale" in pools:
+            x, scale = _kv_quantize(x)
+            out[name + "_scale"] = (
+                pools[name + "_scale"].at[(l, *idx)].set(scale))
+        out[name] = pools[name].at[(l, *idx)].set(
+            x.reshape(*x.shape[:-2], -1).astype(pools[name].dtype))
+    return out
+
+
+def _pool_window(pools, l, table, kv_heads):
+    """Layer ``l``'s pages ``table [..., MP]`` as K, V ``[..., MP*ps, KVH,
+    D]`` — a window-sized gather, dequantized to fp32 when the pool is
+    int8."""
+    out = []
+    for name in ("k", "v"):
+        w = pools[name][l, table]  # [..., MP, ps, KVH*D]
+        w = w.reshape(*table.shape[:-1], -1, kv_heads,
+                      w.shape[-1] // kv_heads)
+        if name + "_scale" in pools:
+            sc = pools[name + "_scale"][l, table]
+            w = (w.astype(jnp.float32)
+                 * sc.reshape(*w.shape[:-1])[..., None])
+        out.append(w)
     return out
 
 
@@ -93,18 +138,6 @@ def _attn_out(cfg: TransformerConfig, layer, x, attn):
     return _ffn(cfg, layer, x + attn_delta)
 
 
-def _write_pages(quant, rows, k_pages, v_pages, k_c, v_c, ks_c, vs_c):
-    """Scatter whole pages of fresh K/V into the pools (quantizing when
-    the pool is int8) — shared by whole-prompt and chunked prefill."""
-    if quant:
-        kq, ksc = _kv_quantize(k_pages)
-        vq, vsc = _kv_quantize(v_pages)
-        return (k_c.at[rows].set(kq), v_c.at[rows].set(vq),
-                ks_c.at[rows].set(ksc), vs_c.at[rows].set(vsc))
-    return (k_c.at[rows].set(k_pages.astype(k_c.dtype)),
-            v_c.at[rows].set(v_pages.astype(v_c.dtype)), ks_c, vs_c)
-
-
 def paged_prefill(cfg: TransformerConfig, params, pools,
                   ids, page_rows, length) -> Tuple[jnp.ndarray, Any]:
     """Prefill one prompt.
@@ -115,7 +148,6 @@ def paged_prefill(cfg: TransformerConfig, params, pools,
     page index per chunk (trash for pad chunks); length: real prompt length.
     Returns (last-token logits [V], pools).
     """
-    quant = "k_scale" in pools
     S = ids.shape[0]
     ps = pools["k"].shape[2]
     x = params["embed"]["tok"][ids][None]  # [1, S, H]
@@ -132,12 +164,11 @@ def paged_prefill(cfg: TransformerConfig, params, pools,
 
     use_flash = _use_paged_kernel()
 
-    def body(x, inputs):
-        layer, k_c, v_c, ks_c, vs_c = inputs  # k_c: [P+1, ps, KVH, D]
+    def layer_fn(layer, l, x, pools):
         q, k, v = attn_qkv(cfg, layer, x, positions)
-        k_c, v_c, ks_c, vs_c = _write_pages(
-            quant, page_rows, k[0].reshape(S // ps, ps, *k.shape[2:]),
-            v[0].reshape(S // ps, ps, *v.shape[2:]), k_c, v_c, ks_c, vs_c)
+        pools = _pool_write(
+            pools, l, (page_rows,), k[0].reshape(S // ps, ps, *k.shape[2:]),
+            v[0].reshape(S // ps, ps, *v.shape[2:]))
         if use_flash:
             # GQA-native flash kernel: no [S, S] score materialization.
             # Pad tokens past ``length`` see only earlier slots (causal)
@@ -161,15 +192,13 @@ def paged_prefill(cfg: TransformerConfig, params, pools,
             scores = jnp.where(causal, scores, -1e30)
             probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
             attn = jnp.einsum("bnts,bsnd->btnd", probs, vv).reshape(1, S, -1)
-        return _attn_out(cfg, layer, x, attn), (k_c, v_c, ks_c, vs_c)
+        return _attn_out(cfg, layer, x, attn), pools
 
-    ops = (params["layers"],) + _pools_per_layer(pools)
-    x, new_pools = jax.lax.scan(body, x, ops)
-    out_pools = _pools_from_scan(new_pools)
+    x, pools = _scan_layers(params, pools, x, layer_fn)
     hidden = _norm(x[:, length - 1], params["final_norm"]["scale"],
                    params["final_norm"].get("bias"), cfg.norm, cfg.norm_eps)
     logits = logits_fn(cfg, params, hidden[:, None])[0, 0]
-    return logits, out_pools
+    return logits, pools
 
 
 def paged_copy_page(pools, src, dst):
@@ -197,9 +226,10 @@ def pad_pages_pow2(pages, trash_page):
     return list(pages) + [trash_page] * (n - len(pages))
 
 
-def paged_gather_pages(pools, pages):
+def paged_gather_pages(pools, pages, kv_heads):
     """Host copy of the given pool pages (KV export): one numpy array
-    per pool leaf, shaped ``[L, n_pages, page_size, KVH, D]`` in the
+    per pool leaf, K/V shaped ``[L, n_pages, page_size, KVH, D]`` (the
+    pool's ``KVH*D`` split back on the host, where it is a view) in the
     pool's exact dtype (bf16 round-trips through ml_dtypes) — the
     device half of KV-page migration and of the host-RAM spill
     (``serving/kv_tier.py`` captures evicted prefix pages through
@@ -207,7 +237,10 @@ def paged_gather_pages(pools, pages):
     import numpy as np
 
     rows = jnp.asarray(np.asarray(pages, np.int32))
-    return {name: np.asarray(leaf[:, rows]) for name, leaf in pools.items()}
+    out = {name: np.asarray(leaf[:, rows]) for name, leaf in pools.items()}
+    for name in ("k", "v"):
+        out[name] = out[name].reshape(*out[name].shape[:3], kv_heads, -1)
+    return out
 
 
 def paged_scatter_pages(pools, pages, arrays):
@@ -230,7 +263,9 @@ def paged_scatter_pages(pools, pages, arrays):
             raise ValueError(f"pool leaf {name!r} dtype {leaf.dtype} != "
                              f"bundle dtype {src.dtype}: import must be "
                              "bit-identical, refusing to cast")
-        out[name] = leaf.at[:, rows].set(jnp.asarray(src))
+        out[name] = leaf.at[:, rows].set(
+            jnp.asarray(src.reshape(*src.shape[:3], -1)
+                        if name in ("k", "v") else src))
     return out
 
 
@@ -288,19 +323,12 @@ def paged_prefill_chunk(cfg: TransformerConfig, params, pools,
     # chunked/whole divergence limited to the inherent cross-chunk case
     use_flash = _use_paged_kernel() and not quant
 
-    def body(x, inputs):
-        layer, k_c, v_c, ks_c, vs_c = inputs
+    def layer_fn(layer, l, x, pools):
         q, k, v = attn_qkv(cfg, layer, x, positions)
-        k_c, v_c, ks_c, vs_c = _write_pages(
-            quant, chunk_rows, k[0].reshape(C // ps, ps, *k.shape[2:]),
-            v[0].reshape(C // ps, ps, *v.shape[2:]), k_c, v_c, ks_c, vs_c)
-        kp = k_c[prev_table].reshape(S_prev, *k_c.shape[2:])
-        vp = v_c[prev_table].reshape(S_prev, *v_c.shape[2:])
-        if quant:
-            kp = (kp.astype(jnp.float32)
-                  * ks_c[prev_table].reshape(S_prev, -1)[..., None])
-            vp = (vp.astype(jnp.float32)
-                  * vs_c[prev_table].reshape(S_prev, -1)[..., None])
+        pools = _pool_write(
+            pools, l, (chunk_rows,), k[0].reshape(C // ps, ps, *k.shape[2:]),
+            v[0].reshape(C // ps, ps, *v.shape[2:]))
+        kp, vp = _pool_window(pools, l, prev_table, cfg.kv_heads)
         if use_flash:
             # the table covers the window THROUGH this chunk (engine
             # buckets it to >= start + C), and pool-slot index == global
@@ -316,7 +344,7 @@ def paged_prefill_chunk(cfg: TransformerConfig, params, pools,
                 alibi_slopes=(alibi_slopes(cfg.n_heads)
                               if cfg.position == "alibi" else None)
             ).reshape(1, C, -1)
-            return _attn_out(cfg, layer, x, attn), (k_c, v_c, ks_c, vs_c)
+            return _attn_out(cfg, layer, x, attn), pools
         # keys = [previous pooled slots | this chunk]; the pooled half is
         # masked to < start, the chunk half causally within the chunk
         kk = jnp.concatenate([kp.astype(x.dtype)[None], k], axis=1)
@@ -338,35 +366,28 @@ def paged_prefill_chunk(cfg: TransformerConfig, params, pools,
         scores = jnp.where(mask[None, None], scores, -1e30)
         probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
         attn = jnp.einsum("bnts,bsnd->btnd", probs, vv).reshape(1, C, -1)
-        return _attn_out(cfg, layer, x, attn), (k_c, v_c, ks_c, vs_c)
+        return _attn_out(cfg, layer, x, attn), pools
 
-    ops = (params["layers"],) + _pools_per_layer(pools)
-    x, new_pools = jax.lax.scan(body, x, ops)
-    out_pools = _pools_from_scan(new_pools)
+    x, pools = _scan_layers(params, pools, x, layer_fn)
     hidden = _norm(x[:, n - 1], params["final_norm"]["scale"],
                    params["final_norm"].get("bias"), cfg.norm, cfg.norm_eps)
     logits = logits_fn(cfg, params, hidden[:, None])[0, 0]
-    return logits, out_pools
+    return logits, pools
 
 
-def _gather_window_attend(cfg: TransformerConfig, quant: bool, q,
-                          k_c, v_c, ks_c, vs_c, page_table, q_pos, vis
-                          ) -> jnp.ndarray:
+def _gather_window_attend(cfg: TransformerConfig, q, pools, l,
+                          page_table, q_pos, vis) -> jnp.ndarray:
     """[B, T] written-through queries attend the pooled pages via the
     XLA gather path — THE shared formulation of the paged_decode
     fallback (T=1) and paged_verify (T=k+1), so the dequant / GQA /
     alibi / mask / softmax chain cannot diverge between them.
 
-    q: [B, T, NH, D]; q_pos: [B, T] global positions; vis: [B, T, S]
-    per-query visibility over pool slots.  Returns [B, T, NH*D]."""
+    q: [B, T, NH, D]; pools, l: the carried pools and the layer to read;
+    q_pos: [B, T] global positions; vis: [B, T, S] per-query visibility
+    over pool slots.  Returns [B, T, NH*D]."""
     B, S = vis.shape[0], vis.shape[2]
-    kk = k_c[page_table].reshape(B, S, *k_c.shape[2:])  # [B, S, KVH, D]
-    vv = v_c[page_table].reshape(B, S, *v_c.shape[2:])
-    if quant:
-        kk = kk.astype(jnp.float32) \
-            * ks_c[page_table].reshape(B, S, -1)[..., None]
-        vv = vv.astype(jnp.float32) \
-            * vs_c[page_table].reshape(B, S, -1)[..., None]
+    kk, vv = _pool_window(pools, l, page_table, cfg.kv_heads)  # [B,S,KVH,D]
+    if "k_scale" in pools:
         kk = kk.astype(q.dtype)
         vv = vv.astype(q.dtype)
     kk = _repeat_kv(kk, cfg.n_heads // cfg.kv_heads)
@@ -414,7 +435,6 @@ def paged_verify(cfg: TransformerConfig, params, pools,
     (the Pallas decode kernel is single-query; a multi-query window
     kernel is a future optimization) — the win measured here is model
     *invocations*, not attention FLOPs."""
-    quant = "k_scale" in pools
     B, W = ids.shape
     ps = pools["k"].shape[2]
     trash = pools["k"].shape[1] - 1
@@ -437,30 +457,18 @@ def paged_verify(cfg: TransformerConfig, params, pools,
     slot_pos = jnp.arange(S)[None, None]          # [1, 1, S]
     vis = slot_pos <= pos_w[:, :, None]           # [B, W, S]
 
-    def body(x, inputs):
-        layer, k_c, v_c, ks_c, vs_c = inputs
+    def layer_fn(layer, l, x, pools):
         q, k, v = attn_qkv(cfg, layer, x, pos_w)  # [B, W, NH/KVH, D]
-        if quant:
-            kq, ksc = _kv_quantize(k)
-            vq, vsc = _kv_quantize(v)
-            k_c = k_c.at[page_idx, off].set(kq)
-            v_c = v_c.at[page_idx, off].set(vq)
-            ks_c = ks_c.at[page_idx, off].set(ksc)
-            vs_c = vs_c.at[page_idx, off].set(vsc)
-        else:
-            k_c = k_c.at[page_idx, off].set(k.astype(k_c.dtype))
-            v_c = v_c.at[page_idx, off].set(v.astype(v_c.dtype))
-        attn = _gather_window_attend(cfg, quant, q, k_c, v_c, ks_c,
-                                     vs_c, page_table, pos_w, vis)
-        return _attn_out(cfg, layer, x, attn), (k_c, v_c, ks_c, vs_c)
+        pools = _pool_write(pools, l, (page_idx, off), k, v)
+        attn = _gather_window_attend(cfg, q, pools, l, page_table, pos_w,
+                                     vis)
+        return _attn_out(cfg, layer, x, attn), pools
 
-    ops = (params["layers"],) + _pools_per_layer(pools)
-    x, new_pools = jax.lax.scan(body, x, ops)
-    out_pools = _pools_from_scan(new_pools)
+    x, pools = _scan_layers(params, pools, x, layer_fn)
     hidden = _norm(x, params["final_norm"]["scale"],
                    params["final_norm"].get("bias"), cfg.norm, cfg.norm_eps)
     logits = logits_fn(cfg, params, hidden)  # [B, W, V]
-    return logits, out_pools
+    return logits, pools
 
 
 def paged_decode(cfg: TransformerConfig, params, pools,
@@ -477,7 +485,6 @@ def paged_decode(cfg: TransformerConfig, params, pools,
     ONE formulation, so the fused K-step scan cannot diverge from the
     single-step program it must be bit-identical to.
     """
-    quant = "k_scale" in pools
     B = last_tokens.shape[0]
     ps = pools["k"].shape[2]
     trash = pools["k"].shape[1] - 1
@@ -505,45 +512,33 @@ def paged_decode(cfg: TransformerConfig, params, pools,
 
     use_kernel = _use_paged_kernel()
 
-    def body(x, inputs):
-        layer, k_c, v_c, ks_c, vs_c = inputs
+    def layer_fn(layer, l, x, pools):
         q, k, v = attn_qkv(cfg, layer, x, positions[:, None])
-        if quant:
-            kq, ksc = _kv_quantize(k[:, 0])
-            vq, vsc = _kv_quantize(v[:, 0])
-            k_c = k_c.at[page_idx, off].set(kq)
-            v_c = v_c.at[page_idx, off].set(vq)
-            ks_c = ks_c.at[page_idx, off].set(ksc)
-            vs_c = vs_c.at[page_idx, off].set(vsc)
-        else:
-            k_c = k_c.at[page_idx, off].set(k[:, 0].astype(k_c.dtype))
-            v_c = v_c.at[page_idx, off].set(v[:, 0].astype(v_c.dtype))
+        pools = _pool_write(pools, l, (page_idx, off), k[:, 0], v[:, 0])
         if use_kernel:
-            # Pallas paged kernel: pages addressed in place through the
-            # scalar-prefetched table — no [B, S, KVH, D] materialization
-            # (reference ragged_ops decode kernels)
+            # Pallas paged kernel: the whole pool goes in as it stands and
+            # the scalar-prefetched layer and table address each page in
+            # place — no [B, S, KVH, D] materialization (reference
+            # ragged_ops decode kernels)
             from ...ops.pallas.paged_attention import paged_decode_attention
 
             attn = paged_decode_attention(
-                q[:, 0], k_c, v_c, page_table, positions,
-                k_scale=ks_c, v_scale=vs_c,
+                q[:, 0], pools["k"], pools["v"], page_table, positions,
+                k_scale=pools.get("k_scale"), v_scale=pools.get("v_scale"),
                 alibi_slopes=(alibi_slopes(cfg.n_heads)
-                              if cfg.position == "alibi" else None)
-            ).reshape(B, 1, -1)
+                              if cfg.position == "alibi" else None),
+                layer=l).reshape(B, 1, -1)
         else:
-            attn = _gather_window_attend(cfg, quant, q, k_c, v_c, ks_c,
-                                         vs_c, page_table,
+            attn = _gather_window_attend(cfg, q, pools, l, page_table,
                                          positions[:, None],
                                          vis[:, None, :])
-        return _attn_out(cfg, layer, x, attn), (k_c, v_c, ks_c, vs_c)
+        return _attn_out(cfg, layer, x, attn), pools
 
-    ops = (params["layers"],) + _pools_per_layer(pools)
-    x, new_pools = jax.lax.scan(body, x, ops)
-    out_pools = _pools_from_scan(new_pools)
+    x, pools = _scan_layers(params, pools, x, layer_fn)
     hidden = _norm(x, params["final_norm"]["scale"],
                    params["final_norm"].get("bias"), cfg.norm, cfg.norm_eps)
     logits = logits_fn(cfg, params, hidden)[:, 0]
-    return logits, out_pools
+    return logits, pools
 
 
 def sample_tokens(logits, temps, key, sids, positions) -> jnp.ndarray:

@@ -561,15 +561,23 @@ class PagedKVCache:
     (page, slot, kv-head) — half the pool HBM of bf16, so twice the KV
     capacity (the reference's blocked-KV analogue of weight-only
     quantization, applied to the cache).  Quantize-on-write,
-    dequantize-on-read; the paged Pallas kernel dequantizes in VMEM."""
+    dequantize-on-read; the paged Pallas kernel dequantizes in VMEM.
+
+    K and V are ``[L, P+1, ps, KVH*D]`` — a page is the ``(ps, KVH*D)``
+    block the decode kernel reads, so no program relayouts the pool —
+    and the scales ``[L, P+1, ps, KVH]``.  ``KVPageBundle.arrays`` keeps
+    ``[L, n, ps, KVH, D]``: ``model_runner.paged_gather_pages`` /
+    ``paged_scatter_pages`` split and merge the last axis on page-sized
+    data."""
 
     @staticmethod
     def init(n_layers: int, kv_heads: int, head_dim: int,
              block: KVBlockConfig, dtype=jnp.bfloat16,
              kv_quant: bool = False) -> Dict[str, Any]:
-        shape = (n_layers, block.num_pages + 1, block.page_size, kv_heads, head_dim)
+        shape = (n_layers, block.num_pages + 1, block.page_size,
+                 kv_heads * head_dim)
         if kv_quant:
-            sshape = shape[:-1]
+            sshape = shape[:-1] + (kv_heads,)
             return {"k": jnp.zeros(shape, jnp.int8),
                     "v": jnp.zeros(shape, jnp.int8),
                     "k_scale": jnp.zeros(sshape, jnp.float32),

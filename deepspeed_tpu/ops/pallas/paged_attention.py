@@ -2,13 +2,19 @@
 
 The TPU-native replacement for the reference's ragged decode kernels
 (``inference/v2/kernels/ragged_ops``): one query token per sequence
-attends over that sequence's KV *pages in place* — the page table is a
-scalar-prefetch operand and each grid step's K/V block is addressed
-``k_pool[page_table[b, jp]]`` directly, so the padded [B, S, KVH, D]
-gather the XLA fallback materializes per layer per token never exists.
+attends over that sequence's KV *pages in place* — the layer and the page
+table are scalar-prefetch operands and each grid step's K/V block is
+addressed ``k_pool[layer, page_table[b, jp]]`` directly, so the padded
+[B, S, KVH, D] gather the XLA fallback materializes per layer per token
+never exists, and the serving programs hand the kernel the whole pool they
+carry without slicing a layer out of it.
 
 Layout: q [B, KVH, G, D] (GQA groups folded next to their kv head);
-pools [P, ps, KVH, D], read as [P, ps, KVH*D] page blocks; page_table [B, MP] int32 (trash-filled past each
+pools [L, P, ps, KVH*D] as the engine stores them, read as page blocks
+``(1, ps, KVH*D)`` of the ``[L*P, ps, KVH*D]`` view (merging the two MAJOR
+dimensions moves nothing under the TPU's tiled layouts; merging the two
+minor ones, KVH and D, is a relayout of the pool — which is why the pool is
+stored merged); page_table [B, MP] int32 (trash-filled past each
 sequence's pages); positions [B] int32 (slot of the CURRENT token —
 slots > position are masked, so trash pages beyond the length are
 harmless).  Online softmax accumulates across the page grid axis in VMEM
@@ -30,7 +36,7 @@ from ...utils.platform import pallas_interpret
 NEG_INF = -1e30
 
 
-def _decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
+def _decode_kernel(pt_ref, pos_ref, layer_ref, q_ref, k_ref, v_ref, *rest,
                    ps, scale, kvh, quant, alibi):
     """One (sequence, page) grid step: every kv head of the page against
     its query group.  The page block is [ps, KVH*D] — head ``h`` is the
@@ -89,45 +95,62 @@ def _decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
 
 
 def paged_decode_attention(q, k_pool, v_pool, page_table, positions,
-                           k_scale=None, v_scale=None, alibi_slopes=None):
-    """q: [B, NH, D]; pools: [P, ps, KVH, D] (int8 codes when ``k_scale``/
-    ``v_scale`` [P, ps, KVH] given); page_table: [B, MP] int32;
-    positions: [B] int32; ``alibi_slopes``: optional [NH] per-head ALiBi
-    slopes (bias built in-kernel from slot indices).  Returns [B, NH, D]."""
+                           k_scale=None, v_scale=None, alibi_slopes=None,
+                           layer=None):
+    """q: [B, NH, D]; pools: the engine's ``[L, P, ps, KVH*D]`` read at
+    int32 scalar ``layer`` (int8 codes when ``k_scale``/``v_scale``
+    ``[L, P, ps, KVH]`` given), or with ``layer=None`` one layer's
+    ``[P, ps, KVH, D]`` (scales ``[P, ps, KVH]``); page_table: [B, MP]
+    int32; positions: [B] int32; ``alibi_slopes``: optional [NH] per-head
+    ALiBi slopes (bias built in-kernel from slot indices).
+    Returns [B, NH, D]."""
     B, NH, D = q.shape
-    P, ps, KVH, Dk = k_pool.shape
+    if layer is None:
+        # one layer's pool: merging KVH and D relayouts it, which is only
+        # acceptable because nothing on the serving path comes this way
+        P, ps = k_pool.shape[:2]
+        k_pool, v_pool = (x.reshape(1, P, ps, -1) for x in (k_pool, v_pool))
+        if k_scale is not None:
+            k_scale, v_scale = k_scale[None], v_scale[None]
+        layer = 0
+    L, P, ps, F = k_pool.shape
     MP = page_table.shape[1]
-    assert D == Dk and NH % KVH == 0
+    KVH = F // D
+    assert KVH * D == F and NH % KVH == 0
     quant = k_scale is not None
     G = NH // KVH
     scale = 1.0 / math.sqrt(D)
     qg = q.reshape(B, KVH, G, D)
 
     alibi = alibi_slopes is not None
-    q_spec = pl.BlockSpec((1, KVH, G, D), lambda b, jp, pt, pos: (b, 0, 0, 0))
-    # the page-table lookup: this block IS the page (all kv heads of it;
-    # the [P, ps, KVH*D] view of the pool is a free reshape)
-    page_spec = pl.BlockSpec((1, ps, KVH * D),
-                             lambda b, jp, pt, pos: (pt[b, jp], 0, 0))
+    q_spec = pl.BlockSpec((1, KVH, G, D),
+                          lambda b, jp, pt, pos, lyr: (b, 0, 0, 0))
+
+    # the layer and page-table lookup: this block IS the page (all kv
+    # heads of it), row layer * P + page of the [L*P, ps, ...] view
+    def page_index(b, jp, pt, pos, lyr):
+        return (lyr[0] * P + pt[b, jp], 0, 0)
+
+    page_spec = pl.BlockSpec((1, ps, F), page_index)
     in_specs = [q_spec, page_spec, page_spec]
-    args = [qg, k_pool.reshape(P, ps, KVH * D), v_pool.reshape(P, ps, KVH * D)]
+    args = [qg, k_pool.reshape(L * P, ps, F), v_pool.reshape(L * P, ps, F)]
     if alibi:
         # rides right after k/v so the kernel pops it off *rest first
         in_specs.append(pl.BlockSpec(
-            (KVH, G, 1), lambda b, jp, pt, pos: (0, 0, 0)))
+            (KVH, G, 1), lambda b, jp, pt, pos, lyr: (0, 0, 0)))
         args.append(jnp.asarray(alibi_slopes, jnp.float32)
                     .reshape(KVH, G, 1))
     if quant:
-        scale_spec = pl.BlockSpec((1, ps, KVH),
-                                  lambda b, jp, pt, pos: (pt[b, jp], 0, 0))
+        scale_spec = pl.BlockSpec((1, ps, KVH), page_index)
         in_specs += [scale_spec, scale_spec]
-        args += [k_scale, v_scale]
+        args += [k_scale.reshape(L * P, ps, KVH),
+                 v_scale.reshape(L * P, ps, KVH)]
 
     kernel = pl.pallas_call(
         functools.partial(_decode_kernel, ps=ps, scale=scale, kvh=KVH,
                           quant=quant, alibi=alibi),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(B, MP),
             in_specs=in_specs,
             out_specs=q_spec,
@@ -143,5 +166,6 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, positions,
         interpret=pallas_interpret(),
         name="dstpu_paged_decode",
     )
-    out = kernel(page_table, positions, *args)
+    out = kernel(page_table, positions,
+                 jnp.asarray(layer, jnp.int32).reshape(1), *args)
     return out.reshape(B, NH, D)

@@ -1,5 +1,11 @@
 """Paged decode attention kernel vs the XLA gather reference
-(reference tests: inference/v2 ragged_ops numeric parity)."""
+(reference tests: inference/v2 ragged_ops numeric parity).
+
+Each case runs twice: over one layer's ``[P, ps, KVH, D]`` pool against the
+gather formulation written out here, and ``layered`` — over the engine's
+``[L, P, ps, KVH*D]`` pools of three different layers, read at the middle
+one through the kernel's ``layer`` operand, against the decode program's
+own ``_gather_window_attend`` at that layer."""
 
 import math
 
@@ -8,7 +14,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deepspeed_tpu.inference.v2.model_runner import _gather_window_attend
+from deepspeed_tpu.models.transformer import TransformerConfig
 from deepspeed_tpu.ops.pallas.paged_attention import paged_decode_attention
+
+N_LAYERS, LAYER = 3, 1
 
 
 def _reference(q, k_pool, v_pool, page_table, positions):
@@ -27,8 +37,37 @@ def _reference(q, k_pool, v_pool, page_table, positions):
     return jnp.einsum("bns,bsnd->bnd", p, vv)
 
 
+def _layered(make, **scales):
+    """Engine-layout pools of N_LAYERS layers, each filled by ``make()``
+    (``[P, ps, KVH, D]``, KVH and D merged here as the engine stores
+    them), plus per-layer scales ``name=make_scale``."""
+    pools = {n: jnp.stack([x.reshape(*x.shape[:2], -1) for x in
+                           (make() for _ in range(N_LAYERS))])
+             for n in ("k", "v")}
+    pools.update({n: jnp.stack([mk() for _ in range(N_LAYERS)])
+                  for n, mk in scales.items()})
+    return pools
+
+
+def _attend_layer(q, pools, page_table, positions, kvh):
+    """(kernel, decode program's gather path) at layer LAYER of pools."""
+    B, NH, D = q.shape
+    S = page_table.shape[1] * pools["k"].shape[2]
+    cfg = TransformerConfig(hidden_size=NH * D, n_heads=NH, n_kv_heads=kvh,
+                            position="rope")
+    out = paged_decode_attention(
+        q, pools["k"], pools["v"], page_table, positions,
+        k_scale=pools.get("k_scale"), v_scale=pools.get("v_scale"),
+        layer=jnp.int32(LAYER))
+    vis = jnp.arange(S)[None, None, :] <= positions[:, None, None]
+    ref = _gather_window_attend(cfg, q[:, None], pools, LAYER, page_table,
+                                positions[:, None], vis)
+    return out, ref[:, 0].reshape(B, NH, D)
+
+
+@pytest.mark.parametrize("layered", [False, True], ids=["one", "layered"])
 @pytest.mark.parametrize("kvh", [4, 1, 2])
-def test_paged_decode_matches_gather(kvh):
+def test_paged_decode_matches_gather(kvh, layered):
     rng = np.random.RandomState(0)
     B, NH, D, ps, MP = 3, 4, 16, 8, 4
     P = B * MP + 1  # +1 trash
@@ -47,8 +86,13 @@ def test_paged_decode_matches_gather(kvh):
         n += used
     page_table = jnp.asarray(table, jnp.int32)
 
-    out = paged_decode_attention(q, k_pool, v_pool, page_table, positions)
-    ref = _reference(q, k_pool, v_pool, page_table, positions)
+    if layered:
+        pools = _layered(lambda: jnp.asarray(
+            rng.randn(P, ps, kvh, D), jnp.float32))
+        out, ref = _attend_layer(q, pools, page_table, positions, kvh)
+    else:
+        out = paged_decode_attention(q, k_pool, v_pool, page_table, positions)
+        ref = _reference(q, k_pool, v_pool, page_table, positions)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-4, atol=1e-5)
 
@@ -73,7 +117,8 @@ def test_paged_decode_trash_pages_ignored():
                                rtol=1e-5, atol=1e-6)
 
 
-def test_paged_decode_quantized_matches_dequant():
+@pytest.mark.parametrize("layered", [False, True], ids=["one", "layered"])
+def test_paged_decode_quantized_matches_dequant(layered):
     """Kernel dequant-in-VMEM path vs dequantize-then-gather reference."""
     rng = np.random.RandomState(2)
     B, NH, D, ps, MP, KVH = 2, 4, 16, 8, 3, 2
@@ -86,10 +131,18 @@ def test_paged_decode_quantized_matches_dequant():
     positions = jnp.asarray([10, 20], jnp.int32)
     table = jnp.asarray([[0, 1, 7], [2, 3, 4]], jnp.int32)
 
-    out = paged_decode_attention(q, codes_k, codes_v, table, positions,
-                                 k_scale=ks, v_scale=vs)
-    ref = _reference(q, codes_k.astype(jnp.float32) * ks[..., None],
-                     codes_v.astype(jnp.float32) * vs[..., None],
-                     table, positions)
+    if layered:
+        scale = lambda: jnp.asarray(  # noqa: E731
+            rng.rand(P, ps, KVH) * 0.05 + 0.01, jnp.float32)
+        pools = _layered(lambda: jnp.asarray(
+            rng.randint(-127, 128, (P, ps, KVH, D)), jnp.int8),
+            k_scale=scale, v_scale=scale)
+        out, ref = _attend_layer(q, pools, table, positions, KVH)
+    else:
+        out = paged_decode_attention(q, codes_k, codes_v, table, positions,
+                                     k_scale=ks, v_scale=vs)
+        ref = _reference(q, codes_k.astype(jnp.float32) * ks[..., None],
+                         codes_v.astype(jnp.float32) * vs[..., None],
+                         table, positions)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-4, atol=1e-5)

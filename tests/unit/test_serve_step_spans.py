@@ -128,8 +128,11 @@ def test_serve_step_holds_its_children_and_counts_what_step_returned(run):
         (top,) = [sp for sp in spans if sp.name == "serve_step"]
         assert top.cat == "serve"
         lo, hi = top.ts_us, top.ts_us + top.dur_us
+        # `serve_stall` is the watchdog's verdict on a step that has ended:
+        # recorded after `serve_step` closed, and on a loaded machine any
+        # of these millisecond steps may be rated one
         kids = [sp for sp in spans if sp.cat in STEP_CATS
-                and sp.name not in ("serve_step", "request")]
+                and sp.name not in ("serve_step", "request", "serve_stall")]
         assert all(lo <= sp.ts_us and sp.ts_us + sp.dur_us <= hi
                    for sp in kids)
         # device_wait lies inside the phase that dispatched what it awaits,

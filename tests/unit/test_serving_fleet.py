@@ -526,7 +526,8 @@ def test_kv_export_import_bit_identical_roundtrip(tiny_model, cache):
 
     dst = _engine(model, params, cache=cache)
     assert dst.import_sequence(bundle)
-    got = paged_gather_pages(dst._pools, dst._find_slotted(uid).pages)
+    got = paged_gather_pages(dst._pools, dst._find_slotted(uid).pages,
+                             dst.cfg.kv_heads)
     for leaf, arr in bundle.arrays.items():
         assert got[leaf].dtype == arr.dtype
         assert np.array_equal(got[leaf], arr), leaf
@@ -568,7 +569,8 @@ def test_kv_export_import_covers_copy_on_write_page(tiny_model):
 
     dst = _engine(model, params, cache=True)
     assert dst.import_sequence(bundle)
-    got = paged_gather_pages(dst._pools, dst._find_slotted(uid).pages)
+    got = paged_gather_pages(dst._pools, dst._find_slotted(uid).pages,
+                             dst.cfg.kv_heads)
     for leaf, arr in bundle.arrays.items():
         assert np.array_equal(got[leaf], arr), leaf
     src.release_sequence(uid)

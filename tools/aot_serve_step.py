@@ -1,0 +1,213 @@
+#!/usr/bin/env python3
+"""Compile a serving cell's decode and chunk programs for a v5e — with no chip.
+
+    python tools/aot_serve_step.py
+    python tools/aot_serve_step.py --num-pages 5632 --windows 32,256
+    python tools/aot_serve_step.py --config benchmark/configs/mistral-7b-serve.json
+
+Reads a serving configuration file of the benchmark (model widths, depth and
+the ``engine`` block: page size, ``num_pages``, ``max_seqs``, chunk), builds
+``InferenceEngineV2`` over abstract weights and pools, and compiles the
+engine's own jitted programs — ``_decode`` at ``max_seqs`` rows and
+``_prefill_chunk`` at each window bucket — against a ``v5e:2x2`` topology
+description, one device of it.  Printed per program: XLA's memory analysis
+(arguments, outputs, aliased, temporaries, total of 15.75 GiB), the Mosaic
+kernels in it, and every instruction of the optimized HLO that materializes
+an array of at least one layer's K pool: with the pools carried and donated
+these are the in-place scatters of the fresh K/V alone, and the pools' bytes
+are aliased input to output.  ``--num-pages`` asks what another pool size
+would cost.  Exit 1 when a program does not keep the pools in place.
+
+It compiles; it does not run.  No time or numeric result comes from here.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import re
+import sys
+import time
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+
+GIB = 2.0 ** 30
+_DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
+                "u16": 2, "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8,
+                "u64": 8}
+#: `%name = dtype[dims]{layout} opcode(` of an optimized-HLO instruction
+_INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\][^ ]* "
+                    r"([\w\-]+)\(")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{$")
+#: opcodes that name a buffer and move nothing
+_FREE = ("parameter", "get-tuple-element", "tuple", "while", "bitcast")
+
+
+def big_instructions(hlo: str, floor: int):
+    """(bytes, name, opcode, shape, in_place) of every instruction of the
+    optimized HLO that materializes an array of >= ``floor`` bytes:
+    instructions inside a fusion's body are left out (only the fusion's
+    result is a buffer), as are parameters, tuples, the loop and bitcasts.
+    ``in_place`` is set where libtpu recorded that the result shares its
+    operand's buffer (``aliasing_operands``: a scatter that writes a few
+    rows of the pool it was given)."""
+    fused = set(re.findall(r"kind=k\w+, calls=%([\w.\-]+)", hlo))
+    out, skip = [], False
+    for line in hlo.splitlines():
+        c = _COMPUTATION.match(line)
+        if c:
+            skip = c.group(1) in fused
+            continue
+        m = _INSTR.match(line)
+        if (skip or not m or m.group(2) not in _DTYPE_BYTES
+                or m.group(4) in _FREE):
+            continue
+        dims = [int(d) for d in m.group(3).split(",") if d]
+        nbytes = int(np.prod(dims, dtype=np.int64)) * _DTYPE_BYTES[m.group(2)]
+        if nbytes >= floor:
+            out.append((nbytes, m.group(1), m.group(4),
+                        f"{m.group(2)}[{m.group(3)}]",
+                        '"aliasing_operands":{"lists":[{' in line))
+    return out
+
+
+def abstract_engine(config: dict, engine_overrides: dict):
+    """``InferenceEngineV2`` of a benchmark serving config with no array
+    behind it: weights and pools are ``ShapeDtypeStruct`` leaves."""
+    import importlib
+
+    import deepspeed_tpu.runtime.precision as precision
+    import deepspeed_tpu.utils.platform as plat
+    from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
+                                            RaggedInferenceConfig)
+    from deepspeed_tpu.inference.v2 import ragged
+
+    plat.platform = lambda: "tpu"  # compiled kernels, not interpret mode
+    # what is compiled here cannot be loaded without a chip: keep it out of
+    # the cache the chip runs read
+    jax.config.update("jax_enable_compilation_cache", False)
+    ecfg = dict(config["engine"], **engine_overrides)
+    dtype = {"bf16": jnp.bfloat16, "fp32": jnp.float32}[ecfg["dtype"]]
+    family = importlib.import_module(
+        f"benchmark.families.{config['family']}")
+    model = family.build(
+        config, config["num_hidden_layers"],
+        ecfg["page_size"] * ecfg["max_pages_per_seq"], dtype)
+
+    init_params, init_pools = model.init_params, ragged.PagedKVCache.init
+    model.init_params = lambda key: jax.eval_shape(init_params, key)
+    ragged.PagedKVCache.init = staticmethod(
+        lambda *a, **kw: jax.eval_shape(lambda: init_pools(*a, **kw)))
+    precision.cast_tree = lambda tree, dt: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, dt if jnp.issubdtype(a.dtype, jnp.floating)
+            else a.dtype), tree)
+    return InferenceEngineV2(model, RaggedInferenceConfig(**ecfg))
+
+
+def report(name: str, lowered, pool_bytes: int, layer_pool_bytes: int) -> dict:
+    t0 = time.time()
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"{name}: compiled in {time.time() - t0:.1f} s")
+    print(f"  memory (XLA analysis): arguments "
+          f"{mem.argument_size_in_bytes / GIB:.2f} GiB, outputs "
+          f"{mem.output_size_in_bytes / GIB:.2f} GiB, aliased "
+          f"{mem.alias_size_in_bytes / GIB:.2f} GiB (pools "
+          f"{pool_bytes / GIB:.2f}), temporaries "
+          f"{mem.temp_size_in_bytes / GIB:.3f} GiB, total "
+          f"{total / GIB:.2f} GiB of 15.75")
+    print(f"  Mosaic kernels: "
+          f"{sorted(set(re.findall(r'dstpu_[a-z_]+', lowered.as_text())))}")
+    big = big_instructions(compiled.as_text(), layer_pool_bytes)
+    print(f"  instructions that materialize >= one layer's K pool "
+          f"({layer_pool_bytes / 1e6:.1f} MB): {len(big)}")
+    for nbytes, iname, op, shape, in_place in sorted(big, reverse=True):
+        print(f"    {nbytes / 1e6:9.1f} MB  {op:10s} {iname}  {shape}  "
+              + ("in place: shares its operand's buffer" if in_place
+                 else "A BUFFER OF ITS OWN"))
+    return {"temp": mem.temp_size_in_bytes, "alias": mem.alias_size_in_bytes,
+            "copied": [b for b in big if not b[4]]}
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--config", default=os.path.join(
+        REPO, "benchmark", "configs", "mistral-7b-serve.json"))
+    ap.add_argument("--num-pages", type=int, default=0,
+                    help="pool size to compile for, if not the file's")
+    ap.add_argument("--windows", default="",
+                    help="chunk-program window buckets in pages, a,b,...; "
+                    "default: the smallest that holds a chunk and "
+                    "max_pages_per_seq")
+    args = ap.parse_args()
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    with open(args.config) as f:
+        config = json.load(f)
+    engine = abstract_engine(
+        config, {"num_pages": args.num_pages} if args.num_pages else {})
+    device = topologies.get_topology_desc("v5e:2x2", "tpu").devices[0]
+    one_chip = SingleDeviceSharding(device)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params, pools = on_chip(engine.params), on_chip(engine._pools)
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree_util.tree_leaves(pools))
+    k = pools["k"]
+    layer_pool_bytes = k.size * k.dtype.itemsize // k.shape[0]
+    block, ps, C = engine.block, engine.block.page_size, engine._chunk
+    B, MP = block.max_seqs, block.max_pages_per_seq
+    print(f"aot_serve_step: {device.device_kind!r}, {config['name']}: "
+          f"{k.shape[0]} layers, pools "
+          f"{ {n: a.shape for n, a in pools.items()} } = "
+          f"{pool_bytes / GIB:.2f} GiB, weights "
+          f"{engine.param_bytes / GIB:.2f} GiB, max_seqs {B}, "
+          f"max_pages_per_seq {MP}, chunk {C}")
+
+    i32 = jnp.int32
+    key = arr((2,), jnp.uint32)
+    results = {"decode": report(
+        f"decode [{B} rows x {MP} pages]",
+        engine._decode.lower(params, pools, arr((B,), i32), arr((B,), i32),
+                             arr((B, MP), i32), arr((B,), jnp.bool_),
+                             arr((B,), jnp.float32), arr((B,), i32), key),
+        pool_bytes, layer_pool_bytes)}
+    windows = ([int(w) for w in args.windows.split(",")] if args.windows
+               else sorted({max(1, C // ps), MP}))
+    for w in windows:
+        results[f"chunk{w}"] = report(
+            f"chunk [{C} tokens, window {w} pages]",
+            engine._prefill_chunk.lower(
+                params, pools, arr((C,), i32), arr((C // ps,), i32),
+                arr((w,), i32), arr((), i32), arr((), i32)),
+            pool_bytes, layer_pool_bytes)
+    ok = all(r["temp"] < GIB and r["alias"] >= pool_bytes
+             and not r["copied"] for r in results.values())
+    print("aot_serve_step: " + (
+        "every program keeps the pools in place (temporaries under 1 GiB, "
+        "pools aliased, no pool-sized buffer but the pools)" if ok else
+        "NOT in place: see the temporaries, aliased bytes and instructions "
+        "above"))
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())
