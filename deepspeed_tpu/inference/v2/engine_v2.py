@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...models.layer_types import layers_of, state_leaves
 from ...models.transformer import TransformerConfig
 from ...runtime.config_utils import ConfigModel
 from ...telemetry import get_registry
@@ -45,7 +46,7 @@ from .model_runner import (pad_pages_pow2, paged_copy_page, paged_decode,
                            paged_scatter_pages, paged_verify, sample_tokens)
 from .ragged import (PRIORITY_NORMAL, BlockAllocator, KVBlockConfig,
                      KVPageBundle, PagedKVCache, PrefixCache, RejectedError,
-                     SequenceState)
+                     SequenceState, StateSlots)
 from .speculative import (SpeculativeConfig, build_proposer, longest_accepted)
 
 
@@ -260,6 +261,14 @@ class InferenceEngineV2:
             raise NotImplementedError(
                 "InferenceEngineV2 serves causal decoders; post_norm "
                 "(BERT-style encoder) models have no generative path")
+        if self.cfg.moe_experts > 0:
+            # serving never drops a token: capacity routing is a training
+            # device (set on this copy, like wq_bits below)
+            self.cfg.moe_drop_tokens = False
+        #: fixed-size per-sequence state some layer types keep beside pages
+        self._state = state_leaves(self.cfg)
+        if self._state:
+            self._refuse_with_state(proposer)
         block = self.config.block
         if block.num_pages < block.max_pages_per_seq:
             raise ValueError(
@@ -285,8 +294,14 @@ class InferenceEngineV2:
                 self.params, self.cfg.wq_bits, self.cfg.wq_group,
                 min_size=self.config.quant_min_size)
         self._pools = PagedKVCache.init(
-            self.cfg.n_layers, self.cfg.kv_heads, self.cfg.head_dim, block,
-            self.config.jnp_dtype, kv_quant=self.config.kv_quant)
+            layers_of(self.cfg, "attn"), self.cfg.kv_heads,
+            self.cfg.head_dim, block, self.config.jnp_dtype,
+            kv_quant=self.config.kv_quant, state=self._state,
+            counters=("moe_stats",) if self.cfg.moe_held_count else ())
+        self.state_slots = StateSlots(block.max_seqs if self._state else 0)
+        #: the expert share's counters as the device last reported them
+        #: (``moe_stats`` wraps at 2**32; the host adds differences)
+        self._moe_seen = np.zeros((4,), np.int64)
         self.block = block
         # A learned-position model cannot attend past its position table; cap
         # the paged window to the model's trained context.
@@ -332,7 +347,10 @@ class InferenceEngineV2:
                         "decode_host_syncs": 0, "decode_horizon_shrinks": 0,
                         "spec_proposed_tokens": 0, "spec_accepted_tokens": 0,
                         "spec_verify_calls": 0, "spec_rollback_pages": 0,
-                        "spec_fallback_requests": 0}
+                        "spec_fallback_requests": 0,
+                        "moe_local_picks": 0, "moe_experts_touched": 0,
+                        "moe_padded_rows": 0, "moe_layer_calls": 0,
+                        "state_slot_preemptions": 0}
         self._init_serving_metrics()
         self._uid = itertools.count()
         self._admit_counter = itertools.count()
@@ -464,6 +482,27 @@ class InferenceEngineV2:
             every_n_steps=self.config.timeline_every_n_steps,
             artifact_dir=self.config.timeline_artifact_dir)
         self._wire_memory_ledger()
+
+    def _refuse_with_state(self, proposer: Any) -> None:
+        """What a model with recurrent state cannot be served with: each
+        would answer wrongly (a cached or exported page says nothing of
+        the state that goes with it; a rejected draft cannot be rolled out
+        of a state), so each is refused here, by name."""
+        kinds = sorted(self._state)
+        if self.config.enable_prefix_cache:
+            raise ValueError(
+                f"enable_prefix_cache: this model keeps recurrent state "
+                f"({kinds}) and a cached page carries none of it; serve it "
+                "with the prefix cache off")
+        if self.config.prefill_chunk <= 0:
+            raise ValueError(
+                f"prefill_chunk 0: a model with recurrent state ({kinds}) "
+                "is prefilled through the chunk program, which carries the "
+                "state from chunk to chunk; set prefill_chunk > 0")
+        if proposer is not None or self.config.speculative.mode != "off":
+            raise ValueError(
+                f"speculative decoding: paged_verify cannot roll a rejected "
+                f"draft out of recurrent state ({kinds})")
 
     def _wire_memory_ledger(self) -> None:
         """Attach the serving engine's HBM residents to the process
@@ -662,6 +701,26 @@ class InferenceEngineV2:
             "deepspeed_tpu_serving_slo_tpot_violations_total",
             "finished requests whose mean inter-token time exceeded "
             "slo_tpot_s")
+        # the second kind of cache (state slots) and the expert share
+        self._m_state_slots = reg.gauge(
+            "deepspeed_tpu_serving_state_slots_in_use",
+            "state slots held by scheduled sequences (a model whose layers "
+            "keep recurrent state; 0 otherwise)")
+        self._m_state_preempt = reg.counter(
+            "deepspeed_tpu_serving_state_slot_preemptions_total",
+            "preemptions that dropped a sequence's recurrent state (its "
+            "re-prefill recomputes it)")
+        self._m_moe_picks = reg.counter(
+            "deepspeed_tpu_serving_moe_local_picks_total",
+            "router picks that landed on an expert this chip holds")
+        self._m_moe_touched = reg.counter(
+            "deepspeed_tpu_serving_moe_experts_touched_total",
+            "held experts with at least one pick, summed over expert-layer "
+            "calls (the weights a step has to read)")
+        self._m_moe_padded = reg.counter(
+            "deepspeed_tpu_serving_moe_padded_rows_total",
+            "rows of the sorted and padded buffer the grouped matmul ran "
+            "over (whole blocks per touched expert)")
         # last-published absolutes for the per-engine cache counters, so
         # the process-cumulative registry counters only receive deltas
         self._cache_pub = {"hits": 0, "misses": 0, "evictions": 0}
@@ -927,11 +986,26 @@ class InferenceEngineV2:
                            "unknown sequences have no KV pages to export)")
         return seq
 
+    def read_state(self, uid: int) -> Dict[str, np.ndarray]:
+        """The recurrent state an admitted sequence holds now, per state
+        leaf ``[layers that keep it, *per-sequence shape]`` (``{}`` for a
+        model that keeps only pages): a host copy of its slot, for a check
+        against a reference.  After ``m`` returned tokens the state has
+        taken in the prompt and the first ``m - 1`` of them."""
+        slot = self._find_slotted(uid).slot
+        # dstpu-lint: allow[host-sync] a checking aid, never on a serving path
+        return {name: np.asarray(self._pools[name][:, slot])
+                for name in self._state}
+
     def export_sequence(self, uid: int) -> KVPageBundle:
         """Serialize an admitted sequence's KV pages + scheduling state
         into a :class:`KVPageBundle` (host arrays, bit-exact).  The
         sequence KEEPS running here — callers release it only after a
         successful import elsewhere, so a failed handoff loses nothing."""
+        if self._state:
+            raise NotImplementedError(
+                "KVPageBundle export: a bundle holds pages, and this model "
+                f"keeps recurrent state too ({sorted(self._state)})")
         seq = self._find_slotted(uid)
         ps = self.block.page_size
         immutable = seq.prefilled // ps  # pages never written again
@@ -971,6 +1045,10 @@ class InferenceEngineV2:
         return bundle
 
     def _check_bundle(self, b: KVPageBundle) -> None:
+        if self._state:
+            raise ValueError(
+                "KVPageBundle import: a bundle holds pages, and this model "
+                f"keeps recurrent state too ({sorted(self._state)})")
         sig = (self.cfg.n_layers, self.cfg.kv_heads, self.cfg.head_dim)
         if tuple(b.model_sig) != sig:
             raise ValueError(f"bundle model_sig {tuple(b.model_sig)} != "
@@ -1102,7 +1180,7 @@ class InferenceEngineV2:
         seq = self._find_slotted(uid)
         self.allocator.free(seq.pages)
         self._page_table[seq.slot, :] = self.block.trash_page
-        self._slots[seq.slot] = None
+        self._release_slot(seq.slot)
         seq.slot, seq.pages = -1, []
         m = self._req_meta.pop(uid, None)
         if m is not None:
@@ -1344,7 +1422,7 @@ class InferenceEngineV2:
                 continue
             self.allocator.free(s.pages)
             self._page_table[i, :] = self.block.trash_page
-            self._slots[i] = None
+            self._release_slot(i)
             s.slot, s.pages = -1, []
             uids.append(s.uid)
         for uid in uids:
@@ -1379,7 +1457,11 @@ class InferenceEngineV2:
         so with caching on the "recompute" is mostly a table lookup."""
         self.allocator.free(seq.pages)
         self._page_table[seq.slot, :] = self.block.trash_page
-        self._slots[seq.slot] = None
+        self._release_slot(seq.slot)
+        if self._state:
+            # the state goes with the slot: the re-prefill recomputes it
+            self._dstats["state_slot_preemptions"] += 1
+            self._m_state_preempt.inc()
         seq.slot, seq.pages, seq.prefilled = -1, [], 0
         seq.page_keys, seq.registered_upto, seq.decode_entry = [], 0, False
         seq.cached_match, seq.match_gen, seq.match_evict_gen = None, -1, -1
@@ -1539,6 +1621,9 @@ class InferenceEngineV2:
                          **self._pool_occupancy())
             admitted.append(seq)
             self._slots[i] = seq
+            if self._state:
+                self.state_slots.claim(i, seq.uid)
+                self._m_state_slots.set(self.state_slots.in_use)
         self._publish_pool_gauges()
         return admitted
 
@@ -1596,10 +1681,17 @@ class InferenceEngineV2:
         p /= p.sum()
         return int(self._rng.choice(len(p), p=p))
 
+    def _release_slot(self, slot: int) -> None:
+        """Empty a decode row, and the state slot that is that row."""
+        self._slots[slot] = None
+        if self._state:
+            self.state_slots.release(slot)
+            self._m_state_slots.set(self.state_slots.in_use)
+
     def _retire(self, seq: SequenceState) -> None:
         self.allocator.free(seq.pages)
         self._page_table[seq.slot, :] = self.block.trash_page
-        self._slots[seq.slot] = None
+        self._release_slot(seq.slot)
         seq.slot, seq.pages, seq.done = -1, [], True
         self._spec_fallback_uids.discard(seq.uid)
         self._finish_request(seq)
@@ -1694,6 +1786,8 @@ class InferenceEngineV2:
         self._step_parts.add(("prefill_chunk", C, int(prev.shape[0])))
         args = (jnp.asarray(ids), jnp.asarray(rows), jnp.asarray(prev),
                 jnp.int32(start), jnp.int32(c_n))
+        if self._state:  # the state is carried in the sequence's slot
+            args += (jnp.int32(seq.slot),)
         with self._step_span("dispatch", parent="prefill"):
             logits, self._pools = self._prefill_chunk(
                 self.params, self._pools, *args)
@@ -1738,6 +1832,8 @@ class InferenceEngineV2:
                 # queue head (the decode-overlap call site won if it ran)
                 self._prefetch_restores()
                 counts["queue_len"] = len(self._queue)
+                if self._state:
+                    counts["state_slots_in_use"] = self.state_slots.in_use
                 step_attrs.update(counts)
         except Exception as e:
             dump_on_exception("engine_v2.step", e)
@@ -1942,7 +2038,7 @@ class InferenceEngineV2:
                     # K=1 decode path: [B] int32 tokens cross, never
                     # [B,vocab] logits; decode_horizon > 1 amortizes this
                     # to one [B,K] pull per horizon (_multi_decode)
-                    tokens = np.asarray(tokens)
+                    tokens, = self._pull(tokens)
             self._m_gen_tokens.inc(len(decode_seqs))
             self._m_invocations.inc()
             self._m_host_syncs.inc()
@@ -1975,6 +2071,33 @@ class InferenceEngineV2:
             return out
         with self._step_span("step_emit"):
             self._sync_cache_counters()
+        return out
+
+    def _pull(self, *arrays) -> List[np.ndarray]:
+        """Host copies of a decode call's results.  With an expert share
+        its counters (the pools' ``moe_stats``, 16 bytes, to which the
+        decode and chunk programs since the last pull added: picks on held
+        experts, held experts touched, rows the grouped matmul ran over,
+        expert-layer calls) come in the same ``device_get`` — every copy
+        starts before any is waited for — and their increase is noted on
+        the step."""
+        if "moe_stats" not in self._pools:
+            # dstpu-lint: allow[host-sync] the caller's designed sync
+            return [np.asarray(a) for a in arrays]
+        # dstpu-lint: allow[host-sync] the caller's designed sync
+        *out, now = jax.device_get((*arrays, self._pools["moe_stats"]))
+        now = now.astype(np.int64)
+        # dstpu-lint: allow[host-sync] ``now`` is a host array (and
+        # ``moe_stats`` wraps at 2**32)
+        delta = ((now - self._moe_seen) % (1 << 32)).tolist()
+        self._moe_seen = now
+        for name, d in zip(("moe_local_picks", "moe_experts_touched",
+                            "moe_padded_rows", "moe_layer_calls"), delta):
+            self._dstats[name] += d
+            self._step_counts[name] = d
+        self._m_moe_picks.inc(delta[0])
+        self._m_moe_touched.inc(delta[1])
+        self._m_moe_padded.inc(delta[2])
         return out
 
     def _decode_inputs(self, seqs: List[SequenceState]):
@@ -2102,7 +2225,7 @@ class InferenceEngineV2:
                 # [B,K] int32 tokens + [B] produced counts cross the link
                 # once per K tokens — the fused form of the per-step
                 # decode sync, amortized K-fold
-                toks, produced = np.asarray(toks), np.asarray(produced)
+                toks, produced = self._pull(toks, produced)
         t1 = time.perf_counter()
 
         # the scan ALWAYS executes k iterations (finished rows run
@@ -2382,6 +2505,10 @@ class InferenceEngineV2:
         after speculative rollback / migration / preemption churn."""
         self.allocator.assert_no_leaks(
             [s.pages for s in self._slots if s is not None])
+        if self._state:
+            self.state_slots.assert_no_leaks(
+                {i: s.uid for i, s in enumerate(self._slots)
+                 if s is not None})
 
     def reset_cache_stats(self) -> None:
         """Zero the counters (cache CONTENTS are kept) — benches call this

@@ -23,6 +23,16 @@ pools through the loop; a layer writes ``pool.at[l, ...]`` and reads
 boundary XLA updates the one buffer in place.  No operation of a serving
 program is pool-sized; the only reshapes between ``KVH*D`` and
 ``[KVH, D]`` are on the fresh K/V of a call and on a gathered window.
+
+A stack that is a repeated period of layer types (``models/layer_types``)
+is scanned period by period, a period's layers unrolled in the body.  Each
+program hands ``_scan_layers`` one ``layer_fn`` per mixer; ``l`` counts the
+layers *of that mixer*, which is the layer index of the pool leaves that
+mixer keeps: ``L`` of the K/V pools is the number of attention layers, and
+the state slots of the linear-attention layers (``kda_s`` ``[L_kda, slots+1,
+heads, V, K]`` float32, ``kda_conv`` ``[L_kda, slots+1, conv-1, 3*heads*D]``;
+slot = decode row, the last slot is the trash slot) ride in the same dict,
+donated and updated in place like the pages.
 """
 
 from __future__ import annotations
@@ -33,6 +43,7 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 
+from ...models.layer_types import period_types
 from ...models.transformer import (MODEL_AXIS, TransformerConfig, _mm,
                                    _norm, _repeat_kv, alibi_slopes,
                                    attn_qkv, logits_fn, mlp_block)
@@ -64,20 +75,43 @@ def _kv_quantize(x):
     return q, s.astype(jnp.float32)
 
 
-def _scan_layers(params, pools, x, layer_fn):
-    """The layer loop of every paged program: ``layer_fn(layer, l, x,
-    pools) -> (x, pools)`` over ``params["layers"]`` with the WHOLE pools
-    in the carry.  The pools are never a per-layer operand or a stacked
-    output of the scan — that form makes XLA slice a layer out, update
-    the slice and write it into a second pool-sized buffer — so with the
-    pools donated the writes land in the caller's buffer."""
-    n_layers = pools["k"].shape[0]
+def _scan_layers(cfg: TransformerConfig, params, pools, x, layer_fns):
+    """The layer loop of every paged program: ``layer_fns[mixer](layer, l,
+    x, pools) -> (x, pools, aux)`` over ``params["layers"]`` with the WHOLE
+    pools in the carry.  The pools are never a per-layer operand or a
+    stacked output of the scan — that form makes XLA slice a layer out,
+    update the slice and write it into a second pool-sized buffer — so with
+    the pools donated the writes land in the caller's buffer.
 
-    def body(carry, inputs):
-        return layer_fn(*inputs, *carry), None
+    The scan is over periods of layer types, a period's layers unrolled in
+    the body (a homogeneous stack is a period of one); ``l`` is the index
+    among the layers of that mixer.  ``aux`` is what the layer's
+    feed-forward part returned beside its output (``mlp_block``): for an
+    expert share its int32 ``[4]`` counters, which are added to the pools'
+    ``moe_stats`` leaf where the cache manager made one."""
+    types = period_types(cfg)
+    per = {t.mixer: sum(u.mixer == t.mixer for u in types) for t in types}
+    stack = params["layers"]
+
+    def period_body(carry, inputs):
+        layers, p = inputs
+        x, pools = carry
+        seen = dict.fromkeys(per, 0)
+        for layer, t in zip(layers, types):
+            m = t.mixer
+            # (a mixer that comes once a period counts periods: no index
+            # arithmetic, so a homogeneous stack lowers as it always has)
+            l = p if per[m] == 1 else p * per[m] + seen[m]
+            seen[m] += 1
+            x, pools, aux = layer_fns[m](layer, l, x, pools)
+            if "moe_stats" in pools:
+                pools = dict(pools, moe_stats=pools["moe_stats"] + aux)
+        return (x, pools), None
 
     (x, pools), _ = jax.lax.scan(
-        body, (x, pools), (params["layers"], jnp.arange(n_layers)))
+        period_body, (x, pools),
+        (stack if isinstance(stack, tuple) else (stack,),
+         jnp.arange(cfg.n_layers // len(types))))
     return x, pools
 
 
@@ -115,9 +149,10 @@ def _pool_window(pools, l, table, kv_heads):
 
 
 def _ffn(cfg: TransformerConfig, layer, x):
-    """mlp_block shared with the training forward; inference drops aux loss."""
-    out, _aux = mlp_block(cfg, layer, x, training=False)
-    return out
+    """mlp_block shared with the training forward -> (x, aux): inference has
+    no use for an auxiliary loss; an expert share's counters come in its
+    place (``_scan_layers``)."""
+    return mlp_block(cfg, layer, x, training=False)
 
 
 def _alibi_bias(cfg: TransformerConfig, qpos, kpos):
@@ -128,14 +163,77 @@ def _alibi_bias(cfg: TransformerConfig, qpos, kpos):
     return -alibi_slopes(cfg.n_heads)[:, None, None] * rel[..., None, :, :]
 
 
-def _attn_out(cfg: TransformerConfig, layer, x, attn):
+def _attn_out(cfg: TransformerConfig, layer, x, attn, pools):
     """Output projection + residual/parallel-block epilogue shared by the
-    prefill/chunk/decode scan bodies."""
+    prefill/chunk/decode scan bodies; returns what a layer_fn returns
+    (``_scan_layers``)."""
+    if "wg" in layer["attn"]:  # gated output: wo (attn * sigmoid(wg h))
+        h = _norm(x, layer["norm1"]["scale"], layer["norm1"].get("bias"),
+                  cfg.norm, cfg.norm_eps)
+        gate = jax.nn.sigmoid(_mm(cfg, h, layer["attn"]["wg"], None,
+                                  MODEL_AXIS).astype(jnp.float32))
+        attn = (attn.astype(jnp.float32) * gate).astype(attn.dtype)
     attn_delta = (_mm(cfg, attn, layer["attn"]["wo"], MODEL_AXIS, None)
                   + (layer["attn"]["bo"] if cfg.use_bias else 0))
     if cfg.parallel_block:
-        return _ffn(cfg, layer, x) + attn_delta
-    return _ffn(cfg, layer, x + attn_delta)
+        x, aux = _ffn(cfg, layer, x)
+        return x + attn_delta, pools, aux
+    x, aux = _ffn(cfg, layer, x + attn_delta)
+    return x, pools, aux
+
+
+def _kda_mix(cfg: TransformerConfig, layer, x, tail, valid, scan):
+    """``_kda_mixer`` under the ``kda`` scope (its operations carry the name
+    in a device trace), then the feed-forward part -> (x, aux, rows)."""
+    with jax.named_scope("kda"):
+        y, rows = _kda_mixer(cfg, layer, x, tail, valid, scan)
+    return (*_ffn(cfg, layer, x + y), rows)
+
+
+def _kda_mixer(cfg: TransformerConfig, layer, x, tail, valid, scan):
+    """The linear-attention mixer on ``x [R, T, H]``: ``R`` rows of ``T``
+    tokens each, ``tail [R, conv-1, 3*N]`` the rows of the q | k | v
+    projections that precede them, ``valid [R, T]`` which tokens are real.
+    ``scan(q, k, v, g, beta) -> o [R, T, NH, D]`` runs the recurrence (and
+    keeps the state).  Returns (the mixer's output ``y``, the whole
+    projection rows ``[R, conv-1+T, 3*N]`` for the caller to cut the next
+    tail from).  A token that is not valid gets ``g = 0`` and
+    ``beta = 0``, which leave the state as it was."""
+    f32 = jnp.float32
+    m = layer["kda"]
+    R, T, _ = x.shape
+    NH, D = cfg.kda_heads, cfg.kda_head_dim
+    h = _norm(x, layer["norm1"]["scale"], layer["norm1"].get("bias"),
+              cfg.norm, cfg.norm_eps)
+    pre = jnp.concatenate([h @ m["wq"], h @ m["wk"], h @ m["wv"]], axis=-1)
+    rows = jnp.concatenate([tail.astype(pre.dtype), pre], axis=1)
+    conv = sum(rows[:, j:j + T].astype(f32) * m["conv"][j].astype(f32)
+               for j in range(cfg.kda_conv))
+    q, k, v = jnp.split(jax.nn.silu(conv).reshape(R, T, 3 * NH, D), 3, axis=2)
+    q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-6) \
+        / math.sqrt(D)
+    k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
+    rate = jnp.exp(m["a_log"].astype(f32))[:, None]
+    g = -rate * jax.nn.softplus(
+        ((h @ m["f_down"]) @ m["f_up"]).astype(f32).reshape(R, T, NH, D)
+        + m["dt_bias"].astype(f32).reshape(NH, D))
+    beta = 2.0 * jax.nn.sigmoid((h @ m["w_beta"]).astype(f32))
+    g = jnp.where(valid[..., None, None], g, 0.0)
+    beta = jnp.where(valid[..., None], beta, 0.0)
+    o = scan(q, k, v, g, beta)  # float32 in, float32 out
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + cfg.norm_eps) \
+        * m["o_norm"].astype(f32)
+    gate = jax.nn.sigmoid(((h @ m["g_down"]) @ m["g_up"]).astype(f32))
+    return (o.reshape(R, T, NH * D) * gate).astype(x.dtype) @ m["wo"], rows
+
+
+def _no_mixer(program: str):
+    def refuse(*_a):
+        raise NotImplementedError(
+            f"{program} has no form of the linear-attention mixer: a model "
+            "with recurrent state is served through chunked prefill and the "
+            "decode program only")
+    return refuse
 
 
 def paged_prefill(cfg: TransformerConfig, params, pools,
@@ -192,9 +290,10 @@ def paged_prefill(cfg: TransformerConfig, params, pools,
             scores = jnp.where(causal, scores, -1e30)
             probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
             attn = jnp.einsum("bnts,bsnd->btnd", probs, vv).reshape(1, S, -1)
-        return _attn_out(cfg, layer, x, attn), pools
+        return _attn_out(cfg, layer, x, attn, pools)
 
-    x, pools = _scan_layers(params, pools, x, layer_fn)
+    x, pools = _scan_layers(cfg, params, pools, x, {
+        "attn": layer_fn, "kda": _no_mixer("whole-prompt prefill")})
     hidden = _norm(x[:, length - 1], params["final_norm"]["scale"],
                    params["final_norm"].get("bias"), cfg.norm, cfg.norm_eps)
     logits = logits_fn(cfg, params, hidden[:, None])[0, 0]
@@ -270,7 +369,7 @@ def paged_scatter_pages(pools, pages, arrays):
 
 
 def paged_prefill_chunk(cfg: TransformerConfig, params, pools,
-                        ids, chunk_rows, prev_table, start, n
+                        ids, chunk_rows, prev_table, start, n, slot=None
                         ) -> Tuple[jnp.ndarray, Any]:
     """Prefill ONE CHUNK of a prompt (FastGen Dynamic-SplitFuse-style
     chunked prefill, reference inference/v2 scheduler + blogs/deepspeed-
@@ -295,7 +394,9 @@ def paged_prefill_chunk(cfg: TransformerConfig, params, pools,
     window THROUGH this chunk (the kernel path reads the chunk's own
     keys from the pool; the caller buckets the length to power-of-two
     page counts so early chunks don't gather the full max window);
-    start: global position of ids[0]; n: valid tokens.
+    start: global position of ids[0]; n: valid tokens; slot: the
+    sequence's state slot (its decode row), for a model whose layers keep
+    recurrent state — carried from chunk to chunk there.
     Chunk queries attend to all previously-written positions (< start,
     via the page pool) plus causally within the chunk.  Returns (logits
     of token start+n-1 — meaningful on the FINAL chunk — and pools)."""
@@ -321,7 +422,8 @@ def paged_prefill_chunk(cfg: TransformerConfig, params, pools,
     # the chunk's OWN keys through the int8 round-trip while the fallback
     # (and whole-prompt prefill) attend fresh in-chunk keys — keeping the
     # chunked/whole divergence limited to the inherent cross-chunk case
-    use_flash = _use_paged_kernel() and not quant
+    use_kernel = _use_paged_kernel()
+    use_flash = use_kernel and not quant
 
     def layer_fn(layer, l, x, pools):
         q, k, v = attn_qkv(cfg, layer, x, positions)
@@ -344,7 +446,7 @@ def paged_prefill_chunk(cfg: TransformerConfig, params, pools,
                 alibi_slopes=(alibi_slopes(cfg.n_heads)
                               if cfg.position == "alibi" else None)
             ).reshape(1, C, -1)
-            return _attn_out(cfg, layer, x, attn), pools
+            return _attn_out(cfg, layer, x, attn, pools)
         # keys = [previous pooled slots | this chunk]; the pooled half is
         # masked to < start, the chunk half causally within the chunk
         kk = jnp.concatenate([kp.astype(x.dtype)[None], k], axis=1)
@@ -366,9 +468,36 @@ def paged_prefill_chunk(cfg: TransformerConfig, params, pools,
         scores = jnp.where(mask[None, None], scores, -1e30)
         probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
         attn = jnp.einsum("bnts,bsnd->btnd", probs, vv).reshape(1, C, -1)
-        return _attn_out(cfg, layer, x, attn), pools
+        return _attn_out(cfg, layer, x, attn, pools)
 
-    x, pools = _scan_layers(params, pools, x, layer_fn)
+    def kda_fn(layer, l, x, pools):
+        # the sequence's slot holds what its earlier chunks left; a chunk
+        # that starts the sequence starts from nothing (a slot is never
+        # zeroed by a program of its own)
+        from ...ops.pallas.kda import kda_chunk, kda_chunk_xla
+
+        keep = (start > 0).astype(jnp.float32)
+        st0 = pools["kda_s"][l, slot] * keep
+        tail = pools["kda_conv"][l, slot] * keep.astype(x.dtype)
+        scan_fn = kda_chunk if use_kernel else kda_chunk_xla
+        new = {}
+
+        def scan(q, k, v, g, beta):
+            o, new["s"] = scan_fn(q[0], k[0], v[0], g[0], beta[0], st0)
+            return o[None]
+
+        x, aux, rows = _kda_mix(cfg, layer, x, tail[None],
+                                (jnp.arange(C) < n)[None], scan)
+        # the rows of the last conv - 1 real tokens (reaching into the old
+        # tail where the chunk holds fewer)
+        tail = jax.lax.dynamic_slice_in_dim(rows[0], n, tail.shape[0], 0)
+        return x, dict(pools,
+                       kda_s=pools["kda_s"].at[l, slot].set(new["s"]),
+                       kda_conv=pools["kda_conv"].at[l, slot].set(
+                           tail.astype(pools["kda_conv"].dtype))), aux
+
+    x, pools = _scan_layers(cfg, params, pools, x,
+                            {"attn": layer_fn, "kda": kda_fn})
     hidden = _norm(x[:, n - 1], params["final_norm"]["scale"],
                    params["final_norm"].get("bias"), cfg.norm, cfg.norm_eps)
     logits = logits_fn(cfg, params, hidden[:, None])[0, 0]
@@ -462,9 +591,10 @@ def paged_verify(cfg: TransformerConfig, params, pools,
         pools = _pool_write(pools, l, (page_idx, off), k, v)
         attn = _gather_window_attend(cfg, q, pools, l, page_table, pos_w,
                                      vis)
-        return _attn_out(cfg, layer, x, attn), pools
+        return _attn_out(cfg, layer, x, attn, pools)
 
-    x, pools = _scan_layers(params, pools, x, layer_fn)
+    x, pools = _scan_layers(cfg, params, pools, x, {
+        "attn": layer_fn, "kda": _no_mixer("speculative verify")})
     hidden = _norm(x, params["final_norm"]["scale"],
                    params["final_norm"].get("bias"), cfg.norm, cfg.norm_eps)
     logits = logits_fn(cfg, params, hidden)  # [B, W, V]
@@ -532,9 +662,35 @@ def paged_decode(cfg: TransformerConfig, params, pools,
             attn = _gather_window_attend(cfg, q, pools, l, page_table,
                                          positions[:, None],
                                          vis[:, None, :])
-        return _attn_out(cfg, layer, x, attn), pools
+        return _attn_out(cfg, layer, x, attn, pools)
 
-    x, pools = _scan_layers(params, pools, x, layer_fn)
+    def kda_fn(layer, l, x, pools):
+        # row b's state is slot b; an inactive row's update goes to the
+        # trash slot, as its K/V goes to the trash page
+        from ...ops.pallas.kda import kda_step, kda_step_xla
+
+        trash_slot = pools["kda_s"].shape[1] - 1
+        dst = jnp.where(active, jnp.arange(B), trash_slot)
+        new = {}
+
+        def scan(q, k, v, g, beta):
+            if use_kernel:
+                o, new["s"] = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                       beta[:, 0], pools["kda_s"], l, dst)
+            else:
+                o, st = kda_step_xla(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                     beta[:, 0], pools["kda_s"][l, :B])
+                new["s"] = pools["kda_s"].at[l, dst].set(st)
+            return o[:, None]
+
+        x, aux, rows = _kda_mix(cfg, layer, x, pools["kda_conv"][l, :B],
+                                jnp.ones((B, 1), bool), scan)
+        return x, dict(pools, kda_s=new["s"],
+                       kda_conv=pools["kda_conv"].at[l, dst].set(
+                           rows[:, 1:].astype(pools["kda_conv"].dtype))), aux
+
+    x, pools = _scan_layers(cfg, params, pools, x,
+                            {"attn": layer_fn, "kda": kda_fn})
     hidden = _norm(x, params["final_norm"]["scale"],
                    params["final_norm"].get("bias"), cfg.norm, cfg.norm_eps)
     logits = logits_fn(cfg, params, hidden)[:, 0]

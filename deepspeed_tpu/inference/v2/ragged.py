@@ -12,6 +12,12 @@ Layout: ``k``/``v`` are ``[L, num_pages + 1, page_size, KVH, D]``.  The
 last page (index ``num_pages``) is the *trash page*: writes from inactive
 slots and pad positions are routed there, keeping every device-side
 scatter unconditional (no data-dependent control flow under jit).
+
+A model whose layers keep fixed-size recurrent state has a second kind of
+cache in the same manager: *state slots*, ``[L_state, max_seqs + 1, ...]``
+per leaf, one slot per decode row and a trash slot last (``StateSlots`` is
+the host's book of who holds which).  ``L`` of the page pool is then the
+number of layers that keep pages, not the model's depth.
 """
 
 from __future__ import annotations
@@ -573,16 +579,64 @@ class PagedKVCache:
     @staticmethod
     def init(n_layers: int, kv_heads: int, head_dim: int,
              block: KVBlockConfig, dtype=jnp.bfloat16,
-             kv_quant: bool = False) -> Dict[str, Any]:
+             kv_quant: bool = False,
+             state: Optional[Dict[str, Tuple[int, tuple, Any]]] = None,
+             counters: Sequence[str] = ()) -> Dict[str, Any]:
+        """``n_layers``: the layers that keep pages.  ``state``: ``{leaf:
+        (layers, per-sequence shape, dtype or None for ``dtype``)}`` — each
+        becomes ``[layers, max_seqs + 1, *shape]``, slot = decode row, the
+        last slot the trash slot.  ``counters``: int32 ``[4]`` leaves the
+        programs add to (read by the host, never reset on the device)."""
         shape = (n_layers, block.num_pages + 1, block.page_size,
                  kv_heads * head_dim)
         if kv_quant:
             sshape = shape[:-1] + (kv_heads,)
-            return {"k": jnp.zeros(shape, jnp.int8),
-                    "v": jnp.zeros(shape, jnp.int8),
-                    "k_scale": jnp.zeros(sshape, jnp.float32),
-                    "v_scale": jnp.zeros(sshape, jnp.float32)}
-        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+            pools = {"k": jnp.zeros(shape, jnp.int8),
+                     "v": jnp.zeros(shape, jnp.int8),
+                     "k_scale": jnp.zeros(sshape, jnp.float32),
+                     "v_scale": jnp.zeros(sshape, jnp.float32)}
+        else:
+            pools = {"k": jnp.zeros(shape, dtype),
+                     "v": jnp.zeros(shape, dtype)}
+        for name, (layers, sshape, sdtype) in (state or {}).items():
+            pools[name] = jnp.zeros((layers, block.max_seqs + 1, *sshape),
+                                    sdtype or dtype)
+        for name in counters:
+            pools[name] = jnp.zeros((4,), jnp.int32)
+        return pools
+
+
+class StateSlots:
+    """The host's book of the state slots: slot ``i`` belongs to decode row
+    ``i`` and is held by the sequence scheduled there.  A slot is never
+    zeroed on release: the chunk that starts a sequence starts from nothing
+    (``model_runner.paged_prefill_chunk``), so a preempted sequence's state
+    is dropped here and recomputed by its re-prefill."""
+
+    def __init__(self, n_slots: int):
+        self._owner: List[Optional[int]] = [None] * n_slots
+
+    @property
+    def in_use(self) -> int:
+        return sum(o is not None for o in self._owner)
+
+    def claim(self, slot: int, uid: int) -> None:
+        if self._owner[slot] is not None:
+            raise RuntimeError(f"state slot {slot} is held by uid "
+                               f"{self._owner[slot]}; uid {uid} cannot "
+                               "claim it")
+        self._owner[slot] = uid
+
+    def release(self, slot: int) -> None:
+        self._owner[slot] = None
+
+    def assert_no_leaks(self, live: Dict[int, int]) -> None:
+        """``live``: {slot: uid} of the sequences scheduled now.  Every held
+        slot must be one of them and every one of them must hold its slot."""
+        held = {i: o for i, o in enumerate(self._owner) if o is not None}
+        if held != live:
+            raise AssertionError(
+                f"state slots leaked or lost: held {held}, live {live}")
 
 
 @dataclasses.dataclass

@@ -87,6 +87,13 @@ class TransformerConfig:
     #: normalize_gate_probabilities); qwen2-moe ships norm_topk_prob=false
     moe_norm_topk: bool = True
     moe_drop_tokens: bool = True  # False => dropless sort+grouped-matmul path
+    #: this chip's share of an expert-parallel deployment (MoEConfig
+    #: held_first / held_count; 0 = every expert is held): the router keeps
+    #: ``moe_experts`` outputs, the expert weights hold ``moe_held_count``
+    moe_held_first: int = 0
+    moe_held_count: int = 0
+    #: False: the shared expert is added as it is, with no sigmoid gate
+    moe_shared_gate: bool = True
     #: EP dispatch: "auto" = explicit all-to-all shard_map when the mesh
     #: has an expert axis (moe/ep_dispatch.py); "spmd" = partitioner-driven
     moe_ep_dispatch: str = "auto"
@@ -145,6 +152,23 @@ class TransformerConfig:
     #: redundancy_clean): head_dim is then no longer hidden/n_heads
     head_dim_override: Optional[int] = None
 
+    #: the stack as a repeated *period* of layer types (models/layer_types.py:
+    #: each type defines its parameters, its mixer and the cache it keeps).
+    #: ``("attn",)`` is the homogeneous stack: ``params["layers"]`` one tree
+    #: stacked ``[n_layers, ...]``.  A longer period makes ``params["layers"]``
+    #: a tuple, one tree per position of the period, each stacked
+    #: ``[n_layers / len(period), ...]``
+    layer_period: Tuple[str, ...] = ("attn",)
+    #: attention output gate: ``wo (attn * sigmoid(wg h))``
+    attn_gate: bool = False
+    #: delta-rule linear-attention layers (type "kda"): heads x head_dim for
+    #: q, k and v alike, the causal depthwise convolution's kernel size and
+    #: the rank of the low-rank decay and gate projections
+    kda_heads: int = 0
+    kda_head_dim: int = 128
+    kda_conv: int = 4
+    kda_rank: int = 128
+
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
@@ -166,16 +190,15 @@ class TransformerConfig:
 # ---------------------------------------------------------------------------
 # init
 # ---------------------------------------------------------------------------
-def init_transformer_params(cfg: TransformerConfig, rng) -> Dict[str, Any]:
-    H, L = cfg.hidden_size, cfg.n_layers
-    D, NH, KVH = cfg.head_dim, cfg.n_heads, cfg.kv_heads
-    F, V = cfg.ffn_size, cfg.vocab_size
-    keys = jax.random.split(rng, 16)
-    dt = cfg.dtype
-    std = 0.02
+def _nrm(cfg: TransformerConfig, k, *shape, s=0.02):
+    return (jax.random.normal(k, shape) * s).astype(cfg.dtype)
 
-    def nrm(k, *shape, s=std):
-        return (jax.random.normal(k, shape) * s).astype(dt)
+
+def init_embed_head(cfg: TransformerConfig, keys) -> Dict[str, Any]:
+    """Everything outside the layers: embeddings, final norm, head."""
+    H, V = cfg.hidden_size, cfg.vocab_size
+    dt = cfg.dtype
+    nrm = functools.partial(_nrm, cfg)
 
     p: Dict[str, Any] = {
         "embed": {"tok": nrm(keys[0], V, H)},
@@ -200,28 +223,46 @@ def init_transformer_params(cfg: TransformerConfig, rng) -> Dict[str, Any]:
         p["embed"]["pos"] = nrm(keys[1], cfg.max_seq_len, H)
     if not cfg.tie_embeddings:
         p["lm_head"] = {"w": nrm(keys[2], H, V)}
+    return p
 
-    proj_out_std = std / math.sqrt(2 * L)
-    layers = {
-        "attn": {
+
+def init_layer_stack(cfg: TransformerConfig, keys, L: int,
+                     attn: bool = True) -> Dict[str, Any]:
+    """``L`` layers' parameters stacked ``[L, ...]``: norms, the feed-forward
+    part (dense or experts) and, with ``attn``, the attention projections
+    (a layer type with another mixer adds its own)."""
+    H = cfg.hidden_size
+    D, NH, KVH = cfg.head_dim, cfg.n_heads, cfg.kv_heads
+    F = cfg.ffn_size
+    dt = cfg.dtype
+    nrm = functools.partial(_nrm, cfg)
+
+    proj_out_std = 0.02 / math.sqrt(2 * cfg.n_layers)
+    layers: Dict[str, Any] = {
+        "mlp": {},
+        "norm1": {"scale": jnp.ones((L, H), dt)},
+    }
+    if attn:
+        layers["attn"] = {
             "wq": nrm(keys[3], L, H, NH * D),
             "wk": nrm(keys[4], L, H, KVH * D),
             "wv": nrm(keys[5], L, H, KVH * D),
             "wo": nrm(keys[6], L, NH * D, H, s=proj_out_std),
-        },
-        "mlp": {},
-        "norm1": {"scale": jnp.ones((L, H), dt)},
-    }
+        }
+        if cfg.attn_gate:
+            layers["attn"]["wg"] = nrm(jax.random.fold_in(keys[3], 1),
+                                       L, H, NH * D)
     # falcon-7b/phi share norm1 across both branches; falcon-40b-style
     # parallel blocks (parallel_norms=2) carry separate attn/mlp norms
     if not cfg.parallel_block or cfg.parallel_norms >= 2:
         layers["norm2"] = {"scale": jnp.ones((L, H), dt)}
     if cfg.moe_experts > 0:
         E = cfg.moe_experts
+        held = cfg.moe_held_count or E  # an expert share holds fewer
         layers["mlp"]["router"] = nrm(keys[7], L, H, E)
-        layers["mlp"]["w_gate"] = nrm(keys[8], L, E, H, F)
-        layers["mlp"]["w_up"] = nrm(keys[10], L, E, H, F)
-        layers["mlp"]["w_down"] = nrm(keys[9], L, E, F, H, s=proj_out_std)
+        layers["mlp"]["w_gate"] = nrm(keys[8], L, held, H, F)
+        layers["mlp"]["w_up"] = nrm(keys[10], L, held, H, F)
+        layers["mlp"]["w_down"] = nrm(keys[9], L, held, F, H, s=proj_out_std)
         if cfg.moe_use_residual:  # PR-MoE: dense residual MLP + mixer
             layers["mlp"]["res_w_up"] = nrm(keys[11], L, H, F)
             layers["mlp"]["res_w_down"] = nrm(keys[12], L, F, H, s=proj_out_std)
@@ -232,7 +273,8 @@ def init_transformer_params(cfg: TransformerConfig, rng) -> Dict[str, Any]:
             layers["mlp"]["shared_w_up"] = nrm(keys[14], L, H, Fs)
             layers["mlp"]["shared_w_down"] = nrm(keys[15], L, Fs, H,
                                                  s=proj_out_std)
-            layers["mlp"]["shared_gate"] = jnp.zeros((L, H, 1), dt)
+            if cfg.moe_shared_gate:
+                layers["mlp"]["shared_gate"] = jnp.zeros((L, H, 1), dt)
     elif cfg.activation == "swiglu":
         layers["mlp"]["w_gate"] = nrm(keys[7], L, H, F)
         layers["mlp"]["w_up"] = nrm(keys[8], L, H, F)
@@ -240,19 +282,37 @@ def init_transformer_params(cfg: TransformerConfig, rng) -> Dict[str, Any]:
     else:
         layers["mlp"]["w_up"] = nrm(keys[8], L, H, F)
         layers["mlp"]["w_down"] = nrm(keys[9], L, F, H, s=proj_out_std)
-    if cfg.use_bias or cfg.qkv_bias:
+    if attn and (cfg.use_bias or cfg.qkv_bias):
         layers["attn"]["bq"] = jnp.zeros((L, NH * D), dt)
         layers["attn"]["bk"] = jnp.zeros((L, KVH * D), dt)
         layers["attn"]["bv"] = jnp.zeros((L, KVH * D), dt)
     if cfg.use_bias:
-        layers["attn"]["bo"] = jnp.zeros((L, H), dt)
+        if attn:
+            layers["attn"]["bo"] = jnp.zeros((L, H), dt)
         layers["mlp"]["b_up"] = jnp.zeros((L, F), dt)
         layers["mlp"]["b_down"] = jnp.zeros((L, H), dt)
     if cfg.norm == "layernorm":
         layers["norm1"]["bias"] = jnp.zeros((L, H), dt)
         if "norm2" in layers:
             layers["norm2"]["bias"] = jnp.zeros((L, H), dt)
-    p["layers"] = layers
+    return layers
+
+
+def init_transformer_params(cfg: TransformerConfig, rng) -> Dict[str, Any]:
+    keys = jax.random.split(rng, 16)
+    p = init_embed_head(cfg, keys)
+    if len(cfg.layer_period) == 1:
+        p["layers"] = init_layer_stack(cfg, keys, cfg.n_layers)
+        return p
+    from .layer_types import layer_type
+
+    n, rem = divmod(cfg.n_layers, len(cfg.layer_period))
+    if rem:
+        raise ValueError(f"n_layers {cfg.n_layers} is not a whole number of "
+                         f"periods {cfg.layer_period}")
+    p["layers"] = tuple(
+        layer_type(kind).init(cfg, jax.random.fold_in(rng, 100 + j), n)
+        for j, kind in enumerate(cfg.layer_period))
     return p
 
 
@@ -530,6 +590,8 @@ def _ffn(cfg: TransformerConfig, layer, h, training: bool = True):
                             aux_loss_coef=cfg.moe_aux_coef,
                             drop_tokens=cfg.moe_drop_tokens,
                             norm_topk=cfg.moe_norm_topk,
+                            held_first=cfg.moe_held_first,
+                            held_count=cfg.moe_held_count,
                             ep_dispatch=cfg.moe_ep_dispatch,
                             ep_a2a_compression=cfg.moe_a2a_compression)
         moe_out, aux = moe_ffn(h, m["router"], m, moe_cfg,
@@ -542,9 +604,11 @@ def _ffn(cfg: TransformerConfig, layer, h, training: bool = True):
                 _mm(cfg, h, m["shared_w_gate"], None, MODEL_AXIS))
                 * _mm(cfg, h, m["shared_w_up"], None, MODEL_AXIS),
                 m["shared_w_down"], MODEL_AXIS, None)
-            sgate = jax.nn.sigmoid((h @ m["shared_gate"]).astype(jnp.float32))
-            moe_out = moe_out + (sgate * sh.astype(jnp.float32)).astype(
-                moe_out.dtype)
+            if cfg.moe_shared_gate:
+                sgate = jax.nn.sigmoid(
+                    (h @ m["shared_gate"]).astype(jnp.float32))
+                sh = (sgate * sh.astype(jnp.float32)).astype(moe_out.dtype)
+            moe_out = moe_out + sh
         if cfg.moe_use_residual:
             # PR-MoE (reference moe/layer.py use_residual): dense MLP beside
             # the MoE, mixed by a learned per-token 2-way coefficient

@@ -50,6 +50,14 @@ class MoEConfig:
     #: codes + block scales through comm/collectives, routing metadata
     #: stays exact (docs/COMM.md)
     ep_a2a_compression: Optional[Any] = None
+    #: this chip's share of an expert-parallel deployment: it holds experts
+    #: ``held_first .. held_first + held_count - 1`` of ``num_experts``
+    #: (``held_count`` 0 = all of them).  The router keeps its
+    #: ``num_experts`` outputs and ``top_k`` picks; the layer computes its
+    #: own experts' part of the result (``moe_ffn_share``) and nothing
+    #: stands in for the absent chips or their exchange
+    held_first: int = 0
+    held_count: int = 0
 
 
 def compute_capacity(tokens: int, cfg: MoEConfig, training: bool = True) -> int:
@@ -163,6 +171,24 @@ def _expert_ffn_blocks(xs, experts, block_expert, activation, block_rows):
     return gm(h, experts["w_down"])
 
 
+def _sorted_expert_ffn(xt, key, gate, top_k: int, n_experts: int, experts,
+                       activation: str, block_rows: int):
+    """The dropless tail: ``xt [T, H]`` tokens, ``key`` / ``gate``
+    ``[T * top_k]`` each pick's expert (``>= n_experts``: not computed here)
+    and weight.  Picks are sorted and padded by expert, run through the
+    grouped matmuls and added back to their tokens; an invalid pick is
+    scattered out of bounds (dropped) and gathered as zero."""
+    order, dest, n_rows, block_expert = sort_pad_by_expert(key, n_experts,
+                                                           block_rows)
+    token_of = order // top_k
+    xs = jnp.zeros((n_rows, xt.shape[1]), xt.dtype).at[dest].set(
+        xt[token_of], mode="drop")
+    ys = _expert_ffn_blocks(xs, experts, block_expert, activation, block_rows)
+    contrib = (ys.at[dest].get(mode="fill", fill_value=0)
+               * gate[order][:, None].astype(ys.dtype))
+    return jnp.zeros_like(xt).at[token_of].add(contrib.astype(xt.dtype))
+
+
 def moe_ffn_dropless(x: jnp.ndarray, gate_w: jnp.ndarray,
                      experts: Dict[str, jnp.ndarray], cfg: MoEConfig,
                      activation: str = "swiglu", rng=None,
@@ -182,17 +208,42 @@ def moe_ffn_dropless(x: jnp.ndarray, gate_w: jnp.ndarray,
     logits = xt @ gate_w
     _, expert_idx, gate_k, aux = _gate_and_aux(logits, cfg, rng)
 
-    flat_e = expert_idx.reshape(T * K)
-    flat_g = gate_k.reshape(T * K)
-    order, dest, n_rows, block_expert = sort_pad_by_expert(flat_e, E,
-                                                           block_rows)
-    token_of = order // K
-    xs = jnp.zeros((n_rows, H), x.dtype).at[dest].set(xt[token_of])
-
-    ys = _expert_ffn_blocks(xs, experts, block_expert, activation, block_rows)
-    contrib = ys[dest] * flat_g[order][:, None].astype(ys.dtype)
-    out = jnp.zeros((T, H), x.dtype).at[token_of].add(contrib.astype(x.dtype))
+    out = _sorted_expert_ffn(xt, expert_idx.reshape(T * K),
+                             gate_k.reshape(T * K), K, E, experts,
+                             activation, block_rows)
     return out.reshape(B, S, H), aux
+
+
+def moe_ffn_share(x: jnp.ndarray, gate_w: jnp.ndarray,
+                  experts: Dict[str, jnp.ndarray], cfg: MoEConfig,
+                  activation: str = "swiglu", block_rows: int = 128
+                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """One expert rank's part of the layer (``cfg.held_count`` experts from
+    ``cfg.held_first``; ``experts`` holds only those).  Routes over all
+    ``num_experts`` in float32, renormalises over the ``top_k`` picks, keeps
+    the picks that land on a held expert, sorts and pads them by expert and
+    runs the grouped matmul over the held experts.  No pick is dropped;
+    what the absent experts would add is left out (their chips add it).
+    Returns (the share's output, its counters: int32 ``[4]`` — picks that
+    landed on held experts, held experts touched, rows of the padded buffer
+    the grouped matmul ran over, 1 for the call)."""
+    B, S, H = x.shape
+    T, K = B * S, cfg.top_k
+    xt = x.reshape(T, H)
+    logits = jnp.dot(xt.astype(jnp.float32), gate_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    _, expert_idx, gate_k, _ = _gate_and_aux(logits, cfg)
+    local = expert_idx.reshape(T * K) - cfg.held_first
+    held = (local >= 0) & (local < cfg.held_count)
+    # a pick on an absent expert gets the invalid key
+    key = jnp.where(held, local, cfg.held_count)
+    out = _sorted_expert_ffn(xt, key, gate_k.reshape(T * K), K,
+                             cfg.held_count, experts, activation, block_rows)
+    counts = jnp.bincount(key, length=cfg.held_count + 1)[:-1]
+    stats = jnp.stack([jnp.sum(counts), jnp.sum(counts > 0),
+                       jnp.sum(-(-counts // block_rows)) * block_rows,
+                       jnp.ones((), counts.dtype)]).astype(jnp.int32)
+    return out.reshape(B, S, H), stats
 
 
 def moe_ffn(x: jnp.ndarray, gate_w: jnp.ndarray, experts: Dict[str, jnp.ndarray],
@@ -201,10 +252,21 @@ def moe_ffn(x: jnp.ndarray, gate_w: jnp.ndarray, experts: Dict[str, jnp.ndarray]
     """MoE feed-forward over [B, S, H] (reference MOELayer.forward).
 
     experts: stacked weights {w_gate/w_up: [E, H, F], w_down: [E, F, H]}
-    (w_gate only for swiglu).  Returns (out [B, S, H], aux_loss).
+    (w_gate only for swiglu).  Returns (out [B, S, H], aux_loss); an expert
+    share (``cfg.held_count``), which has no such loss, returns its
+    counters there (``moe_ffn_share``).
     """
     from .ep_dispatch import ep_dispatch_active, moe_ffn_ep
 
+    if cfg.held_count:
+        if cfg.drop_tokens or training:
+            raise NotImplementedError(
+                "an expert share (MoEConfig.held_count) is served dropless, "
+                "forward only: there is no capacity form of it and no "
+                "backward of grouped_matmul")
+        # a share has no auxiliary loss: its counters take the slot
+        with jax.named_scope("moe"):
+            return moe_ffn_share(x, gate_w, experts, cfg, activation)
     if ep_dispatch_active(cfg):
         out = moe_ffn_ep(x, gate_w, experts, cfg, activation=activation,
                          rng=rng, training=training)

@@ -4,6 +4,7 @@
     python tools/aot_serve_step.py
     python tools/aot_serve_step.py --num-pages 5632 --windows 32,256
     python tools/aot_serve_step.py --config benchmark/configs/mistral-7b-serve.json
+    python tools/aot_serve_step.py --config benchmark/configs/solar-open2-250b-ep8-serve.json
 
 Reads a serving configuration file of the benchmark (model widths, depth and
 the ``engine`` block: page size, ``num_pages``, ``max_seqs``, chunk), builds
@@ -15,8 +16,11 @@ description, one device of it.  Printed per program: XLA's memory analysis
 kernels in it, and every instruction of the optimized HLO that materializes
 an array of at least one layer's K pool: with the pools carried and donated
 these are the in-place scatters of the fresh K/V alone, and the pools' bytes
-are aliased input to output.  ``--num-pages`` asks what another pool size
-would cost.  Exit 1 when a program does not keep the pools in place.
+are aliased input to output.  The pools are every leaf the cache manager
+keeps: pages and, for a model whose layers keep recurrent state, the state
+slots ("pool-sized" is then one layer of the smallest large leaf).
+``--num-pages`` / ``--max-seqs`` ask what another size would cost.  Exit 1
+when a program does not keep the pools in place.
 
 It compiles; it does not run.  No time or numeric result comes from here.
 """
@@ -129,7 +133,7 @@ def report(name: str, lowered, pool_bytes: int, layer_pool_bytes: int) -> dict:
     print(f"  Mosaic kernels: "
           f"{sorted(set(re.findall(r'dstpu_[a-z_]+', lowered.as_text())))}")
     big = big_instructions(compiled.as_text(), layer_pool_bytes)
-    print(f"  instructions that materialize >= one layer's K pool "
+    print(f"  instructions that materialize >= one layer of a pool "
           f"({layer_pool_bytes / 1e6:.1f} MB): {len(big)}")
     for nbytes, iname, op, shape, in_place in sorted(big, reverse=True):
         print(f"    {nbytes / 1e6:9.1f} MB  {op:10s} {iname}  {shape}  "
@@ -145,6 +149,8 @@ def main() -> int:
         REPO, "benchmark", "configs", "mistral-7b-serve.json"))
     ap.add_argument("--num-pages", type=int, default=0,
                     help="pool size to compile for, if not the file's")
+    ap.add_argument("--max-seqs", type=int, default=0,
+                    help="decode rows (and state slots), if not the file's")
     ap.add_argument("--windows", default="",
                     help="chunk-program window buckets in pages, a,b,...; "
                     "default: the smallest that holds a chunk and "
@@ -156,8 +162,9 @@ def main() -> int:
 
     with open(args.config) as f:
         config = json.load(f)
-    engine = abstract_engine(
-        config, {"num_pages": args.num_pages} if args.num_pages else {})
+    engine = abstract_engine(config, {
+        k: v for k, v in (("num_pages", args.num_pages),
+                          ("max_seqs", args.max_seqs)) if v})
     device = topologies.get_topology_desc("v5e:2x2", "tpu").devices[0]
     one_chip = SingleDeviceSharding(device)
 
@@ -172,7 +179,11 @@ def main() -> int:
     pool_bytes = sum(a.size * a.dtype.itemsize
                      for a in jax.tree_util.tree_leaves(pools))
     k = pools["k"]
-    layer_pool_bytes = k.size * k.dtype.itemsize // k.shape[0]
+    # the floor for "pool-sized": one layer of the smallest large leaf (the
+    # K pool, or a layer's state slots where the model keeps state)
+    layer_pool_bytes = min(
+        a.size * a.dtype.itemsize // a.shape[0] for a in pools.values()
+        if a.size * a.dtype.itemsize >= 256 * 2 ** 20)
     block, ps, C = engine.block, engine.block.page_size, engine._chunk
     B, MP = block.max_seqs, block.max_pages_per_seq
     print(f"aot_serve_step: {device.device_kind!r}, {config['name']}: "
@@ -192,12 +203,14 @@ def main() -> int:
         pool_bytes, layer_pool_bytes)}
     windows = ([int(w) for w in args.windows.split(",")] if args.windows
                else sorted({max(1, C // ps), MP}))
+    # a model whose layers keep recurrent state is handed the sequence's slot
+    slot = (arr((), i32),) if engine._state else ()
     for w in windows:
         results[f"chunk{w}"] = report(
             f"chunk [{C} tokens, window {w} pages]",
             engine._prefill_chunk.lower(
                 params, pools, arr((C,), i32), arr((C // ps,), i32),
-                arr((w,), i32), arr((), i32), arr((), i32)),
+                arr((w,), i32), arr((), i32), arr((), i32), *slot),
             pool_bytes, layer_pool_bytes)
     ok = all(r["temp"] < GIB and r["alias"] >= pool_bytes
              and not r["copied"] for r in results.values())
