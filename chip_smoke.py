@@ -43,14 +43,14 @@ FULL = dict(size="7b", seq=4096, train_layers=1, serve_layers=4, steps=6,
             prompt_lens=(200, 1400, 650, 2000, 330, 1100, 1999, 480),
             new_tokens=32, max_seqs=4, pages_per_seq=128, chunk=512,
             offset_chunk=512, offset_window=2048, offset=1024,
-            decode_positions=(5, 700, 1999, 2047))
+            decode_positions=(5, 700, 1999, 2047), walk_table=(64, 256))
 #: rehearsal: same control flow at sizes the CPU interpreter finishes
 TINY = dict(size="tiny", seq=128, train_layers=2, serve_layers=2, steps=4,
             heads=4, kv_heads=2, head_dim=16,
             prompt_lens=(20, 70, 33, 100, 17, 55, 99, 24),
             new_tokens=6, max_seqs=4, pages_per_seq=8, chunk=32,
             offset_chunk=32, offset_window=128, offset=64,
-            decode_positions=(5, 40, 100, 127))
+            decode_positions=(5, 40, 100, 127), walk_table=(4, 16))
 
 #: Kernel-vs-reference tolerances: max |kernel - ref| over max |ref|, the
 #: reference computed in float32 at "highest" matmul precision from the same
@@ -198,6 +198,49 @@ def leg_kernels(sz, on_chip: bool) -> None:
         text = layered.lower(qd, k_pool, v_pool, table, pos).as_text()
         check("tpu_custom_call" in text and "dstpu_paged_decode" in text,
               "paged decode lowers to the Mosaic custom call")
+
+    # the kernel's time follows the pages the rows have, not the table: at
+    # the chat cell's table, 16 calls in one program (each call's output
+    # the next one's query), every row full / an eighth full / inactive
+    B, MP = sz["walk_table"]
+    k_pool, v_pool = (jax.random.normal(kk, (1, B * MP + 1, ps, KVH * D),
+                                        jnp.bfloat16) for kk in ks[4:6])
+    table = jax.random.permutation(ks[6], B * MP).reshape(B, MP)
+    table = table.astype(jnp.int32)
+    qd = jax.random.normal(ks[7], (B, NH, D), jnp.bfloat16)
+
+    @jax.jit
+    def walk(q_, k_, v_, pos_, act_):
+        return jax.lax.fori_loop(0, 16, lambda _, x: paged_decode_attention(
+            x, k_, v_, table, pos_, layer=0, active=act_), q_)
+
+    def walk_ms(tokens, active):
+        args = (qd, k_pool, v_pool, jnp.full((B,), tokens - 1, jnp.int32),
+                jnp.full((B,), active))
+        out = walk(*args).block_until_ready()
+        check(bool(jnp.all(jnp.isfinite(out.astype(f32)))) and
+              (active or not bool(jnp.any(out))),
+              f"paged decode over {tokens} tokens a row, rows "
+              f"{'active' if active else 'inactive'}: finite"
+              f"{'' if active else ' zeros'}")
+        times = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            walk(*args).block_until_ready()
+            times.append(time.perf_counter() - t0)
+        return sorted(times)[2] * 1e3 / 16
+
+    full, eighth, none = (walk_ms(MP * ps, True), walk_ms(MP * ps // 8, True),
+                          walk_ms(MP * ps, False))
+    if on_chip:  # a time off the chip says nothing
+        gbs = 2 * B * MP * ps * KVH * D * 2 / full / 1e6
+        print(f"  paged decode {B} x {MP} pages, a call: full {full:.3f} ms "
+              f"({gbs:.0f} GB/s of K and V), an eighth {eighth:.3f} ms, all "
+              f"inactive {none:.3f} ms", flush=True)
+        check(eighth < full / 3, f"rows an eighth full take {eighth / full:.3f}"
+              " of rows full < 1/3")
+        check(none < 0.05 * full, f"inactive rows take {none / full:.4f} of "
+              "rows full < 0.05")
 
 
 # ----------------------------------------------------------------- train

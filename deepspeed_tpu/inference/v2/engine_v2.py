@@ -28,6 +28,7 @@ import numpy as np
 
 from ...models.layer_types import layers_of, state_leaves
 from ...models.transformer import TransformerConfig
+from ...ops.pallas.paged_attention import n_blocks, pages_per_block
 from ...runtime.config_utils import ConfigModel
 from ...telemetry import get_registry
 from ...telemetry.compile_sentinel import RecompileSentinel, compile_counts
@@ -188,8 +189,8 @@ class RaggedRequest:
 
 
 #: what a ``serve_step`` span carries at its end
-_STEP_COUNTS = ("chunks", "prefill_tokens", "decode_rows", "admitted",
-                "preempted", "queue_len")
+_STEP_COUNTS = ("chunks", "prefill_tokens", "decode_rows",
+                "decode_kv_blocks", "admitted", "preempted", "queue_len")
 
 
 def _horizon_pages_needed(length: int, budget: int, page_size: int) -> int:
@@ -298,6 +299,11 @@ class InferenceEngineV2:
             self.cfg.head_dim, block, self.config.jnp_dtype,
             kv_quant=self.config.kv_quant, state=self._state,
             counters=("moe_stats",) if self.cfg.moe_held_count else ())
+        #: pages a block of the paged decode kernel holds, from the pool's
+        #: own geometry as the kernel takes it (``decode_kv_blocks``)
+        self._kv_block_pages = pages_per_block(
+            block.page_size, self._pools["k"].shape[-1],
+            self._pools["k"].dtype.itemsize)
         self.state_slots = StateSlots(block.max_seqs if self._state else 0)
         #: the expert share's counters as the device last reported them
         #: (``moe_stats`` wraps at 2**32; the host adds differences)
@@ -345,6 +351,7 @@ class InferenceEngineV2:
         # figure of merit — tokens per invocation
         self._dstats = {"decode_model_invocations": 0, "decode_tokens": 0,
                         "decode_host_syncs": 0, "decode_horizon_shrinks": 0,
+                        "decode_kv_blocks": 0,
                         "spec_proposed_tokens": 0, "spec_accepted_tokens": 0,
                         "spec_verify_calls": 0, "spec_rollback_pages": 0,
                         "spec_fallback_requests": 0,
@@ -701,6 +708,11 @@ class InferenceEngineV2:
             "deepspeed_tpu_serving_slo_tpot_violations_total",
             "finished requests whose mean inter-token time exceeded "
             "slo_tpot_s")
+        self._m_kv_blocks = reg.counter(
+            "deepspeed_tpu_serving_decode_kv_blocks_total",
+            "blocks of KV pages the paged decode kernel's loops walked, a "
+            "layer call (visible pages / (pages a block x this) is the "
+            "fill of a block)")
         # the second kind of cache (state slots) and the expert share
         self._m_state_slots = reg.gauge(
             "deepspeed_tpu_serving_state_slots_in_use",
@@ -2018,6 +2030,7 @@ class InferenceEngineV2:
             self._decode_steps += 1
             self._step_parts.add("decode")
             counts["decode_rows"] += len(decode_seqs)
+            self._note_kv_blocks(np.where(act, pos + 1, 0))
             with self._phase("decode", self._m_decode_h,
                              batch=len(decode_seqs)):
                 args = (jnp.asarray(last), jnp.asarray(pos),
@@ -2099,6 +2112,16 @@ class InferenceEngineV2:
         self._m_moe_touched.inc(delta[1])
         self._m_moe_padded.inc(delta[2])
         return out
+
+    def _note_kv_blocks(self, lengths: np.ndarray) -> None:
+        """``decode_kv_blocks``: the blocks the paged decode kernel walks
+        in one layer call over rows of ``lengths`` visible tokens (0 = the
+        row is not active) — the kernel's own ``n_blocks``."""
+        n = int(n_blocks(lengths, self.block.page_size,
+                         self._kv_block_pages).sum())
+        self._step_counts["decode_kv_blocks"] += n
+        self._dstats["decode_kv_blocks"] += n
+        self._m_kv_blocks.inc(n)
 
     def _decode_inputs(self, seqs: List[SequenceState]):
         """Dense ``[max_seqs]`` dispatch arrays for a decode-phase
@@ -2233,6 +2256,10 @@ class InferenceEngineV2:
         # is wall / k, not wall / produced — dividing by produced would
         # inflate the estimate on every stream tail
         per_step = (t1 - t0) / k
+        # row b ran its first produced[b] iterations active, one token
+        # longer each
+        t = np.arange(k)[:, None]
+        self._note_kv_blocks(np.where(t < produced, pos + 1 + t, 0))
         # EMA of per-token decode wall, the deadline clamp's estimate —
         # updated only from WARM dispatches: a dispatch that compiled
         # its horizon shape measures XLA compile time, not decode time

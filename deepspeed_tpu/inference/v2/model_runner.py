@@ -647,8 +647,9 @@ def paged_decode(cfg: TransformerConfig, params, pools,
         pools = _pool_write(pools, l, (page_idx, off), k[:, 0], v[:, 0])
         if use_kernel:
             # Pallas paged kernel: the whole pool goes in as it stands and
-            # the scalar-prefetched layer and table address each page in
-            # place — no [B, S, KVH, D] materialization (reference
+            # the kernel fetches each active row's live pages from it by
+            # the scalar-prefetched layer and table — no [B, S, KVH, D]
+            # materialization, nothing read for an inactive row (reference
             # ragged_ops decode kernels)
             from ...ops.pallas.paged_attention import paged_decode_attention
 
@@ -657,7 +658,7 @@ def paged_decode(cfg: TransformerConfig, params, pools,
                 k_scale=pools.get("k_scale"), v_scale=pools.get("v_scale"),
                 alibi_slopes=(alibi_slopes(cfg.n_heads)
                               if cfg.position == "alibi" else None),
-                layer=l).reshape(B, 1, -1)
+                layer=l, active=active).reshape(B, 1, -1)
         else:
             attn = _gather_window_attend(cfg, q, pools, l, page_table,
                                          positions[:, None],

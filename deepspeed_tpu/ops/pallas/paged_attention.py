@@ -2,23 +2,38 @@
 
 The TPU-native replacement for the reference's ragged decode kernels
 (``inference/v2/kernels/ragged_ops``): one query token per sequence
-attends over that sequence's KV *pages in place* — the layer and the page
-table are scalar-prefetch operands and each grid step's K/V block is
-addressed ``k_pool[layer, page_table[b, jp]]`` directly, so the padded
-[B, S, KVH, D] gather the XLA fallback materializes per layer per token
-never exists, and the serving programs hand the kernel the whole pool they
-carry without slicing a layer out of it.
+attends over that sequence's KV *pages in place*.  The pools stay in HBM
+as the engine carries them (``memory_space=pl.ANY``: no block, no
+per-layer slice) and the kernel walks **the pages a row has, not the
+table it was given**:
+
+- one grid step a few decode rows (``grid=(B // rows,)``, ``rows`` the
+  largest divisor of B up to 8), walked inside the kernel as ONE sequence
+  of (row, block) items: a ``fori_loop`` over the blocks of
+  ``nb = pages_per_block(...)`` pages (≈ 128 tokens) of the rows that have
+  any, ``n_blocks(length)`` a row — none for an inactive row (length 0),
+  which is never visited, reads nothing and returns zeros;
+- each item's live pages are fetched ``k_pool[layer, page_table[b, j]]``
+  → VMEM by explicit async copies, one a page, into a two-slot ring; the
+  next item's copies — the same row's next block, or the next active
+  row's first — are started before this item's are waited for; pages of a
+  row's last block past its length are not fetched (their V rows are
+  zeroed, their scores masked);
+- per kv head one ``[G, D] x [D, nb*ps]`` score matmul and one online
+  softmax update a block (float32 scores, statistics and accumulator; K/V
+  in the dtype stored).
+
+So device time grows with the visible pages and with nothing else: the
+table's width (``max_pages_per_seq``) costs nothing and ``max_seqs`` a
+grid step of no work for every ``rows`` empty decode slots.
 
 Layout: q [B, KVH, G, D] (GQA groups folded next to their kv head);
-pools [L, P, ps, KVH*D] as the engine stores them, read as page blocks
-``(1, ps, KVH*D)`` of the ``[L*P, ps, KVH*D]`` view (merging the two MAJOR
-dimensions moves nothing under the TPU's tiled layouts; merging the two
-minor ones, KVH and D, is a relayout of the pool — which is why the pool is
-stored merged); page_table [B, MP] int32 (trash-filled past each
-sequence's pages); positions [B] int32 (slot of the CURRENT token —
-slots > position are masked, so trash pages beyond the length are
-harmless).  Online softmax accumulates across the page grid axis in VMEM
-scratch; the output block is written on the last page step.
+pools [L, P, ps, KVH*D] as the engine stores them (KVH and D merged: head
+``h`` of a page is the lane slice ``[h*D, (h+1)*D)``, a tile-aligned view
+for D a multiple of 128); page_table [B, MP] int32 (entries past a row's
+pages are never read); positions [B] int32 (slot of the CURRENT token —
+slots > position are masked); ``active`` [B] bool (a row that is not
+active has length 0).
 """
 
 from __future__ import annotations
@@ -35,50 +50,132 @@ from ...utils.platform import pallas_interpret
 
 NEG_INF = -1e30
 
+#: tokens a block aims at (one MXU tile of keys) and the VMEM one slot of
+#: one pool may take; K and V double-buffered are four such slots
+_BLOCK_TOKENS = 128
+_SLOT_BYTES = 256 << 10
+#: decode rows a grid step walks (the largest divisor of B up to this)
+_ROWS_PER_STEP = 8
 
-def _decode_kernel(pt_ref, pos_ref, layer_ref, q_ref, k_ref, v_ref, *rest,
-                   ps, scale, kvh, quant, alibi):
-    """One (sequence, page) grid step: every kv head of the page against
-    its query group.  The page block is [ps, KVH*D] — head ``h`` is the
-    lane slice ``[h*D, (h+1)*D)``, a tile-aligned view (D a multiple of
-    128), where a per-head block ``(1, ps, 1, D)`` over the pool would put
-    a size-1 block on the second-minor (KVH) dim, which Mosaic refuses."""
+
+def pages_per_block(page_size: int, feat: int, itemsize: int) -> int:
+    """``nb``: pages the kernel fetches and attends at a time, from the
+    page geometry alone (``feat`` = KVH*D)."""
+    return max(1, min(_BLOCK_TOKENS // page_size,
+                      _SLOT_BYTES // (page_size * feat * itemsize)))
+
+
+def n_blocks(lengths, page_size: int, nb: int):
+    """Blocks the kernel's loop walks for rows of ``lengths`` visible
+    tokens (0 = inactive).  The kernel calls it on a row's scalar, the
+    engine on the step's numpy lengths (``decode_kv_blocks``)."""
+    return (lengths + (nb * page_size - 1)) // (nb * page_size)
+
+
+def _decode_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
+                   ps, nb, scale, kvh, quant, alibi):
+    """One grid step: ``rows`` decode rows, walked as ONE sequence of
+    (row, block) items over the live pages of the rows that have any, so
+    the copies of the next item — the same row's next block or the next
+    active row's first — are in flight while this one is attended."""
     rest = list(rest)
     sl_ref = rest.pop(0) if alibi else None
-    ks_ref, vs_ref = (rest.pop(0), rest.pop(0)) if quant else (None, None)
-    o_ref, m_scr, l_scr, acc_scr = rest
-    b, jp = pl.program_id(0), pl.program_id(1)
-    d = q_ref.shape[-1]
-    g = q_ref.shape[-2]
-    pos = pos_ref[b]
+    ks_hbm, vs_hbm = (rest.pop(0), rest.pop(0)) if quant else (None, None)
+    o_ref, m_scr, l_scr, acc_scr, k_buf, v_buf, sems, next_row = rest[:8]
+    ks_buf, vs_buf = rest[8:] if quant else (None, None)
+    rows, _, g, d = q_ref.shape
+    base = pl.program_id(0) * rows
+    T = nb * ps
+    layer = layer_ref[0]
+    # (page -> source, ring, semaphore column); the scales, one layer's
+    # already, ride their codes' semaphore
+    streams = [(lambda page: k_hbm.at[layer, page], k_buf, 0),
+               (lambda page: v_hbm.at[layer, page], v_buf, 1)]
+    if quant:
+        streams += [(lambda page: ks_hbm.at[page], ks_buf, 0),
+                    (lambda page: vs_hbm.at[page], vs_buf, 1)]
 
-    @pl.when(jp == 0)
-    def _():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    def length(r):
+        return jnp.minimum(len_ref[base + r], pt_ref.shape[1] * ps)
 
-    # pages wholly past the current token hold nothing visible (trash
-    # rows of the table all name one page, so they move no data either)
-    @pl.when(jp * ps <= pos)
+    # next_row[r]: the first row after r that has pages (``rows`` if none);
+    # ``row``: the first that has any; ``total``: the items of this step
+    row, total = jnp.int32(rows), jnp.int32(0)
+    for r in reversed(range(rows)):
+        next_row[r] = row
+        row = jnp.where(length(r) > 0, r, row)
+        total = total + n_blocks(length(r), ps, nb)
+
+    def block_dma(r, i, slot, wait):
+        """Start (or wait for) the copies of the live pages of row ``r``'s
+        block ``i`` into ring slot ``slot``."""
+        n_pages = (length(r) + ps - 1) // ps
+        for j in range(nb):
+            live = i * nb + j < n_pages
+
+            @pl.when(live)
+            def _():
+                page = pt_ref[base + r, i * nb + j]
+                for src, buf, s in streams:
+                    dma = pltpu.make_async_copy(
+                        src(page), buf.at[slot, j], sems.at[slot, s])
+                    dma.wait() if wait else dma.start()
+
+            if wait:
+                # a page never fetched holds whatever the slot held: its
+                # scores are masked, but 0 * NaN is NaN in p @ v
+                @pl.when(jnp.logical_not(live))
+                def _():
+                    v_buf[slot, j] = jnp.zeros(v_buf.shape[2:], v_buf.dtype)
+                    if quant:
+                        vs_buf[slot, j] = jnp.zeros(vs_buf.shape[2:],
+                                                    vs_buf.dtype)
+
+    # a row that has no pages is never visited
+    o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(total > 0)
     def _():
-        slots = jp * ps + jax.lax.broadcasted_iota(jnp.int32, (g, ps), 1)
+        block_dma(row, 0, 0, wait=False)
+
+    def item(n, carry):
+        r, i = carry
+        slot = jax.lax.rem(n, 2)
+        seq_len = length(r)
+        last = (i + 1) * T >= seq_len
+        r_next = jnp.where(last, next_row[r], r)
+        i_next = jnp.where(last, 0, i + 1)
+
+        @pl.when(n + 1 < total)
+        def _():
+            block_dma(r_next, i_next, 1 - slot, wait=False)
+
+        block_dma(r, i, slot, wait=True)
+
+        @pl.when(i == 0)
+        def _():
+            m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[...] = jnp.zeros_like(l_scr)
+            acc_scr[...] = jnp.zeros_like(acc_scr)
+
+        slots = i * T + jax.lax.broadcasted_iota(jnp.int32, (g, T), 1)
+        visible = slots < seq_len
         for h in range(kvh):
-            q = q_ref[0, h]                           # [G, D]
-            k = k_ref[0, :, h * d:(h + 1) * d]        # [ps, D]
-            v = v_ref[0, :, h * d:(h + 1) * d]
+            q = q_ref[r, h]                                       # [G, D]
+            k = k_buf[slot, :, :, h * d:(h + 1) * d].reshape(T, d)
+            v = v_buf[slot, :, :, h * d:(h + 1) * d].reshape(T, d)
             if quant:  # int8 codes * per-(slot, head) scale, in VMEM
-                k = (k.astype(jnp.float32)
-                     * ks_ref[0, :, h:h + 1]).astype(q.dtype)
-                v = (v.astype(jnp.float32)
-                     * vs_ref[0, :, h:h + 1]).astype(q.dtype)
+                k = (k.astype(jnp.float32) * ks_buf[
+                    slot, :, :, h:h + 1].reshape(T, 1)).astype(q.dtype)
+                v = (v.astype(jnp.float32) * vs_buf[
+                    slot, :, :, h:h + 1].reshape(T, 1)).astype(q.dtype)
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale  # [G, ps]
+                preferred_element_type=jnp.float32) * scale       # [G, T]
             if alibi:
                 # ALiBi distance penalty from page-slot indices (bloom)
-                s = s - sl_ref[h] * (pos - slots).astype(jnp.float32)
-            s = jnp.where(slots <= pos, s, NEG_INF)
+                s = s - sl_ref[h] * (seq_len - 1 - slots).astype(jnp.float32)
+            s = jnp.where(visible, s, NEG_INF)
             m_prev = m_scr[h]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
             p = jnp.exp(s - m_new)
@@ -88,22 +185,26 @@ def _decode_kernel(pt_ref, pos_ref, layer_ref, q_ref, k_ref, v_ref, *rest,
                 p.astype(v.dtype), v, preferred_element_type=jnp.float32)
             m_scr[h] = m_new
 
-    @pl.when(jp == pl.num_programs(1) - 1)
-    def _():
-        o_ref[0] = (acc_scr[...] /
-                    jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+        @pl.when(last)
+        def _():
+            o_ref[r] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
+
+        return r_next, i_next
+
+    jax.lax.fori_loop(0, total, item, (row, jnp.int32(0)))
 
 
 def paged_decode_attention(q, k_pool, v_pool, page_table, positions,
                            k_scale=None, v_scale=None, alibi_slopes=None,
-                           layer=None):
+                           layer=None, active=None):
     """q: [B, NH, D]; pools: the engine's ``[L, P, ps, KVH*D]`` read at
     int32 scalar ``layer`` (int8 codes when ``k_scale``/``v_scale``
     ``[L, P, ps, KVH]`` given), or with ``layer=None`` one layer's
     ``[P, ps, KVH, D]`` (scales ``[P, ps, KVH]``); page_table: [B, MP]
     int32; positions: [B] int32; ``alibi_slopes``: optional [NH] per-head
-    ALiBi slopes (bias built in-kernel from slot indices).
-    Returns [B, NH, D]."""
+    ALiBi slopes (bias built in-kernel from slot indices); ``active``:
+    optional [B] bool — a row that is not active attends nothing and
+    returns zeros.  Returns [B, NH, D]."""
     B, NH, D = q.shape
     if layer is None:
         # one layer's pool: merging KVH and D relayouts it, which is only
@@ -113,59 +214,66 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, positions,
         if k_scale is not None:
             k_scale, v_scale = k_scale[None], v_scale[None]
         layer = 0
-    L, P, ps, F = k_pool.shape
-    MP = page_table.shape[1]
+    ps, F = k_pool.shape[2:]
     KVH = F // D
     assert KVH * D == F and NH % KVH == 0
     quant = k_scale is not None
-    G = NH // KVH
-    scale = 1.0 / math.sqrt(D)
-    qg = q.reshape(B, KVH, G, D)
-
     alibi = alibi_slopes is not None
-    q_spec = pl.BlockSpec((1, KVH, G, D),
-                          lambda b, jp, pt, pos, lyr: (b, 0, 0, 0))
+    G = NH // KVH
+    nb = pages_per_block(ps, F, k_pool.dtype.itemsize)
+    rows = max(r for r in range(1, _ROWS_PER_STEP + 1) if B % r == 0)
+    lengths = positions.astype(jnp.int32) + 1
+    if active is not None:
+        lengths = jnp.where(active, lengths, 0)
 
-    # the layer and page-table lookup: this block IS the page (all kv
-    # heads of it), row layer * P + page of the [L*P, ps, ...] view
-    def page_index(b, jp, pt, pos, lyr):
-        return (lyr[0] * P + pt[b, jp], 0, 0)
-
-    page_spec = pl.BlockSpec((1, ps, F), page_index)
-    in_specs = [q_spec, page_spec, page_spec]
-    args = [qg, k_pool.reshape(L * P, ps, F), v_pool.reshape(L * P, ps, F)]
+    q_spec = pl.BlockSpec((rows, KVH, G, D), lambda b, *_: (b, 0, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [q_spec, hbm, hbm]
+    args = [q.reshape(B, KVH, G, D), k_pool, v_pool]
+    scratch = [
+        pltpu.VMEM((KVH, G, 1), jnp.float32),
+        pltpu.VMEM((KVH, G, 1), jnp.float32),
+        pltpu.VMEM((KVH, G, D), jnp.float32),
+        pltpu.VMEM((2, nb, ps, F), k_pool.dtype),
+        pltpu.VMEM((2, nb, ps, F), v_pool.dtype),
+        pltpu.SemaphoreType.DMA((2, 2)),
+        pltpu.SMEM((rows,), jnp.int32),
+    ]
     if alibi:
         # rides right after k/v so the kernel pops it off *rest first
-        in_specs.append(pl.BlockSpec(
-            (KVH, G, 1), lambda b, jp, pt, pos, lyr: (0, 0, 0)))
+        in_specs.append(pl.BlockSpec((KVH, G, 1), lambda b, *_: (0, 0, 0)))
         args.append(jnp.asarray(alibi_slopes, jnp.float32)
                     .reshape(KVH, G, 1))
     if quant:
-        scale_spec = pl.BlockSpec((1, ps, KVH), page_index)
-        in_specs += [scale_spec, scale_spec]
-        args += [k_scale.reshape(L * P, ps, KVH),
-                 v_scale.reshape(L * P, ps, KVH)]
+        # Mosaic cannot slice a page out of an HBM operand whose minor
+        # dimension (KVH) is under a lane tile, so the scales come as this
+        # layer's alone, padded to whole lanes: 1/L of what XLA's relayout
+        # of the unpadded operand moved (PERF.md, int8 scales)
+        lanes = KVH + -KVH % 128
+        scales = [jnp.pad(jax.lax.dynamic_index_in_dim(s, layer, 0, False),
+                          ((0, 0), (0, 0), (0, lanes - KVH)))
+                  for s in (k_scale, v_scale)]
+        in_specs += [hbm, hbm]
+        args += scales
+        scratch += [pltpu.VMEM((2, nb, ps, lanes), s.dtype) for s in scales]
 
     kernel = pl.pallas_call(
-        functools.partial(_decode_kernel, ps=ps, scale=scale, kvh=KVH,
-                          quant=quant, alibi=alibi),
+        functools.partial(_decode_kernel, ps=ps, nb=nb,
+                          scale=1.0 / math.sqrt(D), kvh=KVH, quant=quant,
+                          alibi=alibi),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(B, MP),
+            grid=(B // rows,),
             in_specs=in_specs,
             out_specs=q_spec,
-            scratch_shapes=[
-                pltpu.VMEM((KVH, G, 1), jnp.float32),
-                pltpu.VMEM((KVH, G, 1), jnp.float32),
-                pltpu.VMEM((KVH, G, D), jnp.float32),
-            ],
+            scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((B, KVH, G, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel",)),
         interpret=pallas_interpret(),
         name="dstpu_paged_decode",
     )
-    out = kernel(page_table, positions,
+    out = kernel(page_table, lengths,
                  jnp.asarray(layer, jnp.int32).reshape(1), *args)
     return out.reshape(B, NH, D)

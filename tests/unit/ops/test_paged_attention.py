@@ -5,7 +5,11 @@ Each case runs twice: over one layer's ``[P, ps, KVH, D]`` pool against the
 gather formulation written out here, and ``layered`` — over the engine's
 ``[L, P, ps, KVH*D]`` pools of three different layers, read at the middle
 one through the kernel's ``layer`` operand, against the decode program's
-own ``_gather_window_attend`` at that layer."""
+own ``_gather_window_attend`` at that layer.
+
+The kernel walks each row's live pages in blocks of ``pages_per_block``
+pages: the cases at the end hold lengths that straddle a block, an inactive
+row, and poison in every page no live length covers."""
 
 import math
 
@@ -15,8 +19,11 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.inference.v2.model_runner import _gather_window_attend
-from deepspeed_tpu.models.transformer import TransformerConfig
-from deepspeed_tpu.ops.pallas.paged_attention import paged_decode_attention
+from deepspeed_tpu.models.transformer import (TransformerConfig,
+                                              alibi_slopes)
+from deepspeed_tpu.ops.pallas.paged_attention import (n_blocks,
+                                                      pages_per_block,
+                                                      paged_decode_attention)
 
 N_LAYERS, LAYER = 3, 1
 
@@ -146,3 +153,106 @@ def test_paged_decode_quantized_matches_dequant(layered):
                          table, positions)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-4, atol=1e-5)
+
+
+# ------------------------------------------- the walk over a row's live pages
+WALK_PS, WALK_MP, WALK_KVH, WALK_D = 16, 20, 2, 16
+
+
+def _walk_case(rng, g, variant):
+    """Rows whose lengths straddle a block of the kernel's walk, one row
+    inactive, over scattered non-monotone page tables.  Table entries past
+    a row's live pages — all of the inactive row's — name POISON pages
+    (± 3e38 and NaN), as do the pool's unused pages.  Returns the kernel's
+    output over the poisoned pools and `_gather_window_attend` over the
+    same pools with the poison zeroed."""
+    ps, MP, KVH, D = WALK_PS, WALK_MP, WALK_KVH, WALK_D
+    quant, alibi = variant == "int8", variant == "alibi"
+    layered = variant != "one"
+    nb = pages_per_block(ps, KVH * D, 1 if quant else 4)
+    T = nb * ps
+    assert T < MP * ps  # the table holds more than two blocks
+    positions = np.asarray([0, T - 1, T, MP * ps - 1, 2 * T + 3, 37])
+    active = np.asarray([True, True, True, True, True, False])
+    B, NH = len(positions), KVH * g
+    n_live = np.where(active, positions // ps + 1, 0)
+    P = int(n_live.sum()) + 9  # live pages, 8 poison pages, the trash page
+    perm = rng.permutation(P - 1)
+    live, poison = perm[:n_live.sum()], perm[n_live.sum():]
+    table = rng.choice(poison, (B, MP))
+    n = 0
+    for b in range(B):
+        table[b, :n_live[b]] = live[n:n + n_live[b]]
+        n += n_live[b]
+    bad = np.asarray([3e38, -3e38, np.nan, 3e38, np.nan, -3e38, np.nan, 3e38],
+                     np.float32)
+
+    def pool(make, fill):
+        """(poisoned, clean) [L, P, ps, ...] pools of N_LAYERS layers."""
+        clean = np.stack([make() for _ in range(N_LAYERS if layered else 1)])
+        dirty = clean.copy()
+        shape = (1, len(poison)) + (1,) * (clean.ndim - 2)
+        clean[:, poison] = 0
+        dirty[:, poison] = fill.reshape(shape).astype(clean.dtype)
+        return jnp.asarray(dirty), jnp.asarray(clean)
+
+    if quant:
+        codes = lambda: rng.randint(-127, 128, (P, ps, KVH * D)).astype(  # noqa: E731
+            np.int8)
+        scale = lambda: (rng.rand(P, ps, KVH) * 0.05 + 0.01).astype(  # noqa: E731
+            np.float32)
+        names = {"k": (codes, np.full(8, 127)), "v": (codes, np.full(8, -127)),
+                 "k_scale": (scale, bad), "v_scale": (scale, bad)}
+    else:
+        vals = lambda: rng.randn(P, ps, KVH * D).astype(np.float32)  # noqa: E731
+        names = {"k": (vals, bad), "v": (vals, bad[::-1])}
+    dirty, clean = {}, {}
+    for name, (make, fill) in names.items():
+        dirty[name], clean[name] = pool(make, fill)
+
+    q = jnp.asarray(rng.randn(B, NH, D), jnp.float32)
+    table = jnp.asarray(table, jnp.int32)
+    pos = jnp.asarray(positions, jnp.int32)
+    cfg = TransformerConfig(hidden_size=NH * D, n_heads=NH, n_kv_heads=KVH,
+                            position="alibi" if alibi else "rope")
+    slopes = alibi_slopes(NH) if alibi else None
+    lyr = LAYER if layered else 0
+    if layered:
+        out = paged_decode_attention(
+            q, dirty["k"], dirty["v"], table, pos,
+            k_scale=dirty.get("k_scale"), v_scale=dirty.get("v_scale"),
+            alibi_slopes=slopes, layer=jnp.int32(lyr),
+            active=jnp.asarray(active))
+    else:  # one layer's [P, ps, KVH, D] pool
+        out = paged_decode_attention(
+            q, dirty["k"][0].reshape(P, ps, KVH, D),
+            dirty["v"][0].reshape(P, ps, KVH, D), table, pos,
+            active=jnp.asarray(active))
+    vis = jnp.arange(MP * ps)[None, None, :] <= pos[:, None, None]
+    ref = _gather_window_attend(cfg, q[:, None], clean, lyr, table,
+                                pos[:, None], vis)
+    return np.asarray(out), np.asarray(ref[:, 0].reshape(B, NH, D)), active
+
+
+@pytest.mark.parametrize("variant", ["layered", "one", "int8", "alibi"])
+@pytest.mark.parametrize("g", [1, 4, 8])
+def test_paged_decode_walks_the_live_pages_only(g, variant):
+    """Positions 0, nb*ps - 1, nb*ps, MP*ps - 1 and mid-block against the
+    decode program's gather path; whatever the table names past a row's
+    length is never read, and an inactive row is finite zeros."""
+    out, ref, active = _walk_case(np.random.RandomState(7 + g), g, variant)
+    assert np.all(np.isfinite(out))
+    np.testing.assert_allclose(out[active], ref[active], rtol=1e-4,
+                               atol=1e-5)
+    assert not out[~active].any()
+
+
+def test_n_blocks_is_the_kernels_loop_bound():
+    """The host's `decode_kv_blocks` and the kernel's loop share one
+    function; block size follows the page geometry alone."""
+    assert pages_per_block(16, 8 * 128, 2) == 8      # bf16 Mistral / Solar
+    assert pages_per_block(16, 8 * 128, 1) == 8      # int8: 128 tokens bind
+    assert pages_per_block(16, 32 * 128, 2) == 2     # MHA: the slot binds
+    assert pages_per_block(256, 8 * 128, 2) == 1
+    lengths = np.asarray([0, 1, 127, 128, 129, 4096])
+    assert n_blocks(lengths, 16, 8).tolist() == [0, 1, 1, 1, 2, 32]

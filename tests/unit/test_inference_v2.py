@@ -33,7 +33,7 @@ def test_block_allocator():
 def _dense_greedy(model, params, prompt, n_new):
     """Reference generation through the dense cache path."""
     cfg = model.config
-    cache = init_kv_cache(cfg, 1, 256, jnp.float32)
+    cache = init_kv_cache(cfg, 1, cfg.max_seq_len, jnp.float32)
     ids = jnp.asarray(np.array(prompt)[None], jnp.int32)
     logits, cache = forward_with_cache(cfg, params, ids,
                                        cache, jnp.zeros((1,), jnp.int32))
@@ -59,20 +59,40 @@ def test_paged_matches_dense_single():
     assert got[0] == want, (got, want)
 
 
-def test_paged_kernel_path_matches_dense(monkeypatch):
+@pytest.mark.parametrize("horizon", [1, 4])
+def test_paged_kernel_path_matches_dense(horizon, monkeypatch):
     """Same oracle with the Pallas paged-decode kernel forced on
-    (interpret mode on CPU) — the TPU hot path, token-for-token."""
-    monkeypatch.setenv("DSTPU_PAGED_KERNEL", "1")
-    model = llama_model("tiny", max_seq_len=256)
+    (interpret mode on CPU) — the TPU hot path, token-for-token, and the
+    XLA gather path's stream beside it: a mixed batch in which half the
+    decode slots stay empty, the rows span one, two and three blocks of
+    the kernel's walk (128 tokens at this page geometry), one row crosses
+    a block while decoding and the rows finish at different steps — under
+    the fused horizon, mid-scan."""
+    model = llama_model("tiny", max_seq_len=384)
     params = model.init_params(jax.random.PRNGKey(0))
-    prompt = list(np.random.RandomState(5).randint(0, model.config.vocab_size, 13))
-    want = _dense_greedy(model, params, prompt, 8)
+    rng = np.random.RandomState(5)
+    prompts = [list(rng.randint(0, model.config.vocab_size, n))
+               for n in (13, 125, 262)]
+    n_new = (8, 6, 7)
+    wants = [_dense_greedy(model, params, p, n)
+             for p, n in zip(prompts, n_new)]
 
-    eng = InferenceEngineV2(model, RaggedInferenceConfig(
-        dtype="fp32", page_size=8, num_pages=32, max_seqs=2,
-        max_pages_per_seq=8), params=params)
-    got = eng.generate_all([RaggedRequest(prompt_ids=prompt, max_new_tokens=8)])
-    assert got[0] == want, (got, want)
+    def serve(kernel):
+        monkeypatch.setenv("DSTPU_PAGED_KERNEL", kernel)
+        eng = InferenceEngineV2(model, RaggedInferenceConfig(
+            dtype="fp32", page_size=8, num_pages=96, max_seqs=6,
+            max_pages_per_seq=40, prefill_chunk=64, decode_horizon=horizon),
+            params=params)
+        got = eng.generate_all(
+            [RaggedRequest(prompt_ids=p, max_new_tokens=n)
+             for p, n in zip(prompts, n_new)])
+        assert eng.decode_stats()["decode_kv_blocks"] == \
+            7 * 1 + (3 * 1 + 2 * 2) + 6 * 3
+        return [got[u] for u in range(3)]
+
+    got = serve("1")
+    assert got == serve("0")
+    assert got == wants, (got, wants)
 
 
 def test_continuous_batching_mixed_lengths():

@@ -172,6 +172,50 @@ def test_serve_step_holds_its_children_and_counts_what_step_returned(run):
     assert len(first_tokens) == 3
 
 
+@pytest.mark.parametrize("horizon", [1, 4])
+def test_serve_step_counts_the_blocks_the_paged_kernel_walks(ring, registry,
+                                                             horizon):
+    """`decode_kv_blocks` is the kernel module's own `n_blocks` summed over
+    the rows (and, fused, the iterations) the step decoded: rows of one,
+    two and three blocks, one of them crossing a block boundary."""
+    from deepspeed_tpu.ops.pallas.paged_attention import (n_blocks,
+                                                          pages_per_block)
+
+    cfg = dict(dtype="fp32", page_size=8, num_pages=128, max_seqs=4,
+               max_pages_per_seq=48, prefill_chunk=64, decode_horizon=horizon)
+    model = llama_model("tiny", max_seq_len=384)
+    eng = InferenceEngineV2(model, RaggedInferenceConfig(**cfg), seed=0)
+    pool = eng._pools["k"]
+    nb = pages_per_block(8, pool.shape[-1], pool.dtype.itemsize)
+    assert eng._kv_block_pages == nb and nb * 8 == 128
+    lengths = (9, 125, 260)
+    prompts = _prompts(model, lengths)
+    steps = _run(eng, ring, prompts, new_tokens=7)
+    have = dict(enumerate(lengths))  # uids count from 0 in put() order
+    total = 0
+    for _sid, out, spans, _q in steps:
+        (top,) = [sp for sp in spans if sp.name == "serve_step"]
+        firsts = {sp.attrs["uid"] for sp in spans if sp.name == "device_wait"
+                  and sp.attrs["what"] == "first_token"}
+        want = 0
+        for uid, o in out.items():
+            first = int(uid in firsts)
+            # the t-th decoded token attends have + first + t tokens
+            want += sum(int(n_blocks(have[uid] + first + t, 8, nb))
+                        for t in range(len(o["tokens"]) - first))
+            have[uid] += len(o["tokens"])
+        assert top.attrs["decode_kv_blocks"] == want
+        assert want >= top.attrs["decode_rows"]
+        total += want
+    # 6 decoded tokens a request over 1 block, 1 then 2 (the row of 125 + 1
+    # tokens passes 128 after its third) and 3 blocks
+    assert total == 6 * 1 + (3 * 1 + 3 * 2) + 6 * 3
+    assert eng.decode_stats()["decode_kv_blocks"] == total
+    assert registry.get(
+        "deepspeed_tpu_serving_decode_kv_blocks_total").value() == total
+    eng.close()
+
+
 def test_device_wait_once_per_pull_and_once_per_first_token(run):
     _name, _eng, steps = run
     for _sid, out, spans, queue_len in steps:

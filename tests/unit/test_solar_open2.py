@@ -36,9 +36,9 @@ DESC = dict(hidden_size=64, num_attention_heads=4, num_key_value_heads=2,
             num_experts_per_tok=4, norm_topk_prob=True)
 
 
-def _engine(seed=0, **over):
+def _engine(seed=0, max_seq_len=128, **over):
     model = solar_open2_model("tiny", moe_held_first=FIRST,
-                              moe_held_count=HELD, max_seq_len=128)
+                              moe_held_count=HELD, max_seq_len=max_seq_len)
     cfg = dict(dtype="fp32", page_size=8, max_pages_per_seq=16,
                prefill_chunk=16, max_seqs=4, num_pages=80)
     cfg.update(over)
@@ -61,25 +61,37 @@ def _regrets(eng, prompt, toks):
             for row, t in zip(ref[len(prompt) - 1:], toks)]
 
 
+@pytest.mark.parametrize("horizon", [1, 4])
 @pytest.mark.parametrize("kernels", ["xla", "interpreted"])
-def test_engine_prefill_then_decode_matches_the_reference(kernels,
+def test_engine_prefill_then_decode_matches_the_reference(kernels, horizon,
                                                           monkeypatch):
     """put / step against the float32 reference: a prompt over three chunks
-    (state crosses two chunk boundaries), a shorter one in the same batch,
-    nine greedy tokens each; every token the reference's own argmax."""
+    (state crosses two chunk boundaries), a shorter one and one of two
+    blocks of the paged kernel's walk (128 tokens here) in the same batch,
+    one decode slot left empty; nine, six and seven greedy tokens, so the
+    rows retire at different steps (mid-scan under the fused horizon);
+    every token the reference's own argmax."""
     if kernels == "interpreted":
         monkeypatch.setenv("DSTPU_PAGED_KERNEL", "1")
-    eng = _engine()
+    eng = _engine(max_seq_len=192, max_pages_per_seq=24, num_pages=96,
+                  decode_horizon=horizon)
     rng = np.random.default_rng(0)
-    prompts = [rng.integers(0, 256, n).tolist() for n in (40, 13)]
-    outs = _serve(eng, prompts, 9)
-    for prompt, toks in zip(prompts, outs):
-        assert len(toks) == 9
-        assert max(_regrets(eng, prompt, toks)) == 0.0
+    prompts = [rng.integers(0, 256, n).tolist() for n in (40, 13, 133)]
+    n_new = (9, 6, 7)
+    uids = [eng.put(RaggedRequest(prompt_ids=p, max_new_tokens=n))
+            for p, n in zip(prompts, n_new)]
+    got = {u: [] for u in uids}
+    while eng.has_work():
+        for u, o in eng.step().items():
+            got[u] += o["tokens"]
+    for u, prompt, n in zip(uids, prompts, n_new):
+        assert len(got[u]) == n
+        assert max(_regrets(eng, prompt, got[u])) == 0.0
     eng.assert_no_leaks()
     stats = eng.decode_stats()
     assert stats["moe_layer_calls"] > 0
     assert 0 < stats["moe_local_picks"] <= stats["moe_padded_rows"]
+    assert stats["decode_kv_blocks"] == 8 * 1 + 5 * 1 + 6 * 2
     assert eng.state_slots.in_use == 0
 
 

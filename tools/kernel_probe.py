@@ -33,6 +33,8 @@ def cases():
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
     from deepspeed_tpu.ops.pallas.fused_adam import fused_adam_update
     from deepspeed_tpu.ops.pallas.grouped_matmul import grouped_matmul
+    from deepspeed_tpu.ops.pallas.paged_attention import \
+        paged_decode_attention
     from deepspeed_tpu.ops.pallas.quantization import (dequantize_int8,
                                                        quantize_int8)
     from deepspeed_tpu.ops.pallas.wq_matmul import (dequantize_weight,
@@ -51,6 +53,21 @@ def cases():
         out.append((f"flash_attention fwd+bwd seq={s} 32/8 heads D=128",
                     flash_grads, [((1, s, 32, 128), bf), ((1, s, 8, 128), bf),
                                   ((1, s, 8, 128), bf)], None, 0))
+
+    # paged decode at the two serving cells' geometries (rows x table, G)
+    # and the int8 pool; compile only: chip_smoke.py holds its numerics
+    def paged(q, k, v, table, pos, act, *scales):
+        return paged_decode_attention(q, k, v, table, pos, *scales,
+                                      layer=jnp.int32(1), active=act)
+
+    for rows, mp, nh, dt in ((64, 256, 32, bf), (128, 512, 64, bf),
+                             (64, 256, 32, jnp.int8)):
+        pool = ((2, 1025, 16, 1024), dt)
+        scales = [((2, 1025, 16, 8), f32)] * 2 if dt == jnp.int8 else []
+        out.append((f"paged_decode {rows}x{mp} pages of 16, {nh}/8 heads "
+                    f"D=128 {jnp.dtype(dt).name}", paged,
+                    [((rows, nh, 128), bf), pool, pool, ((rows, mp), i32),
+                     ((rows,), i32), ((rows,), jnp.bool_)] + scales, None, 0))
 
     # Mixtral-8x7B expert matrices: 4096 x 14336, 8 experts, 4096 rows
     def gmm(x, w, be):
