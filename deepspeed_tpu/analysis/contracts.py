@@ -25,7 +25,6 @@ drivers.
 
 from __future__ import annotations
 
-import hashlib
 import json
 import math
 import os
@@ -748,23 +747,3 @@ def write_goldens(root: str, contracts: Dict[str, Dict[str, Any]]) -> List[str]:
             f.write("\n")
         written.append(path)
     return written
-
-
-def contract_set_hash(root: str) -> str:
-    """sha256 over the checked-in goldens (stdlib only — bench.py stamps
-    this into its JSON so a perf artifact is traceable to the exact
-    program contracts it ran under).  Returns the literal ``"no-goldens"``
-    when none are present: a hash-of-nothing would let two artifacts from
-    different program contracts compare as 'same contract set' — the
-    exact masquerading this field exists to prevent."""
-    h = hashlib.sha256()
-    d = goldens_dir(root)
-    n = 0
-    if os.path.isdir(d):
-        for fn in sorted(os.listdir(d)):
-            if fn.endswith(".json"):
-                h.update(fn.encode())
-                with open(os.path.join(d, fn), "rb") as f:
-                    h.update(f.read())
-                n += 1
-    return h.hexdigest() if n else "no-goldens"

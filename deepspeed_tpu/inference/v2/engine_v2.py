@@ -346,9 +346,9 @@ class InferenceEngineV2:
         self._stats = {"prefill_admitted_tokens": 0,
                        "prefill_computed_tokens": 0,
                        "prefix_hit_tokens": 0}
-        # decode-phase counters (decode_stats / bench_serving A/B): model
-        # invocations vs tokens produced is THE speculative-decoding
-        # figure of merit — tokens per invocation
+        # decode-phase counters (decode_stats): tokens produced over model
+        # invocations is what speculation buys, over host syncs what the
+        # fused horizon buys
         self._dstats = {"decode_model_invocations": 0, "decode_tokens": 0,
                         "decode_host_syncs": 0, "decode_horizon_shrinks": 0,
                         "decode_kv_blocks": 0,
@@ -1251,9 +1251,8 @@ class InferenceEngineV2:
         self.kv_tier.note_spill(len(pend), time.perf_counter() - t0)
 
     def flush_spills(self) -> None:
-        """Commit any pending host-tier spills NOW (tests, retirement,
-        bench leg boundaries) — the engine otherwise drains them at the
-        next step boundary."""
+        """Commit any pending host-tier spills NOW (tests, retirement) —
+        the engine otherwise drains them at the next step boundary."""
         self._drain_spills()
 
     def _current_match(self, seq: SequenceState):
@@ -1867,8 +1866,7 @@ class InferenceEngineV2:
 
     def force_timeline_capture(self) -> None:
         """Arm the step-time attribution capture for the NEXT ``step()``
-        regardless of cadence (bench_serving stamps its JSON from the
-        record this produces)."""
+        regardless of cadence; ``timeline_record()`` then holds it."""
         self._timeline.force_next()
 
     def timeline_record(self) -> Optional[Dict[str, Any]]:
@@ -2505,24 +2503,10 @@ class InferenceEngineV2:
 
     def decode_stats(self) -> Dict[str, float]:
         """Decode-phase counters (cumulative; all-zero spec entries with
-        speculation off): model invocations, tokens produced, and the
-        speculative propose/accept/rollback tallies.  The derived
-        ``decode_tokens_per_invocation`` is the speculative-decoding
-        figure of merit ``tools/bench_serving.py --ab-speculative``
-        machine-checks."""
-        s: Dict[str, float] = dict(self._dstats)
-        inv = s["decode_model_invocations"]
-        s["decode_tokens_per_invocation"] = (
-            s["decode_tokens"] / inv) if inv else 0.0
-        syncs = s["decode_host_syncs"]
-        # the multi-step figure of merit (bench_serving --ab-multistep):
-        # decode tokens banked per host round-trip
-        s["decode_tokens_per_host_sync"] = (
-            s["decode_tokens"] / syncs) if syncs else 0.0
-        prop = s["spec_proposed_tokens"]
-        s["spec_acceptance_rate"] = (
-            s["spec_accepted_tokens"] / prop) if prop else 0.0
-        return s
+        speculation off): model invocations, host syncs, tokens produced,
+        the speculative propose/accept/rollback tallies and the MoE and
+        state-slot counts."""
+        return dict(self._dstats)
 
     def assert_no_leaks(self) -> None:
         """Exact allocator audit against this engine's live sequences
@@ -2538,8 +2522,9 @@ class InferenceEngineV2:
                  if s is not None})
 
     def reset_cache_stats(self) -> None:
-        """Zero the counters (cache CONTENTS are kept) — benches call this
-        after warmup so compile-wave admissions don't pollute the rates.
+        """Zero the counters (cache CONTENTS are kept) — the benchmark
+        calls this after warm-up so compile-wave admissions don't pollute
+        the rates.
         The registry counters stay cumulative (Prometheus counters never
         go backwards); only the delta baseline resets with the sources."""
         self._stats = {k: 0 for k in self._stats}
@@ -2549,7 +2534,7 @@ class InferenceEngineV2:
             self.prefix_cache.hits = self.prefix_cache.misses = 0
         if self.kv_tier is not None:
             # tier CONTENTS are kept (like the device cache); only the
-            # counters re-baseline so a bench wave measures its own
+            # counters re-baseline so a measured window counts its own
             # spill/restore traffic
             t = self.kv_tier
             t.spilled_pages = t.restored_pages = 0

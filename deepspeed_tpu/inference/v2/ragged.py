@@ -468,8 +468,8 @@ class PrefixCache:
     prefix.  Only full pages are hashed: partial tail pages stay private
     to their sequence (the engine copy-on-writes the one case where a
     shared full page must be written — see engine_v2._admit).  Counters
-    (``hits``/``misses`` here, ``evictions`` on the allocator) feed the
-    serving monitor and bench_serving.py.
+    (``hits``/``misses`` here, ``evictions`` on the allocator) feed
+    ``engine_v2.cache_stats()`` and the serving gauges.
     """
 
     def __init__(self, page_size: int, allocator: BlockAllocator):

@@ -992,11 +992,17 @@ def param_count(cfg: TransformerConfig) -> int:
 
 
 def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
-    """6*N_active + attention flops per token (training fwd+bwd).
+    """Training (forward + backward) model FLOPs per token, by the rules
+    ``benchmark/roofline.py`` states, so the engine's MFU gauge and the
+    benchmark's ``train_mfu_pct`` are one count: a ``[m, k] x [k, n]``
+    matmul is ``2*m*k*n``, backward twice the forward, recomputation never
+    credited; the embedding look-up is a gather; the head is one
+    ``[hidden, vocab]`` matmul tied or not; causal attention does half the
+    work of full attention.
 
-    For MoE layers N_active counts the router plus only the ``top_k``
-    experts a token actually flows through — total expert params would
-    overstate MFU by experts/top_k on the MLP term (mixtral 8x: 4x).
+    For MoE layers the matmul weights count the router plus only the
+    ``top_k`` experts a token actually flows through — total expert params
+    would overstate MFU by experts/top_k on the MLP term (mixtral 8x: 4x).
     """
     mlp = cfg.hidden_size * cfg.ffn_size * (3 if cfg.activation == "swiglu" else 2)
     if cfg.moe_experts > 0:
@@ -1005,10 +1011,11 @@ def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
             mlp += 2 * cfg.hidden_size * cfg.ffn_size + 2 * cfg.hidden_size
         if cfg.moe_shared_expert > 0:  # always-on shared expert + its gate
             mlp += 3 * cfg.hidden_size * cfg.moe_shared_expert + cfg.hidden_size
-    n_params = (cfg.vocab_size * cfg.hidden_size * (1 if cfg.tie_embeddings else 2)
-                + cfg.n_layers * (
-                    cfg.hidden_size * cfg.head_dim * (cfg.n_heads + 2 * cfg.kv_heads)
-                    + cfg.n_heads * cfg.head_dim * cfg.hidden_size
-                    + mlp))
-    attn = 12 * cfg.n_layers * cfg.hidden_size * seq_len
-    return 6.0 * n_params + attn
+    matmul = (cfg.hidden_size * cfg.vocab_size
+              + cfg.n_layers * (
+                  cfg.hidden_size * cfg.head_dim * (cfg.n_heads + 2 * cfg.kv_heads)
+                  + cfg.n_heads * cfg.head_dim * cfg.hidden_size
+                  + mlp))
+    keys = seq_len / 2 if cfg.causal else seq_len  # mean keys a token attends
+    attn = cfg.n_layers * 2 * 2 * keys * cfg.n_heads * cfg.head_dim
+    return 3.0 * (2.0 * matmul + attn)

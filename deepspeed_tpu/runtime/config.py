@@ -131,16 +131,17 @@ class ZeroConfig(ConfigModel):
     #: or overlap_grad_reduce is on and the model supports it), the
     #: gathers are EXPLICIT in-loop collectives issued at the body top
     #: (runtime/zero/overlap.py) — the unrolled pair of gather->compute
-    #: chains is the double buffer.  Off by default — A/B on hardware
-    #: (bench STAGE=3 PREFETCH=1) decides; the reference's analogue is
-    #: the PartitionedParameterCoordinator prefetch.
+    #: chains is the double buffer.  Off by default (no benchmark cell
+    #: sets it); the reference's analogue is the
+    #: PartitionedParameterCoordinator prefetch.
     zero3_param_prefetch: bool = False
     #: issue each layer-bucket's gradient reduce inside the BACKWARD
     #: scan, as soon as the bucket's cotangents materialize
     #: (runtime/zero/overlap.py custom_vjp hook; Domino-style — the
     #: collective rides the dataflow graph, no post-backward block).
-    #: Scheduling only: bit-exact with the unbucketed path, A/B'd by
-    #: ``bench.py --ab-overlap``.  Needs a models/* transformer.  With
+    #: Scheduling only: bit-exact with the unbucketed path
+    #: (tests/unit/test_overlap.py::test_overlap_bit_exact_and_parity_zero1
+    #: and ``_zero3_and_prefetch``).  Needs a models/* transformer.  With
     #: qgZ (or ``overlap_compression``) also set, the in-loop exchange
     #: itself compresses — docs/COMM.md "Compressed overlap"; with
     #: ``overlap_compression: false`` the wrap stands down under qgZ /
@@ -382,8 +383,8 @@ class TimelineConfig(ConfigModel):
     attribution (telemetry/timeline.py).  Every ``every_n_steps`` the
     engine captures a ``jax.profiler`` trace of ONE step and publishes
     the ``deepspeed_tpu_timeline_*`` decomposition (0 = no periodic
-    captures; one-shot captures via ``engine.capture_timeline()`` /
-    bench stamps still work).  ``artifact_dir`` receives one merged
+    captures; one-shot captures via ``engine.capture_timeline()`` still
+    work).  ``artifact_dir`` receives one merged
     host-span + device-op Chrome-trace file per capture ("" = no
     artifact files)."""
 

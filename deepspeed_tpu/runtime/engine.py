@@ -408,11 +408,9 @@ class DeepSpeedTPUEngine:
     def _pipe_schedule_active(self) -> bool:
         """True when the model runs the scan-based pipe schedule
         (runtime/pipe/engine.py) on this engine: a pipe ModelSpec on a
-        pipe>1 mesh, or one pinned to the schedule at pipe=1
-        (``force_schedule`` — the --ab-pipe control arm)."""
+        pipe>1 mesh (at pipe=1 it runs the plain loss)."""
         return (getattr(self.model, "num_microbatches", None) is not None
-                and (self.topology.pipe_parallel_size > 1
-                     or getattr(self.model, "pipe_force_schedule", False)))
+                and self.topology.pipe_parallel_size > 1)
 
     def _configure_pipeline(self, config: DeepSpeedConfig) -> None:
         """Pipe perf wiring (docs/PIPELINE.md): resolve the
@@ -1725,9 +1723,9 @@ class DeepSpeedTPUEngine:
     def capture_timeline(self, batch=None,
                          data_iter: Optional[Iterator] = None):
         """Force a step-time attribution capture around ONE train_batch
-        and return ``(loss, record)`` — the bench/report entry point (no
-        cadence configuration needed).  ``record`` is None when telemetry
-        or the timeline is disabled."""
+        and return ``(loss, record)`` — the report entry point
+        (tools/goodput_report.py; no cadence configuration needed).
+        ``record`` is None when telemetry or the timeline is disabled."""
         tl = self.telemetry.timeline if self.telemetry is not None else None
         if tl is None:
             return self.train_batch(batch=batch, data_iter=data_iter), None
@@ -2111,9 +2109,9 @@ class DeepSpeedTPUEngine:
 
     def _model_flops_per_step(self, batch) -> float:
         """FLOPs one optimizer step spends on the MODEL, cached after the
-        first call.  Preferred source: the analytic ``6N + attn`` model
-        cost (transformer.flops_per_token) — rematerialization cannot
-        inflate it.  Fallback: XLA's cost analysis of the compiled fused
+        first call.  Preferred source: the analytic model cost
+        (transformer.flops_per_token, the benchmark's count) —
+        rematerialization cannot inflate it.  Fallback: XLA's cost analysis of the compiled fused
         step (hardware flops: includes remat + optimizer, so MFU reads a
         few points high there)."""
         if self._flops_per_step is not None:
@@ -2354,7 +2352,7 @@ class DeepSpeedTPUEngine:
         return compare_rank_checksums(per_rank)
 
     def numerics_report(self) -> Optional[dict]:
-        """Numerics observatory summary (bench annex / tools): the
+        """Numerics observatory summary (tools/telemetry_dump.py): the
         sentinel's rolling-window summary plus a fresh divergence-audit
         verdict.  None when the observatory is off."""
         if self._numerics is None:
@@ -2416,7 +2414,7 @@ class DeepSpeedTPUEngine:
         (``telemetry/overlap.py``), or None when the model has no
         stacked layer tree / no data parallelism.  Deterministic: a
         property of the compiled program structure, not runtime
-        jitter — ``bench.py --ab-overlap`` stamps it per arm."""
+        jitter."""
         from ..parallel.mesh import DATA_AXIS
         from ..telemetry.overlap import structural_report
 

@@ -262,17 +262,12 @@ def _pipe_body(params, ids, labels, stage_arr, pipe_comm, *,
 
 
 def pipelined_causal_lm(cfg: TransformerConfig, num_microbatches: int = 4,
-                        name: str = "pipelined-lm",
-                        force_schedule: bool = False) -> ModelSpec:
+                        name: str = "pipelined-lm") -> ModelSpec:
     """Build a ModelSpec whose loss_fn runs the full pipeline schedule.
 
     The engine uses it like any model; ``gradient_accumulation`` inside the
-    pipeline = ``num_microbatches`` (set engine gas=1).
-
-    ``force_schedule`` keeps the scan schedule even at pipe=1 (a
-    single-stage ring with an identity permute) — the bit-exactness
-    control arm of ``bench.py --ab-pipe`` runs THE SAME program text as
-    the multi-stage arm, so a loss mismatch isolates the pipelining.
+    pipeline = ``num_microbatches`` (set engine gas=1).  On a pipe=1
+    mesh the loss is the plain ``causal_lm_loss``: no schedule to run.
     """
     if cfg.post_norm:
         raise NotImplementedError("pipelined_causal_lm: post_norm "
@@ -293,7 +288,7 @@ def pipelined_causal_lm(cfg: TransformerConfig, num_microbatches: int = 4,
         if isinstance(params, dict) and "_pipe_comm" in params:
             params = dict(params)
             pipe_comm = params.pop("_pipe_comm")
-        if pp == 1 and not force_schedule:
+        if pp == 1:
             from ...models.transformer import causal_lm_loss
 
             return causal_lm_loss(cfg, params, batch, rng)
@@ -357,5 +352,4 @@ def pipelined_causal_lm(cfg: TransformerConfig, num_microbatches: int = 4,
     )
     spec.config = cfg
     spec.num_microbatches = num_microbatches
-    spec.pipe_force_schedule = force_schedule
     return spec

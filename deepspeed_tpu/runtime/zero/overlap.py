@@ -44,8 +44,8 @@ exactly this way; see the scan comment in models/transformer.py).
 
 The wrap is value-identity — per-shard compute is the same arithmetic
 and the explicit collectives compute the same sums — so overlap-on
-training is bit-exact with overlap-off (asserted per-run by
-``bench.py --ab-overlap`` and tests/unit/test_overlap.py).  Every
+training is bit-exact with overlap-off (tests/unit/test_overlap.py's
+``test_overlap_bit_exact_*``).  Every
 bucket logs a trace-time collective event (``grad_bucket_reduce``)
 into the span ring; the engine publishes the exposure split
 (``telemetry/overlap.py``) as

@@ -291,7 +291,7 @@ class HostKVTier:
             = OrderedDict()  # key -> (arrays, crc, nbytes); oldest first
         self._bytes = 0
         # cumulative counters (mirrored onto the registry family below;
-        # these stay the per-tier source of truth for bench/tests)
+        # these stay the per-tier source of truth for stats())
         self.spilled_pages = 0
         self.restored_pages = 0
         self.host_evictions = 0
@@ -490,8 +490,8 @@ class HostKVTier:
         self._publish()
 
     def stats(self) -> Dict[str, float]:
-        """Cumulative tier counters (bench_serving/--ab-kv-tier and the
-        fleet drill machine-check these)."""
+        """Cumulative tier counters (tests/unit/test_kv_tier.py and
+        tools/fleet_drill.py check these)."""
         out = {"spilled_pages": self.spilled_pages,
                "restored_pages": self.restored_pages,
                "host_pages": self.host_pages,

@@ -1,20 +1,16 @@
 """Model-FLOPs-utilization accounting.
 
-One canonical per-generation TPU peak-FLOPs table (dense bf16, per
-chip) shared by the telemetry gauges, ``bench.py`` and
-``tools/tune_mfu.py`` — a second copy of this table drifting is how MFU
-numbers stop being comparable.  Sources: published TPU specs (v4 275T,
-v5e 197T, v5p 459T, v6e "Trillium" 918T bf16).
+One per-generation TPU peak-FLOPs table (dense bf16, per chip) behind
+the telemetry gauges.  Sources: published TPU specs (v4 275T, v5e 197T,
+v5p 459T, v6e "Trillium" 918T bf16).
 
-``DSTPU_PEAK_FLOPS`` overrides the lookup.  The CPU entry is a nominal
-1 TFLOP/s so the test tier's gauges stay non-zero and clearly-not-a-chip.
-A device kind that is not in the table is an error, never a default: an
+The CPU entry is a nominal 1 TFLOP/s so the test tier's gauges stay
+non-zero and clearly-not-a-chip.  A device kind that is not in the table is an error, never a default: an
 assumed peak makes every utilization number derived from it fiction.
 """
 
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 #: per-chip peak dense-bf16 FLOP/s, keyed by device_kind substring
@@ -34,10 +30,7 @@ PEAK_BF16_FLOPS = {
 
 
 def peak_flops_for_kind(device_kind: str) -> float:
-    """Peak FLOP/s for a device-kind string (``DSTPU_PEAK_FLOPS`` wins)."""
-    env = os.environ.get("DSTPU_PEAK_FLOPS")
-    if env:
-        return float(env)
+    """Peak FLOP/s for a device-kind string."""
     kind = str(device_kind).lower()
     for name, peak in PEAK_BF16_FLOPS.items():
         if name.lower() in kind:

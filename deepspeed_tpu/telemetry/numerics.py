@@ -476,7 +476,7 @@ class NumericsLedger:
         return inc
 
     def summary(self) -> dict:
-        """JSON-safe snapshot for flight dumps / tools / bench annexes."""
+        """JSON-safe snapshot for flight dumps and tools."""
         return {
             "boundaries": self.boundaries,
             "anomaly_counts": dict(self.anomaly_counts),

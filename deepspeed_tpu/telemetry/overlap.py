@@ -14,14 +14,14 @@ against the measured compute spans (``train_batch`` walls) gives:
   latency-hiding scheduler can hide it (1.0 = nothing is structurally
   serialized after the backward).  Deterministic: it is a property of
   the traced program, not of runtime jitter, so the CPU tier
-  (``bench.py --ab-overlap``) can pin it.
+  (tests/unit/test_overlap.py::test_overlap_gauges_and_events) pins it.
 * ``exposed_collective_seconds`` — an ESTIMATE of the wall time the
   non-overlapped bytes cost per step: wire bytes x the algorithmic bus
   factor (``comms_logger.bus_factor``) over a nominal per-generation
   interconnect bandwidth.  It is a model, clearly labeled as one — on
-  real hardware the before/after walls (``tools/tune_mfu.py``) are the
-  ground truth, and this estimate tells you whether a wall delta is
-  plausibly comm-shaped.
+  real hardware the measured ``collective_exposed_ms_per_step`` of the
+  ``mistral7b-zero3-4chip`` benchmark cell is the ground truth, and
+  this estimate tells you whether a wall delta is plausibly comm-shaped.
 
 Engine gauges (single owner: ``runtime/engine.py``):
 ``deepspeed_tpu_train_overlapped_fraction`` and

@@ -300,7 +300,7 @@ class StepTimeline:
         return self.every_n_steps > 0 and step % self.every_n_steps == 0
 
     def force_next(self) -> None:
-        """Arm a one-shot capture regardless of cadence (bench stamps)."""
+        """Arm a one-shot capture regardless of cadence."""
         self._force = True
 
     def last_record(self) -> Optional[Dict[str, Any]]:
@@ -477,8 +477,8 @@ def capture_thunk(fn: Callable[[], Any], step: int = 0,
                   pipe_struct: Optional[Dict[str, Any]] = None,
                   sync: Optional[Callable[[], None]] = None,
                   artifact_dir: str = "") -> Tuple[Any, Optional[Dict[str, Any]]]:
-    """One-shot attribution of an arbitrary callable (bench stamps a
-    serving leg without owning an engine-side timeline). Returns
+    """One-shot attribution of an arbitrary callable (a caller that owns
+    no engine-side timeline). Returns
     ``(fn(), record)``; the record is None only if the capture machinery
     itself failed."""
     tl = timeline if timeline is not None else StepTimeline(

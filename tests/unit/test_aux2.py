@@ -227,120 +227,6 @@ def test_structured_pruning_non_transformer_degrades_gracefully():
     np.testing.assert_allclose(np.asarray(out["w1"]), np.ones((8, 8)))
 
 
-def test_bench_sweep_tool_routing(tmp_path, monkeypatch):
-    """The sweep drives bench.py for train rungs and the named tool for
-    _tool rungs, with ambient DSTPU_BENCH_/DSTPU_IBENCH_ vars scrubbed so
-    a leaked export cannot silently reshape a rung."""
-    import importlib.util
-    import subprocess as sp
-
-    repo = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    spec = importlib.util.spec_from_file_location(
-        "bench_sweep", os.path.join(repo, "tools", "bench_sweep.py"))
-    sweep = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(sweep)
-
-    calls = []
-
-    def fake_run(cmd, capture_output, text, env, timeout):
-        calls.append((cmd, env))
-
-        class R:
-            stdout = '{"value": 1, "unit": "x"}'
-            stderr = ""
-        return R()
-
-    monkeypatch.setattr(sp, "run", fake_run)
-    monkeypatch.setattr(sweep, "subprocess", sp)
-    monkeypatch.setattr(sweep, "ROOT", str(tmp_path))
-    os.makedirs(tmp_path / "docs", exist_ok=True)
-    monkeypatch.setenv("DSTPU_BENCH_SIZE", "leaked")
-    monkeypatch.setenv("DSTPU_IBENCH_GEN", "leaked")
-    # routing under test, not the PR-11 contract gate (its subprocess call
-    # would hit the fake_run signature); the provenance stamp still rides
-    monkeypatch.setenv("DSTPU_SWEEP_SKIP_CONTRACTS", "1")
-    monkeypatch.setattr(sweep.sys, "argv", ["bench_sweep.py", "flagship",
-                                            "serving-160m"])
-    assert sweep.main() == 0
-    (cmd1, env1), (cmd2, env2) = calls
-    # ROOT points at an empty artifact tree: the stamped hash is the
-    # explicit no-goldens sentinel, never a hash-of-nothing
-    with open(tmp_path / "docs" / "BENCH_SWEEP.json") as f:
-        recs = json.load(f)
-    assert all(r["contract_set_hash"] == "no-goldens" for r in recs)
-    assert cmd1[1].endswith("bench.py")
-    assert env1["DSTPU_BENCH_SIZE"] == "160m"  # rung wins over ambient
-    assert "DSTPU_IBENCH_GEN" not in env1
-    assert cmd2[1].endswith(os.path.join("tools", "bench_inference.py"))
-    assert env2["DSTPU_IBENCH_GEN"] == "128"
-    assert "_tool" not in env2 and "DSTPU_BENCH_SIZE" not in env2
-
-
-def _load_bench():
-    import importlib.util
-    repo = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    spec = importlib.util.spec_from_file_location(
-        "bench_mod", os.path.join(repo, "bench.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_bench_exits_nonzero_without_chip():
-    """No accelerator is a failure, not a fallback: a bare `python bench.py`
-    on the CPU platform exits non-zero, names the platform it found, and
-    prints no JSON line."""
-    import subprocess as sp
-
-    repo = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    env = {k: v for k, v in os.environ.items()
-           if not k.startswith("DSTPU_BENCH_")}
-    env["JAX_PLATFORMS"] = "cpu"
-    proc = sp.run([sys.executable, os.path.join(repo, "bench.py")],
-                  capture_output=True, text=True, env=env, timeout=240)
-    assert proc.returncode != 0
-    assert "platform is 'cpu'" in proc.stderr
-    assert not proc.stdout.strip()
-
-
-def test_bench_kernel_lowering_failure_is_fatal(monkeypatch):
-    """A Pallas/Mosaic lowering failure propagates (non-zero exit) — there
-    is no retry with attn_impl=xla; only an OOM steps down the bs ladder."""
-    bench = _load_bench()
-    monkeypatch.delenv("DSTPU_BENCH_ATTN", raising=False)
-    calls = []
-
-    def lowering_fails(size, seq, bs, steps, attn_impl=None):
-        calls.append((bs, attn_impl))
-        raise RuntimeError("MosaicError: Mosaic failed to compile TPU kernel")
-
-    monkeypatch.setattr(bench, "_run", lowering_fails)
-    with pytest.raises(RuntimeError, match="Mosaic"):
-        bench.main(allow_cpu=True)
-    assert calls == [(2, None)]  # one attempt, default kernels, no xla phase
-
-    calls.clear()
-    monkeypatch.setenv("DSTPU_BENCH_BS", "")
-    script = ["oom", "ok"]
-
-    def oom_then_ok(size, seq, bs, steps, attn_impl=None):
-        calls.append(bs)
-        if script.pop(0) == "oom":
-            raise RuntimeError("RESOURCE_EXHAUSTED: out of memory")
-        return {"value": 1}
-
-    monkeypatch.setattr(bench, "_run", oom_then_ok)
-    # the real one deletes every live device array in this pytest process
-    monkeypatch.setattr(bench, "_release_device_memory", lambda: None)
-    monkeypatch.setattr(bench, "_device_or_exit", lambda allow_cpu: type(
-        "Dev", (), {"platform": "tpu"})())
-    assert bench.main() == 0
-    assert calls == [32, 16]
-
-
 def test_layer_reduction_student_init():
     """Reference student_initialization (compression/compress.py:192): the
     student's stacked layers are the teacher's configured layers; the

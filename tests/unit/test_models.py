@@ -5,11 +5,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-pytestmark = pytest.mark.slow  # multi-minute integration tier
-
 import deepspeed_tpu
 from deepspeed_tpu.models import (bert_model, gpt2_model, llama_model,
                                   mixtral_model)
+
+#: the training tests below are the multi-minute integration tier; the two
+#: FLOP-count tests at the end are arithmetic and run in tier-1
+slow = pytest.mark.slow
 
 SEQ = 32
 BS = 4
@@ -37,19 +39,23 @@ def _train(model, cfg_overrides=None, steps=6, vocab=256, batch_fn=_lm_batch):
     return engine, losses
 
 
+@slow
 def test_llama_tiny_trains():
     _train(llama_model("tiny", max_seq_len=SEQ))
 
 
+@slow
 def test_llama_gqa_shapes():
     model = llama_model("tiny", max_seq_len=SEQ, n_kv_heads=2)
     _train(model)
 
 
+@slow
 def test_gpt2_tiny_trains():
     _train(gpt2_model("tiny"))
 
 
+@slow
 def test_bert_tiny_trains():
     def mlm_batch(vocab, seed=0, gas=1):
         rng = np.random.RandomState(seed)
@@ -60,10 +66,12 @@ def test_bert_tiny_trains():
     _train(bert_model("tiny"), batch_fn=mlm_batch)
 
 
+@slow
 def test_mixtral_tiny_trains():
     _train(mixtral_model("tiny", max_seq_len=SEQ))
 
 
+@slow
 def test_llama_zero3_tp_mesh(devices8):
     """2-way TP x 4-way ZeRO-3: the composition milestone."""
     model = llama_model("tiny", max_seq_len=SEQ)
@@ -76,6 +84,7 @@ def test_llama_zero3_tp_mesh(devices8):
     assert "data" in flat_axes
 
 
+@slow
 def test_mixtral_expert_parallel(devices8):
     model = mixtral_model("tiny", max_seq_len=SEQ)
     engine, _ = _train(model, {"mesh": {"expert": 4, "data": -1},
@@ -85,10 +94,12 @@ def test_mixtral_expert_parallel(devices8):
     assert "expert" in flat_axes
 
 
+@slow
 def test_remat_trains():
     _train(llama_model("tiny", max_seq_len=SEQ, remat=True))
 
 
+@slow
 def test_unscanned_matches_scanned():
     m1 = llama_model("tiny", max_seq_len=SEQ, scan_layers=True)
     m2 = llama_model("tiny", max_seq_len=SEQ, scan_layers=False)
@@ -101,6 +112,7 @@ def test_unscanned_matches_scanned():
     np.testing.assert_allclose(float(l1), float(l2), rtol=1e-5)
 
 
+@slow
 def test_tiled_loss_matches_full():
     m_full = llama_model("tiny", max_seq_len=SEQ, attn_impl="xla")
     m_tiled = llama_model("tiny", max_seq_len=SEQ, attn_impl="xla", loss_chunk=8)
@@ -119,6 +131,7 @@ def test_tiled_loss_matches_full():
         np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=1e-5, rtol=1e-3)
 
 
+@slow
 def test_mics_mesh_and_sharding(devices8):
     import deepspeed_tpu
     model = llama_model("tiny", max_seq_len=SEQ, attn_impl="xla")
@@ -158,3 +171,52 @@ def test_flops_per_token_counts_active_experts_only():
     np.testing.assert_allclose(f_moe - f_dense, expect_extra, rtol=1e-6)
     # and nowhere near total-expert pricing
     assert f_moe < f_dense + 6.0 * moe.n_layers * 3 * mlp
+
+
+def _benchmark_dense_cases():
+    """(family module, sizes, sequence) for each dense configuration of
+    BENCHMARK.json, at the sequence its cell runs: a training cell's
+    ``sequence_length``, a serving configuration's ``page_size *
+    max_pages_per_seq``."""
+    import importlib
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    cases = []
+    for entry in bench["configs"]:
+        with open(os.path.join(root, entry["file"])) as f:
+            sizes = json.load(f)
+        if sizes["family"] not in ("opt", "mistral"):
+            continue  # the MoE / linear-attention share has its own count
+        cell = next(w for w in bench["workloads"]
+                    if w["config"] == entry["name"])
+        with open(os.path.join(root, "benchmark", "traffic",
+                               cell["traffic"] + ".json")) as f:
+            traffic = json.load(f)
+        seq = traffic.get("sequence_length") or (
+            sizes["engine"]["page_size"] * sizes["engine"]["max_pages_per_seq"])
+        family = importlib.import_module(
+            "benchmark.families." + sizes["family"])
+        cases.append(pytest.param(family, sizes, int(seq), id=entry["name"]))
+    return cases
+
+
+@pytest.mark.parametrize("family,sizes,seq", _benchmark_dense_cases())
+def test_flops_per_token_agrees_with_the_benchmarks_count(family, sizes, seq):
+    """One FLOP count: the engine's MFU gauge prices a token as the
+    benchmark's ``train_mfu_pct`` does (benchmark/roofline.py's rules), at
+    the published widths of each dense benchmark configuration."""
+    from benchmark import roofline
+    from deepspeed_tpu.models.transformer import flops_per_token
+
+    n_layers = int(sizes["num_hidden_layers"])
+    model = family.build(sizes, n_layers, seq, jnp.float32)
+    want = roofline.train_flops_per_token(family.describe(sizes), n_layers,
+                                          seq)
+    np.testing.assert_allclose(flops_per_token(model.config, seq), want,
+                               rtol=1e-6)
+    np.testing.assert_allclose(model.flops_per_sample, want * seq, rtol=1e-6)

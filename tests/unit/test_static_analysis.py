@@ -542,28 +542,6 @@ def test_multistep_decode_replay_and_donation_contract(contracts_mod,
             "data, never shapes)")
 
 
-def test_contract_set_hash_tracks_goldens(contracts_mod, tmp_path):
-    h = contracts_mod.contract_set_hash(REPO)
-    assert len(h) == 64 and int(h, 16) >= 0
-    # the hash follows the golden bytes (bench JSON provenance)
-    import shutil
-
-    dst = tmp_path / "tests" / "contracts"
-    shutil.copytree(os.path.join(REPO, "tests", "contracts"), dst)
-    assert contracts_mod.contract_set_hash(str(tmp_path)) == h
-    with open(dst / "decode.json", "r+") as f:
-        data = json.load(f)
-        data["contract"]["collectives"]["all-gather"] += 1
-        f.seek(0)
-        json.dump(data, f)
-        f.truncate()
-    assert contracts_mod.contract_set_hash(str(tmp_path)) != h
-    # no goldens at all -> explicit sentinel, never a hash-of-nothing
-    # that would compare equal across unrelated contract sets
-    assert contracts_mod.contract_set_hash(str(tmp_path / "void")) == \
-        "no-goldens"
-
-
 # -------------------------------------------------------- unified driver
 def test_dstpu_lint_driver_merges_and_gates(tmp_path):
     import tools.dstpu_lint as dl

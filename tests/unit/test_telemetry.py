@@ -268,16 +268,13 @@ def test_stall_watchdog_flags_outlier():
     assert reg.get("deepspeed_tpu_stall_ratio").value(loop="t") < 3.0
 
 
-def test_mfu_helpers(monkeypatch):
+def test_mfu_helpers():
     assert peak_flops_for_kind("TPU v4") == 275e12
     assert peak_flops_for_kind("TPU v5e") == 197e12
     assert peak_flops_for_kind("TPU v5 lite") == 197e12  # what a v5e reports
     assert peak_flops_for_kind("cpu") == 1e12  # nominal, test tier only
     with pytest.raises(ValueError, match="made-up"):
         peak_flops_for_kind("made-up")  # an assumed peak is an error
-    monkeypatch.setenv("DSTPU_PEAK_FLOPS", "2e12")
-    assert peak_flops_for_kind("TPU v4") == 2e12
-    monkeypatch.delenv("DSTPU_PEAK_FLOPS")
     assert mfu(1e12, 1.0, n_chips=1, peak_flops=2e12) == 0.5
     assert mfu(1e12, 1.0, n_chips=2, peak_flops=1e12) == 0.5
     assert mfu(1e12, 0.0, peak_flops=1e12) == 0.0  # degenerate inputs

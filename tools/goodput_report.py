@@ -25,8 +25,7 @@ attribution & goodput") and hard-gates its invariants:
 
 Writes ``goodput_report.json`` under ``--out``, prints ONE JSON summary
 line, exits non-zero when any check fails — the acceptance gate for the
-measured-goodput subsystem (wired into bench.py / tools/bench_serving.py
-JSON via their ``timeline`` + ``goodput`` sections).
+measured-goodput subsystem.
 
 Knobs: ``--out DIR`` (default ./goodput_demo), ``--steps N`` (default
 8), ``--seed S``.
