@@ -10,7 +10,9 @@ platform but ``tpu`` before doing work, and only a run on the chip prints the
 final result line.  Legs:
 
 * kernels — flash forward / backward / ``q_offset`` forward and paged decode
-  against their XLA references at the shapes the other legs use;
+  against their XLA references at the shapes the other legs use, and the
+  grouped expert matmul at the Solar-Open2 share's (40 experts of
+  4096 x 1280 and 1280 x 4096, 1,024 and 4,096 picks);
 * train   — ``deepspeed_tpu.initialize`` -> ``engine.train_batch``: bf16,
   AdamW, ZeRO-1, clipping 1.0, seq 4096, one repeated seeded batch;
 * serve   — ``InferenceEngineV2`` driven by the ``put`` / ``step`` loop of
@@ -43,14 +45,18 @@ FULL = dict(size="7b", seq=4096, train_layers=1, serve_layers=4, steps=6,
             prompt_lens=(200, 1400, 650, 2000, 330, 1100, 1999, 480),
             new_tokens=32, max_seqs=4, pages_per_seq=128, chunk=512,
             offset_chunk=512, offset_window=2048, offset=1024,
-            decode_positions=(5, 700, 1999, 2047), walk_table=(64, 256))
+            decode_positions=(5, 700, 1999, 2047), walk_table=(64, 256),
+            moe=dict(held=40, experts=320, hidden=4096, ffn=1280,
+                     picks=(1024, 4096)))
 #: rehearsal: same control flow at sizes the CPU interpreter finishes
 TINY = dict(size="tiny", seq=128, train_layers=2, serve_layers=2, steps=4,
             heads=4, kv_heads=2, head_dim=16,
             prompt_lens=(20, 70, 33, 100, 17, 55, 99, 24),
             new_tokens=6, max_seqs=4, pages_per_seq=8, chunk=32,
             offset_chunk=32, offset_window=128, offset=64,
-            decode_positions=(5, 40, 100, 127), walk_table=(4, 16))
+            decode_positions=(5, 40, 100, 127), walk_table=(4, 16),
+            moe=dict(held=4, experts=32, hidden=256, ffn=128,
+                     picks=(64, 256)))
 
 #: Kernel-vs-reference tolerances: max |kernel - ref| over max |ref|, the
 #: reference computed in float32 at "highest" matmul precision from the same
@@ -241,6 +247,101 @@ def leg_kernels(sz, on_chip: bool) -> None:
               " of rows full < 1/3")
         check(none < 0.05 * full, f"inactive rows take {none / full:.4f} of "
               "rows full < 0.05")
+    del k_pool, v_pool
+    expert_matmul_checks(sz["moe"], on_chip)
+
+
+def expert_matmul_checks(moe, on_chip: bool) -> None:
+    """The grouped expert matmul at an expert share's shapes: against the
+    einsum on the rows that hold picks, and its time following the experts
+    touched, not the worst-case buffer."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.moe.sharded_moe import sort_pad_by_expert
+    from deepspeed_tpu.ops.pallas.grouped_matmul import (expert_block_rows,
+                                                         grouped_matmul)
+
+    E, H, F = moe["held"], moe["hidden"], moe["ffn"]
+    bf = jnp.bfloat16
+    ks = jax.random.split(jax.random.PRNGKey(1), 5)
+    w_up = jax.random.normal(ks[0], (E, H, F), bf) * H ** -0.5
+    w_down = jax.random.normal(ks[1], (E, F, H), bf) * F ** -0.5
+
+    def padded(rows, key, bs):
+        """The rows sorted by expert and scattered into the block-padded
+        buffer, as ``moe.sharded_moe._sorted_expert_ffn`` lays them."""
+        order, dest, n_rows, be, n_real = sort_pad_by_expert(key, E, bs)
+        xs = jnp.zeros((n_rows, rows.shape[1]), bf).at[dest].set(
+            rows[order], mode="drop")
+        return xs, dest, be, n_real
+
+    def run(impl, bs):
+        def f(rows, w, key):
+            xs, dest, be, n_real = padded(rows, key, bs)
+            ys = grouped_matmul(xs, w, be, bs, impl=impl, n_real=n_real)
+            return ys.at[dest].get(mode="fill", fill_value=0), n_real
+        return jax.jit(f)
+
+    for picks in moe["picks"]:
+        bs = expert_block_rows(picks / moe["experts"], bf)
+        # the router's picks over all the experts: one in eight is held
+        key = jax.random.randint(ks[2], (picks,), 0, moe["experts"])
+        key = jnp.minimum(key, E).astype(jnp.int32)
+        for name, w in (("gate / up", w_up), ("down", w_down)):
+            rows = jax.random.normal(ks[3], (picks, w.shape[1]), bf)
+            got, n_real = run("pallas", bs)(rows, w, key)
+            ref, _ = run("xla", bs)(rows, w, key)
+            e = rel_err(got, ref)
+            check(e < TOL_FWD and bool(jnp.any(ref)),
+                  f"grouped matmul {name} {w.shape[1]} x {w.shape[2]}, {E} "
+                  f"experts, {picks} picks in blocks of {bs} "
+                  f"({int(n_real)} hold picks) vs the einsum: rel err "
+                  f"{e:.2e} < {TOL_FWD:.2e}")
+            del got, ref
+
+    # a decode call's picks; all, a quarter and none of the experts touched,
+    # about three picks a touched expert as the router gives; 256 calls in
+    # one program (a program's launch and return, about a millisecond, is
+    # then a fifth of the shortest reading), each call's first row fed by
+    # the last one's
+    picks, n_calls = moe["picks"][0], 256 if on_chip else 2
+    bs = expert_block_rows(picks / moe["experts"], bf)
+    rows = jax.random.normal(ks[3], (picks, H), bf)
+
+    @jax.jit
+    def calls(rows_, w, key):
+        xs, _, be, n_real = padded(rows_, key, bs)
+
+        def body(_, x):
+            y = grouped_matmul(x, w, be, bs, impl="pallas", n_real=n_real)
+            return x.at[0, :128].add(y[0, :128] * 0)
+
+        return jax.lax.fori_loop(0, n_calls, body, xs)[0, 0]
+
+    def call_ms(touched):
+        key = jnp.where(jnp.arange(picks) < 3 * touched,
+                        jnp.arange(picks) % max(touched, 1), E)
+        args = (rows, w_up, key.astype(jnp.int32))
+        calls(*args).block_until_ready()
+        times = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            calls(*args).block_until_ready()
+            times.append(time.perf_counter() - t0)
+        return sorted(times)[2] * 1e3 / n_calls
+
+    every, quarter, none = call_ms(E), call_ms(E // 4), call_ms(0)
+    if on_chip:  # a time off the chip says nothing
+        gbs = E * H * F * 2 / every / 1e6
+        print(f"  grouped matmul {picks} picks over {E} experts of {H} x {F},"
+              f" a call: all touched {every:.3f} ms ({gbs:.0f} GB/s of "
+              f"weights), a quarter {quarter:.3f} ms, none {none:.3f} ms",
+              flush=True)
+        check(quarter < 0.4 * every, "a quarter of the experts touched takes "
+              f"{quarter / every:.3f} of all of them < 0.4")
+        check(none < 0.05 * every, f"no expert touched takes "
+              f"{none / every:.4f} of all of them < 0.05")
 
 
 # ----------------------------------------------------------------- train

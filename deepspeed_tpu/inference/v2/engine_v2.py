@@ -28,6 +28,7 @@ import numpy as np
 
 from ...models.layer_types import layers_of, state_leaves
 from ...models.transformer import TransformerConfig
+from ...moe.sharded_moe import MOE_COUNTERS
 from ...ops.pallas.paged_attention import n_blocks, pages_per_block
 from ...runtime.config_utils import ConfigModel
 from ...telemetry import get_registry
@@ -298,7 +299,8 @@ class InferenceEngineV2:
             layers_of(self.cfg, "attn"), self.cfg.kv_heads,
             self.cfg.head_dim, block, self.config.jnp_dtype,
             kv_quant=self.config.kv_quant, state=self._state,
-            counters=("moe_stats",) if self.cfg.moe_held_count else ())
+            counters=({"moe_stats": len(MOE_COUNTERS)}
+                      if self.cfg.moe_held_count else None))
         #: pages a block of the paged decode kernel holds, from the pool's
         #: own geometry as the kernel takes it (``decode_kv_blocks``)
         self._kv_block_pages = pages_per_block(
@@ -307,7 +309,7 @@ class InferenceEngineV2:
         self.state_slots = StateSlots(block.max_seqs if self._state else 0)
         #: the expert share's counters as the device last reported them
         #: (``moe_stats`` wraps at 2**32; the host adds differences)
-        self._moe_seen = np.zeros((4,), np.int64)
+        self._moe_seen = np.zeros((len(MOE_COUNTERS),), np.int64)
         self.block = block
         # A learned-position model cannot attend past its position table; cap
         # the paged window to the model's trained context.
@@ -355,8 +357,7 @@ class InferenceEngineV2:
                         "spec_proposed_tokens": 0, "spec_accepted_tokens": 0,
                         "spec_verify_calls": 0, "spec_rollback_pages": 0,
                         "spec_fallback_requests": 0,
-                        "moe_local_picks": 0, "moe_experts_touched": 0,
-                        "moe_padded_rows": 0, "moe_layer_calls": 0,
+                        **dict.fromkeys(MOE_COUNTERS, 0),
                         "state_slot_preemptions": 0}
         self._init_serving_metrics()
         self._uid = itertools.count()
@@ -732,7 +733,11 @@ class InferenceEngineV2:
         self._m_moe_padded = reg.counter(
             "deepspeed_tpu_serving_moe_padded_rows_total",
             "rows of the sorted and padded buffer the grouped matmul ran "
-            "over (whole blocks per touched expert)")
+            "(whole blocks per touched expert)")
+        self._m_moe_grid = reg.counter(
+            "deepspeed_tpu_serving_moe_grid_rows_total",
+            "rows of the worst-case buffer the grouped matmul's grid spans "
+            "(1 - padded / grid is the share of it that was skipped)")
         # last-published absolutes for the per-engine cache counters, so
         # the process-cumulative registry counters only receive deltas
         self._cache_pub = {"hits": 0, "misses": 0, "evictions": 0}
@@ -2086,12 +2091,12 @@ class InferenceEngineV2:
 
     def _pull(self, *arrays) -> List[np.ndarray]:
         """Host copies of a decode call's results.  With an expert share
-        its counters (the pools' ``moe_stats``, 16 bytes, to which the
+        its counters (the pools' ``moe_stats``, 20 bytes, to which the
         decode and chunk programs since the last pull added: picks on held
-        experts, held experts touched, rows the grouped matmul ran over,
-        expert-layer calls) come in the same ``device_get`` — every copy
-        starts before any is waited for — and their increase is noted on
-        the step."""
+        experts, held experts touched, rows the grouped matmul ran,
+        expert-layer calls, rows its grid spans) come in the same
+        ``device_get`` — every copy starts before any is waited for — and
+        their increase is noted on the step."""
         if "moe_stats" not in self._pools:
             # dstpu-lint: allow[host-sync] the caller's designed sync
             return [np.asarray(a) for a in arrays]
@@ -2102,13 +2107,13 @@ class InferenceEngineV2:
         # ``moe_stats`` wraps at 2**32)
         delta = ((now - self._moe_seen) % (1 << 32)).tolist()
         self._moe_seen = now
-        for name, d in zip(("moe_local_picks", "moe_experts_touched",
-                            "moe_padded_rows", "moe_layer_calls"), delta):
+        for name, d in zip(MOE_COUNTERS, delta):
             self._dstats[name] += d
             self._step_counts[name] = d
         self._m_moe_picks.inc(delta[0])
         self._m_moe_touched.inc(delta[1])
         self._m_moe_padded.inc(delta[2])
+        self._m_moe_grid.inc(delta[4])
         return out
 
     def _note_kv_blocks(self, lengths: np.ndarray) -> None:

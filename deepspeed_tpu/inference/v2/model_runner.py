@@ -87,8 +87,8 @@ def _scan_layers(cfg: TransformerConfig, params, pools, x, layer_fns):
     the body (a homogeneous stack is a period of one); ``l`` is the index
     among the layers of that mixer.  ``aux`` is what the layer's
     feed-forward part returned beside its output (``mlp_block``): for an
-    expert share its int32 ``[4]`` counters, which are added to the pools'
-    ``moe_stats`` leaf where the cache manager made one."""
+    expert share its int32 counters (``moe.sharded_moe.MOE_COUNTERS``), added
+    to the pools' ``moe_stats`` leaf where the cache manager made one."""
     types = period_types(cfg)
     per = {t.mixer: sum(u.mixer == t.mixer for u in types) for t in types}
     stack = params["layers"]

@@ -581,12 +581,13 @@ class PagedKVCache:
              block: KVBlockConfig, dtype=jnp.bfloat16,
              kv_quant: bool = False,
              state: Optional[Dict[str, Tuple[int, tuple, Any]]] = None,
-             counters: Sequence[str] = ()) -> Dict[str, Any]:
+             counters: Optional[Dict[str, int]] = None) -> Dict[str, Any]:
         """``n_layers``: the layers that keep pages.  ``state``: ``{leaf:
         (layers, per-sequence shape, dtype or None for ``dtype``)}`` — each
         becomes ``[layers, max_seqs + 1, *shape]``, slot = decode row, the
-        last slot the trash slot.  ``counters``: int32 ``[4]`` leaves the
-        programs add to (read by the host, never reset on the device)."""
+        last slot the trash slot.  ``counters``: ``{leaf: n}``, int32 ``[n]``
+        leaves the programs add to (read by the host, never reset on the
+        device)."""
         shape = (n_layers, block.num_pages + 1, block.page_size,
                  kv_heads * head_dim)
         if kv_quant:
@@ -601,8 +602,8 @@ class PagedKVCache:
         for name, (layers, sshape, sdtype) in (state or {}).items():
             pools[name] = jnp.zeros((layers, block.max_seqs + 1, *sshape),
                                     sdtype or dtype)
-        for name in counters:
-            pools[name] = jnp.zeros((4,), jnp.int32)
+        for name, n in (counters or {}).items():
+            pools[name] = jnp.zeros((n,), jnp.int32)
         return pools
 
 

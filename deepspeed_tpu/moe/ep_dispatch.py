@@ -199,20 +199,20 @@ def _dropless_block(x, gate_w, wg, wu, wd, rng, *, cfg, activation, ep,
     R = ep * c_send
     rl = recv_le.reshape(R)
     key = jnp.where(rl >= 0, rl, E_loc)  # E_loc = the invalid sentinel
-    order2, dest, n_rows, block_expert = sort_pad_by_expert(key, E_loc,
-                                                            block_rows)
+    order2, dest, n_rows, block_expert, n_real = sort_pad_by_expert(
+        key, E_loc, block_rows)
     xs = jnp.zeros((n_rows, H), x.dtype).at[dest].set(
         recv_x.reshape(R, H)[order2], mode="drop")
 
     experts_loc = {"w_up": wu, "w_down": wd}
     if activation == "swiglu":
         experts_loc["w_gate"] = wg
-    ys = _expert_ffn_blocks(xs, experts_loc, block_expert, activation,
-                            block_rows)
-    ys = jax.lax.psum(ys, MODEL_AXIS)  # model-TP down-proj combine
-
+    ys = _expert_ffn_blocks(xs, experts_loc, block_expert, n_real,
+                            activation, block_rows)
+    # (rows of the blocks past n_real are undefined: gather, then combine)
     y_rows = jnp.zeros((R, H), ys.dtype).at[order2].set(
         ys.at[dest].get(mode="fill", fill_value=0))
+    y_rows = jax.lax.psum(y_rows, MODEL_AXIS)  # model-TP down-proj combine
     ret = _ep_a2a(y_rows.reshape(ep, c_send, H), a2a_spec)
     y_asgn = ret.at[dest_rank, rank_pos].get(mode="fill", fill_value=0)
     contrib = y_asgn * (flat_g[order] * keep)[:, None].astype(ys.dtype)
@@ -223,10 +223,15 @@ def _dropless_block(x, gate_w, wg, wu, wd, rng, *, cfg, activation, ep,
 def moe_ffn_ep(x: jnp.ndarray, gate_w: jnp.ndarray,
                experts: Dict[str, jnp.ndarray], cfg, activation: str = "swiglu",
                rng=None, training: bool = True,
-               block_rows: int = 128) -> Optional[Tuple[jnp.ndarray, jnp.ndarray]]:
+               block_rows: Optional[int] = None
+               ) -> Optional[Tuple[jnp.ndarray, jnp.ndarray]]:
     """MoE FFN through the explicit EP all-to-all.  Returns None when the
     global batch/seq do not divide the token-shard grid (caller falls back
-    to the SPMD path — jit would reject those shardings anyway)."""
+    to the SPMD path — jit would reject those shardings anyway).
+    ``block_rows`` None: ``expert_block_rows`` of the picks a rank's expert
+    expects under balanced load (the dropless receiver's row blocks)."""
+    from ..ops.pallas.grouped_matmul import expert_block_rows
+
     topo = peek_topology()
     mesh = topo.mesh
     ep = topo.expert_parallel_size
@@ -265,7 +270,9 @@ def moe_ffn_ep(x: jnp.ndarray, gate_w: jnp.ndarray,
         else:
             c_send = min(A, -(-math.ceil(A * factor / ep) // 8) * 8)
         block = partial(_dropless_block, cfg=cfg, activation=activation,
-                        ep=ep, block_rows=block_rows, c_send=c_send,
+                        ep=ep, c_send=c_send,
+                        block_rows=block_rows or expert_block_rows(
+                            A * ep / cfg.num_experts, x.dtype),
                         a2a_spec=a2a_spec)
 
     rng_in = rng if rng is not None else jax.random.PRNGKey(0)

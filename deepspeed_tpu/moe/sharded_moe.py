@@ -60,6 +60,13 @@ class MoEConfig:
     held_count: int = 0
 
 
+#: the counters an expert share returns per call (``moe_ffn_share``), in
+#: order: the engine's ``decode_stats()`` keys and the ``serve_step``
+#: span's attributes
+MOE_COUNTERS = ("moe_local_picks", "moe_experts_touched", "moe_padded_rows",
+                "moe_layer_calls", "moe_grid_rows")
+
+
 def compute_capacity(tokens: int, cfg: MoEConfig, training: bool = True) -> int:
     factor = cfg.capacity_factor if training else cfg.eval_capacity_factor
     cap = int(tokens * factor * cfg.top_k / cfg.num_experts)
@@ -134,11 +141,16 @@ def sort_pad_by_expert(key: jnp.ndarray, n_experts: int, block_rows: int):
     grouped matmul.  ``key`` values >= n_experts mark INVALID rows (they sort
     to the end and get dest == n_rows — scatter them with mode='drop').
 
-    Returns (order, dest, n_rows, block_expert):
+    Returns (order, dest, n_rows, block_expert, n_real):
       order        [N] sorted row order (stable)
       dest         [N] padded-buffer row for each SORTED position
-      n_rows       static padded buffer size (worst case, whole blocks)
+      n_rows       static padded buffer size: the most whole blocks N rows
+                   can take (every expert one row into a block of its own,
+                   the rest filling blocks)
       block_expert [n_rows/block_rows] expert of each row block
+      n_real       int32 scalar: the blocks that hold rows,
+                   ``sum(ceil(counts / block_rows))`` — they come first, and
+                   ``dest`` points into them only
     """
     N = key.shape[0]
     counts = jnp.bincount(jnp.minimum(key, n_experts),
@@ -148,7 +160,9 @@ def sort_pad_by_expert(key: jnp.ndarray, n_experts: int, block_rows: int):
     starts_raw = jnp.cumsum(counts) - counts
     padded = ((counts + block_rows - 1) // block_rows) * block_rows
     starts_b = jnp.cumsum(padded) - padded
-    n_rows = (-(-N // block_rows) + n_experts) * block_rows
+    most_touched = min(n_experts, N)
+    n_rows = max(1, most_touched
+                 + (N - most_touched) // block_rows) * block_rows
     se = jnp.clip(key_s, 0, n_experts - 1)
     dest = jnp.where(key_s < n_experts,
                      starts_b[se] + (jnp.arange(N) - starts_raw[se]), n_rows)
@@ -156,14 +170,19 @@ def sort_pad_by_expert(key: jnp.ndarray, n_experts: int, block_rows: int):
     block_expert = jnp.clip(
         jnp.searchsorted(starts_b, block_starts, side="right") - 1,
         0, n_experts - 1).astype(jnp.int32)
-    return order, dest, n_rows, block_expert
+    n_real = (jnp.sum(padded) // block_rows).astype(jnp.int32)
+    return order, dest, n_rows, block_expert, n_real
 
 
-def _expert_ffn_blocks(xs, experts, block_expert, activation, block_rows):
-    """The three grouped matmuls of one FFN over sorted+padded tokens."""
+def _expert_ffn_blocks(xs, experts, block_expert, n_real, activation,
+                       block_rows):
+    """The three grouped matmuls of one FFN over sorted+padded tokens (the
+    rows of the blocks past ``n_real`` come back undefined)."""
     from ..ops.pallas.grouped_matmul import grouped_matmul
 
-    gm = lambda a, w: grouped_matmul(a, w, block_expert, block_rows)  # noqa: E731
+    def gm(a, w):
+        return grouped_matmul(a, w, block_expert, block_rows, n_real=n_real)
+
     if activation == "swiglu":
         h = jax.nn.silu(gm(xs, experts["w_gate"])) * gm(xs, experts["w_up"])
     else:
@@ -177,28 +196,37 @@ def _sorted_expert_ffn(xt, key, gate, top_k: int, n_experts: int, experts,
     ``[T * top_k]`` each pick's expert (``>= n_experts``: not computed here)
     and weight.  Picks are sorted and padded by expert, run through the
     grouped matmuls and added back to their tokens; an invalid pick is
-    scattered out of bounds (dropped) and gathered as zero."""
-    order, dest, n_rows, block_expert = sort_pad_by_expert(key, n_experts,
-                                                           block_rows)
+    scattered out of bounds (dropped) and gathered as zero.  Returns (the
+    tokens' sums, the rows of the blocks that hold picks — the rows the
+    kernel runs, the rows of the worst-case buffer its grid spans)."""
+    order, dest, n_rows, block_expert, n_real = sort_pad_by_expert(
+        key, n_experts, block_rows)
     token_of = order // top_k
     xs = jnp.zeros((n_rows, xt.shape[1]), xt.dtype).at[dest].set(
         xt[token_of], mode="drop")
-    ys = _expert_ffn_blocks(xs, experts, block_expert, activation, block_rows)
+    ys = _expert_ffn_blocks(xs, experts, block_expert, n_real, activation,
+                            block_rows)
     contrib = (ys.at[dest].get(mode="fill", fill_value=0)
                * gate[order][:, None].astype(ys.dtype))
-    return jnp.zeros_like(xt).at[token_of].add(contrib.astype(xt.dtype))
+    out = jnp.zeros_like(xt).at[token_of].add(contrib.astype(xt.dtype))
+    return out, n_real * block_rows, n_rows
 
 
 def moe_ffn_dropless(x: jnp.ndarray, gate_w: jnp.ndarray,
                      experts: Dict[str, jnp.ndarray], cfg: MoEConfig,
                      activation: str = "swiglu", rng=None,
-                     block_rows: int = 128) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                     block_rows: Optional[int] = None
+                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """drop_tokens=False (reference top-k gating with drop_tokens=False /
     Megablocks dropless): NO token is ever dropped.  Tokens are sorted by
-    expert and padded to block boundaries (static worst-case P = T*K +
-    E*block_rows), then the grouped Pallas matmul streams block-diagonal
-    expert FFNs through the MXU.
+    expert and padded to block boundaries (a static worst-case buffer,
+    ``sort_pad_by_expert``; ``block_rows`` None: ``expert_block_rows`` of
+    the picks an expert expects), then the grouped Pallas matmul streams
+    the block-diagonal expert FFNs of the blocks that hold tokens through
+    the MXU.
     """
+    from ..ops.pallas.grouped_matmul import expert_block_rows
+
     B, S, H = x.shape
     T = B * S
     E = cfg.num_experts
@@ -208,25 +236,31 @@ def moe_ffn_dropless(x: jnp.ndarray, gate_w: jnp.ndarray,
     logits = xt @ gate_w
     _, expert_idx, gate_k, aux = _gate_and_aux(logits, cfg, rng)
 
-    out = _sorted_expert_ffn(xt, expert_idx.reshape(T * K),
-                             gate_k.reshape(T * K), K, E, experts,
-                             activation, block_rows)
+    out, _, _ = _sorted_expert_ffn(
+        xt, expert_idx.reshape(T * K), gate_k.reshape(T * K), K, E, experts,
+        activation, block_rows or expert_block_rows(T * K / E, x.dtype))
     return out.reshape(B, S, H), aux
 
 
 def moe_ffn_share(x: jnp.ndarray, gate_w: jnp.ndarray,
                   experts: Dict[str, jnp.ndarray], cfg: MoEConfig,
-                  activation: str = "swiglu", block_rows: int = 128
+                  activation: str = "swiglu",
+                  block_rows: Optional[int] = None
                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """One expert rank's part of the layer (``cfg.held_count`` experts from
     ``cfg.held_first``; ``experts`` holds only those).  Routes over all
     ``num_experts`` in float32, renormalises over the ``top_k`` picks, keeps
-    the picks that land on a held expert, sorts and pads them by expert and
-    runs the grouped matmul over the held experts.  No pick is dropped;
-    what the absent experts would add is left out (their chips add it).
-    Returns (the share's output, its counters: int32 ``[4]`` — picks that
-    landed on held experts, held experts touched, rows of the padded buffer
-    the grouped matmul ran over, 1 for the call)."""
+    the picks that land on a held expert, sorts and pads them by expert
+    (blocks of ``expert_block_rows`` of the picks an expert expects, unless
+    ``block_rows`` says) and runs the grouped matmul over the held experts.
+    No pick is dropped; what the absent experts would add is left out (their
+    chips add it).  Returns (the share's output, its counters: int32
+    ``[len(MOE_COUNTERS)]`` — picks that landed on held experts, held experts
+    touched, rows of the blocks that hold picks (the rows the grouped matmul
+    ran), 1 for the call, rows of the worst-case buffer the kernel's grid
+    spans)."""
+    from ..ops.pallas.grouped_matmul import expert_block_rows
+
     B, S, H = x.shape
     T, K = B * S, cfg.top_k
     xt = x.reshape(T, H)
@@ -237,12 +271,15 @@ def moe_ffn_share(x: jnp.ndarray, gate_w: jnp.ndarray,
     held = (local >= 0) & (local < cfg.held_count)
     # a pick on an absent expert gets the invalid key
     key = jnp.where(held, local, cfg.held_count)
-    out = _sorted_expert_ffn(xt, key, gate_k.reshape(T * K), K,
-                             cfg.held_count, experts, activation, block_rows)
+    out, ran_rows, grid_rows = _sorted_expert_ffn(
+        xt, key, gate_k.reshape(T * K), K, cfg.held_count, experts,
+        activation,
+        block_rows or expert_block_rows(T * K / cfg.num_experts, x.dtype))
     counts = jnp.bincount(key, length=cfg.held_count + 1)[:-1]
-    stats = jnp.stack([jnp.sum(counts), jnp.sum(counts > 0),
-                       jnp.sum(-(-counts // block_rows)) * block_rows,
-                       jnp.ones((), counts.dtype)]).astype(jnp.int32)
+    stats = jnp.stack([jnp.sum(counts), jnp.sum(counts > 0), ran_rows,
+                       jnp.ones((), counts.dtype),
+                       jnp.full((), grid_rows, counts.dtype)]
+                      ).astype(jnp.int32)
     return out.reshape(B, S, H), stats
 
 

@@ -9,10 +9,13 @@ weight matrix via a scalar-prefetched block->expert map (the TPU version
 of Megablocks' block-diagonal sparsity).
 
 ``x``: [P, H] sorted+padded tokens, ``w``: [E, H, F] stacked expert
-weights, ``block_expert``: [P / block_rows] int32.  Returns [P, F].
+weights, ``block_expert``: [P / block_rows] int32, ``n_real``: how many of
+those blocks, from the first on, hold a row anyone reads.  Returns [P, F].
 """
 
 from __future__ import annotations
+
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -21,36 +24,72 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ...utils.platform import on_tpu, pallas_interpret
 
+#: bytes of one weight tile ``[H, tn]``.  Two are in flight (the pipeline's
+#: double buffer) beside two row blocks and two output blocks, and a v5e
+#: core has 128 MiB of VMEM.  Wider is faster as far as measured (PERF.md
+#: §6, PR 30): a Solar-Open2 expert matrix (10.5 MB) is one tile, read in
+#: whole rows at 92 % of the HBM's rate; Mixtral's 14336 x 4096 in tiles
+#: of 512 columns
+_W_TILE_BYTES = 16 * 2 ** 20
 
-def _tile(dim: int) -> int:
-    """Largest MXU-friendly tile dividing ``dim`` (else the whole dim)."""
-    return next((t for t in (1024, 512, 256, 128) if dim % t == 0), dim)
+
+def expert_block_rows(picks_per_expert: float, dtype) -> int:
+    """The row-block height of the sorted and padded buffer, from what a
+    call knows before it runs: the picks an expert expects (``T * top_k /
+    num_experts``) and the activations' dtype.  The power of two that holds
+    twice the expected picks — a touched expert gets more than the mean —
+    between the dtype's sublane tile (16 rows of bf16, 8 of float32: the
+    least a block can be) and the MXU's 128.  A block too low costs grid
+    steps and nothing else (an expert's blocks share one fetch of its
+    matrix); one too high costs the rows of every touched expert's last
+    block, in the scatter, the activation and the kernel's row reads."""
+    sublane = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+    want = max(1, int(2 * picks_per_expert))
+    return min(128, max(sublane, 1 << (want - 1).bit_length()))
 
 
-def _gmm_kernel(be_ref, x_ref, w_ref, o_ref, acc_ref):
-    # w_ref block was selected by the scalar-prefetched index map: it is
-    # already a [tk, tn] tile of THIS row block's expert matrix
-    k = pl.program_id(2)
+def _out_tile(h: int, f: int, itemsize: int) -> int:
+    """The widest tile of the output dim that divides it in whole lanes and
+    keeps ``[h, tile]`` of the expert matrix within ``_W_TILE_BYTES`` (else
+    128 lanes, or the whole of a dim that has no such divisor)."""
+    if f % 128:
+        return f
+    fits = [t for t in range(128, f + 1, 128)
+            if f % t == 0 and h * t * itemsize <= _W_TILE_BYTES]
+    return max(fits, default=128)
 
-    @pl.when(k == 0)
+
+def _gmm_kernel(be_ref, nr_ref, x_ref, w_ref, o_ref):
+    # w_ref is the [H, tn] tile of THIS row block's expert matrix, selected
+    # by the scalar-prefetched index map; a block past the real ones holds
+    # the previous step's tiles (nothing was fetched) and does nothing
+    @pl.when(pl.program_id(1) < nr_ref[0])
     def _():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    acc_ref[...] += jnp.dot(x_ref[...], w_ref[0],
-                            preferred_element_type=jnp.float32)
-
-    @pl.when(k == pl.num_programs(2) - 1)
-    def _():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+        o_ref[...] = jnp.dot(x_ref[...], w_ref[0],
+                             preferred_element_type=jnp.float32
+                             ).astype(o_ref.dtype)
 
 
 def grouped_matmul(x: jnp.ndarray, w: jnp.ndarray,
                    block_expert: jnp.ndarray, block_rows: int = 128,
-                   impl: str = "auto") -> jnp.ndarray:
+                   impl: str = "auto",
+                   n_real: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Block-grouped ``x @ w[block_expert[block]]``.
 
     Every ``block_rows`` rows of ``x`` share one expert.  P must be a
     multiple of ``block_rows`` (the no-drop router pads per expert).
+
+    ``n_real`` (an int32 scalar; None: every block): the first ``n_real``
+    blocks are the ones that hold rows — ``sort_pad_by_expert`` lays them
+    first and counts them.  The kernel does their work and no other: a grid
+    step past them computes nothing and fetches nothing (its index maps
+    return the tiles the last real block held, and an unchanged block is
+    not fetched again), and blocks of one expert that follow each other
+    share one fetch of each of its tiles (the output tiles are the outer
+    grid axis).  THE ROWS OF THE BLOCKS PAST ``n_real`` ARE NOT WRITTEN:
+    they hold whatever the buffer held, which may be NaN.  Gather only the
+    rows that were scattered in.  With ``n_real`` 0 the call reads one tile
+    (the pipeline's first fetch) and no more.
 
     ``impl="auto"`` is the kernel on TPU — never the XLA einsum — and the
     einsum on the CPU test tier, where interpreting the kernel would only
@@ -68,26 +107,43 @@ def grouped_matmul(x: jnp.ndarray, w: jnp.ndarray,
         return jnp.einsum("bph,bhf->bpf", xb.astype(jnp.float32),
                           wb.astype(jnp.float32)).reshape(P, F).astype(x.dtype)
 
-    # tiled over the contraction (H) and output (F) dims with an fp32
-    # accumulator: VMEM holds [tk, tn] of the expert matrix, not all of it
-    # (one Mixtral expert matrix is 117 MB in bf16)
-    tk, tn = _tile(H), _tile(F)
+    n_real = jnp.asarray(n_blocks if n_real is None else n_real,
+                         jnp.int32).reshape(1)
+    # the whole contraction in one step (VMEM holds [H, tn] of the expert
+    # matrix, not all of it: one Mixtral matrix is 117 MB in bf16), output
+    # tiles outermost: the grid is (F / tn) * n_blocks steps, and a step
+    # that does nothing costs about a tenth of a microsecond
+    tn = _out_tile(H, F, w.dtype.itemsize)
+
+    def block(i, nr):  # the last real block stands in for the ones past it
+        return jnp.minimum(i, jnp.maximum(nr[0] - 1, 0))
+
+    def tile(j, nr):  # no real block: one tile for the whole grid
+        return jnp.where(nr[0] > 0, j, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_blocks, F // tn, H // tk),
+        num_scalar_prefetch=2,
+        grid=(F // tn, n_blocks),
         in_specs=[
-            pl.BlockSpec((block_rows, tk), lambda i, j, k, be: (i, k)),
-            pl.BlockSpec((1, tk, tn), lambda i, j, k, be: (be[i], k, j)),
+            pl.BlockSpec((block_rows, H),
+                         lambda j, i, be, nr: (block(i, nr), 0)),
+            pl.BlockSpec((1, H, tn),
+                         lambda j, i, be, nr: (be[block(i, nr)], 0,
+                                               tile(j, nr))),
         ],
-        out_specs=pl.BlockSpec((block_rows, tn), lambda i, j, k, be: (i, j)),
-        scratch_shapes=[pltpu.VMEM((block_rows, tn), jnp.float32)],
+        out_specs=pl.BlockSpec(
+            (block_rows, tn),
+            lambda j, i, be, nr: (block(i, nr), tile(j, nr))),
     )
+    in_flight = 2 * (H * tn * w.dtype.itemsize
+                     + block_rows * (H + tn) * x.dtype.itemsize)
     return pl.pallas_call(
         _gmm_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((P, F), x.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=in_flight + 16 * 2 ** 20),
         interpret=pallas_interpret(),
         name="dstpu_grouped_matmul",
-    )(block_expert, x, w)
+    )(block_expert, n_real, x, w)

@@ -91,6 +91,7 @@ def test_engine_prefill_then_decode_matches_the_reference(kernels, horizon,
     stats = eng.decode_stats()
     assert stats["moe_layer_calls"] > 0
     assert 0 < stats["moe_local_picks"] <= stats["moe_padded_rows"]
+    assert stats["moe_padded_rows"] < stats["moe_grid_rows"]
     assert stats["decode_kv_blocks"] == 8 * 1 + 5 * 1 + 6 * 2
     assert eng.state_slots.in_use == 0
 

@@ -32,7 +32,9 @@ def cases():
     """(name, fn, arg specs [(shape, dtype)], reference fn or None, tol)."""
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
     from deepspeed_tpu.ops.pallas.fused_adam import fused_adam_update
-    from deepspeed_tpu.ops.pallas.grouped_matmul import grouped_matmul
+    from deepspeed_tpu.moe.sharded_moe import sort_pad_by_expert
+    from deepspeed_tpu.ops.pallas.grouped_matmul import (expert_block_rows,
+                                                         grouped_matmul)
     from deepspeed_tpu.ops.pallas.paged_attention import \
         paged_decode_attention
     from deepspeed_tpu.ops.pallas.quantization import (dequantize_int8,
@@ -79,6 +81,30 @@ def cases():
     out.append(("grouped_matmul E=8 H=4096 F=14336 rows=4096", gmm,
                 [((4096, 4096), bf), ((8, 4096, 14336), bf), ((32,), i32)],
                 gmm_ref, 2.0 ** -7))
+
+    # the Solar-Open2 share's expert matrices (40 of 320 experts held, gate
+    # / up 4096 x 1280 and down 1280 x 4096) at its decode call's 1,024
+    # picks and its chunk call's 4,096: sorted and padded at the derived
+    # block height, the blocks that hold picks run, the picks' rows gathered
+    # (a key of 40 or more is a pick on an absent expert)
+    def share(impl):
+        def run(rows, w, key):
+            bs = expert_block_rows(key.shape[0] / 320, rows.dtype)
+            order, dest, n_rows, be, n_real = sort_pad_by_expert(
+                key, w.shape[0], bs)
+            xs = jnp.zeros((n_rows, rows.shape[1]), rows.dtype).at[dest].set(
+                rows[order], mode="drop")
+            ys = grouped_matmul(xs, w, be, bs, impl=impl, n_real=n_real)
+            return ys.at[dest].get(mode="fill", fill_value=0)
+        return run
+
+    for picks in (1024, 4096):
+        for h, f in ((4096, 1280), (1280, 4096)):
+            out.append((f"grouped_matmul share E=40 H={h} F={f} picks={picks}"
+                        f" blocks of {expert_block_rows(picks / 320, bf)}",
+                        share("pallas"), [((picks, h), bf), ((40, h, f), bf),
+                                          ((picks,), i32)],
+                        share("xla"), 2.0 ** -7))
 
     # Mistral-7B down projection, decode batch of 4
     for bits in (8, 4):
