@@ -5,6 +5,7 @@ from .families import (bloom_config, bloom_model, falcon_config,
                        mistral_model, opt_config, opt_model, phi_config,
                        phi_model, qwen_config, qwen_model)
 from .gpt2 import gpt2_config, gpt2_model
+from .lfm2_moe import lfm2_moe_config, lfm2_moe_model
 from .llama import llama_config, llama_model
 from .mixtral import mixtral_config, mixtral_model
 from .solar_open2 import solar_open2_config, solar_open2_model
@@ -16,4 +17,5 @@ __all__ = ["bert_config", "bert_model", "gpt2_config", "gpt2_model",
            "phi_config", "phi_model", "opt_config", "opt_model",
            "falcon_config", "falcon_model", "bloom_config", "bloom_model",
            "gpt_neox_config", "gpt_neox_model", "solar_open2_config",
-           "solar_open2_model", "TransformerConfig"]
+           "solar_open2_model", "lfm2_moe_config", "lfm2_moe_model",
+           "TransformerConfig"]

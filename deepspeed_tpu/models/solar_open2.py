@@ -45,8 +45,8 @@ def solar_open2_config(size: str = "250b", max_seq_len: int = 8192,
 def _no_training(*_a, **_k):
     raise NotImplementedError(
         "solar_open2 is served only: training it needs the backward of the "
-        "delta-rule scan (ops/pallas/kda.py: dstpu_kda_chunk) and of "
-        "grouped_matmul (ops/pallas/grouped_matmul.py), and neither exists")
+        "delta-rule scan (ops/pallas/kda.py: dstpu_kda_chunk), which does "
+        "not exist; its expert layers would train as lfm2_moe's do")
 
 
 def solar_open2_model(size: str = "250b", max_seq_len: int = 8192,

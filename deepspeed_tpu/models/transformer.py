@@ -94,6 +94,18 @@ class TransformerConfig:
     moe_held_count: int = 0
     #: False: the shared expert is added as it is, with no sigmoid gate
     moe_shared_gate: bool = True
+    #: router scoring ("softmax" | "sigmoid", MoEConfig.scoring), whether the
+    #: router has a per-expert selection bias (``mlp/router_bias``: a buffer,
+    #: not a parameter — ``ModelSpec.buffers``) and the factor on the picks'
+    #: weights (routed_scaling_factor)
+    moe_scoring: str = "softmax"
+    moe_router_bias: bool = False
+    moe_routed_scale: float = 1.0
+    #: expert-share counters (engine-set per trace, like numerics_act_stats):
+    #: ``causal_lm_loss`` also returns the int32 counters of each expert
+    #: layer (``moe.sharded_moe.MOE_TRAIN_COUNTERS``), which the fused step
+    #: sums on the device (``engine.moe_stats()``)
+    moe_counters: bool = False
     #: EP dispatch: "auto" = explicit all-to-all shard_map when the mesh
     #: has an expert axis (moe/ep_dispatch.py); "spmd" = partitioner-driven
     moe_ep_dispatch: str = "auto"
@@ -159,6 +171,23 @@ class TransformerConfig:
     #: a tuple, one tree per position of the period, each stacked
     #: ``[n_layers / len(period), ...]``
     layer_period: Tuple[str, ...] = ("attn",)
+    #: the stack as a LIST of layer types, one name per layer, where the
+    #: published pattern is no repeated period (LFM2: attention at layers 2,
+    #: 6, 10, 14, 18, 21 of 24).  ``params["layers"]`` is then a tuple with
+    #: one tree per *run* of layers alike in mixer and feed-forward part
+    #: (``layer_types.stack_runs``), each stacked ``[layers of the run,
+    #: ...]``; the first ``dense_layers`` layers carry a dense feed-forward
+    #: part of width ``dense_ffn_size`` — the prologue — and the others the
+    #: configuration's (experts where ``moe_experts``).  Trained through
+    #: ``transformer_forward``; empty: ``layer_period`` describes the stack
+    layer_types: Tuple[str, ...] = ()
+    dense_layers: int = 0
+    dense_ffn_size: int = 0
+    #: taps of the gated short convolution's depthwise causal kernel (type
+    #: "conv"; LFM2's ``conv_L_cache``)
+    conv_taps: int = 3
+    #: RMSNorm over head_dim on each q head and each k head, before rotary
+    qk_norm: bool = False
     #: attention output gate: ``wo (attn * sigmoid(wg h))``
     attn_gate: bool = False
     #: delta-rule linear-attention layers (type "kda"): heads x head_dim for
@@ -252,6 +281,9 @@ def init_layer_stack(cfg: TransformerConfig, keys, L: int,
         if cfg.attn_gate:
             layers["attn"]["wg"] = nrm(jax.random.fold_in(keys[3], 1),
                                        L, H, NH * D)
+        if cfg.qk_norm:
+            layers["attn"]["q_norm"] = jnp.ones((L, D), dt)
+            layers["attn"]["k_norm"] = jnp.ones((L, D), dt)
     # falcon-7b/phi share norm1 across both branches; falcon-40b-style
     # parallel blocks (parallel_norms=2) carry separate attn/mlp norms
     if not cfg.parallel_block or cfg.parallel_norms >= 2:
@@ -260,6 +292,10 @@ def init_layer_stack(cfg: TransformerConfig, keys, L: int,
         E = cfg.moe_experts
         held = cfg.moe_held_count or E  # an expert share holds fewer
         layers["mlp"]["router"] = nrm(keys[7], L, H, E)
+        if cfg.moe_router_bias:
+            # drawn, not zero, so that it moves picks; nothing updates it
+            layers["mlp"]["router_bias"] = nrm(
+                jax.random.fold_in(keys[7], 1), L, E)
         layers["mlp"]["w_gate"] = nrm(keys[8], L, held, H, F)
         layers["mlp"]["w_up"] = nrm(keys[10], L, held, H, F)
         layers["mlp"]["w_down"] = nrm(keys[9], L, held, F, H, s=proj_out_std)
@@ -301,6 +337,11 @@ def init_layer_stack(cfg: TransformerConfig, keys, L: int,
 def init_transformer_params(cfg: TransformerConfig, rng) -> Dict[str, Any]:
     keys = jax.random.split(rng, 16)
     p = init_embed_head(cfg, keys)
+    if cfg.layer_types:
+        from .layer_types import init_runs
+
+        p["layers"] = init_runs(cfg, rng)
+        return p
     if len(cfg.layer_period) == 1:
         p["layers"] = init_layer_stack(cfg, keys, cfg.n_layers)
         return p
@@ -320,6 +361,19 @@ def init_transformer_params(cfg: TransformerConfig, rng) -> Dict[str, Any]:
 # partition rules: Megatron TP layout over the "model" axis
 # ---------------------------------------------------------------------------
 def transformer_partition_rules(cfg: TransformerConfig) -> List[Tuple[str, P]]:
+    if cfg.layer_types:
+        # a run's leaves by the run's own configuration (a dense run's
+        # mlp/w_down has one dim fewer than an expert run's)
+        from .layer_types import run_config, stack_runs
+
+        rules: List[Tuple[str, P]] = []
+        for j, (_kind, ffn, _n) in enumerate(stack_runs(cfg)):
+            rcfg = dataclasses.replace(run_config(cfg, ffn), layer_types=())
+            rules += [(rule if rule.startswith(("embed", "lm_head"))
+                       else f"layers/{j}/{rule}", spec)
+                      for rule, spec in transformer_partition_rules(rcfg)
+                      if j == 0 or not rule.startswith(("embed", "lm_head"))]
+        return rules
     lead = (None,)  # stacked layer dim
     rules = [
         (r"embed/tok", P(MODEL_AXIS, None)),  # vocab-sharded embedding
@@ -553,6 +607,9 @@ def attn_qkv(cfg: TransformerConfig, layer, x, positions):
     q = (_mm(cfg, h, a["wq"], None, MODEL_AXIS) + (a["bq"] if qb else 0)).reshape(B, T, NH, D)
     k = (_mm(cfg, h, a["wk"], None, MODEL_AXIS) + (a["bk"] if qb else 0)).reshape(B, T, KVH, D)
     v = (_mm(cfg, h, a["wv"], None, MODEL_AXIS) + (a["bv"] if qb else 0)).reshape(B, T, KVH, D)
+    if cfg.qk_norm:
+        q = _norm(q, a["q_norm"], None, "rmsnorm", cfg.norm_eps)
+        k = _norm(k, a["k_norm"], None, "rmsnorm", cfg.norm_eps)
     if cfg.position == "rope":
         q = _rope(q, cfg.rope_theta, positions, cfg.rotary_pct)
         k = _rope(k, cfg.rope_theta, positions, cfg.rotary_pct)
@@ -592,10 +649,13 @@ def _ffn(cfg: TransformerConfig, layer, h, training: bool = True):
                             norm_topk=cfg.moe_norm_topk,
                             held_first=cfg.moe_held_first,
                             held_count=cfg.moe_held_count,
+                            scoring=cfg.moe_scoring,
+                            routed_scale=cfg.moe_routed_scale,
                             ep_dispatch=cfg.moe_ep_dispatch,
                             ep_a2a_compression=cfg.moe_a2a_compression)
         moe_out, aux = moe_ffn(h, m["router"], m, moe_cfg,
-                               activation=cfg.activation, training=training)
+                               activation=cfg.activation, training=training,
+                               router_bias=m.get("router_bias"))
         if cfg.moe_shared_expert > 0:
             # qwen2-moe: the shared expert sees every token; its output is
             # gated by a per-token sigmoid scalar and ADDED to the routed
@@ -641,8 +701,10 @@ def _ffn(cfg: TransformerConfig, layer, h, training: bool = True):
     return h, aux
 
 
-def _block(cfg: TransformerConfig, x, layer, positions, mask, attn_fn):
-    """One transformer block, [B, S, H] -> [B, S, H]."""
+def attn_mixer(cfg: TransformerConfig, layer, x, positions, mask, attn_fn):
+    """The attention mixer over whole sequences, [B, S, H] -> the delta the
+    block adds to its residual stream (norm1, q/k/v, ``attn_fn``, output
+    projection).  The "attn" layer type's training form."""
     B, S, H = x.shape
     NH, KVH, D = cfg.n_heads, cfg.kv_heads, cfg.head_dim
     a = layer["attn"]
@@ -669,8 +731,13 @@ def _block(cfg: TransformerConfig, x, layer, positions, mask, attn_fn):
     else:
         attn = attn_fn(q, k, v, cfg.causal, mask)
     attn = attn.reshape(B, S, NH * D)
-    attn_delta = _mm(cfg, attn, a["wo"], MODEL_AXIS, None) \
+    return _mm(cfg, attn, a["wo"], MODEL_AXIS, None) \
         + (a["bo"] if cfg.use_bias else 0)
+
+
+def _block(cfg: TransformerConfig, x, layer, positions, mask, attn_fn):
+    """One transformer block, [B, S, H] -> [B, S, H]."""
+    attn_delta = attn_mixer(cfg, layer, x, positions, mask, attn_fn)
     if cfg.parallel_block:
         # falcon/phi: attention and MLP both read the block input
         out, aux = mlp_block(cfg, layer, x)
@@ -687,8 +754,14 @@ def _block(cfg: TransformerConfig, x, layer, positions, mask, attn_fn):
 
 
 def transformer_forward(cfg: TransformerConfig, params, input_ids, mask=None,
-                        token_type_ids=None, with_act_stats=False):
+                        token_type_ids=None, with_act_stats=False,
+                        with_moe_counters=False):
     """[B, S] int tokens -> ([B, S, H] final hidden states, aux loss).
+
+    A stack described by ``cfg.layer_types`` runs each layer as its type
+    defines it (``layer_types.run_stack``); ``with_moe_counters`` then adds,
+    last, the int32 counters of its expert-share layers ``[expert layers,
+    held + 3]`` (None without a share).
 
     ``with_act_stats`` (numerics observatory): additionally return a
     stacked ``[L, 3]`` per-layer activation-health side output
@@ -712,6 +785,19 @@ def transformer_forward(cfg: TransformerConfig, params, input_ids, mask=None,
     if with_act_stats:
         # lazy: telemetry must stay an optional dependency of the model code
         from ..telemetry.numerics import activation_stats as _act_row
+
+    if cfg.layer_types:
+        from .layer_types import run_stack
+
+        x, aux, act, moe = run_stack(cfg, params["layers"], x, positions,
+                                     mask, attn_fn, with_act_stats)
+        hidden = _norm(x, params["final_norm"]["scale"],
+                       params["final_norm"].get("bias"), cfg.norm,
+                       cfg.norm_eps)
+        return ((hidden, aux) + ((act,) if with_act_stats else ())
+                + ((moe,) if with_moe_counters else ()))
+    if with_moe_counters:
+        raise ValueError("moe counters ride a stack of cfg.layer_types")
 
     plan = getattr(cfg, "overlap_plan", None)
     # compressed-overlap comm state (runtime/zero/overlap.py): the engine
@@ -816,8 +902,16 @@ def causal_lm_loss(cfg: TransformerConfig, params, batch, rng=None):
     else:
         ids, labels, mask = batch, batch, None
     with_act = bool(getattr(cfg, "numerics_act_stats", False))
-    fwd = transformer_forward(cfg, params, ids, mask,
-                              with_act_stats=with_act)
+    # expert-share counters (cfg.moe_counters, engine-set per trace): the
+    # loss then returns (loss, act or None, counters)
+    with_moe = bool(getattr(cfg, "moe_counters", False))
+    if with_moe:
+        *fwd, moe = transformer_forward(cfg, params, ids, mask,
+                                        with_act_stats=with_act,
+                                        with_moe_counters=True)
+    else:
+        fwd = transformer_forward(cfg, params, ids, mask,
+                                  with_act_stats=with_act)
     hidden, aux = fwd[0], fwd[1]
     act = fwd[2] if with_act else None
     hidden = hidden[:, :-1]
@@ -825,6 +919,8 @@ def causal_lm_loss(cfg: TransformerConfig, params, batch, rng=None):
     m = mask[:, 1:].astype(jnp.float32) if mask is not None else None
 
     def _out(loss):
+        if with_moe:
+            return loss, act, moe
         return (loss, act) if with_act else loss
 
     if cfg.loss_chunk and hidden.shape[1] > cfg.loss_chunk:
@@ -977,6 +1073,12 @@ def param_count(cfg: TransformerConfig) -> int:
     """Total STORED parameter count: embeddings (tied or not), attention,
     and ALL experts' MLPs — what weight-bytes math needs.
     ``flops_per_token`` instead prices only the ACTIVE (top-k) params."""
+    if cfg.layer_types:
+        from .layer_types import stack_matmul_params
+
+        return (cfg.vocab_size * cfg.hidden_size
+                * (1 if cfg.tie_embeddings else 2)
+                + stack_matmul_params(cfg, active=False))
     mlp = cfg.hidden_size * cfg.ffn_size * (3 if cfg.activation == "swiglu" else 2)
     if cfg.moe_experts > 0:
         mlp = mlp * cfg.moe_experts + cfg.hidden_size * cfg.moe_experts
@@ -1003,7 +1105,18 @@ def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
     For MoE layers the matmul weights count the router plus only the
     ``top_k`` experts a token actually flows through — total expert params
     would overstate MFU by experts/top_k on the MLP term (mixtral 8x: 4x).
+    A stack of ``layer_types`` is counted layer by layer
+    (``layer_types.stack_matmul_params``); an expert share counts the picks
+    it expects, ``top_k * held / experts`` a token.
     """
+    if cfg.layer_types:
+        from .layer_types import layer_type, stack_matmul_params
+
+        keys = seq_len / 2 if cfg.causal else seq_len
+        attends = sum(layer_type(k).kv_pages for k in cfg.layer_types)
+        return 3.0 * (2.0 * (cfg.hidden_size * cfg.vocab_size
+                             + stack_matmul_params(cfg, active=True))
+                      + attends * 2 * 2 * keys * cfg.n_heads * cfg.head_dim)
     mlp = cfg.hidden_size * cfg.ffn_size * (3 if cfg.activation == "swiglu" else 2)
     if cfg.moe_experts > 0:
         mlp = mlp * cfg.moe_top_k + cfg.hidden_size * cfg.moe_experts

@@ -58,6 +58,14 @@ class MoEConfig:
     #: stands in for the absent chips or their exchange
     held_first: int = 0
     held_count: int = 0
+    #: how the router scores: "softmax" over all experts, or "sigmoid" per
+    #: expert (each score on its own; picks by score + a per-expert
+    #: selection bias where the layer has one, weights from the scores
+    #: alone, renormalised over the picks with + 1e-6 when ``norm_topk``;
+    #: no auxiliary loss, whatever ``aux_loss_coef`` says)
+    scoring: str = "softmax"
+    #: the picks' weights are multiplied by this (routed_scaling_factor)
+    routed_scale: float = 1.0
 
 
 #: the counters an expert share returns per call (``moe_ffn_share``), in
@@ -65,6 +73,13 @@ class MoEConfig:
 #: span's attributes
 MOE_COUNTERS = ("moe_local_picks", "moe_experts_touched", "moe_padded_rows",
                 "moe_layer_calls", "moe_grid_rows")
+
+#: what a *trained* share returns per call after the picks on each of its
+#: ``held_count`` experts: the rows of the blocks that hold picks (what each
+#: of the three kernels ran), the rows of the worst-case buffer, 1 for the
+#: call.  int32 ``[held_count + 3]``; the engine sums them over micro-batches
+#: and steps (``engine.moe_stats()``)
+MOE_TRAIN_COUNTERS = ("rows_run", "rows_grid", "calls")
 
 
 def compute_capacity(tokens: int, cfg: MoEConfig, training: bool = True) -> int:
@@ -113,9 +128,23 @@ def top_k_gating(logits: jnp.ndarray, cfg: MoEConfig, capacity: int,
     return combine, dispatch, aux
 
 
-def _gate_and_aux(logits: jnp.ndarray, cfg: MoEConfig, rng=None):
-    """Shared top-k gate probabilities + load-balance aux (no capacity)."""
+def _gate_and_aux(logits: jnp.ndarray, cfg: MoEConfig, rng=None, bias=None):
+    """Shared top-k gate probabilities + load-balance aux (no capacity).
+    ``bias`` ``[E]``: the sigmoid router's selection bias — it moves which
+    experts are picked and never a pick's weight."""
     E = logits.shape[-1]
+    if cfg.scoring == "sigmoid":
+        gates = jax.nn.sigmoid(logits.astype(jnp.float32))
+        select = gates if bias is None else gates + bias.astype(jnp.float32)
+        _, expert_idx = jax.lax.top_k(select, cfg.top_k)
+        gate_k = jnp.take_along_axis(gates, expert_idx, axis=1)
+        if cfg.norm_topk:
+            gate_k = gate_k / (jnp.sum(gate_k, -1, keepdims=True) + 1e-6)
+        # no auxiliary loss: the bias is such a router's balancing
+        return (gates, expert_idx, gate_k * cfg.routed_scale,
+                jnp.asarray(0.0, jnp.float32))
+    if cfg.scoring != "softmax":
+        raise ValueError(f"unknown router scoring {cfg.scoring!r}")
     if cfg.noisy_gate_policy == "Jitter" and rng is not None:
         logits = logits * jax.random.uniform(rng, logits.shape, minval=0.98,
                                              maxval=1.02)
@@ -215,7 +244,7 @@ def _sorted_expert_ffn(xt, key, gate, top_k: int, n_experts: int, experts,
 def moe_ffn_dropless(x: jnp.ndarray, gate_w: jnp.ndarray,
                      experts: Dict[str, jnp.ndarray], cfg: MoEConfig,
                      activation: str = "swiglu", rng=None,
-                     block_rows: Optional[int] = None
+                     block_rows: Optional[int] = None, router_bias=None
                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """drop_tokens=False (reference top-k gating with drop_tokens=False /
     Megablocks dropless): NO token is ever dropped.  Tokens are sorted by
@@ -234,7 +263,7 @@ def moe_ffn_dropless(x: jnp.ndarray, gate_w: jnp.ndarray,
     xt = x.reshape(T, H)
 
     logits = xt @ gate_w
-    _, expert_idx, gate_k, aux = _gate_and_aux(logits, cfg, rng)
+    _, expert_idx, gate_k, aux = _gate_and_aux(logits, cfg, rng, router_bias)
 
     out, _, _ = _sorted_expert_ffn(
         xt, expert_idx.reshape(T * K), gate_k.reshape(T * K), K, E, experts,
@@ -245,7 +274,8 @@ def moe_ffn_dropless(x: jnp.ndarray, gate_w: jnp.ndarray,
 def moe_ffn_share(x: jnp.ndarray, gate_w: jnp.ndarray,
                   experts: Dict[str, jnp.ndarray], cfg: MoEConfig,
                   activation: str = "swiglu",
-                  block_rows: Optional[int] = None
+                  block_rows: Optional[int] = None, router_bias=None,
+                  training: bool = False
                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """One expert rank's part of the layer (``cfg.held_count`` experts from
     ``cfg.held_first``; ``experts`` holds only those).  Routes over all
@@ -258,7 +288,9 @@ def moe_ffn_share(x: jnp.ndarray, gate_w: jnp.ndarray,
     ``[len(MOE_COUNTERS)]`` — picks that landed on held experts, held experts
     touched, rows of the blocks that hold picks (the rows the grouped matmul
     ran), 1 for the call, rows of the worst-case buffer the kernel's grid
-    spans)."""
+    spans).  Under ``training`` the same layer, differentiated through the
+    kernels' backward, returns the counters a training step sums instead:
+    the picks on each held expert, then ``MOE_TRAIN_COUNTERS``."""
     from ..ops.pallas.grouped_matmul import expert_block_rows
 
     B, S, H = x.shape
@@ -266,7 +298,7 @@ def moe_ffn_share(x: jnp.ndarray, gate_w: jnp.ndarray,
     xt = x.reshape(T, H)
     logits = jnp.dot(xt.astype(jnp.float32), gate_w.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    _, expert_idx, gate_k, _ = _gate_and_aux(logits, cfg)
+    _, expert_idx, gate_k, _ = _gate_and_aux(logits, cfg, bias=router_bias)
     local = expert_idx.reshape(T * K) - cfg.held_first
     held = (local >= 0) & (local < cfg.held_count)
     # a pick on an absent expert gets the invalid key
@@ -276,6 +308,11 @@ def moe_ffn_share(x: jnp.ndarray, gate_w: jnp.ndarray,
         activation,
         block_rows or expert_block_rows(T * K / cfg.num_experts, x.dtype))
     counts = jnp.bincount(key, length=cfg.held_count + 1)[:-1]
+    if training:
+        stats = jnp.concatenate([counts, jnp.stack([
+            ran_rows, jnp.full((), grid_rows, counts.dtype),
+            jnp.ones((), counts.dtype)])]).astype(jnp.int32)
+        return out.reshape(B, S, H), stats
     stats = jnp.stack([jnp.sum(counts), jnp.sum(counts > 0), ran_rows,
                        jnp.ones((), counts.dtype),
                        jnp.full((), grid_rows, counts.dtype)]
@@ -285,32 +322,40 @@ def moe_ffn_share(x: jnp.ndarray, gate_w: jnp.ndarray,
 
 def moe_ffn(x: jnp.ndarray, gate_w: jnp.ndarray, experts: Dict[str, jnp.ndarray],
             cfg: MoEConfig, activation: str = "swiglu", rng=None,
-            training: bool = True) -> Tuple[jnp.ndarray, jnp.ndarray]:
+            training: bool = True, router_bias=None
+            ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """MoE feed-forward over [B, S, H] (reference MOELayer.forward).
 
     experts: stacked weights {w_gate/w_up: [E, H, F], w_down: [E, F, H]}
     (w_gate only for swiglu).  Returns (out [B, S, H], aux_loss); an expert
     share (``cfg.held_count``), which has no such loss, returns its
-    counters there (``moe_ffn_share``).
+    counters there (``moe_ffn_share``).  ``router_bias`` ``[E]``: the
+    selection bias of a sigmoid router (``MoEConfig.scoring``).
     """
     from .ep_dispatch import ep_dispatch_active, moe_ffn_ep
 
     if cfg.held_count:
-        if cfg.drop_tokens or training:
+        if cfg.drop_tokens:
             raise NotImplementedError(
-                "an expert share (MoEConfig.held_count) is served dropless, "
-                "forward only: there is no capacity form of it and no "
-                "backward of grouped_matmul")
+                "an expert share (MoEConfig.held_count) is dropless, served "
+                "and trained: there is no capacity form of it "
+                "(moe_drop_tokens=True with a share)")
         # a share has no auxiliary loss: its counters take the slot
         with jax.named_scope("moe"):
-            return moe_ffn_share(x, gate_w, experts, cfg, activation)
+            return moe_ffn_share(x, gate_w, experts, cfg, activation,
+                                 router_bias=router_bias, training=training)
     if ep_dispatch_active(cfg):
         out = moe_ffn_ep(x, gate_w, experts, cfg, activation=activation,
                          rng=rng, training=training)
         if out is not None:
             return out
     if not cfg.drop_tokens:
-        return moe_ffn_dropless(x, gate_w, experts, cfg, activation, rng)
+        return moe_ffn_dropless(x, gate_w, experts, cfg, activation, rng,
+                                router_bias=router_bias)
+    if router_bias is not None:
+        raise NotImplementedError(
+            "a router selection bias has no capacity form: set "
+            "moe_drop_tokens=False")
     B, S, H = x.shape
     T = B * S
     xt = x.reshape(T, H)

@@ -15,6 +15,7 @@ those blocks, from the first on, hold a row anyone reads.  Returns [P, F].
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -59,15 +60,169 @@ def _out_tile(h: int, f: int, itemsize: int) -> int:
     return max(fits, default=128)
 
 
-def _gmm_kernel(be_ref, nr_ref, x_ref, w_ref, o_ref):
-    # w_ref is the [H, tn] tile of THIS row block's expert matrix, selected
-    # by the scalar-prefetched index map; a block past the real ones holds
-    # the previous step's tiles (nothing was fetched) and does nothing
+def _gmm_kernel(be_ref, nr_ref, x_ref, w_ref, o_ref, *, transpose_w):
+    # w_ref is the tile of THIS row block's expert matrix, selected by the
+    # scalar-prefetched index map ([H, tn], or [tn, F] of its rows for the
+    # product against the transpose); a block past the real ones holds the
+    # previous step's tiles (nothing was fetched) and does nothing
     @pl.when(pl.program_id(1) < nr_ref[0])
     def _():
-        o_ref[...] = jnp.dot(x_ref[...], w_ref[0],
-                             preferred_element_type=jnp.float32
-                             ).astype(o_ref.dtype)
+        if transpose_w:
+            y = jax.lax.dot_general(x_ref[...], w_ref[0],
+                                    (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+        else:
+            y = jnp.dot(x_ref[...], w_ref[0],
+                        preferred_element_type=jnp.float32)
+        o_ref[...] = y.astype(o_ref.dtype)
+
+
+def _gmm_dw_kernel(be_ref, nr_ref, x_ref, dy_ref, o_ref, acc_ref):
+    # one expert's blocks follow each other, so its [H, tn] tile of the
+    # result stays in place while they are summed into the float32 scratch;
+    # the expert's last block writes it.  Blocks past the real ones are
+    # neither fetched nor read
+    i, nr = pl.program_id(1), nr_ref[0]
+
+    @pl.when(i < nr)
+    def _():
+        e = be_ref[i]
+        first = jnp.logical_or(i == 0, be_ref[jnp.maximum(i - 1, 0)] != e)
+        last = jnp.logical_or(
+            i == nr - 1,
+            be_ref[jnp.minimum(i + 1, pl.num_programs(1) - 1)] != e)
+        part = jax.lax.dot_general(x_ref[...], dy_ref[...],
+                                   (((0,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+
+        @pl.when(first)
+        def _():
+            acc_ref[...] = part
+
+        @pl.when(jnp.logical_not(first))
+        def _():
+            acc_ref[...] += part
+
+        @pl.when(last)
+        def _():
+            o_ref[0] = acc_ref[...].astype(o_ref.dtype)
+
+
+def _last_real(i, nr):  # the last real block stands in for the ones past it
+    return jnp.minimum(i, jnp.maximum(nr[0] - 1, 0))
+
+
+def _tile(j, nr):  # no real block: one tile for the whole grid
+    return jnp.where(nr[0] > 0, j, 0)
+
+
+def _gmm_call(x, w, block_expert, n_real, block_rows, transpose_w):
+    """``x @ w[e]`` (or ``x @ w[e].T``) over the real blocks.  The whole
+    contraction in one step (VMEM holds one tile of the expert matrix, not
+    all of it: one Mixtral matrix is 117 MB in bf16), output tiles
+    outermost: the grid is (tiles) * n_blocks steps, and a step that does
+    nothing costs about a tenth of a microsecond."""
+    P, K = x.shape
+    n_blocks = P // block_rows
+    N = w.shape[1] if transpose_w else w.shape[2]
+    tn = _out_tile(K, N, w.dtype.itemsize)
+    if transpose_w:
+        w_spec = pl.BlockSpec(
+            (1, tn, K), lambda j, i, be, nr: (be[_last_real(i, nr)],
+                                              _tile(j, nr), 0))
+    else:
+        w_spec = pl.BlockSpec(
+            (1, K, tn), lambda j, i, be, nr: (be[_last_real(i, nr)], 0,
+                                              _tile(j, nr)))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(N // tn, n_blocks),
+        in_specs=[
+            pl.BlockSpec((block_rows, K),
+                         lambda j, i, be, nr: (_last_real(i, nr), 0)),
+            w_spec,
+        ],
+        out_specs=pl.BlockSpec(
+            (block_rows, tn),
+            lambda j, i, be, nr: (_last_real(i, nr), _tile(j, nr))),
+    )
+    in_flight = 2 * (K * tn * w.dtype.itemsize
+                     + block_rows * (K + tn) * x.dtype.itemsize)
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, transpose_w=transpose_w),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((P, N), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=in_flight + 16 * 2 ** 20),
+        interpret=pallas_interpret(),
+        name="dstpu_grouped_matmul_dx" if transpose_w
+        else "dstpu_grouped_matmul",
+    )(block_expert, n_real, x, w)
+
+
+def _gmm_dw_call(x, dy, block_expert, n_real, block_rows, n_experts, dtype):
+    """Per expert, ``x_blocks^T @ dy_blocks`` summed over its real blocks:
+    ``[E, H, F]``.  An expert without a block is never visited: its tiles
+    come back undefined and the caller zeroes them."""
+    P, H = x.shape
+    F = dy.shape[1]
+    n_blocks = P // block_rows
+    itemsize = jnp.dtype(dtype).itemsize
+    tn = _out_tile(H, F, itemsize)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(F // tn, n_blocks),
+        in_specs=[
+            pl.BlockSpec((block_rows, H),
+                         lambda j, i, be, nr: (_last_real(i, nr), 0)),
+            pl.BlockSpec((block_rows, tn),
+                         lambda j, i, be, nr: (_last_real(i, nr),
+                                               _tile(j, nr))),
+        ],
+        out_specs=pl.BlockSpec(
+            (1, H, tn), lambda j, i, be, nr: (be[_last_real(i, nr)], 0,
+                                              _tile(j, nr))),
+        scratch_shapes=[pltpu.VMEM((H, tn), jnp.float32)],
+    )
+    in_flight = (H * tn * (4 + 2 * itemsize)
+                 + 2 * block_rows * (H + tn) * x.dtype.itemsize)
+    return pl.pallas_call(
+        _gmm_dw_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n_experts, H, F), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=in_flight + 16 * 2 ** 20),
+        interpret=pallas_interpret(),
+        name="dstpu_grouped_matmul_dw",
+    )(block_expert, n_real, x, dy)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _gmm(x, w, block_expert, n_real, block_rows):
+    return _gmm_call(x, w, block_expert, n_real, block_rows, False)
+
+
+def _gmm_fwd(x, w, block_expert, n_real, block_rows):
+    return (_gmm(x, w, block_expert, n_real, block_rows),
+            (x, w, block_expert, n_real))
+
+
+def _gmm_bwd(block_rows, res, dy):
+    x, w, block_expert, n_real = res
+    dx = _gmm_call(dy, w, block_expert, n_real, block_rows, True)
+    dw = _gmm_dw_call(x, dy, block_expert, n_real, block_rows, w.shape[0],
+                      w.dtype)
+    # an expert no real block names was never visited by the kernel
+    n_blocks = block_expert.shape[0]
+    touched = jnp.zeros((w.shape[0],), jnp.bool_).at[block_expert].max(
+        jnp.arange(n_blocks) < n_real[0])
+    dw = jnp.where(touched[:, None, None], dw, jnp.zeros((), dw.dtype))
+    return dx, dw, None, None
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
 
 
 def grouped_matmul(x: jnp.ndarray, w: jnp.ndarray,
@@ -93,9 +248,16 @@ def grouped_matmul(x: jnp.ndarray, w: jnp.ndarray,
 
     ``impl="auto"`` is the kernel on TPU — never the XLA einsum — and the
     einsum on the CPU test tier, where interpreting the kernel would only
-    slow the tests.  The kernel is FORWARD-ONLY (no VJP yet): training a
-    dropless MoE on the chip fails at differentiation instead of quietly
-    taking the einsum."""
+    slow the tests.
+
+    The kernel differentiates (``custom_vjp``) into two more kernels over
+    the same real blocks: ``dstpu_grouped_matmul_dx``, the same product
+    against the transposed tiles (``dy @ w[e].T``; the rows of the blocks
+    past ``n_real`` of ``dx`` are as undefined as the forward's), and
+    ``dstpu_grouped_matmul_dw``, per expert ``x_blocks^T @ dy_blocks``
+    summed in float32 over the expert's blocks.  Neither reads a row of a
+    block past ``n_real``, so what those rows hold — NaN included — reaches
+    no gradient; an expert without a pick gets a zero gradient."""
     P, H = x.shape
     E, _, F = w.shape
     assert P % block_rows == 0, (P, block_rows)
@@ -109,41 +271,4 @@ def grouped_matmul(x: jnp.ndarray, w: jnp.ndarray,
 
     n_real = jnp.asarray(n_blocks if n_real is None else n_real,
                          jnp.int32).reshape(1)
-    # the whole contraction in one step (VMEM holds [H, tn] of the expert
-    # matrix, not all of it: one Mixtral matrix is 117 MB in bf16), output
-    # tiles outermost: the grid is (F / tn) * n_blocks steps, and a step
-    # that does nothing costs about a tenth of a microsecond
-    tn = _out_tile(H, F, w.dtype.itemsize)
-
-    def block(i, nr):  # the last real block stands in for the ones past it
-        return jnp.minimum(i, jnp.maximum(nr[0] - 1, 0))
-
-    def tile(j, nr):  # no real block: one tile for the whole grid
-        return jnp.where(nr[0] > 0, j, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(F // tn, n_blocks),
-        in_specs=[
-            pl.BlockSpec((block_rows, H),
-                         lambda j, i, be, nr: (block(i, nr), 0)),
-            pl.BlockSpec((1, H, tn),
-                         lambda j, i, be, nr: (be[block(i, nr)], 0,
-                                               tile(j, nr))),
-        ],
-        out_specs=pl.BlockSpec(
-            (block_rows, tn),
-            lambda j, i, be, nr: (block(i, nr), tile(j, nr))),
-    )
-    in_flight = 2 * (H * tn * w.dtype.itemsize
-                     + block_rows * (H + tn) * x.dtype.itemsize)
-    return pl.pallas_call(
-        _gmm_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((P, F), x.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=in_flight + 16 * 2 ** 20),
-        interpret=pallas_interpret(),
-        name="dstpu_grouped_matmul",
-    )(block_expert, n_real, x, w)
+    return _gmm(x, w, block_expert, n_real, block_rows)

@@ -258,6 +258,33 @@ class DeepSpeedTPUEngine:
             and getattr(self, "_pipe_hop_spec", None) is None
             and getattr(self, "_pipe_plan", None) is None
             and not self._pipe_schedule_active())
+        # expert-share counters (moe/sharded_moe.MOE_TRAIN_COUNTERS): a model
+        # whose config has an expert share and a stack of layer_types returns
+        # them from its loss; the fused step adds them to a device-resident
+        # sum that only moe_stats() reads.  Plain fused path only
+        self._moe_counters = (
+            _mc is not None and bool(getattr(_mc, "moe_held_count", 0))
+            and bool(getattr(_mc, "layer_types", ()))
+            and hasattr(_mc, "moe_counters")
+            and self.offload_optimizer is None
+            and not (self._qgz or self._hier_inner)
+            and getattr(self, "_overlap_plan", None) is None
+            and getattr(self, "_pipe_hop_spec", None) is None
+            and getattr(self, "_pipe_plan", None) is None
+            and not self._pipe_schedule_active())
+        self._moe_acc = None
+        self._moe_steps = 0
+        # leaves the optimizer leaves as they are (ModelSpec.buffers)
+        self._buffer_mask = None
+        buffers = tuple(getattr(self.model, "buffers", ()) or ())
+        if buffers:
+            import re
+
+            from .zero.strategy import _path_str
+
+            self._buffer_mask = jax.tree_util.tree_map_with_path(
+                lambda path, _: any(re.search(b, _path_str(path))
+                                    for b in buffers), self.state.params)
         self._init_comm_errors()
         self._compile_steps()
         self._wire_memory_ledger()
@@ -819,7 +846,7 @@ class DeepSpeedTPUEngine:
         )
 
     # ------------------------------------------------------------- programs
-    def _model_loss(self, p, batch, rng, act_stats=False):
+    def _model_loss(self, p, batch, rng, act_stats=False, moe_counters=False):
         """model.loss_fn with the engine's qwZ / stage-3-prefetch flags
         applied for the duration of the trace (not a permanent config
         mutation — engines may share a model object).
@@ -827,7 +854,9 @@ class DeepSpeedTPUEngine:
         ``act_stats``: numerics-observatory per-layer activation stats —
         set ONLY by the training trace (``_micro_grads``); the loss then
         returns ``(loss, [L, 3] act)`` (models/transformer.py).  The
-        eval path never sets it, so eval losses stay scalar."""
+        eval path never sets it, so eval losses stay scalar.
+        ``moe_counters``: likewise for an expert share's counters — the
+        loss then returns ``(loss, act or None, counters)``."""
         mc = getattr(self.model, "config", None)
         has_q = mc is not None and hasattr(mc, "qwz")
         has_pf = mc is not None and hasattr(mc, "zero3_prefetch")
@@ -835,7 +864,9 @@ class DeepSpeedTPUEngine:
         has_hop = mc is not None and hasattr(mc, "pipe_hop_spec")
         has_pp = mc is not None and hasattr(mc, "pipe_overlap_plan")
         has_nm = mc is not None and hasattr(mc, "numerics_act_stats")
-        if not (has_q or has_pf or has_ov or has_hop or has_pp or has_nm):
+        has_moe = mc is not None and hasattr(mc, "moe_counters")
+        if not (has_q or has_pf or has_ov or has_hop or has_pp or has_nm
+                or has_moe):
             return self.model.loss_fn(p, batch, rng)
         old_q = mc.qwz if has_q else None
         old_pf = mc.zero3_prefetch if has_pf else None
@@ -843,6 +874,9 @@ class DeepSpeedTPUEngine:
         old_hop = mc.pipe_hop_spec if has_hop else None
         old_pp = mc.pipe_overlap_plan if has_pp else None
         old_nm = mc.numerics_act_stats if has_nm else None
+        old_moe = mc.moe_counters if has_moe else None
+        if has_moe:
+            mc.moe_counters = bool(moe_counters)
         if has_q:
             mc.qwz = self._qwz
         if has_pf:
@@ -870,6 +904,8 @@ class DeepSpeedTPUEngine:
                 mc.pipe_overlap_plan = old_pp
             if has_nm:
                 mc.numerics_act_stats = old_nm
+            if has_moe:
+                mc.moe_counters = old_moe
 
     def _fetch_params(self, master_params):
         """Host-offloaded masters (offload_param): stream them into device
@@ -896,7 +932,8 @@ class DeepSpeedTPUEngine:
         + the updated compressed-collective EF residuals (None when no
         compressed path carries error feedback on this trace) + a numerics
         ``extras`` dict: ``"act"`` ([L, 3] per-layer activation stats when
-        the observatory's act stats ride this trace, else None) and
+        the observatory's act stats ride this trace, else None), ``"moe"``
+        (an expert share's int32 counters, else None) and
         ``"overflow"`` (the fp16 finiteness verdict over the post-cast
         grads — computed ONCE here and threaded both to the EF residual
         gate and, with ``want_overflow``, to ``_apply_step_body``'s skip
@@ -909,11 +946,16 @@ class DeepSpeedTPUEngine:
         if compute_params is None:
             compute_params = self._compute_params(state.params)
         act_on = getattr(self, "_numerics_act", False)
+        moe_on = getattr(self, "_moe_counters", False)
 
         def scaled_loss_fn(p, b=None):
             out = self._model_loss(p, b if b is not None else batch, rng,
-                                   act_stats=act_on)
-            loss, act = out if act_on else (out, None)
+                                   act_stats=act_on, moe_counters=moe_on)
+            if moe_on:
+                # the counters ride the slot of the activation stats
+                loss, act = out[0], {"act": out[1], "moe": out[2]}
+            else:
+                loss, act = out if act_on else (out, None)
             if self.fp16_enabled:
                 # scale in fp32: the default scale (2^16) overflows float16
                 return (loss.astype(jnp.float32) * state.loss_scale.cur_scale,
@@ -1009,14 +1051,21 @@ class DeepSpeedTPUEngine:
             from .zero.overlap import record_tail_reduce
 
             record_tail_reduce(self._overlap_struct["tail_bytes"])
-        return grads, loss, new_comm, {"act": act, "overflow": bad}
+        moe = None
+        if moe_on:
+            act, moe = act["act"], act["moe"]
+        return grads, loss, new_comm, {"act": act, "overflow": bad,
+                                       "moe": moe}
 
     def _micro_step_body(self, state: TrainState, batch, rng,
-                         compute_params=None, with_act=False):
+                         compute_params=None, with_act=False,
+                         with_moe=False):
         """One accumulation micro-step.  ``with_act`` (numerics scan
-        path only) returns ``(state, (loss, act))`` so the gas>1 scan
-        can stack the per-layer activation stats; the incremental API
-        keeps the plain ``(state, loss)`` shape."""
+        path only) and ``with_moe`` (fused scan only) return ``(state,
+        (loss, act or None, counters or None))`` so the gas>1 scan can
+        stack the per-layer activation stats and an expert share's
+        counters; the incremental API keeps the plain ``(state, loss)``
+        shape."""
         grads, loss, new_comm, extras = self._micro_grads(
             state, batch, rng, compute_params=compute_params)
         new_acc = jax.tree_util.tree_map(jnp.add, state.grad_acc, grads)
@@ -1025,7 +1074,10 @@ class DeepSpeedTPUEngine:
             comm_errors=(new_comm if new_comm is not None
                          else state.comm_errors))
         loss = loss.astype(jnp.float32)
-        return (state, (loss, extras["act"])) if with_act else (state, loss)
+        if with_act or with_moe:
+            return state, (loss, extras["act"] if with_act else None,
+                           extras["moe"] if with_moe else None)
+        return state, loss
 
     def _qgz_grads(self, scaled_loss_fn, compute_params, batch,
                    comm_errors=None):
@@ -1204,6 +1256,11 @@ class DeepSpeedTPUEngine:
             else:
                 updates, new_opt = self.optimizer.update(grads, opt_state, params)
                 new_params = optax.apply_updates(params, updates)
+            if getattr(self, "_buffer_mask", None) is not None:
+                # a buffer is state, not a parameter: no update, no decay
+                new_params = jax.tree_util.tree_map(
+                    lambda keep, old, new: old if keep else new,
+                    self._buffer_mask, params, new_params)
             return new_params, new_opt, jnp.asarray(0, jnp.int32)
 
         def skip_update(operand):
@@ -1249,7 +1306,8 @@ class DeepSpeedTPUEngine:
             global_grad_norm=norm,
         )
 
-    def _train_batch_body(self, state: TrainState, batches, rng):
+    def _train_batch_body(self, state: TrainState, batches, rng,
+                          moe_acc=None):
         """Fused full step: scan micro-batches then apply.  ``batches`` has a
         leading gradient-accumulation dim.  At gas=1 the micro-batch's
         gradients feed the update directly — no accumulation-buffer
@@ -1259,9 +1317,25 @@ class DeepSpeedTPUEngine:
         output rides the fused step: the in-graph stats tree
         (``_numerics_tree``) — device-resident until the existing
         steps_per_print boundary pulls it, so the hot path gains zero
-        host syncs."""
+        host syncs.
+
+        With an expert share's counters on (``_moe_counters``) the step
+        takes their running sum ``moe_acc`` and returns it, this step's
+        added, as its LAST output: device-resident until ``moe_stats()``."""
+        if getattr(self, "_moe_counters", False):
+            out, moe = self._train_batch_core(state, batches, rng)
+            # the sum comes back as it went in (replicated), so that a
+            # reset sum and a returned one meet one compiled program
+            return (*out, jax.lax.with_sharding_constraint(
+                moe_acc + moe, self.topology.replicated()))
+        return self._train_batch_core(state, batches, rng)[0]
+
+    def _train_batch_core(self, state: TrainState, batches, rng):
+        """-> (the fused step's outputs, this step's expert-share counters
+        summed over its micro-batches or None)."""
         gas = self.config.gradient_accumulation_steps or 1
         nm = getattr(self, "_numerics_fused", False)
+        moe_on = getattr(self, "_moe_counters", False)
         if gas == 1:
             batch = jax.tree_util.tree_map(lambda x: x[0], batches)
             # same rng stream as the scan path (split, don't use raw) so a
@@ -1275,24 +1349,27 @@ class DeepSpeedTPUEngine:
                                           overflow=extras["overflow"])
             loss = loss.astype(jnp.float32)
             if not nm:
-                return state, loss
-            return state, loss, self._numerics_tree(state, grads, loss,
-                                                    extras["act"])
+                return (state, loss), extras["moe"]
+            return (state, loss, self._numerics_tree(
+                state, grads, loss, extras["act"])), extras["moe"]
+        act_on = nm and getattr(self, "_numerics_act", False)
+        res = self._micro_scan_body(state, batches, rng, with_act=act_on,
+                                    with_moe=moe_on)
+        state, loss = res[0], res[1]
+        act = res[2] if act_on else None
+        moe = res[-1] if moe_on else None
         if nm:
-            act_on = getattr(self, "_numerics_act", False)
-            res = self._micro_scan_body(state, batches, rng,
-                                        with_act=act_on)
-            (state, loss), act = ((res[0], res[1]), res[2]) if act_on \
-                else (res, None)
             grads = state.grad_acc  # pre-apply: apply zeroes the buffer
             state = self._apply_step_body(state)
-            return state, loss, self._numerics_tree(state, grads, loss, act)
-        state, loss = self._micro_scan_body(state, batches, rng)
+            return (state, loss, self._numerics_tree(state, grads, loss,
+                                                     act)), moe
         state = self._apply_step_body(state)
-        return state, loss
+        return (state, loss), moe
 
     def _micro_scan_body(self, state: TrainState, batches, rng,
-                         with_act=False):
+                         with_act=False, with_moe=False):
+        """-> (state, mean loss[, act if ``with_act``][, the expert-share
+        counters summed over the micro-batches if ``with_moe``])."""
         gas = self.config.gradient_accumulation_steps or 1
         rngs = jax.random.split(rng, gas)
         compute_params = self._compute_params(state.params)
@@ -1301,18 +1378,23 @@ class DeepSpeedTPUEngine:
             batch, r = xs
             return self._micro_step_body(st, batch, r,
                                          compute_params=compute_params,
-                                         with_act=with_act)
+                                         with_act=with_act,
+                                         with_moe=with_moe)
 
         state, ys = jax.lax.scan(body, state, (batches, rngs))
-        if not with_act:
+        if not (with_act or with_moe):
             return state, jnp.mean(ys)
-        losses, acts = ys  # acts: [gas, L, 3]
-        # fold the per-micro-step rows the way each column means:
-        # norms average, max-abs maxes, nonfinite counts sum
-        act = jnp.stack([jnp.mean(acts[..., 0], axis=0),
-                         jnp.max(acts[..., 1], axis=0),
-                         jnp.sum(acts[..., 2], axis=0)], axis=-1)
-        return state, jnp.mean(losses), act
+        losses, acts, moe = ys  # acts: [gas, L, 3]
+        out = (state, jnp.mean(losses))
+        if with_act:
+            # fold the per-micro-step rows the way each column means:
+            # norms average, max-abs maxes, nonfinite counts sum
+            out += (jnp.stack([jnp.mean(acts[..., 0], axis=0),
+                               jnp.max(acts[..., 1], axis=0),
+                               jnp.sum(acts[..., 2], axis=0)], axis=-1),)
+        if with_moe:
+            out += (jnp.sum(moe, axis=0),)
+        return out
 
     def _numerics_tree(self, state: TrainState, grads, loss, act):
         """In-graph numerics stats tree (telemetry/numerics.py) — the
@@ -1444,6 +1526,8 @@ class DeepSpeedTPUEngine:
                 out_sh = ((state_sh, None, None)
                           if getattr(self, "_numerics_fused", False)
                           else (state_sh, None))
+                if getattr(self, "_moe_counters", False):
+                    out_sh += (self.topology.replicated(),)
                 self._train_batch = jax.jit(self._train_batch_body,
                                             out_shardings=out_sh,
                                             **donate)
@@ -1804,15 +1888,24 @@ class DeepSpeedTPUEngine:
             with cap, trace, span("train_batch", cat="train",
                                   step=self.global_steps):
                 with self.topology.mesh:
+                    if getattr(self, "_moe_counters", False):
+                        # the counters' running sum goes in and comes out,
+                        # device-resident (no sync): moe_stats() reads it
+                        if self._moe_acc is None:
+                            self._moe_acc = self._moe_zeros()
+                        *out, self._moe_acc = self._train_batch(
+                            self.state, batch, self._next_rng(),
+                            self._moe_acc)
+                        self._moe_steps += 1
+                    else:
+                        out = self._train_batch(self.state, batch,
+                                                self._next_rng())
                     if getattr(self, "_numerics_fused", False):
                         # stats stay device-resident (no sync): pulled at
                         # the steps_per_print boundary by _report_telemetry
-                        self.state, loss, self._last_numerics = \
-                            self._train_batch(self.state, batch,
-                                              self._next_rng())
+                        self.state, loss, self._last_numerics = out
                     else:
-                        self.state, loss = self._train_batch(
-                            self.state, batch, self._next_rng())
+                        self.state, loss = out
                 self._repin_opt_state()
                 if self.offload_optimizer is not None:
                     self._apply_step_offload()
@@ -2005,6 +2098,20 @@ class DeepSpeedTPUEngine:
             "DEPRECATED alias of "
             "deepspeed_tpu_train_exposed_collective_seconds_estimated "
             "(renamed to make the byte-model nature explicit)")
+        self._m_moe_picks = reg.gauge(
+            "deepspeed_tpu_train_moe_held_picks_per_step",
+            "picks that landed on this chip's held experts, all expert "
+            "layers, per step over the last reporting window "
+            "(engine.moe_stats())")
+        self._m_moe_pad = reg.gauge(
+            "deepspeed_tpu_train_moe_pad_share",
+            "rows the grouped expert matmuls ran (blocks that hold picks) "
+            "over the held picks, last reporting window; 1 = no padding")
+        self._m_moe_load = reg.gauge(
+            "deepspeed_tpu_train_moe_load_max_over_mean",
+            "the fullest held expert's picks over the mean held expert's, "
+            "averaged over expert layers, last reporting window: the "
+            "imbalance the dropless path carries")
         self._m_pipe_bubble = reg.gauge(
             "deepspeed_tpu_train_pipe_bubble_fraction",
             "structural share of pipe-schedule ticks that are warm-up/"
@@ -2215,6 +2322,7 @@ class DeepSpeedTPUEngine:
         # boundary-cadence device_get), feed the anomaly sentinel, run
         # the cross-rank divergence audit at its cadence
         self._numerics_boundary(loss)
+        self._moe_boundary()
         if self._win_time > 0:
             bs = self.config.train_batch_size or 1
             self._m_samples_ps.set(self._win_steps * bs / self._win_time)
@@ -2239,6 +2347,60 @@ class DeepSpeedTPUEngine:
         if self.monitor is not None:
             self.monitor.write_registry(tm.registry, self.global_steps)
         tm.export(self.global_steps)
+
+    def _moe_shape(self) -> Tuple[int, int]:
+        """Of the counters' sum: ``[expert layers, held + 3]``."""
+        from ..models.layer_types import stack_runs
+
+        mc = self.model.config
+        layers = sum(n for _k, ffn, n in stack_runs(mc) if ffn == "experts")
+        return layers, mc.moe_held_count + 3
+
+    def _moe_zeros(self):
+        """The counters' sum at zero, int32, placed as the step returns it."""
+        return jax.device_put(np.zeros(self._moe_shape(), np.int32),
+                              self.topology.replicated())
+
+    def moe_stats(self) -> Optional[dict]:
+        """What the expert share's layers did since the last call, pulled
+        from the device (one ``device_get``) and reset: per expert layer the
+        picks on each held expert (``picks``), the rows each of the three
+        grouped-matmul kernels ran (``rows_run``: the blocks that hold
+        picks), the rows of the worst-case buffer their grids span
+        (``rows_grid``) and the layer's calls (``calls``: one a micro-batch),
+        all summed over ``steps`` fused steps.  None for a model without an
+        expert share.  The engine calls it at the ``steps_per_print``
+        boundary into the ``deepspeed_tpu_train_moe_*`` gauges; nothing else
+        syncs on the counters."""
+        if not getattr(self, "_moe_counters", False):
+            return None
+        held = self.model.config.moe_held_count
+        steps, self._moe_steps = self._moe_steps, 0
+        if self._moe_acc is None:
+            layers, width = self._moe_shape()
+            rows = [[0] * width for _ in range(layers)]
+        else:
+            # dstpu-lint: allow[host-sync] the caller's cadence: the
+            # steps_per_print boundary or an explicit read, never a step
+            rows = jax.device_get(self._moe_acc).tolist()
+            self._moe_acc = self._moe_zeros()
+        return {"steps": steps,
+                "picks": [r[:held] for r in rows],
+                "rows_run": [r[held] for r in rows],
+                "rows_grid": [r[held + 1] for r in rows],
+                "calls": [r[held + 2] for r in rows]}
+
+    def _moe_boundary(self) -> None:
+        st = self.moe_stats()
+        if not st or not st["steps"]:
+            return
+        total = sum(sum(layer) for layer in st["picks"])
+        self._m_moe_picks.set(total / st["steps"])
+        if total > 0:
+            self._m_moe_pad.set(sum(st["rows_run"]) / total)
+            self._m_moe_load.set(sum(
+                max(layer) * len(layer) / max(sum(layer), 1)
+                for layer in st["picks"]) / len(st["picks"]))
 
     def _numerics_boundary(self, loss) -> None:
         """Numerics-observatory boundary (called from _report_telemetry

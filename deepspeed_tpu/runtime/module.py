@@ -28,8 +28,13 @@ class ModelSpec:
                  loss_fn: Callable[[Any, Any, Any], Any],
                  partition_rules: Optional[Sequence[Tuple[str, P]]] = None,
                  apply_fn: Optional[Callable] = None,
-                 flops_per_sample: Optional[float] = None):
+                 flops_per_sample: Optional[float] = None,
+                 buffers: Sequence[str] = ()):
         self.init_params = init_params
+        #: regexes over parameter paths: leaves that are state the optimizer
+        #: must leave as they are (no update, no weight decay) — they are
+        #: cast, sharded and checkpointed with the parameters
+        self.buffers = tuple(buffers)
         self.loss_fn = loss_fn
         self._partition_rules = list(partition_rules or [])
         self.apply_fn = apply_fn  # inference/eval forward (params, batch) -> outputs

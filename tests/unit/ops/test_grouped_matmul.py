@@ -251,3 +251,123 @@ def test_grid_rows_equal_padded_rows_when_every_block_is_full(monkeypatch):
     (0.01, "bfloat16", 16), (40, "float32", 128), (5, "float32", 16)])
 def test_block_height_follows_the_expected_picks(picks, dtype, want):
     assert expert_block_rows(picks, jnp.dtype(dtype)) == want
+
+
+# ------------------------------------------------------------- the backward
+@pytest.mark.parametrize("shape", ["gate", "down"])
+@pytest.mark.parametrize("block_rows", [8, 32])
+def test_vjp_matches_per_expert_autodiff_and_reads_no_row_past_n_real(
+        block_rows, shape, monkeypatch):
+    """dX and dW of the kernel against ``jnp`` autodiff of a plain
+    per-expert product, with experts 1 and 4 without a pick (zero gradient),
+    a ragged last block in every touched expert, more than one output tile,
+    and NaN planted in every row of the blocks past ``n_real`` — of x, and,
+    by the interpreter, of what the forward and dX kernels never write: none
+    of it reaches dX's gathered rows or dW (ISSUE 32)."""
+    monkeypatch.setattr(gmm_mod, "_W_TILE_BYTES", 128 * 256 * 4)
+    H, F = (256, 384) if shape == "down" else (384, 256)
+    E = 6
+    rng = np.random.default_rng(block_rows + H)
+    key = jnp.asarray(rng.choice([0, 2, 2, 2, 3, 5, E], 45), jnp.int32)
+    order, dest, n_rows, be, n_real = sort_pad_by_expert(key, E, block_rows)
+    counts, real = _layout(np.asarray(key), E, block_rows)
+    assert counts[1] == counts[4] == 0 and real < n_rows // block_rows
+    assert any(c % block_rows for c in counts)
+    rows = jnp.asarray(rng.standard_normal((45, H)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((E, H, F)) / np.sqrt(H), jnp.float32)
+    past = (jnp.arange(n_rows) // block_rows >= n_real)[:, None]
+
+    def through_kernel(rows, w):
+        xs = jnp.zeros((n_rows, H), rows.dtype).at[dest].set(
+            rows[order], mode="drop")
+        xs = jnp.where(past, jnp.nan, xs)
+        ys = grouped_matmul(xs, w, be, block_rows, impl="pallas",
+                            n_real=n_real)
+        got = ys.at[dest].get(mode="fill", fill_value=0)
+        return jnp.sum(jnp.sin(got)), got
+
+    def per_expert(rows, w):
+        srt = rows[order]
+        ks = key[order]
+        out = jnp.zeros((45, F), rows.dtype)
+        for e in range(E):
+            out = out + jnp.where((ks == e)[:, None], srt @ w[e], 0.0)
+        return jnp.sum(jnp.sin(out)), out
+
+    (_, got), (gx, gw) = jax.value_and_grad(through_kernel, (0, 1),
+                                            has_aux=True)(rows, w)
+    (_, want), (rx, rw) = jax.value_and_grad(per_expert, (0, 1),
+                                             has_aux=True)(rows, w)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    assert np.isfinite(np.asarray(gx)).all()
+    assert np.isfinite(np.asarray(gw)).all()
+    np.testing.assert_allclose(np.asarray(gx), np.asarray(rx), atol=1e-5)
+    np.testing.assert_allclose(np.asarray(gw), np.asarray(rw), atol=1e-5)
+    assert not np.asarray(gw)[[1, 4]].any()
+    # a pick on an absent expert (key E) gets no gradient
+    assert not np.asarray(gx)[np.asarray(key) == E].any()
+
+
+def test_vjp_in_bfloat16_sums_an_experts_blocks_in_float32():
+    """dW of an expert spread over many blocks: the kernel adds the blocks'
+    products in a float32 scratch and rounds once."""
+    H, F, E, block_rows = 128, 128, 2, 16
+    rng = np.random.default_rng(3)
+    key = jnp.asarray([0] * 200 + [1] * 8, jnp.int32)
+    order, dest, n_rows, be, n_real = sort_pad_by_expert(key, E, block_rows)
+    rows = jnp.asarray(rng.standard_normal((208, H)), jnp.bfloat16)
+    w = jnp.asarray(rng.standard_normal((E, H, F)) / np.sqrt(H), jnp.bfloat16)
+    up = jnp.asarray(rng.standard_normal((208, F)), jnp.bfloat16)
+
+    def f(w, impl):
+        xs = jnp.zeros((n_rows, H), rows.dtype).at[dest].set(
+            rows[order], mode="drop")
+        ys = grouped_matmul(xs, w, be, block_rows, impl=impl,
+                            n_real=n_real if impl == "pallas" else None)
+        return jnp.sum(ys.at[dest].get(mode="fill", fill_value=0)
+                       .astype(jnp.float32) * up.astype(jnp.float32))
+
+    got = jax.grad(f)(w, "pallas")
+    assert got.dtype == jnp.bfloat16
+    exact = np.einsum("th,tf->hf", np.asarray(rows[order][:200], np.float64),
+                      np.asarray(up[:200], np.float64))
+    err = np.abs(np.asarray(got[0], np.float64) - exact).max()
+    assert err <= 2.0 ** -8 * np.abs(exact).max()
+
+
+def test_the_dropless_tail_differentiates_through_the_kernels():
+    """``_sorted_expert_ffn`` (three grouped matmuls and SwiGLU between)
+    under ``jax.grad`` with the kernels interpreted, against the einsum
+    path: weights, tokens and gates."""
+    H, F, E, K, T = 128, 256, 4, 2, 24
+    rng = np.random.default_rng(9)
+    xt = jnp.asarray(rng.standard_normal((T, H)), jnp.float32)
+    key = jnp.asarray(rng.choice([0, 1, 3, E], T * K), jnp.int32)
+    gate = jnp.asarray(rng.random(T * K), jnp.float32)
+    experts = {n: jnp.asarray(rng.standard_normal(s) / np.sqrt(s[1]),
+                              jnp.float32)
+               for n, s in (("w_gate", (E, H, F)), ("w_up", (E, H, F)),
+                            ("w_down", (E, F, H)))}
+
+    def f(experts, xt, gate, impl):
+        real = grouped_matmul
+
+        def gm(x, w, be, bs=128, impl_="auto", n_real=None):
+            return real(x, w, be, bs, impl=impl,
+                        n_real=n_real if impl == "pallas" else None)
+
+        gmm_mod.grouped_matmul = gm
+        try:
+            out, _, _ = _sorted_expert_ffn(xt, key, gate, K, E, experts,
+                                           "swiglu", 8)
+        finally:
+            gmm_mod.grouped_matmul = real
+        return jnp.sum(jnp.cos(out))
+
+    got = jax.grad(f, (0, 1, 2))(experts, xt, gate, "pallas")
+    want = jax.grad(f, (0, 1, 2))(experts, xt, gate, "xla")
+    for g, r in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert np.isfinite(np.asarray(g)).all()
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=2e-5)
+    assert not np.asarray(got[0]["w_up"])[2].any()

@@ -317,8 +317,9 @@ def test_what_a_model_with_state_refuses(what):
             eng.export_sequence(uid)
     else:
         model = solar_open2_model("tiny")
+        # the delta-rule scan alone: grouped_matmul has its backward (PR 32)
         with pytest.raises(NotImplementedError,
-                           match="dstpu_kda_chunk.*grouped_matmul"):
+                           match="dstpu_kda_chunk[^;]*does not exist"):
             model.loss_fn(None, None, None)
 
 

@@ -4,6 +4,8 @@
     python tools/aot_train_step.py --mesh data=4 --stage 3 --layers 2
     python tools/aot_train_step.py --mesh model=2,data=2 --stage 1 --gas 2
     python tools/aot_train_step.py --mesh data=1 --layers 1 --gas 4
+    python tools/aot_train_step.py --config benchmark/configs/<name>.json \
+        --traffic benchmark/traffic/<name>.json [--remat 0|1]
 
 libtpu compiles against a ``v5e:2x2`` topology description on a machine that
 has no TPU, so the real ``deepspeed_tpu.initialize`` -> ``train_batch`` program
@@ -14,6 +16,13 @@ partitioned"), out-of-memory programs, donation that does not alias, a kernel
 that is missing from the step.  Printed per run: compile seconds, XLA's
 per-device memory analysis, the kernel names in the lowered text, and the
 collective counts of the optimized HLO.
+
+``--config`` compiles a benchmark training cell's own step instead: the
+model its family builds (``benchmark/families/<family>.py``) at the
+configuration's sizes, the engine options and mesh it states, and — with
+``--traffic`` — the cell's sequence length, micro-batch and accumulation
+steps.  ``--remat`` overrides the configuration's ``remat`` key, so that a cut
+can quote the analysis with and without recomputation.
 
 It compiles; it does not run.  No time, rate or numeric result comes from here
 — ``chip_smoke.py`` on the chip is the proof that the step is right.
@@ -78,6 +87,14 @@ def main() -> int:
     ap.add_argument("--gas", type=int, default=1)
     ap.add_argument("--zero", default="",
                     help="extra zero_optimization keys, key=true,...")
+    ap.add_argument("--config", default="",
+                    help="a benchmark training configuration file: its "
+                         "family, sizes, engine options and mesh")
+    ap.add_argument("--traffic", default="",
+                    help="with --config: the cell's traffic file (sequence "
+                         "length, micro-batch, accumulation steps)")
+    ap.add_argument("--remat", type=int, choices=(0, 1), default=None,
+                    help="with --config: override its remat key")
     args = ap.parse_args()
 
     from jax.experimental import topologies
@@ -90,9 +107,34 @@ def main() -> int:
     from deepspeed_tpu.runtime.engine import DeepSpeedTPUEngine
 
     plat.platform = lambda: "tpu"  # compiled kernels, not interpret mode
+    # a cache entry written from here makes a chip call's runs cold (PR 26)
+    jax.config.update("jax_enable_compilation_cache", False)
     DeepSpeedTPUEngine._init_state = _abstract_init_state
     mesh = {k: int(v) for k, v in (kv.split("=")
                                    for kv in args.mesh.split(","))}
+    bench = None
+    if args.config:
+        import importlib.util
+        import json
+
+        with open(args.config, encoding="utf-8") as f:
+            bench = json.load(f)
+        if args.traffic:
+            with open(args.traffic, encoding="utf-8") as f:
+                tr = json.load(f)
+            args.seq = int(tr["sequence_length"])
+            args.micro = int(tr["micro_batch_per_chip"])
+            args.gas = int(tr["gradient_accumulation_steps"])
+        if args.remat is not None:
+            bench["remat"] = bool(args.remat)
+        mesh = dict(bench["mesh"])
+        args.layers = int(bench["num_hidden_layers"])
+        spec = importlib.util.spec_from_file_location(
+            "aot_family", os.path.join(
+                os.path.dirname(os.path.dirname(os.path.abspath(
+                    args.config))), "families", bench["family"] + ".py"))
+        family = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(family)
     n = 1
     for v in mesh.values():
         n *= v
@@ -101,30 +143,44 @@ def main() -> int:
     zero = {"stage": args.stage}
     zero.update({k: v == "true" for k, v in (
         kv.split("=") for kv in args.zero.split(",") if kv)})
-    engine, _, _, _ = deepspeed_tpu.initialize(
-        model=mistral_model("7b", max_seq_len=args.seq, n_layers=args.layers),
-        config={"train_micro_batch_size_per_gpu": args.micro,
-                "gradient_accumulation_steps": args.gas,
-                "bf16": {"enabled": True}, "gradient_clipping": 1.0,
-                "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
-                "zero_optimization": zero, "mesh": mesh},
-        topology=topo)
+    config = {"bf16": {"enabled": True}, "gradient_clipping": 1.0,
+              "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
+              "zero_optimization": zero}
+    if bench is not None:
+        model = family.build(bench, args.layers, args.seq, jnp.float32)
+        config = dict(bench["engine"])
+        zero = config["zero_optimization"]
+    else:
+        model = mistral_model("7b", max_seq_len=args.seq,
+                              n_layers=args.layers)
+    config.update({"train_micro_batch_size_per_gpu": args.micro,
+                   "gradient_accumulation_steps": args.gas, "mesh": mesh,
+                   "steps_per_print": 10 ** 9})
+    engine, _, _, _ = deepspeed_tpu.initialize(model=model, config=config,
+                                               topology=topo)
     rep = topo.replicated()
     batch = jax.ShapeDtypeStruct(
         (args.gas, args.micro * topo.dp_world_size, args.seq), jnp.int32,
         sharding=rep)
     key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)
 
+    step_args = (engine.state, batch, key)
+    if getattr(engine, "_moe_counters", False):
+        # an expert share's step takes its counters' running sum
+        step_args += (jax.ShapeDtypeStruct(engine._moe_shape(), jnp.int32,
+                                           sharding=rep),)
     t0 = time.time()
     with topo.mesh:
-        lowered = engine._train_batch.lower(engine.state, batch, key)
+        lowered = engine._train_batch.lower(*step_args)
         text = lowered.as_text()
         compiled = lowered.compile()
     mem = compiled.memory_analysis()
     gib = 2.0 ** 30
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    print(f"aot_train_step: {devices[0].device_kind!r} x{n} mesh={mesh} "
+    what = f"config={bench['name']} remat={bool(bench.get('remat'))} " \
+        if bench is not None else ""
+    print(f"aot_train_step: {what}{devices[0].device_kind!r} x{n} mesh={mesh} "
           f"zero={zero} layers={args.layers} seq={args.seq} "
           f"micro={args.micro} gas={args.gas}")
     print(f"  compiled in {time.time() - t0:.1f} s")

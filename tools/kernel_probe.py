@@ -56,6 +56,11 @@ def cases():
                     flash_grads, [((1, s, 32, 128), bf), ((1, s, 8, 128), bf),
                                   ((1, s, 8, 128), bf)], None, 0))
 
+    # head_dim 64 (half a lane tile) at 8k, the LFM2 cell's attention layer
+    out.append(("flash_attention fwd+bwd seq=8192 32/8 heads D=64",
+                flash_grads, [((1, 8192, 32, 64), bf), ((1, 8192, 8, 64), bf),
+                              ((1, 8192, 8, 64), bf)], None, 0))
+
     # paged decode at the two serving cells' geometries (rows x table, G)
     # and the int8 pool; compile only: chip_smoke.py holds its numerics
     def paged(q, k, v, table, pos, act, *scales):
@@ -105,6 +110,21 @@ def cases():
                         share("pallas"), [((picks, h), bf), ((40, h, f), bf),
                                           ((picks,), i32)],
                         share("xla"), 2.0 ** -7))
+
+    # the LFM2 training share (8 of 32 experts, 4 picks a token, 8192 tokens:
+    # 32,768 picks of which a quarter land here): forward and both backward
+    # kernels, gate / up 2048 x 1792 and down 1792 x 2048
+    def share_grads(impl):
+        def run(rows, w, key):
+            return jax.grad(lambda r, m: share(impl)(r, m, key).astype(f32)
+                            .sum(), argnums=(0, 1))(rows, w)
+        return run
+
+    for h, f in ((2048, 1792), (1792, 2048)):
+        out.append((f"grouped_matmul fwd+dx+dw share E=8 H={h} F={f} "
+                    "picks=32768 of 32 experts", share_grads("pallas"),
+                    [((32768, h), bf), ((8, h, f), bf), ((32768,), i32)],
+                    share_grads("xla"), 2.0 ** -6))
 
     # Mistral-7B down projection, decode batch of 4
     for bits in (8, 4):
