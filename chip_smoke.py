@@ -253,12 +253,16 @@ def leg_kernels(sz, on_chip: bool) -> None:
 
 def expert_matmul_checks(moe, on_chip: bool) -> None:
     """The grouped expert matmul at an expert share's shapes: against the
-    einsum on the rows that hold picks, and its time following the experts
-    touched, not the worst-case buffer."""
+    einsum on the rows that hold picks, its time following the experts
+    touched, not the worst-case buffer, and the whole tail — the rows laid by
+    ``dstpu_moe_dispatch`` and collected by ``dstpu_moe_combine`` around the
+    matmuls — against the XLA form, the tokens' and the gates' gradients
+    included."""
     import jax
     import jax.numpy as jnp
 
-    from deepspeed_tpu.moe.sharded_moe import sort_pad_by_expert
+    from deepspeed_tpu.moe.sharded_moe import (_sorted_expert_ffn,
+                                               sort_pad_by_expert)
     from deepspeed_tpu.ops.pallas.grouped_matmul import (expert_block_rows,
                                                          grouped_matmul)
 
@@ -299,6 +303,46 @@ def expert_matmul_checks(moe, on_chip: bool) -> None:
                   f"({int(n_real)} hold picks) vs the einsum: rel err "
                   f"{e:.2e} < {TOL_FWD:.2e}")
             del got, ref
+
+    # the layer's tail as the share runs it (8 picks a token, gates on them):
+    # laid and collected by the two row kernels around the up and down
+    # matmuls, against XLA's scatter and gathers around the einsums in
+    # float32
+    f32, top_k = jnp.float32, 8
+    experts = {"w_up": w_up, "w_down": w_down}
+    for picks in moe["picks"]:
+        bs = expert_block_rows(picks / moe["experts"], bf)
+        key = jax.random.randint(ks[2], (picks,), 0, moe["experts"])
+        key = jnp.minimum(key, E).astype(jnp.int32)
+        xt = jax.random.normal(ks[3], (picks // top_k, H), bf)
+        gate = jax.random.uniform(ks[4], (picks,), f32)
+
+        def tail(impl):
+            def loss(xt, gate, experts):
+                out, _, _, _ = _sorted_expert_ffn(xt, key, gate, top_k, E,
+                                                  experts, "gelu", bs,
+                                                  impl=impl)
+                return (out.astype(f32) * jnp.cos(
+                    jnp.arange(H, dtype=f32))).sum(), out
+            # the tokens' and the gates' gradients are the two row kernels'
+            # transposes; the expert matrices' is the grouped matmul's dW,
+            # which runs at a trained share's shapes (tools/kernel_probe.py)
+            # and at this share's asks for more VMEM than a kernel may have
+            return jax.jit(jax.value_and_grad(loss, (0, 1), has_aux=True))
+
+        (_, got), g_got = tail("pallas")(xt, gate, experts)
+        (_, ref), g_ref = tail("xla")(xt.astype(f32), gate, {
+            n: w.astype(f32) for n, w in experts.items()})
+        e = rel_err(got, ref)
+        check(e < TOL_FWD and bool(jnp.any(ref)),
+              f"expert tail through dstpu_moe_dispatch / _combine, {picks} "
+              f"picks of {top_k} a token vs the XLA form: rel err {e:.2e} < "
+              f"{TOL_FWD:.2e}")
+        for name, a, b in zip(("d tokens", "d gates"), g_got, g_ref):
+            e = rel_err(a, b)
+            check(e < TOL_BWD, f"expert tail backward {name}: rel err "
+                  f"{e:.2e} < {TOL_BWD:.2e}")
+        del got, ref, g_got, g_ref
 
     # a decode call's picks; all, a quarter and none of the experts touched,
     # about three picks a touched expert as the router gives; 256 calls in

@@ -165,6 +165,30 @@ def _gate_and_aux(logits: jnp.ndarray, cfg: MoEConfig, rng=None, bias=None):
     return gates, expert_idx, gate_k, aux
 
 
+def _expert_blocks(key: jnp.ndarray, n_experts: int, block_rows: int):
+    """The padded buffer's layout from the picks' keys alone (``key`` values
+    >= n_experts mark picks not computed here).  Returns (hit ``[E, N]`` bool:
+    pick n is on expert e; counts, starts_raw, starts_b ``[E]``: each
+    expert's picks, its first position among the sorted picks and its first
+    buffer row; n_rows, block_expert, n_real as ``sort_pad_by_expert`` gives
+    them).  The counts are a compare and a sum, not a scatter-add of N."""
+    N = key.shape[0]
+    hit = key[None, :] == jnp.arange(n_experts, dtype=key.dtype)[:, None]
+    counts = jnp.sum(hit, axis=1, dtype=jnp.int32)
+    starts_raw = jnp.cumsum(counts) - counts
+    padded = ((counts + block_rows - 1) // block_rows) * block_rows
+    starts_b = jnp.cumsum(padded) - padded
+    most_touched = min(n_experts, N)
+    n_rows = max(1, most_touched
+                 + (N - most_touched) // block_rows) * block_rows
+    block_starts = jnp.arange(n_rows // block_rows) * block_rows
+    block_expert = jnp.clip(
+        jnp.searchsorted(starts_b, block_starts, side="right") - 1,
+        0, n_experts - 1).astype(jnp.int32)
+    n_real = (jnp.sum(padded) // block_rows).astype(jnp.int32)
+    return hit, counts, starts_raw, starts_b, n_rows, block_expert, n_real
+
+
 def sort_pad_by_expert(key: jnp.ndarray, n_experts: int, block_rows: int):
     """Sort rows by expert key and compute block-padded destinations for the
     grouped matmul.  ``key`` values >= n_experts mark INVALID rows (they sort
@@ -182,35 +206,59 @@ def sort_pad_by_expert(key: jnp.ndarray, n_experts: int, block_rows: int):
                    ``dest`` points into them only
     """
     N = key.shape[0]
-    counts = jnp.bincount(jnp.minimum(key, n_experts),
-                          length=n_experts + 1)[:n_experts]
+    _, _, starts_raw, starts_b, n_rows, block_expert, n_real = _expert_blocks(
+        key, n_experts, block_rows)
     order = jnp.argsort(key, stable=True)
     key_s = key[order]
-    starts_raw = jnp.cumsum(counts) - counts
-    padded = ((counts + block_rows - 1) // block_rows) * block_rows
-    starts_b = jnp.cumsum(padded) - padded
-    most_touched = min(n_experts, N)
-    n_rows = max(1, most_touched
-                 + (N - most_touched) // block_rows) * block_rows
     se = jnp.clip(key_s, 0, n_experts - 1)
     dest = jnp.where(key_s < n_experts,
                      starts_b[se] + (jnp.arange(N) - starts_raw[se]), n_rows)
-    block_starts = jnp.arange(n_rows // block_rows) * block_rows
-    block_expert = jnp.clip(
-        jnp.searchsorted(starts_b, block_starts, side="right") - 1,
-        0, n_experts - 1).astype(jnp.int32)
-    n_real = (jnp.sum(padded) // block_rows).astype(jnp.int32)
     return order, dest, n_rows, block_expert, n_real
 
 
+def pick_row_maps(key: jnp.ndarray, top_k: int, n_experts: int,
+                  block_rows: int):
+    """The same layout as ``sort_pad_by_expert``'s, as the maps the dispatch
+    and combine kernels walk (``ops/pallas/moe_dispatch.py``), built without
+    a gather or a scatter of N index rows: XLA's cost by the index row, held
+    or not.  Returns (row_pick ``[n_rows]``: the pick each buffer row holds —
+    by arithmetic from the layout: row ``r`` of a block of expert ``e`` is
+    sorted position ``starts_raw[e] + r - starts_b[e]``, a block's positions
+    follow each other, so a block is one slice of the sort's order; n_valid
+    ``[n_blocks]``: the rows of each block that hold a pick, 0 from ``n_real``
+    on; dest ``[T, top_k]``: each pick's buffer row — its expert's first row
+    plus its rank among the expert's picks, a cumulative sum — and -1 where
+    the pick is not held; counts ``[E]``; n_rows, block_expert, n_real)."""
+    N = key.shape[0]
+    hit, counts, starts_raw, starts_b, n_rows, block_expert, n_real = \
+        _expert_blocks(key, n_experts, block_rows)
+    rank = jnp.cumsum(hit, axis=1, dtype=jnp.int32) - 1
+    dest = jnp.sum(jnp.where(hit, starts_b[:, None] + rank, 0), axis=0,
+                   dtype=jnp.int32)
+    dest = jnp.where(key < n_experts, dest, -1).reshape(N // top_k, top_k)
+    n_blocks = n_rows // block_rows
+    into = jnp.arange(n_blocks) * block_rows - starts_b[block_expert]
+    n_valid = jnp.where(jnp.arange(n_blocks) < n_real,
+                        jnp.clip(counts[block_expert] - into, 0, block_rows),
+                        0).astype(jnp.int32)
+    order = jnp.pad(jnp.argsort(key, stable=True).astype(jnp.int32),
+                    (0, block_rows))
+    first = jnp.clip(starts_raw[block_expert] + into, 0, N)
+    row_pick = jax.vmap(
+        lambda p: jax.lax.dynamic_slice(order, (p,), (block_rows,)))(first)
+    return (row_pick.reshape(n_rows), n_valid, dest, counts, n_rows,
+            block_expert, n_real)
+
+
 def _expert_ffn_blocks(xs, experts, block_expert, n_real, activation,
-                       block_rows):
+                       block_rows, impl="auto"):
     """The three grouped matmuls of one FFN over sorted+padded tokens (the
     rows of the blocks past ``n_real`` come back undefined)."""
     from ..ops.pallas.grouped_matmul import grouped_matmul
 
     def gm(a, w):
-        return grouped_matmul(a, w, block_expert, block_rows, n_real=n_real)
+        return grouped_matmul(a, w, block_expert, block_rows, impl=impl,
+                              n_real=n_real)
 
     if activation == "swiglu":
         h = jax.nn.silu(gm(xs, experts["w_gate"])) * gm(xs, experts["w_up"])
@@ -220,25 +268,45 @@ def _expert_ffn_blocks(xs, experts, block_expert, n_real, activation,
 
 
 def _sorted_expert_ffn(xt, key, gate, top_k: int, n_experts: int, experts,
-                       activation: str, block_rows: int):
+                       activation: str, block_rows: int, impl: str = "auto"):
     """The dropless tail: ``xt [T, H]`` tokens, ``key`` / ``gate``
     ``[T * top_k]`` each pick's expert (``>= n_experts``: not computed here)
     and weight.  Picks are sorted and padded by expert, run through the
-    grouped matmuls and added back to their tokens; an invalid pick is
-    scattered out of bounds (dropped) and gathered as zero.  Returns (the
-    tokens' sums, the rows of the blocks that hold picks — the rows the
-    kernel runs, the rows of the worst-case buffer its grid spans)."""
+    grouped matmuls and added back to their tokens.  ``impl="auto"`` moves
+    the rows through the two row kernels on TPU (``dstpu_moe_dispatch``,
+    ``dstpu_moe_combine``: the work of the picks held here) and through
+    XLA's scatter and gathers on the CPU test tier (``"xla"``, the reference
+    form: an invalid pick is scattered out of bounds and gathered as zero),
+    as ``grouped_matmul``, which takes the same ``impl``, chooses its own.
+    Returns (the tokens' sums, the picks on each expert, the rows of the
+    blocks that hold picks — the rows each kernel runs, the rows of the
+    worst-case buffer the grids span)."""
+    from ..ops.pallas import moe_dispatch as rows
+
+    if impl == "pallas" or (impl == "auto" and rows.on_tpu()
+                            and rows.rows_kernel_serves(
+                                xt.shape[1], xt.dtype, key.shape[0])):
+        (row_pick, n_valid, dest, counts, n_rows, block_expert,
+         n_real) = pick_row_maps(key, top_k, n_experts, block_rows)
+        maps = (row_pick, n_valid, n_real, dest, block_rows)
+        xs = rows.moe_dispatch(xt, *maps)
+        ys = _expert_ffn_blocks(xs, experts, block_expert, n_real,
+                                activation, block_rows, impl)
+        out = rows.moe_combine(ys, gate.reshape(dest.shape), *maps)
+        return out, counts, n_real * block_rows, n_rows
     order, dest, n_rows, block_expert, n_real = sort_pad_by_expert(
         key, n_experts, block_rows)
     token_of = order // top_k
     xs = jnp.zeros((n_rows, xt.shape[1]), xt.dtype).at[dest].set(
         xt[token_of], mode="drop")
     ys = _expert_ffn_blocks(xs, experts, block_expert, n_real, activation,
-                            block_rows)
+                            block_rows, impl)
     contrib = (ys.at[dest].get(mode="fill", fill_value=0)
                * gate[order][:, None].astype(ys.dtype))
     out = jnp.zeros_like(xt).at[token_of].add(contrib.astype(xt.dtype))
-    return out, n_real * block_rows, n_rows
+    counts = jnp.bincount(jnp.minimum(key, n_experts),
+                          length=n_experts + 1)[:n_experts]
+    return out, counts, n_real * block_rows, n_rows
 
 
 def moe_ffn_dropless(x: jnp.ndarray, gate_w: jnp.ndarray,
@@ -265,7 +333,7 @@ def moe_ffn_dropless(x: jnp.ndarray, gate_w: jnp.ndarray,
     logits = xt @ gate_w
     _, expert_idx, gate_k, aux = _gate_and_aux(logits, cfg, rng, router_bias)
 
-    out, _, _ = _sorted_expert_ffn(
+    out, _, _, _ = _sorted_expert_ffn(
         xt, expert_idx.reshape(T * K), gate_k.reshape(T * K), K, E, experts,
         activation, block_rows or expert_block_rows(T * K / E, x.dtype))
     return out.reshape(B, S, H), aux
@@ -303,11 +371,10 @@ def moe_ffn_share(x: jnp.ndarray, gate_w: jnp.ndarray,
     held = (local >= 0) & (local < cfg.held_count)
     # a pick on an absent expert gets the invalid key
     key = jnp.where(held, local, cfg.held_count)
-    out, ran_rows, grid_rows = _sorted_expert_ffn(
+    out, counts, ran_rows, grid_rows = _sorted_expert_ffn(
         xt, key, gate_k.reshape(T * K), K, cfg.held_count, experts,
         activation,
         block_rows or expert_block_rows(T * K / cfg.num_experts, x.dtype))
-    counts = jnp.bincount(key, length=cfg.held_count + 1)[:-1]
     if training:
         stats = jnp.concatenate([counts, jnp.stack([
             ran_rows, jnp.full((), grid_rows, counts.dtype),

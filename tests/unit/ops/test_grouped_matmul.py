@@ -87,9 +87,10 @@ def _experts(rng, E, H, F, dtype=jnp.float32, scale=0.3):
 def _tail(monkeypatch, xt, key, gate, top_k, E, experts, block_rows):
     """``_sorted_expert_ffn`` with the kernel interpreted."""
     monkeypatch.setattr(gmm_mod, "on_tpu", lambda: True)
-    return _sorted_expert_ffn(xt, jnp.asarray(key, jnp.int32),
-                              jnp.asarray(gate, jnp.float32), top_k, E,
-                              experts, "swiglu", block_rows)
+    out, _, ran, grid = _sorted_expert_ffn(
+        xt, jnp.asarray(key, jnp.int32), jnp.asarray(gate, jnp.float32),
+        top_k, E, experts, "swiglu", block_rows)
+    return out, ran, grid
 
 
 def _tail_reference(xt, key, gate, top_k, E, experts):
@@ -350,18 +351,8 @@ def test_the_dropless_tail_differentiates_through_the_kernels():
                             ("w_down", (E, F, H)))}
 
     def f(experts, xt, gate, impl):
-        real = grouped_matmul
-
-        def gm(x, w, be, bs=128, impl_="auto", n_real=None):
-            return real(x, w, be, bs, impl=impl,
-                        n_real=n_real if impl == "pallas" else None)
-
-        gmm_mod.grouped_matmul = gm
-        try:
-            out, _, _ = _sorted_expert_ffn(xt, key, gate, K, E, experts,
-                                           "swiglu", 8)
-        finally:
-            gmm_mod.grouped_matmul = real
+        out, _, _, _ = _sorted_expert_ffn(xt, key, gate, K, E, experts,
+                                          "swiglu", 8, impl=impl)
         return jnp.sum(jnp.cos(out))
 
     got = jax.grad(f, (0, 1, 2))(experts, xt, gate, "pallas")

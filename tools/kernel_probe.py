@@ -28,11 +28,20 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 
+#: the expert shares' layer calls: (name, experts held, of, picks a token,
+#: tokens, hidden size)
+ROW_SHAPES = [("LFM2 share", 8, 32, 4, 8192, 2048),
+              ("Solar decode", 40, 320, 8, 128, 4096),
+              ("Solar chunk", 40, 320, 8, 512, 4096)]
+
+
 def cases():
     """(name, fn, arg specs [(shape, dtype)], reference fn or None, tol)."""
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
     from deepspeed_tpu.ops.pallas.fused_adam import fused_adam_update
     from deepspeed_tpu.moe.sharded_moe import sort_pad_by_expert
+    from deepspeed_tpu.ops.pallas.moe_dispatch import (moe_combine,
+                                                       moe_dispatch)
     from deepspeed_tpu.ops.pallas.grouped_matmul import (expert_block_rows,
                                                          grouped_matmul)
     from deepspeed_tpu.ops.pallas.paged_attention import \
@@ -126,6 +135,42 @@ def cases():
                     [((32768, h), bf), ((8, h, f), bf), ((32768,), i32)],
                     share_grads("xla"), 2.0 ** -6))
 
+    # the rows into the sorted, padded buffer and back onto their tokens
+    # (no expert between: out[t] = x[t] * the sum of t's held gates), forward
+    # and backward, through the two row kernels against XLA's scatter and
+    # gathers: the LFM2 training share's call and the Solar share's decode
+    # call
+    def moved(impl, held, of, top_k):
+        def run(xt, gate, key):
+            key = _held_keys(key, held, of)
+            bs = expert_block_rows(key.shape[0] / of, xt.dtype)
+
+            def through(xt, gate):
+                if impl == "pallas":
+                    maps = _row_maps(key, top_k, held, bs)
+                    out = moe_combine(moe_dispatch(xt, *maps), gate, *maps)
+                else:
+                    order, dest, n_rows, _, _ = sort_pad_by_expert(key, held,
+                                                                   bs)
+                    xs = jnp.zeros((n_rows, xt.shape[1]), xt.dtype).at[
+                        dest].set(xt[order // top_k], mode="drop")
+                    rows = (xs.at[dest].get(mode="fill", fill_value=0)
+                            * gate.reshape(-1)[order][:, None])
+                    out = jnp.zeros(xt.shape, f32).at[order // top_k].add(rows)
+                return (out.astype(f32) * jnp.cos(
+                    jnp.arange(xt.shape[1], dtype=f32))).sum(), out
+            (_, out), grads = jax.value_and_grad(through, (0, 1),
+                                                 has_aux=True)(xt, gate)
+            return out.astype(f32), grads
+        return run
+
+    for what, held, of, top_k, t, h in ROW_SHAPES[:2]:
+        out.append((f"moe_dispatch + moe_combine fwd+bwd {what}: {t} tokens "
+                    f"x {top_k} picks, {held} of {of} experts held, H={h}",
+                    moved("pallas", held, of, top_k),
+                    [((t, h), bf), ((t, top_k), f32), ((t * top_k,), i32)],
+                    moved("xla", held, of, top_k), 2.0 ** -6))
+
     # Mistral-7B down projection, decode batch of 4
     for bits in (8, 4):
         rows = 14336 if bits == 8 else 14336 // 2
@@ -167,10 +212,108 @@ def cases():
     return out
 
 
+def _held_keys(key, held: int, of: int):
+    """Random ints as expert picks over ``of`` experts, ``held`` of them
+    here (the others get the invalid key)."""
+    key = key % of
+    return jnp.where(key < held, key, held)
+
+
+def _row_maps(key, top_k: int, held: int, block_rows: int):
+    from deepspeed_tpu.moe.sharded_moe import pick_row_maps
+
+    row_pick, n_valid, dest, _, _, _, n_real = pick_row_maps(
+        key, top_k, held, block_rows)
+    return row_pick, n_valid, n_real, dest, block_rows
+
+
+def row_rates() -> None:
+    """On the chip: what a call of each row kernel, of the maps they walk
+    and of XLA's scatter and gather of the same rows costs, each as 64
+    calls chained in one program (a program's launch and return is about a
+    millisecond) by the host's clock."""
+    from deepspeed_tpu.moe.sharded_moe import sort_pad_by_expert
+    from deepspeed_tpu.ops.pallas.grouped_matmul import expert_block_rows
+    from deepspeed_tpu.ops.pallas.moe_dispatch import (combine_rows,
+                                                       dispatch_rows)
+
+    reps = 64
+
+    def chained(body):
+        """``body(bump) -> array``; ``bump`` is an int32 0 that XLA cannot
+        fold, taken from the previous call's result."""
+        def run(*args):
+            def step(_, bump):
+                out = body(bump, *args)
+                return (out.reshape(-1)[0] > 3e38).astype(jnp.int32)
+            return jax.lax.fori_loop(0, reps, step, jnp.int32(0))
+        return run
+
+    for what, held, of, top_k, t, h in ROW_SHAPES:
+        ks = jax.random.split(jax.random.PRNGKey(1), 4)
+        key = _held_keys(jax.random.randint(ks[0], (t * top_k,), 0, of * 64),
+                         held, of)
+        xt = jax.random.normal(ks[1], (t, h), jnp.float32).astype(jnp.bfloat16)
+        gate = jax.random.uniform(ks[2], (t, top_k), jnp.float32)
+        bs = expert_block_rows(t * top_k / of, xt.dtype)
+        row_pick, n_valid, n_real, dest, _ = jax.jit(
+            _row_maps, static_argnums=(1, 2, 3))(key, top_k, held, bs)
+        order, sdest, n_rows, _, _ = jax.jit(
+            sort_pad_by_expert, static_argnums=(1, 2))(key, held, bs)
+        ys = jax.random.normal(ks[3], (n_rows, h), jnp.float32).astype(
+            jnp.bfloat16)
+        rows = int(jnp.sum(n_valid))
+        forms = {
+            "maps (argsort, cumulative sum, block slices)": (
+                lambda b, key: sum(jnp.sum(m) for m in _row_maps(
+                    key + b, top_k, held, bs)[:4]).astype(
+                        jnp.float32).reshape(1), (key,)),
+            "dstpu_moe_dispatch": (
+                lambda b, xt, rp, nv, nr: dispatch_rows(
+                    xt, rp // top_k, nv, nr + b, bs), (xt, row_pick, n_valid,
+                                                      n_real)),
+            "dstpu_moe_combine (rows already tiles)": (
+                lambda b, ys, dest, gate: combine_rows(
+                    ys, dest + b, weights=gate), (ys, dest, gate)),
+            "dstpu_moe_combine, dot": (
+                lambda b, ys, dest, xt: combine_rows(ys, dest + b, dot=xt),
+                (ys, dest, xt)),
+            "relayout of the buffer to tiles + combine": (
+                lambda b, ys, dest, gate: combine_rows(
+                    ys + b.astype(ys.dtype), dest, weights=gate),
+                (ys, dest, gate)),
+            "XLA scatter of the rows": (
+                lambda b, xt, order, sdest: jnp.zeros(
+                    (n_rows, h), xt.dtype).at[sdest + b].set(
+                        xt[order // top_k], mode="drop"), (xt, order, sdest)),
+            "XLA gather back and scatter-add": (
+                lambda b, ys, order, sdest, gate: jnp.zeros(
+                    (t, h), ys.dtype).at[order // top_k].add(
+                        ys.at[sdest + b].get(mode="fill", fill_value=0)
+                        * gate.reshape(-1)[order][:, None].astype(ys.dtype)),
+                (ys, order, sdest, gate)),
+        }
+        print(f"row rates, {what}: {t * top_k} picks, {rows} held, "
+              f"{int(n_real)} real blocks of {bs}, buffer {n_rows} rows x "
+              f"{h}", flush=True)
+        for name, (body, args) in forms.items():
+            fn = jax.jit(chained(body))
+            fn(*args).block_until_ready()
+            t0 = time.perf_counter()
+            for _ in range(3):
+                fn(*args).block_until_ready()
+            ms = (time.perf_counter() - t0) / 3 / reps * 1e3
+            print(f"  {name}: {ms:.4f} ms a call"
+                  + (f" = {ms * 1e3 / rows:.4f} us a held row"
+                     if name.startswith("dstpu") else ""), flush=True)
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--aot", action="store_true",
                     help="compile only, against a v5e:2x2 topology (no chip)")
+    ap.add_argument("--only", default="",
+                    help="probe only the kernels whose line holds this")
     args = ap.parse_args()
 
     sharding = None
@@ -195,6 +338,8 @@ def main() -> int:
 
     bad = 0
     for name, fn, specs, ref, tol in cases():
+        if args.only not in name:
+            continue
         t0 = time.time()
         abstract = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
                     for s, d in specs]
@@ -227,6 +372,8 @@ def main() -> int:
                      f"{'<' if ok else '>= (DISAGREES)'} {tol:.2e}")
             del vals, got, want
         print(line, flush=True)
+    if not args.aot and args.only in "moe_dispatch + moe_combine":
+        row_rates()
     return 1 if bad else 0
 
 
