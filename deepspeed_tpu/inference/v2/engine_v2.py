@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...models.layer_types import layers_of, state_leaves
+from ...models.layer_types import layers_of, page_layers, state_leaves
 from ...models.transformer import TransformerConfig
 from ...moe.sharded_moe import MOE_COUNTERS
 from ...ops.pallas.paged_attention import n_blocks, pages_per_block
@@ -296,7 +296,7 @@ class InferenceEngineV2:
                 self.params, self.cfg.wq_bits, self.cfg.wq_group,
                 min_size=self.config.quant_min_size)
         self._pools = PagedKVCache.init(
-            layers_of(self.cfg, "attn"), self.cfg.kv_heads,
+            page_layers(self.cfg), self.cfg.kv_heads,
             self.cfg.head_dim, block, self.config.jnp_dtype,
             kv_quant=self.config.kv_quant, state=self._state,
             counters=({"moe_stats": len(MOE_COUNTERS)}
@@ -391,6 +391,16 @@ class InferenceEngineV2:
             lambda *a: paged_prefill(cfg, *a), donate_argnums=(1,))
         self._prefill_chunk = jax.jit(
             lambda *a: paged_prefill_chunk(cfg, *a), donate_argnums=(1,))
+        #: a stack with a cross-decoder (a ``dattn`` layer, whose pages the
+        #: layers after it read) runs that decoder for a prompt's last token
+        #: only: a chunk that is not the prompt's last stops at that layer's
+        #: K/V write, in a program of its own
+        self._xdec = layers_of(cfg, "dattn") > 0
+        self._prefill_chunk_part = jax.jit(
+            lambda *a: paged_prefill_chunk(cfg, *a, final=False),
+            donate_argnums=(1,))
+        #: what a window layer's ring holds of a context, at most
+        self._window = cfg.sliding_window if layers_of(cfg, "swa") else 0
         self._copy_page = jax.jit(paged_copy_page, donate_argnums=(0,))
         ps = self.block.page_size
         self._chunk = (-(-self.config.prefill_chunk // ps) * ps
@@ -499,18 +509,30 @@ class InferenceEngineV2:
         kinds = sorted(self._state)
         if self.config.enable_prefix_cache:
             raise ValueError(
-                f"enable_prefix_cache: this model keeps recurrent state "
-                f"({kinds}) and a cached page carries none of it; serve it "
-                "with the prefix cache off")
+                f"enable_prefix_cache: this model keeps recurrent state or "
+                f"a window's ring in its slots ({kinds}) and a cached page "
+                "carries none of it; serve it with the prefix cache off")
         if self.config.prefill_chunk <= 0:
             raise ValueError(
-                f"prefill_chunk 0: a model with recurrent state ({kinds}) "
-                "is prefilled through the chunk program, which carries the "
-                "state from chunk to chunk; set prefill_chunk > 0")
+                f"prefill_chunk 0: a model with recurrent state or a "
+                f"window's ring ({kinds}) is prefilled through the chunk "
+                "program, which carries both from chunk to chunk; set "
+                "prefill_chunk > 0")
         if proposer is not None or self.config.speculative.mode != "off":
             raise ValueError(
                 f"speculative decoding: paged_verify cannot roll a rejected "
-                f"draft out of recurrent state ({kinds})")
+                f"draft out of recurrent state or a window's ring ({kinds})")
+        if self.config.kv_quant and "win_k" in self._state:
+            raise ValueError(
+                "kv_quant: the differential layers read keys and values in "
+                "pairs of heads, from the pool and from a window's ring "
+                f"({kinds}), as they are stored; serve it with kv_quant off")
+        if self.cfg.sliding_window % self.config.block.page_size and \
+                "win_k" in self._state:
+            raise ValueError(
+                f"sliding_window {self.cfg.sliding_window} is not a whole "
+                f"number of pages of {self.config.block.page_size}: the "
+                "decode kernel reads a window's ring as pages")
 
     def _wire_memory_ledger(self) -> None:
         """Attach the serving engine's HBM residents to the process
@@ -1797,16 +1819,22 @@ class InferenceEngineV2:
         b = 1
         while b < max(used, 1):
             b *= 2
+        if self._xdec:
+            # one query reads the pages, through the decode kernel, which
+            # walks the pages a row has: the whole row, one shape
+            b = self.block.max_pages_per_seq
         prev = self._page_table[seq.slot][:min(
             b, self.block.max_pages_per_seq)]
-        self._step_parts.add(("prefill_chunk", C, int(prev.shape[0])))
+        final = not self._xdec or start + c_n >= seq.length
+        self._step_parts.add(("prefill_chunk", C, int(prev.shape[0]))
+                             + (() if final else ("part",)))
         args = (jnp.asarray(ids), jnp.asarray(rows), jnp.asarray(prev),
                 jnp.int32(start), jnp.int32(c_n))
         if self._state:  # the state is carried in the sequence's slot
             args += (jnp.int32(seq.slot),)
+        program = self._prefill_chunk if final else self._prefill_chunk_part
         with self._step_span("dispatch", parent="prefill"):
-            logits, self._pools = self._prefill_chunk(
-                self.params, self._pools, *args)
+            logits, self._pools = program(self.params, self._pools, *args)
         seq.prefilled = start + c_n
         self._register_pages(seq)
         return logits
@@ -1908,8 +1936,17 @@ class InferenceEngineV2:
                 c_n = min(self._chunk, seq.length - start)
                 counts["chunks"] += 1
                 counts["prefill_tokens"] += c_n
+                attrs = {}
+                if self._xdec:  # the cross-decoder runs for the last token
+                    attrs["xdec_rows"] = int(start + c_n >= seq.length)
+                    counts["xdec_rows"] = (counts.get("xdec_rows", 0)
+                                           + attrs["xdec_rows"])
+                    # that one row reads the pages of the whole prompt
+                    counts["shared_kv_pages"] = (
+                        counts.get("shared_kv_pages", 0)
+                        + attrs["xdec_rows"] * -(-(start + c_n) // ps))
                 with self._phase("prefill", self._m_prefill_h, uid=seq.uid,
-                                 start=start, tokens=c_n):
+                                 start=start, tokens=c_n, **attrs):
                     logits = self._run_prefill_chunk(seq, start, c_n,
                                                      self._chunk)
                     if seq.prefilled >= seq.length:
@@ -2034,6 +2071,7 @@ class InferenceEngineV2:
             self._step_parts.add("decode")
             counts["decode_rows"] += len(decode_seqs)
             self._note_kv_blocks(np.where(act, pos + 1, 0))
+            self._note_state_rows(np.where(act, pos + 1, 0))
             with self._phase("decode", self._m_decode_h,
                              batch=len(decode_seqs)):
                 args = (jnp.asarray(last), jnp.asarray(pos),
@@ -2125,6 +2163,26 @@ class InferenceEngineV2:
         self._step_counts["decode_kv_blocks"] += n
         self._dstats["decode_kv_blocks"] += n
         self._m_kv_blocks.inc(n)
+
+    def _note_state_rows(self, lengths: np.ndarray) -> None:
+        """What the decode program's state-space, window and cross-decoder
+        layers touch in one layer call over rows of ``lengths`` visible
+        tokens (0 = the row is not active), from the host's own book: the
+        rows whose state the step kernel moves, the cached positions the
+        window decode reads (a ring holds ``sliding_window`` at most), the
+        visible pages of the one pool layer (once, however many layers read
+        them) and the rows the cross-decoder runs."""
+        counts, rows = self._step_counts, int((lengths > 0).sum())
+        if "ssm_s" in self._state:
+            counts["ssm_rows"] = counts.get("ssm_rows", 0) + rows
+        if self._window:
+            counts["window_tokens"] = counts.get("window_tokens", 0) + int(
+                np.minimum(lengths, self._window).sum())
+        if self._xdec:
+            ps = self.block.page_size
+            counts["shared_kv_pages"] = counts.get("shared_kv_pages", 0) \
+                + int((-(-lengths // ps)).sum())
+            counts["xdec_rows"] = counts.get("xdec_rows", 0) + rows
 
     def _decode_inputs(self, seqs: List[SequenceState]):
         """Dense ``[max_seqs]`` dispatch arrays for a decode-phase

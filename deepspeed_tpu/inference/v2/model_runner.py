@@ -33,17 +33,30 @@ the state slots of the linear-attention layers (``kda_s`` ``[L_kda, slots+1,
 heads, V, K]`` float32, ``kda_conv`` ``[L_kda, slots+1, conv-1, 3*heads*D]``;
 slot = decode row, the last slot is the trash slot) ride in the same dict,
 donated and updated in place like the pages.
+
+A stack of several runs of periods (``cfg.layer_runs``: Phi-4-mini-flash) is
+scanned run by run.  Its state-space layers keep ``ssm_s`` ``[L_mamba,
+slots+1, state, inner]`` float32 and a convolution tail ``ssm_conv``; its
+window layers keep a ring of the last ``sliding_window`` positions' keys and
+values in the slots (``win_k`` / ``win_v`` ``[L_swa, slots+1, window,
+KVH*D]``: no pages), read by the paged decode kernel as ``window / page_size``
+pages a slot; one full-attention layer writes the only pages, and the
+cross-attention layers read them.  Two values cross layers in the carry
+beside the pools: the last state-space layer's scan output (the gated memory
+units' memory) and, in the chunk program, the cut to the prompt's last token,
+after which the cross-decoder runs for one row.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import math
 from typing import Any, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ...models.layer_types import period_types
+from ...models.layer_types import layers_of, page_layers, served_runs
 from ...models.transformer import (MODEL_AXIS, TransformerConfig, _mm,
                                    _norm, _repeat_kv, alibi_slopes,
                                    attn_qkv, logits_fn, mlp_block)
@@ -75,7 +88,37 @@ def _kv_quantize(x):
     return q, s.astype(jnp.float32)
 
 
-def _scan_layers(cfg: TransformerConfig, params, pools, x, layer_fns):
+def _period_body(types, per, before, first, layer_fns):
+    """The scan body of one run of ``_scan_layers``: a period's layers,
+    unrolled.  ``per``: layers of each mixer in a period; ``before``: layers
+    of each mixer in the runs before this one; ``first``: the stack index of
+    the run's first layer."""
+    def period_body(carry, inputs):
+        layers, p = inputs
+        x, pools, cross = carry
+        seen = dict.fromkeys(per, 0)
+        for j, (layer, t) in enumerate(zip(layers, types)):
+            m = t.mixer
+            # (a mixer that comes once a period counts periods: no index
+            # arithmetic, so a homogeneous stack lowers as it always has)
+            l = p if per[m] == 1 else p * per[m] + seen[m]
+            if before.get(m):
+                l = l + before[m]
+            seen[m] += 1
+            if t.crosses:
+                x, pools, aux, cross = layer_fns[m](
+                    layer, l, x, pools, cross, first + p * len(types) + j)
+            else:
+                x, pools, aux = layer_fns[m](layer, l, x, pools)
+            if "moe_stats" in pools:
+                pools = dict(pools, moe_stats=pools["moe_stats"] + aux)
+        return (x, pools, cross), None
+
+    return period_body
+
+
+def _scan_layers(cfg: TransformerConfig, params, pools, x, layer_fns,
+                 cross=None, until=None):
     """The layer loop of every paged program: ``layer_fns[mixer](layer, l,
     x, pools) -> (x, pools, aux)`` over ``params["layers"]`` with the WHOLE
     pools in the carry.  The pools are never a per-layer operand or a
@@ -83,35 +126,42 @@ def _scan_layers(cfg: TransformerConfig, params, pools, x, layer_fns):
     update the slice and write it into a second pool-sized buffer — so with
     the pools donated the writes land in the caller's buffer.
 
-    The scan is over periods of layer types, a period's layers unrolled in
-    the body (a homogeneous stack is a period of one); ``l`` is the index
-    among the layers of that mixer.  ``aux`` is what the layer's
-    feed-forward part returned beside its output (``mlp_block``): for an
-    expert share its int32 counters (``moe.sharded_moe.MOE_COUNTERS``), added
-    to the pools' ``moe_stats`` leaf where the cache manager made one."""
-    types = period_types(cfg)
-    per = {t.mixer: sum(u.mixer == t.mixer for u in types) for t in types}
+    The stack is a sequence of runs (``layer_types.served_runs``), each
+    scanned over its periods of layer types, a period's layers unrolled in
+    the body (a homogeneous stack is one run of a period of one); ``l`` is
+    the index among the model's layers of that mixer.  A run of one period
+    among several is not scanned, so its layers may change the shape of
+    ``x``.  ``aux`` is what the layer's feed-forward part returned beside its
+    output (``mlp_block``): for an expert share its int32 counters
+    (``moe.sharded_moe.MOE_COUNTERS``), added to the pools' ``moe_stats``
+    leaf where the cache manager made one.
+
+    A type that ``crosses`` has ``layer_fns[mixer](layer, l, x, pools, cross,
+    i) -> (x, pools, aux, cross)``: ``cross`` is the dict of values that
+    cross layers, carried beside the pools, ``i`` the layer's index in the
+    stack.  ``until``: stop after the run that holds this mixer."""
+    runs = served_runs(cfg)
     stack = params["layers"]
-
-    def period_body(carry, inputs):
-        layers, p = inputs
-        x, pools = carry
-        seen = dict.fromkeys(per, 0)
-        for layer, t in zip(layers, types):
-            m = t.mixer
-            # (a mixer that comes once a period counts periods: no index
-            # arithmetic, so a homogeneous stack lowers as it always has)
-            l = p if per[m] == 1 else p * per[m] + seen[m]
-            seen[m] += 1
-            x, pools, aux = layer_fns[m](layer, l, x, pools)
-            if "moe_stats" in pools:
-                pools = dict(pools, moe_stats=pools["moe_stats"] + aux)
-        return (x, pools), None
-
-    (x, pools), _ = jax.lax.scan(
-        period_body, (x, pools),
-        (stack if isinstance(stack, tuple) else (stack,),
-         jnp.arange(cfg.n_layers // len(types))))
+    if not cfg.layer_runs:  # one run: the stack is its period's trees
+        stack = (stack if isinstance(stack, tuple) else (stack,),)
+    cross = dict(cross or {})
+    before = {}   # layers of each mixer in the runs before this one
+    first = 0     # the stack index of this run's first layer
+    for (types, n), trees in zip(runs, stack):
+        per = {t.mixer: sum(u.mixer == t.mixer for u in types) for t in types}
+        period_body = _period_body(types, per, dict(before), first, layer_fns)
+        if n == 1 and len(runs) > 1:
+            (x, pools, cross), _ = period_body(
+                (x, pools, cross),
+                (jax.tree_util.tree_map(lambda a: a[0], trees), 0))
+        else:
+            (x, pools, cross), _ = jax.lax.scan(
+                period_body, (x, pools, cross), (trees, jnp.arange(n)))
+        for m, k in per.items():
+            before[m] = before.get(m, 0) + k * n
+        first += n * len(types)
+        if until is not None and until in per:
+            break
     return x, pools
 
 
@@ -227,13 +277,154 @@ def _kda_mixer(cfg: TransformerConfig, layer, x, tail, valid, scan):
     return (o.reshape(R, T, NH * D) * gate).astype(x.dtype) @ m["wo"], rows
 
 
-def _no_mixer(program: str):
-    def refuse(*_a):
+# ------------------------------------------------ SambaY (Phi-4-mini-flash)
+def _ln1(cfg: TransformerConfig, layer, x):
+    return _norm(x, layer["norm1"]["scale"], layer["norm1"].get("bias"),
+                 cfg.norm, cfg.norm_eps)
+
+
+def _mamba_mix(cfg: TransformerConfig, layer, x, tail, scan):
+    """The Mamba-1 mixer on ``x [R, T, H]`` under the ``mamba`` scope, then
+    the feed-forward part: ``tail [R, conv-1, inner]`` the rows of ``u`` that
+    precede the tokens, ``scan(dt, u, b, c, a, d) -> y [R, T, inner]``
+    float32 runs the recurrence (and keeps the state).  Returns (x, aux, the
+    scan's output ``y`` — the memory the gated memory units read —, the
+    whole rows of ``u`` ``[R, conv-1+T, inner]`` for the caller to cut the
+    next tail from)."""
+    f32 = jnp.float32
+    m = layer["mamba"]
+    T, N, R = x.shape[1], cfg.ssm_state, cfg.ssm_dt_rank
+    with jax.named_scope("mamba"):
+        u, z = jnp.split(_ln1(cfg, layer, x) @ m["w_in"], 2, axis=-1)
+        rows = jnp.concatenate([tail.astype(u.dtype), u], axis=1)
+        u = jax.nn.silu(sum(
+            rows[:, j:j + T].astype(f32) * m["conv"][j].astype(f32)
+            for j in range(cfg.ssm_conv)) + m["conv_b"].astype(f32))
+        delta, b, c = jnp.split(u.astype(x.dtype) @ m["w_x"], [R, R + N],
+                                axis=-1)
+        dt = jax.nn.softplus((delta @ m["w_dt"]).astype(f32)
+                             + m["b_dt"].astype(f32))
+        y = scan(dt, u, b, c, -jnp.exp(m["a_log"].astype(f32)), m["d"])
+        out = (y * jax.nn.silu(z.astype(f32))).astype(x.dtype) @ m["w_out"]
+    return (*_ffn(cfg, layer, x + out), y, rows)
+
+
+def _paired_q(q):
+    """The differential form's queries for a kernel whose heads are pairs:
+    ``[..., NH, D] -> [..., NH, 2D]``, an even head in the first half and an
+    odd one in the second, zeros in the other — against a pair's keys ``[k1 |
+    k2]`` an even head scores with ``k1`` and an odd one with ``k2``, and both
+    read the pair's values ``[v1 | v2]``: keys and values stay as stored."""
+    even = (jnp.arange(q.shape[-2]) % 2 == 0)[:, None]
+    z = jnp.zeros_like(q)
+    return jnp.concatenate([jnp.where(even, q, z), jnp.where(even, z, q)],
+                           axis=-1)
+
+
+def _diff_out(cfg: TransformerConfig, layer, x, o, i):
+    """What follows the two softmaxes of a differential-attention layer:
+    ``o [B, T, NH, 2D]`` holds ``A1`` of pair ``p`` at head ``2p`` and ``A2``
+    at ``2p + 1``; ``W_o[(1 - lam0) RMSNorm(A1 - lam A2)] + b_o``, the
+    residual and the feed-forward part -> (x, aux).  ``i``: the layer's index
+    in the stack, which sets ``lam0``."""
+    f32 = jnp.float32
+    a = layer["attn"]
+    B, T, NH, D2 = o.shape
+    lam0 = 0.8 - 0.6 * jnp.exp(-0.3 * jnp.asarray(i, f32))
+    lam = (jnp.exp(jnp.sum(a["lam_q1"].astype(f32) * a["lam_k1"].astype(f32)))
+           - jnp.exp(jnp.sum(a["lam_q2"].astype(f32)
+                             * a["lam_k2"].astype(f32))) + lam0)
+    o = o.astype(f32).reshape(B, T, NH // 2, 2, D2)
+    d = o[..., 0, :] - lam * o[..., 1, :]
+    d = (d * jax.lax.rsqrt(jnp.mean(d * d, -1, keepdims=True) + cfg.norm_eps)
+         * a["sub_norm"].astype(f32) * (1.0 - lam0))
+    delta = _mm(cfg, d.reshape(B, T, -1).astype(x.dtype), a["wo"],
+                MODEL_AXIS, None) + a["bo"]
+    return _ffn(cfg, layer, x + delta)
+
+
+def _pair_cfg(cfg: TransformerConfig) -> TransformerConfig:
+    """The heads as the paired kernels see them: half the K/V heads, twice
+    as wide."""
+    return dataclasses.replace(cfg, n_kv_heads=cfg.kv_heads // 2,
+                               head_dim_override=2 * cfg.head_dim)
+
+
+def _rows_attend(cfg: TransformerConfig, q, pools, l, table, positions,
+                 active, use_kernel: bool, name: str):
+    """One query a row, ``q [B, 1, NH, D]``, in the differential form over
+    layer ``l`` of ``pools`` (``{"k", "v"}`` in pages) -> ``[B, 1, NH, 2D]``:
+    the paged decode kernel under ``name``, or the gather path."""
+    B = q.shape[0]
+    q2, scale = _paired_q(q), 1.0 / math.sqrt(cfg.head_dim)
+    if use_kernel:
+        from ...ops.pallas.paged_attention import paged_decode_attention
+
+        return paged_decode_attention(
+            q2[:, 0], pools["k"], pools["v"], table, positions, layer=l,
+            active=active, scale=scale, name=name)[:, None]
+    S = table.shape[1] * pools["k"].shape[2]
+    vis = (jnp.arange(S)[None] <= positions[:, None]) & active[:, None]
+    o = _gather_window_attend(_pair_cfg(cfg), q2, pools, l, table,
+                              positions[:, None], vis[:, None], scale=scale)
+    return o.reshape(B, 1, q.shape[2], -1)
+
+
+def _ring_pages(pools, ps: int):
+    """The window layers' rings as pages, and back: ``win_k`` / ``win_v``
+    ``[L, slots+1, window, F] <-> [L, (slots+1) * window/ps, ps, F]`` — slot
+    ``s`` holds pages ``s * window/ps ...``; a reshape of the leading
+    dimensions, so no data moves."""
+    out = dict(pools)
+    for name in ("win_k", "win_v"):
+        if name in pools:
+            a = pools[name]
+            out[name] = a.reshape(a.shape[0], -1, ps, a.shape[-1])
+    return out
+
+
+def _ring_slots(pools, like):
+    return {name: (a.reshape(like[name].shape) if name in ("win_k", "win_v")
+                   else a) for name, a in pools.items()}
+
+
+def _gmu_fn(cfg: TransformerConfig):
+    def gmu_fn(layer, l, x, pools, cross, i):
+        g = layer["gmu"]
+        with jax.named_scope("gmu"):
+            gate = jax.nn.silu((_ln1(cfg, layer, x) @ g["w_in"])
+                               .astype(jnp.float32))
+            y = (cross["mem"] * gate).astype(x.dtype) @ g["w_out"]
+        x, aux = _ffn(cfg, layer, x + y)
+        return x, pools, aux, cross
+    return gmu_fn
+
+
+def _xattn_fn(cfg: TransformerConfig, attend):
+    """``attend(q [B, 1, NH, D]) -> [B, 1, NH, 2D]`` over the pages the
+    full-attention layer wrote."""
+    def xattn_fn(layer, l, x, pools, cross, i):
+        a = layer["attn"]
+        q = (_mm(cfg, _ln1(cfg, layer, x), a["wq"], None, MODEL_AXIS)
+             + a["bq"]).reshape(*x.shape[:2], cfg.n_heads, cfg.head_dim)
+        x, aux = _diff_out(cfg, layer, x, attend(q, pools), i)
+        return x, pools, aux, cross
+    return xattn_fn
+
+
+class _Forms(dict):
+    """A program's ``layer_fns``: a mixer it has no form of is refused by
+    name when a stack asks for it."""
+
+    def __init__(self, program: str, **forms):
+        super().__init__(forms)
+        self.program = program
+
+    def __missing__(self, mixer):
         raise NotImplementedError(
-            f"{program} has no form of the linear-attention mixer: a model "
-            "with recurrent state is served through chunked prefill and the "
-            "decode program only")
-    return refuse
+            f"{self.program} has no form of the {mixer!r} mixer: a model "
+            "with recurrent state or a window cache is served through "
+            "chunked prefill and the decode program only")
 
 
 def paged_prefill(cfg: TransformerConfig, params, pools,
@@ -292,8 +483,8 @@ def paged_prefill(cfg: TransformerConfig, params, pools,
             attn = jnp.einsum("bnts,bsnd->btnd", probs, vv).reshape(1, S, -1)
         return _attn_out(cfg, layer, x, attn, pools)
 
-    x, pools = _scan_layers(cfg, params, pools, x, {
-        "attn": layer_fn, "kda": _no_mixer("whole-prompt prefill")})
+    x, pools = _scan_layers(cfg, params, pools, x,
+                            _Forms("whole-prompt prefill", attn=layer_fn))
     hidden = _norm(x[:, length - 1], params["final_norm"]["scale"],
                    params["final_norm"].get("bias"), cfg.norm, cfg.norm_eps)
     logits = logits_fn(cfg, params, hidden[:, None])[0, 0]
@@ -369,8 +560,8 @@ def paged_scatter_pages(pools, pages, arrays):
 
 
 def paged_prefill_chunk(cfg: TransformerConfig, params, pools,
-                        ids, chunk_rows, prev_table, start, n, slot=None
-                        ) -> Tuple[jnp.ndarray, Any]:
+                        ids, chunk_rows, prev_table, start, n, slot=None,
+                        final: bool = True) -> Tuple[jnp.ndarray, Any]:
     """Prefill ONE CHUNK of a prompt (FastGen Dynamic-SplitFuse-style
     chunked prefill, reference inference/v2 scheduler + blogs/deepspeed-
     fastgen): long prompts are processed in fixed-size chunks so decode
@@ -399,7 +590,14 @@ def paged_prefill_chunk(cfg: TransformerConfig, params, pools,
     recurrent state — carried from chunk to chunk there.
     Chunk queries attend to all previously-written positions (< start,
     via the page pool) plus causally within the chunk.  Returns (logits
-    of token start+n-1 — meaningful on the FINAL chunk — and pools)."""
+    of token start+n-1 — meaningful on the FINAL chunk — and pools).
+
+    A stack with a cross-decoder (a ``dattn`` layer: Phi-4-mini-flash) runs
+    the layers up to it and its K/V projection over the chunk, and — on a
+    prompt's last chunk alone, ``final`` (static) — its attention, its
+    feed-forward part and every layer after it for token ``start + n - 1``
+    only; a chunk that is not final stops at the K/V write and returns zeros
+    for logits.  ``prev_table`` is then the sequence's whole table row."""
     quant = "k_scale" in pools
     C = ids.shape[0]
     ps = pools["k"].shape[2]
@@ -496,16 +694,124 @@ def paged_prefill_chunk(cfg: TransformerConfig, params, pools,
                        kda_conv=pools["kda_conv"].at[l, slot].set(
                            tail.astype(pools["kda_conv"].dtype))), aux
 
-    x, pools = _scan_layers(cfg, params, pools, x,
-                            {"attn": layer_fn, "kda": kda_fn})
-    hidden = _norm(x[:, n - 1], params["final_norm"]["scale"],
+    # ---- SambaY: state-space and window layers over the chunk, the cross-
+    # decoder for the prompt's last token
+    def mamba_fn(layer, l, x, pools, cross, i):
+        from ...ops.pallas.ssm import ssm_chunk
+
+        keep = start > 0
+        st0 = jnp.where(keep, pools["ssm_s"][l, slot], 0.0)
+        tail = jnp.where(keep, pools["ssm_conv"][l, slot], 0)
+
+        new = {}
+
+        def scan(dt, u, b, c, a, d):
+            y, new["s"] = ssm_chunk(dt[0], u[0], b[0], c[0], a, d, st0, n,
+                                    kernel=use_kernel)
+            return y[None]
+
+        x, aux, y, rows = _mamba_mix(cfg, layer, x, tail[None], scan)
+        tail = jax.lax.dynamic_slice_in_dim(rows[0], n, tail.shape[0], 0)
+        pools = dict(pools,
+                     ssm_s=pools["ssm_s"].at[l, slot].set(new["s"]),
+                     ssm_conv=pools["ssm_conv"].at[l, slot].set(
+                         tail.astype(pools["ssm_conv"].dtype)))
+        # the memory of the prompt's last token is all the cross-decoder
+        # reads of this chunk
+        return x, pools, aux, dict(cross, mem=jax.lax.dynamic_slice_in_dim(
+            y, n - 1, 1, 1))
+
+    def swa_fn(layer, l, x, pools, cross, i):
+        W = cfg.sliding_window
+        WP, F = W // ps, pools["win_k"].shape[-1]
+        q, k, v = attn_qkv(cfg, layer, x, positions)
+        pair = lambda a: a.reshape(*a.shape[:-2], a.shape[-2] // 2, -1)  # noqa: E731
+        at = (l, slot * WP, 0, 0)
+        # ring row r holds the last position before ``start`` that is r mod
+        # W; in position order it holds start - W .. start - 1, of which
+        # those before 0 were never written
+        order = (start + jnp.arange(W)) % W
+        old = {nm: jax.lax.dynamic_slice(pools["win_" + nm], at,
+                                         (1, WP, ps, F)).reshape(W, F)
+               for nm in ("k", "v")}
+        k_first = jnp.maximum(W - start, 0)
+        prev = {nm: jnp.where((jnp.arange(W) >= k_first)[:, None], a[order],
+                              0).reshape(1, W, cfg.kv_heads // 2, -1)
+                for nm, a in old.items()}
+        kk = jnp.concatenate([prev["k"].astype(x.dtype), pair(k)], axis=1)
+        vv = jnp.concatenate([prev["v"].astype(x.dtype), pair(v)], axis=1)
+        q2, scale = _paired_q(q), 1.0 / math.sqrt(cfg.head_dim)
+        if use_kernel:
+            from ...ops.pallas.flash_attention import flash_attention
+
+            o = flash_attention(q2, kk, vv, causal=True, q_offset=W,
+                                sm_scale=scale, window=W, k_first=k_first)
+        else:
+            rows_ = W + jnp.arange(C)[:, None]
+            cols = jnp.arange(W + C)[None]
+            vis = (cols <= rows_) & (rows_ - cols < W) & (cols >= k_first)
+            g = q2.shape[2] // kk.shape[2]
+            sc = jnp.einsum("btnd,bsnd->bnts", q2, _repeat_kv(kk, g)
+                            ).astype(jnp.float32) * scale
+            pr = jax.nn.softmax(jnp.where(vis[None, None], sc, -1e30),
+                                axis=-1).astype(x.dtype)
+            o = jnp.einsum("bnts,bsnd->btnd", pr, _repeat_kv(vv, g))
+        # the ring after the chunk: row r takes the chunk's last real token
+        # at a position r mod W, if the chunk has one
+        last = start + n - 1
+        p = last - (last - jnp.arange(W)) % W
+        new = {nm: jnp.where((p >= start)[:, None],
+                             a[0].reshape(C, F)[jnp.clip(p - start, 0, C - 1)],
+                             old[nm]) for nm, a in (("k", k), ("v", v))}
+        pools = dict(pools, **{
+            "win_" + nm: jax.lax.dynamic_update_slice(
+                pools["win_" + nm], a.reshape(1, WP, ps, F).astype(
+                    pools["win_" + nm].dtype), at) for nm, a in new.items()})
+        x, aux = _diff_out(cfg, layer, x, o, i)
+        return x, pools, aux, cross
+
+    last_pos = (start + n - 1).reshape(1)
+
+    def attend_last(q, pools):
+        return _rows_attend(cfg, q, pools, page_layers(cfg) - 1,
+                            prev_table[None], last_pos,
+                            jnp.ones((1,), bool), use_kernel,
+                            "dstpu_paged_decode")
+
+    def dattn_fn(layer, l, x, pools, cross, i):
+        q, k, v = attn_qkv(cfg, layer, x, positions)
+        pools = _pool_write(
+            pools, l, (chunk_rows,), k[0].reshape(C // ps, ps, *k.shape[2:]),
+            v[0].reshape(C // ps, ps, *v.shape[2:]))
+        if not final:
+            return x, pools, 0, cross
+        # the cut: from here on, the prompt's last token alone
+        cut = lambda a: jax.lax.dynamic_slice_in_dim(a, n - 1, 1, 1)  # noqa: E731
+        x = cut(x)
+        x, aux = _diff_out(cfg, layer, x, attend_last(cut(q), pools), i)
+        return x, pools, aux, cross
+
+    xdec = layers_of(cfg, "dattn") > 0
+    like = pools
+    x, pools = _scan_layers(
+        cfg, params, _ring_pages(pools, ps), x,
+        _Forms("chunked prefill", attn=layer_fn, kda=kda_fn, mamba=mamba_fn,
+               swa=swa_fn, dattn=dattn_fn, gmu=_gmu_fn(cfg),
+               xattn=_xattn_fn(cfg, attend_last)),
+        cross=({"mem": jnp.zeros((1, 1, cfg.ssm_inner), jnp.float32)}
+               if cfg.ssm_inner else None),
+        until=None if final or not xdec else "dattn")
+    pools = _ring_slots(pools, like)
+    if xdec and not final:
+        return jnp.zeros((cfg.vocab_size,), x.dtype), pools
+    hidden = _norm(x[:, 0 if xdec else n - 1], params["final_norm"]["scale"],
                    params["final_norm"].get("bias"), cfg.norm, cfg.norm_eps)
     logits = logits_fn(cfg, params, hidden[:, None])[0, 0]
     return logits, pools
 
 
 def _gather_window_attend(cfg: TransformerConfig, q, pools, l,
-                          page_table, q_pos, vis) -> jnp.ndarray:
+                          page_table, q_pos, vis, scale=None) -> jnp.ndarray:
     """[B, T] written-through queries attend the pooled pages via the
     XLA gather path — THE shared formulation of the paged_decode
     fallback (T=1) and paged_verify (T=k+1), so the dequant / GQA /
@@ -522,7 +828,8 @@ def _gather_window_attend(cfg: TransformerConfig, q, pools, l,
     kk = _repeat_kv(kk, cfg.n_heads // cfg.kv_heads)
     vv = _repeat_kv(vv, cfg.n_heads // cfg.kv_heads)
     scores = jnp.einsum("btnd,bsnd->bnts", q, kk).astype(jnp.float32)
-    scores = scores / math.sqrt(cfg.head_dim)
+    scores = (scores / math.sqrt(cfg.head_dim) if scale is None
+              else scores * scale)
     if cfg.position == "alibi":
         scores = scores + _alibi_bias(cfg, q_pos, jnp.arange(S)[None])
     scores = jnp.where(vis[:, None], scores, -1e30)
@@ -593,8 +900,8 @@ def paged_verify(cfg: TransformerConfig, params, pools,
                                      vis)
         return _attn_out(cfg, layer, x, attn, pools)
 
-    x, pools = _scan_layers(cfg, params, pools, x, {
-        "attn": layer_fn, "kda": _no_mixer("speculative verify")})
+    x, pools = _scan_layers(cfg, params, pools, x,
+                            _Forms("speculative verify", attn=layer_fn))
     hidden = _norm(x, params["final_norm"]["scale"],
                    params["final_norm"].get("bias"), cfg.norm, cfg.norm_eps)
     logits = logits_fn(cfg, params, hidden)  # [B, W, V]
@@ -690,8 +997,67 @@ def paged_decode(cfg: TransformerConfig, params, pools,
                        kda_conv=pools["kda_conv"].at[l, dst].set(
                            rows[:, 1:].astype(pools["kda_conv"].dtype))), aux
 
-    x, pools = _scan_layers(cfg, params, pools, x,
-                            {"attn": layer_fn, "kda": kda_fn})
+    # ---- SambaY: every layer for one token a row
+    def mamba_fn(layer, l, x, pools, cross, i):
+        from ...ops.pallas.ssm import ssm_step
+
+        trash_slot = pools["ssm_s"].shape[1] - 1
+        dst = jnp.where(active, jnp.arange(B), trash_slot)
+        new = {}
+
+        def scan(dt, u, b, c, a, d):
+            y, new["s"] = ssm_step(dt[:, 0], u[:, 0], b[:, 0], c[:, 0], a, d,
+                                   pools["ssm_s"], l, active,
+                                   kernel=use_kernel)
+            return y[:, None]
+
+        x, aux, y, rows = _mamba_mix(cfg, layer, x, pools["ssm_conv"][l, :B],
+                                     scan)
+        pools = dict(pools, ssm_s=new["s"],
+                     ssm_conv=pools["ssm_conv"].at[l, dst].set(
+                         rows[:, 1:].astype(pools["ssm_conv"].dtype)))
+        return x, pools, aux, dict(cross, mem=y)
+
+    def attend_pages(q, pools):
+        return _rows_attend(cfg, q, pools, page_layers(cfg) - 1, page_table,
+                            positions, active, use_kernel,
+                            "dstpu_paged_decode")
+
+    def dattn_fn(layer, l, x, pools, cross, i):
+        q, k, v = attn_qkv(cfg, layer, x, positions[:, None])
+        pools = _pool_write(pools, l, (page_idx, off), k[:, 0], v[:, 0])
+        x, aux = _diff_out(cfg, layer, x, attend_pages(q, pools), i)
+        return x, pools, aux, cross
+
+    def swa_fn(layer, l, x, pools, cross, i):
+        # position t lives at row t mod W of the row's ring, which the
+        # kernel reads as W / ps pages of the slot; with no position
+        # encoding the order of the keys is not needed
+        W = cfg.sliding_window
+        WP = W // ps
+        slots = pools["win_k"].shape[1] // WP - 1
+        q, k, v = attn_qkv(cfg, layer, x, positions[:, None])
+        ring = positions % W
+        win = _pool_write(
+            {"k": pools["win_k"], "v": pools["win_v"]}, l,
+            (jnp.where(active, jnp.arange(B), slots) * WP + ring // ps,
+             ring % ps), k[:, 0], v[:, 0])
+        o = _rows_attend(
+            cfg, q, win, l, jnp.arange(B)[:, None] * WP + jnp.arange(WP)[None],
+            jnp.minimum(positions, W - 1), active, use_kernel,
+            "dstpu_window_decode")
+        x, aux = _diff_out(cfg, layer, x, o, i)
+        return x, dict(pools, win_k=win["k"], win_v=win["v"]), aux, cross
+
+    like = pools
+    x, pools = _scan_layers(
+        cfg, params, _ring_pages(pools, ps), x,
+        _Forms("decode", attn=layer_fn, kda=kda_fn, mamba=mamba_fn,
+               swa=swa_fn, dattn=dattn_fn, gmu=_gmu_fn(cfg),
+               xattn=_xattn_fn(cfg, attend_pages)),
+        cross=({"mem": jnp.zeros((B, 1, cfg.ssm_inner), jnp.float32)}
+               if cfg.ssm_inner else None))
+    pools = _ring_slots(pools, like)
     hidden = _norm(x, params["final_norm"]["scale"],
                    params["final_norm"].get("bias"), cfg.norm, cfg.norm_eps)
     logits = logits_fn(cfg, params, hidden)[:, 0]

@@ -8,6 +8,7 @@ from .gpt2 import gpt2_config, gpt2_model
 from .lfm2_moe import lfm2_moe_config, lfm2_moe_model
 from .llama import llama_config, llama_model
 from .mixtral import mixtral_config, mixtral_model
+from .phi4_flash import phi4_flash_config, phi4_flash_model
 from .solar_open2 import solar_open2_config, solar_open2_model
 from .transformer import TransformerConfig
 
@@ -18,4 +19,5 @@ __all__ = ["bert_config", "bert_model", "gpt2_config", "gpt2_model",
            "falcon_config", "falcon_model", "bloom_config", "bloom_model",
            "gpt_neox_config", "gpt_neox_model", "solar_open2_config",
            "solar_open2_model", "lfm2_moe_config", "lfm2_moe_model",
+           "phi4_flash_config", "phi4_flash_model",
            "TransformerConfig"]

@@ -9,8 +9,10 @@ prefill/decode, TP/SP/ZeRO shardings) is shared with llama/gpt2.
 
 Family-specific structure carried by the config:
   mistral — llama-shape with GQA (the reference's sliding-window attention
-            is approximated as full causal attention: windowing changes
-            masks, not layout)
+            is full causal attention here: its window never binds at the
+            contexts served; a window that binds is the ``swa`` layer type
+            of ``models/layer_types.py``, with a ring for a cache, which
+            ``models/phi4_flash.py`` serves)
   qwen2   — llama-shape + biases on q/k/v only (``qkv_bias``)
   phi     — partial rotary (``rotary_pct``), parallel attn+MLP block,
             layernorm + gelu + biases

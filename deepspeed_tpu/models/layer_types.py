@@ -9,10 +9,12 @@ paged pool (``kv_pages``) and/or fixed-size state in per-sequence slots
 (``state``).  The cache manager sizes its pools from these, so a model with
 fewer attention layers than layers gets a pool with fewer layers.
 
-A stack names its layers one of two ways.  ``TransformerConfig.layer_period``
-is a repeated *period* of types (served: ``model_runner._scan_layers``); every
-layer's feed-forward part is the configuration's (``mlp_block``: dense or
-experts).  ``TransformerConfig.layer_types`` is the published list, one type a
+A stack names its layers one of three ways.  ``TransformerConfig.layer_period``
+is a repeated *period* of types and ``layer_runs`` a sequence of runs, each a
+repeated period (served: ``model_runner._scan_layers`` scans run by run, and
+``served_runs`` reads both as runs); every layer's feed-forward part is the
+configuration's (``mlp_block``: dense or experts).
+``TransformerConfig.layer_types`` is the published list, one type a
 layer, behind a prologue: the first ``dense_layers`` layers carry a dense
 feed-forward part, the others the configuration's.  Such a stack is cut into
 *runs* of layers alike in mixer and feed-forward part (``stack_runs``), each
@@ -47,6 +49,10 @@ class LayerType:
     #: (cfg, layer, x [B, S, H], positions, mask, attn_fn) -> what the mixer
     #: adds to the residual stream, over whole sequences and differentiable
     mix: Callable[..., Any] = None
+    #: the serving forms take and return the values that cross layers beside
+    #: the pools (``model_runner._scan_layers``: the stack's layer index, the
+    #: memory a state-space layer leaves for the gated memory units)
+    crosses: bool = False
 
 
 def _init_attn(cfg: TransformerConfig, rng, n: int) -> Dict[str, Any]:
@@ -91,13 +97,6 @@ def _kda_state(cfg: TransformerConfig) -> Dict[str, Tuple[tuple, Any]]:
         # the last conv - 1 rows of the q | k | v projections
         "kda_conv": ((cfg.kda_conv - 1, 3 * NH * D), None),
     }
-
-
-def _kda_mix(*_a, **_k):
-    raise NotImplementedError(
-        "a delta-rule linear-attention layer (type 'kda') is served only: "
-        "training it needs the backward of the delta-rule scan "
-        "(ops/pallas/kda.py: dstpu_kda_chunk), which does not exist")
 
 
 def _init_conv(cfg: TransformerConfig, rng, n: int) -> Dict[str, Any]:
@@ -147,15 +146,154 @@ def _conv_state(cfg: TransformerConfig) -> Dict[str, Tuple[tuple, Any]]:
     return {"conv_tail": ((cfg.conv_taps - 1, cfg.hidden_size), None)}
 
 
+def _served_only(kind: str, what: str):
+    def mix(*_a, **_k):
+        raise NotImplementedError(
+            f"a layer of type {kind!r} is served only: training it needs "
+            f"{what}, which does not exist")
+    return mix
+
+
+_NO_SCAN_BWD = ("the backward of the selective scan (ops/pallas/ssm.py: "
+                "dstpu_ssm_chunk)")
+
+
+def _init_mamba(cfg: TransformerConfig, rng, n: int) -> Dict[str, Any]:
+    keys = jax.random.split(rng, 32)
+    layers = init_layer_stack(cfg, keys, n, attn=False)
+    H, DI, N, K, R = (cfg.hidden_size, cfg.ssm_inner, cfg.ssm_state,
+                      cfg.ssm_conv, cfg.ssm_dt_rank)
+    dt = cfg.dtype
+
+    def nrm(i, *shape, s=0.02):
+        return _nrm(cfg, keys[16 + i], *shape, s=s)
+
+    # a decay rate -A = 1 .. N along the state index times a step
+    # softplus(b_dt) in [1e-3, 1e-1] (log-uniform), as Mamba initialises
+    step = jnp.exp(jax.random.uniform(keys[30], (n, DI))
+                   * (math.log(0.1) - math.log(1e-3)) + math.log(1e-3))
+    layers["mamba"] = {
+        "w_in": nrm(0, n, H, 2 * DI),                       # u | z
+        # one causal kernel of ssm_conv taps per channel, the last tap on
+        # the token itself, at 1 / sqrt(taps) as the other convolutions here
+        "conv": nrm(1, n, K, DI, s=1.0 / math.sqrt(K)),
+        "conv_b": nrm(2, n, DI),
+        "w_x": nrm(3, n, DI, R + 2 * N),                    # delta | B | C
+        "w_dt": nrm(4, n, R, DI),
+        "b_dt": (step + jnp.log(-jnp.expm1(-step))).astype(dt),
+        # [state, channel]: the layout of the state the kernels keep
+        "a_log": jnp.broadcast_to(
+            jnp.log(jnp.arange(1, N + 1, dtype=jnp.float32))[None, :, None],
+            (n, N, DI)).astype(dt),
+        "d": jnp.ones((n, DI), dt),
+        "w_out": nrm(5, n, DI, H, s=0.02 / math.sqrt(2 * cfg.n_layers)),
+    }
+    return layers
+
+
+def _mamba_state(cfg: TransformerConfig) -> Dict[str, Tuple[tuple, Any]]:
+    return {
+        # state-major [state, channel]: channels on the lanes, float32 always
+        "ssm_s": ((cfg.ssm_state, cfg.ssm_inner), jnp.float32),
+        # the last conv - 1 rows of u
+        "ssm_conv": ((cfg.ssm_conv - 1, cfg.ssm_inner), None),
+    }
+
+
+def _diff_params(cfg: TransformerConfig, keys, n: int, layers) -> None:
+    """What the differential form adds to ``layers["attn"]``: the output
+    bias, the four vectors of the learned part of lambda and the scale of
+    the RMSNorm over a pair's 2 * head_dim values.  Biases are drawn, not
+    zero, so that a comparison sees them."""
+    D, a = cfg.head_dim, layers["attn"]
+    for i, name in enumerate(("lam_q1", "lam_k1", "lam_q2", "lam_k2")):
+        a[name] = _nrm(cfg, keys[20 + i], n, D, s=0.1)
+    a["sub_norm"] = jnp.ones((n, 2 * D), cfg.dtype)
+    a["bo"] = _nrm(cfg, keys[24], n, cfg.hidden_size)
+    for i, name in enumerate(("bq", "bk", "bv")):
+        if name in a:
+            a[name] = _nrm(cfg, keys[25 + i], *a[name].shape)
+
+
+def _init_dattn(cfg: TransformerConfig, rng, n: int) -> Dict[str, Any]:
+    keys = jax.random.split(rng, 32)
+    layers = init_layer_stack(cfg, keys, n)
+    _diff_params(cfg, keys, n, layers)
+    return layers
+
+
+def _init_xattn(cfg: TransformerConfig, rng, n: int) -> Dict[str, Any]:
+    keys = jax.random.split(rng, 32)
+    layers = init_layer_stack(cfg, keys, n, attn=False)
+    H, ND = cfg.hidden_size, cfg.n_heads * cfg.head_dim
+    layers["attn"] = {
+        "wq": _nrm(cfg, keys[3], n, H, ND),
+        "bq": jnp.zeros((n, ND), cfg.dtype),
+        "wo": _nrm(cfg, keys[6], n, ND, H,
+                   s=0.02 / math.sqrt(2 * cfg.n_layers)),
+    }
+    _diff_params(cfg, keys, n, layers)
+    return layers
+
+
+def _init_gmu(cfg: TransformerConfig, rng, n: int) -> Dict[str, Any]:
+    keys = jax.random.split(rng, 32)
+    layers = init_layer_stack(cfg, keys, n, attn=False)
+    H, DI = cfg.hidden_size, cfg.ssm_inner
+    layers["gmu"] = {
+        "w_in": _nrm(cfg, keys[16], n, H, DI),
+        "w_out": _nrm(cfg, keys[17], n, DI, H,
+                      s=0.02 / math.sqrt(2 * cfg.n_layers)),
+    }
+    return layers
+
+
+def _window_state(cfg: TransformerConfig) -> Dict[str, Tuple[tuple, Any]]:
+    # a ring of the last sliding_window positions' keys and values: position
+    # t at row t % window (no position encoding: the order is not needed)
+    shape = (cfg.sliding_window, cfg.kv_heads * cfg.head_dim)
+    return {"win_k": (shape, None), "win_v": (shape, None)}
+
+
 ATTN = LayerType("attn", _init_attn, mixer="attn", kv_pages=True,
                  state=lambda cfg: {}, mix=attn_mixer)
 KDA = LayerType("kda", _init_kda, mixer="kda", kv_pages=False,
-                state=_kda_state, mix=_kda_mix)
+                state=_kda_state,
+                mix=_served_only("kda", "the backward of the delta-rule scan "
+                                 "(ops/pallas/kda.py: dstpu_kda_chunk)"))
 #: trained only: the paged programs have no form of this mixer yet; the type
 #: declares the state a serving PR has to keep
 CONV = LayerType("conv", _init_conv, mixer="conv", kv_pages=False,
                  state=_conv_state, mix=_conv_mix)
-_TYPES = {"attn": ATTN, "kda": KDA, "conv": CONV}
+#: Phi-4-mini-flash (SambaY), served only.  A Mamba-1 selective state-space
+#: layer: float32 state and a convolution tail in the sequence's slot; the
+#: last one's scan output is the memory the gated memory units read
+MAMBA = LayerType("mamba", _init_mamba, mixer="mamba", kv_pages=False,
+                  state=_mamba_state, mix=_served_only("mamba", _NO_SCAN_BWD),
+                  crosses=True)
+#: differential attention over the last ``sliding_window`` positions, kept as
+#: a ring in the sequence's slot: no pages, no page accounting
+SWA = LayerType("swa", _init_dattn, mixer="swa", kv_pages=False,
+                state=_window_state,
+                mix=_served_only("swa", "a window mask in the flash backward"),
+                crosses=True)
+#: differential attention over the whole context: the one layer that writes
+#: pages, which the cross-attention layers after it read
+DATTN = LayerType("dattn", _init_dattn, mixer="dattn", kv_pages=True,
+                  state=lambda cfg: {},
+                  mix=_served_only("dattn", "the differential form in the "
+                                   "training forward"), crosses=True)
+#: a gated memory unit: the last state-space layer's scan output, gated
+GMU = LayerType("gmu", _init_gmu, mixer="gmu", kv_pages=False,
+                state=lambda cfg: {}, mix=_served_only("gmu", _NO_SCAN_BWD),
+                crosses=True)
+#: differential attention of its own queries to the pages ``dattn`` wrote
+XATTN = LayerType("xattn", _init_xattn, mixer="xattn", kv_pages=False,
+                  state=lambda cfg: {},
+                  mix=_served_only("xattn", "the differential form in the "
+                                   "training forward"), crosses=True)
+_TYPES = {"attn": ATTN, "kda": KDA, "conv": CONV, "mamba": MAMBA, "swa": SWA,
+          "dattn": DATTN, "gmu": GMU, "xattn": XATTN}
 
 
 def layer_type(kind: str) -> LayerType:
@@ -166,13 +304,34 @@ def layer_type(kind: str) -> LayerType:
                          f"{sorted(_TYPES)}") from None
 
 
-def period_types(cfg: TransformerConfig) -> Tuple[LayerType, ...]:
+def served_runs(cfg: TransformerConfig
+                ) -> Tuple[Tuple[Tuple[LayerType, ...], int], ...]:
+    """The stack as the serving programs scan it: runs ``(period of types,
+    repeats)``; ``layer_period`` is one run."""
     if cfg.layer_types:
         raise NotImplementedError(
-            "a stack of cfg.layer_types is trained, not served: the paged "
-            "programs (inference/v2/model_runner) run a repeated "
-            "layer_period and have no form of the 'conv' mixer")
-    return tuple(layer_type(k) for k in cfg.layer_period)
+            "a stack of cfg.layer_types (one type a layer behind a dense "
+            "prologue) is trained through run_stack; the paged programs "
+            "(inference/v2/model_runner) serve cfg.layer_period or "
+            "cfg.layer_runs, and have no form of the 'conv' mixer or of a "
+            "prologue's own feed-forward width")
+    runs = cfg.layer_runs or (
+        (cfg.layer_period, cfg.n_layers // len(cfg.layer_period)),)
+    if sum(len(period) * n for period, n in runs) != cfg.n_layers:
+        raise ValueError(f"the runs {runs} do not make n_layers "
+                         f"{cfg.n_layers}")
+    return tuple((tuple(layer_type(k) for k in period), int(n))
+                 for period, n in runs)
+
+
+def init_period_runs(cfg: TransformerConfig, rng):
+    """``params["layers"]`` of a stack of ``cfg.layer_runs``: per run, per
+    position of its period, that type's parameters stacked ``[repeats,
+    ...]``."""
+    return tuple(
+        tuple(t.init(cfg, jax.random.fold_in(rng, 100 * (r + 1) + j), n)
+              for j, t in enumerate(types))
+        for r, (types, n) in enumerate(served_runs(cfg)))
 
 
 # ------------------------------------------------- a stack of cfg.layer_types
@@ -261,15 +420,21 @@ def run_stack(cfg: TransformerConfig, stack, x, positions, mask, attn_fn,
 
 def layers_of(cfg: TransformerConfig, mixer: str) -> int:
     """How many of the model's layers run ``mixer``."""
-    types = period_types(cfg)
-    return (cfg.n_layers // len(types)) * sum(t.mixer == mixer for t in types)
+    return sum(n * sum(t.mixer == mixer for t in types)
+               for types, n in served_runs(cfg))
+
+
+def page_layers(cfg: TransformerConfig) -> int:
+    """How many of the model's layers keep K/V pages: the pool's layers."""
+    return sum(n * sum(t.kv_pages for t in types)
+               for types, n in served_runs(cfg))
 
 
 def state_leaves(cfg: TransformerConfig) -> Dict[str, Tuple[int, tuple, Any]]:
     """{pool leaf: (layers that keep it, per-sequence shape, dtype)} over the
     whole model; empty for a model that keeps only pages."""
     out: Dict[str, Tuple[int, tuple, Any]] = {}
-    for t in set(period_types(cfg)):
+    for t in {t for types, _ in served_runs(cfg) for t in types}:
         for name, (shape, dtype) in t.state(cfg).items():
             out[name] = (layers_of(cfg, t.mixer), shape, dtype)
     return out

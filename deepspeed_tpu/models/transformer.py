@@ -181,6 +181,15 @@ class TransformerConfig:
     #: configuration's (experts where ``moe_experts``).  Trained through
     #: ``transformer_forward``; empty: ``layer_period`` describes the stack
     layer_types: Tuple[str, ...] = ()
+    #: the stack as a sequence of RUNS, each a repeated period of layer types
+    #: — ``((("mamba", "swa"), 8), (("mamba", "dattn"), 1), (("gmu",
+    #: "xattn"), 7))`` — where the published pattern is neither one period
+    #: nor a list worth unrolling.  ``params["layers"]`` is then a tuple with
+    #: one entry per run, each a tuple with one tree per position of the
+    #: run's period, stacked ``[repeats, ...]``.  Served
+    #: (``model_runner._scan_layers`` scans run by run); ``layer_period`` is
+    #: the stack of one run
+    layer_runs: Tuple[Tuple[Tuple[str, ...], int], ...] = ()
     dense_layers: int = 0
     dense_ffn_size: int = 0
     #: taps of the gated short convolution's depthwise causal kernel (type
@@ -197,6 +206,16 @@ class TransformerConfig:
     kda_head_dim: int = 128
     kda_conv: int = 4
     kda_rank: int = 128
+    #: a window layer's reach (type "swa"): a query sees itself and the
+    #: ``sliding_window - 1`` positions before it
+    sliding_window: int = 0
+    #: selective state-space layers (type "mamba", Mamba-1): inner width, the
+    #: state's size per channel, the causal depthwise convolution's kernel
+    #: size and the rank of the step projection
+    ssm_inner: int = 0
+    ssm_state: int = 16
+    ssm_conv: int = 4
+    ssm_dt_rank: int = 0
 
     @property
     def kv_heads(self) -> int:
@@ -341,6 +360,11 @@ def init_transformer_params(cfg: TransformerConfig, rng) -> Dict[str, Any]:
         from .layer_types import init_runs
 
         p["layers"] = init_runs(cfg, rng)
+        return p
+    if cfg.layer_runs:
+        from .layer_types import init_period_runs
+
+        p["layers"] = init_period_runs(cfg, rng)
         return p
     if len(cfg.layer_period) == 1:
         p["layers"] = init_layer_stack(cfg, keys, cfg.n_layers)

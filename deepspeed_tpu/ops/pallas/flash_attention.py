@@ -43,10 +43,11 @@ def _dot(a, b, contract):
 
 
 def _scores(q, k, sl_ref, head, rows, cols, *, sm_scale, causal, alibi,
-            seq_q, seq_k):
+            seq_q, seq_k, window=0, k_first=None):
     """Masked fp32 scores [bq, bk] of one tile + the validity mask.
     ``rows``/``cols`` are absolute positions; rows past ``seq_q`` and cols
-    past ``seq_k`` are block padding."""
+    past ``seq_k`` are block padding.  ``window``: a row sees itself and the
+    ``window - 1`` columns before it, none before ``k_first``."""
     s = _dot(q, k, ((1,), (1,))) * sm_scale
     if alibi:
         # ALiBi from block indices: no [S, S] bias materialization
@@ -56,6 +57,8 @@ def _scores(q, k, sl_ref, head, rows, cols, *, sm_scale, causal, alibi,
         valid = valid & (rows < seq_q)
     if causal:
         valid = valid & (rows >= cols)
+    if window:
+        valid = valid & (rows - cols < window) & (cols >= k_first)
     return jnp.where(valid, s, NEG_INF), valid
 
 
@@ -63,7 +66,8 @@ def _scores(q, k, sl_ref, head, rows, cols, *, sm_scale, causal, alibi,
 # forward kernel
 # ---------------------------------------------------------------------------
 def _fwd_kernel(off_ref, sl_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *, sm_scale, causal, seq_k, alibi):
+                m_scr, l_scr, acc_scr, *, sm_scale, causal, seq_k, alibi,
+                window=0):
     """Grid (B*NH, nq, nk), k innermost: one [bq, bk] score tile per step,
     the online-softmax state (m, l, acc) carried in VMEM scratch across
     the k axis — VMEM holds tiles, never a whole sequence."""
@@ -86,7 +90,8 @@ def _fwd_kernel(off_ref, sl_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         rows = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         cols = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         s, _ = _scores(q_ref[0], k_ref[0], sl_ref, head, rows, cols, sm_scale=sm_scale, causal=causal, alibi=alibi,
-                       seq_q=None, seq_k=seq_k)
+                       seq_q=None, seq_k=seq_k, window=window,
+                       k_first=off_ref[1] if window else None)
         m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -111,11 +116,12 @@ def _fwd_kernel(off_ref, sl_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 def _fwd(q, k, v, alibi_arr, offset_arr, sm_scale, causal, block_q, block_k,
-         valid_k=None, q_per_kv=1, alibi=False):
+         valid_k=None, q_per_kv=1, alibi=False, window=0):
     """q: [B*NH, Sq, D]; k/v: [B*KVH, Sk, D] with NH = KVH * q_per_kv —
     GQA reads each kv head once via the index map instead of materializing
     the repeat (the reference's kv-replication copy).  ``alibi_arr``:
-    [B*NH] fp32 slopes and ``offset_arr``: [1] int32 query offset, both
+    [B*NH] fp32 slopes and ``offset_arr``: [1] int32 query offset (with a
+    ``window``, [2]: the offset and the first key a query may see), both
     scalar memory."""
     bh, seq_q, d = q.shape
     seq_k = k.shape[1]
@@ -133,7 +139,8 @@ def _fwd(q, k, v, alibi_arr, offset_arr, sm_scale, causal, block_q, block_k,
 
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
-                          seq_k=valid_k, alibi=alibi),
+                          seq_k=valid_k, alibi=alibi,
+                          **({"window": window} if window else {})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, pl.cdiv(seq_q, bq), nk),
@@ -355,7 +362,8 @@ def flash_attention(q, k, v, causal: bool = True, segment_mask=None,
                     sm_scale: Optional[float] = None,
                     block_q: int = 512, block_k: int = 512, impl: str = "pallas",
                     bwd_block_q: int = 0, bwd_block_k: int = 0,
-                    alibi_slopes=None, q_offset=None):
+                    alibi_slopes=None, q_offset=None, window: int = 0,
+                    k_first=None):
     """Public API on [B, S, NH, D] (matching models/transformer.py).
 
     GQA-native: k/v may carry KVH < NH heads (NH % KVH == 0) — each kv
@@ -380,6 +388,12 @@ def flash_attention(q, k, v, causal: bool = True, segment_mask=None,
     their position (chunked prefill over a position-ordered KV window).
     FORWARD-ONLY: the offset is not threaded through the backward
     kernels, so this path defines no VJP.
+
+    ``window`` (with ``q_offset``, forward-only): a query sees itself and
+    the ``window - 1`` keys before it, and no key before the RUNTIME scalar
+    ``k_first`` (a window layer's chunk attends ``[its ring in position
+    order | the chunk]``, and a ring that holds fewer than ``window``
+    positions is masked from the front).
     """
     B, Sq, NH, D = q.shape
     KVH = k.shape[2]
@@ -443,12 +457,19 @@ def flash_attention(q, k, v, causal: bool = True, segment_mask=None,
         sl = jnp.tile(jnp.asarray(alibi_slopes, jnp.float32), B)
     else:
         sl = jnp.zeros((B * NH,), jnp.float32)
+    if window and (q_offset is None or not causal):
+        raise ValueError("window: the mask exists in the causal forward-only "
+                         "kernel (q_offset) alone")
     if q_offset is not None:
         # forward-only inference path (no custom VJP)
-        out, _ = _fwd(qh, kh, vh, sl,
-                      jnp.asarray(q_offset, jnp.int32).reshape(1), scale,
+        off = jnp.asarray(q_offset, jnp.int32).reshape(1)
+        if window:
+            off = jnp.concatenate([off, jnp.asarray(
+                0 if k_first is None else k_first, jnp.int32).reshape(1)])
+        out, _ = _fwd(qh, kh, vh, sl, off, scale,
                       causal, block_q, block_k, Sk, q_per_kv,
-                      alibi=alibi_slopes is not None)
+                      alibi=alibi_slopes is not None,
+                      **({"window": int(window)} if window else {}))
     else:
         out = _flash_bhsd(qh, kh, vh, sl, scale, causal, block_q, block_k,
                           Sq, Sk, q_per_kv, bwd_block_q, bwd_block_k,

@@ -196,7 +196,8 @@ def _decode_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
 
 def paged_decode_attention(q, k_pool, v_pool, page_table, positions,
                            k_scale=None, v_scale=None, alibi_slopes=None,
-                           layer=None, active=None):
+                           layer=None, active=None, scale=None,
+                           name="dstpu_paged_decode"):
     """q: [B, NH, D]; pools: the engine's ``[L, P, ps, KVH*D]`` read at
     int32 scalar ``layer`` (int8 codes when ``k_scale``/``v_scale``
     ``[L, P, ps, KVH]`` given), or with ``layer=None`` one layer's
@@ -204,7 +205,10 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, positions,
     int32; positions: [B] int32; ``alibi_slopes``: optional [NH] per-head
     ALiBi slopes (bias built in-kernel from slot indices); ``active``:
     optional [B] bool — a row that is not active attends nothing and
-    returns zeros.  Returns [B, NH, D]."""
+    returns zeros; ``scale``: the scores' factor where it is not
+    ``1 / sqrt(D)``; ``name``: the kernel's name in a device trace, for a
+    caller whose "pages" are another cache (a window layer's ring in the
+    state slots is ``dstpu_window_decode``).  Returns [B, NH, D]."""
     B, NH, D = q.shape
     if layer is None:
         # one layer's pool: merging KVH and D relayouts it, which is only
@@ -259,7 +263,8 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, positions,
 
     kernel = pl.pallas_call(
         functools.partial(_decode_kernel, ps=ps, nb=nb,
-                          scale=1.0 / math.sqrt(D), kvh=KVH, quant=quant,
+                          scale=scale or 1.0 / math.sqrt(D), kvh=KVH,
+                          quant=quant,
                           alibi=alibi),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
@@ -272,7 +277,7 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, positions,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=pallas_interpret(),
-        name="dstpu_paged_decode",
+        name=name,
     )
     out = kernel(page_table, lengths,
                  jnp.asarray(layer, jnp.int32).reshape(1), *args)

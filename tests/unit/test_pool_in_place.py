@@ -96,3 +96,65 @@ def test_serving_program_keeps_the_pools_in_place(engine_for, program,
     assert mem.alias_size_in_bytes >= pool_bytes, (
         f"{program}: {mem.alias_size_in_bytes} B aliased input to output, "
         f"the pools are {pool_bytes} B — their donation no longer holds")
+
+
+# ------------------------------------------------- state and rings in slots
+S_B, S_MP, S_CHUNK = 256, 8, 32
+
+
+@pytest.fixture(scope="module")
+def slots_engine():
+    """Phi-4-mini-flash at its tiny widths with 256 slots: the window layers'
+    rings (2.4 MB each of K and V) and the state-space layers' state (4.2 MB)
+    beside one layer of 1024 pages (1 MB each) and a 1.3 MB model — what is
+    kept per sequence is most of the pools."""
+    from deepspeed_tpu.models.phi4_flash import phi4_flash_model
+
+    return InferenceEngineV2(
+        phi4_flash_model("tiny", max_seq_len=PS * S_MP),
+        RaggedInferenceConfig(dtype="fp32", page_size=PS, num_pages=1024,
+                              max_seqs=S_B, max_pages_per_seq=S_MP,
+                              prefill_chunk=S_CHUNK))
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_chunk",
+                                     "prefill_chunk_part"])
+def test_state_slots_and_window_rings_are_kept_in_place(slots_engine,
+                                                        program):
+    eng = slots_engine
+    i32 = jnp.int32
+
+    def arr(shape, dtype=i32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    if program == "decode":
+        fn, args = eng._decode, (
+            arr((S_B,)), arr((S_B,)), arr((S_B, S_MP)),
+            arr((S_B,), jnp.bool_), arr((S_B,), jnp.float32), arr((S_B,)),
+            arr((2,), jnp.uint32))
+    else:
+        fn = (eng._prefill_chunk if program == "prefill_chunk"
+              else eng._prefill_chunk_part)
+        args = (arr((S_CHUNK,)), arr((S_CHUNK // PS,)), arr((S_MP,)),
+                arr(()), arr(()), arr(()))
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree_util.tree_leaves(eng._pools))
+    slots = sum(eng._pools[n].size * 4
+                for n in ("win_k", "win_v", "ssm_s", "ssm_conv"))
+    assert slots > 3 * eng.param_bytes and slots > 0.8 * pool_bytes
+    mem = fn.lower(eng.params, eng._pools, *args).compile().memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes, (
+        f"{program}: {mem.alias_size_in_bytes} B aliased input to output, "
+        f"the pools are {pool_bytes} B — their donation does not hold")
+    if program == "decode":
+        # the CPU tier's decode runs the gather path, whose gathered windows
+        # and all-rows scan step are row-sized temporaries by nature: what
+        # the chip's program keeps is tools/aot_serve_step.py's to say
+        return
+    # the CPU backend copies the two rings once where their page view (a
+    # reshape of the leading dimensions) enters the layer loop — 4.7 MB here;
+    # libtpu does not (aot_serve_step: temporaries 0.05 GiB beside 5.4 GiB).
+    # A per-layer operand or stacked output would be every leaf again
+    assert mem.temp_size_in_bytes < pool_bytes // 2, (
+        f"{program}: temporaries {mem.temp_size_in_bytes} B beside pools of "
+        f"{pool_bytes} B — a slot-pool-sized copy is in the program")

@@ -54,7 +54,7 @@ _COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{$")
 _FREE = ("parameter", "get-tuple-element", "tuple", "while", "bitcast")
 
 
-def big_instructions(hlo: str, floor: int):
+def big_instructions(hlo: str, floor: int, no_pool=None):
     """(bytes, name, opcode, shape, in_place) of every instruction of the
     optimized HLO that materializes an array of >= ``floor`` bytes:
     instructions inside a fusion's body are left out (only the fusion's
@@ -75,7 +75,7 @@ def big_instructions(hlo: str, floor: int):
             continue
         dims = [int(d) for d in m.group(3).split(",") if d]
         nbytes = int(np.prod(dims, dtype=np.int64)) * _DTYPE_BYTES[m.group(2)]
-        if nbytes >= floor:
+        if nbytes >= floor and not (no_pool and no_pool(dims)):
             out.append((nbytes, m.group(1), m.group(4),
                         f"{m.group(2)}[{m.group(3)}]",
                         '"aliasing_operands":{"lists":[{' in line))
@@ -116,7 +116,8 @@ def abstract_engine(config: dict, engine_overrides: dict):
     return InferenceEngineV2(model, RaggedInferenceConfig(**ecfg))
 
 
-def report(name: str, lowered, pool_bytes: int, layer_pool_bytes: int) -> dict:
+def report(name: str, lowered, pool_bytes: int, layer_pool_bytes: int,
+           no_pool=None) -> dict:
     t0 = time.time()
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
@@ -132,7 +133,7 @@ def report(name: str, lowered, pool_bytes: int, layer_pool_bytes: int) -> dict:
           f"{total / GIB:.2f} GiB of 15.75")
     print(f"  Mosaic kernels: "
           f"{sorted(set(re.findall(r'dstpu_[a-z_]+', lowered.as_text())))}")
-    big = big_instructions(compiled.as_text(), layer_pool_bytes)
+    big = big_instructions(compiled.as_text(), layer_pool_bytes, no_pool)
     print(f"  instructions that materialize >= one layer of a pool "
           f"({layer_pool_bytes / 1e6:.1f} MB): {len(big)}")
     for nbytes, iname, op, shape, in_place in sorted(big, reverse=True):
@@ -195,13 +196,26 @@ def main() -> int:
 
     i32 = jnp.int32
     key = arr((2,), jnp.uint32)
+    # where a layer of the smallest pool leaf is tens of MB, two kinds of
+    # buffer are as large and are no pool: one layer's slice of a stacked
+    # weight (XLA prefetches it) and rows of logits over the vocabulary
+    weights = {(1, *a.shape[1:]) for a in jax.tree_util.tree_leaves(params)}
+    V = engine.cfg.vocab_size
+
+    def no_pool(dims):
+        return tuple(dims) in weights or (
+            dims[-1] == V and V > max(a.shape[-1] for a in pools.values()))
     results = {"decode": report(
         f"decode [{B} rows x {MP} pages]",
         engine._decode.lower(params, pools, arr((B,), i32), arr((B,), i32),
                              arr((B, MP), i32), arr((B,), jnp.bool_),
                              arr((B,), jnp.float32), arr((B,), i32), key),
-        pool_bytes, layer_pool_bytes)}
+        pool_bytes, layer_pool_bytes, no_pool)}
+    # a stack with a cross-decoder is handed the whole table row (one query
+    # reads it through the decode kernel): one shape, and a program of its
+    # own for the chunks that are not a prompt's last
     windows = ([int(w) for w in args.windows.split(",")] if args.windows
+               else [MP] if engine._xdec
                else sorted({max(1, C // ps), MP}))
     # a model whose layers keep recurrent state is handed the sequence's slot
     slot = (arr((), i32),) if engine._state else ()
@@ -211,7 +225,14 @@ def main() -> int:
             engine._prefill_chunk.lower(
                 params, pools, arr((C,), i32), arr((C // ps,), i32),
                 arr((w,), i32), arr((), i32), arr((), i32), *slot),
-            pool_bytes, layer_pool_bytes)
+            pool_bytes, layer_pool_bytes, no_pool)
+        if engine._xdec:
+            results[f"chunk{w}.part"] = report(
+                f"chunk, not a prompt's last [{C} tokens, window {w} pages]",
+                engine._prefill_chunk_part.lower(
+                    params, pools, arr((C,), i32), arr((C // ps,), i32),
+                    arr((w,), i32), arr((), i32), arr((), i32), *slot),
+                pool_bytes, layer_pool_bytes, no_pool)
     ok = all(r["temp"] < GIB and r["alias"] >= pool_bytes
              and not r["copied"] for r in results.values())
     print("aot_serve_step: " + (

@@ -18,6 +18,7 @@ are not probed: nothing calls them (ROADMAP D8).
 from __future__ import annotations
 
 import argparse
+import functools
 import os
 import sys
 import time
@@ -84,6 +85,64 @@ def cases():
                     f"D=128 {jnp.dtype(dt).name}", paged,
                     [((rows, nh, 128), bf), pool, pool, ((rows, mp), i32),
                      ((rows,), i32), ((rows,), jnp.bool_)] + scales, None, 0))
+
+    # Phi-4-mini-flash's cell: the shared pool's decode (pairs: 40 query
+    # heads of 128 over 10 K/V heads, F = 1280, blocks of 6 pages) and the
+    # window decode over a ring of 32 pages a slot, under its own name
+    def paired(name):
+        def run(q, k, v, table, pos, act):
+            return paged_decode_attention(
+                q, k, v, table, pos, layer=jnp.int32(0), active=act,
+                scale=0.125, name=name)
+        return run
+
+    for rows, mp, pages, name in ((128, 640, 32769, "dstpu_paged_decode"),
+                                  (128, 32, 129 * 32, "dstpu_window_decode")):
+        pool = ((1, pages, 16, 1280), bf)
+        out.append((f"paged_decode pairs {rows}x{mp} pages of 16, 40/10 "
+                    f"heads D=128 as {name}", paired(name),
+                    [((rows, 40, 128), bf), pool, pool, ((rows, mp), i32),
+                     ((rows,), i32), ((rows,), jnp.bool_)], None, 0))
+
+    # its window layers' chunk attention: [ring | chunk] keys with the mask
+    def flash_window(q, k, v, k_first):
+        return flash_attention(q, k, v, causal=True, q_offset=512,
+                               sm_scale=0.125, window=512, k_first=k_first)
+
+    out.append(("flash_attention fwd window=512 chunk=512 40/10 heads D=128",
+                flash_window, [((1, 512, 40, 128), bf),
+                               ((1, 1024, 10, 128), bf),
+                               ((1, 1024, 10, 128), bf), ((), i32)], None, 0))
+
+    # its selective scan: a 512-token chunk and 128 decode rows of a 129-slot
+    # pool, state [16, 5120] float32, against the token-by-token scan
+    from deepspeed_tpu.ops.pallas import ssm
+
+    def positive(dt, a):
+        return jnp.abs(dt) * 0.05, -jnp.abs(a) - 0.5
+
+    def ssm_chunk(dt, u, b, c, a, d, s, kernel=True):
+        dt, a = positive(dt, a)
+        return ssm.ssm_chunk(dt, u, b, c, a, d, s, jnp.int32(400),
+                             kernel=kernel)
+
+    def ssm_step(dt, u, b, c, a, d, pool, kernel=True):
+        dt, a = positive(dt, a)
+        act = jnp.arange(dt.shape[0]) % 5 != 0
+        y, pool = ssm.ssm_step(dt, u, b, c, a, d, pool, jnp.int32(1), act,
+                               kernel=kernel)
+        return y, pool[1, :dt.shape[0]] * act[:, None, None]
+
+    rows_of = lambda n: [((n, 5120), f32), ((n, 5120), f32),  # noqa: E731
+                         ((n, 16), f32), ((n, 16), f32), ((16, 5120), f32),
+                         ((5120,), f32)]
+    out.append(("ssm_chunk 512 tokens inner=5120 state=16", ssm_chunk,
+                rows_of(512) + [((16, 5120), f32)],
+                functools.partial(ssm_chunk, kernel=False), 1e-5))
+    out.append(("ssm_step 128 rows of 129 slots x 2 layers inner=5120 "
+                "state=16", ssm_step,
+                rows_of(128) + [((2, 129, 16, 5120), f32)],
+                functools.partial(ssm_step, kernel=False), 1e-5))
 
     # Mixtral-8x7B expert matrices: 4096 x 14336, 8 experts, 4096 rows
     def gmm(x, w, be):
