@@ -156,24 +156,31 @@ def test_paged_decode_quantized_matches_dequant(layered):
 
 
 # ------------------------------------------- the walk over a row's live pages
-WALK_PS, WALK_MP, WALK_KVH, WALK_D = 16, 20, 2, 16
+WALK_PS, WALK_MP, WALK_KVH, WALK_D = 16, 40, 2, 16
 
 
-def _walk_case(rng, g, variant):
-    """Rows whose lengths straddle a block of the kernel's walk, one row
-    inactive, over scattered non-monotone page tables.  Table entries past
-    a row's live pages — all of the inactive row's — name POISON pages
-    (± 3e38 and NaN), as do the pool's unused pages.  Returns the kernel's
-    output over the poisoned pools and `_gather_window_attend` over the
-    same pools with the poison zeroed."""
-    ps, MP, KVH, D = WALK_PS, WALK_MP, WALK_KVH, WALK_D
+def _walk_case(rng, g, variant, kvh=WALK_KVH, d=WALK_D, dtype=jnp.float32,
+               inactive_at=5, **kernel_kw):
+    """Rows whose lengths straddle a block of the kernel's walk — one of a
+    single token, one that ends exactly on a block's edge, one a token
+    past it, a full table — and one row inactive (at ``inactive_at`` of the
+    one grid step the six rows make), over scattered non-monotone page
+    tables.  Table entries past a row's live pages — all of the inactive
+    row's — name POISON pages (± 3e38 and NaN), as do the pool's unused
+    pages.  Returns the kernel's output over the poisoned pools (stored as
+    ``dtype``; ``kernel_kw``: its ``scale`` and ``name``) and
+    `_gather_window_attend` in float32 over the same pools with the poison
+    zeroed."""
+    ps, MP, KVH, D = WALK_PS, WALK_MP, kvh, d
     quant, alibi = variant == "int8", variant == "alibi"
     layered = variant != "one"
-    nb = pages_per_block(ps, KVH * D, 1 if quant else 4)
+    nb = pages_per_block(ps, KVH * D, 1 if quant else jnp.dtype(dtype).itemsize)
     T = nb * ps
-    assert T < MP * ps  # the table holds more than two blocks
-    positions = np.asarray([0, T - 1, T, MP * ps - 1, 2 * T + 3, 37])
-    active = np.asarray([True, True, True, True, True, False])
+    assert 2 * T + 3 < MP * ps  # the table holds more than two blocks
+    order = list(range(5))
+    order.insert(inactive_at, 5)
+    positions = np.asarray([0, T - 1, T, MP * ps - 1, 2 * T + 3, 37])[order]
+    active = np.asarray([True, True, True, True, True, False])[order]
     B, NH = len(positions), KVH * g
     n_live = np.where(active, positions // ps + 1, 0)
     P = int(n_live.sum()) + 9  # live pages, 8 poison pages, the trash page
@@ -187,30 +194,36 @@ def _walk_case(rng, g, variant):
     bad = np.asarray([3e38, -3e38, np.nan, 3e38, np.nan, -3e38, np.nan, 3e38],
                      np.float32)
 
-    def pool(make, fill):
-        """(poisoned, clean) [L, P, ps, ...] pools of N_LAYERS layers."""
-        clean = np.stack([make() for _ in range(N_LAYERS if layered else 1)])
-        dirty = clean.copy()
-        shape = (1, len(poison)) + (1,) * (clean.ndim - 2)
-        clean[:, poison] = 0
-        dirty[:, poison] = fill.reshape(shape).astype(clean.dtype)
-        return jnp.asarray(dirty), jnp.asarray(clean)
+    def pool(make, fill, store):
+        """(poisoned as stored, clean float32) [L, P, ps, ...] pools of
+        N_LAYERS layers; the clean one holds what the stored one does."""
+        made = np.stack([make() for _ in range(N_LAYERS if layered else 1)])
+        dirty = made.copy()
+        shape = (1, len(poison)) + (1,) * (made.ndim - 2)
+        made[:, poison] = 0
+        dirty[:, poison] = fill.reshape(shape).astype(made.dtype)
+        clean = jnp.asarray(made, store)
+        return jnp.asarray(dirty, store), (
+            clean if store == jnp.int8 else clean.astype(jnp.float32))
 
     if quant:
         codes = lambda: rng.randint(-127, 128, (P, ps, KVH * D)).astype(  # noqa: E731
             np.int8)
-        scale = lambda: (rng.rand(P, ps, KVH) * 0.05 + 0.01).astype(  # noqa: E731
-            np.float32)
-        names = {"k": (codes, np.full(8, 127)), "v": (codes, np.full(8, -127)),
-                 "k_scale": (scale, bad), "v_scale": (scale, bad)}
+        # values of about a unit normal's size at any head dimension
+        scale = lambda: ((rng.rand(P, ps, KVH) * 0.05 + 0.01)  # noqa: E731
+                         * 4 / math.sqrt(D)).astype(np.float32)
+        names = {"k": (codes, np.full(8, 127), jnp.int8),
+                 "v": (codes, np.full(8, -127), jnp.int8),
+                 "k_scale": (scale, bad, jnp.float32),
+                 "v_scale": (scale, bad, jnp.float32)}
     else:
         vals = lambda: rng.randn(P, ps, KVH * D).astype(np.float32)  # noqa: E731
-        names = {"k": (vals, bad), "v": (vals, bad[::-1])}
+        names = {"k": (vals, bad, dtype), "v": (vals, bad[::-1], dtype)}
     dirty, clean = {}, {}
-    for name, (make, fill) in names.items():
-        dirty[name], clean[name] = pool(make, fill)
+    for name, (make, fill, store) in names.items():
+        dirty[name], clean[name] = pool(make, fill, store)
 
-    q = jnp.asarray(rng.randn(B, NH, D), jnp.float32)
+    q = jnp.asarray(rng.randn(B, NH, D), dtype)
     table = jnp.asarray(table, jnp.int32)
     pos = jnp.asarray(positions, jnp.int32)
     cfg = TransformerConfig(hidden_size=NH * D, n_heads=NH, n_kv_heads=KVH,
@@ -222,16 +235,18 @@ def _walk_case(rng, g, variant):
             q, dirty["k"], dirty["v"], table, pos,
             k_scale=dirty.get("k_scale"), v_scale=dirty.get("v_scale"),
             alibi_slopes=slopes, layer=jnp.int32(lyr),
-            active=jnp.asarray(active))
+            active=jnp.asarray(active), **kernel_kw)
     else:  # one layer's [P, ps, KVH, D] pool
         out = paged_decode_attention(
             q, dirty["k"][0].reshape(P, ps, KVH, D),
             dirty["v"][0].reshape(P, ps, KVH, D), table, pos,
-            active=jnp.asarray(active))
+            active=jnp.asarray(active), **kernel_kw)
     vis = jnp.arange(MP * ps)[None, None, :] <= pos[:, None, None]
-    ref = _gather_window_attend(cfg, q[:, None], clean, lyr, table,
-                                pos[:, None], vis)
-    return np.asarray(out), np.asarray(ref[:, 0].reshape(B, NH, D)), active
+    ref = _gather_window_attend(cfg, q.astype(jnp.float32)[:, None], clean,
+                                lyr, table, pos[:, None], vis,
+                                scale=kernel_kw.get("scale"))
+    return (np.asarray(out.astype(jnp.float32)),
+            np.asarray(ref[:, 0].reshape(B, NH, D)), active)
 
 
 @pytest.mark.parametrize("variant", ["layered", "one", "int8", "alibi"])
@@ -247,12 +262,40 @@ def test_paged_decode_walks_the_live_pages_only(g, variant):
     assert not out[~active].any()
 
 
+#: the geometries a block is attended at in the serving cells: Phi-4-mini-
+#: flash's pairs (F = 1280) under the window layers' name and scale, and
+#: Solar-Open2's 8 query heads a K/V head (64 stacked rows of scores)
+GEOMETRIES = {
+    "phi4-pairs": dict(kvh=10, g=4, scale=0.125, name="dstpu_window_decode"),
+    "solar-g8": dict(kvh=8, g=8),
+}
+
+
+@pytest.mark.parametrize("variant", ["layered", "one", "int8", "alibi"])
+@pytest.mark.parametrize("geo", sorted(GEOMETRIES))
+def test_paged_decode_attends_a_block_for_all_its_heads(geo, variant):
+    """bfloat16 at head 128 in blocks of `pages_per_block` pages: a row of
+    one token, one that ends on a block's edge, an inactive row between
+    two live ones in the one grid step, poison in every unfetched page;
+    against the gather path in float32."""
+    out, ref, active = _walk_case(
+        np.random.RandomState(len(geo)), variant=variant, d=128,
+        dtype=jnp.bfloat16, inactive_at=2, **GEOMETRIES[geo])
+    assert active.tolist() == [True, True, False, True, True, True]
+    assert np.all(np.isfinite(out))
+    # bfloat16 keys, values, probabilities and result against float32
+    np.testing.assert_allclose(out[active], ref[active], rtol=2e-2,
+                               atol=2e-2 * np.abs(ref).max())
+    assert not out[~active].any()
+
+
 def test_n_blocks_is_the_kernels_loop_bound():
     """The host's `decode_kv_blocks` and the kernel's loop share one
     function; block size follows the page geometry alone."""
-    assert pages_per_block(16, 8 * 128, 2) == 8      # bf16 Mistral / Solar
-    assert pages_per_block(16, 8 * 128, 1) == 8      # int8: 128 tokens bind
-    assert pages_per_block(16, 32 * 128, 2) == 2     # MHA: the slot binds
+    assert pages_per_block(16, 8 * 128, 2) == 16     # bf16 Mistral / Solar
+    assert pages_per_block(16, 8 * 128, 1) == 16     # int8: 256 tokens bind
+    assert pages_per_block(16, 10 * 128, 2) == 16    # Phi-4's pairs
+    assert pages_per_block(16, 32 * 128, 2) == 8     # MHA: the slot binds
     assert pages_per_block(256, 8 * 128, 2) == 1
-    lengths = np.asarray([0, 1, 127, 128, 129, 4096])
-    assert n_blocks(lengths, 16, 8).tolist() == [0, 1, 1, 1, 2, 32]
+    lengths = np.asarray([0, 1, 255, 256, 257, 4096])
+    assert n_blocks(lengths, 16, 16).tolist() == [0, 1, 1, 1, 2, 16]

@@ -182,13 +182,13 @@ def test_serve_step_counts_the_blocks_the_paged_kernel_walks(ring, registry,
                                                           pages_per_block)
 
     cfg = dict(dtype="fp32", page_size=8, num_pages=128, max_seqs=4,
-               max_pages_per_seq=48, prefill_chunk=64, decode_horizon=horizon)
-    model = llama_model("tiny", max_seq_len=384)
+               max_pages_per_seq=72, prefill_chunk=64, decode_horizon=horizon)
+    model = llama_model("tiny", max_seq_len=576)
     eng = InferenceEngineV2(model, RaggedInferenceConfig(**cfg), seed=0)
     pool = eng._pools["k"]
     nb = pages_per_block(8, pool.shape[-1], pool.dtype.itemsize)
-    assert eng._kv_block_pages == nb and nb * 8 == 128
-    lengths = (9, 125, 260)
+    assert eng._kv_block_pages == nb and nb * 8 == 256
+    lengths = (9, 253, 520)
     prompts = _prompts(model, lengths)
     steps = _run(eng, ring, prompts, new_tokens=7)
     have = dict(enumerate(lengths))  # uids count from 0 in put() order
@@ -207,8 +207,8 @@ def test_serve_step_counts_the_blocks_the_paged_kernel_walks(ring, registry,
         assert top.attrs["decode_kv_blocks"] == want
         assert want >= top.attrs["decode_rows"]
         total += want
-    # 6 decoded tokens a request over 1 block, 1 then 2 (the row of 125 + 1
-    # tokens passes 128 after its third) and 3 blocks
+    # 6 decoded tokens a request over 1 block, 1 then 2 (the row of 253 + 1
+    # tokens passes 256 after its third) and 3 blocks
     assert total == 6 * 1 + (3 * 1 + 3 * 2) + 6 * 3
     assert eng.decode_stats()["decode_kv_blocks"] == total
     assert registry.get(

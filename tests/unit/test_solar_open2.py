@@ -67,16 +67,16 @@ def test_engine_prefill_then_decode_matches_the_reference(kernels, horizon,
                                                           monkeypatch):
     """put / step against the float32 reference: a prompt over three chunks
     (state crosses two chunk boundaries), a shorter one and one of two
-    blocks of the paged kernel's walk (128 tokens here) in the same batch,
+    blocks of the paged kernel's walk (256 tokens here) in the same batch,
     one decode slot left empty; nine, six and seven greedy tokens, so the
     rows retire at different steps (mid-scan under the fused horizon);
     every token the reference's own argmax."""
     if kernels == "interpreted":
         monkeypatch.setenv("DSTPU_PAGED_KERNEL", "1")
-    eng = _engine(max_seq_len=192, max_pages_per_seq=24, num_pages=96,
+    eng = _engine(max_seq_len=320, max_pages_per_seq=40, num_pages=96,
                   decode_horizon=horizon)
     rng = np.random.default_rng(0)
-    prompts = [rng.integers(0, 256, n).tolist() for n in (40, 13, 133)]
+    prompts = [rng.integers(0, 256, n).tolist() for n in (40, 13, 261)]
     n_new = (9, 6, 7)
     uids = [eng.put(RaggedRequest(prompt_ids=p, max_new_tokens=n))
             for p, n in zip(prompts, n_new)]

@@ -12,7 +12,10 @@ time.  It says nothing about numerics — only a run on the chip does.
 One line per kernel: ``compiles`` (and, on the chip, the error against its XLA
 reference) or ``refused`` with the compiler's message.  Exit code 1 if any
 kernel was refused or disagreed.  ``sparse_attention`` and ``evoformer_attn``
-are not probed: nothing calls them (ROADMAP D8).
+are not probed: nothing calls them (ROADMAP D8).  On the chip it also times
+the expert share's two row kernels (``--only moe_dispatch``) and the paged
+decode kernel at its four geometries (``--only paged_decode``: the time a
+call, a block, and the share of the bytes' roofline).
 """
 
 from __future__ import annotations
@@ -367,6 +370,80 @@ def row_rates() -> None:
                      if name.startswith("dstpu") else ""), flush=True)
 
 
+#: the paged kernel's four geometries: (name in a trace, rows, table pages,
+#: query heads, K/V heads, scale, visible tokens of row i)
+PAGED_SHAPES = [
+    ("dstpu_paged_decode", "Mistral chat", 64, 256, 32, 8, None,
+     lambda i: 4096),
+    ("dstpu_paged_decode", "Solar", 128, 512, 64, 8, None, lambda i: 2048),
+    ("dstpu_paged_decode", "Phi-4 pairs, the shared pool", 128, 640, 40, 10,
+     0.125, lambda i: 160 + (i * 613) % 1900),
+    ("dstpu_window_decode", "Phi-4 pairs, a ring of 512", 128, 32, 40, 10,
+     0.125, lambda i: 512),
+]
+
+
+def paged_rates() -> None:
+    """On the chip: what a call of the paged decode kernel costs at each of
+    its four geometries (pages of 16 tokens, heads of 128, bfloat16), as 16
+    calls chained in one program (a call's result is the next one's
+    queries) by the host's clock: the time a call, a block of
+    ``pages_per_block`` pages, and the visible pages' bytes against the
+    HBM's peak."""
+    import json
+    import pathlib
+
+    import numpy as np
+
+    from deepspeed_tpu.ops.pallas.paged_attention import (
+        n_blocks, paged_decode_attention, pages_per_block)
+
+    reps, ps, d = 16, 16, 128
+    peaks = json.loads((pathlib.Path(__file__).resolve().parents[1]
+                        / "benchmark" / "peaks.json").read_text())
+    hbm = peaks[jax.devices()[0].device_kind]["hbm_bytes_per_s"]
+    for name, what, b, mp, nh, kvh, scale, tokens in PAGED_SHAPES:
+        feat = kvh * d
+        toks = np.asarray([min(tokens(i), mp * ps) for i in range(b)])
+        pages = -(-toks // ps)
+        n_pages = int(pages.sum())
+        nb = pages_per_block(ps, feat, 2)
+        blocks = int(n_blocks(toks, ps, nb).sum())
+        ks = jax.random.split(jax.random.PRNGKey(2), 4)
+        q = jax.random.normal(ks[0], (b, nh, d), jnp.bfloat16)
+        k_pool, v_pool = (jax.random.normal(
+            k, (1, n_pages + 1, ps, feat), jnp.bfloat16) for k in ks[1:3])
+        table = np.full((b, mp), n_pages, np.int32)
+        perm = np.asarray(jax.random.permutation(ks[3], n_pages))
+        ends = np.cumsum(pages)
+        for r in range(b):
+            table[r, :pages[r]] = perm[ends[r] - pages[r]:ends[r]]
+
+        @jax.jit
+        def chained(q, k_pool, v_pool, table, pos):
+            return jax.lax.fori_loop(
+                0, reps, lambda _, x: paged_decode_attention(
+                    x, k_pool, v_pool, table, pos, layer=jnp.int32(0),
+                    scale=scale, name=name), q)
+
+        args = (q, k_pool, v_pool, jnp.asarray(table),
+                jnp.asarray(toks - 1, jnp.int32))
+        chained(*args).block_until_ready()
+        times = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            chained(*args).block_until_ready()
+            times.append(time.perf_counter() - t0)
+        ms = sorted(times)[2] / reps * 1e3
+        least_ms = 2 * n_pages * ps * feat * 2 / hbm * 1e3
+        print(f"paged rates, {what} as {name}: {b} rows x {mp} pages, "
+              f"{nh}/{kvh} heads, {n_pages} visible pages in {blocks} blocks "
+              f"of {nb}: {ms:.4f} ms a call = {ms * 1e3 / blocks:.4f} us a "
+              f"block, {least_ms / ms * 100:.1f} % of the bytes' roofline "
+              f"({least_ms:.4f} ms)", flush=True)
+        del args, k_pool, v_pool
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--aot", action="store_true",
@@ -433,6 +510,8 @@ def main() -> int:
         print(line, flush=True)
     if not args.aot and args.only in "moe_dispatch + moe_combine":
         row_rates()
+    if not args.aot and args.only in "paged_decode":
+        paged_rates()
     return 1 if bad else 0
 
 
