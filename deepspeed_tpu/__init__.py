@@ -11,7 +11,11 @@ are Pallas.
 
 from __future__ import annotations
 
-from typing import Any, Optional, Tuple
+import time as _time
+
+_T_IMPORT = _time.perf_counter()  # the set-up ledger's origin
+
+from typing import Any, Optional, Tuple  # noqa: E402
 
 __version__ = "0.1.0"
 
@@ -205,3 +209,8 @@ def add_config_arguments(parser):
     group.add_argument("--local_rank", type=int, default=0,
                        help="Local process index (set by the launcher)")
     return parser
+
+
+from .telemetry.compile_sentinel import setup_span as _setup_span  # noqa: E402
+
+_setup_span("package_import", _T_IMPORT)

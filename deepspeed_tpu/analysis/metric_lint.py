@@ -17,7 +17,9 @@ string-literal first argument — and enforces:
 
 It also scans span/event recordings — ``span("name", ...)``,
 ``begin_span("name", ...)``, ``record_event("name", ...)`` with a
-string-literal first argument (``telemetry/spans.py``) — and enforces
+string-literal first argument (``telemetry/spans.py``), and the set-up
+ledger's ``setup_span("name", ...)``
+(``telemetry/compile_sentinel.py``) — and enforces
 the matching rules for the trace namespace:
 
 4. ``snake_case`` WITHOUT the ``deepspeed_tpu_`` prefix (that namespace
@@ -60,7 +62,8 @@ SPAN_NAME_RE = re.compile(r"^[a-z][a-z0-9_]*$")
 
 _METHODS = {"counter": "counter", "gauge": "gauge", "histogram": "histogram"}
 _CTORS = {"Counter": "counter", "Gauge": "gauge", "Histogram": "histogram"}
-_SPAN_FNS = {"span": "span", "begin_span": "span", "record_event": "event"}
+_SPAN_FNS = {"span": "span", "begin_span": "span", "record_event": "event",
+             "setup_span": "span"}
 
 #: registration sites that define the generic machinery itself, not a metric
 _EXCLUDE_FILES = {os.path.join("deepspeed_tpu", "telemetry", "registry.py")}
@@ -88,6 +91,10 @@ _FAMILY_OWNERS = {
         os.path.join("deepspeed_tpu", "serving", "autoscale.py"),
     "deepspeed_tpu_serving_kv_nvme_":
         os.path.join("deepspeed_tpu", "serving", "kv_tier.py"),
+    # the set-up ledger is the one account of where a start went
+    # (docs/OBSERVABILITY.md "Set-up")
+    "deepspeed_tpu_setup_":
+        os.path.join("deepspeed_tpu", "telemetry", "compile_sentinel.py"),
 }
 
 Site = Tuple[str, int, str]  # (relpath, lineno, metric_type)

@@ -32,7 +32,9 @@ from ...moe.sharded_moe import MOE_COUNTERS
 from ...ops.pallas.paged_attention import n_blocks, pages_per_block
 from ...runtime.config_utils import ConfigModel
 from ...telemetry import get_registry
-from ...telemetry.compile_sentinel import RecompileSentinel, compile_counts
+from ...telemetry.compile_sentinel import (RecompileSentinel,
+                                           compile_counts,
+                                           publish_setup_seconds, setup_span)
 from ...telemetry.compile_sentinel import \
     expect_recompile as sentinel_expect_recompile
 from ...telemetry.flight import dump_on_exception, get_flight_recorder
@@ -248,6 +250,7 @@ class InferenceEngineV2:
 
     def __init__(self, model: Any, config: Optional[RaggedInferenceConfig] = None,
                  params: Any = None, seed: int = 0, proposer: Any = None):
+        t_init = time.perf_counter()
         ensure_compile_cache()
         self.config = config or RaggedInferenceConfig()
         if isinstance(self.config.speculative, dict):  # hand-built configs
@@ -500,6 +503,8 @@ class InferenceEngineV2:
             every_n_steps=self.config.timeline_every_n_steps,
             artifact_dir=self.config.timeline_artifact_dir)
         self._wire_memory_ledger()
+        setup_span("serve_engine_init", t_init)
+        publish_setup_seconds()
 
     def _refuse_with_state(self, proposer: Any) -> None:
         """What a model with recurrent state cannot be served with: each
@@ -2608,6 +2613,7 @@ class InferenceEngineV2:
     def publish_metrics(self, monitor, step: int) -> None:
         """Surface the serving counters through a monitor/* writer
         (MonitorMaster or any object with ``write_events``)."""
+        publish_setup_seconds()
         monitor.write_events([(f"serving/{k}", float(v), int(step))
                               for k, v in self.cache_stats().items()])
 

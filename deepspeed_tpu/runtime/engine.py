@@ -36,7 +36,8 @@ import optax
 
 from .. import comm
 from ..parallel.mesh import MeshTopology
-from ..telemetry.compile_sentinel import expect_recompile
+from ..telemetry.compile_sentinel import (expect_recompile,
+                                          publish_setup_seconds, setup_span)
 from ..telemetry.flight import dump_on_exception
 from ..telemetry.spans import record_event, span
 from ..utils.jax_compat import shard_map
@@ -55,9 +56,6 @@ from .precision import (LossScaleState, cast_tree, check_overflow,
                         loss_scale_summary, nonfinite_count,
                         update_loss_scale)
 from .zero.strategy import ZeroShardingPlan
-
-#: warn-once latch for the deprecated (pre-rename) exposed-seconds alias
-_EXPOSED_ALIAS_WARNED = False
 
 
 @jax.tree_util.register_dataclass
@@ -95,6 +93,7 @@ class DeepSpeedTPUEngine:
                  client_optimizer=None,
                  lr_scheduler=None,
                  seed: Optional[int] = None):
+        t_init = time.perf_counter()
         self.config = config
         self.topology = topology or MeshTopology(config.mesh)
         config.resolve_batch_size(self.topology.dp_world_size)
@@ -324,6 +323,8 @@ class DeepSpeedTPUEngine:
                  f"dtype={self.compute_dtype.__name__} mesh={self.topology.axis_sizes} "
                  f"micro_bs={config.train_micro_batch_size_per_gpu} "
                  f"gas={config.gradient_accumulation_steps}")
+        setup_span("train_engine_init", t_init)
+        publish_setup_seconds()
 
     def _configure_zeropp(self, config: DeepSpeedConfig) -> None:
         """ZeRO++ wiring (reference engine.py:1101-1113 config keys).
@@ -2090,14 +2091,6 @@ class DeepSpeedTPUEngine:
             "nominal per-generation interconnect bandwidth (a model — "
             "the MEASURED counterpart is "
             "deepspeed_tpu_timeline_exposed_collective_seconds)")
-        # deprecated alias: the pre-rename series keeps moving so
-        # existing dashboards don't flatline; a warn-once fires at the
-        # first increment (see _report_telemetry)
-        self._m_exposed_deprecated = reg.counter(
-            "deepspeed_tpu_train_exposed_collective_seconds",
-            "DEPRECATED alias of "
-            "deepspeed_tpu_train_exposed_collective_seconds_estimated "
-            "(renamed to make the byte-model nature explicit)")
         self._m_moe_picks = reg.gauge(
             "deepspeed_tpu_train_moe_held_picks_per_step",
             "picks that landed on this chip's held experts, all expert "
@@ -2290,6 +2283,7 @@ class DeepSpeedTPUEngine:
             # structural attribution + watermarks -> gauges (host-side
             # tree walk; boundary cadence keeps it off the hot path)
             tm.ledger.publish()
+        publish_setup_seconds()
         # dstpu-lint: allow[host-sync] boundary cadence, queue drained
         skipped = int(self.state.skipped_steps)
         if skipped > self._skipped_pub:
@@ -2299,17 +2293,8 @@ class DeepSpeedTPUEngine:
         if report is not None:
             self._m_overlap_frac.set(report.overlapped_fraction)
             if self._win_steps > 0:
-                inc = report.exposed_seconds_per_step * self._win_steps
-                self._m_exposed.inc(inc)
-                global _EXPOSED_ALIAS_WARNED
-                if not _EXPOSED_ALIAS_WARNED:
-                    _EXPOSED_ALIAS_WARNED = True
-                    logger.warning(
-                        "deepspeed_tpu_train_exposed_collective_seconds is "
-                        "deprecated: read ..._estimated (same byte-model "
-                        "series) or the MEASURED "
-                        "deepspeed_tpu_timeline_exposed_collective_seconds")
-                self._m_exposed_deprecated.inc(inc)
+                self._m_exposed.inc(
+                    report.exposed_seconds_per_step * self._win_steps)
         # structural (schedule-derived, no sync): pipe bubble share
         pipe_struct = getattr(self, "_pipe_struct", None)
         if pipe_struct is not None:

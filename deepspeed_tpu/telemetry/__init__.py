@@ -17,7 +17,7 @@ from __future__ import annotations
 from typing import Optional
 
 from .compile_sentinel import (RecompileSentinel, compile_counts,
-                               expect_recompile)
+                               expect_recompile, setup_ledger)
 from .exporter import (JSONLWriter, PrometheusFileExporter,
                        PrometheusHTTPExporter, parse_prometheus_text,
                        record_export_failure, snapshot_metrics,
@@ -63,6 +63,7 @@ __all__ = [
     "is_resource_exhausted", "record_oom_incident", "oom_hints",
     "top_live_buffers",
     "RecompileSentinel", "expect_recompile", "compile_counts",
+    "setup_ledger",
     "PEAK_BF16_FLOPS", "peak_flops_for_kind", "peak_flops_for_device", "mfu",
     "StepTimeline", "capture_thunk", "categorize_op", "decompose_events",
     "last_timeline_record",

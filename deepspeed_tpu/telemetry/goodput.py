@@ -8,9 +8,10 @@ versus badput buckets, as single-owner counters plus a
 * ``step``            — productive optimizer steps (an fp16 overflow-skip
   step still bought loss-scale adaptation: it counts as productive, not
   badput);
-* ``compile``         — XLA backend compiles (PR 3 compile sentinel;
-  compile seconds are *subtracted* from whatever phase they interrupted
-  so a second is never counted twice);
+* ``compile``         — making programs runnable: the Python trace,
+  lowering, and the XLA compile or persistent-cache load (the compile
+  sentinel's set-up ledger; these seconds are *subtracted* from whatever
+  phase they interrupted so a second is never counted twice);
 * ``checkpoint_save`` / ``checkpoint_load`` — checkpoint I/O (the
   existing ``checkpoint_save``/``checkpoint_load`` span sites);
 * ``restart``         — preemption/kill recovery: auto-resume restore
@@ -59,12 +60,13 @@ _RUN_BUCKETS = tuple(b for b in BUCKETS if b != "idle")
 
 
 def _compile_seconds_total() -> float:
-    """Process-wide XLA compile seconds from the compile sentinel
-    (0.0 when the jax.monitoring listener is unavailable)."""
+    """Process-wide trace + lowering + compile-or-load seconds from the
+    compile sentinel (0.0 when the jax.monitoring listener is
+    unavailable)."""
     try:
-        from .compile_sentinel import compile_counts
+        from .compile_sentinel import compile_path_seconds
 
-        return float(compile_counts()[1])
+        return compile_path_seconds()
     except Exception:
         return 0.0
 

@@ -25,7 +25,7 @@ against the measured compute spans (``train_batch`` walls) gives:
 
 Engine gauges (single owner: ``runtime/engine.py``):
 ``deepspeed_tpu_train_overlapped_fraction`` and
-``deepspeed_tpu_train_exposed_collective_seconds`` (cumulative
+``deepspeed_tpu_train_exposed_collective_seconds_estimated`` (cumulative
 estimate), catalogued in docs/OBSERVABILITY.md and explained in
 docs/COMM.md ("Overlap & scheduling").
 """
