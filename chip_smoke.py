@@ -12,7 +12,9 @@ final result line.  Legs:
 * kernels — flash forward / backward / ``q_offset`` forward and paged decode
   against their XLA references at the shapes the other legs use, and the
   grouped expert matmul at the Solar-Open2 share's (40 experts of
-  4096 x 1280 and 1280 x 4096, 1,024 and 4,096 picks);
+  4096 x 1280 and 1280 x 4096, 1,024 and 4,096 picks), and its delta-rule
+  step kernel (128 rows of 64 heads of 128 x 128, 8, 40 and 128 of them
+  decoding);
 * train   — ``deepspeed_tpu.initialize`` -> ``engine.train_batch``: bf16,
   AdamW, ZeRO-1, clipping 1.0, seq 4096, one repeated seeded batch;
 * serve   — ``InferenceEngineV2`` driven by the ``put`` / ``step`` loop of
@@ -28,6 +30,7 @@ holds and printed.  Weights are random from a seed.
 from __future__ import annotations
 
 import argparse
+import functools
 import gc
 import json
 import math
@@ -47,7 +50,9 @@ FULL = dict(size="7b", seq=4096, train_layers=1, serve_layers=4, steps=6,
             offset_chunk=512, offset_window=2048, offset=1024,
             decode_positions=(5, 700, 1999, 2047), walk_table=(64, 256),
             moe=dict(held=40, experts=320, hidden=4096, ffn=1280,
-                     picks=(1024, 4096)))
+                     picks=(1024, 4096)),
+            kda=dict(rows=128, heads=64, dim=128, layers=3,
+                     active=(8, 40, 128)))
 #: rehearsal: same control flow at sizes the CPU interpreter finishes
 TINY = dict(size="tiny", seq=128, train_layers=2, serve_layers=2, steps=4,
             heads=4, kv_heads=2, head_dim=16,
@@ -56,7 +61,8 @@ TINY = dict(size="tiny", seq=128, train_layers=2, serve_layers=2, steps=4,
             offset_chunk=32, offset_window=128, offset=64,
             decode_positions=(5, 40, 100, 127), walk_table=(4, 16),
             moe=dict(held=4, experts=32, hidden=256, ffn=128,
-                     picks=(64, 256)))
+                     picks=(64, 256)),
+            kda=dict(rows=8, heads=64, dim=32, layers=2, active=(1, 3, 8)))
 
 #: Kernel-vs-reference tolerances: max |kernel - ref| over max |ref|, the
 #: reference computed in float32 at "highest" matmul precision from the same
@@ -249,6 +255,7 @@ def leg_kernels(sz, on_chip: bool) -> None:
               "rows full < 0.05")
     del k_pool, v_pool
     expert_matmul_checks(sz["moe"], on_chip)
+    kda_step_checks(sz["kda"], on_chip)
 
 
 def expert_matmul_checks(moe, on_chip: bool) -> None:
@@ -386,6 +393,91 @@ def expert_matmul_checks(moe, on_chip: bool) -> None:
               f"{quarter / every:.3f} of all of them < 0.4")
         check(none < 0.05 * every, f"no expert touched takes "
               f"{none / every:.4f} of all of them < 0.05")
+
+
+def kda_step_checks(kda, on_chip: bool) -> None:
+    """The delta-rule step kernel at a serving share's widths: a scattered
+    set of decode rows against ``kda_step_xla`` on the same inputs, every
+    other slot left as it was, and its time following the rows that decode,
+    not the slots."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops.pallas.kda import kda_step, kda_step_xla
+
+    B, H, D, L = kda["rows"], kda["heads"], kda["dim"], kda["layers"]
+    few, some, every = kda["active"]
+    f32, lyr = jnp.float32, 1
+    ks = jax.random.split(jax.random.PRNGKey(3), 7)
+    unit = lambda x: x * jax.lax.rsqrt(  # noqa: E731
+        jnp.sum(x * x, -1, keepdims=True))
+    q = unit(jax.random.normal(ks[0], (B, H, D), f32)) / math.sqrt(D)
+    k = unit(jax.random.normal(ks[1], (B, H, D), f32))
+    v = jax.random.normal(ks[2], (B, H, D), f32)
+    g = -jax.random.uniform(ks[3], (B, H, D), f32, 0.0, 6.0)
+    beta = jax.random.uniform(ks[4], (B, H), f32, 0.0, 2.0)
+    order = jax.random.permutation(ks[6], B)
+
+    def mask(n):
+        return jnp.zeros((B,), bool).at[order[:n]].set(True)
+
+    pool = jax.random.normal(ks[5], (L, B + 1, H, D, D), f32)
+    act = mask(some)
+    want_o, want_s = jax.jit(kda_step_xla)(q, k, v, g, beta, pool[lyr, :B])
+    got_o, got_s = kda_step(q, k, v, g, beta, pool, lyr, act)
+    e_o = rel_err(got_o, want_o * act[:, None, None])
+    e_s = rel_err(got_s[lyr, :B][act], want_s[act])
+    check(e_o < 1e-5 and e_s < 1e-5 and bool(jnp.any(want_o)),
+          f"dstpu_kda_step {some} scattered rows of {B}, {H} heads of {D} x "
+          f"{D}, layer {lyr} of {L} vs kda_step_xla: o rel err {e_o:.2e}, "
+          f"state {e_s:.2e} < 1e-05")
+    check(not bool(jnp.any(got_o[~act])) and bool(jnp.array_equal(
+        got_s[lyr, :B][~act], pool[lyr, :B][~act])) and all(
+            bool(jnp.array_equal(got_s[i], pool[i]))
+            for i in range(L) if i != lyr),
+          "a row that does not decode returns zero and its slot, like the "
+          "other layers', is bit for bit what went in")
+    if on_chip:
+        text = kda_step.lower(q, k, v, g, beta, pool, lyr, act).as_text()
+        check("tpu_custom_call" in text and "dstpu_kda_step" in text,
+              "the step kernel lowers to the Mosaic custom call")
+    del want_o, want_s, got_o, got_s
+
+    # 64 calls in one program, the pool updated in place from call to call
+    # and each call's first output fed to the next one's queries
+    n_calls = 64 if on_chip else 2
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def calls(q_, pool_, act_):
+        def body(_, c):
+            q_, pool_ = c
+            o, pool_ = kda_step(q_, k, v, g, beta, pool_, lyr, act_)
+            return q_.at[0, 0].add(o[0, 0] * 0), pool_
+        return jax.lax.fori_loop(0, n_calls, body, (q_, pool_))[1]
+
+    def call_ms(n):
+        nonlocal pool
+        pool = calls(q, pool, mask(n)).block_until_ready()
+        times = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            pool = calls(q, pool, mask(n)).block_until_ready()
+            times.append(time.perf_counter() - t0)
+        return sorted(times)[2] * 1e3 / n_calls
+
+    t_few, t_some, t_every = call_ms(few), call_ms(some), call_ms(every)
+    check(bool(jnp.all(jnp.isfinite(pool[lyr, :B]))),
+          "the states stay finite over the timed calls")
+    if on_chip:  # a time off the chip says nothing
+        row_mb = 2 * H * D * D * 4 / 1e6
+        print(f"  kda step {B} rows x {H} heads of {D} x {D}, a call: "
+              f"{few} rows decode {t_few:.3f} ms, {some} rows {t_some:.3f} "
+              f"ms ({some * row_mb / t_some:.0f} GB/s of state), {every} "
+              f"rows {t_every:.3f} ms ({every * row_mb / t_every:.0f} GB/s)",
+              flush=True)
+        check(t_some < 0.4 * t_every, f"{some} rows of {every} take "
+              f"{t_some / t_every:.3f} of all of them < 0.4")
+    del pool
 
 
 # ----------------------------------------------------------------- train

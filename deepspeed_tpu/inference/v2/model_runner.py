@@ -973,8 +973,8 @@ def paged_decode(cfg: TransformerConfig, params, pools,
         return _attn_out(cfg, layer, x, attn, pools)
 
     def kda_fn(layer, l, x, pools):
-        # row b's state is slot b; an inactive row's update goes to the
-        # trash slot, as its K/V goes to the trash page
+        # row b's state is slot b; the kernel visits the rows that decode, an
+        # inactive row's conv tail (and XLA-form state) go to the trash slot
         from ...ops.pallas.kda import kda_step, kda_step_xla
 
         trash_slot = pools["kda_s"].shape[1] - 1
@@ -984,7 +984,7 @@ def paged_decode(cfg: TransformerConfig, params, pools,
         def scan(q, k, v, g, beta):
             if use_kernel:
                 o, new["s"] = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                                       beta[:, 0], pools["kda_s"], l, dst)
+                                       beta[:, 0], pools["kda_s"], l, active)
             else:
                 o, st = kda_step_xla(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
                                      beta[:, 0], pools["kda_s"][l, :B])

@@ -26,10 +26,13 @@ forgets fast — and the unit-triangular inverse is the finite product
 ``(I + M)(I + M^2)(I + M^4)...`` of its nilpotent part.  A token with ``g =
 0`` and ``b = 0`` leaves the state as it was: that is how a caller pads.
 
-``dstpu_kda_step`` — one token for every decode row: the state pool goes in
-whole and comes back aliased, each row's state read from its slot and
-written to ``dst[row]`` (the trash slot for an inactive row), the layer a
-scalar-prefetch operand: no slot-pool-sized copy exists.
+``dstpu_kda_step`` — one token for the decode rows **that decode**: the state
+pool goes in whole and comes back aliased, and the grid walks a compacted
+list of the active rows and ends with it — each one's state read from its
+slot and written back to it, the layer a scalar-prefetch operand.  An
+inactive row's state is neither read nor written and no slot-pool-sized
+copy exists (``ops/pallas/ssm.py``'s step kernel takes the same ``active``
+mask and has the same contract).
 
 ``kda_chunk_xla`` / ``kda_step_xla`` are the same mathematics as XLA
 programs (a token-by-token ``lax.scan``): what the CPU test tier runs by
@@ -164,9 +167,16 @@ def kda_chunk(q, k, v, g, beta, st, sub: int = 16):
 
 
 # ------------------------------------------------------------- the step kernel
-def _step_kernel(layer_ref, dst_ref, q_ref, k_ref, v_ref, g_ref, b_ref,
+#: heads of one row a grid step takes: a block of the state is ``_HEADS * V *
+#: K * 4`` bytes (2 MiB at 128 x 128), in VMEM four times over — fetched and
+#: written back, both double-buffered; 64 heads would need the scoped VMEM
+#: limit raised for 2 % of a call's time (PERF.md §6, PR 39)
+_HEADS = 32
+
+
+def _step_kernel(layer_ref, rows_ref, q_ref, k_ref, v_ref, g_ref, b_ref,
                  s_ref, o_ref, so_ref, *, heads):
-    del layer_ref, dst_ref  # consumed by the index maps
+    del layer_ref, rows_ref  # consumed by the index maps
     f32 = jnp.float32
     V, K = s_ref.shape[-2:]
     eye = (jax.lax.broadcasted_iota(jnp.int32, (V, V), 0)
@@ -187,42 +197,67 @@ def _step_kernel(layer_ref, dst_ref, q_ref, k_ref, v_ref, g_ref, b_ref,
                                     keepdims=True).astype(o_ref.dtype)
 
 
-def kda_step(q, k, v, g, beta, pool, layer, dst, heads_per_block: int = 16):
-    """One token for every decode row, the state pool updated in place.
+@jax.jit
+def kda_step(q, k, v, g, beta, pool, layer, active):
+    """One token for every decode row that decodes, the state pool updated
+    in place.
 
     q, k: [B, H, K]; v: [B, H, V]; g: [B, H, K] float32; beta: [B, H]
     float32; pool: ``[L, N + 1, H, V, K]`` float32, row ``b``'s state in
-    slot ``b``; layer: int32 scalar; dst: [B] int32, the slot row ``b``'s
-    new state goes to (``b``, or the trash slot ``N`` for an inactive
-    row).  Returns (o [B, H, V] float32, pool)."""
+    slot ``b`` (``B <= N``); layer: int32 scalar; active: [B] bool (the
+    calling convention of ``ssm.ssm_step``).  Returns (o [B, H, V] float32,
+    zero for a row that is not active; pool): nothing of a row that is not
+    active is read, and its slot is not written.
+
+    The grid is ``(n, H // _HEADS)`` over the active rows sorted to the
+    front, ``n`` their number — a dynamic bound: one compiled program
+    whatever ``n`` is, and no grid step for any other row (with no row
+    active the call does nothing).  ``ssm_step``'s fixed grid, whose steps
+    past ``n`` all name one block of the trash slot, would here have to pin
+    the head block past ``n`` too, or those steps alternate between the trash
+    slot's blocks and move 2 MiB each; and a skipped step still costs
+    ≈ 0.37 µs, 0.1 ms a call at 40 of 128 rows (PERF.md §6, PR 39).  Under
+    its own ``jax.jit``: a program that calls it for several layers traces
+    and lowers it once."""
+    f32 = jnp.float32
     B, H, K = q.shape
     V = v.shape[-1]
     L, N1 = pool.shape[:2]
-    hb = min(heads_per_block, H)
-    assert H % hb == 0 and pool.shape[2:] == (H, V, K), (pool.shape, q.shape)
-    row = lambda d: pl.BlockSpec(  # noqa: E731
-        (1, hb, d), lambda b, j, lyr, dst: (b, j, 0))
+    hb = min(_HEADS, H)
+    assert H % hb == 0 and pool.shape[2:] == (H, V, K) and B <= N1, (
+        pool.shape, q.shape)
+    # the active rows first, in order; the grid ends with them
+    rows = jnp.sort(jnp.where(active, jnp.arange(B), B)).astype(jnp.int32)
+    n = jnp.sum(active).astype(jnp.int32)
+
+    def row(i, j, lyr, rows):
+        return (rows[i], j, 0)
+
+    def slot(i, j, lyr, rows):
+        return (lyr[0] * N1 + rows[i], j, 0, 0)
+
+    per_row = lambda d: pl.BlockSpec((1, hb, d), row)  # noqa: E731
+    state = pl.BlockSpec((1, hb, V, K), slot)
     o, pool = pl.pallas_call(
         functools.partial(_step_kernel, heads=hb),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(B, H // hb),
-            in_specs=[row(K), row(K), row(V), row(K), row(1),
-                      pl.BlockSpec((1, hb, V, K), lambda b, j, lyr, dst:
-                                   (lyr[0] * N1 + b, j, 0, 0))],
-            out_specs=[row(V),
-                       pl.BlockSpec((1, hb, V, K), lambda b, j, lyr, dst:
-                                    (lyr[0] * N1 + dst[b], j, 0, 0))],
+            grid=(n, H // hb),
+            in_specs=[per_row(K), per_row(K), per_row(V), per_row(K),
+                      per_row(1), state],
+            out_specs=[per_row(V), state],
         ),
-        out_shape=[jax.ShapeDtypeStruct((B, H, V), jnp.float32),
-                   jax.ShapeDtypeStruct((L * N1, H, V, K), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct((B, H, V), f32),
+                   jax.ShapeDtypeStruct((L * N1, H, V, K), f32)],
         # the pool (operand 7, after the two scalar operands) IS output 1
         input_output_aliases={7: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=pallas_interpret(),
         name="dstpu_kda_step",
-    )(jnp.asarray(layer, jnp.int32).reshape(1), dst.astype(jnp.int32),
-      q, k, v, g.astype(jnp.float32), beta.astype(jnp.float32)[..., None],
+    )(jnp.asarray(layer, jnp.int32).reshape(1), rows,
+      q, k, v, g.astype(f32), beta.astype(f32)[..., None],
       pool.reshape(L * N1, H, V, K))
-    return o, pool.reshape(L, N1, H, V, K)
+    # the rows of o that no grid step wrote hold whatever the buffer held
+    return (jnp.where(active[:, None, None], o, 0.0),
+            pool.reshape(L, N1, H, V, K))

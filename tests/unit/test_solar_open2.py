@@ -49,10 +49,23 @@ def _serve(eng, prompts, n_new):
     uids = [eng.put(RaggedRequest(prompt_ids=p, max_new_tokens=n_new))
             for p in prompts]
     got = {u: [] for u in uids}
-    while eng.has_work():
+    _step_while(eng, got, eng.has_work)
+    return [got[u] for u in uids]
+
+
+def _step_while(eng, got, more):
+    while more():
         for u, o in eng.step().items():
             got[u] += o["tokens"]
-    return [got[u] for u in uids]
+
+
+@pytest.fixture(params=["xla", "interpreted"])
+def kernels(request, monkeypatch):
+    """The serving programs in their XLA form and with the Pallas kernels
+    interpreted."""
+    if request.param == "interpreted":
+        monkeypatch.setenv("DSTPU_PAGED_KERNEL", "1")
+    return request.param
 
 
 def _regrets(eng, prompt, toks):
@@ -62,17 +75,13 @@ def _regrets(eng, prompt, toks):
 
 
 @pytest.mark.parametrize("horizon", [1, 4])
-@pytest.mark.parametrize("kernels", ["xla", "interpreted"])
-def test_engine_prefill_then_decode_matches_the_reference(kernels, horizon,
-                                                          monkeypatch):
+def test_engine_prefill_then_decode_matches_the_reference(kernels, horizon):
     """put / step against the float32 reference: a prompt over three chunks
     (state crosses two chunk boundaries), a shorter one and one of two
     blocks of the paged kernel's walk (256 tokens here) in the same batch,
     one decode slot left empty; nine, six and seven greedy tokens, so the
     rows retire at different steps (mid-scan under the fused horizon);
     every token the reference's own argmax."""
-    if kernels == "interpreted":
-        monkeypatch.setenv("DSTPU_PAGED_KERNEL", "1")
     eng = _engine(max_seq_len=320, max_pages_per_seq=40, num_pages=96,
                   decode_horizon=horizon)
     rng = np.random.default_rng(0)
@@ -81,9 +90,7 @@ def test_engine_prefill_then_decode_matches_the_reference(kernels, horizon,
     uids = [eng.put(RaggedRequest(prompt_ids=p, max_new_tokens=n))
             for p, n in zip(prompts, n_new)]
     got = {u: [] for u in uids}
-    while eng.has_work():
-        for u, o in eng.step().items():
-            got[u] += o["tokens"]
+    _step_while(eng, got, eng.has_work)
     for u, prompt, n in zip(uids, prompts, n_new):
         assert len(got[u]) == n
         assert max(_regrets(eng, prompt, got[u])) == 0.0
@@ -97,11 +104,13 @@ def test_engine_prefill_then_decode_matches_the_reference(kernels, horizon,
 
 
 @pytest.mark.parametrize("n_prompt", [13, 40])
-def test_read_state_is_the_reference_state_after_the_same_tokens(n_prompt):
+def test_read_state_is_the_reference_state_after_the_same_tokens(kernels,
+                                                                 n_prompt):
     """``read_state`` of an admitted sequence after m returned tokens is the
     reference's state after the prompt and the first m - 1 of them (one
     chunk, and three chunks and on into the decode program), transposed;
-    the benchmark's check holds the chip's programs to this."""
+    the benchmark's check holds the chip's programs to this.  Three of the
+    four decode rows are free: the step kernel walks one."""
     eng = _engine()
     prompt = np.random.default_rng(3).integers(0, 256, n_prompt).tolist()
     uid = eng.put(RaggedRequest(prompt_ids=prompt, max_new_tokens=8))
@@ -121,6 +130,97 @@ def test_read_state_is_the_reference_state_after_the_same_tokens(n_prompt):
     eng.assert_no_leaks()
     with pytest.raises(KeyError):
         eng.read_state(uid)
+
+
+@pytest.mark.parametrize("horizon", [1, 3])
+def test_states_of_the_rows_that_stay_when_one_finishes_mid_run(kernels,
+                                                                horizon):
+    """Three sequences in four decode rows (one free throughout), the
+    middle one done after two tokens — inside the scan at horizon 3 —: its
+    row then decodes no more while its neighbours go on, and after six
+    tokens each neighbour's state is the reference's."""
+    eng = _engine(decode_horizon=horizon)
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(0, 256, n).tolist() for n in (21, 9, 14)]
+    uids = [eng.put(RaggedRequest(prompt_ids=p, max_new_tokens=n))
+            for p, n in zip(prompts, (12, 2, 12))]
+    got = {u: [] for u in uids}
+    _step_while(eng, got,
+                lambda: min(len(got[uids[0]]), len(got[uids[2]])) < 6)
+    assert len(got[uids[1]]) == 2 and eng.state_slots.in_use == 2
+    for u, prompt in ((uids[0], prompts[0]), (uids[2], prompts[2])):
+        assert max(_regrets(eng, prompt, got[u])) == 0.0
+        _, ref = kda_moe_lm.forward(DESC, eng.params, prompt + got[u][:-1])
+        for have, want in zip(eng.read_state(u)["kda_s"], ref):
+            np.testing.assert_allclose(
+                have, np.asarray(want).transpose(0, 2, 1), rtol=0, atol=2e-5)
+    eng.abort_all()
+    eng.assert_no_leaks()
+
+
+def test_a_row_that_stops_inside_the_scan_keeps_its_last_active_state(
+        monkeypatch):
+    """``paged_multi_decode`` over three iterations, row 0 with a budget of
+    three tokens, row 1 of one, rows 2 and 3 free: the kernel form leaves
+    row 1's state as one ``paged_decode`` leaves it, row 0's as three do,
+    the free rows' slots bit for bit as they went in — and the XLA form
+    agrees on all of them to rounding."""
+    from deepspeed_tpu.inference.v2 import model_runner as mr
+
+    eng = _engine()
+    rng = np.random.default_rng(13)
+    uids = [eng.put(RaggedRequest(prompt_ids=rng.integers(0, 256, n).tolist(),
+                                  max_new_tokens=8)) for n in (17, 10)]
+    got = {u: [] for u in uids}
+    _step_while(eng, got, lambda: min(map(len, got.values())) < 2)
+    seqs = [q for q in eng._slots if q is not None]
+    assert sorted(q.slot for q in seqs) == [0, 1]
+    last, pos, act, temps, sids = map(jnp.asarray, eng._decode_inputs(seqs))
+    table = jnp.asarray(eng._page_table)
+    budgets = jnp.zeros(4, jnp.int32).at[seqs[0].slot].set(3).at[
+        seqs[1].slot].set(1)
+    eos = jnp.full(4, -1, jnp.int32)
+    pools = eng._pools
+
+    def multi(pools):
+        return mr.paged_multi_decode(eng.cfg, eng.params, pools, last, pos,
+                                     table, act, temps, eos, budgets, sids,
+                                     eng._sample_key, 3)
+
+    def singles(pools, steps):
+        l, p = last, pos
+        for _ in range(steps):
+            logits, pools = mr.paged_decode(eng.cfg, eng.params, pools, l, p,
+                                            table, act)
+            l = mr.sample_tokens(logits, temps, eng._sample_key, sids, p + 1)
+            p = p + 1
+        return pools
+
+    monkeypatch.setenv("DSTPU_PAGED_KERNEL", "1")
+    toks, produced, fused = jax.jit(multi)(pools)
+    one = jax.jit(lambda x: singles(x, 1))(pools)["kda_s"]
+    three = jax.jit(lambda x: singles(x, 3))(pools)["kda_s"]
+    monkeypatch.delenv("DSTPU_PAGED_KERNEL")
+    _, produced_x, fused_x = jax.jit(multi)(pools)
+    s0, s1 = seqs[0].slot, seqs[1].slot
+    assert produced.tolist() == produced_x.tolist()
+    assert [int(produced[s0]), int(produced[s1])] == [3, 1]
+    kept = np.asarray(fused["kda_s"])
+    # (two programs, so to the last bits and not bit for bit; one more
+    # update moves a state by a thousand times the tolerance)
+    np.testing.assert_allclose(kept[:, s1], np.asarray(one[:, s1]), rtol=0,
+                               atol=1e-6)
+    np.testing.assert_allclose(kept[:, s0], np.asarray(three[:, s0]), rtol=0,
+                               atol=1e-6)
+    assert np.abs(kept[:, s0] - np.asarray(one[:, s0])).max() > 1e-3
+    assert np.abs(kept[:, s1] - np.asarray(three[:, s1])).max() > 1e-3
+    np.testing.assert_array_equal(kept[:, 2:4],
+                                  np.asarray(pools["kda_s"][:, 2:4]))
+    np.testing.assert_allclose(kept[:, :4],
+                               np.asarray(fused_x["kda_s"][:, :4]),
+                               rtol=0, atol=2e-5)
+    eng.abort_all()
+    eng.assert_no_leaks()
 
 
 def test_a_model_without_state_reads_no_state():
@@ -206,24 +306,66 @@ def test_kda_chunk_kernel_matches_the_recurrence(beta_max, zero_state):
     np.testing.assert_allclose(np.asarray(s_x), s_ref, atol=2e-5)
 
 
-def test_kda_step_kernel_matches_the_recurrence_in_place():
-    """Interpreted ``dstpu_kda_step``: every row one token from its own
-    slot's non-zero state, beta up to 2; an inactive row's update lands in
-    the trash slot and its own slot, like every other layer, is untouched."""
-    B, H, K, V = 5, 4, 32, 32
+_MASKS = {"all": range(8), "none": (), "scattered": (1, 4, 5),
+          "last": (7,), "first": (0,)}
+
+
+def _step_case(mask):
+    """Eight rows of 64 heads (two head blocks of the kernel) on a two-layer
+    pool of nine slots, the rows of ``mask`` active."""
+    B, H, K, V = 8, 2 * kda._HEADS, 32, 32
     q, k, v, g, beta, _ = _kda_inputs(B, H, K, V, 1, 2.0)
     pool = jax.random.normal(jax.random.PRNGKey(9), (2, B + 1, H, V, K))
-    dst = jnp.array([0, 1, B, 3, 4])
-    o, new = kda.kda_step(q, k, v, g, beta, pool, 1, dst)
-    for b in range(B):
-        o_ref, s_ref = _recurrence(q[b:b + 1], k[b:b + 1], v[b:b + 1],
-                                   g[b:b + 1], beta[b:b + 1], pool[1, b])
-        np.testing.assert_allclose(np.asarray(o[b]), o_ref[0], atol=2e-5)
-        np.testing.assert_allclose(np.asarray(new[1, int(dst[b])]), s_ref,
-                                   atol=2e-5)
+    active = np.zeros(B, bool)
+    active[list(_MASKS[mask])] = True
+    return (q, k, v, g, beta, pool), active
+
+
+@pytest.mark.parametrize("mask", sorted(_MASKS))
+def test_kda_step_kernel_matches_the_recurrence_in_place(mask):
+    """Interpreted ``dstpu_kda_step``: every active row one token from its
+    own slot's non-zero state, beta up to 2, over more than one head block;
+    a row that is not active returns exactly zero, and its slot, like every
+    slot of the other layer, is bit for bit what went in."""
+    (q, k, v, g, beta, pool), active = _step_case(mask)
+    o, new = kda.kda_step(q, k, v, g, beta, pool, 1, jnp.asarray(active))
+    assert o.shape == v.shape and new.shape == pool.shape
+    for b in range(len(active)):
+        if active[b]:
+            o_ref, s_ref = _recurrence(q[b:b + 1], k[b:b + 1], v[b:b + 1],
+                                       g[b:b + 1], beta[b:b + 1], pool[1, b])
+            np.testing.assert_allclose(np.asarray(o[b]), o_ref[0], atol=2e-5)
+            np.testing.assert_allclose(np.asarray(new[1, b]), s_ref,
+                                       atol=2e-5)
+        else:
+            assert not np.asarray(o[b]).any()
+            np.testing.assert_array_equal(np.asarray(new[1, b]),
+                                          np.asarray(pool[1, b]))
     np.testing.assert_array_equal(np.asarray(new[0]), np.asarray(pool[0]))
-    np.testing.assert_array_equal(np.asarray(new[1, 2]),
-                                  np.asarray(pool[1, 2]))
+
+
+@pytest.mark.parametrize("mask", ["scattered", "last", "first"])
+def test_kda_step_kernel_reads_nothing_of_a_row_that_does_not_decode(mask):
+    """NaN in every inactive row's slot and in its q, k, v, g and beta: the
+    active rows' outputs and states are finite and bit for bit what they
+    are over clean inputs, the inactive rows return zero and keep their NaN."""
+    (q, k, v, g, beta, pool), active = _step_case(mask)
+    act = jnp.asarray(active)
+    o, new = kda.kda_step(q, k, v, g, beta, pool, 1, act)
+    dead = jnp.asarray(~active)
+    nan = lambda a: jnp.where(  # noqa: E731
+        dead.reshape((-1,) + (1,) * (a.ndim - 1)), jnp.nan, a)
+    bad = pool.at[1, :len(active)].set(nan(pool[1, :len(active)]))
+    o_n, new_n = kda.kda_step(nan(q), nan(k), nan(v), nan(g), nan(beta), bad,
+                              1, act)
+    assert np.isfinite(np.asarray(o_n)).all()
+    np.testing.assert_array_equal(np.asarray(o_n), np.asarray(o))
+    rows = np.flatnonzero(active)
+    assert np.isfinite(np.asarray(new_n[1, rows])).all()
+    np.testing.assert_array_equal(np.asarray(new_n[1, rows]),
+                                  np.asarray(new[1, rows]))
+    assert np.isnan(np.asarray(new_n[1, np.flatnonzero(~active)])).all()
+    np.testing.assert_array_equal(np.asarray(new_n[0]), np.asarray(pool[0]))
 
 
 def test_the_ranks_shares_add_up_to_the_uncut_layer():

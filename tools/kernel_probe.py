@@ -147,6 +147,30 @@ def cases():
                 rows_of(128) + [((2, 129, 16, 5120), f32)],
                 functools.partial(ssm_step, kernel=False), 1e-5))
 
+    # Solar-Open2's delta-rule step: 128 decode rows of a 129-slot pool, 64
+    # heads of 128 x 128 float32 a row, three in five rows active
+    from deepspeed_tpu.ops.pallas import kda
+
+    def kda_step(q, k, v, g, beta, pool, kernel=True):
+        unit = lambda x: x * jax.lax.rsqrt(  # noqa: E731
+            jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+        q, k, g, beta = unit(q), unit(k), -jnp.abs(g), jax.nn.sigmoid(beta)
+        B = q.shape[0]
+        act = jnp.arange(B) % 5 < 3
+        if kernel:
+            o, pool = kda.kda_step(q, k, v, g, beta, pool, jnp.int32(1), act)
+        else:
+            o, st = kda.kda_step_xla(q, k, v, g, beta, pool[1, :B])
+            o, pool = o * act[:, None, None], pool.at[1, :B].set(st)
+        return o, pool[1, :B] * act[:, None, None, None]
+
+    out.append(("kda_step 128 rows of 129 slots x 2 layers, 64 heads of "
+                "128 x 128", kda_step,
+                [((128, 64, 128), f32), ((128, 64, 128), f32),
+                 ((128, 64, 128), f32), ((128, 64, 128), f32),
+                 ((128, 64), f32), ((2, 129, 64, 128, 128), f32)],
+                functools.partial(kda_step, kernel=False), 1e-5))
+
     # Mixtral-8x7B expert matrices: 4096 x 14336, 8 experts, 4096 rows
     def gmm(x, w, be):
         return grouped_matmul(x, w, be % w.shape[0], impl="pallas")
