@@ -26,7 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...models.layer_types import layers_of, page_layers, state_leaves
+from ...models.layer_types import (layers_of, page_layers, page_leaves,
+                                   state_leaves)
 from ...models.transformer import TransformerConfig
 from ...moe.sharded_moe import MOE_COUNTERS
 from ...ops.pallas.paged_attention import n_blocks, pages_per_block
@@ -274,6 +275,12 @@ class InferenceEngineV2:
         self._state = state_leaves(self.cfg)
         if self._state:
             self._refuse_with_state(proposer)
+        #: the page format the layer types declare: K and V of ``kv_heads *
+        #: head_dim``, or one latent row a token (``mla``)
+        self._pages = page_leaves(self.cfg)
+        self._latent = "latent" in self._pages
+        if self._latent:
+            self._refuse_with_latent(proposer)
         block = self.config.block
         if block.num_pages < block.max_pages_per_seq:
             raise ValueError(
@@ -303,12 +310,13 @@ class InferenceEngineV2:
             self.cfg.head_dim, block, self.config.jnp_dtype,
             kv_quant=self.config.kv_quant, state=self._state,
             counters=({"moe_stats": len(MOE_COUNTERS)}
-                      if self.cfg.moe_held_count else None))
+                      if self.cfg.moe_held_count else None),
+            pages=self._pages)
         #: pages a block of the paged decode kernel holds, from the pool's
         #: own geometry as the kernel takes it (``decode_kv_blocks``)
+        page_leaf = self._pools["latent" if self._latent else "k"]
         self._kv_block_pages = pages_per_block(
-            block.page_size, self._pools["k"].shape[-1],
-            self._pools["k"].dtype.itemsize)
+            block.page_size, page_leaf.shape[-1], page_leaf.dtype.itemsize)
         self.state_slots = StateSlots(block.max_seqs if self._state else 0)
         #: the expert share's counters as the device last reported them
         #: (``moe_stats`` wraps at 2**32; the host adds differences)
@@ -538,6 +546,40 @@ class InferenceEngineV2:
                 f"sliding_window {self.cfg.sliding_window} is not a whole "
                 f"number of pages of {self.config.block.page_size}: the "
                 "decode kernel reads a window's ring as pages")
+
+    def _refuse_with_latent(self, proposer: Any) -> None:
+        """What a model that caches a latent cannot be served with, by name.
+        The prefix cache, copy-on-write and bundle export work over latent
+        pages as over any page (a cached page holds its positions' latents
+        and rotated keys, valid for every request that shares the prefix)."""
+        if self.config.prefill_chunk <= 0:
+            raise ValueError(
+                "prefill_chunk 0: a latent-attention model is prefilled "
+                "through the chunk program, which expands keys and values "
+                "from the window's latents; whole-prompt prefill has no form "
+                "of the 'mla' mixer; set prefill_chunk > 0")
+        if proposer is not None or self.config.speculative.mode != "off":
+            raise ValueError(
+                "speculative decoding: paged_verify has no form of the "
+                "'mla' mixer (a window of queries against latent pages)")
+        if self.config.kv_quant:
+            raise ValueError(
+                "kv_quant: int8 codes and per-head scales exist for K and V "
+                "pools; a latent pool has no heads to scale, serve it with "
+                "kv_quant off")
+        tier = self.config.kv_tier
+        if tier is not None and (tier.get("enabled") if isinstance(tier, dict)
+                                 else tier.enabled):
+            raise ValueError(
+                "kv_tier: the host tier's page format is K and V; a latent "
+                "pool is not spilled")
+
+    def _model_sig(self) -> Tuple[int, int, int]:
+        """(layers, heads, width) of a page as a bundle carries it."""
+        if self._latent:
+            layers, width = self._pages["latent"]
+            return (layers, 1, width)
+        return (self.cfg.n_layers, self.cfg.kv_heads, self.cfg.head_dim)
 
     def _wire_memory_ledger(self) -> None:
         """Attach the serving engine's HBM residents to the process
@@ -1041,6 +1083,22 @@ class InferenceEngineV2:
         return {name: np.asarray(self._pools[name][:, slot])
                 for name in self._state}
 
+    def read_latent(self, uid: int) -> np.ndarray:
+        """The latent rows an admitted sequence has cached now, ``[layers,
+        positions, kv_lora_rank + qk_rope_head_dim]`` (each ``[c | k_rope]``,
+        the lane padding cut off): a host copy of its pages in position
+        order, for a check against a reference.  After ``m`` returned tokens
+        the cache holds the prompt and the first ``m - 1`` of them."""
+        seq = self._find_slotted(uid)
+        n, ps = seq.length - 1, self.block.page_size
+        # the gather runs op-by-op outside the step programs, as an export's
+        sentinel_expect_recompile("read_latent")
+        rows = paged_gather_pages(self._pools, seq.pages[:-(-n // ps)],
+                                  self.cfg.kv_heads)["latent"]
+
+        width = self.cfg.kv_lora_rank + self.cfg.qk_rope_head_dim
+        return rows.reshape(rows.shape[0], -1, rows.shape[-1])[:, :n, :width]
+
     def export_sequence(self, uid: int) -> KVPageBundle:
         """Serialize an admitted sequence's KV pages + scheduling state
         into a :class:`KVPageBundle` (host arrays, bit-exact).  The
@@ -1063,8 +1121,7 @@ class InferenceEngineV2:
             src_pages=self.allocator.export_meta(seq.pages),
             arrays=paged_gather_pages(self._pools, seq.pages,
                                       self.cfg.kv_heads),
-            model_sig=(self.cfg.n_layers, self.cfg.kv_heads,
-                       self.cfg.head_dim),
+            model_sig=self._model_sig(),
             kv_quant=bool(self.config.kv_quant), dtype=self.config.dtype)
         tr = self._reqtrace(seq)
         if tr is not None:
@@ -1093,7 +1150,7 @@ class InferenceEngineV2:
             raise ValueError(
                 "KVPageBundle import: a bundle holds pages, and this model "
                 f"keeps recurrent state too ({sorted(self._state)})")
-        sig = (self.cfg.n_layers, self.cfg.kv_heads, self.cfg.head_dim)
+        sig = self._model_sig()
         if tuple(b.model_sig) != sig:
             raise ValueError(f"bundle model_sig {tuple(b.model_sig)} != "
                              f"engine {sig}")
@@ -1883,6 +1940,10 @@ class InferenceEngineV2:
                 counts["queue_len"] = len(self._queue)
                 if self._state:
                     counts["state_slots_in_use"] = self.state_slots.in_use
+                if self._latent:
+                    counts["latent_tokens_in_use"] = self.block.page_size \
+                        * sum(len(s.pages) for s in self._slots
+                              if s is not None)
                 step_attrs.update(counts)
         except Exception as e:
             dump_on_exception("engine_v2.step", e)
@@ -1942,6 +2003,8 @@ class InferenceEngineV2:
                 counts["chunks"] += 1
                 counts["prefill_tokens"] += c_n
                 attrs = {}
+                if self._latent:  # the cached positions the chunk attends
+                    attrs["ctx_tokens"] = start
                 if self._xdec:  # the cross-decoder runs for the last token
                     attrs["xdec_rows"] = int(start + c_n >= seq.length)
                     counts["xdec_rows"] = (counts.get("xdec_rows", 0)
@@ -2176,8 +2239,12 @@ class InferenceEngineV2:
         rows whose state the step kernel moves, the cached positions the
         window decode reads (a ring holds ``sliding_window`` at most), the
         visible pages of the one pool layer (once, however many layers read
-        them) and the rows the cross-decoder runs."""
+        them), the rows the cross-decoder runs, and the cached positions a
+        latent-attention layer's decode kernel reads."""
         counts, rows = self._step_counts, int((lengths > 0).sum())
+        if self._latent:  # once, not a layer: the kernel's bytes are x layers
+            counts["latent_kv_tokens"] = counts.get("latent_kv_tokens", 0) \
+                + int(lengths.sum())
         if "ssm_s" in self._state:
             counts["ssm_rows"] = counts.get("ssm_rows", 0) + rows
         if self._window:

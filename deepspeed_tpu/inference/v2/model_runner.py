@@ -45,6 +45,16 @@ cross-attention layers read them.  Two values cross layers in the carry
 beside the pools: the last state-space layer's scan output (the gated memory
 units' memory) and, in the chunk program, the cut to the prompt's last token,
 after which the cross-decoder runs for one row.
+
+A stack of latent-attention layers (``mla``: Mistral-Small-4) keeps ONE page
+leaf, ``latent`` ``[L, P+1, ps, W]``: a token's normalised latent and, beside
+it, the rotary key all heads share, already rotated (``W``: whole lane tiles,
+``layer_types.latent_width``) — no K pool and no V pool.  The chunk program
+writes the chunk's rows, gathers the window's and *expands* them (``[k_nope |
+v] = c W_ukv`` per head) into keys and values for the flash kernel; the decode
+program *absorbs* the up-projections into the query and the output and attends
+the latent rows themselves (``ops/pallas/mla_attention``), each visible page
+fetched once.  Nothing expanded outlives a call.
 """
 
 from __future__ import annotations
@@ -56,10 +66,12 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 
-from ...models.layer_types import layers_of, page_layers, served_runs
+from ...models.layer_types import (latent_width, layers_of, page_layers,
+                                   served_runs)
 from ...models.transformer import (MODEL_AXIS, TransformerConfig, _mm,
                                    _norm, _repeat_kv, alibi_slopes,
-                                   attn_qkv, logits_fn, mlp_block)
+                                   attn_qkv, logits_fn, mlp_block,
+                                   rope_interleaved, yarn_inv_freq)
 
 
 def _use_paged_kernel() -> bool:
@@ -78,6 +90,18 @@ def _use_paged_kernel() -> bool:
                 "the chip serves through the paged kernel only")
         return True
     return forced == "1"
+
+
+#: the pool leaves laid out in pages ``[L, P+1, ps, ...]`` (beside them ride
+#: state slots and counters, which no page gather or copy touches)
+PAGE_LEAVES = ("k", "v", "k_scale", "v_scale", "latent")
+
+
+def _page_geometry(pools) -> Tuple[int, int]:
+    """(page_size, the trash page's index) of the carried pools, from
+    whichever page leaf the model's layer types declared."""
+    leaf = pools["k"] if "k" in pools else pools["latent"]
+    return leaf.shape[2], leaf.shape[1] - 1
 
 
 def _kv_quantize(x):
@@ -115,6 +139,36 @@ def _period_body(types, per, before, first, layer_fns):
         return (x, pools, cross), None
 
     return period_body
+
+
+_EXPERT_MATRICES = ("w_gate", "w_up", "w_down")
+
+
+def _experts_left_stacked(period_body, trees):
+    """An expert share scanned over several periods: take the expert matrices
+    ``[n, E, ...]`` out of what the scan slices a layer at a time and leave
+    them to the body whole, as ``[n * E, ...]`` beside ``expert_first = p *
+    E`` (``moe.sharded_moe._expert_ffn_blocks``).  A slice of the stack as
+    the operand of a Mosaic call is a copy XLA makes first: 3 x 268 MB a layer
+    call at Mistral-Small-4's share, 19.6 ms of a 52.9 ms decode program
+    (PERF.md, PR 40).  -> (the body, the trees the scan slices)."""
+    held = tuple({k: t["mlp"][k] for k in _EXPERT_MATRICES
+                  if getattr(t.get("mlp", {}).get(k), "ndim", 0) == 4}
+                 for t in trees)  # plain arrays only: not quantized leaves
+    sliced = tuple(dict(t, mlp={k: v for k, v in t["mlp"].items()
+                                if k not in h}) if h else t
+                   for t, h in zip(trees, held))
+
+    def body(carry, inputs):
+        layers, p = inputs
+        layers = tuple(
+            dict(layer, mlp=dict(
+                layer["mlp"], expert_first=p * h["w_up"].shape[1],
+                **{k: w.reshape(-1, *w.shape[2:]) for k, w in h.items()}))
+            if h else layer for layer, h in zip(layers, held))
+        return period_body(carry, (layers, p))
+
+    return body, sliced
 
 
 def _scan_layers(cfg: TransformerConfig, params, pools, x, layer_fns,
@@ -155,6 +209,8 @@ def _scan_layers(cfg: TransformerConfig, params, pools, x, layer_fns,
                 (x, pools, cross),
                 (jax.tree_util.tree_map(lambda a: a[0], trees), 0))
         else:
+            if cfg.moe_held_count and n > 1:
+                period_body, trees = _experts_left_stacked(period_body, trees)
             (x, pools, cross), _ = jax.lax.scan(
                 period_body, (x, pools, cross), (trees, jnp.arange(n)))
         for m, k in per.items():
@@ -412,6 +468,105 @@ def _xattn_fn(cfg: TransformerConfig, attend):
     return xattn_fn
 
 
+# --------------------------------------------- latent attention (Mistral-4)
+def _mla_project(cfg: TransformerConfig, layer, x, positions):
+    """What a latent-attention layer makes of ``x [B, T, H]`` at ``positions
+    [B, T]``: the queries' parts without and with rotary ``[B, T, NH, dn]`` /
+    ``[B, T, NH, dr]`` (rotated), both already multiplied by the softmax
+    scale ``a_t * (dn + dr) ** -0.5 * m ** 2`` in float32, and the row each
+    token leaves in the pool ``[B, T, W]``: ``[RMSNorm(c_kv) | R_t(k_r) |
+    zeros]``."""
+    f32 = jnp.float32
+    a = layer["attn"]
+    B, T, _ = x.shape
+    NH, R = cfg.n_heads, cfg.kv_lora_rank
+    dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    h = _ln1(cfg, layer, x)
+    cq = _norm(h @ a["w_dq"], a["q_norm"], None, "rmsnorm", cfg.norm_eps)
+    q = (cq @ a["w_uq"]).reshape(B, T, NH, dn + dr)
+    ckv = h @ a["w_dkv"]
+    c = _norm(ckv[..., :R], a["kv_norm"], None, "rmsnorm", cfg.norm_eps)
+    inv_freq = yarn_inv_freq(dr, cfg.rope_theta, cfg.rope_factor,
+                             cfg.rope_original_max, cfg.rope_beta_fast,
+                             cfg.rope_beta_slow)
+    k_rope = rope_interleaved(ckv[..., None, R:], inv_freq, positions)[:, :, 0]
+    q_rope = rope_interleaved(q[..., dn:], inv_freq, positions)
+    m = 1.0
+    if cfg.rope_mscale_all_dim and cfg.rope_factor > 1.0:
+        m = 0.1 * cfg.rope_mscale_all_dim * math.log(cfg.rope_factor) + 1.0
+    scale = jnp.full(positions.shape, m * m / math.sqrt(dn + dr), f32)
+    if cfg.attn_scale_beta:
+        scale = scale * (1.0 + cfg.attn_scale_beta * jnp.log1p(
+            (positions // cfg.rope_original_max).astype(f32)))
+    scale = scale[..., None, None]
+    row = jnp.concatenate([c, k_rope], axis=-1)
+    row = jnp.pad(row, ((0, 0), (0, 0),
+                        (0, latent_width(cfg) - row.shape[-1])))
+    return ((q[..., :dn].astype(f32) * scale).astype(x.dtype),
+            (q_rope.astype(f32) * scale).astype(x.dtype), row)
+
+
+def _mla_expanded(cfg: TransformerConfig, layer, q_nope, q_rope, rows, q_pos,
+                  use_flash: bool, q_offset=None):
+    """The expanded form: keys and values of every head made from cached rows
+    ``rows [S, W]`` (slot index == position), ``[k_nope | v] = c W_ukv``, the
+    shared rotary key beside each head's ``k_nope``; queries ``[1, T, NH,
+    .]`` at positions ``q_pos [T]`` see the slots at or before them.  Returns
+    ``[1, T, NH * dv]``.  The flash kernel takes one width for keys and
+    values: where they differ the narrower is padded with zeros."""
+    NH, R = cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    S, T = rows.shape[0], q_nope.shape[1]
+    kv = (rows[:, :R] @ layer["attn"]["w_ukv"]).reshape(S, NH, dn + dv)
+    k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
+        rows[:, None, R:R + dr], (S, NH, dr))], axis=-1)[None]
+    v = kv[..., dn:][None]
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    if use_flash:
+        from ...ops.pallas.flash_attention import flash_attention
+
+        wide = max(dn + dr, dv)
+        widen = lambda a: jnp.pad(  # noqa: E731
+            a, ((0, 0),) * 3 + ((0, wide - a.shape[-1]),))
+        o = flash_attention(widen(q), widen(k), widen(v), causal=True,
+                            q_offset=q_offset, sm_scale=1.0)[..., :dv]
+    else:
+        s = jnp.einsum("btnd,bsnd->bnts", q, k).astype(jnp.float32)
+        vis = jnp.arange(S)[None, :] <= q_pos[:, None]
+        p = jax.nn.softmax(jnp.where(vis[None, None], s, -1e30), axis=-1)
+        o = jnp.einsum("bnts,bsnd->btnd", p.astype(v.dtype), v)
+    return o.reshape(1, T, NH * dv)
+
+
+def _mla_absorbed(cfg: TransformerConfig, layer, q_nope, q_rope, pools, l,
+                  page_table, positions, active, use_kernel: bool):
+    """The absorbed form, one query a row (``q_nope [B, NH, dn]``, ``q_rope
+    [B, NH, dr]``): ``q~ = q_nope W_uk^T`` scores against the latent rows
+    themselves, the output is ``(sum p c) W_uv``.  Returns ``[B, 1, NH *
+    dv]``."""
+    NH, R = cfg.n_heads, cfg.kv_lora_rank
+    dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    B = q_nope.shape[0]
+    w = layer["attn"]["w_ukv"].reshape(R, NH, -1)
+    q = jnp.concatenate([jnp.einsum("bnd,rnd->bnr", q_nope, w[..., :dn]),
+                         q_rope], axis=-1)
+    if use_kernel:
+        from ...ops.pallas.mla_attention import mla_decode_attention
+
+        u = mla_decode_attention(q, pools["latent"], page_table, positions,
+                                 l, active, rank=R)
+    else:
+        win = pools["latent"][l, page_table]
+        win = win.reshape(B, -1, win.shape[-1])
+        s = jnp.einsum("bnf,bsf->bns", q, win[..., :R + dr]
+                       ).astype(jnp.float32)
+        vis = (jnp.arange(win.shape[1])[None] <= positions[:, None]) \
+            & active[:, None]
+        p = jax.nn.softmax(jnp.where(vis[:, None], s, -1e30), axis=-1)
+        u = jnp.einsum("bns,bsr->bnr", p.astype(q.dtype), win[..., :R])
+    return jnp.einsum("bnr,rnv->bnv", u, w[..., dn:]).reshape(B, 1, -1)
+
+
 class _Forms(dict):
     """A program's ``layer_fns``: a mixer it has no form of is refused by
     name when a stack asks for it."""
@@ -423,8 +578,8 @@ class _Forms(dict):
     def __missing__(self, mixer):
         raise NotImplementedError(
             f"{self.program} has no form of the {mixer!r} mixer: a model "
-            "with recurrent state or a window cache is served through "
-            "chunked prefill and the decode program only")
+            "with recurrent state, a window cache or a latent cache is "
+            "served through chunked prefill and the decode program only")
 
 
 def paged_prefill(cfg: TransformerConfig, params, pools,
@@ -493,14 +648,15 @@ def paged_prefill(cfg: TransformerConfig, params, pools,
 
 def paged_copy_page(pools, src, dst):
     """Copy-on-write for the prefix cache: duplicate page ``src`` into
-    ``dst`` across every pool leaf (K/V codes and, under kv_quant, their
-    scales — all laid out ``[L, P+1, ...]``).  A sequence whose whole
+    ``dst`` across every page leaf (K/V codes and, under kv_quant, their
+    scales, or a latent leaf — all laid out ``[L, P+1, ...]``; state slots
+    and counters ride along untouched).  A sequence whose whole
     prompt is cached must write the KV of its final prompt token through
     the decode program; that write lands in its private copy so the
     shared cached page is never mutated.  The engine jits this with the
     pools donated — one compiled program regardless of src/dst."""
-    return jax.tree_util.tree_map(
-        lambda a: a.at[:, dst].set(a[:, src]), pools)
+    return {name: (a.at[:, dst].set(a[:, src]) if name in PAGE_LEAVES else a)
+            for name, a in pools.items()}
 
 
 def pad_pages_pow2(pages, trash_page):
@@ -527,9 +683,11 @@ def paged_gather_pages(pools, pages, kv_heads):
     import numpy as np
 
     rows = jnp.asarray(np.asarray(pages, np.int32))
-    out = {name: np.asarray(leaf[:, rows]) for name, leaf in pools.items()}
+    out = {name: np.asarray(leaf[:, rows]) for name, leaf in pools.items()
+           if name in PAGE_LEAVES}
     for name in ("k", "v"):
-        out[name] = out[name].reshape(*out[name].shape[:3], kv_heads, -1)
+        if name in out:  # a latent pool's rows have no heads to split
+            out[name] = out[name].reshape(*out[name].shape[:3], kv_heads, -1)
     return out
 
 
@@ -542,13 +700,14 @@ def paged_scatter_pages(pools, pages, arrays):
     path); returns the updated pools dict."""
     import numpy as np
 
-    if set(arrays) != set(pools):
-        raise ValueError(f"pool leaves {sorted(pools)} != bundle leaves "
+    paged = set(pools).intersection(PAGE_LEAVES)
+    if set(arrays) != paged:
+        raise ValueError(f"pool leaves {sorted(paged)} != bundle leaves "
                          f"{sorted(arrays)} (kv_quant mismatch?)")
     rows = jnp.asarray(np.asarray(pages, np.int32))
-    out = {}
-    for name, leaf in pools.items():
-        src = arrays[name]
+    out = dict(pools)
+    for name in paged:
+        leaf, src = pools[name], arrays[name]
         if jnp.dtype(leaf.dtype) != jnp.dtype(src.dtype):
             raise ValueError(f"pool leaf {name!r} dtype {leaf.dtype} != "
                              f"bundle dtype {src.dtype}: import must be "
@@ -600,7 +759,7 @@ def paged_prefill_chunk(cfg: TransformerConfig, params, pools,
     for logits.  ``prev_table`` is then the sequence's whole table row."""
     quant = "k_scale" in pools
     C = ids.shape[0]
-    ps = pools["k"].shape[2]
+    ps, _ = _page_geometry(pools)
     S_prev = prev_table.shape[0] * ps
     x = params["embed"]["tok"][ids][None]  # [1, C, H]
     positions = start + jnp.arange(C)[None]
@@ -666,6 +825,18 @@ def paged_prefill_chunk(cfg: TransformerConfig, params, pools,
         scores = jnp.where(mask[None, None], scores, -1e30)
         probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
         attn = jnp.einsum("bnts,bsnd->btnd", probs, vv).reshape(1, C, -1)
+        return _attn_out(cfg, layer, x, attn, pools)
+
+    def mla_fn(layer, l, x, pools):
+        # the chunk's rows go to the pool first; the window's rows, the
+        # chunk's among them at their positions, are then expanded
+        q_nope, q_rope, row = _mla_project(cfg, layer, x, positions)
+        latent = pools["latent"].at[l, chunk_rows].set(
+            row[0].reshape(C // ps, ps, -1).astype(pools["latent"].dtype))
+        pools = dict(pools, latent=latent)
+        rows = latent[l, prev_table].reshape(S_prev, -1).astype(x.dtype)
+        attn = _mla_expanded(cfg, layer, q_nope, q_rope, rows, positions[0],
+                             use_kernel, q_offset=start)
         return _attn_out(cfg, layer, x, attn, pools)
 
     def kda_fn(layer, l, x, pools):
@@ -795,8 +966,8 @@ def paged_prefill_chunk(cfg: TransformerConfig, params, pools,
     like = pools
     x, pools = _scan_layers(
         cfg, params, _ring_pages(pools, ps), x,
-        _Forms("chunked prefill", attn=layer_fn, kda=kda_fn, mamba=mamba_fn,
-               swa=swa_fn, dattn=dattn_fn, gmu=_gmu_fn(cfg),
+        _Forms("chunked prefill", attn=layer_fn, mla=mla_fn, kda=kda_fn,
+               mamba=mamba_fn, swa=swa_fn, dattn=dattn_fn, gmu=_gmu_fn(cfg),
                xattn=_xattn_fn(cfg, attend_last)),
         cross=({"mem": jnp.zeros((1, 1, cfg.ssm_inner), jnp.float32)}
                if cfg.ssm_inner else None),
@@ -923,8 +1094,7 @@ def paged_decode(cfg: TransformerConfig, params, pools,
     single-step program it must be bit-identical to.
     """
     B = last_tokens.shape[0]
-    ps = pools["k"].shape[2]
-    trash = pools["k"].shape[1] - 1
+    ps, trash = _page_geometry(pools)
     x = params["embed"]["tok"][last_tokens][:, None]  # [B, 1, H]
     if cfg.position == "learned":
         x = x + params["embed"]["pos"][positions][:, None]
@@ -970,6 +1140,14 @@ def paged_decode(cfg: TransformerConfig, params, pools,
             attn = _gather_window_attend(cfg, q, pools, l, page_table,
                                          positions[:, None],
                                          vis[:, None, :])
+        return _attn_out(cfg, layer, x, attn, pools)
+
+    def mla_fn(layer, l, x, pools):
+        q_nope, q_rope, row = _mla_project(cfg, layer, x, positions[:, None])
+        pools = dict(pools, latent=pools["latent"].at[l, page_idx, off].set(
+            row[:, 0].astype(pools["latent"].dtype)))
+        attn = _mla_absorbed(cfg, layer, q_nope[:, 0], q_rope[:, 0], pools, l,
+                             page_table, positions, active, use_kernel)
         return _attn_out(cfg, layer, x, attn, pools)
 
     def kda_fn(layer, l, x, pools):
@@ -1052,8 +1230,8 @@ def paged_decode(cfg: TransformerConfig, params, pools,
     like = pools
     x, pools = _scan_layers(
         cfg, params, _ring_pages(pools, ps), x,
-        _Forms("decode", attn=layer_fn, kda=kda_fn, mamba=mamba_fn,
-               swa=swa_fn, dattn=dattn_fn, gmu=_gmu_fn(cfg),
+        _Forms("decode", attn=layer_fn, mla=mla_fn, kda=kda_fn,
+               mamba=mamba_fn, swa=swa_fn, dattn=dattn_fn, gmu=_gmu_fn(cfg),
                xattn=_xattn_fn(cfg, attend_pages)),
         cross=({"mem": jnp.zeros((B, 1, cfg.ssm_inner), jnp.float32)}
                if cfg.ssm_inner else None))

@@ -574,31 +574,49 @@ class PagedKVCache:
     and the scales ``[L, P+1, ps, KVH]``.  ``KVPageBundle.arrays`` keeps
     ``[L, n, ps, KVH, D]``: ``model_runner.paged_gather_pages`` /
     ``paged_scatter_pages`` split and merge the last axis on page-sized
-    data."""
+    data.
+
+    The page format belongs to the layer types (``layer_types.page_leaves``):
+    a model whose layers cache a latent (``mla``) has ONE leaf, ``latent``
+    ``[L, P+1, ps, kv_lora_rank + qk_rope_head_dim]`` — the normalised latent
+    and the rotated rotary key of a token side by side — and no K or V pool.
+    At 256 + 64 bf16 values that row is 640 B; the device tiles the minor
+    dimension by 128 lanes, so it occupies 384 lanes = 768 B as laid out
+    (``tools/aot_serve_step.py`` prints it): one copy a page for the decode
+    kernel, which reads the values as the first 256 lanes of the keys."""
 
     @staticmethod
     def init(n_layers: int, kv_heads: int, head_dim: int,
              block: KVBlockConfig, dtype=jnp.bfloat16,
              kv_quant: bool = False,
              state: Optional[Dict[str, Tuple[int, tuple, Any]]] = None,
-             counters: Optional[Dict[str, int]] = None) -> Dict[str, Any]:
-        """``n_layers``: the layers that keep pages.  ``state``: ``{leaf:
+             counters: Optional[Dict[str, int]] = None,
+             pages: Optional[Dict[str, Tuple[int, int]]] = None
+             ) -> Dict[str, Any]:
+        """``n_layers``: the layers that keep pages.  ``pages``: ``{leaf:
+        (layers, values a token)}`` where the format is not K and V of
+        ``kv_heads * head_dim`` over ``n_layers``.  ``state``: ``{leaf:
         (layers, per-sequence shape, dtype or None for ``dtype``)}`` — each
         becomes ``[layers, max_seqs + 1, *shape]``, slot = decode row, the
         last slot the trash slot.  ``counters``: ``{leaf: n}``, int32 ``[n]``
         leaves the programs add to (read by the host, never reset on the
         device)."""
-        shape = (n_layers, block.num_pages + 1, block.page_size,
-                 kv_heads * head_dim)
+        if pages is None:
+            pages = dict.fromkeys(("k", "v"), (n_layers, kv_heads * head_dim))
+        rows = (block.num_pages + 1, block.page_size)
         if kv_quant:
-            sshape = shape[:-1] + (kv_heads,)
-            pools = {"k": jnp.zeros(shape, jnp.int8),
-                     "v": jnp.zeros(shape, jnp.int8),
-                     "k_scale": jnp.zeros(sshape, jnp.float32),
-                     "v_scale": jnp.zeros(sshape, jnp.float32)}
+            if set(pages) != {"k", "v"}:
+                raise ValueError(
+                    "kv_quant: int8 codes and scales exist for K and V "
+                    f"pools, not for the page leaves {sorted(pages)}")
+            pools = {}
+            for name, (layers, width) in pages.items():
+                pools[name] = jnp.zeros((layers, *rows, width), jnp.int8)
+                pools[name + "_scale"] = jnp.zeros((layers, *rows, kv_heads),
+                                                   jnp.float32)
         else:
-            pools = {"k": jnp.zeros(shape, dtype),
-                     "v": jnp.zeros(shape, dtype)}
+            pools = {name: jnp.zeros((layers, *rows, width), dtype)
+                     for name, (layers, width) in pages.items()}
         for name, (layers, sshape, sdtype) in (state or {}).items():
             pools[name] = jnp.zeros((layers, block.max_seqs + 1, *sshape),
                                     sdtype or dtype)

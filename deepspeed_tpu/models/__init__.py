@@ -7,6 +7,7 @@ from .families import (bloom_config, bloom_model, falcon_config,
 from .gpt2 import gpt2_config, gpt2_model
 from .lfm2_moe import lfm2_moe_config, lfm2_moe_model
 from .llama import llama_config, llama_model
+from .mistral4 import mistral4_config, mistral4_model
 from .mixtral import mixtral_config, mixtral_model
 from .phi4_flash import phi4_flash_config, phi4_flash_model
 from .solar_open2 import solar_open2_config, solar_open2_model
@@ -19,5 +20,6 @@ __all__ = ["bert_config", "bert_model", "gpt2_config", "gpt2_model",
            "falcon_config", "falcon_model", "bloom_config", "bloom_model",
            "gpt_neox_config", "gpt_neox_model", "solar_open2_config",
            "solar_open2_model", "lfm2_moe_config", "lfm2_moe_model",
-           "phi4_flash_config", "phi4_flash_model",
+           "phi4_flash_config", "phi4_flash_model", "mistral4_config",
+           "mistral4_model",
            "TransformerConfig"]

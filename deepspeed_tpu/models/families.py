@@ -21,6 +21,14 @@ Family-specific structure carried by the config:
   bloom   — ALiBi attention bias, word_embeddings_layernorm, tied head
   gpt-neox— partial rotary, parallel residual with separate norms,
             untied embed_out
+
+Families with layer types of their own live beside this file, each served
+through ``inference/v2`` and refused for training by name: ``solar_open2.py``
+(GQA + delta-rule layers over an expert share), ``phi4_flash.py`` (Mamba,
+window and cross-attention layers, gated memory units), ``mistral4.py``
+(latent attention over a paged latent leaf, a sigmoid-routed expert share);
+``lfm2_moe.py`` (short-convolution + GQA layers over an expert share) is
+trained and not served.
 """
 
 from __future__ import annotations

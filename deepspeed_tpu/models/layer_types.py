@@ -4,10 +4,13 @@ A type says what a layer of its kind holds (``init``), what its mixer computes
 over whole sequences (``mix``: the form the training forward runs, and
 differentiates), which mixer the serving programs run for it (``mixer``: the
 key under which ``inference/v2/model_runner`` keeps that mixer's chunk and
-decode forms), and what it keeps per sequence between calls: K/V pages in the
-paged pool (``kv_pages``) and/or fixed-size state in per-sequence slots
-(``state``).  The cache manager sizes its pools from these, so a model with
-fewer attention layers than layers gets a pool with fewer layers.
+decode forms), and what it keeps per sequence between calls: pages in the
+paged pool (``pages``: the pool leaves it keeps and each leaf's width a token
+— keys and values of ``kv_heads * head_dim`` for an attention layer, one
+latent row for a latent-attention layer) and/or fixed-size state in
+per-sequence slots (``state``).  The cache manager sizes its pools from these,
+so a model with fewer attention layers than layers gets a pool with fewer
+layers, and a model that caches a latent gets no K/V pool at all.
 
 A stack names its layers one of three ways.  ``TransformerConfig.layer_period``
 is a repeated *period* of types and ``layer_runs`` a sequence of runs, each a
@@ -42,7 +45,10 @@ class LayerType:
     #: (cfg, rng, n) -> the parameters of n layers stacked [n, ...]
     init: Callable[[TransformerConfig, Any, int], Dict[str, Any]]
     mixer: str
-    kv_pages: bool
+    #: cfg -> {pool leaf: values a token keeps in it}: the type's page
+    #: format, each leaf ``[layers, pages + 1, page_size, width]`` in the
+    #: served dtype; empty for a type that keeps no pages
+    pages: Callable[[TransformerConfig], Dict[str, int]]
     #: cfg -> {pool leaf: (per-sequence shape, dtype or None for the served
     #: dtype)}: state kept in slots, one per decode row, beside the pages
     state: Callable[[TransformerConfig], Dict[str, Tuple[tuple, Any]]]
@@ -144,6 +150,45 @@ def _conv_mix(cfg: TransformerConfig, layer, x, positions, mask, attn_fn):
 def _conv_state(cfg: TransformerConfig) -> Dict[str, Tuple[tuple, Any]]:
     # the last taps - 1 rows of B u: what a decode step would convolve with
     return {"conv_tail": ((cfg.conv_taps - 1, cfg.hidden_size), None)}
+
+
+def _init_mla(cfg: TransformerConfig, rng, n: int) -> Dict[str, Any]:
+    keys = jax.random.split(rng, 32)
+    layers = init_layer_stack(cfg, keys, n, attn=False)
+    H, NH, R = cfg.hidden_size, cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    layers["attn"] = {
+        "w_dq": _nrm(cfg, keys[16], n, H, cfg.q_lora_rank),
+        "q_norm": jnp.ones((n, cfg.q_lora_rank), cfg.dtype),
+        "w_uq": _nrm(cfg, keys[17], n, cfg.q_lora_rank, NH * (dn + dr)),
+        # the latent and, beside it, the one rotary key all heads share
+        "w_dkv": _nrm(cfg, keys[18], n, H, R + dr),
+        "kv_norm": jnp.ones((n, R), cfg.dtype),
+        # per head [k_nope | v]
+        "w_ukv": _nrm(cfg, keys[19], n, R, NH * (dn + dv)),
+        "wo": _nrm(cfg, keys[20], n, NH * dv, H,
+                   s=0.02 / math.sqrt(2 * cfg.n_layers)),
+    }
+    return layers
+
+
+def latent_width(cfg: TransformerConfig) -> int:
+    """Lanes a token's ``[latent | rotary key]`` row takes in the pool: whole
+    lane tiles of 128 (256 + 64 -> 384).  The device lays a minor dimension
+    of 320 out as 384 lanes whatever it is declared, and the decode kernel
+    can only cut a page out of an operand whose rows are whole tiles; the
+    lanes past ``kv_lora_rank + qk_rope_head_dim`` hold zeros and enter no
+    product."""
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // 128) * 128
+
+
+def _kv_pages(cfg: TransformerConfig) -> Dict[str, int]:
+    width = cfg.kv_heads * cfg.head_dim
+    return {"k": width, "v": width}
+
+
+def _no_pages(cfg: TransformerConfig) -> Dict[str, int]:
+    return {}
 
 
 def _served_only(kind: str, what: str):
@@ -255,45 +300,56 @@ def _window_state(cfg: TransformerConfig) -> Dict[str, Tuple[tuple, Any]]:
     return {"win_k": (shape, None), "win_v": (shape, None)}
 
 
-ATTN = LayerType("attn", _init_attn, mixer="attn", kv_pages=True,
+ATTN = LayerType("attn", _init_attn, mixer="attn", pages=_kv_pages,
                  state=lambda cfg: {}, mix=attn_mixer)
-KDA = LayerType("kda", _init_kda, mixer="kda", kv_pages=False,
+KDA = LayerType("kda", _init_kda, mixer="kda", pages=_no_pages,
                 state=_kda_state,
                 mix=_served_only("kda", "the backward of the delta-rule scan "
                                  "(ops/pallas/kda.py: dstpu_kda_chunk)"))
 #: trained only: the paged programs have no form of this mixer yet; the type
 #: declares the state a serving PR has to keep
-CONV = LayerType("conv", _init_conv, mixer="conv", kv_pages=False,
+CONV = LayerType("conv", _init_conv, mixer="conv", pages=_no_pages,
                  state=_conv_state, mix=_conv_mix)
 #: Phi-4-mini-flash (SambaY), served only.  A Mamba-1 selective state-space
 #: layer: float32 state and a convolution tail in the sequence's slot; the
 #: last one's scan output is the memory the gated memory units read
-MAMBA = LayerType("mamba", _init_mamba, mixer="mamba", kv_pages=False,
+MAMBA = LayerType("mamba", _init_mamba, mixer="mamba", pages=_no_pages,
                   state=_mamba_state, mix=_served_only("mamba", _NO_SCAN_BWD),
                   crosses=True)
 #: differential attention over the last ``sliding_window`` positions, kept as
 #: a ring in the sequence's slot: no pages, no page accounting
-SWA = LayerType("swa", _init_dattn, mixer="swa", kv_pages=False,
+SWA = LayerType("swa", _init_dattn, mixer="swa", pages=_no_pages,
                 state=_window_state,
                 mix=_served_only("swa", "a window mask in the flash backward"),
                 crosses=True)
 #: differential attention over the whole context: the one layer that writes
 #: pages, which the cross-attention layers after it read
-DATTN = LayerType("dattn", _init_dattn, mixer="dattn", kv_pages=True,
+DATTN = LayerType("dattn", _init_dattn, mixer="dattn", pages=_kv_pages,
                   state=lambda cfg: {},
                   mix=_served_only("dattn", "the differential form in the "
                                    "training forward"), crosses=True)
 #: a gated memory unit: the last state-space layer's scan output, gated
-GMU = LayerType("gmu", _init_gmu, mixer="gmu", kv_pages=False,
+GMU = LayerType("gmu", _init_gmu, mixer="gmu", pages=_no_pages,
                 state=lambda cfg: {}, mix=_served_only("gmu", _NO_SCAN_BWD),
                 crosses=True)
 #: differential attention of its own queries to the pages ``dattn`` wrote
-XATTN = LayerType("xattn", _init_xattn, mixer="xattn", kv_pages=False,
+XATTN = LayerType("xattn", _init_xattn, mixer="xattn", pages=_no_pages,
                   state=lambda cfg: {},
                   mix=_served_only("xattn", "the differential form in the "
                                    "training forward"), crosses=True)
+#: latent attention (MLA), served only.  A token leaves one row in the pool:
+#: the normalised ``kv_lora_rank``-wide latent and, beside it, the rotary key
+#: all heads share, already rotated — no K pool, no V pool.  A chunk expands
+#: keys and values from the window's latents; a decode row attends the
+#: latents themselves, the up-projections absorbed into its query and output
+MLA = LayerType("mla", _init_mla, mixer="mla",
+                pages=lambda cfg: {"latent": latent_width(cfg)},
+                state=lambda cfg: {},
+                mix=_served_only("mla", "the latent form in the training "
+                                 "forward (and a cut of this family that "
+                                 "fits a chip at 16 B a parameter)"))
 _TYPES = {"attn": ATTN, "kda": KDA, "conv": CONV, "mamba": MAMBA, "swa": SWA,
-          "dattn": DATTN, "gmu": GMU, "xattn": XATTN}
+          "dattn": DATTN, "gmu": GMU, "xattn": XATTN, "mla": MLA}
 
 
 def layer_type(kind: str) -> LayerType:
@@ -425,9 +481,24 @@ def layers_of(cfg: TransformerConfig, mixer: str) -> int:
 
 
 def page_layers(cfg: TransformerConfig) -> int:
-    """How many of the model's layers keep K/V pages: the pool's layers."""
-    return sum(n * sum(t.kv_pages for t in types)
+    """How many of the model's layers keep pages: the pool's layers."""
+    return sum(n * sum(bool(t.pages(cfg)) for t in types)
                for types, n in served_runs(cfg))
+
+
+def page_leaves(cfg: TransformerConfig) -> Dict[str, Tuple[int, int]]:
+    """{pool leaf: (layers that keep it, values a token keeps in it)} over
+    the whole model: the page format the cache manager builds."""
+    out: Dict[str, Tuple[int, int]] = {}
+    for types, n in served_runs(cfg):
+        for t in types:
+            for name, width in t.pages(cfg).items():
+                layers, w = out.get(name, (0, width))
+                if w != width:
+                    raise ValueError(f"page leaf {name!r} has widths {w} "
+                                     f"and {width} in one model")
+                out[name] = (layers + n, width)
+    return out
 
 
 def state_leaves(cfg: TransformerConfig) -> Dict[str, Tuple[int, tuple, Any]]:

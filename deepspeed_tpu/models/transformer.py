@@ -216,6 +216,27 @@ class TransformerConfig:
     ssm_state: int = 16
     ssm_conv: int = 4
     ssm_dt_rank: int = 0
+    #: latent attention (type "mla"): the ranks of the query's and the
+    #: key/value's down-projections, a head's query/key width without and
+    #: with rotary, and a head's value width; what a token leaves in the
+    #: cache is ``kv_lora_rank + qk_rope_head_dim`` values a layer
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    #: YaRN rotary tables (``yarn_inv_freq``): ``rope_factor`` over
+    #: ``rope_original_max`` positions (0: plain rotary), the blend's two
+    #: rotation counts, and ``mscale_all_dim`` (the softmax scale is
+    #: multiplied by ``(0.1 * mscale_all_dim * ln(factor) + 1) ** 2``)
+    rope_factor: float = 1.0
+    rope_original_max: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    #: a query at position t is scaled by ``1 + beta * ln(1 + floor(t /
+    #: rope_original_max))`` (``llama_4_scaling_beta``; 0: not at all)
+    attn_scale_beta: float = 0.0
 
     @property
     def kv_heads(self) -> int:
@@ -366,7 +387,7 @@ def init_transformer_params(cfg: TransformerConfig, rng) -> Dict[str, Any]:
 
         p["layers"] = init_period_runs(cfg, rng)
         return p
-    if len(cfg.layer_period) == 1:
+    if cfg.layer_period == ("attn",):
         p["layers"] = init_layer_stack(cfg, keys, cfg.n_layers)
         return p
     from .layer_types import layer_type
@@ -486,6 +507,45 @@ def _rope(x, theta: float, positions, pct: float = 1.0):
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     out = out.astype(x.dtype)
     return out if d == d_full else jnp.concatenate([out, x_pass], axis=-1)
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, original_max: int,
+                  beta_fast: float = 32.0, beta_slow: float = 1.0,
+                  blend: bool = True) -> jnp.ndarray:
+    """``[dim / 2]`` float32 rotary frequencies under YaRN: pair ``i`` turns
+    at ``theta_i = theta ** (-2i / dim)`` where it makes more than
+    ``beta_fast`` rotations over the original context, at ``theta_i /
+    factor`` where it makes fewer than ``beta_slow``, and at a linear blend
+    between (``f_i = theta_i (1 - r_i) + theta_i / factor r_i``, ``r_i`` the
+    ramp from the first pair index to the second).  ``blend`` False: the
+    plain table."""
+    i = jnp.arange(dim // 2, dtype=jnp.float32)
+    th = jnp.exp(-2.0 * i / dim * math.log(theta))
+    if not blend or not original_max or factor == 1.0:
+        return th
+
+    def pair_of(rotations: float) -> float:
+        return dim * math.log(original_max / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), dim - 1)
+    r = jnp.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return th * (1.0 - r) + th / factor * r
+
+
+def rope_interleaved(x, inv_freq, positions):
+    """Rotary embedding of pairs ``(2i, 2i + 1)`` of ``x [..., T, NH, d]`` by
+    angle ``positions * inv_freq[i]``; ``positions [..., T]``.  The pairs'
+    even members come out first and the odd ones second (``[d/2 | d/2]``),
+    as the published code leaves them: every query and key goes through
+    here, so their products are those of the pairs rotated in place."""
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., 0::2], xf[..., 1::2]
+    ang = positions[..., None, None].astype(jnp.float32) * inv_freq
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
 
 
 def alibi_slopes(n_heads: int) -> jnp.ndarray:
@@ -1137,7 +1197,7 @@ def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
         from .layer_types import layer_type, stack_matmul_params
 
         keys = seq_len / 2 if cfg.causal else seq_len
-        attends = sum(layer_type(k).kv_pages for k in cfg.layer_types)
+        attends = sum(bool(layer_type(k).pages(cfg)) for k in cfg.layer_types)
         return 3.0 * (2.0 * (cfg.hidden_size * cfg.vocab_size
                              + stack_matmul_params(cfg, active=True))
                       + attends * 2 * 2 * keys * cfg.n_heads * cfg.head_dim)

@@ -253,8 +253,14 @@ def pick_row_maps(key: jnp.ndarray, top_k: int, n_experts: int,
 def _expert_ffn_blocks(xs, experts, block_expert, n_real, activation,
                        block_rows, impl="auto"):
     """The three grouped matmuls of one FFN over sorted+padded tokens (the
-    rows of the blocks past ``n_real`` come back undefined)."""
+    rows of the blocks past ``n_real`` come back undefined).  Where
+    ``experts`` names an ``expert_first`` its matrices are those of several
+    layers, stacked, and this layer's begin there: the blocks' map is moved
+    by it, and the kernel fetches this layer's tiles out of the stack."""
     from ..ops.pallas.grouped_matmul import grouped_matmul
+
+    if "expert_first" in experts:
+        block_expert = block_expert + experts["expert_first"]
 
     def gm(a, w):
         return grouped_matmul(a, w, block_expert, block_rows, impl=impl,

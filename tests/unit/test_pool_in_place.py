@@ -158,3 +158,54 @@ def test_state_slots_and_window_rings_are_kept_in_place(slots_engine,
     assert mem.temp_size_in_bytes < pool_bytes // 2, (
         f"{program}: temporaries {mem.temp_size_in_bytes} B beside pools of "
         f"{pool_bytes} B — a slot-pool-sized copy is in the program")
+
+
+# ------------------------------------------------------- one latent leaf
+L_B, L_MP, L_CHUNK, L_PS = 8, 16, 16, 4
+
+
+@pytest.fixture(scope="module")
+def latent_engine():
+    """Mistral-Small-4 at its tiny widths with a pool of 4096 pages: one
+    latent leaf of 4 layers x 4097 x 4 x 128 float32 = 33.6 MB beside a 0.7
+    MB model — the pool is nearly all of the program's memory."""
+    from deepspeed_tpu.models import mistral4_model
+
+    return InferenceEngineV2(
+        mistral4_model("tiny", max_seq_len=L_PS * L_MP, moe_held_first=2,
+                       moe_held_count=2),
+        RaggedInferenceConfig(dtype="fp32", page_size=L_PS, num_pages=4096,
+                              max_seqs=L_B, max_pages_per_seq=L_MP,
+                              prefill_chunk=L_CHUNK))
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
+def test_the_latent_pool_is_kept_in_place(latent_engine, program):
+    """The chunk program gathers a window of latent rows and expands it; the
+    decode program scatters one row a sequence: neither may hold a second
+    pool, and the one leaf is aliased input to output."""
+    eng = latent_engine
+    i32 = jnp.int32
+
+    def arr(shape, dtype=i32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    if program == "decode":
+        fn, args = eng._decode, (
+            arr((L_B,)), arr((L_B,)), arr((L_B, L_MP)),
+            arr((L_B,), jnp.bool_), arr((L_B,), jnp.float32), arr((L_B,)),
+            arr((2,), jnp.uint32))
+    else:
+        fn, args = eng._prefill_chunk, (
+            arr((L_CHUNK,)), arr((L_CHUNK // L_PS,)), arr((L_MP,)), arr(()),
+            arr(()))
+    assert set(eng._pools) == {"latent", "moe_stats"}
+    pool_bytes = eng._pools["latent"].size * 4
+    assert pool_bytes > 40 * eng.param_bytes
+    mem = fn.lower(eng.params, eng._pools, *args).compile().memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes, (
+        f"{program}: {mem.alias_size_in_bytes} B aliased input to output, "
+        f"the latent pool is {pool_bytes} B — its donation does not hold")
+    assert mem.temp_size_in_bytes < pool_bytes // 4, (
+        f"{program}: temporaries {mem.temp_size_in_bytes} B beside a pool of "
+        f"{pool_bytes} B — a pool-sized copy is in the program")

@@ -179,7 +179,15 @@ def main() -> int:
     params, pools = on_chip(engine.params), on_chip(engine._pools)
     pool_bytes = sum(a.size * a.dtype.itemsize
                      for a in jax.tree_util.tree_leaves(pools))
-    k = pools["k"]
+    # the page leaves the layer types declared: K and V (and their scales
+    # under kv_quant), or one latent leaf
+    from deepspeed_tpu.inference.v2.model_runner import PAGE_LEAVES
+
+    paged = {n: a for n, a in pools.items() if n in PAGE_LEAVES}
+    k = next(iter(paged.values()))
+    token_bytes = sum(
+        a.shape[0] * -(-a.shape[-1] // 128) * 128 * a.dtype.itemsize
+        for a in paged.values())
     # the floor for "pool-sized": one layer of the smallest large leaf (the
     # K pool, or a layer's state slots where the model keeps state)
     layer_pool_bytes = min(
@@ -192,7 +200,11 @@ def main() -> int:
           f"{ {n: a.shape for n, a in pools.items()} } = "
           f"{pool_bytes / GIB:.2f} GiB, weights "
           f"{engine.param_bytes / GIB:.2f} GiB, max_seqs {B}, "
-          f"max_pages_per_seq {MP}, chunk {C}")
+          f"max_pages_per_seq {MP}, chunk {C}; a cached token is "
+          f"{token_bytes} B over the layers as laid out (rows padded to "
+          f"whole lane tiles), "
+          f"{sum(a.shape[0] * a.shape[-1] * a.dtype.itemsize for a in paged.values())}"
+          f" B as declared")
 
     i32 = jnp.int32
     key = arr((2,), jnp.uint32)

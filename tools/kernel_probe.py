@@ -107,6 +107,21 @@ def cases():
                     [((rows, 40, 128), bf), pool, pool, ((rows, mp), i32),
                      ((rows,), i32), ((rows,), jnp.bool_)], None, 0))
 
+    # Mistral-Small-4's cell: the latent decode over one leaf of 256 + 64
+    # lanes a token (384 as laid out: whole lane tiles), 32 absorbed heads
+    def mla(q, pool, table, pos, act):
+        from deepspeed_tpu.ops.pallas.mla_attention import \
+            mla_decode_attention
+
+        return mla_decode_attention(q, pool, table, pos, jnp.int32(1), act,
+                                    rank=256)
+
+    out.append(("mla_decode 128x1088 pages of 16, 32 heads over a latent of "
+                "256 + 64", mla,
+                [((128, 32, 320), bf), ((2, 8193, 16, 384), bf),
+                 ((128, 1088), i32), ((128,), i32), ((128,), jnp.bool_)],
+                None, 0))
+
     # its window layers' chunk attention: [ring | chunk] keys with the mask
     def flash_window(q, k, v, k_first):
         return flash_attention(q, k, v, causal=True, q_offset=512,
