@@ -35,6 +35,7 @@ from typing import Any, Callable, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..runtime.activation_checkpointing.checkpointing import get_policy
 from .transformer import (MODEL_AXIS, TransformerConfig, _mm, _nrm, _norm,
                           attn_mixer, init_layer_stack, mlp_block)
 
@@ -428,13 +429,21 @@ def init_runs(cfg: TransformerConfig, rng) -> Tuple[Dict[str, Any], ...]:
         for j, (kind, ffn, n) in enumerate(stack_runs(cfg)))
 
 
+#: the longest run of recomputed blocks that keep residuals to be unrolled
+#: (the hybrids' periods are runs of three; a program grows with every layer
+#: unrolled)
+UNROLLED_KEEPING_RUN = 4
+
+
 def run_stack(cfg: TransformerConfig, stack, x, positions, mask, attn_fn,
               with_act_stats: bool = False):
     """The layer loop of ``transformer_forward`` over a stack of
-    ``cfg.layer_types``: each run scanned (a run of one layer unrolled), each
-    layer ``x + mix(x)`` then ``mlp_block``.  Returns (x, the float auxiliary
-    losses summed, ``[L, 3]`` activation rows or None, the int32 counters of
-    the expert-share layers ``[expert layers, held + 3]`` or None)."""
+    ``cfg.layer_types``: each run scanned (a run of one layer unrolled, and
+    a run of up to ``UNROLLED_KEEPING_RUN`` recomputed blocks that keep
+    residuals), each layer ``x + mix(x)`` then ``mlp_block``.  Returns (x,
+    the float auxiliary losses summed, ``[L, 3]`` activation rows or None,
+    the int32 counters of the expert-share layers ``[expert layers, held +
+    3]`` or None)."""
     if with_act_stats:
         from ..telemetry.numerics import activation_stats as act_row
     aux = jnp.asarray(0.0, jnp.float32)
@@ -446,15 +455,22 @@ def run_stack(cfg: TransformerConfig, stack, x, positions, mask, attn_fn,
             h = x + mix(rcfg, layer, x, positions, mask, attn_fn)
             return mlp_block(rcfg, layer, h)
 
+        keeps = False
         if cfg.remat:
-            block = jax.checkpoint(block, policy=getattr(
-                jax.checkpoint_policies, cfg.remat_policy, None))
+            policy = get_policy(cfg.remat_policy)
+            block = jax.checkpoint(block, policy=policy)
+            # a scan stacks what its recomputed blocks keep: each kept
+            # buffer is copied into its stack a layer and held beside it.
+            # A short run is unrolled instead: each is read where it was made
+            keeps = policy not in (
+                None, jax.checkpoint_policies.nothing_saveable)
 
         def body(carry, layer, block=block):
             y, a = block(carry, layer)
             return y, ((a, act_row(y)) if with_act_stats else a)
 
-        if n == 1 or not cfg.scan_layers:
+        if (n == 1 or not cfg.scan_layers
+                or (keeps and n <= UNROLLED_KEEPING_RUN)):
             rows = []
             for i in range(n):
                 x, y = body(x, jax.tree_util.tree_map(lambda a: a[i], layers))

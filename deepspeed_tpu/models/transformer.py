@@ -31,6 +31,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..runtime.activation_checkpointing.checkpointing import (DEFAULT_POLICY,
+                                                              get_policy)
+
 MODEL_AXIS = "model"
 SEQ_AXIS = "sequence"
 
@@ -72,7 +75,11 @@ class TransformerConfig:
     type_vocab_size: int = 2
     dtype: Any = jnp.float32  # params storage dtype at init (engine recasts)
     remat: bool = False
-    remat_policy: str = "nothing_saveable"
+    #: what a recomputed block keeps (a name of runtime/
+    #: activation_checkpointing/checkpointing.py::POLICY_MAP): by default the
+    #: outputs of its matrix products and kernels, the elementwise work
+    #: replayed; "nothing_saveable" keeps the block's input alone
+    remat_policy: str = DEFAULT_POLICY
     attn_impl: str = "auto"  # auto | xla | flash | ulysses | ring
     scan_layers: bool = True
     # MoE (mixtral-style: every layer's MLP is replaced when num_experts > 0)
@@ -905,8 +912,7 @@ def transformer_forward(cfg: TransformerConfig, params, input_ids, mask=None,
             has_mask=mask is not None)
         block = lambda x, layer, comm_s=None: wrapped(x, positions, mask, layer, comm_s)  # noqa: E731
     if cfg.remat:
-        policy = getattr(jax.checkpoint_policies, cfg.remat_policy, None)
-        block = jax.checkpoint(block, policy=policy)
+        block = jax.checkpoint(block, policy=get_policy(cfg.remat_policy))
 
     if cfg.scan_layers:
         # stage-3 manual prefetch (zero3_prefetch, engine-set per trace):

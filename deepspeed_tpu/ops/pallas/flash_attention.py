@@ -24,9 +24,12 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...runtime.activation_checkpointing.checkpointing import (
+    FLASH_LSE, FLASH_OUT)
 from ...utils.platform import pallas_interpret
 
 NEG_INF = -1e30
@@ -346,6 +349,10 @@ def _flash_fwd_rule(q, k, v, alibi_arr, sm_scale, causal, block_q, block_k,
                     alibi):
     out, lse = _fwd(q, k, v, alibi_arr, jnp.zeros((1,), jnp.int32), sm_scale,
                     causal, block_q, block_k, valid_k, q_per_kv, alibi=alibi)
+    # a recomputed block that keeps these two replays no forward kernel
+    # (the backward kernels read both: one without the other buys nothing)
+    out = checkpoint_name(out, FLASH_OUT)
+    lse = checkpoint_name(lse, FLASH_LSE)
     return out, (q, k, v, alibi_arr, out, lse)
 
 

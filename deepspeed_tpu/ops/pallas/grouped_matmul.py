@@ -20,9 +20,12 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...runtime.activation_checkpointing.checkpointing import (
+    GROUPED_MATMUL_OUT)
 from ...utils.platform import on_tpu, pallas_interpret
 
 #: bytes of one weight tile ``[H, tn]``.  Two are in flight (the pipeline's
@@ -205,8 +208,9 @@ def _gmm(x, w, block_expert, n_real, block_rows):
 
 
 def _gmm_fwd(x, w, block_expert, n_real, block_rows):
-    return (_gmm(x, w, block_expert, n_real, block_rows),
-            (x, w, block_expert, n_real))
+    y = checkpoint_name(_gmm(x, w, block_expert, n_real, block_rows),
+                        GROUPED_MATMUL_OUT)
+    return y, (x, w, block_expert, n_real)
 
 
 def _gmm_bwd(block_rows, res, dy):

@@ -38,9 +38,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...runtime.activation_checkpointing.checkpointing import MOE_DISPATCH_ROWS
 from ...utils.platform import on_tpu, pallas_interpret
 
 _LANES = 128
@@ -338,8 +340,9 @@ def moe_dispatch(xt, row_pick, n_valid, n_real, dest, block_rows: int):
 
 
 def _moe_dispatch_fwd(xt, row_pick, n_valid, n_real, dest, block_rows):
-    return dispatch_rows(xt, row_pick // dest.shape[1], n_valid, n_real,
-                         block_rows), dest
+    xs = dispatch_rows(xt, row_pick // dest.shape[1], n_valid, n_real,
+                       block_rows)
+    return checkpoint_name(xs, MOE_DISPATCH_ROWS), dest
 
 
 def _moe_dispatch_bwd(block_rows, dest, dxs):

@@ -27,15 +27,49 @@ _CONFIG = {
     "profile": False,
 }
 
+#: ``checkpoint_name`` tags on what the Pallas kernels' ``custom_vjp`` forward
+#: rules return (``ops/pallas/``: flash attention's output and its row
+#: log-sum-exp, the grouped expert matmul's product, the rows the expert
+#: dispatch laid out).  Only a differentiated call traces a forward rule, so
+#: no served program carries them.  A recomputed block that keeps them finds
+#: the kernels' outputs made when its backward pass replays the block.
+FLASH_OUT, FLASH_LSE, GROUPED_MATMUL_OUT, MOE_DISPATCH_ROWS = \
+    KERNEL_RESIDUALS = ("flash_out", "flash_lse", "grouped_matmul_out",
+                        "moe_dispatch_rows")
+
+#: what ``remat: true`` keeps unless the model's ``remat_policy`` says
+#: otherwise
+DEFAULT_POLICY = "products_saveable"
+
+
+def _products_saveable():
+    """Keep what costs matrix-unit time, replay what is elementwise: the
+    outputs of XLA's own products without batch dimensions and of the
+    kernels (``KERNEL_RESIDUALS``) are saved; norms, rotary, the router's
+    softmax and top-k, activation products, convolution taps and relayouts
+    are recomputed from the block's input."""
+    cp = jax.checkpoint_policies
+    return cp.save_from_both_policies(
+        cp.dots_with_no_batch_dims_saveable,
+        cp.save_only_these_names(*KERNEL_RESIDUALS))
+
+
+def _offload_dots():
+    return jax.checkpoint_policies.offload_dot_with_no_batch_dims(
+        "device", "pinned_host")
+
+
 POLICY_MAP = {
-    # DeepSpeed-ish names -> jax.checkpoint_policies
+    # DeepSpeed-ish names -> jax.checkpoint_policies (a name of that module,
+    # or a function that builds the policy)
+    DEFAULT_POLICY: _products_saveable,
     "nothing_saveable": "nothing_saveable",
     "everything_saveable": "everything_saveable",
     "dots_saveable": "dots_saveable",
     "checkpoint_dots": "dots_saveable",
     "dots_with_no_batch_dims_saveable": "dots_with_no_batch_dims_saveable",
     "save_anything_except_these_names": None,
-    "offload_dots": "save_and_offload_only_these_names",
+    "offload_dots": _offload_dots,
 }
 
 
@@ -67,7 +101,8 @@ def get_policy(name: Optional[str] = None):
     mapped = POLICY_MAP.get(name, name)
     if mapped is None:
         return None
-    pol = getattr(jax.checkpoint_policies, mapped, None)
+    pol = mapped() if callable(mapped) \
+        else getattr(jax.checkpoint_policies, mapped, None)
     if pol is None:
         logger.warning(f"unknown remat policy '{name}'; saving nothing")
     if _CONFIG["cpu_checkpointing"]:

@@ -36,7 +36,7 @@ import optax
 
 from .. import comm
 from ..parallel.mesh import MeshTopology
-from ..telemetry.compile_sentinel import (expect_recompile,
+from ..telemetry.compile_sentinel import (expect_recompile, note_program,
                                           publish_setup_seconds, setup_span)
 from ..telemetry.flight import dump_on_exception
 from ..telemetry.spans import record_event, span
@@ -1457,6 +1457,7 @@ class DeepSpeedTPUEngine:
         # rebuilt jit wrappers legitimately compile on the next call —
         # announce it so the sentinel does not flag a steady-state recompile
         expect_recompile("engine._compile_steps")
+        self._step_program_noted = False
         donate = dict(donate_argnums=(0,))
         self._micro_step = jax.jit(self._micro_step_body, **donate)
         self._eval_fn = None
@@ -2238,6 +2239,26 @@ class DeepSpeedTPUEngine:
             self._flops_per_step = float(costs.get("flops", 0.0))
         return self._flops_per_step
 
+    def _note_step_program(self, batch) -> None:
+        """Once a build of the step, at a reporting boundary: what a
+        recomputed block keeps (the model's ``remat_policy``; None without
+        ``remat``) and the temporaries XLA gave the fused step, on the
+        set-up ledger's ``_train_batch_body`` entry.  The step has run with
+        these very arguments, so ``jit`` hands back the jaxpr and the
+        executable it holds: nothing is lowered or compiled for this."""
+        self._step_program_noted = True
+        mc = getattr(self.model, "config", None)
+        args = (self.state, batch, jax.random.PRNGKey(0))
+        if getattr(self, "_moe_counters", False):
+            args += (self._moe_acc,)
+        with self.topology.mesh:
+            mem = self._train_batch.lower(*args).compile().memory_analysis()
+        note_program(
+            "_train_batch_body",
+            remat_policy=(getattr(mc, "remat_policy", None)
+                          if getattr(mc, "remat", False) else None),
+            temp_size_in_bytes=mem.temp_size_in_bytes)
+
     def _report_telemetry(self, loss, batch,
                           step_dt: Optional[float] = None) -> None:
         """Per-step registry updates + boundary-cadence export.
@@ -2284,6 +2305,9 @@ class DeepSpeedTPUEngine:
             # tree walk; boundary cadence keeps it off the hot path)
             tm.ledger.publish()
         publish_setup_seconds()
+        if (batch is not None and step_dt is not None
+                and not self._step_program_noted):
+            self._note_step_program(batch)
         # dstpu-lint: allow[host-sync] boundary cadence, queue drained
         skipped = int(self.state.skipped_steps)
         if skipped > self._skipped_pub:

@@ -103,6 +103,8 @@ _totals: Dict[Tuple[str, str], List[float]] = {}
 _origin: Optional[float] = None
 _miss_stamps: List[float] = []  # the first _KEEP cache_misses events
 _misses = 0
+#: fun_name -> what its owner said of the program (``note_program``)
+_notes: Dict[str, Dict[str, Any]] = {}
 #: fun_names of the latest backend compiles, for the recompile event
 _compiled: "deque[str]" = deque(maxlen=16)
 _tls = threading.local()  # .hit: a cache_hits event awaits its program
@@ -175,6 +177,14 @@ def setup_span(name: str, start: float, end: Optional[float] = None,
         rec.record(name, perf_to_us(start), (end - start) * 1e6,
                    cat="setup" if part in ("import", "engine_init")
                    else "compile", seconds=end - start, **attrs)
+
+
+def note_program(fun_name: str, **facts) -> None:
+    """Keep ``facts`` with the ledger's entry of the program ``fun_name``
+    (``setup_ledger()["notes"]``): what only its owner knows of it — the
+    train step's recomputation policy and the temporaries XLA gave it."""
+    with _lock:
+        _notes.setdefault(fun_name, {}).update(facts)
 
 
 def _on_duration_event(event: str, duration_secs: float, **kw) -> None:
@@ -294,7 +304,8 @@ def setup_ledger(a: Optional[float] = None,
     never exceed ``b - a``, and ``unnamed`` is the rest.  ``parts`` and
     ``cache_misses`` (the counter over the same stretch) are None where
     the stretch reaches past what the ledger kept.  ``programs`` is the
-    whole process's ``(part, fun_name) -> (intervals, seconds)`` and
+    whole process's ``(part, fun_name) -> (intervals, seconds)``, ``notes``
+    what the owners of programs said of them (:func:`note_program`) and
     ``traces_after`` counts the programs whose trace ended after ``b``;
     ``events`` is what the listener was called with, a part."""
     with _lock:
@@ -310,6 +321,7 @@ def setup_ledger(a: Optional[float] = None,
         stamps = list(_miss_stamps)
         programs = {k: (int(n), s) for k, (n, s) in _totals.items()}
         events = {part: p.events for part, p in _parts.items()}
+        notes = {k: dict(v) for k, v in _notes.items()}
     a = (origin or 0.0) if a is None else a
     b = time.perf_counter() if b is None else b
     whole = not cuts or b <= min(cuts)
@@ -321,6 +333,7 @@ def setup_ledger(a: Optional[float] = None,
         "traces_after": (traces - sum(e <= b for e in trace_ends) if whole
                          else None),
         "programs": programs,
+        "notes": notes,
         "events": events,
         "kept": len(kept),
     }

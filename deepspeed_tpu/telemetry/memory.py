@@ -488,8 +488,9 @@ def oom_hints(context: Dict[str, Any], report: Dict[str, Any]) -> List[str]:
     if in_use > 0 and unattr > 0.25 * in_use:
         hints.append(
             f"{unattr} bytes ({100.0 * unattr / in_use:.0f}% of occupancy) "
-            "are unattributed transients: reduce the micro batch or enable "
-            "activation checkpointing (activation_checkpointing.policy)")
+            "are unattributed transients: reduce the micro batch, enable "
+            "activation checkpointing (the model's remat) or, with it on, "
+            "keep less across a block (remat_policy=\"nothing_saveable\")")
     if not hints:
         hints.append("reduce batch size / model size, or add devices: no "
                      "config headroom detected from the registered context")

@@ -1,9 +1,13 @@
 """engine.compile() pass tests (reference: tests/unit/v1/compile, deepspeed/compile/)."""
 
+import logging
+
 import numpy as np
 import pytest
 
 import deepspeed_tpu
+from deepspeed_tpu.runtime.activation_checkpointing.checkpointing import (
+    DEFAULT_POLICY, POLICY_MAP, get_policy)
 from tests.unit.simple_model import random_batch, simple_mlp_spec
 
 
@@ -64,3 +68,38 @@ def test_compile_offload_activation_remat():
     batch = {"input_ids": jnp.asarray(ids)}
     losses = [float(engine.train_batch(batch)) for _ in range(8)]
     assert losses[-1] < losses[0]
+
+
+# ------------------------------------------- what a recomputed block keeps
+def _kept_grad(policy):
+    import jax
+    import jax.numpy as jnp
+
+    x = jnp.asarray(np.random.RandomState(0).randn(8, 8), jnp.float32)
+    f = lambda x: jnp.sum(jnp.tanh(x @ x.T) @ x)  # noqa: E731
+    return (np.asarray(jax.grad(jax.checkpoint(f, policy=policy))(x)),
+            np.asarray(jax.grad(f)(x)))
+
+
+@pytest.mark.parametrize("name", sorted(POLICY_MAP))
+def test_every_remat_policy_name_resolves_to_a_policy(name):
+    """``remat_policy`` takes any name of ``POLICY_MAP``, the default's
+    among them: each is something ``jax.checkpoint`` takes, and what is
+    kept changes no gradient."""
+    assert DEFAULT_POLICY in POLICY_MAP
+    policy = get_policy(name)
+    assert policy is None or callable(policy)
+    got, want = _kept_grad(policy)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+
+def test_an_unknown_remat_policy_warns_and_keeps_nothing(caplog):
+    logger = logging.getLogger("DeepSpeedTPU")
+    logger.propagate = True
+    try:
+        with caplog.at_level("WARNING", logger="DeepSpeedTPU"):
+            assert get_policy("keep_what_i_mean") is None
+    finally:
+        logger.propagate = False
+    assert any("unknown remat policy 'keep_what_i_mean'" in r.getMessage()
+               for r in caplog.records)

@@ -2,8 +2,10 @@
 (``benchmark/reference/lfm2_moe_lm.py``): logits, loss and every gradient
 leaf, for the benchmark's cut pattern and for the published 24-layer one; the
 four expert shares against the uncut layer; the sigmoid router; what the
-family refuses (ISSUE 32; the engine's part is test_lfm2_moe_engine.py)."""
+family refuses (ISSUE 32; the engine's part is test_lfm2_moe_engine.py); what
+a recomputed block keeps and replays (ISSUE 42)."""
 
+import collections
 import importlib.util
 import os
 
@@ -18,7 +20,11 @@ from deepspeed_tpu.models.layer_types import stack_runs
 from deepspeed_tpu.models.transformer import (TransformerConfig, _ffn,
                                               logits_fn, transformer_forward)
 from deepspeed_tpu.moe.sharded_moe import MoEConfig, _gate_and_aux
+from deepspeed_tpu.ops.pallas import grouped_matmul as gmm_mod
+from deepspeed_tpu.ops.pallas import moe_dispatch as rows_mod
 from deepspeed_tpu.parallel.mesh import initialize_topology
+from deepspeed_tpu.runtime.activation_checkpointing.checkpointing import (
+    DEFAULT_POLICY, KERNEL_RESIDUALS)
 from deepspeed_tpu.runtime.config import MeshConfig
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -103,6 +109,88 @@ def test_logits_loss_and_every_gradient_leaf_match_the_reference(pattern,
     for run, (_, ffn, _) in zip(grads["layers"], stack_runs(cfg)):
         if ffn == "experts":
             assert not np.any(np.asarray(run["mlp"]["router_bias"]))
+
+
+# ------------------------------------------- what a recomputed block keeps
+KEEPS = {"default": {"remat": True},
+         "nothing_saveable": {"remat": True,
+                              "remat_policy": "nothing_saveable"},
+         "no_remat": {"remat": False}}
+
+
+def _kernel_sites(jaxpr, out=None):
+    """{kernel: its ``pallas_call`` equations} over ``jaxpr`` and every
+    jaxpr under it."""
+    out = collections.Counter() if out is None else out
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out[eqn.params["name"]] += 1
+            continue
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _kernel_sites(sub, out)
+    return out
+
+
+@pytest.fixture(scope="module")
+def kept():
+    """{case of KEEPS: (every gradient leaf, the kernel sites of the
+    gradient's jaxpr)} of the cut stack through the kernels, interpreted:
+    ``impl="auto"`` as on the chip."""
+    ids = jnp.asarray(np.random.default_rng(5).integers(0, 256, (2, 24),
+                                                        dtype=np.int32))
+    out = {}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(gmm_mod, "on_tpu", lambda: True)
+        mp.setattr(rows_mod, "on_tpu", lambda: True)
+        mp.setattr(rows_mod, "rows_kernel_serves", lambda h, d, n: True)
+        for case, over in KEEPS.items():
+            model = _model(CUT, 1, attn_impl="flash", **over)
+            params = jax.jit(model.init_params)(jax.random.PRNGKey(3))
+            grad = jax.grad(lambda p, m=model: m.loss_fn(p, ids, None))
+            out[case] = (jax.jit(grad)(params),
+                         _kernel_sites(jax.make_jaxpr(grad)(params).jaxpr))
+    return out
+
+
+def test_remat_by_default_keeps_products_and_kernel_outputs():
+    assert TransformerConfig().remat_policy == DEFAULT_POLICY
+    assert set(KERNEL_RESIDUALS) == {"flash_out", "flash_lse",
+                                     "grouped_matmul_out",
+                                     "moe_dispatch_rows"}
+
+
+@pytest.mark.parametrize("case", ["nothing_saveable", "no_remat"])
+def test_what_a_block_keeps_changes_no_gradient_leaf(kept, case):
+    got, _ = jax.tree_util.tree_flatten_with_path(kept["default"][0])
+    want = jax.tree_util.tree_leaves(kept[case][0])
+    assert len(got) == len(want)
+    for (path, g), r in zip(got, want):
+        scale = max(float(np.abs(r).max()), 1e-6)
+        assert float(np.abs(np.asarray(g) - r).max()) < 2e-4 * scale + 1e-7, \
+            jax.tree_util.keystr(path)
+
+
+@pytest.mark.parametrize("case,replays,bodies", [
+    ("default", 0, 4), ("no_remat", 0, 2), ("nothing_saveable", 1, 2)])
+def test_a_block_that_keeps_its_kernels_outputs_replays_no_kernel(
+        kept, case, replays, bodies):
+    """Under the default a block's backward holds no second forward call of
+    flash, the grouped matmul or the dispatch; under "nothing_saveable" it
+    holds one of each.  ``bodies``: the expert layers traced — the run of
+    three is one scanned body, and four layers where its blocks keep
+    residuals (``UNROLLED_KEEPING_RUN``)."""
+    sites = kept[case][1]
+    assert (sites["dstpu_flash_bwd_dq"], sites["dstpu_flash_bwd_dkv"],
+            sites["dstpu_grouped_matmul_dx"],
+            sites["dstpu_grouped_matmul_dw"]) == (1, 1, 3 * bodies,
+                                                  3 * bodies)
+    assert sites["dstpu_flash_fwd"] == 1 + replays
+    assert sites["dstpu_grouped_matmul"] == 3 * bodies * (1 + replays)
+    # the dispatch kernel also lays d ys out (the combine's backward)
+    assert sites["dstpu_moe_dispatch"] == bodies * (2 + replays)
 
 
 def _expert_layer(held_first, held):

@@ -285,6 +285,36 @@ def test_each_engine_leaves_one_init_interval():
         eng.close()
 
 
+def test_the_train_step_notes_what_it_keeps_and_compiles_nothing_for_it():
+    """At its first reporting boundary the engine puts the recomputation
+    policy and the step's temporaries on the ledger's ``_train_batch_body``
+    entry, from the executable ``jit`` already holds."""
+    from deepspeed_tpu.models.llama import llama_model
+    from deepspeed_tpu.runtime.activation_checkpointing.checkpointing import \
+        DEFAULT_POLICY
+
+    engine, *_ = deepspeed_tpu.initialize(
+        model=llama_model("tiny", max_seq_len=16, remat=True),
+        config={"train_micro_batch_size_per_gpu": 2, "steps_per_print": 2,
+                "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
+                "telemetry": {"enabled": True}})
+    batch = {"input_ids": jnp.zeros((1, 16, 16), jnp.int32)}
+    try:
+        engine.train_batch(batch)
+        assert not engine._step_program_noted
+        engine.train_batch(batch)
+        note = cs.setup_ledger()["notes"]["_train_batch_body"]
+        assert note["remat_policy"] == DEFAULT_POLICY
+        assert note["temp_size_in_bytes"] > 0
+        before = cs.setup_ledger()["events"]
+        engine._note_step_program(batch)
+        after = cs.setup_ledger()["events"]
+        assert [after[p] - before[p]
+                for p in ("lower", "compile", "cache_load")] == [0, 0, 0]
+    finally:
+        engine.close()
+
+
 @pytest.fixture
 def persistent_cache(tmp_path):
     """JAX's persistent compilation cache in ``tmp_path`` (the suite runs

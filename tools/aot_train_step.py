@@ -14,8 +14,8 @@ can be lowered and compiled from the sandbox.  What this catches before any
 chip time is spent: lowering refusals ("Mosaic kernels cannot be automatically
 partitioned"), out-of-memory programs, donation that does not alias, a kernel
 that is missing from the step.  Printed per run: compile seconds, XLA's
-per-device memory analysis, the kernel names in the lowered text, and the
-collective counts of the optimized HLO.
+per-device memory analysis, the kernel names in the lowered text, the number
+of kernel call sites and the collective counts of the optimized HLO.
 
 ``--config`` compiles a benchmark training cell's own step instead: the
 model its family builds (``benchmark/families/<family>.py``) at the
@@ -190,9 +190,13 @@ def main() -> int:
           f"{mem.alias_size_in_bytes / gib:.2f}), temporaries "
           f"{mem.temp_size_in_bytes / gib:.2f} GiB, total "
           f"{total / gib:.2f} GiB of 15.75")
-    print(f"  Mosaic kernels in the lowered step: "
-          f"{sorted(set(re.findall(r'dstpu_[a-z_]+', text)))}")
     hlo = compiled.as_text()
+    # a scanned body's call counts once: a replay that remat leaves in the
+    # backward pass is a site more, whatever the trip count
+    print(f"  Mosaic kernels in the lowered step: "
+          f"{sorted(set(re.findall(r'dstpu_[a-z_]+', text)))}, "
+          f"{len(re.findall(r'custom_call_target=.tpu_custom_call', hlo))} "
+          f"call sites in the optimized HLO")
     print("  collectives in the optimized HLO: " + ", ".join(
         f"{k} {len(re.findall(rf' {k}(-start)?[.0-9]*[(]', hlo))}"
         for k in ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",

@@ -229,7 +229,7 @@ def main(argv=None) -> int:
     if tm.jsonl is not None:
         tm.jsonl.emit("demo_complete", steps=args.steps,
                       serve_requests=args.serve_requests)
-    from deepspeed_tpu.telemetry import get_memory_ledger
+    from deepspeed_tpu.telemetry import get_memory_ledger, setup_ledger
 
     # read the ledger BEFORE close(): close releases the engine's
     # component slots (they would otherwise pin the TrainState forever)
@@ -262,6 +262,9 @@ def main(argv=None) -> int:
         "metric_samples": len(samples),
         "metric_families": len(names),
         "mfu": reg.get("deepspeed_tpu_train_mfu").value(),
+        # what a recomputed block keeps and the step's temporaries
+        "train_step_program": setup_ledger()["notes"].get(
+            "_train_batch_body"),
         "decode_latency_s": dec.percentiles() if dec.count() else None,
         "prefix_hit_rate": cache["prefix_hit_rate"],
         "memory": {
