@@ -1,30 +1,191 @@
 """What the Solar-Open2 cell added: the generator kind that takes the
 reference from the configuration, the kernels' operation and byte counts, and
 readers that read nothing (and do not raise) where the program has no such
-counter."""
+counter.  Since PR 50 the cell is ``solaropen2-ep8-reason-saturated``: the
+same configuration, lengths, check and limits under a trace at twice what
+the engine completes; the control and a planted fault run here through
+``run.py``'s own path at the tiny sizes and read ``correct: false``."""
 
 import json
 import os
 import subprocess
 import sys
 
+import numpy as np
+
 from benchmark import kernel_counts
 from benchmark import manifest as manifest_mod
 
 ROOT = os.path.dirname(manifest_mod.HERE)
-CELL = "solaropen2-ep8-reason-steady"
+CELL = "solaropen2-ep8-reason-saturated"
+ARGS = ["--workload", CELL, "--seed", "3000000001", "--seconds", "2",
+        "--trace", "0"]
 
 
 def test_the_cell_rehearses_against_its_own_reference():
     p = subprocess.run(
         [sys.executable, os.path.join(manifest_mod.HERE, "rehearse.py"),
-         "--workload", CELL, "--seed", "3000000001", "--seconds", "2",
-         "--trace", "0"], env=dict(os.environ, JAX_PLATFORMS="cpu"),
+         *ARGS], env=dict(os.environ, JAX_PLATFORMS="cpu"),
         cwd=ROOT, capture_output=True, text=True, timeout=600)
     assert p.returncode == 0, p.stderr[-2000:]
     line = json.loads(p.stdout.strip().splitlines()[-1])
     assert line["correct"] and line["failed"] == 0
+    assert line["counts"]["preempted"] == 0
     assert "largest regret against the float32 reference 0.000e+00" in p.stdout
+
+
+def _run_here(capsys, monkeypatch, on_generator=None):
+    """``run.main`` at the tiny sizes in this process — everything of a run
+    but the look for a chip — and its last line.  ``on_generator(module)``
+    sees the cell's generator module as the run loads it."""
+    from benchmark import run
+
+    load = manifest_mod.Manifest.module
+
+    def module(self, sub, name):
+        mod = load(self, sub, name)
+        if on_generator and (sub, name) == ("generators",
+                                            "serve_requests_ref"):
+            on_generator(mod)
+        return mod
+
+    monkeypatch.setattr(manifest_mod.Manifest, "module", module)
+    assert run.main(ARGS, rehearse=True) == 0
+    said = capsys.readouterr().out
+    return json.loads(said.strip().splitlines()[-1]), said
+
+
+def test_a_token_altered_where_it_is_produced_is_not_correct(
+        capsys, monkeypatch):
+    """The timed path broken underneath: the first token ``step()`` returns
+    for a request — what its prompt's last chunk samples — comes back with
+    its lowest bit flipped; every other token is the program's."""
+    from deepspeed_tpu.inference.v2 import InferenceEngineV2
+
+    step = InferenceEngineV2.step
+    seen = set()
+
+    def altered(self):
+        out = step(self)
+        for uid, o in out.items():
+            if o["tokens"] and uid not in seen:
+                seen.add(uid)
+                o["tokens"] = [o["tokens"][0] ^ 1] + list(o["tokens"][1:])
+        return out
+
+    monkeypatch.setattr(InferenceEngineV2, "step", altered)
+    line, said = _run_here(capsys, monkeypatch)
+    assert line["correct"] is False
+    assert "largest regret against the float32 reference 0.000e+00" not in said
+
+
+def _float8_reference_in_the_programs_place(gen):
+    """The control: the reference with its weights rounded to float8_e4m3's
+    mantissa decodes the check's prompts greedily — its own tokens, as the
+    program's are its own — and its tokens and states are read against the
+    float32 reference under the limits the program has just passed."""
+    import jax.numpy as jnp
+
+    check = gen.check_against_reference
+
+    def control(reference, ctx, engine, desc, vocab):
+        chk = check(reference, ctx, engine, desc, vocab)
+        rng = np.random.default_rng(ctx.seed + 1)
+        want = int(ctx.traffic["check_decode_steps"]) + 1
+        low = dict(weights_dtype=jnp.float8_e4m3fn)
+        regrets, state = [], {"state_error": 0.0, "state_bf16_share": 0.0}
+        for n in ctx.traffic["check_prompt_tokens"]:
+            ids = rng.integers(0, vocab, int(n), dtype=np.int64).tolist()
+            toks = []
+            for _ in range(want):
+                logits, states = reference.forward(desc, engine.params,
+                                                   ids + toks, **low)
+                toks.append(int(np.argmax(np.asarray(logits)[-1])))
+            ref, ref_states = reference.forward(desc, engine.params,
+                                                ids + toks[:-1])
+            for row, tok in zip(np.asarray(ref)[len(ids) - 1:], toks):
+                regrets.append(float(row.max() - row[tok])
+                               / float(np.abs(row).max()))
+            kept = np.stack([np.asarray(s).transpose(0, 2, 1)
+                             for s in states])
+            for k, v in gen.state_readings(kept, ref_states).items():
+                state[k] = max(state[k], v)
+        chk.update(regrets=regrets, max_regret=max(regrets), **state)
+        return chk
+
+    gen.check_against_reference = control
+
+
+def test_the_float8_reference_in_the_programs_place_is_not_correct(
+        capsys, monkeypatch):
+    line, said = _run_here(capsys, monkeypatch,
+                           _float8_reference_in_the_programs_place)
+    assert line["correct"] is False and line["failed"] == 0
+    state = said.split("recurrent state of the check requests")[1]
+    error = float(state.split("state_error ")[1].split()[0])
+    assert error > 1e-2        # the tiny limit is 1e-4: the program 1e-6
+
+
+def test_the_new_traffic_keeps_the_lengths_the_check_and_the_limits():
+    """PR 50 changed the arrival process; lengths, check and limits are what
+    the retired ``reason-steady.json`` had, key by key, but the limit on the
+    largest regret, set anew from its two readings (0.075 there)."""
+    man = manifest_mod.Manifest()
+    cell = man.cell(CELL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "solar-open2-250b-ep8-serve", "reason-saturated", 1)
+    tr = man.traffic(cell["traffic"])
+    assert tr["kind"] == "serve_requests_ref"
+    assert tr["prompt_tokens"] == {"dist": "lognormal", "median": 768,
+                                   "sigma": 0.7, "min": 64, "max": 4096}
+    assert tr["output_tokens"] == {"dist": "lognormal", "median": 1024,
+                                   "sigma": 0.5, "min": 256, "max": 3072}
+    assert (tr["balance_group"], tr["schedule_seed"], tr["tpot_min_gaps"],
+            tr["trace_seconds"]) == (8, 0, 16, 20)
+    assert (tr["check_prompt_tokens"], tr["check_decode_steps"]) == (
+        [320, 384, 640], 16)
+    assert (tr["regret_tolerance"], tr["state_error_tolerance"],
+            tr["state_bf16_share_tolerance"]) == (0.1, 0.15, 0.01)
+    assert tr["reports"] == {"tpot_p50_ms": "tpot_p50_ms",
+                             "setup_s": "setup_s"}
+    # over capacity: a trace, no TTFT sample, a pre-roll of 40 s at most
+    arr = tr["arrivals"]
+    assert arr["process"] == "trace" and tr["ttft_share"] == 0
+    assert 25 <= arr["preroll_s"] <= 40
+    # the worst case fits the pool: no preemption by construction
+    cfg = man.config(cell["config"])["engine"]
+    longest = tr["prompt_tokens"]["max"] + tr["output_tokens"]["max"]
+    assert cfg["max_seqs"] * longest <= cfg["num_pages"] * cfg["page_size"]
+    assert not man.find("traffic", "reason-steady.json")
+    assert not any(w["name"] == "solaropen2-ep8-reason-steady"
+                   for w in man.data["workloads"])
+
+
+def test_the_cell_is_on_every_list_the_retired_cell_was_on():
+    man = manifest_mod.Manifest()
+    listed = {m["name"] for m in man.per_layer(CELL)}
+    for name in ("compiles_in_window.steady", "decode_step_device_ms",
+                 "paged_decode_ms_per_step", "peak_hbm_gb.steady",
+                 "step_host_ms.steady", "idle_in_device_wait_ms.steady",
+                 "idle_outside_device_wait_ms.steady",
+                 "moe_experts_ms_per_step", "moe_experts_roofline",
+                 "kda_step_ms_per_step", "kda_step_roofline",
+                 "kda_chunk_ms_per_ktok", "kda_chunk_roofline",
+                 "moe_pad_share", "state_slots_in_use_p50",
+                 "paged_decode_roofline.period", "moe_dispatch_ms_per_step",
+                 "setup_import_s", "setup_engine_init_s", "setup_trace_s",
+                 "setup_lower_s", "setup_compile_s", "setup_cache_load_s",
+                 "setup_cache_misses", "setup_unnamed_s",
+                 # gained: chunk calls now ride the steps of this cell too
+                 "chunk_share_of_step.steady",
+                 "chunk_device_ms_per_ktok.steady"):
+        assert name in listed, name
+    # metrics of an end-to-end metric this cell does not report stay off it
+    for name in ("queue_wait_p50_ms", "ttft_p50_ms.steady",
+                 "ttft_p90_ms.steady"):
+        assert name not in listed
+    assert [m["name"] for m in man.end_to_end(CELL)] == ["tpot_p50_ms",
+                                                        "setup_s"]
 
 
 def test_the_configuration_keeps_the_published_widths():
