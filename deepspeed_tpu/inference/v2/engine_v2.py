@@ -52,6 +52,7 @@ from .model_runner import (pad_pages_pow2, paged_copy_page, paged_decode,
 from .ragged import (PRIORITY_NORMAL, BlockAllocator, KVBlockConfig,
                      KVPageBundle, PagedKVCache, PrefixCache, RejectedError,
                      SequenceState, StateSlots)
+from .block_diffusion import block_policy
 from .speculative import (SpeculativeConfig, build_proposer, longest_accepted)
 
 
@@ -190,6 +191,11 @@ class RaggedRequest:
     #: lifecycle trace event, and the KV-migration wire so one request
     #: is ONE connected trace across replicas
     trace_id: Optional[str] = None
+    #: a model that generates by diffusion over blocks (``block_diffusion``):
+    #: the passes that denoise a block, a divisor of the model's block length
+    #: (None = the block length: one position a pass); ``max_new_tokens`` is
+    #: then the fixed generation length
+    denoising_steps: Optional[int] = None
 
 
 #: what a ``serve_step`` span carries at its end
@@ -376,6 +382,10 @@ class InferenceEngineV2:
                         **dict.fromkeys(MOE_COUNTERS, 0),
                         "state_slot_preemptions": 0}
         self._init_serving_metrics()
+        #: the one question asked of the model: does it generate by blocks
+        #: (``block_diffusion.BlockPolicy``, which owns the decode phase of
+        #: such a model's step; None: one token a row a step)
+        self.blocks = block_policy(self, proposer)
         self._uid = itertools.count()
         self._admit_counter = itertools.count()
         self._enqueue_counter = itertools.count()
@@ -1006,6 +1016,11 @@ class InferenceEngineV2:
         if n >= self.max_seq_len:
             raise ValueError(f"prompt length {n} >= max_seq_len "
                              f"{self.max_seq_len}")
+        if self.blocks is not None:
+            self.blocks.check_request(request)
+        elif request.denoising_steps is not None:
+            raise ValueError("denoising_steps: this model generates one "
+                             "token a step, not by blocks")
         if (self.config.max_queue_depth > 0
                 and len(self._queue) >= self.config.max_queue_depth):
             # bounded queue: shed LOUDLY instead of growing the queue
@@ -1030,7 +1045,8 @@ class InferenceEngineV2:
             deadline=(now + max(0.0, float(request.deadline_s))
                       if request.deadline_s is not None else 0.0),
             enqueue_order=next(self._enqueue_counter),
-            queued_at=now, trace_id=request.trace_id))
+            queued_at=now, trace_id=request.trace_id,
+            denoising_steps=request.denoising_steps or 0))
         self._req_meta[uid] = {
             "t0": now, "t_first": None, "t_last": None,
             "n": 0,
@@ -1106,6 +1122,8 @@ class InferenceEngineV2:
         from ...ops.pallas.paged_attention import merged_keys
 
         seq = self._find_slotted(uid)
+        if self.blocks is not None:  # the committed blocks' K/V
+            return self.blocks.read_kv(seq)
         n, ps = seq.length - 1, self.block.page_size
         sentinel_expect_recompile("read_kv")
         pages = paged_gather_pages(self._pools, seq.pages[:-(-n // ps)], 1)
@@ -1160,6 +1178,8 @@ class InferenceEngineV2:
                 "KVPageBundle export: a bundle holds pages, and this model "
                 f"keeps recurrent state too ({sorted(self._state)})")
         seq = self._find_slotted(uid)
+        if self.blocks is not None:
+            self.blocks.refuse_export()
         ps = self.block.page_size
         immutable = seq.prefilled // ps  # pages never written again
         keys = list(seq.page_keys[:min(immutable, len(seq.page_keys))])
@@ -1613,6 +1633,8 @@ class InferenceEngineV2:
             # the state goes with the slot: the re-prefill recomputes it
             self._dstats["state_slot_preemptions"] += 1
             self._m_state_preempt.inc()
+        if seq.block is not None:  # a half-denoised block is redone
+            self.blocks.drop(seq)
         seq.slot, seq.pages, seq.prefilled = -1, [], 0
         seq.page_keys, seq.registered_upto, seq.decode_entry = [], 0, False
         seq.cached_match, seq.match_gen, seq.match_evict_gen = None, -1, -1
@@ -1812,14 +1834,16 @@ class InferenceEngineV2:
             out[seq.uid]["done"] = True
             out[seq.uid]["finish_reason"] = seq.finish_reason
 
-    @staticmethod
-    def _ready_to_decode(seq: SequenceState) -> bool:
+    def _ready_to_decode(self, seq: SequenceState) -> bool:
         """KV written for tokens[0:length-1] AND a token has been sampled
         off the prefix end — mid-chunked-prefill sequences (and preempted
         ones re-prefilling their prefix) must not enter the decode batch.
         Exception: a fully-cached prompt (decode_entry) starts decoding
         immediately — its first decode step recomputes the final prompt
-        token's KV (into its CoW page) and samples the first token."""
+        token's KV (into its CoW page) and samples the first token.
+        A model that generates by blocks: its whole blocks are prefilled."""
+        if self.blocks is not None:
+            return seq.prefilled >= self.blocks.prefill_end(seq)
         return ((seq.generated > 0 or seq.decode_entry)
                 and seq.prefilled >= seq.length - 1)
 
@@ -2051,7 +2075,10 @@ class InferenceEngineV2:
                        and not self._ready_to_decode(s)]
             for seq in pending:
                 start = seq.prefilled  # page-aligned: chunk % ps == 0
-                c_n = min(self._chunk, seq.length - start)
+                # (by blocks: the prompt's whole blocks, and no token)
+                end = (seq.length if self.blocks is None
+                       else self.blocks.prefill_end(seq))
+                c_n = min(self._chunk, end - start)
                 counts["chunks"] += 1
                 counts["prefill_tokens"] += c_n
                 attrs = {}
@@ -2070,7 +2097,7 @@ class InferenceEngineV2:
                                  start=start, tokens=c_n, **attrs):
                     logits = self._run_prefill_chunk(seq, start, c_n,
                                                      self._chunk)
-                    if seq.prefilled >= seq.length:
+                    if seq.prefilled >= seq.length and self.blocks is None:
                         self._emit_sampled(seq, logits, out)
         else:
             for seq in admitted:
@@ -2118,38 +2145,13 @@ class InferenceEngineV2:
         if not active:
             return out
 
-        # grow page tables where the pending token crosses a page boundary;
-        # under pool pressure, preempt running sequences (youngest first) to
-        # recompute later — never crash mid-step (reference: the v2 scheduler
-        # holds requests back under KV pressure rather than failing)
+        if self.blocks is not None:
+            return self.blocks.step(active, out)
+
+        # grow page tables where the pending token crosses a page boundary
         for seq in list(active):
-            if seq.slot < 0:
-                continue  # already preempted this step
-            pos = seq.length - 1  # position the pending token will occupy
-            if pos // ps == len(seq.pages):
-                while self.allocator.free_pages < 1:
-                    victims = [s for s in self._slots
-                               if s is not None and s is not seq]
-                    # evict the lowest priority class first, then the
-                    # most recently admitted (cheapest prefix to
-                    # recompute) — interactive work decodes through
-                    # pool pressure at batch work's expense.  Never
-                    # upward: when every other slotted sequence is MORE
-                    # urgent than the requester, the requester preempts
-                    # ITSELF (mirrors the admission-side victim rule)
-                    victim = (max(victims,
-                                  key=lambda s: (s.priority, s.admit_order))
-                              if victims else seq)
-                    if victim is not seq and victim.priority < seq.priority:
-                        victim = seq
-                    self._preempt(victim)
-                    if victim is seq:
-                        break
-                if seq.slot < 0:
-                    continue
-                page = self.allocator.alloc(1)[0]
-                seq.pages.append(page)
-                self._page_table[seq.slot, len(seq.pages) - 1] = page
+            if seq.slot >= 0:  # (not already preempted this step)
+                self._grow_pages(seq, seq.length - 1)
         active = [s for s in self._slots
                   if s is not None and self._ready_to_decode(s)]
         if not active:
@@ -2247,6 +2249,38 @@ class InferenceEngineV2:
         with self._step_span("step_emit"):
             self._sync_cache_counters()
         return out
+
+    def _grow_pages(self, seq: SequenceState, pos: int) -> None:
+        """Give ``seq`` the page that position ``pos`` — the one its pending
+        token, or its block, will occupy — falls on, where it crosses a page
+        boundary.  Under pool pressure, preempt running sequences (youngest
+        first) to recompute later — never crash mid-step (reference: the v2
+        scheduler holds requests back under KV pressure rather than
+        failing); ``seq`` itself may be the one preempted (``seq.slot`` is
+        then -1)."""
+        if pos // self.block.page_size != len(seq.pages):
+            return
+        while self.allocator.free_pages < 1:
+            victims = [s for s in self._slots
+                       if s is not None and s is not seq]
+            # evict the lowest priority class first, then the
+            # most recently admitted (cheapest prefix to
+            # recompute) — interactive work decodes through
+            # pool pressure at batch work's expense.  Never
+            # upward: when every other slotted sequence is MORE
+            # urgent than the requester, the requester preempts
+            # ITSELF (mirrors the admission-side victim rule)
+            victim = (max(victims,
+                          key=lambda s: (s.priority, s.admit_order))
+                      if victims else seq)
+            if victim is not seq and victim.priority < seq.priority:
+                victim = seq
+            self._preempt(victim)
+            if victim is seq:
+                return
+        page = self.allocator.alloc(1)[0]
+        seq.pages.append(page)
+        self._page_table[seq.slot, len(seq.pages) - 1] = page
 
     def _pull(self, *arrays) -> List[np.ndarray]:
         """Host copies of a decode call's results.  With an expert share

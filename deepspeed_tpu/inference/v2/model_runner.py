@@ -68,6 +68,15 @@ window``.  Keys are stored rotated with the type's base, so a ring's order is
 not needed, only which rows are live.  Its first layer is a run of its own
 with a dense feed-forward part: a served layer's feed-forward part is what
 its parameters are (``_ffn``).
+
+A model that generates by diffusion over blocks (``cfg.block_length``:
+SDAR-MoE) has ``paged_block_pass`` in ``paged_decode``'s place — a block of
+``B`` positions a row, its K/V written in place to its slots of the row's page,
+every one of its queries attending all ``start + B`` positions through the
+paged decode kernel with the block folded into the head axis, and the reveal
+rule on the device (``reveal_tokens``) — and the chunk program under the block
+mask (causal between blocks, bidirectional inside one), which for such a model
+ends at the last layer's K/V write.
 """
 
 from __future__ import annotations
@@ -893,6 +902,13 @@ def paged_prefill_chunk(cfg: TransformerConfig, params, pools,
     # visibility of pooled (previous-chunk) slots: strictly before start
     prev_vis = jnp.arange(S_prev)[None, :] < start  # [1, S_prev]
     causal = jnp.arange(C)[:, None] >= jnp.arange(C)[None, :]  # [C(q), C(k)]
+    blocked = {}
+    if cfg.block_length:
+        # generation by blocks: causal between blocks, bidirectional inside
+        # one (``start`` and the chunk are whole blocks)
+        blocked = {"block": cfg.block_length}
+        causal = (jnp.arange(C)[:, None] | (cfg.block_length - 1)
+                  ) >= jnp.arange(C)[None, :]
 
     # quant + chunked stays on the XLA path: the kernel window would put
     # the chunk's OWN keys through the int8 round-trip while the fallback
@@ -920,8 +936,8 @@ def paged_prefill_chunk(cfg: TransformerConfig, params, pools,
                 q, kp.astype(x.dtype)[None], vp.astype(x.dtype)[None],
                 causal=True, q_offset=start,
                 alibi_slopes=(alibi_slopes(cfg.n_heads)
-                              if cfg.position == "alibi" else None)
-            ).reshape(1, C, -1)
+                              if cfg.position == "alibi" else None),
+                **blocked).reshape(1, C, -1)
             return _attn_out(cfg, layer, x, attn, pools)
         # keys = [previous pooled slots | this chunk]; the pooled half is
         # masked to < start, the chunk half causally within the chunk
@@ -1128,6 +1144,10 @@ def paged_prefill_chunk(cfg: TransformerConfig, params, pools,
     pools = _ring_slots(pools, like)
     if xdec and not final:
         return jnp.zeros((cfg.vocab_size,), x.dtype), pools
+    if cfg.block_length:
+        # prefill yields no token: the program ends at the last layer's K/V
+        # write (no final norm, no head)
+        return jnp.zeros((), x.dtype), pools
     hidden = _norm(x[:, 0 if xdec else n - 1], params["final_norm"]["scale"],
                    params["final_norm"].get("bias"), cfg.norm, cfg.norm_eps)
     logits = logits_fn(cfg, params, hidden[:, None])[0, 0]
@@ -1462,6 +1482,101 @@ def sample_tokens(logits, temps, key, sids, positions) -> jnp.ndarray:
     sampled = jax.vmap(_one)(sids.astype(jnp.int32),
                              positions.astype(jnp.int32), z, temps)
     return jnp.where(temps > 0.0, sampled, greedy)
+
+
+def paged_block_pass(cfg: TransformerConfig, params, pools, ids, masked,
+                     start, page_table, active, n_reveal
+                     ) -> Tuple[jnp.ndarray, jnp.ndarray, Any]:
+    """One pass of generation by diffusion over blocks, for every decode
+    slot: a row's block of ``B = cfg.block_length`` positions ``start ...
+    start + B - 1`` (``start`` a multiple of ``B``, which divides the page: a
+    block never straddles one) runs against the kept K/V of every earlier
+    block and its own fresh K/V, every one of its ``B`` queries attending all
+    ``start + B`` positions.
+
+    The block's K/V are written IN PLACE, to its slots of the row's page: a
+    pass that is not the last is simply overwritten by the next, and the
+    pass over a block with no masked position — the commit — is this same
+    program, whose K/V are the ones kept.  No second cache for K/V in
+    flight.
+
+    ids: [R, B] the block's tokens (what a masked position holds is not
+    read: it embeds ``cfg.mask_token_id``); masked: [R, B] bool; start: [R];
+    page_table: [R, MP]; active: [R] bool (a row that is not active writes
+    to the trash page and attends nothing); n_reveal: [R] masked positions
+    to reveal.  Returns ``(ids, masked)`` after the reveal
+    (``reveal_tokens``: the logits stay on the device) and the pools.
+
+    The paged decode kernel serves as it is with the block folded into the
+    head axis: a block's queries see the same pages at the same length, so
+    they are ``B x G`` query rows of one K/V head."""
+    R, Bk = ids.shape
+    ps, trash = _page_geometry(pools)
+    tok = jnp.where(masked, jnp.int32(cfg.mask_token_id), ids)
+    x = params["embed"]["tok"][tok]  # [R, B, H]
+    pos = start[:, None] + jnp.arange(Bk)[None]  # [R, B]
+    page_idx = jnp.where(
+        active,
+        page_table[jnp.arange(R),
+                   jnp.minimum(start // ps, page_table.shape[1] - 1)],
+        trash)
+    at = (jnp.broadcast_to(page_idx[:, None], pos.shape), pos % ps)
+    last = start + Bk - 1  # the block's last position: what every query sees
+    S = page_table.shape[1] * ps
+    vis = jnp.broadcast_to((jnp.arange(S)[None] <= last[:, None])[:, None],
+                           (R, Bk, S))
+    KVH, G = cfg.kv_heads, cfg.n_heads // cfg.kv_heads
+    use_kernel = _use_paged_kernel()
+
+    def layer_fn(layer, l, x, pools):
+        q, k, v = attn_qkv(cfg, layer, x, pos)  # [R, B, NH | KVH, D]
+        pools = _pool_write(pools, l, at, k, v)
+        if use_kernel:
+            from ...ops.pallas.paged_attention import paged_decode_attention
+
+            fold = lambda a: a.reshape(R, Bk, KVH, G, -1).transpose(  # noqa: E731
+                0, 2, 1, 3, 4)
+            o = paged_decode_attention(
+                fold(q).reshape(R, KVH * Bk * G, -1), pools["k"], pools["v"],
+                page_table, last, layer=l, active=active)
+            attn = o.reshape(R, KVH, Bk, G, -1).transpose(
+                0, 2, 1, 3, 4).reshape(R, Bk, -1)
+        else:
+            attn = _gather_window_attend(cfg, q, pools, l, page_table, pos,
+                                         vis)
+        return _attn_out(cfg, layer, x, attn, pools)
+
+    x, pools = _scan_layers(cfg, params, pools, x,
+                            _Forms("the block pass", attn=layer_fn))
+    hidden = _norm(x, params["final_norm"]["scale"],
+                   params["final_norm"].get("bias"), cfg.norm, cfg.norm_eps)
+    ids, masked = reveal_tokens(logits_fn(cfg, params, hidden), ids, masked,
+                                n_reveal)
+    return ids, masked, pools
+
+
+def reveal_tokens(logits, ids, masked, n_reveal
+                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The reveal rule of a block pass, on the device (greedy, static
+    low-confidence remasking): at each masked position the arg-max token of
+    the logits AT that position (no shift) and its softmax probability as
+    confidence; a row's ``n_reveal`` masked positions of highest confidence
+    (the lower position on a tie) take their token and are never masked
+    again.  logits: [R, B, V]; ids, masked: [R, B]; n_reveal: [R].  Returns
+    the block's ``(ids, masked)`` after the pass — ``[R, B]`` integers are
+    what crosses the link, never ``[R x B, V]`` logits."""
+    z = logits.astype(jnp.float32)
+    top = jnp.argmax(z, axis=-1).astype(jnp.int32)
+    # softmax(z)[argmax] = 1 / sum(exp(z - max))
+    conf = 1.0 / jnp.sum(jnp.exp(z - jnp.max(z, axis=-1, keepdims=True)),
+                         axis=-1)
+    conf = jnp.where(masked, conf, -1.0)
+    i = jnp.arange(ids.shape[1])
+    ahead = (conf[:, None, :] > conf[:, :, None]) | (
+        (conf[:, None, :] == conf[:, :, None]) & (i[None, None, :]
+                                                  < i[None, :, None]))
+    reveal = masked & (jnp.sum(ahead, axis=-1) < n_reveal[:, None])
+    return jnp.where(reveal, top, ids), masked & jnp.logical_not(reveal)
 
 
 def paged_multi_decode(cfg: TransformerConfig, params, pools,

@@ -770,6 +770,11 @@ class SequenceState:
     #: standalone): the cross-replica correlation key — uids are
     #: per-engine and collide across a fleet
     trace_id: Optional[str] = None
+    #: generation by diffusion over blocks (``block_diffusion.BlockPolicy``):
+    #: the request's passes a block (0 = the block length) and the block in
+    #: progress (None between blocks, and for every other model)
+    denoising_steps: int = 0
+    block: Any = None
 
     @property
     def length(self) -> int:

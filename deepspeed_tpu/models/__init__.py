@@ -11,6 +11,7 @@ from .mimo_v2 import mimo_v2_config, mimo_v2_model
 from .mistral4 import mistral4_config, mistral4_model
 from .mixtral import mixtral_config, mixtral_model
 from .phi4_flash import phi4_flash_config, phi4_flash_model
+from .sdar_moe import sdar_moe_config, sdar_moe_model
 from .solar_open2 import solar_open2_config, solar_open2_model
 from .transformer import TransformerConfig
 
@@ -23,4 +24,5 @@ __all__ = ["bert_config", "bert_model", "gpt2_config", "gpt2_model",
            "solar_open2_model", "lfm2_moe_config", "lfm2_moe_model",
            "phi4_flash_config", "phi4_flash_model", "mistral4_config",
            "mistral4_model", "mimo_v2_config", "mimo_v2_model",
+           "sdar_moe_config", "sdar_moe_model",
            "TransformerConfig"]

@@ -204,6 +204,13 @@ class TransformerConfig:
     conv_taps: int = 3
     #: RMSNorm over head_dim on each q head and each k head, before rotary
     qk_norm: bool = False
+    #: generation by diffusion over blocks (``inference/v2/block_diffusion``):
+    #: positions come in blocks of this many at absolute multiples of it, the
+    #: attention mask is causal between blocks and bidirectional inside one,
+    #: and a position not yet decided holds ``mask_token_id``.  0: one token a
+    #: step under the causal mask.  A power of two that divides the page
+    block_length: int = 0
+    mask_token_id: int = 0
     #: attention output gate: ``wo (attn * sigmoid(wg h))``
     attn_gate: bool = False
     #: delta-rule linear-attention layers (type "kda"): heads x head_dim for

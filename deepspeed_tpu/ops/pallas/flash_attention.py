@@ -46,11 +46,13 @@ def _dot(a, b, contract):
 
 
 def _scores(q, k, sl_ref, head, rows, cols, *, sm_scale, causal, alibi,
-            seq_q, seq_k, window=0, k_first=None):
+            seq_q, seq_k, window=0, k_first=None, block=0):
     """Masked fp32 scores [bq, bk] of one tile + the validity mask.
     ``rows``/``cols`` are absolute positions; rows past ``seq_q`` and cols
     past ``seq_k`` are block padding.  ``window``: a row sees itself and the
-    ``window - 1`` columns before it, none before ``k_first``."""
+    ``window - 1`` columns before it, none before ``k_first``.  ``block`` (a
+    power of two): the causal mask is between blocks of that many positions
+    and a row sees the whole of its own block."""
     s = _dot(q, k, ((1,), (1,))) * sm_scale
     if alibi:
         # ALiBi from block indices: no [S, S] bias materialization
@@ -59,7 +61,7 @@ def _scores(q, k, sl_ref, head, rows, cols, *, sm_scale, causal, alibi,
     if seq_q is not None:
         valid = valid & (rows < seq_q)
     if causal:
-        valid = valid & (rows >= cols)
+        valid = valid & ((rows | (block - 1) if block else rows) >= cols)
     if window:
         valid = valid & (rows - cols < window) & (cols >= k_first)
     return jnp.where(valid, s, NEG_INF), valid
@@ -70,7 +72,7 @@ def _scores(q, k, sl_ref, head, rows, cols, *, sm_scale, causal, alibi,
 # ---------------------------------------------------------------------------
 def _fwd_kernel(off_ref, sl_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, sm_scale, causal, seq_k, alibi,
-                window=0, sink=False):
+                window=0, sink=False, block=0):
     """Grid (B*NH, nq, nk), k innermost: one [bq, bk] score tile per step,
     the online-softmax state (m, l, acc) carried in VMEM scratch across
     the k axis — VMEM holds tiles, never a whole sequence."""
@@ -100,7 +102,8 @@ def _fwd_kernel(off_ref, sl_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         cols = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         s, _ = _scores(q_ref[0], k_ref[0], sl_ref, head, rows, cols, sm_scale=sm_scale, causal=causal, alibi=alibi,
                        seq_q=None, seq_k=seq_k, window=window,
-                       k_first=off_ref[1] if window else None)
+                       k_first=off_ref[1] if window else None,
+                       **({"block": block} if block else {}))
         m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -131,14 +134,18 @@ def _fwd_kernel(off_ref, sl_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 def _fwd(q, k, v, alibi_arr, offset_arr, sm_scale, causal, block_q, block_k,
-         valid_k=None, q_per_kv=1, alibi=False, window=0, sink=False):
+         valid_k=None, q_per_kv=1, alibi=False, window=0, sink=False,
+         block=0):
     """q: [B*NH, Sq, D]; k/v: [B*KVH, Sk, D] with NH = KVH * q_per_kv —
     GQA reads each kv head once via the index map instead of materializing
     the repeat (the reference's kv-replication copy).  ``alibi_arr``:
     [B*NH] fp32 slopes and ``offset_arr``: [1] int32 query offset (with a
     ``window``, [2]: the offset and the first key a query may see), both
     scalar memory.  ``v`` may be narrower than ``q`` and ``k``: the output
-    has its width.  ``sink``: ``alibi_arr`` holds a sink a head instead."""
+    has its width.  ``sink``: ``alibi_arr`` holds a sink a head instead.
+    ``block``: the block mask (``_scores``); the offset and the q tile are
+    whole blocks, so a tile's last row bounds what its rows see as it does
+    under the causal mask and the same k tiles are skipped."""
     bh, seq_q, d = q.shape
     dv = v.shape[2]
     seq_k = k.shape[1]
@@ -158,7 +165,8 @@ def _fwd(q, k, v, alibi_arr, offset_arr, sm_scale, causal, block_q, block_k,
         functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
                           seq_k=valid_k, alibi=alibi,
                           sink=sink,
-                          **({"window": window} if window else {})),
+                          **({"window": window} if window else {}),
+                          **({"block": block} if block else {})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, pl.cdiv(seq_q, bq), nk),
@@ -385,7 +393,7 @@ def flash_attention(q, k, v, causal: bool = True, segment_mask=None,
                     block_q: int = 512, block_k: int = 512, impl: str = "pallas",
                     bwd_block_q: int = 0, bwd_block_k: int = 0,
                     alibi_slopes=None, q_offset=None, window: int = 0,
-                    k_first=None, sink=None):
+                    k_first=None, sink=None, block: int = 0):
     """Public API on [B, S, NH, D] (matching models/transformer.py).
 
     GQA-native: k/v may carry KVH < NH heads (NH % KVH == 0) — each kv
@@ -422,6 +430,12 @@ def flash_attention(q, k, v, causal: bool = True, segment_mask=None,
     and ``sink`` ``[NH]`` float32 is one more column of each head's softmax
     that has no value — it joins the running maximum and the denominator, so
     a row's probabilities sum to less than 1.
+
+    ``block`` (with ``q_offset``, forward-only; a power of two): the mask is
+    causal between blocks of ``block`` positions at absolute multiples of it
+    and bidirectional inside one — query ``i`` sees key ``j`` iff ``j //
+    block <= i // block`` (generation by diffusion over blocks).  The offset
+    and the q tile must be whole blocks.
     """
     B, Sq, NH, D = q.shape
     KVH, DV = k.shape[2], v.shape[3]
@@ -496,6 +510,11 @@ def flash_attention(q, k, v, causal: bool = True, segment_mask=None,
     if window and (q_offset is None or not causal):
         raise ValueError("window: the mask exists in the causal forward-only "
                          "kernel (q_offset) alone")
+    if block and (q_offset is None or not causal or window
+                  or block & (block - 1) or min(block_q, Sq) % block):
+        raise ValueError("block: the block mask exists in the causal "
+                         "forward-only kernel (q_offset) alone, without a "
+                         "window, for a power of two that divides the q tile")
     if q_offset is not None:
         # forward-only inference path (no custom VJP)
         off = jnp.asarray(q_offset, jnp.int32).reshape(1)
@@ -506,7 +525,8 @@ def flash_attention(q, k, v, causal: bool = True, segment_mask=None,
                       causal, block_q, block_k, Sk, q_per_kv,
                       alibi=alibi_slopes is not None,
                       sink=sink is not None,
-                      **({"window": int(window)} if window else {}))
+                      **({"window": int(window)} if window else {}),
+                      **({"block": int(block)} if block else {}))
     else:
         out = _flash_bhsd(qh, kh, vh, sl, scale, causal, block_q, block_k,
                           Sq, Sk, q_per_kv, bwd_block_q, bwd_block_k,

@@ -1,0 +1,70 @@
+"""The two attention kernels at the SDAR-30B-A3B cell's call shapes, compiled
+for a described v5e with no chip: what interpret mode accepts Mosaic may
+refuse (VMEM for 128 query rows a grid step's row, a table of 256 x 320
+entries in scalar memory, the block mask's ``|`` on a tile of positions).
+
+``dstpu_paged_decode`` at 256 rows x (4 positions x 32 heads = 128 query rows,
+32 of them a K/V head) x 320 pages of 16 over a pool of 4 K/V heads of 128;
+``dstpu_flash_fwd`` under the block mask at a chunk of 2,048 against a window
+of 4,096 positions, 32 / 4 heads of 128.  It compiles; it does not run.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import deepspeed_tpu.utils.platform as plat
+from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+from deepspeed_tpu.ops.pallas.paged_attention import paged_decode_attention
+
+ROWS, BLOCK, NH, KVH, D, PAGES, PS = 256, 4, 32, 4, 128, 320, 16
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One device of a described v5e:2x2 (never at import: a machine whose
+    libtpu cannot describe one skips the file)."""
+    try:
+        from jax.experimental import topologies
+
+        dev = topologies.get_topology_desc("v5e:2x2", "tpu").devices[0]
+    except Exception as e:  # noqa: BLE001 - whatever libtpu raises here
+        pytest.skip(f"no v5e topology description: {e}")
+    return jax.sharding.SingleDeviceSharding(dev)
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """Kernels compiled, not interpreted; nothing compiled here is cached
+    where a chip run would look for it."""
+    monkeypatch.setattr(plat, "platform", lambda: "tpu")
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield
+    jax.config.update("jax_enable_compilation_cache", cache)
+
+
+def _arr(v5e, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+
+def test_paged_decode_with_a_block_folded_into_its_heads(v5e,
+                                                         compiled_kernels):
+    pool = _arr(v5e, (6, 1025, PS, KVH * D), jnp.bfloat16)
+    f = jax.jit(lambda q, k, v, table, last, act: paged_decode_attention(
+        q, k, v, table, last, layer=3, active=act))
+    compiled = f.lower(
+        _arr(v5e, (ROWS, BLOCK * NH, D), jnp.bfloat16), pool, pool,
+        _arr(v5e, (ROWS, PAGES), jnp.int32), _arr(v5e, (ROWS,), jnp.int32),
+        _arr(v5e, (ROWS,), jnp.bool_)).compile()
+    assert "dstpu_paged_decode" in compiled.as_text()
+
+
+def test_flash_under_the_block_mask_at_a_chunk(v5e, compiled_kernels):
+    compiled = jax.jit(lambda q, k, v, off: flash_attention(
+        q, k, v, causal=True, q_offset=off, block=BLOCK)).lower(
+        _arr(v5e, (1, 2048, NH, D), jnp.bfloat16),
+        _arr(v5e, (1, 4096, KVH, D), jnp.bfloat16),
+        _arr(v5e, (1, 4096, KVH, D), jnp.bfloat16),
+        _arr(v5e, (), jnp.int32)).compile()
+    assert "dstpu_flash_fwd" in compiled.as_text()

@@ -228,12 +228,25 @@ def main() -> int:
     def no_pool(dims):
         return tuple(dims) in weights or (
             dims[-1] == V and V > max(a.shape[-1] for a in pools.values()))
-    results = {"decode": report(
-        f"decode [{B} rows x {MP} pages]",
-        engine._decode.lower(params, pools, arr((B,), i32), arr((B,), i32),
-                             arr((B, MP), i32), arr((B,), jnp.bool_),
-                             arr((B,), jnp.float32), arr((B,), i32), key),
-        pool_bytes, layer_pool_bytes, no_pool)}
+    if engine.blocks is not None:
+        # a model that generates by blocks has the block program in the
+        # decode program's place: max_seqs rows of block_length positions
+        Bk = engine.cfg.block_length
+        results = {"block_pass": report(
+            f"block pass [{B} rows x {Bk} positions x {MP} pages]",
+            engine.blocks._program.lower(
+                params, pools, arr((B, Bk), i32), arr((B, Bk), jnp.bool_),
+                arr((B,), i32), arr((B, MP), i32), arr((B,), jnp.bool_),
+                arr((B,), i32)),
+            pool_bytes, layer_pool_bytes, no_pool)}
+    else:
+        results = {"decode": report(
+            f"decode [{B} rows x {MP} pages]",
+            engine._decode.lower(
+                params, pools, arr((B,), i32), arr((B,), i32),
+                arr((B, MP), i32), arr((B,), jnp.bool_),
+                arr((B,), jnp.float32), arr((B,), i32), key),
+            pool_bytes, layer_pool_bytes, no_pool)}
     # a stack with a cross-decoder is handed the whole table row (one query
     # reads it through the decode kernel): one shape, and a program of its
     # own for the chunks that are not a prompt's last
