@@ -258,7 +258,6 @@ class BlockPolicy:
         n_masked = int(self.masked[act].sum())
 
         e._decode_steps += 1
-        e._step_parts.add("block_pass")
         counts["decode_rows"] += len(seqs)
         # what the paged kernel reads in one layer call: every position
         # through the block's last, once a row (its B queries share them)
@@ -272,8 +271,8 @@ class BlockPolicy:
                     jnp.asarray(self.start), jnp.asarray(e._page_table),
                     jnp.asarray(act), jnp.asarray(self.n_reveal))
             with e._step_span("dispatch", parent="block_pass"):
-                new_ids, new_masked, e._pools = self._program(
-                    e.params, e._pools, *args)
+                new_ids, new_masked, e._pools = e._dispatch(
+                    "block_pass", self._program, *args)
             with e._step_span("device_wait", parent="block_pass",
                               what="block_tokens"):
                 # THE designed sync of a pass: [R, B] token ids and [R, B]

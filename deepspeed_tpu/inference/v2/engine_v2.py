@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...compile.deep_frame import first_call_beneath
 from ...models.layer_types import (gqa_shape, layers_of, page_layers,
                                    page_leaves, served_runs, state_leaves)
 from ...models.transformer import TransformerConfig
@@ -505,6 +506,8 @@ class InferenceEngineV2:
         # components — a compile during a step that introduced no new
         # component after warmup is a steady-state recompilation
         self._step_parts: set = set()
+        #: the parts ``_dispatch`` has seen, over the engine's life
+        self._lowered_parts: set = set()
         #: ``step()`` calls so far: the ``step`` every span and event of a
         #: step carries (``_decode_steps`` counts decode dispatches only)
         self._step_id = 0
@@ -1935,6 +1938,15 @@ class InferenceEngineV2:
             seq.finish_reason = reason
             self._retire(seq)
 
+    def _dispatch(self, part, program, *args):
+        """``program(params, pools, *args)`` as the part ``part`` of this
+        step (what the recompile sentinel hears of).  A part's first
+        dispatch traces and lowers its program: that one runs beneath the
+        deep frame, no later one (compile/deep_frame.py says why)."""
+        self._step_parts.add(part)
+        return first_call_beneath(self._lowered_parts, part, program,
+                                  self.params, self._pools, *args)
+
     def _run_prefill_chunk(self, seq: SequenceState, start: int, c_n: int,
                            C: int):
         """One start-offset prefill call covering tokens
@@ -1963,15 +1975,15 @@ class InferenceEngineV2:
         prev = self._page_table[seq.slot][:min(
             b, self.block.max_pages_per_seq)]
         final = not self._xdec or start + c_n >= seq.length
-        self._step_parts.add(("prefill_chunk", C, int(prev.shape[0]))
-                             + (() if final else ("part",)))
+        part = (("prefill_chunk", C, int(prev.shape[0]))
+                + (() if final else ("part",)))
         args = (jnp.asarray(ids), jnp.asarray(rows), jnp.asarray(prev),
                 jnp.int32(start), jnp.int32(c_n))
         if self._state:  # the state is carried in the sequence's slot
             args += (jnp.int32(seq.slot),)
         program = self._prefill_chunk if final else self._prefill_chunk_part
         with self._step_span("dispatch", parent="prefill"):
-            logits, self._pools = program(self.params, self._pools, *args)
+            logits, self._pools = self._dispatch(part, program, *args)
         seq.prefilled = start + c_n
         self._register_pages(seq)
         return logits
@@ -2127,15 +2139,14 @@ class InferenceEngineV2:
                 rows = np.full((bucket // ps,), self.block.trash_page,
                                np.int32)
                 rows[:len(seq.pages)] = seq.pages
-                self._step_parts.add(("prefill", bucket))
                 counts["chunks"] += 1
                 counts["prefill_tokens"] += n
                 with self._phase("prefill", self._m_prefill_h, uid=seq.uid,
                                  tokens=n, bucket=bucket):
                     args = (jnp.asarray(ids), jnp.asarray(rows), jnp.int32(n))
                     with self._step_span("dispatch", parent="prefill"):
-                        logits, self._pools = self._prefill(
-                            self.params, self._pools, *args)
+                        logits, self._pools = self._dispatch(
+                            ("prefill", bucket), self._prefill, *args)
                     seq.prefilled = n
                     self._register_pages(seq)
                     self._emit_sampled(seq, logits, out)
@@ -2191,7 +2202,6 @@ class InferenceEngineV2:
         elif decode_seqs:
             last, pos, act, temps, sids = self._decode_inputs(decode_seqs)
             self._decode_steps += 1
-            self._step_parts.add("decode")
             counts["decode_rows"] += len(decode_seqs)
             self._note_kv_blocks(np.where(act, pos + 1, 0))
             self._note_state_rows(np.where(act, pos + 1, 0))
@@ -2201,8 +2211,8 @@ class InferenceEngineV2:
                         jnp.asarray(self._page_table), jnp.asarray(act),
                         jnp.asarray(temps), jnp.asarray(sids))
                 with self._step_span("dispatch", parent="decode"):
-                    tokens, self._pools = self._decode(
-                        self.params, self._pools, *args, self._sample_key)
+                    tokens, self._pools = self._dispatch(
+                        "decode", self._decode, *args, self._sample_key)
                 # restore-prefetch rides the in-flight decode: the host
                 # walks queued prefixes into the host tier while the
                 # device decodes, and the H2D scatter chains behind the
@@ -2457,7 +2467,6 @@ class InferenceEngineV2:
             budg[seq.slot] = budgets[seq.uid]
 
         self._decode_steps += 1
-        self._step_parts.add(("multi_decode", k))
         self._step_counts["decode_rows"] += len(seqs)
         warm = k in self._warm_horizons
         self._warm_horizons.add(k)
@@ -2469,8 +2478,9 @@ class InferenceEngineV2:
                     jnp.asarray(temps), jnp.asarray(eos), jnp.asarray(budg),
                     jnp.asarray(sids))
             with self._step_span("dispatch", parent="multi_decode"):
-                toks, produced, self._pools = self._multi(
-                    self.params, self._pools, *args, self._sample_key, k)
+                toks, produced, self._pools = self._dispatch(
+                    ("multi_decode", k), self._multi, *args,
+                    self._sample_key, k)
             # restore-prefetch rides the in-flight scan, like K=1
             self._prefetch_restores()
             with self._step_span("device_wait", parent="multi_decode",
@@ -2609,7 +2619,6 @@ class InferenceEngineV2:
             pos[seq.slot] = seq.length - 1
             act[seq.slot] = True
             nv[seq.slot] = len(row)
-        self._step_parts.add(("verify", W))
         self._step_counts["decode_rows"] += len(seqs)
         with self._phase("spec_verify", self._m_spec_verify_h,
                          batch=len(seqs), width=W):
@@ -2617,8 +2626,8 @@ class InferenceEngineV2:
                     jnp.asarray(self._page_table), jnp.asarray(act),
                     jnp.asarray(nv))
             with self._step_span("dispatch", parent="spec_verify"):
-                greedy, self._pools = self._verify(
-                    self.params, self._pools, *args)
+                greedy, self._pools = self._dispatch(
+                    ("verify", W), self._verify, *args)
             with self._step_span("device_wait", parent="spec_verify",
                                  what="decode_tokens"):
                 # dstpu-lint: allow[host-sync] one [B,W] int32 pull per

@@ -35,6 +35,7 @@ import numpy as np
 import optax
 
 from .. import comm
+from ..compile.deep_frame import first_call_beneath, under_deep_frame
 from ..parallel.mesh import MeshTopology
 from ..telemetry.compile_sentinel import (expect_recompile, note_program,
                                           publish_setup_seconds, setup_span)
@@ -1458,6 +1459,10 @@ class DeepSpeedTPUEngine:
         # announce it so the sentinel does not flag a steady-state recompile
         expect_recompile("engine._compile_steps")
         self._step_program_noted = False
+        #: the programs built here that have been called: a program's first
+        #: call traces and lowers it, beneath the deep frame
+        #: (compile/deep_frame.py)
+        self._lowered_programs: set = set()
         donate = dict(donate_argnums=(0,))
         self._micro_step = jax.jit(self._micro_step_body, **donate)
         self._eval_fn = None
@@ -1895,13 +1900,16 @@ class DeepSpeedTPUEngine:
                         # device-resident (no sync): moe_stats() reads it
                         if self._moe_acc is None:
                             self._moe_acc = self._moe_zeros()
-                        *out, self._moe_acc = self._train_batch(
-                            self.state, batch, self._next_rng(),
-                            self._moe_acc)
+                        *out, self._moe_acc = first_call_beneath(
+                            self._lowered_programs, "train_batch",
+                            self._train_batch, self.state, batch,
+                            self._next_rng(), self._moe_acc)
                         self._moe_steps += 1
                     else:
-                        out = self._train_batch(self.state, batch,
-                                                self._next_rng())
+                        out = first_call_beneath(
+                            self._lowered_programs, "train_batch",
+                            self._train_batch, self.state, batch,
+                            self._next_rng())
                     if getattr(self, "_numerics_fused", False):
                         # stats stay device-resident (no sync): pulled at
                         # the steps_per_print boundary by _report_telemetry
@@ -1956,7 +1964,9 @@ class DeepSpeedTPUEngine:
         self.timers(FORWARD_GLOBAL_TIMER).start()
         with span("forward", cat="train", micro_step=self.micro_steps), \
                 self.topology.mesh:
-            self.state, loss = self._micro_step(self.state, batch, self._next_rng())
+            self.state, loss = first_call_beneath(
+                self._lowered_programs, "micro_step", self._micro_step,
+                self.state, batch, self._next_rng())
         self._acc_dirty = True
         self.timers(FORWARD_GLOBAL_TIMER).stop()
         self._cached_loss = loss
@@ -1995,7 +2005,9 @@ class DeepSpeedTPUEngine:
                         self._apply_step_offload()
                     else:
                         with self.topology.mesh:
-                            self.state = self._apply_step(self.state)
+                            self.state = first_call_beneath(
+                                self._lowered_programs, "apply_step",
+                                self._apply_step, self.state)
                         self._repin_opt_state()
             except Exception as e:
                 dump_on_exception("engine.step", e)
@@ -2027,7 +2039,9 @@ class DeepSpeedTPUEngine:
         t0 = time.perf_counter()
         with span("eval_batch", cat="eval"):
             with self.topology.mesh:
-                out = self._eval_fn(self.state.params, batch)
+                out = first_call_beneath(
+                    self._lowered_programs, "eval", self._eval_fn,
+                    self.state.params, batch)
         gp = self.telemetry.goodput if self.telemetry is not None else None
         if gp is not None:
             # eval wall time is badput in the goodput ledger (dispatch
@@ -2234,8 +2248,9 @@ class DeepSpeedTPUEngine:
             # announce it so the sentinel doesn't blame the next step
             expect_recompile("cost_analysis")
             with self.topology.mesh:
-                costs = cost_analysis_of(self._train_batch, self.state,
-                                         batch, jax.random.PRNGKey(0))
+                costs = under_deep_frame(
+                    cost_analysis_of, self._train_batch, self.state, batch,
+                    jax.random.PRNGKey(0))
             self._flops_per_step = float(costs.get("flops", 0.0))
         return self._flops_per_step
 
@@ -2252,7 +2267,8 @@ class DeepSpeedTPUEngine:
         if getattr(self, "_moe_counters", False):
             args += (self._moe_acc,)
         with self.topology.mesh:
-            mem = self._train_batch.lower(*args).compile().memory_analysis()
+            mem = under_deep_frame(self._train_batch.lower,
+                                   *args).compile().memory_analysis()
         note_program(
             "_train_batch_body",
             remat_policy=(getattr(mc, "remat_policy", None)

@@ -205,4 +205,8 @@ def main() -> int:
 
 
 if __name__ == "__main__":
-    sys.exit(main())
+    # the programs are lowered here, outside any engine call: beneath the
+    # frame an engine makes their first dispatch under (compile/deep_frame.py)
+    from deepspeed_tpu.compile.deep_frame import under_deep_frame
+
+    sys.exit(under_deep_frame(main))
