@@ -40,8 +40,6 @@ from __future__ import annotations
 
 from typing import Any, Dict, List, Optional
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 from ...telemetry import get_registry
@@ -49,6 +47,7 @@ from ...telemetry.compile_sentinel import \
     expect_recompile as sentinel_expect_recompile
 from ...telemetry.spans import record_event
 from .model_runner import paged_block_pass, paged_gather_pages
+from .packed_inputs import PackedProgram
 from .ragged import SequenceState
 
 #: what the policy counts, cumulative (``decode_stats()``) and a step (the
@@ -135,7 +134,7 @@ class BlockPolicy:
         def _block_pass(*a):  # the program's name in a device trace
             return paged_block_pass(cfg, *a)
 
-        self._program = jax.jit(_block_pass, donate_argnums=(1,))
+        self._program = PackedProgram(_block_pass)
         engine._dstats.update(dict.fromkeys(COUNTERS, 0))
         reg = get_registry()
         self._m_row_passes = reg.counter(
@@ -267,12 +266,10 @@ class BlockPolicy:
         with e._phase("decode", e._m_decode_h, batch=len(seqs)), \
                 e._step_span("block_pass", parent="decode", rows=len(seqs),
                              commit_rows=n_commit, masked_positions=n_masked):
-            args = (jnp.asarray(self.ids), jnp.asarray(self.masked),
-                    jnp.asarray(self.start), jnp.asarray(e._page_table),
-                    jnp.asarray(act), jnp.asarray(self.n_reveal))
-            with e._step_span("dispatch", parent="block_pass"):
-                new_ids, new_masked, e._pools = e._dispatch(
-                    "block_pass", self._program, *args)
+            new_ids, new_masked, e._pools = e._dispatch(
+                "block_pass", self._program,
+                (self.ids, self.masked, self.start, e._page_table, act,
+                 self.n_reveal), phase="block_pass")
             with e._step_span("device_wait", parent="block_pass",
                               what="block_tokens"):
                 # THE designed sync of a pass: [R, B] token ids and [R, B]

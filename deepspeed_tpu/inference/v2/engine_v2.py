@@ -50,6 +50,7 @@ from .model_runner import (pad_pages_pow2, paged_copy_page, paged_decode,
                            paged_gather_pages, paged_multi_decode,
                            paged_prefill, paged_prefill_chunk,
                            paged_scatter_pages, paged_verify, sample_tokens)
+from .packed_inputs import PackedProgram, pack_inputs
 from .ragged import (PRIORITY_NORMAL, BlockAllocator, KVBlockConfig,
                      KVPageBundle, PagedKVCache, PrefixCache, RejectedError,
                      SequenceState, StateSlots)
@@ -201,7 +202,8 @@ class RaggedRequest:
 
 #: what a ``serve_step`` span carries at its end
 _STEP_COUNTS = ("chunks", "prefill_tokens", "decode_rows",
-                "decode_kv_blocks", "admitted", "preempted", "queue_len")
+                "decode_kv_blocks", "admitted", "preempted", "queue_len",
+                "input_transfers")
 
 
 #: a decoding row whose context is over this many tokens is a long one
@@ -413,19 +415,19 @@ class InferenceEngineV2:
             # stream keeps its noise through preemption / migration
             return sample_tokens(logits, temps, key, sids, pos + 1), pools
 
-        self._decode = jax.jit(_decode_and_sample, donate_argnums=(1,))
-        self._prefill = jax.jit(
-            lambda *a: paged_prefill(cfg, *a), donate_argnums=(1,))
-        self._prefill_chunk = jax.jit(
-            lambda *a: paged_prefill_chunk(cfg, *a), donate_argnums=(1,))
+        # every serving program takes its host inputs packed
+        # (packed_inputs.py): ``_dispatch`` moves them across in one piece
+        self._decode = PackedProgram(_decode_and_sample, rest=1)
+        self._prefill = PackedProgram(lambda *a: paged_prefill(cfg, *a))
+        self._prefill_chunk = PackedProgram(
+            lambda *a: paged_prefill_chunk(cfg, *a))
         #: a stack with a cross-decoder (a ``dattn`` layer, whose pages the
         #: layers after it read) runs that decoder for a prompt's last token
         #: only: a chunk that is not the prompt's last stops at that layer's
         #: K/V write, in a program of its own
         self._xdec = layers_of(cfg, "dattn") > 0
-        self._prefill_chunk_part = jax.jit(
-            lambda *a: paged_prefill_chunk(cfg, *a, final=False),
-            donate_argnums=(1,))
+        self._prefill_chunk_part = PackedProgram(
+            lambda *a: paged_prefill_chunk(cfg, *a, final=False))
         #: what a window layer's ring holds of a context, at most
         self._window = cfg.sliding_window if layers_of(cfg, "swa") else 0
         #: a stack of full layers with pages and window layers with rings
@@ -461,7 +463,7 @@ class InferenceEngineV2:
                 return (jnp.argmax(logits.astype(jnp.float32), axis=-1)
                         .astype(jnp.int32), pools)
 
-            self._verify = jax.jit(_verify_and_greedy, donate_argnums=(1,))
+            self._verify = PackedProgram(_verify_and_greedy)
         # fused multi-step decode (docs/SERVING.md "Multi-step decode"):
         # one designed exclusive decode path at a time — a configured
         # proposer owns the decode loop, so the horizon stands down
@@ -493,8 +495,7 @@ class InferenceEngineV2:
             # horizon is static (the scan length); the engine only ever
             # dispatches halving-chain values, so the compiled-shape
             # set stays O(log decode_horizon)
-            self._multi = jax.jit(_multi_fn, donate_argnums=(1,),
-                                  static_argnums=(11,))
+            self._multi = PackedProgram(_multi_fn, rest=2, static_rest=(1,))
         else:
             self._multi = None
         # request lifecycle bookkeeping: enqueue/first-token stamps + the
@@ -666,13 +667,12 @@ class InferenceEngineV2:
         self._m_prefill_h = reg.histogram(
             "deepspeed_tpu_serving_prefill_seconds",
             "host wall time of one prefill call (a chunk or a whole "
-            "prompt): input building (its jnp.int32 scalars are tiny device "
-            "programs that wait behind the programs in flight), uploads and "
+            "prompt): input building, the one transfer of the inputs and "
             "an asynchronous dispatch; a prompt's last call also waits for "
             "the device and samples the first token")
         self._m_decode_h = reg.histogram(
             "deepspeed_tpu_serving_decode_seconds",
-            "host wall time of one decode dispatch: input upload, "
+            "host wall time of one decode dispatch: the inputs' transfer, "
             "dispatch, restore-prefetch and the wait for the tokens (the "
             "whole horizon when decode_horizon > 1)")
         self._m_step_phase_h = reg.histogram(
@@ -863,11 +863,8 @@ class InferenceEngineV2:
         ``device_wait`` is the device's or the runtime's.  Only decode-only
         steps are rated, and in those nothing is in flight when the inputs
         are uploaded and the program is called, so a stall anywhere else is
-        the host's.  (With programs in flight a phase's self time is not
-        all host work: a chunk call's ``jnp.int32`` scalars each run a tiny
-        device program that waits its turn behind them, most of ``prefill``
-        self in a chunked-prefill step, which is one reason such steps are
-        not rated.)"""
+        the host's.  (A step that carries chunks is several programs long
+        and is not rated.)"""
         ph, counts = self._phase_s, self._step_counts
         total = ph["serve_step"]
         self_ms = {(n + " self" if n in self._child_s else n):
@@ -1938,14 +1935,28 @@ class InferenceEngineV2:
             seq.finish_reason = reason
             self._retire(seq)
 
-    def _dispatch(self, part, program, *args):
-        """``program(params, pools, *args)`` as the part ``part`` of this
-        step (what the recompile sentinel hears of).  A part's first
-        dispatch traces and lowers its program: that one runs beneath the
-        deep frame, no later one (compile/deep_frame.py says why)."""
+    def _dispatch(self, part, program: PackedProgram, inputs, *rest, phase):
+        """``program`` over ``(params, pools, *inputs, *rest)`` as the part
+        ``part`` of this step (what the recompile sentinel hears of), the
+        call itself in a ``dispatch`` span of the phase ``phase``.
+        ``inputs`` is what the host built for this call, numpy arrays and
+        numpy scalars: they cross the link here, packed into one new array
+        and moved in ONE transfer (``input_transfers`` on the step), and
+        nowhere else.  Being a copy, what crosses is out of reach of a
+        later write to a mirror the engine keeps (``_page_table``, a
+        chunk's view of its row, a block's ids), zero copy on the CPU
+        backend included.  ``rest`` (the sampling key, a static horizon)
+        is on the device or static already and passes through.  A part's
+        first dispatch traces and lowers its program: that one runs beneath
+        the deep frame, no later one (compile/deep_frame.py says why)."""
         self._step_parts.add(part)
-        return first_call_beneath(self._lowered_parts, part, program,
-                                  self.params, self._pools, *args)
+        self._step_counts["input_transfers"] += 1
+        packed, layout = pack_inputs(inputs)
+        packed = jax.device_put(packed)
+        with self._step_span("dispatch", parent=phase):
+            return first_call_beneath(self._lowered_parts, part, program.run,
+                                      self.params, self._pools, layout,
+                                      packed, *rest)
 
     def _run_prefill_chunk(self, seq: SequenceState, start: int, c_n: int,
                            C: int):
@@ -1977,13 +1988,12 @@ class InferenceEngineV2:
         final = not self._xdec or start + c_n >= seq.length
         part = (("prefill_chunk", C, int(prev.shape[0]))
                 + (() if final else ("part",)))
-        args = (jnp.asarray(ids), jnp.asarray(rows), jnp.asarray(prev),
-                jnp.int32(start), jnp.int32(c_n))
+        inputs = (ids, rows, prev, np.int32(start), np.int32(c_n))
         if self._state:  # the state is carried in the sequence's slot
-            args += (jnp.int32(seq.slot),)
+            inputs += (np.int32(seq.slot),)
         program = self._prefill_chunk if final else self._prefill_chunk_part
-        with self._step_span("dispatch", parent="prefill"):
-            logits, self._pools = self._dispatch(part, program, *args)
+        logits, self._pools = self._dispatch(part, program, inputs,
+                                             phase="prefill")
         seq.prefilled = start + c_n
         self._register_pages(seq)
         return logits
@@ -2143,10 +2153,9 @@ class InferenceEngineV2:
                 counts["prefill_tokens"] += n
                 with self._phase("prefill", self._m_prefill_h, uid=seq.uid,
                                  tokens=n, bucket=bucket):
-                    args = (jnp.asarray(ids), jnp.asarray(rows), jnp.int32(n))
-                    with self._step_span("dispatch", parent="prefill"):
-                        logits, self._pools = self._dispatch(
-                            ("prefill", bucket), self._prefill, *args)
+                    logits, self._pools = self._dispatch(
+                        ("prefill", bucket), self._prefill,
+                        (ids, rows, np.int32(n)), phase="prefill")
                     seq.prefilled = n
                     self._register_pages(seq)
                     self._emit_sampled(seq, logits, out)
@@ -2207,12 +2216,10 @@ class InferenceEngineV2:
             self._note_state_rows(np.where(act, pos + 1, 0))
             with self._phase("decode", self._m_decode_h,
                              batch=len(decode_seqs)):
-                args = (jnp.asarray(last), jnp.asarray(pos),
-                        jnp.asarray(self._page_table), jnp.asarray(act),
-                        jnp.asarray(temps), jnp.asarray(sids))
-                with self._step_span("dispatch", parent="decode"):
-                    tokens, self._pools = self._dispatch(
-                        "decode", self._decode, *args, self._sample_key)
+                tokens, self._pools = self._dispatch(
+                    "decode", self._decode,
+                    (last, pos, self._page_table, act, temps, sids),
+                    self._sample_key, phase="decode")
                 # restore-prefetch rides the in-flight decode: the host
                 # walks queued prefixes into the host tier while the
                 # device decodes, and the H2D scatter chains behind the
@@ -2473,14 +2480,10 @@ class InferenceEngineV2:
         t0 = time.perf_counter()
         with self._phase("multi_decode", self._m_decode_h,
                          batch=len(seqs), horizon=k):
-            args = (jnp.asarray(last), jnp.asarray(pos),
-                    jnp.asarray(self._page_table), jnp.asarray(act),
-                    jnp.asarray(temps), jnp.asarray(eos), jnp.asarray(budg),
-                    jnp.asarray(sids))
-            with self._step_span("dispatch", parent="multi_decode"):
-                toks, produced, self._pools = self._dispatch(
-                    ("multi_decode", k), self._multi, *args,
-                    self._sample_key, k)
+            toks, produced, self._pools = self._dispatch(
+                ("multi_decode", k), self._multi,
+                (last, pos, self._page_table, act, temps, eos, budg, sids),
+                self._sample_key, k, phase="multi_decode")
             # restore-prefetch rides the in-flight scan, like K=1
             self._prefetch_restores()
             with self._step_span("device_wait", parent="multi_decode",
@@ -2622,12 +2625,9 @@ class InferenceEngineV2:
         self._step_counts["decode_rows"] += len(seqs)
         with self._phase("spec_verify", self._m_spec_verify_h,
                          batch=len(seqs), width=W):
-            args = (jnp.asarray(ids), jnp.asarray(pos),
-                    jnp.asarray(self._page_table), jnp.asarray(act),
-                    jnp.asarray(nv))
-            with self._step_span("dispatch", parent="spec_verify"):
-                greedy, self._pools = self._dispatch(
-                    ("verify", W), self._verify, *args)
+            greedy, self._pools = self._dispatch(
+                ("verify", W), self._verify,
+                (ids, pos, self._page_table, act, nv), phase="spec_verify")
             with self._step_span("device_wait", parent="spec_verify",
                                  what="decode_tokens"):
                 # dstpu-lint: allow[host-sync] one [B,W] int32 pull per

@@ -156,13 +156,13 @@ def _dispatches(program):
     if program in ("decode", "chunk"):
         eng = _mistral_engine()
         attr = {"decode": "_decode", "chunk": "_prefill_chunk"}[program]
-        stub = _Recording(getattr(eng, attr))
-        setattr(eng, attr, stub)
+        packed = getattr(eng, attr)  # `_dispatch` calls its `run`
+        stub = packed.run = _Recording(packed.run)
         for _ in range(2):  # two chunks, of two windows: two parts
             _serve(eng, 20, 3)
     elif program == "block_pass":
         eng = _sdar_engine()
-        stub = eng.blocks._program = _Recording(eng.blocks._program)
+        stub = eng.blocks._program.run = _Recording(eng.blocks._program.run)
         for _ in range(2):
             _serve(eng, 9, 5)
     else:

@@ -287,14 +287,14 @@ class _SlowPull:
 
 
 def _stall_in_pull(eng, seconds):
-    real = eng._decode
+    real = eng._decode.run  # what `_dispatch` calls of a program
 
     def slow(*args):
         tokens, pools = real(*args)
-        eng._decode = real
+        eng._decode.run = real
         return _SlowPull(tokens, seconds), pools
 
-    eng._decode = slow
+    eng._decode.run = slow
 
 
 def _stall_in_admission(eng, seconds):
