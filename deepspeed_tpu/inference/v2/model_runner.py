@@ -92,9 +92,9 @@ from ...models.layer_types import (GqaShape, gqa_shape, latent_width,
                                    layers_of, page_layers, run_config,
                                    served_runs)
 from ...models.transformer import (MODEL_AXIS, TransformerConfig, _mm,
-                                   _norm, _repeat_kv, _rope, alibi_slopes,
-                                   attn_qkv, logits_fn, mlp_block,
-                                   rope_interleaved, yarn_inv_freq)
+                                   _norm, _repeat_kv, alibi_slopes, attn_qkv,
+                                   logits_fn, mlp_block, rope_interleaved,
+                                   yarn_inv_freq)
 from ...ops.pallas.paged_attention import (merged_keys, split_keys,
                                            split_queries)
 
@@ -308,6 +308,8 @@ def _attn_out(cfg: TransformerConfig, layer, x, attn, pools):
                   cfg.norm, cfg.norm_eps)
         gate = jax.nn.sigmoid(_mm(cfg, h, layer["attn"]["wg"], None,
                                   MODEL_AXIS).astype(jnp.float32))
+        if gate.shape[-1] != attn.shape[-1]:  # one scalar a head
+            gate = jnp.repeat(gate, attn.shape[-1] // gate.shape[-1], axis=-1)
         attn = (attn.astype(jnp.float32) * gate).astype(attn.dtype)
     attn_delta = (_mm(cfg, attn, layer["attn"]["wo"], MODEL_AXIS, None)
                   + (layer["attn"]["bo"] if cfg.use_bias else 0))
@@ -600,23 +602,20 @@ def _mla_absorbed(cfg: TransformerConfig, layer, q_nope, q_rope, pools, l,
 # ------------------------------ grouped-query layers by type (MiMo-V2-Flash)
 def _gqa_qkv(cfg: TransformerConfig, sh: GqaShape, layer, x, positions):
     """What a ``gqa_full`` or ``gqa_window`` layer makes of ``x [B, T, H]`` at
-    ``positions [B, T]``: queries ``[B, T, NH, k_dim]`` and keys ``[B, T, KVH,
-    k_dim]``, the first ``rot`` lanes of each head rotated with the type's
-    base, and values ``[B, T, KVH, v_dim]`` times ``attn_value_scale`` —
-    keys and values as the cache keeps them."""
+    ``positions [B, T]``: queries ``[B, T, the type's heads, k_dim]`` and keys
+    ``[B, T, KVH, k_dim]``, the first ``rot`` lanes of each head rotated by
+    the type's table (``GqaShape.rotate``), and values ``[B, T, KVH, v_dim]``
+    times ``attn_value_scale`` — keys and values as the cache keeps them."""
     a = layer["attn"]
     B, T, _ = x.shape
     h = _ln1(cfg, layer, x)
     q, k, v = (_mm(cfg, h, a[w], None, MODEL_AXIS).reshape(B, T, n, d)
-               for w, n, d in (("wq", cfg.n_heads, sh.k_dim),
+               for w, n, d in (("wq", sh.heads, sh.k_dim),
                                ("wk", sh.kv_heads, sh.k_dim),
                                ("wv", sh.kv_heads, sh.v_dim)))
     if cfg.attn_value_scale != 1.0:
         v = (v.astype(jnp.float32) * cfg.attn_value_scale).astype(v.dtype)
-    # (``rotary_pct`` itself: ``_rope`` cuts ``sh.rot`` lanes from it as
-    # ``gqa_shape`` did)
-    return (_rope(q, sh.theta, positions, cfg.rotary_pct),
-            _rope(k, sh.theta, positions, cfg.rotary_pct), v)
+    return sh.rotate(q, positions), sh.rotate(k, positions), v
 
 
 def _gqa_softmax(q, k, v, vis, scale: float, sink=None):

@@ -5,6 +5,7 @@ from .families import (bloom_config, bloom_model, falcon_config,
                        mistral_model, opt_config, opt_model, phi_config,
                        phi_model, qwen_config, qwen_model)
 from .gpt2 import gpt2_config, gpt2_model
+from .laguna import laguna_config, laguna_model
 from .lfm2_moe import lfm2_moe_config, lfm2_moe_model
 from .llama import llama_config, llama_model
 from .mimo_v2 import mimo_v2_config, mimo_v2_model
@@ -24,5 +25,6 @@ __all__ = ["bert_config", "bert_model", "gpt2_config", "gpt2_model",
            "solar_open2_model", "lfm2_moe_config", "lfm2_moe_model",
            "phi4_flash_config", "phi4_flash_model", "mistral4_config",
            "mistral4_model", "mimo_v2_config", "mimo_v2_model",
-           "sdar_moe_config", "sdar_moe_model",
+           "sdar_moe_config", "sdar_moe_model", "laguna_config",
+           "laguna_model",
            "TransformerConfig"]

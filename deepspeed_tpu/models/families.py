@@ -28,7 +28,9 @@ through ``inference/v2`` and refused for training by name: ``solar_open2.py``
 window and cross-attention layers, gated memory units), ``mistral4.py``
 (latent attention over a paged latent leaf, a sigmoid-routed expert share),
 ``mimo_v2.py`` (full layers with pages and window layers with rings, of
-different K/V head counts, keys wider than values, behind a dense first layer);
+different K/V head counts, keys wider than values, behind a dense first layer),
+``laguna.py`` (the same two types with query heads, rotary table and rotated
+share by type, a gate a head, a shared expert);
 ``lfm2_moe.py`` (short-convolution + GQA layers over an expert share) is
 trained and not served.
 """

@@ -35,14 +35,15 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Callable, Dict, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ..runtime.activation_checkpointing.checkpointing import get_policy
 from .transformer import (MODEL_AXIS, TransformerConfig, _mm, _nrm, _norm,
-                          attn_mixer, init_layer_stack, mlp_block)
+                          _rope, attn_mixer, init_layer_stack, mlp_block,
+                          yarn_inv_freq)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -361,7 +362,11 @@ class GqaShape:
     """What a grouped-query layer of one type computes with and keeps: K/V
     heads, a key head's and a value head's width, the leading lanes of a head
     that are rotated and the rotary base, the window (0: the whole context)
-    and whether a sink joins the softmax."""
+    and whether a sink joins the softmax; its query heads, the share of a
+    head it was asked to rotate (what ``_rope`` cuts ``rot`` from) and, where
+    its rotary table is YaRN's, ``(factor, original positions, beta_fast,
+    beta_slow, the factor on the cos and sin of the rotated lanes)`` (None:
+    the plain table)."""
     kv_heads: int
     k_dim: int
     v_dim: int
@@ -369,6 +374,9 @@ class GqaShape:
     theta: float
     window: int
     sink: bool
+    heads: int
+    pct: float
+    yarn: Optional[Tuple[float, int, float, float, float]] = None
 
     @property
     def split(self) -> int:
@@ -389,26 +397,52 @@ class GqaShape:
     def v_width(self) -> int:
         return self.kv_heads * self.v_dim
 
+    def rotate(self, x, positions):
+        """``x [B, T, heads, k_dim]`` at ``positions [B, T]`` with the first
+        ``rot`` lanes of every head rotated by the type's table, half-split
+        pairs: ``_rope``'s plain table, or under YaRN the angles of
+        ``yarn_inv_freq`` with cos and sin times the attention factor."""
+        if self.yarn is None:
+            return _rope(x, self.theta, positions, self.pct)
+        *table, mult = self.yarn
+        ang = positions[:, :, None, None].astype(jnp.float32) \
+            * yarn_inv_freq(self.rot, self.theta, *table)
+        cos, sin = jnp.cos(ang) * mult, jnp.sin(ang) * mult
+        x1, x2 = jnp.split(x[..., :self.rot].astype(jnp.float32), 2, axis=-1)
+        out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                              axis=-1).astype(x.dtype)
+        return jnp.concatenate([out, x[..., self.rot:]], axis=-1)
+
+
+def _rot_lanes(head_dim: int, pct: float) -> int:
+    return head_dim if pct >= 1.0 else (int(head_dim * pct) // 2) * 2
+
 
 def gqa_shape(cfg: TransformerConfig, kind: str) -> GqaShape:
     """The shape of the ``gqa_full`` or the ``gqa_window`` layers of ``cfg``:
     defined here once, read by the types' parameters, pages and rings and by
     the serving forms."""
     D = cfg.head_dim
-    rot = D if cfg.rotary_pct >= 1.0 else (int(D * cfg.rotary_pct) // 2) * 2
     if kind == "gqa_full":
-        return GqaShape(cfg.kv_heads, D, cfg.v_head_dim or D, rot,
-                        cfg.rope_theta, 0, False)
+        yarn = (cfg.rope_factor, cfg.rope_original_max, cfg.rope_beta_fast,
+                cfg.rope_beta_slow, cfg.rope_attention_factor or 1.0) \
+            if cfg.rope_factor > 1.0 else None
+        return GqaShape(cfg.kv_heads, D, cfg.v_head_dim or D,
+                        _rot_lanes(D, cfg.rotary_pct), cfg.rope_theta, 0,
+                        False, cfg.n_heads, cfg.rotary_pct, yarn)
+    pct = cfg.swa_rotary_pct or cfg.rotary_pct
     return GqaShape(cfg.swa_kv_heads or cfg.kv_heads, D, cfg.v_head_dim or D,
-                    rot, cfg.swa_rope_theta or cfg.rope_theta,
-                    cfg.sliding_window, cfg.swa_sink)
+                    _rot_lanes(D, pct), cfg.swa_rope_theta or cfg.rope_theta,
+                    cfg.sliding_window, cfg.swa_sink,
+                    cfg.swa_n_heads or cfg.n_heads, pct)
 
 
 def _init_gqa(kind: str):
     def init(cfg: TransformerConfig, rng, n: int) -> Dict[str, Any]:
         keys = jax.random.split(rng, 32)
         layers = init_layer_stack(cfg, keys, n, attn=False)
-        H, NH, sh = cfg.hidden_size, cfg.n_heads, gqa_shape(cfg, kind)
+        sh = gqa_shape(cfg, kind)
+        H, NH = cfg.hidden_size, sh.heads
         layers["attn"] = {
             "wq": _nrm(cfg, keys[16], n, H, NH * sh.k_dim),
             "wk": _nrm(cfg, keys[17], n, H, sh.k_width),
@@ -421,6 +455,11 @@ def _init_gqa(kind: str):
             # beside a window of near-equal scores a sink of b takes e^b /
             # (window + e^b) of a row, a tenth of it at b = 2.7 of 128 keys
             layers["attn"]["sink"] = _nrm(cfg, keys[20], n, NH, s=2.0)
+        if cfg.attn_head_gate:
+            # over a normed input of H values a draw of 0.02 gives the gate's
+            # argument a spread of 0.02 sqrt(H): heads differ, and a dropped
+            # gate moves what a comparison sees
+            layers["attn"]["wg"] = _nrm(cfg, keys[21], n, H, NH)
         return layers
     return init
 

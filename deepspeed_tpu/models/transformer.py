@@ -263,6 +263,18 @@ class TransformerConfig:
     swa_rope_theta: float = 0.0
     swa_sink: bool = False
     attn_value_scale: float = 1.0
+    #: what else may follow the type (Laguna): a window layer's query heads
+    #: and the share of a head it rotates (0: the full layers' ``n_heads`` and
+    #: ``rotary_pct``); a full layer rotates under YaRN where ``rope_factor``
+    #: > 1 (a window layer's table is plain), and ``rope_attention_factor``
+    #: (0: none) multiplies the cos and sin of its rotated lanes — queries and
+    #: keys alike, so the cache keeps it; ``attn_head_gate``: one learned
+    #: scalar a query head on the mixer's output, ``wo (attn_n * sigmoid(h
+    #: wg)_n)``, ``wg [H, heads of the type]``
+    swa_n_heads: int = 0
+    swa_rotary_pct: float = 0.0
+    rope_attention_factor: float = 0.0
+    attn_head_gate: bool = False
 
     @property
     def kv_heads(self) -> int:

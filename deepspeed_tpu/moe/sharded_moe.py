@@ -162,6 +162,8 @@ def _gate_and_aux(logits: jnp.ndarray, cfg: MoEConfig, rng=None, bias=None):
     gate_k = jnp.take_along_axis(gates, expert_idx, axis=1)  # [T, K]
     if cfg.norm_topk:
         gate_k = gate_k / jnp.maximum(jnp.sum(gate_k, -1, keepdims=True), 1e-9)
+    if cfg.routed_scale != 1.0:  # (a softmax router without one: as it was)
+        gate_k = gate_k * cfg.routed_scale
     return gates, expert_idx, gate_k, aux
 
 
