@@ -11,6 +11,13 @@ Layout: kernels work on [BH, S, D] (batch*heads merged); the public API
 takes [B, S, NH, D] to match models/transformer.py.  Every kernel walks a
 (q tile, k tile) grid with its running state in VMEM scratch, so VMEM
 holds tiles only and the sequence length is bounded by HBM, not VMEM.
+A backward tile is classed once, from the scalars of its position
+(``_tile_class``): *skipped* (wholly above the diagonal: no body, no DMA),
+*interior* (causal with ``k_start + bk - 1 <= q_start`` or not causal;
+``k_start + bk <= seq_k``; ``q_start + bq <= seq_q`` — every pair visible, so
+the body has no iota, compare or select) or *edge* (the rest: the masked body).
+The forward masks every computed tile: there the mask's arithmetic hides under
+the row reductions (all tiles bare read - 0.1 % on the v5e: PERF.md §6, PR 55).
 ``impl="jax"`` selects the stock jax pallas kernel
 (``jax.experimental.pallas.ops.tpu.flash_attention``) for comparison.
 Compiled on TPU, interpreted on the CPU test tier (utils/platform.py).
@@ -46,8 +53,9 @@ def _dot(a, b, contract):
 
 
 def _scores(q, k, sl_ref, head, rows, cols, *, sm_scale, causal, alibi,
-            seq_q, seq_k, window=0, k_first=None, block=0):
-    """Masked fp32 scores [bq, bk] of one tile + the validity mask.
+            seq_q, seq_k, window=0, k_first=None, block=0, masked=True):
+    """fp32 scores [bq, bk] of one tile + the validity mask; an interior tile
+    (``masked=False``) has neither mask nor select.
     ``rows``/``cols`` are absolute positions; rows past ``seq_q`` and cols
     past ``seq_k`` are block padding.  ``window``: a row sees itself and the
     ``window - 1`` columns before it, none before ``k_first``.  ``block`` (a
@@ -57,6 +65,8 @@ def _scores(q, k, sl_ref, head, rows, cols, *, sm_scale, causal, alibi,
     if alibi:
         # ALiBi from block indices: no [S, S] bias materialization
         s = s - sl_ref[head] * (rows - cols).astype(jnp.float32)
+    if not masked:
+        return s, None
     valid = cols < seq_k
     if seq_q is not None:
         valid = valid & (rows < seq_q)
@@ -65,6 +75,48 @@ def _scores(q, k, sl_ref, head, rows, cols, *, sm_scale, causal, alibi,
     if window:
         valid = valid & (rows - cols < window) & (cols >= k_first)
     return jnp.where(valid, s, NEG_INF), valid
+
+
+def _tile_class(q_start, k_start, bq, bk, *, causal, seq_q, seq_k):
+    """(computed, interior) of the backward tile at (``q_start``,
+    ``k_start``), from scalars alone (traced in a kernel, Python ints in
+    ``tile_classes``).  Not computed: wholly above the diagonal.  Interior:
+    every (row, column) is visible — wholly under the diagonal and inside
+    both sequences; a computed tile that is not interior is an edge tile."""
+    q_last, k_last = q_start + bq - 1, k_start + bk - 1
+    interior = (q_last < seq_q) & (k_last < seq_k)
+    if not causal:
+        return True, interior
+    return k_start <= q_last, interior & (k_last <= q_start)
+
+
+def _run_tile(cls, body):
+    """``body(masked)`` as the tile's class asks: not at all, bare or masked."""
+    computed, interior = cls
+    pl.when(computed & interior)(functools.partial(body, False))
+    pl.when(computed & jnp.logical_not(interior))(
+        functools.partial(body, True))
+
+
+def tile_classes(seq_q, seq_k, block_q, block_k, causal=True, valid_q=None,
+                 valid_k=None):
+    """(skipped, interior, edge): how many tiles of each class one head of a
+    backward call has — the kernels' own predicate over their grid, on the
+    host.  ``seq_q`` / ``seq_k`` are the lengths the grid covers, ``valid_q``
+    / ``valid_k`` the rows and keys that are not padding."""
+    bq, bk = min(block_q, seq_q), min(block_k, seq_k)
+    valid_q = seq_q if valid_q is None else valid_q
+    valid_k = seq_k if valid_k is None else valid_k
+    skipped = interior = edge = 0
+    for q_start in range(0, seq_q, bq):
+        for k_start in range(0, seq_k, bk):
+            computed, bare = _tile_class(q_start, k_start, bq, bk,
+                                         causal=causal, seq_q=valid_q,
+                                         seq_k=valid_k)
+            skipped += not computed
+            interior += computed and bare
+            edge += computed and not bare
+    return skipped, interior, edge
 
 
 # ---------------------------------------------------------------------------
@@ -201,15 +253,22 @@ def _fwd(q, k, v, alibi_arr, offset_arr, sm_scale, causal, block_q, block_k,
 # backward kernels (recompute p from q,k + lse)
 # ---------------------------------------------------------------------------
 def _bwd_tile(q, k, v, do, lse, delta, sl_ref, head, q_start, k_start, *,
-              sm_scale, causal, alibi, seq_q, seq_k):
+              masked, sm_scale, causal, alibi, seq_q, seq_k):
     """(p, ds) of one tile, both fp32 [bq, bk].  Padded q rows carry
-    garbage q/lse and padded k cols garbage k — both masked to zero."""
-    bq, bk = q.shape[0], k.shape[0]
-    rows = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    cols = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    garbage q/lse and padded k cols garbage k — both masked to zero (an
+    interior tile, ``masked=False``, has neither and builds no positions
+    but for ALiBi)."""
+    rows = cols = None
+    if masked or alibi:
+        bq, bk = q.shape[0], k.shape[0]
+        rows = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        cols = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
     s, valid = _scores(q, k, sl_ref, head, rows, cols, sm_scale=sm_scale,
-                       causal=causal, alibi=alibi, seq_q=seq_q, seq_k=seq_k)
-    p = jnp.where(valid, jnp.exp(s - lse), 0.0)
+                       causal=causal, alibi=alibi, seq_q=seq_q, seq_k=seq_k,
+                       masked=masked)
+    p = jnp.exp(s - lse)
+    if masked:
+        p = jnp.where(valid, p, 0.0)
     dp = _dot(do, v, ((1,), (1,)))
     return p, p * (dp - delta) * sm_scale
 
@@ -226,18 +285,16 @@ def _bwd_dq_kernel(sl_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    def tile():
+    def tile(masked):
         _, ds = _bwd_tile(q_ref[0], k_ref[0], v_ref[0], do_ref[0],
                           lse_ref[0], delta_ref[0], sl_ref, head, q_start,
-                          k_start,
+                          k_start, masked=masked,
                           sm_scale=sm_scale, causal=causal, alibi=alibi,
                           seq_q=seq_q, seq_k=seq_k)
         dq_scr[...] += _dot(ds.astype(k_ref.dtype), k_ref[0], ((1,), (0,)))
 
-    if causal:
-        pl.when(k_start <= q_start + bq - 1)(tile)
-    else:
-        tile()
+    _run_tile(_tile_class(q_start, k_start, bq, bk, causal=causal,
+                          seq_q=seq_q, seq_k=seq_k), tile)
 
     @pl.when(jk == pl.num_programs(2) - 1)
     def _():
@@ -263,20 +320,18 @@ def _bwd_dkv_kernel(sl_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    def tile():
+    def tile(masked):
         p, ds = _bwd_tile(q_ref[0], k_ref[0], v_ref[0], do_ref[0],
                           lse_ref[0], delta_ref[0], sl_ref, head, q_start,
-                          k_start,
+                          k_start, masked=masked,
                           sm_scale=sm_scale, causal=causal, alibi=alibi,
                           seq_q=seq_q, seq_k=seq_k)
         dv_scr[...] += _dot(p.astype(do_ref.dtype), do_ref[0], ((0,), (0,)))
         dk_scr[...] += _dot(ds.astype(q_ref.dtype), q_ref[0], ((0,), (0,)))
 
-    if causal:
-        # q tiles wholly before this k tile contribute nothing
-        pl.when(q_start + bq - 1 >= k_start)(tile)
-    else:
-        tile()
+    # q tiles wholly before this k tile are the skipped ones here
+    _run_tile(_tile_class(q_start, k_start, bq, bk, causal=causal,
+                          seq_q=seq_q, seq_k=seq_k), tile)
 
     @pl.when((gi == q_per_kv - 1) & (iq == pl.num_programs(3) - 1))
     def _():

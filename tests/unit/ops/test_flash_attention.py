@@ -10,10 +10,10 @@ from deepspeed_tpu.models.transformer import xla_attention
 from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 
 
-def _qkv(b=2, s=128, nh=4, d=64, dtype=jnp.float32, seed=0):
+def _qkv(b=2, s=128, nh=4, d=64, dtype=jnp.float32, seed=0, kvh=None):
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
-    shape = (b, s, nh, d)
-    return tuple(jax.random.normal(k, shape, dtype) for k in ks)
+    return tuple(jax.random.normal(k, (b, s, h, d), dtype)
+                 for k, h in zip(ks, (nh, kvh or nh, kvh or nh)))
 
 
 @pytest.mark.parametrize("causal", [True, False])
@@ -237,3 +237,126 @@ def test_flash_on_mesh_matches_xla(devices8):
             axis_names={"data"}))(q, k, v)
         np.testing.assert_allclose(np.asarray(nested), np.asarray(ref),
                                    atol=2e-5, rtol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# tile classes (backward kernels): an interior tile's bare body is the edge
+# body where the mask is all true, so the classes change no bit of a gradient
+# ---------------------------------------------------------------------------
+# (id, S, NH, KVH, D, keywords of the call), forward tiles of 32
+_CLASS_CASES = [
+    ("causal-mha-d64", 128, 2, 2, 64, {}),
+    ("full-mha-d64", 128, 2, 2, 64, {"causal": False}),
+    ("causal-gqa4-d128-padded", 80, 4, 1, 128, {}),
+    ("full-gqa4-d64-padded", 80, 4, 1, 64, {"causal": False}),
+    ("causal-gqa16-d64", 96, 16, 1, 64, {}),
+    ("causal-gqa4-d192", 96, 4, 1, 192, {}),
+    ("causal-bf16-gqa4", 128, 4, 1, 64, {"dtype": jnp.bfloat16}),
+    ("causal-bwd-tiles-wider", 128, 2, 1, 64,
+     {"bwd_block_q": 64, "bwd_block_k": 64}),
+    ("causal-bwd-tiles-narrower-padded", 112, 2, 1, 64,
+     {"bwd_block_q": 16, "bwd_block_k": 64}),
+    ("causal-alibi", 96, 2, 1, 64, {"alibi": True}),
+]
+
+
+@pytest.mark.parametrize("s,nh,kvh,d,kw", [c[1:] for c in _CLASS_CASES],
+                         ids=[c[0] for c in _CLASS_CASES])
+def test_tile_classes_change_no_bit(monkeypatch, s, nh, kvh, d, kw):
+    """The shipped classes against kernels that run the edge body on every
+    computed tile: out, dq, dk and dv bit for bit.
+
+    The scale and the slopes are powers of two.  XLA:CPU contracts the bare
+    body's ``dot * scale - lse`` into one fused multiply-add, which rounds
+    once (in the masked body the select stands between the two); a power of
+    two rounds the same either way, so what is compared is the kernel and not
+    the interpreter's compiler.  Mosaic contracts nothing: the chip's
+    comparison (PERF.md §6, PR 55) ran the models' own scales."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    kw = dict(kw)
+    q, k, v = _qkv(1, s, nh, d, kw.pop("dtype", jnp.float32), seed=5,
+                   kvh=kvh)
+    if kw.pop("alibi", False):
+        kw["alibi_slopes"] = 2.0 ** -jnp.arange(2, 2 + nh, dtype=jnp.float32)
+
+    def call(q, k, v):
+        return fa.flash_attention(q, k, v, block_q=32, block_k=32,
+                                  sm_scale=0.125, **{"causal": True, **kw})
+
+    seen = []
+    scores = fa._scores
+    monkeypatch.setattr(fa, "_scores", lambda *a, masked=True, **k: (
+        seen.append(masked), scores(*a, masked=masked, **k))[1])
+
+    def run():
+        del seen[:]
+        out, vjp = jax.vjp(call, q, k, v)
+        return (out,) + vjp(jnp.cos(out.astype(jnp.float32)).astype(
+            out.dtype)), set(seen)
+
+    shipped, bodies = run()
+    assert bodies == {True, False}
+    monkeypatch.setattr(fa, "_run_tile", lambda cls, body: fa.pl.when(cls[0])(
+        lambda: body(True)))
+    all_edge, bodies = run()
+    assert bodies == {True}
+    for a, b, name in zip(shipped, all_edge, ("out", "dq", "dk", "dv")):
+        assert a.dtype == b.dtype and np.array_equal(
+            np.asarray(a), np.asarray(b)), name
+
+
+def _classes_by_mask(seq_q, seq_k, block_q, block_k, causal=True,
+                     valid_q=None, valid_k=None):
+    """The classes counted over every (row, column) of every tile's mask."""
+    bq, bk = min(block_q, seq_q), min(block_k, seq_k)
+    skipped = interior = edge = 0
+    for q0 in range(0, seq_q, bq):
+        rows = q0 + np.arange(bq)[:, None]
+        for k0 in range(0, seq_k, bk):
+            cols = k0 + np.arange(bk)[None, :]
+            under = rows >= cols if causal else np.ones((bq, bk), bool)
+            mask = (under & (rows < (seq_q if valid_q is None else valid_q))
+                    & (cols < (seq_k if valid_k is None else valid_k)))
+            if not under.any():
+                skipped += 1
+            elif mask.all():
+                interior += 1
+            else:
+                edge += 1
+    return skipped, interior, edge
+
+
+@pytest.mark.parametrize("seq,block,want", [
+    (8192, 512, (120, 120, 16)),   # lfm2-ep4-pretrain-8k, a head
+    (4096, 512, (28, 28, 8)),      # mistral7b-zero3-4chip
+    (2048, 512, (6, 6, 4)),        # opt6.7b-sft-1chip
+])
+def test_tile_classes_of_the_training_cells(seq, block, want):
+    from deepspeed_tpu.ops.pallas.flash_attention import tile_classes
+
+    assert tile_classes(seq, seq, block, block) == want
+    assert _classes_by_mask(seq, seq, block, block) == want
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("block_q,block_k", [(32, 32), (64, 32), (32, 64),
+                                             (48, 16)])
+def test_tile_classes_match_the_mask(causal, block_q, block_k):
+    """A tile is interior iff its mask is all true and skipped iff no row of
+    it reaches a column under the causal rule — over lengths that do and do
+    not divide the tile, square and not, with padded rows and keys."""
+    from deepspeed_tpu.ops.pallas.flash_attention import tile_classes
+
+    n = 0
+    for seq_q, seq_k in ((32, 32), (64, 64), (96, 96), (192, 192), (240, 240),
+                         (64, 192), (192, 96)):
+        for pad_q, pad_k in ((0, 0), (5, 0), (0, 17), (24, 24), (40, 70)):
+            args = dict(causal=causal,
+                        valid_q=seq_q - pad_q if pad_q else None,
+                        valid_k=seq_k - pad_k if pad_k else None)
+            got = tile_classes(seq_q, seq_k, block_q, block_k, **args)
+            assert got == _classes_by_mask(seq_q, seq_k, block_q, block_k,
+                                           **args), (seq_q, seq_k, args)
+            n += sum(got)
+    assert n > 200
