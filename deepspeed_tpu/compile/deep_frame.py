@@ -18,6 +18,8 @@ PERF.md section 6 (PR 52) has the numbers.  An interpreter without the
 chunked stack runs this as a plain call.
 """
 
+from ..telemetry.regions import abstract_args, note_dispatch
+
 #: value-stack slots of :func:`under_deep_frame`'s frame, 8 bytes each
 DEEP_FRAME_SLOTS = 1 << 17
 
@@ -33,8 +35,14 @@ under_deep_frame.__code__ = under_deep_frame.__code__.replace(
 
 def first_call_beneath(seen: set, key, fn, /, *args, **kwargs):
     """``fn(*args, **kwargs)``: beneath the deep frame the first time the
-    caller's set ``seen`` meets ``key``, a plain call ever after."""
+    caller's set ``seen`` meets ``key``, a plain call ever after.  The first
+    call of a jitted ``fn`` is also where its program is noted for whoever
+    asks of its regions later (``telemetry/regions.py``): the arguments'
+    shapes are taken before the call, which may donate them."""
     if key in seen:
         return fn(*args, **kwargs)
     seen.add(key)
-    return under_deep_frame(fn, *args, **kwargs)
+    shapes = abstract_args((args, kwargs))
+    out = under_deep_frame(fn, *args, **kwargs)
+    note_dispatch(fn, *shapes)
+    return out

@@ -40,6 +40,7 @@ from ...telemetry.compile_sentinel import (RecompileSentinel,
 from ...telemetry.compile_sentinel import \
     expect_recompile as sentinel_expect_recompile
 from ...telemetry.flight import dump_on_exception, get_flight_recorder
+from ...telemetry.regions import region, region_index
 from ...telemetry.reqtrace import get_reqtrace_ledger, slo_exemplar
 from ...telemetry.spans import begin_span, end_span, record_event, span
 from ...telemetry.tracing import PhaseTimer
@@ -460,8 +461,9 @@ class InferenceEngineV2:
                                              table, act, nv)
                 # greedy argmax on device: [B, W] int32 crosses the link,
                 # not [B, W, vocab] logits (same economics as decode)
-                return (jnp.argmax(logits.astype(jnp.float32), axis=-1)
-                        .astype(jnp.int32), pools)
+                with region("sample"):
+                    return (jnp.argmax(logits.astype(jnp.float32), axis=-1)
+                            .astype(jnp.int32), pools)
 
             self._verify = PackedProgram(_verify_and_greedy)
         # fused multi-step decode (docs/SERVING.md "Multi-step decode"):
@@ -1948,7 +1950,9 @@ class InferenceEngineV2:
         backend included.  ``rest`` (the sampling key, a static horizon)
         is on the device or static already and passes through.  A part's
         first dispatch traces and lowers its program: that one runs beneath
-        the deep frame, no later one (compile/deep_frame.py says why)."""
+        the deep frame, no later one (compile/deep_frame.py says why), and
+        leaves the note its region table is built from when someone asks
+        (telemetry/regions.py)."""
         self._step_parts.add(part)
         self._step_counts["input_transfers"] += 1
         packed, layout = pack_inputs(inputs)
@@ -2027,7 +2031,8 @@ class InferenceEngineV2:
                     # periodic step-time attribution: only this step pays
                     # the profiler start/stop + parse (capture context is
                     # exception-safe; a failed step still propagates)
-                    with self._timeline.capture(self._decode_steps):
+                    with self._timeline.capture(self._decode_steps,
+                                                regions=region_index):
                         out = self._step_impl()
                 else:
                     out = self._step_impl()

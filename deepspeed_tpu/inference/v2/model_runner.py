@@ -95,6 +95,7 @@ from ...models.transformer import (MODEL_AXIS, TransformerConfig, _mm,
                                    _norm, _repeat_kv, alibi_slopes, attn_qkv,
                                    logits_fn, mlp_block, rope_interleaved,
                                    yarn_inv_freq)
+from ...telemetry.regions import region
 from ...ops.pallas.paged_attention import (merged_keys, split_keys,
                                            split_queries)
 
@@ -154,11 +155,13 @@ def _period_body(types, per, before, first, layer_fns):
             if before.get(m):
                 l = l + before[m]
             seen[m] += 1
-            if t.crosses:
-                x, pools, aux, cross = layer_fns[m](
-                    layer, l, x, pools, cross, first + p * len(types) + j)
-            else:
-                x, pools, aux = layer_fns[m](layer, l, x, pools)
+            with region(_MIXER_GLUE.get(m, "attn_glue")):
+                if t.crosses:
+                    x, pools, aux, cross = layer_fns[m](
+                        layer, l, x, pools, cross,
+                        first + p * len(types) + j)
+                else:
+                    x, pools, aux = layer_fns[m](layer, l, x, pools)
             # (a prologue's dense layer beside an expert share has none)
             if "moe_stats" in pools and "router" in layer["mlp"]:
                 pools = dict(pools, moe_stats=pools["moe_stats"] + aux)
@@ -168,6 +171,13 @@ def _period_body(types, per, before, first, layer_fns):
 
 
 _EXPERT_MATRICES = ("w_gate", "w_up", "w_down")
+
+#: the region of what a mixer's layer function writes itself, outside the
+#: shared pieces that name their own (``attn_qkv``, ``_attn_out``, ``_ffn``):
+#: cache writes, page gathers, masks and the XLA forms of attention, or what
+#: surrounds a recurrent-state kernel
+_MIXER_GLUE = {"kda": "state_glue", "mamba": "state_glue",
+               "gmu": "state_glue"}
 
 
 def _experts_left_stacked(period_body, trees):
@@ -230,15 +240,18 @@ def _scan_layers(cfg: TransformerConfig, params, pools, x, layer_fns,
     for (types, n), trees in zip(runs, stack):
         per = {t.mixer: sum(u.mixer == t.mixer for u in types) for t in types}
         period_body = _period_body(types, per, dict(before), first, layer_fns)
-        if n == 1 and len(runs) > 1:
-            (x, pools, cross), _ = period_body(
-                (x, pools, cross),
-                (jax.tree_util.tree_map(lambda a: a[0], trees), 0))
-        else:
-            if cfg.moe_held_count and n > 1:
-                period_body, trees = _experts_left_stacked(period_body, trees)
-            (x, pools, cross), _ = jax.lax.scan(
-                period_body, (x, pools, cross), (trees, jnp.arange(n)))
+        # (the loop under ``stack``: its own slices of the stacked weights)
+        with region("stack"):
+            if n == 1 and len(runs) > 1:
+                (x, pools, cross), _ = period_body(
+                    (x, pools, cross),
+                    (jax.tree_util.tree_map(lambda a: a[0], trees), 0))
+            else:
+                if cfg.moe_held_count and n > 1:
+                    period_body, trees = _experts_left_stacked(period_body,
+                                                               trees)
+                (x, pools, cross), _ = jax.lax.scan(
+                    period_body, (x, pools, cross), (trees, jnp.arange(n)))
         for m, k in per.items():
             before[m] = before.get(m, 0) + k * n
         first += n * len(types)
@@ -311,8 +324,9 @@ def _attn_out(cfg: TransformerConfig, layer, x, attn, pools):
         if gate.shape[-1] != attn.shape[-1]:  # one scalar a head
             gate = jnp.repeat(gate, attn.shape[-1] // gate.shape[-1], axis=-1)
         attn = (attn.astype(jnp.float32) * gate).astype(attn.dtype)
-    attn_delta = (_mm(cfg, attn, layer["attn"]["wo"], MODEL_AXIS, None)
-                  + (layer["attn"]["bo"] if cfg.use_bias else 0))
+    with region("attn_out"):
+        attn_delta = (_mm(cfg, attn, layer["attn"]["wo"], MODEL_AXIS, None)
+                      + (layer["attn"]["bo"] if cfg.use_bias else 0))
     if cfg.parallel_block:
         x, aux = _ffn(cfg, layer, x)
         return x + attn_delta, pools, aux
@@ -321,10 +335,9 @@ def _attn_out(cfg: TransformerConfig, layer, x, attn, pools):
 
 
 def _kda_mix(cfg: TransformerConfig, layer, x, tail, valid, scan):
-    """``_kda_mixer`` under the ``kda`` scope (its operations carry the name
-    in a device trace), then the feed-forward part -> (x, aux, rows)."""
-    with jax.named_scope("kda"):
-        y, rows = _kda_mixer(cfg, layer, x, tail, valid, scan)
+    """``_kda_mixer`` (the ``state_glue`` region: ``_period_body``), then
+    the feed-forward part -> (x, aux, rows)."""
+    y, rows = _kda_mixer(cfg, layer, x, tail, valid, scan)
     return (*_ffn(cfg, layer, x + y), rows)
 
 
@@ -341,8 +354,7 @@ def _kda_mixer(cfg: TransformerConfig, layer, x, tail, valid, scan):
     m = layer["kda"]
     R, T, _ = x.shape
     NH, D = cfg.kda_heads, cfg.kda_head_dim
-    h = _norm(x, layer["norm1"]["scale"], layer["norm1"].get("bias"),
-              cfg.norm, cfg.norm_eps)
+    h = _ln1(cfg, layer, x)
     pre = jnp.concatenate([h @ m["wq"], h @ m["wk"], h @ m["wv"]], axis=-1)
     rows = jnp.concatenate([tail.astype(pre.dtype), pre], axis=1)
     conv = sum(rows[:, j:j + T].astype(f32) * m["conv"][j].astype(f32)
@@ -367,13 +379,15 @@ def _kda_mixer(cfg: TransformerConfig, layer, x, tail, valid, scan):
 
 # ------------------------------------------------ SambaY (Phi-4-mini-flash)
 def _ln1(cfg: TransformerConfig, layer, x):
-    return _norm(x, layer["norm1"]["scale"], layer["norm1"].get("bias"),
-                 cfg.norm, cfg.norm_eps)
+    with region("norm"):
+        return _norm(x, layer["norm1"]["scale"], layer["norm1"].get("bias"),
+                     cfg.norm, cfg.norm_eps)
 
 
 def _mamba_mix(cfg: TransformerConfig, layer, x, tail, scan):
-    """The Mamba-1 mixer on ``x [R, T, H]`` under the ``mamba`` scope, then
-    the feed-forward part: ``tail [R, conv-1, inner]`` the rows of ``u`` that
+    """The Mamba-1 mixer on ``x [R, T, H]`` (the ``state_glue`` region:
+    ``_period_body``), then the feed-forward part: ``tail [R, conv-1,
+    inner]`` the rows of ``u`` that
     precede the tokens, ``scan(dt, u, b, c, a, d) -> y [R, T, inner]``
     float32 runs the recurrence (and keeps the state).  Returns (x, aux, the
     scan's output ``y`` — the memory the gated memory units read —, the
@@ -382,18 +396,17 @@ def _mamba_mix(cfg: TransformerConfig, layer, x, tail, scan):
     f32 = jnp.float32
     m = layer["mamba"]
     T, N, R = x.shape[1], cfg.ssm_state, cfg.ssm_dt_rank
-    with jax.named_scope("mamba"):
-        u, z = jnp.split(_ln1(cfg, layer, x) @ m["w_in"], 2, axis=-1)
-        rows = jnp.concatenate([tail.astype(u.dtype), u], axis=1)
-        u = jax.nn.silu(sum(
-            rows[:, j:j + T].astype(f32) * m["conv"][j].astype(f32)
-            for j in range(cfg.ssm_conv)) + m["conv_b"].astype(f32))
-        delta, b, c = jnp.split(u.astype(x.dtype) @ m["w_x"], [R, R + N],
-                                axis=-1)
-        dt = jax.nn.softplus((delta @ m["w_dt"]).astype(f32)
-                             + m["b_dt"].astype(f32))
-        y = scan(dt, u, b, c, -jnp.exp(m["a_log"].astype(f32)), m["d"])
-        out = (y * jax.nn.silu(z.astype(f32))).astype(x.dtype) @ m["w_out"]
+    u, z = jnp.split(_ln1(cfg, layer, x) @ m["w_in"], 2, axis=-1)
+    rows = jnp.concatenate([tail.astype(u.dtype), u], axis=1)
+    u = jax.nn.silu(sum(
+        rows[:, j:j + T].astype(f32) * m["conv"][j].astype(f32)
+        for j in range(cfg.ssm_conv)) + m["conv_b"].astype(f32))
+    delta, b, c = jnp.split(u.astype(x.dtype) @ m["w_x"], [R, R + N],
+                            axis=-1)
+    dt = jax.nn.softplus((delta @ m["w_dt"]).astype(f32)
+                         + m["b_dt"].astype(f32))
+    y = scan(dt, u, b, c, -jnp.exp(m["a_log"].astype(f32)), m["d"])
+    out = (y * jax.nn.silu(z.astype(f32))).astype(x.dtype) @ m["w_out"]
     return (*_ffn(cfg, layer, x + out), y, rows)
 
 
@@ -426,8 +439,9 @@ def _diff_out(cfg: TransformerConfig, layer, x, o, i):
     d = o[..., 0, :] - lam * o[..., 1, :]
     d = (d * jax.lax.rsqrt(jnp.mean(d * d, -1, keepdims=True) + cfg.norm_eps)
          * a["sub_norm"].astype(f32) * (1.0 - lam0))
-    delta = _mm(cfg, d.reshape(B, T, -1).astype(x.dtype), a["wo"],
-                MODEL_AXIS, None) + a["bo"]
+    with region("attn_out"):
+        delta = _mm(cfg, d.reshape(B, T, -1).astype(x.dtype), a["wo"],
+                    MODEL_AXIS, None) + a["bo"]
     return _ffn(cfg, layer, x + delta)
 
 
@@ -479,10 +493,9 @@ def _ring_slots(pools, like):
 def _gmu_fn(cfg: TransformerConfig):
     def gmu_fn(layer, l, x, pools, cross, i):
         g = layer["gmu"]
-        with jax.named_scope("gmu"):
-            gate = jax.nn.silu((_ln1(cfg, layer, x) @ g["w_in"])
-                               .astype(jnp.float32))
-            y = (cross["mem"] * gate).astype(x.dtype) @ g["w_out"]
+        gate = jax.nn.silu((_ln1(cfg, layer, x) @ g["w_in"])
+                           .astype(jnp.float32))
+        y = (cross["mem"] * gate).astype(x.dtype) @ g["w_out"]
         x, aux = _ffn(cfg, layer, x + y)
         return x, pools, aux, cross
     return gmu_fn
@@ -493,8 +506,10 @@ def _xattn_fn(cfg: TransformerConfig, attend):
     full-attention layer wrote."""
     def xattn_fn(layer, l, x, pools, cross, i):
         a = layer["attn"]
-        q = (_mm(cfg, _ln1(cfg, layer, x), a["wq"], None, MODEL_AXIS)
-             + a["bq"]).reshape(*x.shape[:2], cfg.n_heads, cfg.head_dim)
+        h = _ln1(cfg, layer, x)
+        with region("attn_qkv"):
+            q = (_mm(cfg, h, a["wq"], None, MODEL_AXIS)
+                 + a["bq"]).reshape(*x.shape[:2], cfg.n_heads, cfg.head_dim)
         x, aux = _diff_out(cfg, layer, x, attend(q, pools), i)
         return x, pools, aux, cross
     return xattn_fn
@@ -514,28 +529,29 @@ def _mla_project(cfg: TransformerConfig, layer, x, positions):
     NH, R = cfg.n_heads, cfg.kv_lora_rank
     dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     h = _ln1(cfg, layer, x)
-    cq = _norm(h @ a["w_dq"], a["q_norm"], None, "rmsnorm", cfg.norm_eps)
-    q = (cq @ a["w_uq"]).reshape(B, T, NH, dn + dr)
-    ckv = h @ a["w_dkv"]
-    c = _norm(ckv[..., :R], a["kv_norm"], None, "rmsnorm", cfg.norm_eps)
-    inv_freq = yarn_inv_freq(dr, cfg.rope_theta, cfg.rope_factor,
-                             cfg.rope_original_max, cfg.rope_beta_fast,
-                             cfg.rope_beta_slow)
-    k_rope = rope_interleaved(ckv[..., None, R:], inv_freq, positions)[:, :, 0]
-    q_rope = rope_interleaved(q[..., dn:], inv_freq, positions)
-    m = 1.0
-    if cfg.rope_mscale_all_dim and cfg.rope_factor > 1.0:
-        m = 0.1 * cfg.rope_mscale_all_dim * math.log(cfg.rope_factor) + 1.0
-    scale = jnp.full(positions.shape, m * m / math.sqrt(dn + dr), f32)
-    if cfg.attn_scale_beta:
-        scale = scale * (1.0 + cfg.attn_scale_beta * jnp.log1p(
-            (positions // cfg.rope_original_max).astype(f32)))
-    scale = scale[..., None, None]
-    row = jnp.concatenate([c, k_rope], axis=-1)
-    row = jnp.pad(row, ((0, 0), (0, 0),
-                        (0, latent_width(cfg) - row.shape[-1])))
-    return ((q[..., :dn].astype(f32) * scale).astype(x.dtype),
-            (q_rope.astype(f32) * scale).astype(x.dtype), row)
+    with region("attn_qkv"):
+        cq = _norm(h @ a["w_dq"], a["q_norm"], None, "rmsnorm", cfg.norm_eps)
+        q = (cq @ a["w_uq"]).reshape(B, T, NH, dn + dr)
+        ckv = h @ a["w_dkv"]
+        c = _norm(ckv[..., :R], a["kv_norm"], None, "rmsnorm", cfg.norm_eps)
+        inv_freq = yarn_inv_freq(dr, cfg.rope_theta, cfg.rope_factor,
+                                 cfg.rope_original_max, cfg.rope_beta_fast,
+                                 cfg.rope_beta_slow)
+        k_rope = rope_interleaved(ckv[..., None, R:], inv_freq, positions)[:, :, 0]
+        q_rope = rope_interleaved(q[..., dn:], inv_freq, positions)
+        m = 1.0
+        if cfg.rope_mscale_all_dim and cfg.rope_factor > 1.0:
+            m = 0.1 * cfg.rope_mscale_all_dim * math.log(cfg.rope_factor) + 1.0
+        scale = jnp.full(positions.shape, m * m / math.sqrt(dn + dr), f32)
+        if cfg.attn_scale_beta:
+            scale = scale * (1.0 + cfg.attn_scale_beta * jnp.log1p(
+                (positions // cfg.rope_original_max).astype(f32)))
+        scale = scale[..., None, None]
+        row = jnp.concatenate([c, k_rope], axis=-1)
+        row = jnp.pad(row, ((0, 0), (0, 0),
+                            (0, latent_width(cfg) - row.shape[-1])))
+        return ((q[..., :dn].astype(f32) * scale).astype(x.dtype),
+                (q_rope.astype(f32) * scale).astype(x.dtype), row)
 
 
 def _mla_expanded(cfg: TransformerConfig, layer, q_nope, q_rope, rows, q_pos,
@@ -546,28 +562,29 @@ def _mla_expanded(cfg: TransformerConfig, layer, q_nope, q_rope, rows, q_pos,
     .]`` at positions ``q_pos [T]`` see the slots at or before them.  Returns
     ``[1, T, NH * dv]``.  The flash kernel takes one width for keys and
     values: where they differ the narrower is padded with zeros."""
-    NH, R = cfg.n_heads, cfg.kv_lora_rank
-    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    S, T = rows.shape[0], q_nope.shape[1]
-    kv = (rows[:, :R] @ layer["attn"]["w_ukv"]).reshape(S, NH, dn + dv)
-    k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
-        rows[:, None, R:R + dr], (S, NH, dr))], axis=-1)[None]
-    v = kv[..., dn:][None]
-    q = jnp.concatenate([q_nope, q_rope], axis=-1)
-    if use_flash:
-        from ...ops.pallas.flash_attention import flash_attention
+    with region("latent_expand"):
+        NH, R = cfg.n_heads, cfg.kv_lora_rank
+        dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        S, T = rows.shape[0], q_nope.shape[1]
+        kv = (rows[:, :R] @ layer["attn"]["w_ukv"]).reshape(S, NH, dn + dv)
+        k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
+            rows[:, None, R:R + dr], (S, NH, dr))], axis=-1)[None]
+        v = kv[..., dn:][None]
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        if use_flash:
+            from ...ops.pallas.flash_attention import flash_attention
 
-        wide = max(dn + dr, dv)
-        widen = lambda a: jnp.pad(  # noqa: E731
-            a, ((0, 0),) * 3 + ((0, wide - a.shape[-1]),))
-        o = flash_attention(widen(q), widen(k), widen(v), causal=True,
-                            q_offset=q_offset, sm_scale=1.0)[..., :dv]
-    else:
-        s = jnp.einsum("btnd,bsnd->bnts", q, k).astype(jnp.float32)
-        vis = jnp.arange(S)[None, :] <= q_pos[:, None]
-        p = jax.nn.softmax(jnp.where(vis[None, None], s, -1e30), axis=-1)
-        o = jnp.einsum("bnts,bsnd->btnd", p.astype(v.dtype), v)
-    return o.reshape(1, T, NH * dv)
+            wide = max(dn + dr, dv)
+            widen = lambda a: jnp.pad(  # noqa: E731
+                a, ((0, 0),) * 3 + ((0, wide - a.shape[-1]),))
+            o = flash_attention(widen(q), widen(k), widen(v), causal=True,
+                                q_offset=q_offset, sm_scale=1.0)[..., :dv]
+        else:
+            s = jnp.einsum("btnd,bsnd->bnts", q, k).astype(jnp.float32)
+            vis = jnp.arange(S)[None, :] <= q_pos[:, None]
+            p = jax.nn.softmax(jnp.where(vis[None, None], s, -1e30), axis=-1)
+            o = jnp.einsum("bnts,bsnd->btnd", p.astype(v.dtype), v)
+        return o.reshape(1, T, NH * dv)
 
 
 def _mla_absorbed(cfg: TransformerConfig, layer, q_nope, q_rope, pools, l,
@@ -576,27 +593,28 @@ def _mla_absorbed(cfg: TransformerConfig, layer, q_nope, q_rope, pools, l,
     [B, NH, dr]``): ``q~ = q_nope W_uk^T`` scores against the latent rows
     themselves, the output is ``(sum p c) W_uv``.  Returns ``[B, 1, NH *
     dv]``."""
-    NH, R = cfg.n_heads, cfg.kv_lora_rank
-    dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-    B = q_nope.shape[0]
-    w = layer["attn"]["w_ukv"].reshape(R, NH, -1)
-    q = jnp.concatenate([jnp.einsum("bnd,rnd->bnr", q_nope, w[..., :dn]),
-                         q_rope], axis=-1)
-    if use_kernel:
-        from ...ops.pallas.mla_attention import mla_decode_attention
+    with region("latent_expand"):
+        NH, R = cfg.n_heads, cfg.kv_lora_rank
+        dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        B = q_nope.shape[0]
+        w = layer["attn"]["w_ukv"].reshape(R, NH, -1)
+        q = jnp.concatenate([jnp.einsum("bnd,rnd->bnr", q_nope, w[..., :dn]),
+                             q_rope], axis=-1)
+        if use_kernel:
+            from ...ops.pallas.mla_attention import mla_decode_attention
 
-        u = mla_decode_attention(q, pools["latent"], page_table, positions,
-                                 l, active, rank=R)
-    else:
-        win = pools["latent"][l, page_table]
-        win = win.reshape(B, -1, win.shape[-1])
-        s = jnp.einsum("bnf,bsf->bns", q, win[..., :R + dr]
-                       ).astype(jnp.float32)
-        vis = (jnp.arange(win.shape[1])[None] <= positions[:, None]) \
-            & active[:, None]
-        p = jax.nn.softmax(jnp.where(vis[:, None], s, -1e30), axis=-1)
-        u = jnp.einsum("bns,bsr->bnr", p.astype(q.dtype), win[..., :R])
-    return jnp.einsum("bnr,rnv->bnv", u, w[..., dn:]).reshape(B, 1, -1)
+            u = mla_decode_attention(q, pools["latent"], page_table, positions,
+                                     l, active, rank=R)
+        else:
+            win = pools["latent"][l, page_table]
+            win = win.reshape(B, -1, win.shape[-1])
+            s = jnp.einsum("bnf,bsf->bns", q, win[..., :R + dr]
+                           ).astype(jnp.float32)
+            vis = (jnp.arange(win.shape[1])[None] <= positions[:, None]) \
+                & active[:, None]
+            p = jax.nn.softmax(jnp.where(vis[:, None], s, -1e30), axis=-1)
+            u = jnp.einsum("bns,bsr->bnr", p.astype(q.dtype), win[..., :R])
+        return jnp.einsum("bnr,rnv->bnv", u, w[..., dn:]).reshape(B, 1, -1)
 
 
 # ------------------------------ grouped-query layers by type (MiMo-V2-Flash)
@@ -609,13 +627,14 @@ def _gqa_qkv(cfg: TransformerConfig, sh: GqaShape, layer, x, positions):
     a = layer["attn"]
     B, T, _ = x.shape
     h = _ln1(cfg, layer, x)
-    q, k, v = (_mm(cfg, h, a[w], None, MODEL_AXIS).reshape(B, T, n, d)
-               for w, n, d in (("wq", sh.heads, sh.k_dim),
-                               ("wk", sh.kv_heads, sh.k_dim),
-                               ("wv", sh.kv_heads, sh.v_dim)))
-    if cfg.attn_value_scale != 1.0:
-        v = (v.astype(jnp.float32) * cfg.attn_value_scale).astype(v.dtype)
-    return sh.rotate(q, positions), sh.rotate(k, positions), v
+    with region("attn_qkv"):
+        q, k, v = (_mm(cfg, h, a[w], None, MODEL_AXIS).reshape(B, T, n, d)
+                   for w, n, d in (("wq", sh.heads, sh.k_dim),
+                                   ("wk", sh.kv_heads, sh.k_dim),
+                                   ("wv", sh.kv_heads, sh.v_dim)))
+        if cfg.attn_value_scale != 1.0:
+            v = (v.astype(jnp.float32) * cfg.attn_value_scale).astype(v.dtype)
+        return sh.rotate(q, positions), sh.rotate(k, positions), v
 
 
 def _gqa_softmax(q, k, v, vis, scale: float, sink=None):
@@ -721,16 +740,17 @@ def paged_prefill(cfg: TransformerConfig, params, pools,
     """
     S = ids.shape[0]
     ps = pools["k"].shape[2]
-    x = params["embed"]["tok"][ids][None]  # [1, S, H]
-    if cfg.position == "learned":
-        # the bucket may pad up to page_size-1 slots past the position
-        # table; clamp explicitly (pad positions >= length never influence
-        # real-token outputs under the causal mask)
-        pos_idx = jnp.minimum(jnp.arange(S), params["embed"]["pos"].shape[0] - 1)
-        x = x + params["embed"]["pos"][pos_idx][None]
-    if "norm" in params["embed"]:  # bloom-style word_embeddings_layernorm
-        x = _norm(x, params["embed"]["norm"]["scale"],
-                  params["embed"]["norm"].get("bias"), cfg.norm, cfg.norm_eps)
+    with region("embed"):
+        x = params["embed"]["tok"][ids][None]  # [1, S, H]
+        if cfg.position == "learned":
+            # the bucket may pad up to page_size-1 slots past the position
+            # table; clamp explicitly (pad positions >= length never influence
+            # real-token outputs under the causal mask)
+            pos_idx = jnp.minimum(jnp.arange(S), params["embed"]["pos"].shape[0] - 1)
+            x = x + params["embed"]["pos"][pos_idx][None]
+        if "norm" in params["embed"]:  # bloom-style word_embeddings_layernorm
+            x = _norm(x, params["embed"]["norm"]["scale"],
+                      params["embed"]["norm"].get("bias"), cfg.norm, cfg.norm_eps)
     positions = jnp.arange(S)[None]
 
     use_flash = _use_paged_kernel()
@@ -767,9 +787,11 @@ def paged_prefill(cfg: TransformerConfig, params, pools,
 
     x, pools = _scan_layers(cfg, params, pools, x,
                             _Forms("whole-prompt prefill", attn=layer_fn))
-    hidden = _norm(x[:, length - 1], params["final_norm"]["scale"],
-                   params["final_norm"].get("bias"), cfg.norm, cfg.norm_eps)
-    logits = logits_fn(cfg, params, hidden[:, None])[0, 0]
+    with region("head"):
+        hidden = _norm(x[:, length - 1], params["final_norm"]["scale"],
+                       params["final_norm"].get("bias"), cfg.norm,
+                       cfg.norm_eps)
+        logits = logits_fn(cfg, params, hidden[:, None])[0, 0]
     return logits, pools
 
 
@@ -888,26 +910,28 @@ def paged_prefill_chunk(cfg: TransformerConfig, params, pools,
     C = ids.shape[0]
     ps, _ = _page_geometry(pools)
     S_prev = prev_table.shape[0] * ps
-    x = params["embed"]["tok"][ids][None]  # [1, C, H]
-    positions = start + jnp.arange(C)[None]
-    if cfg.position == "learned":
-        pos_idx = jnp.minimum(positions[0],
-                              params["embed"]["pos"].shape[0] - 1)
-        x = x + params["embed"]["pos"][pos_idx][None]
-    if "norm" in params["embed"]:
-        x = _norm(x, params["embed"]["norm"]["scale"],
-                  params["embed"]["norm"].get("bias"), cfg.norm, cfg.norm_eps)
+    with region("embed"):
+        x = params["embed"]["tok"][ids][None]  # [1, C, H]
+        positions = start + jnp.arange(C)[None]
+        if cfg.position == "learned":
+            pos_idx = jnp.minimum(positions[0],
+                                  params["embed"]["pos"].shape[0] - 1)
+            x = x + params["embed"]["pos"][pos_idx][None]
+        if "norm" in params["embed"]:
+            x = _norm(x, params["embed"]["norm"]["scale"],
+                      params["embed"]["norm"].get("bias"), cfg.norm, cfg.norm_eps)
 
-    # visibility of pooled (previous-chunk) slots: strictly before start
-    prev_vis = jnp.arange(S_prev)[None, :] < start  # [1, S_prev]
-    causal = jnp.arange(C)[:, None] >= jnp.arange(C)[None, :]  # [C(q), C(k)]
-    blocked = {}
-    if cfg.block_length:
-        # generation by blocks: causal between blocks, bidirectional inside
-        # one (``start`` and the chunk are whole blocks)
-        blocked = {"block": cfg.block_length}
-        causal = (jnp.arange(C)[:, None] | (cfg.block_length - 1)
-                  ) >= jnp.arange(C)[None, :]
+    with region("attn_glue"):
+        # visibility of pooled (previous-chunk) slots: strictly before start
+        prev_vis = jnp.arange(S_prev)[None, :] < start  # [1, S_prev]
+        causal = jnp.arange(C)[:, None] >= jnp.arange(C)[None, :]  # [C(q), C(k)]
+        blocked = {}
+        if cfg.block_length:
+            # generation by blocks: causal between blocks, bidirectional inside
+            # one (``start`` and the chunk are whole blocks)
+            blocked = {"block": cfg.block_length}
+            causal = (jnp.arange(C)[:, None] | (cfg.block_length - 1)
+                      ) >= jnp.arange(C)[None, :]
 
     # quant + chunked stays on the XLA path: the kernel window would put
     # the chunk's OWN keys through the int8 round-trip while the fallback
@@ -1147,9 +1171,12 @@ def paged_prefill_chunk(cfg: TransformerConfig, params, pools,
         # prefill yields no token: the program ends at the last layer's K/V
         # write (no final norm, no head)
         return jnp.zeros((), x.dtype), pools
-    hidden = _norm(x[:, 0 if xdec else n - 1], params["final_norm"]["scale"],
-                   params["final_norm"].get("bias"), cfg.norm, cfg.norm_eps)
-    logits = logits_fn(cfg, params, hidden[:, None])[0, 0]
+    with region("head"):
+        hidden = _norm(x[:, 0 if xdec else n - 1],
+                       params["final_norm"]["scale"],
+                       params["final_norm"].get("bias"), cfg.norm,
+                       cfg.norm_eps)
+        logits = logits_fn(cfg, params, hidden[:, None])[0, 0]
     return logits, pools
 
 
@@ -1218,23 +1245,25 @@ def paged_verify(cfg: TransformerConfig, params, pools,
     ps = pools["k"].shape[2]
     trash = pools["k"].shape[1] - 1
     pos_w = positions[:, None] + jnp.arange(W)[None]  # [B, W]
-    x = params["embed"]["tok"][ids]  # [B, W, H]
-    if cfg.position == "learned":
-        pos_idx = jnp.minimum(pos_w, params["embed"]["pos"].shape[0] - 1)
-        x = x + params["embed"]["pos"][pos_idx]
-    if "norm" in params["embed"]:
-        x = _norm(x, params["embed"]["norm"]["scale"],
-                  params["embed"]["norm"].get("bias"), cfg.norm, cfg.norm_eps)
+    with region("embed"):
+        x = params["embed"]["tok"][ids]  # [B, W, H]
+        if cfg.position == "learned":
+            pos_idx = jnp.minimum(pos_w, params["embed"]["pos"].shape[0] - 1)
+            x = x + params["embed"]["pos"][pos_idx]
+        if "norm" in params["embed"]:
+            x = _norm(x, params["embed"]["norm"]["scale"],
+                      params["embed"]["norm"].get("bias"), cfg.norm, cfg.norm_eps)
 
-    valid = active[:, None] & (jnp.arange(W)[None] < n_valid[:, None])
-    S = page_table.shape[1] * ps
-    page_idx = jnp.where(
-        valid, page_table[jnp.arange(B)[:, None],
-                          jnp.minimum(pos_w // ps, page_table.shape[1] - 1)],
-        trash)
-    off = pos_w % ps
-    slot_pos = jnp.arange(S)[None, None]          # [1, 1, S]
-    vis = slot_pos <= pos_w[:, :, None]           # [B, W, S]
+    with region("attn_glue"):
+        valid = active[:, None] & (jnp.arange(W)[None] < n_valid[:, None])
+        S = page_table.shape[1] * ps
+        page_idx = jnp.where(
+            valid, page_table[jnp.arange(B)[:, None],
+                              jnp.minimum(pos_w // ps, page_table.shape[1] - 1)],
+            trash)
+        off = pos_w % ps
+        slot_pos = jnp.arange(S)[None, None]          # [1, 1, S]
+        vis = slot_pos <= pos_w[:, :, None]           # [B, W, S]
 
     def layer_fn(layer, l, x, pools):
         q, k, v = attn_qkv(cfg, layer, x, pos_w)  # [B, W, NH/KVH, D]
@@ -1245,9 +1274,11 @@ def paged_verify(cfg: TransformerConfig, params, pools,
 
     x, pools = _scan_layers(cfg, params, pools, x,
                             _Forms("speculative verify", attn=layer_fn))
-    hidden = _norm(x, params["final_norm"]["scale"],
-                   params["final_norm"].get("bias"), cfg.norm, cfg.norm_eps)
-    logits = logits_fn(cfg, params, hidden)  # [B, W, V]
+    with region("head"):
+        hidden = _norm(x, params["final_norm"]["scale"],
+                       params["final_norm"].get("bias"), cfg.norm,
+                       cfg.norm_eps)
+        logits = logits_fn(cfg, params, hidden)  # [B, W, V]
     return logits, pools
 
 
@@ -1267,27 +1298,29 @@ def paged_decode(cfg: TransformerConfig, params, pools,
     """
     B = last_tokens.shape[0]
     ps, trash = _page_geometry(pools)
-    x = params["embed"]["tok"][last_tokens][:, None]  # [B, 1, H]
-    if cfg.position == "learned":
-        x = x + params["embed"]["pos"][positions][:, None]
-    if "norm" in params["embed"]:
-        x = _norm(x, params["embed"]["norm"]["scale"],
-                  params["embed"]["norm"].get("bias"), cfg.norm, cfg.norm_eps)
+    with region("embed"):
+        x = params["embed"]["tok"][last_tokens][:, None]  # [B, 1, H]
+        if cfg.position == "learned":
+            x = x + params["embed"]["pos"][positions][:, None]
+        if "norm" in params["embed"]:
+            x = _norm(x, params["embed"]["norm"]["scale"],
+                      params["embed"]["norm"].get("bias"), cfg.norm, cfg.norm_eps)
 
-    # clamp the page lookup for INACTIVE rows: inside the multi-step
-    # scan a finished row's position stops advancing but may already sit
-    # one past its last page; the gathered index is discarded (the
-    # jnp.where routes the write to the trash page), active rows always
-    # index in range by the engine's headroom-reservation contract
-    page_idx = jnp.where(
-        active,
-        page_table[jnp.arange(B),
-                   jnp.minimum(positions // ps, page_table.shape[1] - 1)],
-        trash)
-    off = positions % ps
-    S = page_table.shape[1] * ps
-    slot_pos = jnp.arange(S)[None]  # [1, S]
-    vis = slot_pos <= positions[:, None]  # [B, S]
+    with region("attn_glue"):
+        # clamp the page lookup for INACTIVE rows: inside the multi-step
+        # scan a finished row's position stops advancing but may already sit
+        # one past its last page; the gathered index is discarded (the
+        # jnp.where routes the write to the trash page), active rows always
+        # index in range by the engine's headroom-reservation contract
+        page_idx = jnp.where(
+            active,
+            page_table[jnp.arange(B),
+                       jnp.minimum(positions // ps, page_table.shape[1] - 1)],
+            trash)
+        off = positions % ps
+        S = page_table.shape[1] * ps
+        slot_pos = jnp.arange(S)[None]  # [1, S]
+        vis = slot_pos <= positions[:, None]  # [B, S]
 
     use_kernel = _use_paged_kernel()
 
@@ -1447,9 +1480,11 @@ def paged_decode(cfg: TransformerConfig, params, pools,
         cross=({"mem": jnp.zeros((B, 1, cfg.ssm_inner), jnp.float32)}
                if cfg.ssm_inner else None))
     pools = _ring_slots(pools, like)
-    hidden = _norm(x, params["final_norm"]["scale"],
-                   params["final_norm"].get("bias"), cfg.norm, cfg.norm_eps)
-    logits = logits_fn(cfg, params, hidden)[:, 0]
+    with region("head"):
+        hidden = _norm(x, params["final_norm"]["scale"],
+                       params["final_norm"].get("bias"), cfg.norm,
+                       cfg.norm_eps)
+        logits = logits_fn(cfg, params, hidden)[:, 0]
     return logits, pools
 
 
@@ -1470,17 +1505,17 @@ def sample_tokens(logits, temps, key, sids, positions) -> jnp.ndarray:
     sids: [B] int32 per-row request ids; positions: [B] position the
     sampled token will occupy.  Returns [B] int32 token ids.
     """
-    z = logits.astype(jnp.float32)
-    greedy = jnp.argmax(z, axis=-1).astype(jnp.int32)
-
     def _one(sid, p, zrow, t):
         k = jax.random.fold_in(jax.random.fold_in(key, sid), p)
         return jax.random.categorical(
             k, zrow / jnp.maximum(t, 1e-6)).astype(jnp.int32)
 
-    sampled = jax.vmap(_one)(sids.astype(jnp.int32),
-                             positions.astype(jnp.int32), z, temps)
-    return jnp.where(temps > 0.0, sampled, greedy)
+    with region("sample"):
+        z = logits.astype(jnp.float32)
+        greedy = jnp.argmax(z, axis=-1).astype(jnp.int32)
+        sampled = jax.vmap(_one)(sids.astype(jnp.int32),
+                                 positions.astype(jnp.int32), z, temps)
+        return jnp.where(temps > 0.0, sampled, greedy)
 
 
 def paged_block_pass(cfg: TransformerConfig, params, pools, ids, masked,
@@ -1511,19 +1546,21 @@ def paged_block_pass(cfg: TransformerConfig, params, pools, ids, masked,
     they are ``B x G`` query rows of one K/V head."""
     R, Bk = ids.shape
     ps, trash = _page_geometry(pools)
-    tok = jnp.where(masked, jnp.int32(cfg.mask_token_id), ids)
-    x = params["embed"]["tok"][tok]  # [R, B, H]
-    pos = start[:, None] + jnp.arange(Bk)[None]  # [R, B]
-    page_idx = jnp.where(
-        active,
-        page_table[jnp.arange(R),
-                   jnp.minimum(start // ps, page_table.shape[1] - 1)],
-        trash)
-    at = (jnp.broadcast_to(page_idx[:, None], pos.shape), pos % ps)
-    last = start + Bk - 1  # the block's last position: what every query sees
-    S = page_table.shape[1] * ps
-    vis = jnp.broadcast_to((jnp.arange(S)[None] <= last[:, None])[:, None],
-                           (R, Bk, S))
+    with region("embed"):
+        tok = jnp.where(masked, jnp.int32(cfg.mask_token_id), ids)
+        x = params["embed"]["tok"][tok]  # [R, B, H]
+    with region("attn_glue"):
+        pos = start[:, None] + jnp.arange(Bk)[None]  # [R, B]
+        page_idx = jnp.where(
+            active,
+            page_table[jnp.arange(R),
+                       jnp.minimum(start // ps, page_table.shape[1] - 1)],
+            trash)
+        at = (jnp.broadcast_to(page_idx[:, None], pos.shape), pos % ps)
+        last = start + Bk - 1  # the block's last position: what every query sees
+        S = page_table.shape[1] * ps
+        vis = jnp.broadcast_to((jnp.arange(S)[None] <= last[:, None])[:, None],
+                               (R, Bk, S))
     KVH, G = cfg.kv_heads, cfg.n_heads // cfg.kv_heads
     use_kernel = _use_paged_kernel()
 
@@ -1547,10 +1584,12 @@ def paged_block_pass(cfg: TransformerConfig, params, pools, ids, masked,
 
     x, pools = _scan_layers(cfg, params, pools, x,
                             _Forms("the block pass", attn=layer_fn))
-    hidden = _norm(x, params["final_norm"]["scale"],
-                   params["final_norm"].get("bias"), cfg.norm, cfg.norm_eps)
-    ids, masked = reveal_tokens(logits_fn(cfg, params, hidden), ids, masked,
-                                n_reveal)
+    with region("head"):
+        hidden = _norm(x, params["final_norm"]["scale"],
+                       params["final_norm"].get("bias"), cfg.norm,
+                       cfg.norm_eps)
+        logits = logits_fn(cfg, params, hidden)
+    ids, masked = reveal_tokens(logits, ids, masked, n_reveal)
     return ids, masked, pools
 
 
@@ -1564,18 +1603,19 @@ def reveal_tokens(logits, ids, masked, n_reveal
     again.  logits: [R, B, V]; ids, masked: [R, B]; n_reveal: [R].  Returns
     the block's ``(ids, masked)`` after the pass — ``[R, B]`` integers are
     what crosses the link, never ``[R x B, V]`` logits."""
-    z = logits.astype(jnp.float32)
-    top = jnp.argmax(z, axis=-1).astype(jnp.int32)
-    # softmax(z)[argmax] = 1 / sum(exp(z - max))
-    conf = 1.0 / jnp.sum(jnp.exp(z - jnp.max(z, axis=-1, keepdims=True)),
-                         axis=-1)
-    conf = jnp.where(masked, conf, -1.0)
-    i = jnp.arange(ids.shape[1])
-    ahead = (conf[:, None, :] > conf[:, :, None]) | (
-        (conf[:, None, :] == conf[:, :, None]) & (i[None, None, :]
-                                                  < i[None, :, None]))
-    reveal = masked & (jnp.sum(ahead, axis=-1) < n_reveal[:, None])
-    return jnp.where(reveal, top, ids), masked & jnp.logical_not(reveal)
+    with region("sample"):
+        z = logits.astype(jnp.float32)
+        top = jnp.argmax(z, axis=-1).astype(jnp.int32)
+        # softmax(z)[argmax] = 1 / sum(exp(z - max))
+        conf = 1.0 / jnp.sum(jnp.exp(z - jnp.max(z, axis=-1, keepdims=True)),
+                             axis=-1)
+        conf = jnp.where(masked, conf, -1.0)
+        i = jnp.arange(ids.shape[1])
+        ahead = (conf[:, None, :] > conf[:, :, None]) | (
+            (conf[:, None, :] == conf[:, :, None]) & (i[None, None, :]
+                                                      < i[None, :, None]))
+        reveal = masked & (jnp.sum(ahead, axis=-1) < n_reveal[:, None])
+        return jnp.where(reveal, top, ids), masked & jnp.logical_not(reveal)
 
 
 def paged_multi_decode(cfg: TransformerConfig, params, pools,
@@ -1614,13 +1654,14 @@ def paged_multi_decode(cfg: TransformerConfig, params, pools,
         logits, pools = paged_decode(cfg, params, pools, last, pos,
                                      page_table, act)
         tok = sample_tokens(logits, temps, key, sids, pos + 1)
-        emit = act
-        tok = jnp.where(emit, tok, jnp.int32(-1))
-        produced = produced + emit.astype(jnp.int32)
-        eos_hit = emit & (eos_ids >= 0) & (tok == eos_ids)
-        act = emit & jnp.logical_not(eos_hit) & (produced < budgets)
-        last = jnp.where(emit, tok, last)
-        pos = pos + emit.astype(jnp.int32)
+        with region("sample"):  # (and what a row emits of it)
+            emit = act
+            tok = jnp.where(emit, tok, jnp.int32(-1))
+            produced = produced + emit.astype(jnp.int32)
+            eos_hit = emit & (eos_ids >= 0) & (tok == eos_ids)
+            act = emit & jnp.logical_not(eos_hit) & (produced < budgets)
+            last = jnp.where(emit, tok, last)
+            pos = pos + emit.astype(jnp.int32)
         return (pools, last, pos, act, produced), tok
 
     act0 = active & (budgets > 0)

@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from ..runtime.activation_checkpointing.checkpointing import get_policy
+from ..telemetry.regions import region
 from .transformer import (MODEL_AXIS, TransformerConfig, _mm, _nrm, _norm,
                           _rope, attn_mixer, init_layer_stack, mlp_block,
                           yarn_inv_freq)
@@ -141,9 +142,10 @@ def _conv_mix(cfg: TransformerConfig, layer, x, positions, mask, attn_fn):
             "the convolution mixer takes whole sequences: an attention_mask "
             "(padding inside a sequence) has no form here")
     c = layer["conv"]
-    with jax.named_scope("conv"):  # its operations carry the name in a trace
+    with region("norm"):
         z = _norm(x, layer["norm1"]["scale"], layer["norm1"].get("bias"),
                   cfg.norm, cfg.norm_eps)
+    with region("conv_mixer"):
         b, g, u = jnp.split(_mm(cfg, z, c["w_in"], None, MODEL_AXIS), 3,
                             axis=-1)
         v = b * u
@@ -633,15 +635,19 @@ def run_stack(cfg: TransformerConfig, stack, x, positions, mask, attn_fn,
             y, a = block(carry, layer)
             return y, ((a, act_row(y)) if with_act_stats else a)
 
-        if (n == 1 or not cfg.scan_layers
-                or (keeps and n <= UNROLLED_KEEPING_RUN)):
-            rows = []
-            for i in range(n):
-                x, y = body(x, jax.tree_util.tree_map(lambda a: a[i], layers))
-                rows.append(y)
-            ys = jax.tree_util.tree_map(lambda *r: jnp.stack(r), *rows)
-        else:
-            x, ys = jax.lax.scan(body, x, layers)
+        # (the loop under ``stack``: its own slices of the stacked weights
+        # and the residual adds have no other home)
+        with region("stack"):
+            if (n == 1 or not cfg.scan_layers
+                    or (keeps and n <= UNROLLED_KEEPING_RUN)):
+                rows = []
+                for i in range(n):
+                    x, y = body(x, jax.tree_util.tree_map(lambda a: a[i],
+                                                          layers))
+                    rows.append(y)
+                ys = jax.tree_util.tree_map(lambda *r: jnp.stack(r), *rows)
+            else:
+                x, ys = jax.lax.scan(body, x, layers)
         a, act = ys if with_act_stats else (ys, None)
         if with_act_stats:
             acts.append(act)

@@ -33,6 +33,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..runtime.activation_checkpointing.checkpointing import (DEFAULT_POLICY,
                                                               get_policy)
+from ..telemetry.regions import region
 
 MODEL_AXIS = "model"
 SEQ_AXIS = "sequence"
@@ -723,18 +724,20 @@ def attn_qkv(cfg: TransformerConfig, layer, x, positions):
     qb = cfg.use_bias or cfg.qkv_bias
     # post-norm: projections read the RAW residual stream; the norm comes
     # after the residual add in _block
-    h = x if cfg.post_norm else _norm(
-        x, layer["norm1"]["scale"], layer["norm1"].get("bias"), cfg.norm,
-        cfg.norm_eps)
-    q = (_mm(cfg, h, a["wq"], None, MODEL_AXIS) + (a["bq"] if qb else 0)).reshape(B, T, NH, D)
-    k = (_mm(cfg, h, a["wk"], None, MODEL_AXIS) + (a["bk"] if qb else 0)).reshape(B, T, KVH, D)
-    v = (_mm(cfg, h, a["wv"], None, MODEL_AXIS) + (a["bv"] if qb else 0)).reshape(B, T, KVH, D)
-    if cfg.qk_norm:
-        q = _norm(q, a["q_norm"], None, "rmsnorm", cfg.norm_eps)
-        k = _norm(k, a["k_norm"], None, "rmsnorm", cfg.norm_eps)
-    if cfg.position == "rope":
-        q = _rope(q, cfg.rope_theta, positions, cfg.rotary_pct)
-        k = _rope(k, cfg.rope_theta, positions, cfg.rotary_pct)
+    with region("norm"):
+        h = x if cfg.post_norm else _norm(
+            x, layer["norm1"]["scale"], layer["norm1"].get("bias"), cfg.norm,
+            cfg.norm_eps)
+    with region("attn_qkv"):
+        q = (_mm(cfg, h, a["wq"], None, MODEL_AXIS) + (a["bq"] if qb else 0)).reshape(B, T, NH, D)
+        k = (_mm(cfg, h, a["wk"], None, MODEL_AXIS) + (a["bk"] if qb else 0)).reshape(B, T, KVH, D)
+        v = (_mm(cfg, h, a["wv"], None, MODEL_AXIS) + (a["bv"] if qb else 0)).reshape(B, T, KVH, D)
+        if cfg.qk_norm:
+            q = _norm(q, a["q_norm"], None, "rmsnorm", cfg.norm_eps)
+            k = _norm(k, a["k_norm"], None, "rmsnorm", cfg.norm_eps)
+        if cfg.position == "rope":
+            q = _rope(q, cfg.rope_theta, positions, cfg.rotary_pct)
+            k = _rope(k, cfg.rope_theta, positions, cfg.rotary_pct)
     return q, k, v
 
 
@@ -751,7 +754,8 @@ def mlp_block(cfg: TransformerConfig, layer, x, training: bool = True):
         ln = layer["norm1"]
     else:
         ln = layer["norm2"]
-    h = _norm(x, ln["scale"], ln.get("bias"), cfg.norm, cfg.norm_eps)
+    with region("norm"):
+        h = _norm(x, ln["scale"], ln.get("bias"), cfg.norm, cfg.norm_eps)
     h, aux = _ffn(cfg, layer, h, training)
     return x + h, aux
 
@@ -782,26 +786,37 @@ def _ffn(cfg: TransformerConfig, layer, h, training: bool = True):
             # qwen2-moe: the shared expert sees every token; its output is
             # gated by a per-token sigmoid scalar and ADDED to the routed
             # output (reference qwen_v2_moe model implementation)
-            sh = _mm(cfg, jax.nn.silu(
-                _mm(cfg, h, m["shared_w_gate"], None, MODEL_AXIS))
-                * _mm(cfg, h, m["shared_w_up"], None, MODEL_AXIS),
-                m["shared_w_down"], MODEL_AXIS, None)
-            if cfg.moe_shared_gate:
-                sgate = jax.nn.sigmoid(
-                    (h @ m["shared_gate"]).astype(jnp.float32))
-                sh = (sgate * sh.astype(jnp.float32)).astype(moe_out.dtype)
-            moe_out = moe_out + sh
+            with region("shared_expert"):
+                sh = _mm(cfg, jax.nn.silu(
+                    _mm(cfg, h, m["shared_w_gate"], None, MODEL_AXIS))
+                    * _mm(cfg, h, m["shared_w_up"], None, MODEL_AXIS),
+                    m["shared_w_down"], MODEL_AXIS, None)
+                if cfg.moe_shared_gate:
+                    sgate = jax.nn.sigmoid(
+                        (h @ m["shared_gate"]).astype(jnp.float32))
+                    sh = (sgate * sh.astype(jnp.float32)
+                          ).astype(moe_out.dtype)
+                moe_out = moe_out + sh
         if cfg.moe_use_residual:
             # PR-MoE (reference moe/layer.py use_residual): dense MLP beside
             # the MoE, mixed by a learned per-token 2-way coefficient
             act = jax.nn.silu if cfg.activation == "swiglu" else jax.nn.gelu
-            res = _mm(cfg, act(_mm(cfg, h, m["res_w_up"], None, MODEL_AXIS)),
-                      m["res_w_down"], MODEL_AXIS, None)  # plain dense MLP
-            coef = jax.nn.softmax((h @ m["coef"]).astype(jnp.float32), -1)
-            h = (moe_out * coef[..., 0:1] + res * coef[..., 1:2]).astype(moe_out.dtype)
+            with region("shared_expert"):
+                res = _mm(cfg, act(_mm(cfg, h, m["res_w_up"], None,
+                                       MODEL_AXIS)),
+                          m["res_w_down"], MODEL_AXIS, None)  # plain dense MLP
+                coef = jax.nn.softmax((h @ m["coef"]).astype(jnp.float32), -1)
+                h = (moe_out * coef[..., 0:1] + res * coef[..., 1:2]).astype(moe_out.dtype)
         else:
             h = moe_out
-    elif cfg.activation == "swiglu":
+        return h, aux
+    with region("mlp"):
+        return _dense_ffn(cfg, m, h), aux
+
+
+def _dense_ffn(cfg: TransformerConfig, m, h):
+    """The dense feed-forward part of ``_ffn``."""
+    if cfg.activation == "swiglu":
         h = _mm(cfg, jax.nn.silu(_mm(cfg, h, m["w_gate"], None, MODEL_AXIS))
                 * _mm(cfg, h, m["w_up"], None, MODEL_AXIS),
                 m["w_down"], MODEL_AXIS, None)
@@ -820,7 +835,7 @@ def _ffn(cfg: TransformerConfig, layer, h, training: bool = True):
                 m["w_down"], MODEL_AXIS, None)
         if cfg.use_bias:
             h = h + m["b_down"]
-    return h, aux
+    return h
 
 
 def attn_mixer(cfg: TransformerConfig, layer, x, positions, mask, attn_fn):
@@ -832,6 +847,18 @@ def attn_mixer(cfg: TransformerConfig, layer, x, positions, mask, attn_fn):
     a = layer["attn"]
 
     q, k, v = attn_qkv(cfg, layer, x, positions)
+    with region("attn_glue"):
+        attn = _attend(cfg, attn_fn, q, k, v, positions, mask)
+        attn = attn.reshape(B, S, NH * D)
+    with region("attn_out"):
+        return _mm(cfg, attn, a["wo"], MODEL_AXIS, None) \
+            + (a["bo"] if cfg.use_bias else 0)
+
+
+def _attend(cfg: TransformerConfig, attn_fn, q, k, v, positions, mask):
+    """``attn_fn`` over ``attn_qkv``'s heads, with what it does not handle
+    itself made for it first (the GQA repeat, the ALiBi bias)."""
+    NH, KVH = cfg.n_heads, cfg.kv_heads
     if not getattr(attn_fn, "handles_gqa", False):
         # GQA-aware impls (flash) read each kv head once through the kernel
         # index map; everyone else gets the materialized repeat
@@ -843,18 +870,12 @@ def attn_mixer(cfg: TransformerConfig, layer, x, positions, mask, attn_fn):
         # which differs only by a per-row constant)
         if getattr(attn_fn, "handles_alibi", False):
             # flash: bias built in-kernel from block indices
-            attn = attn_fn(q, k, v, cfg.causal, mask,
-                           alibi=alibi_slopes(NH))
-        else:
-            rel = (positions[:, None, :, None]
-                   - positions[:, None, None, :]).astype(jnp.float32)
-            attn = attn_fn(q, k, v, cfg.causal, mask,
-                           bias=-alibi_slopes(NH)[None, :, None, None] * rel)
-    else:
-        attn = attn_fn(q, k, v, cfg.causal, mask)
-    attn = attn.reshape(B, S, NH * D)
-    return _mm(cfg, attn, a["wo"], MODEL_AXIS, None) \
-        + (a["bo"] if cfg.use_bias else 0)
+            return attn_fn(q, k, v, cfg.causal, mask, alibi=alibi_slopes(NH))
+        rel = (positions[:, None, :, None]
+               - positions[:, None, None, :]).astype(jnp.float32)
+        return attn_fn(q, k, v, cfg.causal, mask,
+                       bias=-alibi_slopes(NH)[None, :, None, None] * rel)
+    return attn_fn(q, k, v, cfg.causal, mask)
 
 
 def _block(cfg: TransformerConfig, x, layer, positions, mask, attn_fn):
@@ -866,11 +887,13 @@ def _block(cfg: TransformerConfig, x, layer, positions, mask, attn_fn):
         return out + attn_delta, aux
     if cfg.post_norm:
         # BERT/original-transformer ordering: norm AFTER each residual add
-        h = _norm(x + attn_delta, layer["norm1"]["scale"],
-                  layer["norm1"].get("bias"), cfg.norm, cfg.norm_eps)
+        with region("norm"):
+            h = _norm(x + attn_delta, layer["norm1"]["scale"],
+                      layer["norm1"].get("bias"), cfg.norm, cfg.norm_eps)
         ffn, aux = _ffn(cfg, layer, h)
-        out = _norm(h + ffn, layer["norm2"]["scale"],
-                    layer["norm2"].get("bias"), cfg.norm, cfg.norm_eps)
+        with region("norm"):
+            out = _norm(h + ffn, layer["norm2"]["scale"],
+                        layer["norm2"].get("bias"), cfg.norm, cfg.norm_eps)
         return out, aux
     return mlp_block(cfg, layer, x + attn_delta)
 
@@ -891,18 +914,21 @@ def transformer_forward(cfg: TransformerConfig, params, input_ids, mask=None,
     output) as a third element.  Computed OUTSIDE the (possibly
     overlap-wrapped, possibly remat'd) block call, so the overlap hook's
     shard_map specs and the remat policy are untouched."""
-    x = params["embed"]["tok"][input_ids]
+    with region("embed"):
+        x = params["embed"]["tok"][input_ids]
     B, S = input_ids.shape
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
-    if cfg.position == "learned":
-        x = x + params["embed"]["pos"][:S][None]
-    if "type" in params["embed"]:  # BERT segment embeddings
-        tt = (token_type_ids if token_type_ids is not None
-              else jnp.zeros_like(input_ids))
-        x = x + params["embed"]["type"][tt]
-    if "norm" in params["embed"]:  # post-norm models norm the embeddings
-        x = _norm(x, params["embed"]["norm"]["scale"],
-                  params["embed"]["norm"].get("bias"), cfg.norm, cfg.norm_eps)
+    with region("embed"):
+        if cfg.position == "learned":
+            x = x + params["embed"]["pos"][:S][None]
+        if "type" in params["embed"]:  # BERT segment embeddings
+            tt = (token_type_ids if token_type_ids is not None
+                  else jnp.zeros_like(input_ids))
+            x = x + params["embed"]["type"][tt]
+        if "norm" in params["embed"]:  # post-norm models norm the embeddings
+            x = _norm(x, params["embed"]["norm"]["scale"],
+                      params["embed"]["norm"].get("bias"), cfg.norm,
+                      cfg.norm_eps)
     attn_fn = _pick_attn(cfg)
     if with_act_stats:
         # lazy: telemetry must stay an optional dependency of the model code
@@ -913,9 +939,10 @@ def transformer_forward(cfg: TransformerConfig, params, input_ids, mask=None,
 
         x, aux, act, moe = run_stack(cfg, params["layers"], x, positions,
                                      mask, attn_fn, with_act_stats)
-        hidden = _norm(x, params["final_norm"]["scale"],
-                       params["final_norm"].get("bias"), cfg.norm,
-                       cfg.norm_eps)
+        with region("head"):
+            hidden = _norm(x, params["final_norm"]["scale"],
+                           params["final_norm"].get("bias"), cfg.norm,
+                           cfg.norm_eps)
         return ((hidden, aux) + ((act,) if with_act_stats else ())
                 + ((moe,) if with_moe_counters else ()))
     if with_moe_counters:
@@ -945,6 +972,8 @@ def transformer_forward(cfg: TransformerConfig, params, input_ids, mask=None,
     if cfg.remat:
         block = jax.checkpoint(block, policy=get_policy(cfg.remat_policy))
 
+    # (the layer loop under ``stack``: the loop's own slices of the stacked
+    # weights and updates of the residuals have no other home)
     if cfg.scan_layers:
         # stage-3 manual prefetch (zero3_prefetch, engine-set per trace):
         # unroll the layer scan 2x so each trip holds TWO independent
@@ -961,28 +990,33 @@ def transformer_forward(cfg: TransformerConfig, params, input_ids, mask=None,
                 y, aux = block(carry, layer, comm_s)
                 return y, ((aux, _act_row(y)) if with_act_stats else aux)
 
-            x, ys = jax.lax.scan(scan_body, x,
-                                 (params["layers"], comm_state),
-                                 unroll=unroll)
+            with region("stack"):
+                x, ys = jax.lax.scan(scan_body, x,
+                                     (params["layers"], comm_state),
+                                     unroll=unroll)
         else:
             def scan_body(carry, layer):
                 y, aux = block(carry, layer)
                 return y, ((aux, _act_row(y)) if with_act_stats else aux)
 
-            x, ys = jax.lax.scan(scan_body, x, params["layers"],
-                                 unroll=unroll)
+            with region("stack"):
+                x, ys = jax.lax.scan(scan_body, x, params["layers"],
+                                     unroll=unroll)
         auxs, act = ys if with_act_stats else (ys, None)
         aux = jnp.sum(auxs)
     else:
         aux = jnp.asarray(0.0, jnp.float32)
         act_rows = []
         for i in range(cfg.n_layers):
-            layer = jax.tree_util.tree_map(lambda a: a[i], params["layers"])
-            if comm_state is not None:
-                comm_s = jax.tree_util.tree_map(lambda a: a[i], comm_state)
-                x, a = block(x, layer, comm_s)
-            else:
-                x, a = block(x, layer)
+            with region("stack"):
+                layer = jax.tree_util.tree_map(lambda a: a[i],
+                                               params["layers"])
+                if comm_state is not None:
+                    comm_s = jax.tree_util.tree_map(lambda a: a[i],
+                                                    comm_state)
+                    x, a = block(x, layer, comm_s)
+                else:
+                    x, a = block(x, layer)
             aux = aux + a
             if with_act_stats:
                 act_rows.append(_act_row(x))
@@ -991,21 +1025,24 @@ def transformer_forward(cfg: TransformerConfig, params, input_ids, mask=None,
     if cfg.post_norm:
         # each block already ends in norm2; a final norm would re-normalize
         return (x, aux, act) if with_act_stats else (x, aux)
-    hidden = _norm(x, params["final_norm"]["scale"], params["final_norm"].get("bias"),
-                   cfg.norm, cfg.norm_eps)
+    with region("head"):
+        hidden = _norm(x, params["final_norm"]["scale"],
+                       params["final_norm"].get("bias"), cfg.norm,
+                       cfg.norm_eps)
     return (hidden, aux, act) if with_act_stats else (hidden, aux)
 
 
 def logits_fn(cfg: TransformerConfig, params, hidden):
-    if cfg.tie_embeddings:
-        return hidden @ params["embed"]["tok"].T
-    w = params["lm_head"]["w"]
-    if isinstance(w, dict):  # weight-only quantized head
-        out = _mm(cfg, hidden, w)
-    else:
-        out = hidden @ w
-    b = params["lm_head"].get("b")  # phi-style biased head
-    return out if b is None else out + b
+    with region("head"):
+        if cfg.tie_embeddings:
+            return hidden @ params["embed"]["tok"].T
+        w = params["lm_head"]["w"]
+        if isinstance(w, dict):  # weight-only quantized head
+            out = _mm(cfg, hidden, w)
+        else:
+            out = hidden @ w
+        b = params["lm_head"].get("b")  # phi-style biased head
+        return out if b is None else out + b
 
 
 def causal_lm_loss(cfg: TransformerConfig, params, batch, rng=None):
@@ -1035,14 +1072,22 @@ def causal_lm_loss(cfg: TransformerConfig, params, batch, rng=None):
                                   with_act_stats=with_act)
     hidden, aux = fwd[0], fwd[1]
     act = fwd[2] if with_act else None
-    hidden = hidden[:, :-1]
-    targets = labels[:, 1:]
-    m = mask[:, 1:].astype(jnp.float32) if mask is not None else None
 
     def _out(loss):
         if with_moe:
             return loss, act, moe
         return (loss, act) if with_act else loss
+
+    with region("loss"):
+        return _out(_lm_loss(cfg, params, hidden, labels, mask) + aux)
+
+
+def _lm_loss(cfg: TransformerConfig, params, hidden, labels, mask):
+    """The next-token cross entropy of ``causal_lm_loss`` from the final
+    hidden states on."""
+    hidden = hidden[:, :-1]
+    targets = labels[:, 1:]
+    m = mask[:, 1:].astype(jnp.float32) if mask is not None else None
 
     if cfg.loss_chunk and hidden.shape[1] > cfg.loss_chunk:
         if hidden.shape[1] % cfg.loss_chunk == 0:
@@ -1052,7 +1097,7 @@ def causal_lm_loss(cfg: TransformerConfig, params, batch, rng=None):
             # inside
             nll_sum, cnt = _tiled_nll(cfg, params, hidden, targets, m,
                                       cfg.loss_chunk)
-            return _out(nll_sum / jnp.maximum(cnt, 1.0) + aux)
+            return nll_sum / jnp.maximum(cnt, 1.0)
         from ..utils.logging import warning_once
 
         warning_once(
@@ -1064,8 +1109,8 @@ def causal_lm_loss(cfg: TransformerConfig, params, batch, rng=None):
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     nll = nll_pick(logp, targets)
     if m is not None:
-        return _out(jnp.sum(nll * m) / jnp.maximum(jnp.sum(m), 1.0) + aux)
-    return _out(jnp.mean(nll) + aux)
+        return jnp.sum(nll * m) / jnp.maximum(jnp.sum(m), 1.0)
+    return jnp.mean(nll)
 
 
 def nll_pick(logp: jnp.ndarray, targets: jnp.ndarray) -> jnp.ndarray:
@@ -1132,24 +1177,26 @@ def _block_decode(cfg: TransformerConfig, x, layer, k_cache, v_cache, position):
         return jax.lax.dynamic_update_slice(
             cache, new.astype(cache.dtype), (0, position[0], 0, 0))
 
-    k_cache = upd(k_cache, k)
-    v_cache = upd(v_cache, v)
+    with region("attn_glue"):
+        k_cache = upd(k_cache, k)
+        v_cache = upd(v_cache, v)
 
-    kk = _repeat_kv(k_cache, NH // KVH)
-    vv = _repeat_kv(v_cache, NH // KVH)
-    S = kk.shape[1]
-    scores = jnp.einsum("btnd,bsnd->bnts", q, kk).astype(jnp.float32) / math.sqrt(D)
-    # causal vs cache: token t may see cache slots <= position + t
-    limit = (position[:, None, None, None] + jnp.arange(T)[None, None, :, None])
-    slot = jnp.arange(S)[None, None, None, :]
-    if cfg.position == "alibi":
-        scores = scores - alibi_slopes(NH)[None, :, None, None] \
-            * (limit - slot).astype(jnp.float32)
-    scores = jnp.where(slot <= limit, scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-    attn = jnp.einsum("bnts,bsnd->btnd", probs, vv).reshape(B, T, NH * D)
-    attn_delta = _mm(cfg, attn, a["wo"], MODEL_AXIS, None) \
-        + (a["bo"] if cfg.use_bias else 0)
+        kk = _repeat_kv(k_cache, NH // KVH)
+        vv = _repeat_kv(v_cache, NH // KVH)
+        S = kk.shape[1]
+        scores = jnp.einsum("btnd,bsnd->bnts", q, kk).astype(jnp.float32) / math.sqrt(D)
+        # causal vs cache: token t may see cache slots <= position + t
+        limit = (position[:, None, None, None] + jnp.arange(T)[None, None, :, None])
+        slot = jnp.arange(S)[None, None, None, :]
+        if cfg.position == "alibi":
+            scores = scores - alibi_slopes(NH)[None, :, None, None] \
+                * (limit - slot).astype(jnp.float32)
+        scores = jnp.where(slot <= limit, scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+        attn = jnp.einsum("bnts,bsnd->btnd", probs, vv).reshape(B, T, NH * D)
+    with region("attn_out"):
+        attn_delta = _mm(cfg, attn, a["wo"], MODEL_AXIS, None) \
+            + (a["bo"] if cfg.use_bias else 0)
     if cfg.parallel_block:
         out, _ = mlp_block(cfg, layer, x, training=False)
         return out + attn_delta, k_cache, v_cache
@@ -1166,14 +1213,16 @@ def forward_with_cache(cfg: TransformerConfig, params, input_ids, cache,
         raise NotImplementedError(
             "post_norm models (BERT-style encoders) have no KV-cache "
             "generative path; use transformer_forward + mlm_logits")
-    x = params["embed"]["tok"][input_ids]
     B, T = input_ids.shape
-    if cfg.position == "learned":
-        pos_idx = position[0] + jnp.arange(T)
-        x = x + jnp.take(params["embed"]["pos"], pos_idx, axis=0)[None]
-    if "norm" in params["embed"]:  # bloom word_embeddings_layernorm
-        x = _norm(x, params["embed"]["norm"]["scale"],
-                  params["embed"]["norm"].get("bias"), cfg.norm, cfg.norm_eps)
+    with region("embed"):
+        x = params["embed"]["tok"][input_ids]
+        if cfg.position == "learned":
+            pos_idx = position[0] + jnp.arange(T)
+            x = x + jnp.take(params["embed"]["pos"], pos_idx, axis=0)[None]
+        if "norm" in params["embed"]:  # bloom word_embeddings_layernorm
+            x = _norm(x, params["embed"]["norm"]["scale"],
+                      params["embed"]["norm"].get("bias"), cfg.norm,
+                      cfg.norm_eps)
 
     def scan_body(carry, inputs):
         x = carry
@@ -1181,10 +1230,13 @@ def forward_with_cache(cfg: TransformerConfig, params, input_ids, cache,
         y, k_c, v_c = _block_decode(cfg, x, layer, k_c, v_c, position)
         return y, (k_c, v_c)
 
-    x, (new_k, new_v) = jax.lax.scan(
-        scan_body, x, (params["layers"], cache["k"], cache["v"]))
-    hidden = _norm(x, params["final_norm"]["scale"], params["final_norm"].get("bias"),
-                   cfg.norm, cfg.norm_eps)
+    with region("stack"):
+        x, (new_k, new_v) = jax.lax.scan(
+            scan_body, x, (params["layers"], cache["k"], cache["v"]))
+    with region("head"):
+        hidden = _norm(x, params["final_norm"]["scale"],
+                       params["final_norm"].get("bias"), cfg.norm,
+                       cfg.norm_eps)
     logits = logits_fn(cfg, params, hidden)
     new_cache = {"k": new_k, "v": new_v, "length": position[0] + T}
     return logits, new_cache

@@ -20,6 +20,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..telemetry.regions import region
+
 
 @dataclasses.dataclass
 class MoEConfig:
@@ -268,11 +270,13 @@ def _expert_ffn_blocks(xs, experts, block_expert, n_real, activation,
         return grouped_matmul(a, w, block_expert, block_rows, impl=impl,
                               n_real=n_real)
 
-    if activation == "swiglu":
-        h = jax.nn.silu(gm(xs, experts["w_gate"])) * gm(xs, experts["w_up"])
-    else:
-        h = jax.nn.gelu(gm(xs, experts["w_up"]))
-    return gm(h, experts["w_down"])
+    with region("moe_glue"):  # (the grouped matmuls are kernels)
+        if activation == "swiglu":
+            h = jax.nn.silu(gm(xs, experts["w_gate"])) * gm(xs,
+                                                            experts["w_up"])
+        else:
+            h = jax.nn.gelu(gm(xs, experts["w_up"]))
+        return gm(h, experts["w_down"])
 
 
 def _sorted_expert_ffn(xt, key, gate, top_k: int, n_experts: int, experts,
@@ -294,26 +298,33 @@ def _sorted_expert_ffn(xt, key, gate, top_k: int, n_experts: int, experts,
     if impl == "pallas" or (impl == "auto" and rows.on_tpu()
                             and rows.rows_kernel_serves(
                                 xt.shape[1], xt.dtype, key.shape[0])):
-        (row_pick, n_valid, dest, counts, n_rows, block_expert,
-         n_real) = pick_row_maps(key, top_k, n_experts, block_rows)
+        with region("moe_route"):
+            (row_pick, n_valid, dest, counts, n_rows, block_expert,
+             n_real) = pick_row_maps(key, top_k, n_experts, block_rows)
         maps = (row_pick, n_valid, n_real, dest, block_rows)
-        xs = rows.moe_dispatch(xt, *maps)
+        with region("moe_glue"):  # (what XLA does round the row kernels)
+            xs = rows.moe_dispatch(xt, *maps)
         ys = _expert_ffn_blocks(xs, experts, block_expert, n_real,
                                 activation, block_rows, impl)
-        out = rows.moe_combine(ys, gate.reshape(dest.shape), *maps)
+        with region("moe_glue"):
+            out = rows.moe_combine(ys, gate.reshape(dest.shape), *maps)
         return out, counts, n_real * block_rows, n_rows
-    order, dest, n_rows, block_expert, n_real = sort_pad_by_expert(
-        key, n_experts, block_rows)
-    token_of = order // top_k
-    xs = jnp.zeros((n_rows, xt.shape[1]), xt.dtype).at[dest].set(
-        xt[token_of], mode="drop")
+    with region("moe_route"):
+        order, dest, n_rows, block_expert, n_real = sort_pad_by_expert(
+            key, n_experts, block_rows)
+        token_of = order // top_k
+    with region("moe_glue"):
+        xs = jnp.zeros((n_rows, xt.shape[1]), xt.dtype).at[dest].set(
+            xt[token_of], mode="drop")
     ys = _expert_ffn_blocks(xs, experts, block_expert, n_real, activation,
                             block_rows, impl)
-    contrib = (ys.at[dest].get(mode="fill", fill_value=0)
-               * gate[order][:, None].astype(ys.dtype))
-    out = jnp.zeros_like(xt).at[token_of].add(contrib.astype(xt.dtype))
-    counts = jnp.bincount(jnp.minimum(key, n_experts),
-                          length=n_experts + 1)[:n_experts]
+    with region("moe_glue"):
+        contrib = (ys.at[dest].get(mode="fill", fill_value=0)
+                   * gate[order][:, None].astype(ys.dtype))
+        out = jnp.zeros_like(xt).at[token_of].add(contrib.astype(xt.dtype))
+    with region("moe_route"):
+        counts = jnp.bincount(jnp.minimum(key, n_experts),
+                              length=n_experts + 1)[:n_experts]
     return out, counts, n_real * block_rows, n_rows
 
 
@@ -338,8 +349,10 @@ def moe_ffn_dropless(x: jnp.ndarray, gate_w: jnp.ndarray,
     K = cfg.top_k
     xt = x.reshape(T, H)
 
-    logits = xt @ gate_w
-    _, expert_idx, gate_k, aux = _gate_and_aux(logits, cfg, rng, router_bias)
+    with region("router"):
+        logits = xt @ gate_w
+        _, expert_idx, gate_k, aux = _gate_and_aux(logits, cfg, rng,
+                                                   router_bias)
 
     out, _, _, _ = _sorted_expert_ffn(
         xt, expert_idx.reshape(T * K), gate_k.reshape(T * K), K, E, experts,
@@ -372,26 +385,29 @@ def moe_ffn_share(x: jnp.ndarray, gate_w: jnp.ndarray,
     B, S, H = x.shape
     T, K = B * S, cfg.top_k
     xt = x.reshape(T, H)
-    logits = jnp.dot(xt.astype(jnp.float32), gate_w.astype(jnp.float32),
-                     precision=jax.lax.Precision.HIGHEST)
-    _, expert_idx, gate_k, _ = _gate_and_aux(logits, cfg, bias=router_bias)
-    local = expert_idx.reshape(T * K) - cfg.held_first
-    held = (local >= 0) & (local < cfg.held_count)
-    # a pick on an absent expert gets the invalid key
-    key = jnp.where(held, local, cfg.held_count)
+    with region("router"):
+        logits = jnp.dot(xt.astype(jnp.float32), gate_w.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        _, expert_idx, gate_k, _ = _gate_and_aux(logits, cfg,
+                                                 bias=router_bias)
+        local = expert_idx.reshape(T * K) - cfg.held_first
+        held = (local >= 0) & (local < cfg.held_count)
+        # a pick on an absent expert gets the invalid key
+        key = jnp.where(held, local, cfg.held_count)
     out, counts, ran_rows, grid_rows = _sorted_expert_ffn(
         xt, key, gate_k.reshape(T * K), K, cfg.held_count, experts,
         activation,
         block_rows or expert_block_rows(T * K / cfg.num_experts, x.dtype))
-    if training:
-        stats = jnp.concatenate([counts, jnp.stack([
-            ran_rows, jnp.full((), grid_rows, counts.dtype),
-            jnp.ones((), counts.dtype)])]).astype(jnp.int32)
-        return out.reshape(B, S, H), stats
-    stats = jnp.stack([jnp.sum(counts), jnp.sum(counts > 0), ran_rows,
-                       jnp.ones((), counts.dtype),
-                       jnp.full((), grid_rows, counts.dtype)]
-                      ).astype(jnp.int32)
+    with region("moe_route"):  # (the counters)
+        if training:
+            stats = jnp.concatenate([counts, jnp.stack([
+                ran_rows, jnp.full((), grid_rows, counts.dtype),
+                jnp.ones((), counts.dtype)])]).astype(jnp.int32)
+        else:
+            stats = jnp.stack([jnp.sum(counts), jnp.sum(counts > 0),
+                               ran_rows, jnp.ones((), counts.dtype),
+                               jnp.full((), grid_rows, counts.dtype)]
+                              ).astype(jnp.int32)
     return out.reshape(B, S, H), stats
 
 
@@ -416,12 +432,12 @@ def moe_ffn(x: jnp.ndarray, gate_w: jnp.ndarray, experts: Dict[str, jnp.ndarray]
                 "and trained: there is no capacity form of it "
                 "(moe_drop_tokens=True with a share)")
         # a share has no auxiliary loss: its counters take the slot
-        with jax.named_scope("moe"):
-            return moe_ffn_share(x, gate_w, experts, cfg, activation,
-                                 router_bias=router_bias, training=training)
+        return moe_ffn_share(x, gate_w, experts, cfg, activation,
+                             router_bias=router_bias, training=training)
     if ep_dispatch_active(cfg):
-        out = moe_ffn_ep(x, gate_w, experts, cfg, activation=activation,
-                         rng=rng, training=training)
+        with region("moe_glue"):
+            out = moe_ffn_ep(x, gate_w, experts, cfg, activation=activation,
+                             rng=rng, training=training)
         if out is not None:
             return out
     if not cfg.drop_tokens:
@@ -436,18 +452,22 @@ def moe_ffn(x: jnp.ndarray, gate_w: jnp.ndarray, experts: Dict[str, jnp.ndarray]
     xt = x.reshape(T, H)
     capacity = compute_capacity(T, cfg, training)
 
-    logits = xt @ gate_w  # [T, E] — gate in fp32 for stable routing
-    combine, dispatch, aux = top_k_gating(logits, cfg, capacity, rng)
+    with region("router"):
+        logits = xt @ gate_w  # [T, E] — gate in fp32 for stable routing
+        combine, dispatch, aux = top_k_gating(logits, cfg, capacity, rng)
 
     # dispatch: [E, C, H] — expert dim sharded over the "expert" mesh axis in
     # the stacked weights drives XLA to all-to-all these buffers over ICI
-    expert_in = jnp.einsum("tec,th->ech", dispatch.astype(x.dtype), xt)
-    if activation == "swiglu":
-        h = jax.nn.silu(jnp.einsum("ech,ehf->ecf", expert_in, experts["w_gate"]))
-        h = h * jnp.einsum("ech,ehf->ecf", expert_in, experts["w_up"])
-    else:
-        h = jax.nn.gelu(jnp.einsum("ech,ehf->ecf", expert_in, experts["w_up"]))
-    expert_out = jnp.einsum("ecf,efh->ech", h, experts["w_down"])
+    with region("moe_glue"):
+        expert_in = jnp.einsum("tec,th->ech", dispatch.astype(x.dtype), xt)
+        if activation == "swiglu":
+            h = jax.nn.silu(jnp.einsum("ech,ehf->ecf", expert_in,
+                                       experts["w_gate"]))
+            h = h * jnp.einsum("ech,ehf->ecf", expert_in, experts["w_up"])
+        else:
+            h = jax.nn.gelu(jnp.einsum("ech,ehf->ecf", expert_in,
+                                       experts["w_up"]))
+        expert_out = jnp.einsum("ecf,efh->ech", h, experts["w_down"])
 
-    out = jnp.einsum("tec,ech->th", combine.astype(x.dtype), expert_out)
+        out = jnp.einsum("tec,ech->th", combine.astype(x.dtype), expert_out)
     return out.reshape(B, S, H), aux

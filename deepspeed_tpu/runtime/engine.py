@@ -40,6 +40,7 @@ from ..parallel.mesh import MeshTopology
 from ..telemetry.compile_sentinel import (expect_recompile, note_program,
                                           publish_setup_seconds, setup_span)
 from ..telemetry.flight import dump_on_exception
+from ..telemetry.regions import region, region_index
 from ..telemetry.spans import record_event, span
 from ..utils.jax_compat import shard_map
 from ..utils.logging import log_dist, logger
@@ -925,8 +926,10 @@ class DeepSpeedTPUEngine:
         sharding (stage 3: still sharded; XLA all-gathers per-layer at use,
         in compute dtype — the fetch/release of the reference's
         PartitionedParameterCoordinator, for free)."""
-        p = cast_tree(self._fetch_params(master_params), self.compute_dtype)
-        return self.zero_plan.constrain(p, "param")
+        with region("optimizer"):  # (the master's cast: the step's, once)
+            p = cast_tree(self._fetch_params(master_params),
+                          self.compute_dtype)
+            return self.zero_plan.constrain(p, "param")
 
     def _micro_grads(self, state: TrainState, batch, rng, compute_params=None,
                      want_overflow=False):
@@ -1027,15 +1030,16 @@ class DeepSpeedTPUEngine:
         else:
             grads, (loss, act) = jax.grad(scaled_loss_fn,
                                           has_aux=True)(compute_params)
-        grads = cast_tree(grads, self.grad_accum_dtype)
-        grads = self.zero_plan.constrain(grads, "grad")
-        bad = None
-        if self.fp16_enabled and (new_comm is not None or want_overflow):
-            # ONE finiteness verdict per micro-step, on the POST-CAST
-            # grads (exactly the tree _apply_step_body's skip decision
-            # checks; the cast can only create nonfinites, never remove
-            # them, so this is conservative for the residual gate too)
-            bad = check_overflow(grads)
+        with region("optimizer"):  # (the gradients' casts and their check)
+            grads = cast_tree(grads, self.grad_accum_dtype)
+            grads = self.zero_plan.constrain(grads, "grad")
+            bad = None
+            if self.fp16_enabled and (new_comm is not None or want_overflow):
+                # ONE finiteness verdict per micro-step, on the POST-CAST
+                # grads (exactly the tree _apply_step_body's skip decision
+                # checks; the cast can only create nonfinites, never remove
+                # them, so this is conservative for the residual gate too)
+                bad = check_overflow(grads)
         if new_comm is not None and self.fp16_enabled:
             # an fp16 overflow step must not poison the carried residuals:
             # the backward's inf/nan rides the quantize (scale=inf -> NaN
@@ -1070,7 +1074,8 @@ class DeepSpeedTPUEngine:
         shape."""
         grads, loss, new_comm, extras = self._micro_grads(
             state, batch, rng, compute_params=compute_params)
-        new_acc = jax.tree_util.tree_map(jnp.add, state.grad_acc, grads)
+        with region("optimizer"):  # (the accumulate)
+            new_acc = jax.tree_util.tree_map(jnp.add, state.grad_acc, grads)
         state = dataclasses.replace(
             state, grad_acc=new_acc, micro_step=state.micro_step + 1,
             comm_errors=(new_comm if new_comm is not None
@@ -1188,6 +1193,13 @@ class DeepSpeedTPUEngine:
 
     def _apply_step_body(self, state: TrainState, grads_src=None,
                          overflow=None) -> TrainState:
+        """``_apply_update`` as the ``optimizer`` region of the step's
+        program (telemetry/regions.py)."""
+        with region("optimizer"):
+            return self._apply_update(state, grads_src, overflow)
+
+    def _apply_update(self, state: TrainState, grads_src=None,
+                      overflow=None) -> TrainState:
         """Boundary update.  ``grads_src``: gradients to apply instead of
         ``state.grad_acc`` — the fused gas=1 path feeds the micro-step's
         gradients straight through, skipping the accumulation-buffer
@@ -1383,7 +1395,10 @@ class DeepSpeedTPUEngine:
                                          with_act=with_act,
                                          with_moe=with_moe)
 
-        state, ys = jax.lax.scan(body, state, (batches, rngs))
+        # (the accumulation loop itself — its slices of the batch, the
+        # buffer it carries — is the optimizer's; the model names its own)
+        with region("optimizer"):
+            state, ys = jax.lax.scan(body, state, (batches, rngs))
         if not (with_act or with_moe):
             return state, jnp.mean(ys)
         losses, acts, moe = ys  # acts: [gas, L, 3]
@@ -1889,7 +1904,7 @@ class DeepSpeedTPUEngine:
         self._timeline_captured = capturing
         cap = (tl.capture(self.global_steps,
                           pipe_struct=getattr(self, "_pipe_struct", None),
-                          sync=self._timeline_sync)
+                          sync=self._timeline_sync, regions=region_index)
                if capturing else _no_trace())
         try:
             with cap, trace, span("train_batch", cat="train",

@@ -7,10 +7,13 @@ ONE step, parses the device trace events into categories, and publishes
 a **measured** per-step decomposition:
 
 * ``deepspeed_tpu_timeline_category_seconds{category}`` — where the
-  step's wall went: ``gemm`` / ``attention`` compute, each collective
-  kind (``all_reduce``, ``all_gather``, ``reduce_scatter``,
-  ``all_to_all``, ``collective_permute``), ``copy`` (copies/transposes),
-  ``other_compute``, ``host_gap`` (wall − device busy), and
+  step's wall went: compute by the model region that wrote it
+  (``regions.REGIONS``: ``mlp``, ``attn_qkv``, ``head``, ``optimizer``
+  ..., read from the compiled programs' own region tables;
+  ``unscoped`` what no table knows, never a guess by name), the Mosaic
+  kernels (``attention``, or a kernel's own name), each collective kind
+  (``all_reduce``, ``all_gather``, ``reduce_scatter``, ``all_to_all``,
+  ``collective_permute``), ``host_gap`` (wall − device busy), and
   ``pipe_bubble`` (the structural bubble share carved out of the gap
   when a pipe schedule runs). Every trace instant is attributed to
   exactly ONE category (overlapped collectives attribute to the compute
@@ -42,28 +45,34 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
+from .regions import REGIONS, UNSCOPED, lookup_region, program_name
+
 #: compute categories shadow collectives in the sweep: a collective
 #: running under compute is *overlapped* (hidden) and the instant
-#: belongs to the compute hiding it
-COMPUTE_CATEGORIES = ("attention", "gemm", "copy", "other_compute")
+#: belongs to the compute hiding it.  Compute is the attention kernels,
+#: the model regions (telemetry/regions.py) and what no region table
+#: knows; a Mosaic kernel that is not attention is a category of its own
+#: name (``grouped_matmul``, ``kda_step``), compute like these
+COMPUTE_CATEGORIES = ("attention",) + REGIONS + (UNSCOPED,)
 COLLECTIVE_CATEGORIES = ("all_reduce", "all_gather", "reduce_scatter",
                          "all_to_all", "collective_permute")
 CATEGORY_PRIORITY = COMPUTE_CATEGORIES + COLLECTIVE_CATEGORIES
-#: every category a record (measured or fallback) may carry
+#: every category a record (measured or fallback) may carry, the kernels'
+#: own names aside
 ALL_CATEGORIES = CATEGORY_PRIORITY + ("host_compute", "host_gap",
                                       "pipe_bubble")
 
-_ATTENTION_PAT = ("attention", "flash", "splash", "paged_attn",
-                  "paged_decode", "mha", "softmax")
-_GEMM_PAT = ("dot", "gemm", "matmul", "einsum", "conv")
-_COPY_PAT = ("copy", "transpose", "bitcast", "memcpy", "d2d", "h2d", "d2h")
+#: the attention kernels, by the names they have in a device trace
+_ATTENTION_PAT = ("flash", "splash", "paged_attn", "paged_decode",
+                  "window_decode", "mla_decode")
+_KERNEL_PREFIX = "dstpu_"
 
 
-def categorize_op(name: str) -> str:
-    """Map one device trace-event (HLO op) name to a category.
-
-    Unknown ops land in ``other_compute`` — never dropped: an op the
-    taxonomy doesn't know still spent real device time.
+def categorize_op(name: str, region: str = UNSCOPED) -> str:
+    """Map one device trace-event (HLO op) name to a category: a
+    collective by its opcode, a Mosaic kernel by its name, anything else
+    the ``region`` its program's table gives it — ``unscoped`` where no
+    table knows it, never a guess: on a TPU a gemm is ``fusion.167``.
     """
     n = str(name).lower()
     # collectives first: a fusion name can embed "dot" AND "all-reduce",
@@ -83,23 +92,27 @@ def categorize_op(name: str) -> str:
             return cat
     if any(p in n for p in _ATTENTION_PAT):
         return "attention"
-    if any(p in n for p in _GEMM_PAT):
-        return "gemm"
-    if any(p in n for p in _COPY_PAT):
-        return "copy"
-    return "other_compute"
+    if n.startswith(_KERNEL_PREFIX):
+        return n[len(_KERNEL_PREFIX):].split(".")[0]
+    return region
 
 
 def decompose_events(events: Sequence[Dict[str, Any]], wall_s: float,
-                     pipe_bubble_fraction: float = 0.0) -> Dict[str, Any]:
+                     pipe_bubble_fraction: float = 0.0,
+                     regions: Optional[Dict] = None) -> Dict[str, Any]:
     """Attribute a step's wall clock over device trace events.
 
     ``events``: ``{"name", "ts", "dur"}`` dicts in SECONDS (any common
-    epoch). Interval sweep, each instant attributed to exactly one
-    category (:data:`CATEGORY_PRIORITY` order — compute shadows
-    collectives), so ``sum(categories) == wall_s`` by construction
-    (``host_gap`` is the uncovered remainder; if device busy exceeds the
-    host wall — clock skew — everything is scaled down by ``scale``).
+    epoch); with ``regions`` (``regions.region_index()``) an event's
+    ``"program"`` and ``"text"`` — the run of the program that encloses
+    it and the instruction's whole text, as ``parse_xplane`` leaves them
+    — find its compute category in its program's region table. Interval
+    sweep, each instant attributed to exactly one category
+    (:data:`CATEGORY_PRIORITY` order, a kernel's own name after
+    ``attention`` — compute shadows collectives), so ``sum(categories)
+    == wall_s`` by construction (``host_gap`` is the uncovered
+    remainder; if device busy exceeds the host wall — clock skew —
+    everything is scaled down by ``scale``).
     """
     wall_s = max(0.0, float(wall_s))
     points: List[Tuple[float, int, str]] = []
@@ -109,22 +122,25 @@ def decompose_events(events: Sequence[Dict[str, Any]], wall_s: float,
         if dur <= 0:
             continue
         ts = float(ev.get("ts", 0.0) or 0.0)
-        cat = categorize_op(ev.get("name", ""))
+        cat = categorize_op(ev.get("name", ""), event_region(ev, regions))
         raw_busy[cat] = raw_busy.get(cat, 0.0) + dur
         points.append((ts, +1, cat))
         points.append((ts + dur, -1, cat))
-    categories = {c: 0.0 for c in CATEGORY_PRIORITY}
+    kernels = sorted(set(raw_busy) - set(CATEGORY_PRIORITY))
+    priority = (COMPUTE_CATEGORIES[:1] + tuple(kernels)
+                + CATEGORY_PRIORITY[1:])
+    categories = {c: 0.0 for c in priority}
     busy_union = coll_union = exposed_coll = 0.0
     if points:
         points.sort(key=lambda p: (p[0], -p[1]))
-        active = {c: 0 for c in CATEGORY_PRIORITY}
+        active = {c: 0 for c in priority}
         n_compute = n_coll = 0
         prev = points[0][0]
         for t, delta, cat in points:
             seg = t - prev
             if seg > 0 and (n_compute or n_coll):
                 busy_union += seg
-                for c in CATEGORY_PRIORITY:
+                for c in priority:
                     if active[c]:
                         categories[c] += seg
                         break
@@ -134,10 +150,10 @@ def decompose_events(events: Sequence[Dict[str, Any]], wall_s: float,
                         exposed_coll += seg
             prev = t
             active[cat] += delta
-            if cat in COMPUTE_CATEGORIES:
-                n_compute += delta
-            else:
+            if cat in COLLECTIVE_CATEGORIES:
                 n_coll += delta
+            else:
+                n_compute += delta
     scale = 1.0
     if busy_union > wall_s > 0:
         scale = wall_s / busy_union
@@ -165,9 +181,17 @@ def decompose_events(events: Sequence[Dict[str, Any]], wall_s: float,
     }
 
 
+def event_region(ev: Dict[str, Any], regions: Optional[Dict]) -> str:
+    """The region of a device event by its program's table; ``unscoped``
+    without a table or without the event's program and text."""
+    if regions is None or "text" not in ev:
+        return UNSCOPED
+    return lookup_region(regions, ev.get("program", ""), ev["text"])[0]
+
+
 # ---------------------------------------------------------- xplane parse
 #: control-flow operations contain their bodies' events: counted, a
-#: ``while`` (other_compute) would shadow every collective inside it
+#: ``while`` (compute) would shadow every collective inside it
 _CONTAINER_OPS = ("while", "conditional", "call")
 
 
@@ -192,7 +216,13 @@ def parse_xplane(path: str) -> Tuple[List[Dict[str, Any]],
     operation, named by the instruction's text (``%fusion.3 = bf16[..]
     fusion(..)``: the name kept is ``fusion.3``), and ``Async XLA Ops``
     the DMA side of asynchronous collectives, which overlaps compute;
-    a backend without those lines contributes every line of the plane."""
+    a backend without those lines contributes every line of the plane.
+    ``XLA Modules`` holds one event per executed program: an operation's
+    event carries the name of the run that encloses it (``"program"``)
+    and the instruction's whole text (``"text"``), which is what finds
+    it in its program's region table (telemetry/regions.py)."""
+    import bisect
+
     from jax.profiler import ProfileData
 
     events, artifact = [], []
@@ -203,6 +233,10 @@ def parse_xplane(path: str) -> Tuple[List[Dict[str, Any]],
             continue
         lines = list(plane.lines)
         has_ops = any(ln.name == "XLA Ops" for ln in lines)
+        runs = sorted((ev.start_ns, ev.start_ns + ev.duration_ns, ev.name)
+                      for ln in lines if ln.name == "XLA Modules"
+                      for ev in ln.events)
+        starts = [r[0] for r in runs]
         artifact.append({"ph": "M", "name": "process_name", "pid": pid,
                          "args": {"name": name}})
         for tid, line in enumerate(lines):
@@ -217,11 +251,16 @@ def parse_xplane(path: str) -> Tuple[List[Dict[str, Any]],
                     continue
                 if dma and categorize_op(op) not in COLLECTIVE_CATEGORIES:
                     continue  # an async copy is a DMA, not compute
+                i = bisect.bisect_right(starts, ev.start_ns) - 1
+                run = (runs[i][2] if i >= 0 and ev.start_ns < runs[i][1]
+                       else "")
                 artifact.append({"ph": "X", "name": op, "pid": pid,
                                  "tid": tid, "ts": ev.start_ns / 1e3,
-                                 "dur": ev.duration_ns / 1e3})
+                                 "dur": ev.duration_ns / 1e3,
+                                 "args": {"program": program_name(run)}})
                 events.append({"name": op, "ts": ev.start_ns / 1e9,
-                               "dur": ev.duration_ns / 1e9})
+                               "dur": ev.duration_ns / 1e9,
+                               "program": run, "text": ev.name})
     return (events, artifact) if events else ([], [])
 
 
@@ -262,6 +301,8 @@ class StepTimeline:
         self._force = False
         self._active = False
         self._my_last: Optional[Dict[str, Any]] = None
+        #: every category published so far (a kernel's own name among them)
+        self._published: set = set()
         self._m_cat = registry.gauge(
             "deepspeed_tpu_timeline_category_seconds",
             "measured step-time decomposition from the last profiler "
@@ -312,11 +353,16 @@ class StepTimeline:
     # ------------------------------------------------------------ capture
     @contextlib.contextmanager
     def capture(self, step: int, pipe_struct: Optional[Dict[str, Any]] = None,
-                sync: Optional[Callable[[], None]] = None):
+                sync: Optional[Callable[[], None]] = None,
+                regions: Optional[Callable[[], Dict]] = None):
         """Wrap ONE step. Exception-safe: the profiler trace is always
         stopped, an exception inside the step propagates unchanged (no
         half-step record is published), and no lock is held while user
-        code runs — a flight dump mid-capture cannot deadlock."""
+        code runs — a flight dump mid-capture cannot deadlock.
+        ``regions``: what the engine hands over to name compute by model
+        region (``regions.region_index``), asked after the step, on a
+        captured step only: the first ask builds the noted programs'
+        tables, inside the capture's overhead."""
         if self._active:
             yield
             return
@@ -355,7 +401,7 @@ class StepTimeline:
                 if ok:
                     self._finish(step, wall, t0_us, t1_us,
                                  tmpdir if started else None, pipe_struct,
-                                 overhead_t0)
+                                 overhead_t0, regions)
             # dstpu-lint: allow[swallow] attribution must never fail the
             # step it measures; a failed parse leaves the prior record
             except Exception:
@@ -365,7 +411,7 @@ class StepTimeline:
 
     def _finish(self, step: int, wall: float, t0_us: float, t1_us: float,
                 trace_dir: Optional[str], pipe_struct,
-                overhead_t0: float) -> None:
+                overhead_t0: float, regions=None) -> None:
         bubble = 0.0
         if pipe_struct:
             try:
@@ -381,9 +427,22 @@ class StepTimeline:
                 events, artifact_events = [], []
         measured = bool(events)
         if measured:
-            dec = decompose_events(events, wall, pipe_bubble_fraction=bubble)
+            index = None
+            if regions is not None:
+                try:
+                    index = regions()
+                except Exception:
+                    index = None  # compute then reads ``unscoped``
+            dec = decompose_events(events, wall, pipe_bubble_fraction=bubble,
+                                   regions=index)
             record = {"step": step, "measured": True, "wall_seconds": wall,
                       **dec}
+            # the artifact names each device operation ``<category>:<name>``
+            ops = (a for a in artifact_events if a.get("ph") == "X")
+            for ev, shown in zip(events, ops):
+                shown["name"] = (categorize_op(ev["name"],
+                                               event_region(ev, index))
+                                 + ":" + ev["name"])
         else:
             record = {"step": step, "measured": False, "wall_seconds": wall,
                       "categories": self._host_fallback(wall, t0_us, t1_us),
@@ -394,10 +453,11 @@ class StepTimeline:
                                                   artifact_events)
         # publish: zero every known category first so a fallback capture
         # doesn't leave stale measured numbers standing next to it
-        for c in ALL_CATEGORIES:
+        for c in self._published.union(ALL_CATEGORIES):
             self._m_cat.set(0.0, category=c)
         for c, v in record["categories"].items():
             self._m_cat.set(v, category=c)
+        self._published.update(record["categories"])
         self._m_measured.set(1.0 if measured else 0.0)
         if measured:
             self._m_exposed.set(record["exposed_collective_seconds"])
@@ -476,7 +536,9 @@ def capture_thunk(fn: Callable[[], Any], step: int = 0,
                   timeline: Optional[StepTimeline] = None,
                   pipe_struct: Optional[Dict[str, Any]] = None,
                   sync: Optional[Callable[[], None]] = None,
-                  artifact_dir: str = "") -> Tuple[Any, Optional[Dict[str, Any]]]:
+                  artifact_dir: str = "",
+                  regions: Optional[Callable[[], Dict]] = None
+                  ) -> Tuple[Any, Optional[Dict[str, Any]]]:
     """One-shot attribution of an arbitrary callable (a caller that owns
     no engine-side timeline). Returns
     ``(fn(), record)``; the record is None only if the capture machinery
@@ -484,6 +546,7 @@ def capture_thunk(fn: Callable[[], Any], step: int = 0,
     tl = timeline if timeline is not None else StepTimeline(
         every_n_steps=0, artifact_dir=artifact_dir)
     tl.force_next()
-    with tl.capture(step, pipe_struct=pipe_struct, sync=sync):
+    with tl.capture(step, pipe_struct=pipe_struct, sync=sync,
+                    regions=regions):
         out = fn()
     return out, tl.last_record()

@@ -436,7 +436,11 @@ def test_timeline_parses_the_recorded_v5e_trace_without_tensorflow():
     dec = timeline.decompose_events(events, wall)
     cats = dec["categories"]
     assert sum(cats.values()) == pytest.approx(wall, rel=1e-9)
-    assert cats["attention"] > cats["gemm"] > 0.0
+    # (no region table for a trace recorded before there were any: XLA's
+    # own operations are unscoped, never guessed at by name)
+    assert cats["attention"] > cats["unscoped"] > 0.0
+    assert all(e["program"].startswith("jit_step(") and " = " in e["text"]
+               for e in events)
     # what trace_reduce reads from the same file, less the hair-width gaps
     # between a while's body operations
     assert dec["device_busy_seconds"] == pytest.approx(1.776449e-4, rel=5e-3)
