@@ -286,7 +286,9 @@ def _sorted_expert_ffn(xt, key, gate, top_k: int, n_experts: int, experts,
     and weight.  Picks are sorted and padded by expert, run through the
     grouped matmuls and added back to their tokens.  ``impl="auto"`` moves
     the rows through the two row kernels on TPU (``dstpu_moe_dispatch``,
-    ``dstpu_moe_combine``: the work of the picks held here) and through
+    ``dstpu_moe_combine``: the work of the picks held here; the rows
+    ``rows_kernel_serves`` — float32 or bfloat16 in whole 128-lane tiles,
+    so a hidden size of 2048, 3072 or 4096 alike) and through
     XLA's scatter and gathers on the CPU test tier (``"xla"``, the reference
     form: an invalid pick is scattered out of bounds and gathered as zero),
     as ``grouped_matmul``, which takes the same ``impl``, chooses its own.

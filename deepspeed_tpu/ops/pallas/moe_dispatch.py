@@ -25,11 +25,18 @@ Each is the other's transpose, so the backward needs no third algorithm
 A row is fetched whole, as tiles: Mosaic cannot slice one row out of a
 ``[T, H]`` operand in HBM (its second-minor dimension is tiled by 8 or 16
 rows), so the source is handed over as ``[T, H / 128, 128]`` — a token is
-then whole ``(8, 128)`` tiles, contiguous in HBM, indexed on an untiled
-dimension — and the kernel reads lane tile ``c`` of every fetched row with
-one sublane-strided load.  A bfloat16 source is read through its uint32
-view (two of a token's lane tiles a word, low half first), because a
-packed row is half a sublane; float32 and bfloat16 are the dtypes served.
+then tiles of its own, indexed on an untiled dimension — and the kernel
+reads lane tile ``c`` of every fetched row with one sublane-strided load.
+A bfloat16 source is read through its uint32 view (two of a token's lane
+tiles a word, low half first), because a packed row is half a sublane;
+float32 and bfloat16 are the dtypes served.
+
+A fetched row is ``Sw`` sublanes of 32-bit words (``_row_tiles``) at
+``j * Sw`` of the ring, whatever ``Sw`` is: a VMEM buffer of words 128
+lanes wide is linear, a sublane after a sublane, so a copy lands at any of
+them and the loads take any stride.  Nothing needs a row to be whole
+8-sublane tiles: on a v5e both kernels read equal to XLA at 1, 2, 4, 8, 12
+(a bfloat16 row of 3072) and 24 word-sublanes (``PERF.md`` section 6, PR 57).
 """
 
 from __future__ import annotations
@@ -64,9 +71,8 @@ def _row_tiles(h: int, dtype):
 def rows_kernel_serves(h: int, dtype, picks: int) -> bool:
     """Whether the two kernels take a call of ``picks`` picks over rows of
     ``h`` of ``dtype``: float32 or bfloat16 in whole lane tiles, an even
-    number of them where two share a word, on the TPU whole ``(8, 128)``
-    tiles a row (the ring is sliced by row), and a pick map that fits the
-    scalar memory."""
+    number of them where two share a word, on the TPU tiles of all 128
+    lanes, and a pick map that fits the scalar memory."""
     dtype = jnp.dtype(dtype)
     if picks > _MAX_PICKS or dtype not in (jnp.dtype(jnp.float32),
                                            jnp.dtype(jnp.bfloat16)):
@@ -74,7 +80,7 @@ def rows_kernel_serves(h: int, dtype, picks: int) -> bool:
     s, lanes, sw = _row_tiles(h, dtype)
     if s * lanes != h or sw * (4 // dtype.itemsize) != s:
         return False
-    return not on_tpu() or (lanes == _LANES and sw % 8 == 0)
+    return not on_tpu() or lanes == _LANES
 
 
 def _as_tiles(x):

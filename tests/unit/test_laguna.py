@@ -569,3 +569,143 @@ def mimo_now():
 @pytest.mark.parametrize("what", sorted(_MIMO_AT_PARENT))
 def test_mimo_v2s_tiny_model_is_what_it_was(mimo_now, what):
     assert mimo_now[what] == _MIMO_AT_PARENT[what]
+
+
+# ------------------------------------- the picks' rows, a row of 3072 wide
+def test_the_picks_of_a_3072_wide_row_go_through_the_row_kernels(monkeypatch):
+    """ISSUE 57: on the TPU a bfloat16 row of Laguna's hidden 3072 — 12
+    word-sublanes — is served by ``dstpu_moe_dispatch`` / ``dstpu_moe_combine``
+    as the rows of 2048 and 4096 are, at a chunk call's and a decode call's
+    picks: ``_sorted_expert_ffn`` takes its kernel branch and no scatter is
+    left in the layer."""
+    from deepspeed_tpu.moe.sharded_moe import _sorted_expert_ffn
+    from deepspeed_tpu.ops.pallas import moe_dispatch as rows_mod
+    from deepspeed_tpu.ops.pallas.grouped_matmul import expert_block_rows
+
+    S, bf = jax.ShapeDtypeStruct, jnp.bfloat16
+    h, f, held, of, k = (CONFIG["hidden_size"], CONFIG["moe_intermediate_size"],
+                         CONFIG["num_experts"], 256,
+                         CONFIG["num_experts_per_tok"])
+    experts = {"w_gate": S((held, h, f), bf), "w_up": S((held, h, f), bf),
+               "w_down": S((held, f, h), bf)}
+
+    for on_chip in (False, True):
+        monkeypatch.setattr(rows_mod, "on_tpu", lambda: on_chip)
+
+        def tail(xt, key, gate, experts):  # (a trace is kept by function)
+            return _sorted_expert_ffn(
+                xt, key, gate, k, held, experts, "swiglu",
+                expert_block_rows(key.shape[0] / of, xt.dtype))[0]
+
+        for t in (CONFIG["engine"]["prefill_chunk"],
+                  CONFIG["engine"]["max_seqs"]):
+            text = str(jax.make_jaxpr(tail)(
+                S((t, h), bf), S((t * k,), jnp.int32),
+                S((t * k,), jnp.float32), experts))
+            names = [n in text for n in ("dstpu_moe_dispatch",
+                                         "dstpu_moe_combine")]
+            assert names == [on_chip] * 2, (on_chip, t)
+            assert ("scatter" in text) is not on_chip, (on_chip, t)
+
+
+def _mosaic_texts(lowered):
+    """The Mosaic kernels of a program lowered for the TPU, as text without
+    locations (a kernel's serialized body carries its file's line numbers,
+    which every edit of the file moves)."""
+    import base64
+    import re
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    out = []
+    for m in re.finditer(r'backend_config = "((?:[^"\\]|\\.)*)"',
+                         lowered.as_text()):
+        config = json.loads(m.group(1).replace("\\22", '"')
+                            .replace("\\5C", "\\"))
+        ctx = mlir.make_ir_context()
+        tpu.register_dialect(ctx)
+        ctx.allow_unregistered_dialects = True  # (stable_mosaic.*)
+        with ctx:
+            module = ir.Module.parse(base64.b64decode(
+                config["custom_call_config"]["body"]))
+            out.append(module.operation.get_asm(enable_debug_info=False))
+    return out
+
+
+# sha256[:16] of the kernels' text, locations stripped, taken on the parent
+# commit (dda6f27, jax 0.9.0) by the lines below: a row of whole (8, 128)
+# word tiles — every expert cell but Laguna's — must lower to the kernel it
+# had (ISSUE 57: "the other cells keep their text")
+_ROW_KERNELS_AT_PARENT = {
+    ("dispatch", 2048, "bfloat16"): "84cf4b086386de5f",
+    ("dispatch", 4096, "bfloat16"): "d6d8077e0b7500fd",
+    ("dispatch", 1024, "float32"): "534488ca727aa094",
+    ("combine", 2048, "bfloat16"): "b5f5583c4fb54dd9",
+    ("combine", 4096, "bfloat16"): "4683abb6eefbc0fa",
+    ("combine", 1024, "float32"): "353a29a1b52556b0",
+    ("combine_dot", 2048, "bfloat16"): "7fed79a8d64a5fe9",
+    ("combine_dot", 4096, "bfloat16"): "0d5d3834462fe717",
+    ("combine_dot", 1024, "float32"): "09b2a15e8563c635",
+}
+
+
+@pytest.mark.parametrize("which, h, dtype", sorted(_ROW_KERNELS_AT_PARENT))
+def test_a_row_of_whole_word_tiles_lowers_to_the_parents_kernel(
+        which, h, dtype, monkeypatch):
+    import deepspeed_tpu.utils.platform as plat
+    from deepspeed_tpu.ops.pallas import moe_dispatch as rows_mod
+
+    monkeypatch.setattr(plat, "platform", lambda: "tpu")  # compile, not interpret
+    S, i32, dt = jax.ShapeDtypeStruct, jnp.int32, jnp.dtype(dtype)
+    t, k, bs, blocks = 256, 8, 16, 40
+    if which == "dispatch":
+        fn = lambda x, rs, nv, nr: rows_mod.dispatch_rows(  # noqa: E731
+            x, rs, nv, nr, bs)
+        args = (S((t, h), dt), S((blocks * bs,), i32), S((blocks,), i32),
+                S((), i32))
+    elif which == "combine":
+        fn = lambda ys, dest, w: rows_mod.combine_rows(  # noqa: E731
+            ys, dest, weights=w)
+        args = (S((blocks * bs, h), dt), S((t, k), i32),
+                S((t, k), jnp.float32))
+    else:
+        fn = lambda ys, dest, d: rows_mod.combine_rows(  # noqa: E731
+            ys, dest, dot=d)
+        args = (S((blocks * bs, h), dt), S((t, k), i32), S((t, h), dt))
+    (text,) = _mosaic_texts(jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)))
+    assert "dstpu_moe_" + which.split("_")[0] in text
+    assert (hashlib.sha256(text.encode()).hexdigest()[:16]
+            == _ROW_KERNELS_AT_PARENT[which, h, dtype])
+
+
+def test_the_row_kernels_time_is_read_in_this_cell_under_a_name_of_its_own():
+    """``moe_dispatch_ms_per_step.typed``: the accepted ``op_ms`` reader with
+    ``moe_dispatch_ms_per_step``'s own arguments, listed for this cell alone
+    (``benchmark/tests/test_laguna_cell.py`` holds the un-suffixed list off
+    it), on the layer and the end-to-end metric the un-suffixed one has."""
+    cell = "lagunas21-ep8-codeagent-saturated"
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        listed = {m["name"]: m for m in json.load(f)["per_layer"]}
+
+    def metric_file(name):
+        with open(os.path.join(ROOT, "benchmark", "layer_metrics",
+                               name + ".json")) as f:
+            return json.load(f)
+
+    plain = "moe_dispatch_ms_per_step"
+    typed, was = metric_file(plain + ".typed"), metric_file(plain)
+    assert typed["name"] == plain + ".typed"
+    assert typed["reader"] == was["reader"] == "op_ms"
+    assert typed["args"] == was["args"]
+    assert typed["args"]["contains"] == ["dstpu_moe_dispatch",
+                                         "dstpu_moe_combine"]
+    entry = listed[plain + ".typed"]
+    assert entry["workloads"] == [cell]
+    assert cell not in listed[plain]["workloads"]
+    for key in ("unit", "better", "source", "layer", "moves"):
+        assert entry[key] == typed[key] == was[key] == listed[plain][key], key
+    assert (entry["layer"], entry["moves"]) == ("Serve programs",
+                                                "tpot_p50_ms")

@@ -36,7 +36,10 @@ import jax.numpy as jnp  # noqa: E402
 #: tokens, hidden size)
 ROW_SHAPES = [("LFM2 share", 8, 32, 4, 8192, 2048),
               ("Solar decode", 40, 320, 8, 128, 4096),
-              ("Solar chunk", 40, 320, 8, 512, 4096)]
+              ("Solar chunk", 40, 320, 8, 512, 4096),
+              # a row of 12 word-sublanes (PR 57)
+              ("Laguna chunk", 32, 256, 10, 1024, 3072),
+              ("Laguna decode", 32, 256, 10, 64, 3072)]
 
 
 def cases():
@@ -239,8 +242,7 @@ def cases():
     # the rows into the sorted, padded buffer and back onto their tokens
     # (no expert between: out[t] = x[t] * the sum of t's held gates), forward
     # and backward, through the two row kernels against XLA's scatter and
-    # gathers: the LFM2 training share's call and the Solar share's decode
-    # call
+    # gathers, at every expert share's layer call
     def moved(impl, held, of, top_k):
         def run(xt, gate, key):
             key = _held_keys(key, held, of)
@@ -265,7 +267,7 @@ def cases():
             return out.astype(f32), grads
         return run
 
-    for what, held, of, top_k, t, h in ROW_SHAPES[:2]:
+    for what, held, of, top_k, t, h in ROW_SHAPES:
         out.append((f"moe_dispatch + moe_combine fwd+bwd {what}: {t} tokens "
                     f"x {top_k} picks, {held} of {of} experts held, H={h}",
                     moved("pallas", held, of, top_k),
