@@ -312,6 +312,11 @@ class InferenceEngineV2:
         self.params = cast_tree(params, self.config.jnp_dtype)
         self.param_bytes = sum(l.size * l.dtype.itemsize for l in
                                jax.tree_util.tree_leaves(self.params))
+        if self.config.quant_bits and self.cfg.hc_mult > 1:
+            raise ValueError(
+                "quant_bits: the hyper-connections' projection (layers/hc/"
+                "*/phi) is read as it is stored, in the served dtype; "
+                "weight-only quantization has no form of it")
         if self.config.quant_bits:
             from ..quantization import quantize_inference_params
 
@@ -643,7 +648,10 @@ class InferenceEngineV2:
             kv_page_size=self.block.page_size,
             kv_max_seqs=self.block.max_seqs,
             kv_quant=self.config.kv_quant,
-            prefix_cache=self.config.enable_prefix_cache)
+            prefix_cache=self.config.enable_prefix_cache,
+            # (a residual of several streams: hyper-connections)
+            **({"mhc_streams": self.cfg.hc_mult}
+               if self.cfg.hc_mult > 1 else {}))
 
     def _pinned_page_bytes(self) -> int:
         """Device bytes held by prefix-cache-pinned (LRU) pages: the

@@ -69,6 +69,19 @@ not needed, only which rows are live.  Its first layer is a run of its own
 with a dense feed-forward part: a served layer's feed-forward part is what
 its parameters are (``_ffn``).
 
+A residual of several streams (``cfg.hc_mult`` > 1: Xing4.0's
+manifold-constrained hyper-connections) rides in the same carry, the streams
+side by side: ``x [B, T, n * H]``, stream ``i`` the lanes ``i * H .. (i + 1) *
+H`` (a dimension of ``n`` = 4 before ``H`` would be a second-minor one, which
+the device pads to a tile of 8 or 16 rows).  The embedding is copied to the
+``n`` streams (``_streams_in``) and they are summed before the final norm
+(``_streams_out``); every sublayer reads ``H_pre X`` (``_stream_read``) and
+``H_res X + H_post^T y`` goes back (``_stream_write``) — the one pair through
+which every program adds a sublayer's output to the residual, so that the
+formulations cannot diverge.  With one stream the pair is ``x`` and ``x + y``
+and traces no operation of its own: a one-stream model's programs are what
+they were.
+
 A model that generates by diffusion over blocks (``cfg.block_length``:
 SDAR-MoE) has ``paged_block_pass`` in ``paged_decode``'s place — a block of
 ``B`` positions a row, its K/V written in place to its slots of the row's page,
@@ -93,8 +106,8 @@ from ...models.layer_types import (GqaShape, gqa_shape, latent_width,
                                    served_runs)
 from ...models.transformer import (MODEL_AXIS, TransformerConfig, _mm,
                                    _norm, _repeat_kv, alibi_slopes, attn_qkv,
-                                   logits_fn, mlp_block, rope_interleaved,
-                                   yarn_inv_freq)
+                                   logits_fn, mlp_block, mlp_delta,
+                                   rope_interleaved, yarn_inv_freq)
 from ...telemetry.regions import region
 from ...ops.pallas.paged_attention import (merged_keys, split_keys,
                                            split_queries)
@@ -231,6 +244,16 @@ def _scan_layers(cfg: TransformerConfig, params, pools, x, layer_fns,
     cross layers, carried beside the pools, ``i`` the layer's index in the
     stack.  ``until``: stop after the run that holds this mixer."""
     runs = served_runs(cfg)
+    if cfg.hc_mult > 1:
+        unread = sorted({t.mixer for types, _ in runs for t in types}
+                        - set(_STREAM_MIXERS))
+        if unread or cfg.parallel_block:
+            raise NotImplementedError(
+                f"a residual of hc_mult={cfg.hc_mult} streams is served for "
+                f"sequential blocks of the mixers {_STREAM_MIXERS}: "
+                + (f"the {unread} layer functions read the residual as it is"
+                   if unread else "a parallel block has one read for two "
+                   "sublayers"))
     stack = params["layers"]
     if not cfg.layer_runs:  # one run: the stack is its period's trees
         stack = (stack if isinstance(stack, tuple) else (stack,),)
@@ -293,6 +316,109 @@ def _pool_window(pools, l, table, kv_heads):
     return out
 
 
+# --------------------------- a residual of several streams (Xing4.0: mHC)
+#: the mixers whose layer functions read the residual through
+#: ``_stream_read``; the others' read ``x`` as it is and are refused by name
+#: for a residual of several streams (``_scan_layers``)
+_STREAM_MIXERS = ("attn", "mla", "gqa_full", "gqa_window")
+
+
+def _streams_in(cfg: TransformerConfig, x):
+    """The embedding ``[..., H]`` as the residual the layers carry: copied to
+    each of the ``hc_mult`` streams ``[..., n * H]`` (one stream: ``x``)."""
+    if cfg.hc_mult <= 1:
+        return x
+    with region("mhc"):
+        return jnp.tile(x, cfg.hc_mult)
+
+
+def _streams_out(cfg: TransformerConfig, x):
+    """The residual ``[..., n * H]`` as the final norm reads it: the streams
+    summed, in float32 (one stream: ``x``)."""
+    if cfg.hc_mult <= 1:
+        return x
+    with region("mhc"):
+        return sum(s.astype(jnp.float32)
+                   for s in jnp.split(x, cfg.hc_mult, axis=-1)).astype(x.dtype)
+
+
+def _sinkhorn(rows, rounds: int, eps: float):
+    """``rows[i][j]``: the positive entries of an ``n x n`` matrix a token,
+    each an array over the tokens.  ``rounds`` times: every row divided by
+    its sum + ``eps``, then every column by its sum + ``eps``.  Entry by
+    entry: a round is some sixty elementwise operations over arrays with the
+    tokens on the lanes, which XLA fuses into one kernel — as ``[tokens, n,
+    n]`` with reductions over the minor dimensions a round is several kernels
+    over tiles that are 1/64 full.  A loop of four rounds a trip: unrolled
+    whole, the 1,300 operations of 20 rounds compile for 20 s a sublayer."""
+    n = len(rows)
+
+    def one_round(_, rows):
+        rows = [[e / (sum(r) + eps) for e in r] for r in rows]
+        cols = [sum(rows[i][j] for i in range(n)) + eps for j in range(n)]
+        return tuple(tuple(rows[i][j] / cols[j] for j in range(n))
+                     for i in range(n))
+
+    return jax.lax.fori_loop(0, rounds, one_round,
+                             tuple(tuple(r) for r in rows), unroll=4)
+
+
+def _stream_read(cfg: TransformerConfig, layer, x, part: str):
+    """What a sublayer (``part``: "mixer" | "ffn") reads of the residual ``x
+    [B, T, n * H]`` -> (``h [B, T, H]``, the coefficients ``_stream_write``
+    takes); with one stream ``(x, None)`` and nothing traced.
+
+    ``m = RMSNorm(vec X) phi`` (no learned scale), ``H_pre = sigmoid(a_pre
+    m[:n] + b_pre)``, ``H_post = 2 sigmoid(a_post m[n:2n] + b_post)``, ``H_res
+    = Sinkhorn(exp(clip(a_res mat(m[2n:]) + B_res, -+hc_clamp)))``; ``h =
+    H_pre X``.  All in float32 from the stored stream.  The norm's factor is
+    applied AFTER the projection (``(x phi) r`` for ``(x r) phi``): the stored
+    stream and ``phi`` then enter the product as they are stored, exact in a
+    float32 accumulator, where a normed float32 operand would be rounded to
+    the matrix unit's input type first."""
+    if cfg.hc_mult <= 1:
+        return x, None
+    f32 = jnp.float32
+    n, p = cfg.hc_mult, layer["hc"][part]
+    with region("mhc"):
+        r = jax.lax.rsqrt(jnp.mean(jnp.square(x.astype(f32)), -1)
+                          + cfg.norm_eps)
+        # [2n + n^2, B, T]: the tokens on the lanes
+        m = jnp.einsum("btk,kw->wbt", x, p["phi"].astype(x.dtype),
+                       preferred_element_type=f32,
+                       precision=jax.lax.Precision.HIGHEST) * r
+        alpha = p["alpha"].astype(f32)
+        b = p["b"].astype(f32)[:, None, None]
+        pre = jax.nn.sigmoid(alpha[0] * m[:n] + b[:n])
+        post = 2.0 * jax.nn.sigmoid(alpha[1] * m[n:2 * n] + b[n:2 * n])
+        e = jnp.exp(jnp.clip(alpha[2] * m[2 * n:] + b[2 * n:],
+                             -cfg.hc_clamp, cfg.hc_clamp))
+        res = _sinkhorn([[e[i * n + j] for j in range(n)] for i in range(n)],
+                        cfg.hc_sinkhorn_iters, cfg.hc_eps)
+        xs = jnp.split(x, n, axis=-1)
+        h = sum(pre[i][..., None] * xs[i].astype(f32) for i in range(n))
+        return h.astype(x.dtype), (post, res)
+
+
+def _stream_write(x, y, mix):
+    """The residual after a sublayer whose output is ``y [B, T, H]``: ``H_res
+    X + H_post^T y`` (stream ``i`` gets ``sum_j H_res[i, j] X_j + H_post[i]
+    y``), ``mix`` what ``_stream_read`` returned beside ``h``; with one stream
+    (``mix`` None) ``x + y``."""
+    if mix is None:
+        return x + y
+    post, res = mix
+    n = len(res)
+    with region("mhc"):
+        f32 = jnp.float32
+        xs = [a.astype(f32) for a in jnp.split(x, n, axis=-1)]
+        yf = y.astype(f32)
+        return jnp.concatenate(
+            [sum(res[i][j][..., None] * xs[j] for j in range(n))
+             + post[i][..., None] * yf for i in range(n)],
+            axis=-1).astype(x.dtype)
+
+
 def _ffn(cfg: TransformerConfig, layer, x):
     """mlp_block shared with the training forward -> (x, aux): inference has
     no use for an auxiliary loss; an expert share's counters come in its
@@ -301,6 +427,10 @@ def _ffn(cfg: TransformerConfig, layer, x):
     the prologue's width."""
     if cfg.moe_experts and "router" not in layer["mlp"]:
         cfg = run_config(cfg, "dense")
+    if cfg.hc_mult > 1:
+        h, mix = _stream_read(cfg, layer, x, "ffn")
+        y, aux = mlp_delta(cfg, layer, h, training=False)
+        return _stream_write(x, y, mix), aux
     return mlp_block(cfg, layer, x, training=False)
 
 
@@ -312,12 +442,14 @@ def _alibi_bias(cfg: TransformerConfig, qpos, kpos):
     return -alibi_slopes(cfg.n_heads)[:, None, None] * rel[..., None, :, :]
 
 
-def _attn_out(cfg: TransformerConfig, layer, x, attn, pools):
+def _attn_out(cfg: TransformerConfig, layer, x, attn, pools, read=None):
     """Output projection + residual/parallel-block epilogue shared by the
     prefill/chunk/decode scan bodies; returns what a layer_fn returns
-    (``_scan_layers``)."""
+    (``_scan_layers``).  ``read``: what ``_stream_read`` gave the mixer of
+    the residual ``x`` (None: ``x`` itself, one stream)."""
+    seen, mix = read or (x, None)
     if "wg" in layer["attn"]:  # gated output: wo (attn * sigmoid(wg h))
-        h = _norm(x, layer["norm1"]["scale"], layer["norm1"].get("bias"),
+        h = _norm(seen, layer["norm1"]["scale"], layer["norm1"].get("bias"),
                   cfg.norm, cfg.norm_eps)
         gate = jax.nn.sigmoid(_mm(cfg, h, layer["attn"]["wg"], None,
                                   MODEL_AXIS).astype(jnp.float32))
@@ -329,8 +461,8 @@ def _attn_out(cfg: TransformerConfig, layer, x, attn, pools):
                       + (layer["attn"]["bo"] if cfg.use_bias else 0))
     if cfg.parallel_block:
         x, aux = _ffn(cfg, layer, x)
-        return x + attn_delta, pools, aux
-    x, aux = _ffn(cfg, layer, x + attn_delta)
+        return _stream_write(x, attn_delta, None), pools, aux
+    x, aux = _ffn(cfg, layer, _stream_write(x, attn_delta, mix))
     return x, pools, aux
 
 
@@ -338,7 +470,7 @@ def _kda_mix(cfg: TransformerConfig, layer, x, tail, valid, scan):
     """``_kda_mixer`` (the ``state_glue`` region: ``_period_body``), then
     the feed-forward part -> (x, aux, rows)."""
     y, rows = _kda_mixer(cfg, layer, x, tail, valid, scan)
-    return (*_ffn(cfg, layer, x + y), rows)
+    return (*_ffn(cfg, layer, _stream_write(x, y, None)), rows)
 
 
 def _kda_mixer(cfg: TransformerConfig, layer, x, tail, valid, scan):
@@ -407,7 +539,7 @@ def _mamba_mix(cfg: TransformerConfig, layer, x, tail, scan):
                          + m["b_dt"].astype(f32))
     y = scan(dt, u, b, c, -jnp.exp(m["a_log"].astype(f32)), m["d"])
     out = (y * jax.nn.silu(z.astype(f32))).astype(x.dtype) @ m["w_out"]
-    return (*_ffn(cfg, layer, x + out), y, rows)
+    return (*_ffn(cfg, layer, _stream_write(x, out, None)), y, rows)
 
 
 def _paired_q(q):
@@ -442,7 +574,7 @@ def _diff_out(cfg: TransformerConfig, layer, x, o, i):
     with region("attn_out"):
         delta = _mm(cfg, d.reshape(B, T, -1).astype(x.dtype), a["wo"],
                     MODEL_AXIS, None) + a["bo"]
-    return _ffn(cfg, layer, x + delta)
+    return _ffn(cfg, layer, _stream_write(x, delta, None))
 
 
 def _pair_cfg(cfg: TransformerConfig) -> TransformerConfig:
@@ -496,7 +628,7 @@ def _gmu_fn(cfg: TransformerConfig):
         gate = jax.nn.silu((_ln1(cfg, layer, x) @ g["w_in"])
                            .astype(jnp.float32))
         y = (cross["mem"] * gate).astype(x.dtype) @ g["w_out"]
-        x, aux = _ffn(cfg, layer, x + y)
+        x, aux = _ffn(cfg, layer, _stream_write(x, y, None))
         return x, pools, aux, cross
     return gmu_fn
 
@@ -752,11 +884,13 @@ def paged_prefill(cfg: TransformerConfig, params, pools,
             x = _norm(x, params["embed"]["norm"]["scale"],
                       params["embed"]["norm"].get("bias"), cfg.norm, cfg.norm_eps)
     positions = jnp.arange(S)[None]
+    x = _streams_in(cfg, x)
 
     use_flash = _use_paged_kernel()
 
     def layer_fn(layer, l, x, pools):
-        q, k, v = attn_qkv(cfg, layer, x, positions)
+        read = _stream_read(cfg, layer, x, "mixer")
+        q, k, v = attn_qkv(cfg, layer, read[0], positions)
         pools = _pool_write(
             pools, l, (page_rows,), k[0].reshape(S // ps, ps, *k.shape[2:]),
             v[0].reshape(S // ps, ps, *v.shape[2:]))
@@ -783,12 +917,13 @@ def paged_prefill(cfg: TransformerConfig, params, pools,
             scores = jnp.where(causal, scores, -1e30)
             probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
             attn = jnp.einsum("bnts,bsnd->btnd", probs, vv).reshape(1, S, -1)
-        return _attn_out(cfg, layer, x, attn, pools)
+        return _attn_out(cfg, layer, x, attn, pools, read)
 
     x, pools = _scan_layers(cfg, params, pools, x,
                             _Forms("whole-prompt prefill", attn=layer_fn))
     with region("head"):
-        hidden = _norm(x[:, length - 1], params["final_norm"]["scale"],
+        hidden = _norm(_streams_out(cfg, x[:, length - 1]),
+                       params["final_norm"]["scale"],
                        params["final_norm"].get("bias"), cfg.norm,
                        cfg.norm_eps)
         logits = logits_fn(cfg, params, hidden[:, None])[0, 0]
@@ -920,6 +1055,7 @@ def paged_prefill_chunk(cfg: TransformerConfig, params, pools,
         if "norm" in params["embed"]:
             x = _norm(x, params["embed"]["norm"]["scale"],
                       params["embed"]["norm"].get("bias"), cfg.norm, cfg.norm_eps)
+    x = _streams_in(cfg, x)
 
     with region("attn_glue"):
         # visibility of pooled (previous-chunk) slots: strictly before start
@@ -941,7 +1077,8 @@ def paged_prefill_chunk(cfg: TransformerConfig, params, pools,
     use_flash = use_kernel and not quant
 
     def layer_fn(layer, l, x, pools):
-        q, k, v = attn_qkv(cfg, layer, x, positions)
+        read = _stream_read(cfg, layer, x, "mixer")
+        q, k, v = attn_qkv(cfg, layer, read[0], positions)
         pools = _pool_write(
             pools, l, (chunk_rows,), k[0].reshape(C // ps, ps, *k.shape[2:]),
             v[0].reshape(C // ps, ps, *v.shape[2:]))
@@ -961,7 +1098,7 @@ def paged_prefill_chunk(cfg: TransformerConfig, params, pools,
                 alibi_slopes=(alibi_slopes(cfg.n_heads)
                               if cfg.position == "alibi" else None),
                 **blocked).reshape(1, C, -1)
-            return _attn_out(cfg, layer, x, attn, pools)
+            return _attn_out(cfg, layer, x, attn, pools, read)
         # keys = [previous pooled slots | this chunk]; the pooled half is
         # masked to < start, the chunk half causally within the chunk
         kk = jnp.concatenate([kp.astype(x.dtype)[None], k], axis=1)
@@ -983,19 +1120,21 @@ def paged_prefill_chunk(cfg: TransformerConfig, params, pools,
         scores = jnp.where(mask[None, None], scores, -1e30)
         probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
         attn = jnp.einsum("bnts,bsnd->btnd", probs, vv).reshape(1, C, -1)
-        return _attn_out(cfg, layer, x, attn, pools)
+        return _attn_out(cfg, layer, x, attn, pools, read)
 
     def mla_fn(layer, l, x, pools):
         # the chunk's rows go to the pool first; the window's rows, the
         # chunk's among them at their positions, are then expanded
-        q_nope, q_rope, row = _mla_project(cfg, layer, x, positions)
+        read = _stream_read(cfg, layer, x, "mixer")
+        h = read[0]
+        q_nope, q_rope, row = _mla_project(cfg, layer, h, positions)
         latent = pools["latent"].at[l, chunk_rows].set(
             row[0].reshape(C // ps, ps, -1).astype(pools["latent"].dtype))
         pools = dict(pools, latent=latent)
         rows = latent[l, prev_table].reshape(S_prev, -1).astype(x.dtype)
         attn = _mla_expanded(cfg, layer, q_nope, q_rope, rows, positions[0],
                              use_kernel, q_offset=start)
-        return _attn_out(cfg, layer, x, attn, pools)
+        return _attn_out(cfg, layer, x, attn, pools, read)
 
     def kda_fn(layer, l, x, pools):
         # the sequence's slot holds what its earlier chunks left; a chunk
@@ -1083,7 +1222,8 @@ def paged_prefill_chunk(cfg: TransformerConfig, params, pools,
     # the window layers, keys stored split and rotated
     def gqa_full_fn(layer, l, x, pools):
         sh = gqa_shape(cfg, "gqa_full")
-        q, k, v = _gqa_qkv(cfg, sh, layer, x, positions)
+        read = _stream_read(cfg, layer, x, "mixer")
+        q, k, v = _gqa_qkv(cfg, sh, layer, read[0], positions)
         # the chunk's rows go to the pool first; the window's rows, the
         # chunk's among them, sit at their positions
         rows = {"k": split_keys(k[0], sh.split), "v": v[0].reshape(C, -1)}
@@ -1103,12 +1243,13 @@ def paged_prefill_chunk(cfg: TransformerConfig, params, pools,
         else:
             vis = jnp.arange(S_prev)[None] <= positions[0][:, None]
             o = _gqa_softmax(q, kp, vp, vis[None], sh.scale)
-        return _attn_out(cfg, layer, x, o, pools)
+        return _attn_out(cfg, layer, x, o, pools, read)
 
     def gqa_window_fn(layer, l, x, pools):
         sh = gqa_shape(cfg, "gqa_window")
         W, sink = sh.window, layer["attn"].get("sink")
-        q, k, v = _gqa_qkv(cfg, sh, layer, x, positions)
+        read = _stream_read(cfg, layer, x, "mixer")
+        q, k, v = _gqa_qkv(cfg, sh, layer, read[0], positions)
         at, old, prev, k_first = _ring_read(pools, l, slot, W, ps, start,
                                             lambda a: a[None])
         # [the ring in position order | the chunk] through the window mask
@@ -1130,7 +1271,7 @@ def paged_prefill_chunk(cfg: TransformerConfig, params, pools,
         pools = _ring_write(pools, at, old,
                             {"k": split_keys(k, sh.split), "v": v},
                             W, ps, start, n)
-        return _attn_out(cfg, layer, x, o, pools)
+        return _attn_out(cfg, layer, x, o, pools, read)
 
     last_pos = (start + n - 1).reshape(1)
 
@@ -1172,7 +1313,7 @@ def paged_prefill_chunk(cfg: TransformerConfig, params, pools,
         # write (no final norm, no head)
         return jnp.zeros((), x.dtype), pools
     with region("head"):
-        hidden = _norm(x[:, 0 if xdec else n - 1],
+        hidden = _norm(_streams_out(cfg, x[:, 0 if xdec else n - 1]),
                        params["final_norm"]["scale"],
                        params["final_norm"].get("bias"), cfg.norm,
                        cfg.norm_eps)
@@ -1253,6 +1394,7 @@ def paged_verify(cfg: TransformerConfig, params, pools,
         if "norm" in params["embed"]:
             x = _norm(x, params["embed"]["norm"]["scale"],
                       params["embed"]["norm"].get("bias"), cfg.norm, cfg.norm_eps)
+    x = _streams_in(cfg, x)
 
     with region("attn_glue"):
         valid = active[:, None] & (jnp.arange(W)[None] < n_valid[:, None])
@@ -1266,16 +1408,17 @@ def paged_verify(cfg: TransformerConfig, params, pools,
         vis = slot_pos <= pos_w[:, :, None]           # [B, W, S]
 
     def layer_fn(layer, l, x, pools):
-        q, k, v = attn_qkv(cfg, layer, x, pos_w)  # [B, W, NH/KVH, D]
+        read = _stream_read(cfg, layer, x, "mixer")
+        q, k, v = attn_qkv(cfg, layer, read[0], pos_w)  # [B, W, NH/KVH, D]
         pools = _pool_write(pools, l, (page_idx, off), k, v)
         attn = _gather_window_attend(cfg, q, pools, l, page_table, pos_w,
                                      vis)
-        return _attn_out(cfg, layer, x, attn, pools)
+        return _attn_out(cfg, layer, x, attn, pools, read)
 
     x, pools = _scan_layers(cfg, params, pools, x,
                             _Forms("speculative verify", attn=layer_fn))
     with region("head"):
-        hidden = _norm(x, params["final_norm"]["scale"],
+        hidden = _norm(_streams_out(cfg, x), params["final_norm"]["scale"],
                        params["final_norm"].get("bias"), cfg.norm,
                        cfg.norm_eps)
         logits = logits_fn(cfg, params, hidden)  # [B, W, V]
@@ -1305,6 +1448,7 @@ def paged_decode(cfg: TransformerConfig, params, pools,
         if "norm" in params["embed"]:
             x = _norm(x, params["embed"]["norm"]["scale"],
                       params["embed"]["norm"].get("bias"), cfg.norm, cfg.norm_eps)
+    x = _streams_in(cfg, x)
 
     with region("attn_glue"):
         # clamp the page lookup for INACTIVE rows: inside the multi-step
@@ -1325,7 +1469,8 @@ def paged_decode(cfg: TransformerConfig, params, pools,
     use_kernel = _use_paged_kernel()
 
     def layer_fn(layer, l, x, pools):
-        q, k, v = attn_qkv(cfg, layer, x, positions[:, None])
+        read = _stream_read(cfg, layer, x, "mixer")
+        q, k, v = attn_qkv(cfg, layer, read[0], positions[:, None])
         pools = _pool_write(pools, l, (page_idx, off), k[:, 0], v[:, 0])
         if use_kernel:
             # Pallas paged kernel: the whole pool goes in as it stands and
@@ -1345,15 +1490,17 @@ def paged_decode(cfg: TransformerConfig, params, pools,
             attn = _gather_window_attend(cfg, q, pools, l, page_table,
                                          positions[:, None],
                                          vis[:, None, :])
-        return _attn_out(cfg, layer, x, attn, pools)
+        return _attn_out(cfg, layer, x, attn, pools, read)
 
     def mla_fn(layer, l, x, pools):
-        q_nope, q_rope, row = _mla_project(cfg, layer, x, positions[:, None])
+        read = _stream_read(cfg, layer, x, "mixer")
+        q_nope, q_rope, row = _mla_project(cfg, layer, read[0],
+                                           positions[:, None])
         pools = dict(pools, latent=pools["latent"].at[l, page_idx, off].set(
             row[:, 0].astype(pools["latent"].dtype)))
         attn = _mla_absorbed(cfg, layer, q_nope[:, 0], q_rope[:, 0], pools, l,
                              page_table, positions, active, use_kernel)
-        return _attn_out(cfg, layer, x, attn, pools)
+        return _attn_out(cfg, layer, x, attn, pools, read)
 
     def kda_fn(layer, l, x, pools):
         # row b's state is slot b; the kernel visits the rows that decode, an
@@ -1434,7 +1581,8 @@ def paged_decode(cfg: TransformerConfig, params, pools,
 
     def gqa_full_fn(layer, l, x, pools):
         sh = gqa_shape(cfg, "gqa_full")
-        q, k, v = _gqa_qkv(cfg, sh, layer, x, positions[:, None])
+        read = _stream_read(cfg, layer, x, "mixer")
+        q, k, v = _gqa_qkv(cfg, sh, layer, read[0], positions[:, None])
         pools = dict(
             pools,
             k=pools["k"].at[l, page_idx, off].set(
@@ -1444,7 +1592,7 @@ def paged_decode(cfg: TransformerConfig, params, pools,
         o = _gqa_rows(sh, q[:, 0], pools["k"], pools["v"], l, page_table,
                       positions, active, None, use_kernel,
                       "dstpu_paged_decode")
-        return _attn_out(cfg, layer, x, o[:, None], pools)
+        return _attn_out(cfg, layer, x, o[:, None], pools, read)
 
     def gqa_window_fn(layer, l, x, pools):
         # position t lives at row t mod W of the row's ring, which the
@@ -1454,7 +1602,8 @@ def paged_decode(cfg: TransformerConfig, params, pools,
         W = sh.window
         WP = W // ps
         slots = pools["win_k"].shape[1] // WP - 1
-        q, k, v = _gqa_qkv(cfg, sh, layer, x, positions[:, None])
+        read = _stream_read(cfg, layer, x, "mixer")
+        q, k, v = _gqa_qkv(cfg, sh, layer, read[0], positions[:, None])
         ring = positions % W
         at = (l, jnp.where(active, jnp.arange(B), slots) * WP + ring // ps,
               ring % ps)
@@ -1468,7 +1617,7 @@ def paged_decode(cfg: TransformerConfig, params, pools,
             jnp.minimum(positions, W - 1), active,
             layer["attn"].get("sink"), use_kernel, "dstpu_window_decode")
         return _attn_out(cfg, layer, x, o[:, None],
-                         dict(pools, win_k=win_k, win_v=win_v))
+                         dict(pools, win_k=win_k, win_v=win_v), read)
 
     like = pools
     x, pools = _scan_layers(
@@ -1481,7 +1630,7 @@ def paged_decode(cfg: TransformerConfig, params, pools,
                if cfg.ssm_inner else None))
     pools = _ring_slots(pools, like)
     with region("head"):
-        hidden = _norm(x, params["final_norm"]["scale"],
+        hidden = _norm(_streams_out(cfg, x), params["final_norm"]["scale"],
                        params["final_norm"].get("bias"), cfg.norm,
                        cfg.norm_eps)
         logits = logits_fn(cfg, params, hidden)[:, 0]
@@ -1549,6 +1698,7 @@ def paged_block_pass(cfg: TransformerConfig, params, pools, ids, masked,
     with region("embed"):
         tok = jnp.where(masked, jnp.int32(cfg.mask_token_id), ids)
         x = params["embed"]["tok"][tok]  # [R, B, H]
+    x = _streams_in(cfg, x)
     with region("attn_glue"):
         pos = start[:, None] + jnp.arange(Bk)[None]  # [R, B]
         page_idx = jnp.where(
@@ -1565,7 +1715,8 @@ def paged_block_pass(cfg: TransformerConfig, params, pools, ids, masked,
     use_kernel = _use_paged_kernel()
 
     def layer_fn(layer, l, x, pools):
-        q, k, v = attn_qkv(cfg, layer, x, pos)  # [R, B, NH | KVH, D]
+        read = _stream_read(cfg, layer, x, "mixer")
+        q, k, v = attn_qkv(cfg, layer, read[0], pos)  # [R, B, NH | KVH, D]
         pools = _pool_write(pools, l, at, k, v)
         if use_kernel:
             from ...ops.pallas.paged_attention import paged_decode_attention
@@ -1580,12 +1731,12 @@ def paged_block_pass(cfg: TransformerConfig, params, pools, ids, masked,
         else:
             attn = _gather_window_attend(cfg, q, pools, l, page_table, pos,
                                          vis)
-        return _attn_out(cfg, layer, x, attn, pools)
+        return _attn_out(cfg, layer, x, attn, pools, read)
 
     x, pools = _scan_layers(cfg, params, pools, x,
                             _Forms("the block pass", attn=layer_fn))
     with region("head"):
-        hidden = _norm(x, params["final_norm"]["scale"],
+        hidden = _norm(_streams_out(cfg, x), params["final_norm"]["scale"],
                        params["final_norm"].get("bias"), cfg.norm,
                        cfg.norm_eps)
         logits = logits_fn(cfg, params, hidden)

@@ -15,6 +15,7 @@ from .phi4_flash import phi4_flash_config, phi4_flash_model
 from .sdar_moe import sdar_moe_config, sdar_moe_model
 from .solar_open2 import solar_open2_config, solar_open2_model
 from .transformer import TransformerConfig
+from .xing4 import xing4_config, xing4_model
 
 __all__ = ["bert_config", "bert_model", "gpt2_config", "gpt2_model",
            "llama_config", "llama_model", "mixtral_config", "mixtral_model",
@@ -26,5 +27,5 @@ __all__ = ["bert_config", "bert_model", "gpt2_config", "gpt2_model",
            "phi4_flash_config", "phi4_flash_model", "mistral4_config",
            "mistral4_model", "mimo_v2_config", "mimo_v2_model",
            "sdar_moe_config", "sdar_moe_model", "laguna_config",
-           "laguna_model",
+           "laguna_model", "xing4_config", "xing4_model",
            "TransformerConfig"]

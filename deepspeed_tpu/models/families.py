@@ -30,7 +30,9 @@ window and cross-attention layers, gated memory units), ``mistral4.py``
 ``mimo_v2.py`` (full layers with pages and window layers with rings, of
 different K/V head counts, keys wider than values, behind a dense first layer),
 ``laguna.py`` (the same two types with query heads, rotary table and rotated
-share by type, a gate a head, a shared expert);
+share by type, a gate a head, a shared expert), ``xing4.py`` (a residual of
+four streams mixed by hyper-connections round latent attention and a
+sigmoid-routed expert layer held whole, behind a dense first layer);
 ``lfm2_moe.py`` (short-convolution + GQA layers over an expert share) is
 trained and not served.
 """

@@ -276,6 +276,19 @@ class TransformerConfig:
     swa_rotary_pct: float = 0.0
     rope_attention_factor: float = 0.0
     attn_head_gate: bool = False
+    #: a residual of ``hc_mult`` streams mixed by manifold-constrained
+    #: hyper-connections (mHC, arXiv 2512.24880; Xing4.0): a token's residual
+    #: is ``X [hc_mult, hidden]`` and every sublayer (a mixer, a feed-forward
+    #: part) reads ``H_pre X``, and writes ``H_res X + H_post^T y`` back —
+    #: ``H_res`` the Sinkhorn projection (``hc_sinkhorn_iters`` rounds, rows
+    #: first, ``hc_eps`` in each denominator) of ``exp`` of a matrix clamped to
+    #: ``-+hc_clamp`` (``init_hyper_connections``: the parameters;
+    #: ``inference/v2/model_runner._stream_read`` / ``_stream_write``: the
+    #: form served).  1: one stream, ``x + y``, and no such parameter
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_clamp: float = 30.0
 
     @property
     def kv_heads(self) -> int:
@@ -410,7 +423,51 @@ def init_layer_stack(cfg: TransformerConfig, keys, L: int,
         layers["norm1"]["bias"] = jnp.zeros((L, H), dt)
         if "norm2" in layers:
             layers["norm2"]["bias"] = jnp.zeros((L, H), dt)
+    if cfg.hc_mult > 1:
+        layers["hc"] = init_hyper_connections(
+            cfg, jax.random.fold_in(keys[0], 58), L)
     return layers
+
+
+#: a layer's two sublayers, each with hyper-connection parameters of its own
+HC_SUBLAYERS = ("mixer", "ffn")
+
+
+def init_hyper_connections(cfg: TransformerConfig, rng, L: int
+                           ) -> Dict[str, Any]:
+    """The mixing parameters of ``L`` layers of a residual of ``n =
+    cfg.hc_mult`` streams, per sublayer: ``phi [L, n H, 2n + n^2]`` (the
+    projection of the normed, flattened residual onto the dynamic part of
+    ``H_pre | H_post | H_res``), ``alpha [L, 3]`` (its weight in each of the
+    three) and ``b [L, 2n + n^2]`` (the static part, in ``phi``'s column
+    order).
+
+    Drawn so that each mechanism decides the output visibly — ``phi`` normal
+    ``1 / sqrt(n H)`` over a unit-RMS input gives a projection of unit
+    variance, ``alpha`` 1 keeps it whole, the biases of ``H_pre`` and
+    ``H_post`` normal 0.5, those of ``H_res`` normal 2.5 — where the paper's
+    training initialisation (``alpha`` 0.01, ``b_res`` the identity) would
+    make every coefficient a constant a comparison cannot tell from a
+    projection left out.  ``H_res``'s argument then spreads by 2.7, and ONE
+    Sinkhorn round leaves its rows 0.45 off their sums and the matrix 0.3 from
+    the 20th round's; with biases of 0.5 one round lands within an eighth of
+    the 20th, which on the chip read as near the reference as the served
+    stack's own bfloat16 activations do (``PERF.md`` section 6, PR 58).  The
+    served stack is seeded, not trained."""
+    n, H = cfg.hc_mult, cfg.hidden_size
+    width = 2 * n + n * n
+    b_scale = jnp.concatenate([jnp.full((2 * n,), 0.5), jnp.full((n * n,),
+                                                                 2.5)])
+    out = {}
+    for j, part in enumerate(HC_SUBLAYERS):
+        k = jax.random.split(jax.random.fold_in(rng, j), 2)
+        out[part] = {
+            "phi": _nrm(cfg, k[0], L, n * H, width, s=1.0 / math.sqrt(n * H)),
+            "alpha": jnp.ones((L, 3), cfg.dtype),
+            "b": (jax.random.normal(k[1], (L, width)) * b_scale
+                  ).astype(cfg.dtype),
+        }
+    return out
 
 
 def init_transformer_params(cfg: TransformerConfig, rng) -> Dict[str, Any]:
@@ -750,14 +807,21 @@ def mlp_block(cfg: TransformerConfig, layer, x, training: bool = True):
     (XLA CSEs the duplicate _norm with the one inside attn_qkv).  Falcon's
     new decoder architecture (40b/180b) runs parallel branches with
     SEPARATE norms (cfg.parallel_norms == 2: ln_attn/ln_mlp -> norm1/norm2)."""
+    h, aux = mlp_delta(cfg, layer, x, training)
+    return x + h, aux
+
+
+def mlp_delta(cfg: TransformerConfig, layer, x, training: bool = True):
+    """``mlp_block`` without its residual add: (ffn(norm(x)), aux_loss) — what
+    a residual of several streams writes back through its own mixing
+    (``inference/v2/model_runner._stream_write``)."""
     if cfg.parallel_block and cfg.parallel_norms < 2:
         ln = layer["norm1"]
     else:
         ln = layer["norm2"]
     with region("norm"):
         h = _norm(x, ln["scale"], ln.get("bias"), cfg.norm, cfg.norm_eps)
-    h, aux = _ffn(cfg, layer, h, training)
-    return x + h, aux
+    return _ffn(cfg, layer, h, training)
 
 
 def _ffn(cfg: TransformerConfig, layer, h, training: bool = True):
@@ -898,6 +962,14 @@ def _block(cfg: TransformerConfig, x, layer, positions, mask, attn_fn):
     return mlp_block(cfg, layer, x + attn_delta)
 
 
+def _one_stream(cfg: TransformerConfig, what: str) -> None:
+    if cfg.hc_mult > 1:
+        raise NotImplementedError(
+            f"{what} carries one residual stream: a residual of "
+            f"hc_mult={cfg.hc_mult} streams (hyper-connections) is served "
+            "only, through the paged programs (inference/v2/model_runner)")
+
+
 def transformer_forward(cfg: TransformerConfig, params, input_ids, mask=None,
                         token_type_ids=None, with_act_stats=False,
                         with_moe_counters=False):
@@ -914,6 +986,7 @@ def transformer_forward(cfg: TransformerConfig, params, input_ids, mask=None,
     output) as a third element.  Computed OUTSIDE the (possibly
     overlap-wrapped, possibly remat'd) block call, so the overlap hook's
     shard_map specs and the remat policy are untouched."""
+    _one_stream(cfg, "the training forward")
     with region("embed"):
         x = params["embed"]["tok"][input_ids]
     B, S = input_ids.shape
@@ -1213,6 +1286,7 @@ def forward_with_cache(cfg: TransformerConfig, params, input_ids, cache,
         raise NotImplementedError(
             "post_norm models (BERT-style encoders) have no KV-cache "
             "generative path; use transformer_forward + mlm_logits")
+    _one_stream(cfg, "the dense-cache forward")
     B, T = input_ids.shape
     with region("embed"):
         x = params["embed"]["tok"][input_ids]

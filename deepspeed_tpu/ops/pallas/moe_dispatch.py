@@ -37,6 +37,10 @@ lanes wide is linear, a sublane after a sublane, so a copy lands at any of
 them and the loads take any stride.  Nothing needs a row to be whole
 8-sublane tiles: on a v5e both kernels read equal to XLA at 1, 2, 4, 8, 12
 (a bfloat16 row of 3072) and 24 word-sublanes (``PERF.md`` section 6, PR 57).
+What the SOURCE needs is another matter: the uint32 view of a bfloat16
+operand is tiled by 4 word-sublanes, and Mosaic refuses to cut out a row of
+3, 5, 6, 10 or 14 of them (a bfloat16 row of 3584: Xing4.0), which
+``rows_kernel_serves`` therefore leaves to XLA's scatter and gathers.
 """
 
 from __future__ import annotations
@@ -72,7 +76,8 @@ def rows_kernel_serves(h: int, dtype, picks: int) -> bool:
     """Whether the two kernels take a call of ``picks`` picks over rows of
     ``h`` of ``dtype``: float32 or bfloat16 in whole lane tiles, an even
     number of them where two share a word, on the TPU tiles of all 128
-    lanes, and a pick map that fits the scalar memory."""
+    lanes and a packed row of 1, 2 or a multiple of 4 word-sublanes, and a
+    pick map that fits the scalar memory."""
     dtype = jnp.dtype(dtype)
     if picks > _MAX_PICKS or dtype not in (jnp.dtype(jnp.float32),
                                            jnp.dtype(jnp.bfloat16)):
@@ -80,7 +85,16 @@ def rows_kernel_serves(h: int, dtype, picks: int) -> bool:
     s, lanes, sw = _row_tiles(h, dtype)
     if s * lanes != h or sw * (4 // dtype.itemsize) != s:
         return False
-    return not on_tpu() or lanes == _LANES
+    if not on_tpu():
+        return True
+    # a bfloat16 source is read through its uint32 view, which the device
+    # tiles by 4 word-sublanes: Mosaic cuts a token's row out of it only
+    # where the row is whole tiles or smaller than one ("Slice shape along
+    # dimension 1 must be aligned to tiling (4), but is 14": 3, 5, 6, 10 and
+    # 14 are refused, 1, 2, 4, 8, 12, 16 and 24 compile; a float32 row
+    # compiles at every count from 1 to 14: PERF.md section 6, PR 58)
+    return lanes == _LANES and (dtype.itemsize == 4 or sw <= 2
+                                or sw % 4 == 0)
 
 
 def _as_tiles(x):

@@ -54,6 +54,10 @@ REGIONS = (
     "state_glue",     # what surrounds the recurrent-state kernels (delta
                       # rule, selective scan, gated memory unit)
     "latent_expand",  # latent attention's projections, gather and absorbs
+    "mhc",            # a residual of several streams' mixing (hyper-
+                      # connections): the norm over the streams, the
+                      # coefficients' projection, the Sinkhorn rounds, the
+                      # read-in and the write-back
     "head",           # final norm and logits
     "loss",           # log-softmax, the pick, the mean
     "sample",         # arg-max, temperature sampling, the reveal rule

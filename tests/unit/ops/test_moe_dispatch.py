@@ -58,7 +58,12 @@ CASES = [("mixed", "float32", 8, 2, 256), ("mixed", "bfloat16", 16, 4, 256),
          ("none", "float32", 8, 2, 256), ("all", "float32", 8, 3, 256),
          ("all", "bfloat16", 16, 2, 256), ("mixed", "float32", 32, 4, 256),
          ("mixed", "bfloat16", 16, 3, 3072), ("mixed", "float32", 8, 2, 1536),
-         ("all", "bfloat16", 16, 2, 1024)]
+         ("all", "bfloat16", 16, 2, 1024),
+         # 14 word-sublanes (ISSUE 58: Xing4.0's 3584 in bfloat16): right
+         # under the interpreter, which has no tiles; on the chip Mosaic
+         # refuses to cut such a row out of the source's uint32 view, and
+         # ``rows_kernel_serves`` leaves the call to XLA
+         ("mixed", "bfloat16", 16, 4, 3584)]
 
 
 @pytest.mark.parametrize("how, dtype, block_rows, K, H", CASES)
@@ -269,7 +274,15 @@ def test_combine_sums_in_float32_and_rounds_once():
     (2048, "float16", True, 8, False), (2000, "float32", False, 8, False),
     (32, "float32", False, 8, True), (128, "bfloat16", False, 8, False),
     (2048, "bfloat16", True, 2 ** 17, True),
-    (2048, "bfloat16", True, 2 ** 17 + 4, False)])
+    (2048, "bfloat16", True, 2 ** 17 + 4, False),
+    # a bfloat16 source is read through its uint32 view, tiled by 4
+    # word-sublanes: compiled for a described v5e, a row of 3, 5, 6, 10 or 14
+    # of them (Xing4.0's 3584) is REFUSED by Mosaic ("Slice shape along
+    # dimension 1 must be aligned to tiling (4)"), so XLA serves those on the
+    # chip; the interpreter, and a float32 row of any count, take the kernels
+    (3584, "bfloat16", True, 8192, False), (3584, "bfloat16", False, 8192, True),
+    (768, "bfloat16", True, 8, False), (1280, "bfloat16", True, 8, False),
+    (1792, "float32", True, 8, True), (640, "float32", True, 8, True)])
 def test_which_calls_the_kernels_serve(h, dtype, on_chip, picks, want,
                                        monkeypatch):
     monkeypatch.setattr(rows_mod, "on_tpu", lambda: on_chip)

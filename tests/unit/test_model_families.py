@@ -155,3 +155,31 @@ def test_bloom_paged_inference_matches_dense(monkeypatch):
             got = eng.generate_all(
                 [RaggedRequest(prompt_ids=prompt, max_new_tokens=6)])
             assert got[0] == want, (kernel, chunk, quant, got[0], want)
+
+
+@pytest.mark.parametrize("name, why", [
+    ("mistral4", "'mla' has no mix"),
+    ("xing4", "'mla' has no mix"),
+])
+def test_served_only_families_are_listed_and_refuse_training_by_name(name,
+                                                                     why):
+    """A family with layer types of its own is listed with its two entry
+    points (``<name>_config``, ``<name>_model``) in ``deepspeed_tpu.models``
+    and named in ``models/families.py``; its training entry refuses with the
+    family's name and the reason."""
+    import deepspeed_tpu.models as models
+    from deepspeed_tpu.models import families
+
+    assert {f"{name}_config", f"{name}_model"} <= set(models.__all__)
+    assert f"``{name}.py``" in families.__doc__
+    model = getattr(models, f"{name}_model")("tiny")
+    assert getattr(models, f"{name}_config")("tiny").n_layers == \
+        model.config.n_layers
+    for entry in (model.loss_fn, model.apply_fn):
+        with pytest.raises(NotImplementedError,
+                           match=f"{name} is served only") as err:
+            entry(None, {"input_ids": jnp.zeros((1, 4), jnp.int32)}, None)
+        assert why in str(err.value)
+    # and the generic training forward refuses a residual of several streams
+    if model.config.hc_mult > 1:
+        assert "a residual of several streams" in str(err.value)

@@ -7,6 +7,7 @@
     python tools/aot_serve_step.py --config benchmark/configs/solar-open2-250b-ep8-serve.json
     python tools/aot_serve_step.py --config benchmark/configs/mimo-v2-flash-ep16-serve.json
     python tools/aot_serve_step.py --config benchmark/configs/laguna-s-2.1-ep8-serve.json
+    python tools/aot_serve_step.py --config benchmark/configs/xing4-29b-a4b-pp7-serve.json
 
 Reads a serving configuration file of the benchmark (model widths, depth and
 the ``engine`` block: page size, ``num_pages``, ``max_seqs``, chunk), builds

@@ -40,6 +40,8 @@ ROW_SHAPES = [("LFM2 share", 8, 32, 4, 8192, 2048),
               # a row of 12 word-sublanes (PR 57)
               ("Laguna chunk", 32, 256, 10, 1024, 3072),
               ("Laguna decode", 32, 256, 10, 64, 3072)]
+# (not here: Xing4.0's row of 3584 = 14 word-sublanes, which Mosaic refuses to
+# cut out of a bfloat16 source — ``rows_kernel_serves``; PERF.md §6, PR 58)
 
 
 def cases():
