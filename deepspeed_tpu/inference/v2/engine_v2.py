@@ -31,6 +31,7 @@ from ...models.layer_types import (gqa_shape, layers_of, page_layers,
                                    page_leaves, served_runs, state_leaves)
 from ...models.transformer import TransformerConfig
 from ...moe.sharded_moe import MOE_COUNTERS
+from ...ops.pallas.mla_attention import latent_pages_per_block
 from ...ops.pallas.paged_attention import n_blocks, pages_per_block
 from ...runtime.config_utils import ConfigModel
 from ...telemetry import get_registry
@@ -332,11 +333,13 @@ class InferenceEngineV2:
             counters=({"moe_stats": len(MOE_COUNTERS)}
                       if self.cfg.moe_held_count else None),
             pages=self._pages)
-        #: pages a block of the paged decode kernel holds, from the pool's
-        #: own geometry as the kernel takes it (``decode_kv_blocks``)
+        #: pages a block of the decode kernel holds — the paged kernel's, or
+        #: the latent kernel's over a latent pool — from the pool's own
+        #: geometry as that kernel takes it (``decode_kv_blocks``)
         page_leaf = self._pools["latent" if self._latent else "k"]
-        self._kv_block_pages = pages_per_block(
-            block.page_size, page_leaf.shape[-1], page_leaf.dtype.itemsize)
+        self._kv_block_pages = (
+            latent_pages_per_block if self._latent else pages_per_block)(
+                block.page_size, page_leaf.shape[-1], page_leaf.dtype.itemsize)
         self.state_slots = StateSlots(block.max_seqs if self._state else 0)
         #: the expert share's counters as the device last reported them
         #: (``moe_stats`` wraps at 2**32; the host adds differences)
@@ -390,6 +393,8 @@ class InferenceEngineV2:
                         "spec_fallback_requests": 0,
                         **dict.fromkeys(MOE_COUNTERS, 0),
                         "state_slot_preemptions": 0}
+        if self._latent:
+            self._dstats.update(latent_kv_tokens=0, latent_block_slots=0)
         self._init_serving_metrics()
         #: the one question asked of the model: does it generate by blocks
         #: (``block_diffusion.BlockPolicy``, which owns the decode phase of
@@ -2358,11 +2363,21 @@ class InferenceEngineV2:
         window decode reads (a ring holds ``sliding_window`` at most), the
         visible pages of the one pool layer (once, however many layers read
         them), the rows the cross-decoder runs, and the cached positions a
-        latent-attention layer's decode kernel reads."""
+        latent-attention layer's decode kernel reads beside the positions of
+        the blocks it walks for them (``latent_block_slots``: the kernel's
+        own ``n_blocks`` times its block)."""
         counts, rows = self._step_counts, int((lengths > 0).sum())
         if self._latent:  # once, not a layer: the kernel's bytes are x layers
-            counts["latent_kv_tokens"] = counts.get("latent_kv_tokens", 0) \
-                + int(lengths.sum())
+            # ... and the positions of the blocks the kernel walks for them,
+            # the masked ones of a row's last block included
+            block = self._kv_block_pages * self.block.page_size
+            for name, n in (
+                    ("latent_kv_tokens", int(lengths.sum())),
+                    ("latent_block_slots", block * int(n_blocks(
+                        lengths, self.block.page_size,
+                        self._kv_block_pages).sum()))):
+                counts[name] = counts.get(name, 0) + n
+                self._dstats[name] += n
         if self._hybrid:
             # the positions the full layers' kernel reads (once, not a
             # layer), those the rings hold, and the rows with a long context

@@ -7,6 +7,12 @@ entries in scalar memory, the block mask's ``|`` on a tile of positions).
 32 of them a K/V head) x 320 pages of 16 over a pool of 4 K/V heads of 128;
 ``dstpu_flash_fwd`` under the block mask at a chunk of 2,048 against a window
 of 4,096 positions, 32 / 4 heads of 128.  It compiles; it does not run.
+
+Since PR 59 also ``dstpu_mla_decode`` at both latent cells' tables and pools
+(the tests that compile for a described chip live in ONE file: a second file
+may go to another worker, whose libtpu is then taken): a block of 64 or 48
+pages in a ring of two slots, the whole page table flat in scalar memory, the
+compiler's bounds checks off.
 """
 
 import jax
@@ -15,6 +21,8 @@ import pytest
 
 import deepspeed_tpu.utils.platform as plat
 from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+from deepspeed_tpu.ops.pallas.mla_attention import (latent_pages_per_block,
+                                                    mla_decode_attention)
 from deepspeed_tpu.ops.pallas.paged_attention import paged_decode_attention
 
 ROWS, BLOCK, NH, KVH, D, PAGES, PS = 256, 4, 32, 4, 128, 320, 16
@@ -68,3 +76,18 @@ def test_flash_under_the_block_mask_at_a_chunk(v5e, compiled_kernels):
         _arr(v5e, (1, 4096, KVH, D), jnp.bfloat16),
         _arr(v5e, (), jnp.int32)).compile()
     assert "dstpu_flash_fwd" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows,pages,rank,lanes,nb", [
+    (128, 1089, 256, 384, 64),    # mistralsmall4-ep8-docqa-saturated
+    (48, 2113, 512, 640, 48)])    # xing4-pp7-longrag-saturated
+def test_latent_decode_at_a_latent_cells_block(v5e, compiled_kernels, rows,
+                                               pages, rank, lanes, nb):
+    assert latent_pages_per_block(PS, lanes, 2) == nb
+    compiled = jax.jit(lambda q, pool, table, last, act: mla_decode_attention(
+        q, pool, table, last, 1, act, rank=rank)).lower(
+        _arr(v5e, (rows, NH, rank + 64), jnp.bfloat16),
+        _arr(v5e, (2, 8193, PS, lanes), jnp.bfloat16),
+        _arr(v5e, (rows, pages), jnp.int32), _arr(v5e, (rows,), jnp.int32),
+        _arr(v5e, (rows,), jnp.bool_)).compile()
+    assert "dstpu_mla_decode" in compiled.as_text()

@@ -13,9 +13,11 @@ One line per kernel: ``compiles`` (and, on the chip, the error against its XLA
 reference) or ``refused`` with the compiler's message.  Exit code 1 if any
 kernel was refused or disagreed.  ``sparse_attention`` and ``evoformer_attn``
 are not probed: nothing calls them (ROADMAP D8).  On the chip it also times
-the expert share's two row kernels (``--only moe_dispatch``) and the paged
+the expert share's two row kernels (``--only moe_dispatch``), the paged
 decode kernel at its four geometries (``--only paged_decode``: the time a
-call, a block, and the share of the bytes' roofline).
+call, a block, and the share of the bytes' roofline) and the latent decode
+kernel at its two (``--only mla_decode``: the same, and the kernel against
+its XLA form over 12 seeds, each call twice, bit for bit).
 """
 
 from __future__ import annotations
@@ -112,20 +114,25 @@ def cases():
                     [((rows, 40, 128), bf), pool, pool, ((rows, mp), i32),
                      ((rows,), i32), ((rows,), jnp.bool_)], None, 0))
 
-    # Mistral-Small-4's cell: the latent decode over one leaf of 256 + 64
-    # lanes a token (384 as laid out: whole lane tiles), 32 absorbed heads
-    def mla(q, pool, table, pos, act):
-        from deepspeed_tpu.ops.pallas.mla_attention import \
-            mla_decode_attention
+    # the two latent cells: the latent decode over one leaf of 256 + 64 lanes
+    # a token (384 as laid out: whole lane tiles; Mistral-Small-4) and of
+    # 512 + 64 (640; Xing4.0), 32 absorbed heads, each at the block its
+    # page's bytes give (``latent_pages_per_block``: 64 and 48 pages)
+    def mla(rank):
+        def run(q, pool, table, pos, act):
+            from deepspeed_tpu.ops.pallas.mla_attention import \
+                mla_decode_attention
 
-        return mla_decode_attention(q, pool, table, pos, jnp.int32(1), act,
-                                    rank=256)
+            return mla_decode_attention(q, pool, table, pos, jnp.int32(1),
+                                        act, rank=rank)
+        return run
 
-    out.append(("mla_decode 128x1088 pages of 16, 32 heads over a latent of "
-                "256 + 64", mla,
-                [((128, 32, 320), bf), ((2, 8193, 16, 384), bf),
-                 ((128, 1088), i32), ((128,), i32), ((128,), jnp.bool_)],
-                None, 0))
+    for _, rows, mp, nh, rank, dr, lanes, _ in MLA_SHAPES:
+        out.append((f"mla_decode {rows}x{mp} pages of 16, {nh} heads over a "
+                    f"latent of {rank} + {dr}", mla(rank),
+                    [((rows, nh, rank + dr), bf), ((2, 8193, 16, lanes), bf),
+                     ((rows, mp), i32), ((rows,), i32), ((rows,), jnp.bool_)],
+                    None, 0))
 
     # its window layers' chunk attention: [ring | chunk] keys with the mask
     def flash_window(q, k, v, k_first):
@@ -413,6 +420,32 @@ def row_rates() -> None:
                      if name.startswith("dstpu") else ""), flush=True)
 
 
+def _shuffled_table(pages, mp: int, perm):
+    """A page table ``[rows, mp]`` whose row ``r`` holds ``pages[r]`` ids of
+    a pool of ``sum(pages)`` pages in the order ``perm`` and the pool's one
+    page more (the trash page) past them."""
+    import numpy as np
+
+    n_pages = int(pages.sum())
+    table = np.full((len(pages), mp), n_pages, np.int32)
+    ends = np.cumsum(pages)
+    for r, n in enumerate(pages):
+        table[r, :n] = perm[ends[r] - n:ends[r]]
+    return table
+
+
+def _median_ms(fn, args, reps: int) -> float:
+    """ms a call of a program that chains ``reps`` calls: the median of
+    five runs by the host's clock, after one that compiles."""
+    fn(*args).block_until_ready()
+    times = []
+    for _ in range(5):
+        t0 = time.perf_counter()
+        fn(*args).block_until_ready()
+        times.append(time.perf_counter() - t0)
+    return sorted(times)[2] / reps * 1e3
+
+
 #: the paged kernel's four geometries: (name in a trace, rows, table pages,
 #: query heads, K/V heads, scale, visible tokens of row i)
 PAGED_SHAPES = [
@@ -456,11 +489,8 @@ def paged_rates() -> None:
         q = jax.random.normal(ks[0], (b, nh, d), jnp.bfloat16)
         k_pool, v_pool = (jax.random.normal(
             k, (1, n_pages + 1, ps, feat), jnp.bfloat16) for k in ks[1:3])
-        table = np.full((b, mp), n_pages, np.int32)
-        perm = np.asarray(jax.random.permutation(ks[3], n_pages))
-        ends = np.cumsum(pages)
-        for r in range(b):
-            table[r, :pages[r]] = perm[ends[r] - pages[r]:ends[r]]
+        table = _shuffled_table(pages, mp, np.asarray(
+            jax.random.permutation(ks[3], n_pages)))
 
         @jax.jit
         def chained(q, k_pool, v_pool, table, pos):
@@ -471,13 +501,7 @@ def paged_rates() -> None:
 
         args = (q, k_pool, v_pool, jnp.asarray(table),
                 jnp.asarray(toks - 1, jnp.int32))
-        chained(*args).block_until_ready()
-        times = []
-        for _ in range(5):
-            t0 = time.perf_counter()
-            chained(*args).block_until_ready()
-            times.append(time.perf_counter() - t0)
-        ms = sorted(times)[2] / reps * 1e3
+        ms = _median_ms(chained, args, reps)
         least_ms = 2 * n_pages * ps * feat * 2 / hbm * 1e3
         print(f"paged rates, {what} as {name}: {b} rows x {mp} pages, "
               f"{nh}/{kvh} heads, {n_pages} visible pages in {blocks} blocks "
@@ -485,6 +509,132 @@ def paged_rates() -> None:
               f"block, {least_ms / ms * 100:.1f} % of the bytes' roofline "
               f"({least_ms:.4f} ms)", flush=True)
         del args, k_pool, v_pool
+
+
+#: the latent kernel's two geometries: (cell, rows, table pages, heads, rank,
+#: rotary, lanes as laid out, mean visible tokens a row)
+MLA_SHAPES = [
+    ("Mistral-Small-4", 128, 1088, 32, 256, 64, 384, 5200),
+    ("Xing4.0", 48, 2113, 32, 512, 64, 640, 7600),
+]
+
+
+def mla_rates() -> int:
+    """On the chip: the latent decode kernel at both latent cells' sizes
+    (pages of 16 tokens, bfloat16) over ragged rows — lengths drawn like the
+    cells' prompts, lognormal about the cell's mean — and shuffled page ids.
+    (i) What a call costs, as 16 calls chained in one program by the host's
+    clock: the time a call, a page, a block of ``latent_pages_per_block``
+    pages, and the visible rows' bytes against the HBM's peak.  (ii) Over 12
+    seeds, ``model_runner._mla_absorbed`` through the kernel against its XLA
+    form (over the same values in float32, and as the program runs it), the
+    kernel's call made twice and the two outputs compared bit for bit: a
+    race in the ring shows there and nowhere on the CPU.  Returns the number
+    of seeds that differed."""
+    import json
+    import pathlib
+    import types
+
+    import numpy as np
+
+    from deepspeed_tpu.inference.v2.model_runner import _mla_absorbed
+    from deepspeed_tpu.ops.pallas.mla_attention import (
+        latent_pages_per_block, mla_decode_attention)
+    from deepspeed_tpu.ops.pallas.paged_attention import n_blocks
+
+    reps, ps, seeds, tol = 16, 16, 12, 2.0 ** -6
+    peaks = json.loads((pathlib.Path(__file__).resolve().parents[1]
+                        / "benchmark" / "peaks.json").read_text())
+    hbm = peaks[jax.devices()[0].device_kind]["hbm_bytes_per_s"]
+    bad = 0
+    for what, b, mp, nh, rank, dr, lanes, mean in MLA_SHAPES:
+        nb = latent_pages_per_block(ps, lanes, 2)
+        cfg = types.SimpleNamespace(n_heads=nh, kv_lora_rank=rank,
+                                    qk_nope_head_dim=64, qk_rope_head_dim=dr)
+        absorbed = jax.jit(functools.partial(_mla_absorbed, cfg),
+                           static_argnames=("use_kernel",))
+
+        @jax.jit
+        def chained(q, pool, table, pos, act, rank=rank):
+            def step(_, x):
+                return x.at[:, :, :rank].set(mla_decode_attention(
+                    x, pool, table, pos, jnp.int32(0), act, rank=rank))
+            return jax.lax.fori_loop(0, reps, step, q)
+
+        worst = [0.0, 0.0]
+        for seed in range(seeds):
+            rng = np.random.default_rng(seed)
+            toks = np.clip(rng.lognormal(np.log(mean / 1.2776), 0.7, b), 512,
+                           mp * ps).astype(np.int64)
+            if seed % 4 == 3:  # empty slots among the rows
+                toks[rng.random(b) < 0.1] = 0
+            pages = -(-toks // ps)
+            n_pages = int(pages.sum())
+            table = _shuffled_table(pages, mp, rng.permutation(n_pages))
+            ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+            pool = jax.random.normal(ks[0], (1, n_pages + 1, ps, lanes),
+                                     jnp.bfloat16)
+            q_nope, q_rope = (
+                (jax.random.normal(k, (b, nh, w), jnp.float32) * 0.3).astype(
+                    jnp.bfloat16) for k, w in ((ks[1], 64), (ks[2], dr)))
+            layer = {"attn": {"w_ukv": (jax.random.normal(
+                ks[3], (rank, nh * 192), jnp.float32) * rank ** -0.5).astype(
+                    jnp.bfloat16)}}
+            args = (layer, q_nope, q_rope, {"latent": pool}, jnp.int32(0),
+                    jnp.asarray(table), jnp.asarray(toks - 1, jnp.int32),
+                    jnp.asarray(toks > 0))
+            if seed == 0:
+                q = jnp.concatenate([jnp.einsum(
+                    "bnd,rnd->bnr", q_nope, layer["attn"]["w_ukv"].reshape(
+                        rank, nh, -1)[..., :64]), q_rope], axis=-1)
+                ms = _median_ms(chained, (q, pool) + args[5:], reps)
+                blocks = int(n_blocks(toks, ps, nb).sum())
+                stated, laid = (int(toks.sum()) * w * 2 / hbm * 1e3
+                                for w in (rank + dr, lanes))
+                print(f"mla rates, {what}: {b} rows x {mp} pages, {nh} heads "
+                      f"over {rank} + {dr} in {lanes} lanes, "
+                      f"{int(toks.sum())} visible tokens in {n_pages} pages, "
+                      f"{blocks} blocks of {nb} (fill "
+                      f"{toks.sum() / (blocks * nb * ps):.3f}): {ms:.4f} ms a "
+                      f"call = {ms * 1e6 / n_pages:.1f} ns a page, "
+                      f"{ms * 1e3 / blocks:.3f} us a block, "
+                      f"{stated / ms * 100:.1f} % of the stated bytes' "
+                      f"roofline ({stated:.4f} ms; {laid / ms * 100:.1f} % as "
+                      "laid out)", flush=True)
+                del q
+            one = absorbed(*args, use_kernel=True)
+            two = absorbed(*args, use_kernel=True)
+            same = bool(jnp.array_equal(one, two))
+            # the XLA form as the program would run it (its scores rounded to
+            # bfloat16) and over the same values in float32: the limit is on
+            # the second; a row that is not active is the kernel's alone (the
+            # XLA form's softmax over no key is uniform)
+            errs = []
+            for dt in (jnp.bfloat16, jnp.float32):
+                want = absorbed(*jax.tree_util.tree_map(
+                    lambda a, dt=dt: a.astype(dt) if jnp.issubdtype(
+                        a.dtype, jnp.floating) else a, args),
+                    use_kernel=False).astype(jnp.float32)
+                diff = (one.astype(jnp.float32) - want) * args[-1][:, None,
+                                                                   None]
+                errs.append(float(jnp.max(jnp.abs(diff))
+                                  / jnp.max(jnp.abs(want))))
+                del want, diff
+            zeros = not bool(jnp.any(jnp.where(args[-1][:, None, None], 0,
+                                               one)))
+            worst = [max(w, e) for w, e in zip(worst, errs)]
+            if not (same and zeros and errs[1] < tol):
+                bad += 1
+                print(f"  seed {seed}: DIFFERS — two calls equal {same}, "
+                      f"inactive rows zero {zeros}, rel err vs the XLA form "
+                      f"in float32 {errs[1]:.2e} (limit {tol:.2e}), as the "
+                      f"program runs it {errs[0]:.2e}", flush=True)
+            del pool, args, one, two
+        print(f"  {seeds} seeds x 2 calls, each pair bit for bit: worst rel "
+              f"err vs _mla_absorbed(use_kernel=False) in float32 "
+              f"{worst[1]:.2e} (limit {tol:.2e}), as the program runs it "
+              f"{worst[0]:.2e}; {bad} differences so far", flush=True)
+    return bad
 
 
 def main() -> int:
@@ -555,6 +705,8 @@ def main() -> int:
         row_rates()
     if not args.aot and args.only in "paged_decode":
         paged_rates()
+    if not args.aot and args.only in "mla_decode":
+        bad += mla_rates()
     return 1 if bad else 0
 
 
