@@ -51,9 +51,10 @@ from ...utils.platform import ensure_compile_cache
 from .model_runner import (pad_pages_pow2, paged_copy_page, paged_decode,
                            paged_gather_pages, paged_multi_decode,
                            paged_prefill, paged_prefill_chunk,
-                           paged_scatter_pages, paged_verify, sample_tokens)
+                           paged_read_rows, paged_scatter_pages, paged_verify,
+                           sample_tokens)
 from .packed_inputs import PackedProgram, pack_inputs
-from .ragged import (PRIORITY_NORMAL, BlockAllocator, KVBlockConfig,
+from .ragged import (PRIORITY_NORMAL, BlockAllocator, EvaRows, KVBlockConfig,
                      KVPageBundle, PagedKVCache, PrefixCache, RejectedError,
                      SequenceState, StateSlots)
 from .block_diffusion import block_policy
@@ -298,7 +299,21 @@ class InferenceEngineV2:
         if self._latent:
             self._refuse_with_latent(proposer)
         block = self.config.block
-        if block.num_pages < block.max_pages_per_seq:
+        #: the page arithmetic of a stack of EVA-attention layers (an open
+        #: window's rows and a summary row a chunk in the same pages), None
+        #: for every other model
+        self._eva: Optional[EvaRows] = None
+        if layers_of(self.cfg, "eva"):
+            self._refuse_with_eva(proposer)
+            self._eva = EvaRows(self.cfg.eva_window, self.cfg.eva_chunk,
+                                block.page_size, block.max_seq_len)
+            if block.num_pages < self._eva.max_pages:
+                raise ValueError(
+                    f"num_pages ({block.num_pages}) < the {self._eva.max_pages}"
+                    f" pages a sequence of {block.max_seq_len} positions "
+                    "holds (its summaries and an open window): one sequence "
+                    "could never run to completion even with the whole pool")
+        elif block.num_pages < block.max_pages_per_seq:
             raise ValueError(
                 f"num_pages ({block.num_pages}) < max_pages_per_seq "
                 f"({block.max_pages_per_seq}): one sequence could never run to "
@@ -410,8 +425,10 @@ class InferenceEngineV2:
         #: set by drain(): the engine is retiring, put() refuses admissions
         self._draining = False
         # host mirror of the device page tables, trash-filled
-        self._page_table = np.full((block.max_seqs, block.max_pages_per_seq),
-                                   block.trash_page, dtype=np.int32)
+        # (an 'eva' stack's row is [summary pages | open-window pages])
+        self._page_table = np.full(
+            (block.max_seqs, block.max_pages_per_seq if self._eva is None
+             else self._eva.table_pages), block.trash_page, dtype=np.int32)
 
         cfg = self.cfg
 
@@ -424,6 +441,15 @@ class InferenceEngineV2:
             # the fused multi-step scan uses, so decode horizons are
             # stream-identical (greedy and sampled alike) and a sampled
             # stream keeps its noise through preemption / migration
+            if cfg.pred_heads > 1:
+                # the next token from head 0, the further heads' picks beside
+                # it: [B, pred_heads] int32 crosses, never the logits
+                V = cfg.vocab_size
+                tok = sample_tokens(logits[:, :V], temps, key, sids, pos + 1)
+                with region("sample"):
+                    more = jnp.argmax(logits[:, V:].reshape(
+                        logits.shape[0], -1, V), axis=-1).astype(jnp.int32)
+                return jnp.concatenate([tok[:, None], more], axis=1), pools
             return sample_tokens(logits, temps, key, sids, pos + 1), pools
 
         # every serving program takes its host inputs packed
@@ -582,6 +608,46 @@ class InferenceEngineV2:
                 f"sliding_window {self.cfg.sliding_window} is not a whole "
                 f"number of pages of {self.config.block.page_size}: the "
                 "decode kernel reads a window's ring as pages")
+
+    def _refuse_with_eva(self, proposer: Any) -> None:
+        """What a stack of EVA-attention layers cannot be served with, each
+        by name: every one of them would answer wrongly over a cache whose
+        rows are pooled chunks and a window that empties."""
+        cfg, conf = self.cfg, self.config
+        W, C, ps = cfg.eva_window, cfg.eva_chunk, conf.block.page_size
+        if layers_of(cfg, "eva") != cfg.n_layers:
+            raise NotImplementedError(
+                "a stack that mixes 'eva' layers with others: the page "
+                "accounting of a sequence (ragged.EvaRows) is one for all of "
+                "its layers")
+        if conf.enable_prefix_cache:
+            raise ValueError(
+                "enable_prefix_cache: an 'eva' layer's pages hold a window's "
+                "rows that are given back when it closes and summaries that "
+                "are visible only past their window; a cached page keyed by "
+                "its tokens says neither; serve it with the prefix cache off")
+        if proposer is not None or conf.speculative.mode != "off":
+            raise ValueError(
+                "speculative decoding: paged_verify cannot roll a rejected "
+                "draft out of a pooled chunk or a closed window ('eva')")
+        if conf.kv_quant:
+            raise ValueError(
+                "kv_quant: an 'eva' layer pools a chunk's keys and values "
+                "from the rows as they are stored; int8 codes have no form "
+                "of it; serve it with kv_quant off")
+        if conf.decode_horizon > 1:
+            raise ValueError(
+                f"decode_horizon {conf.decode_horizon}: a row's open pages "
+                "go back to the allocator when its window closes, between "
+                "steps; the fused scan reserves pages by position; serve an "
+                "'eva' stack with decode_horizon 1")
+        chunk = conf.prefill_chunk
+        if chunk <= 0 or W % chunk or chunk % (ps * C):
+            raise ValueError(
+                f"prefill_chunk {chunk}: an 'eva' stack is prefilled through "
+                f"the chunk program in chunks that tile eva_window {W} (a "
+                "chunk never straddles a window) and are whole pages of "
+                f"summaries ({ps} x eva_chunk {C} = {ps * C} positions)")
 
     def _refuse_with_latent(self, proposer: Any) -> None:
         """What a model that caches a latent cannot be served with, by name.
@@ -1183,6 +1249,24 @@ class InferenceEngineV2:
         width = self.cfg.kv_lora_rank + self.cfg.qk_rope_head_dim
         return rows.reshape(rows.shape[0], -1, rows.shape[-1])[:, :n, :width]
 
+    def read_eva(self, uid: int) -> np.ndarray:
+        """The rows an admitted sequence's EVA-attention layers hold for a
+        query now, ``[layers, rows, 2 * heads * head_dim]`` (each ``[key |
+        value]``): the closed windows' summaries in chunk order, then the open
+        window's rows in position order — a host copy, for a check against a
+        reference.  After ``m`` returned tokens the cache holds the prompt
+        and the first ``m - 1`` of them."""
+        seq, ev = self._find_slotted(uid), self._eva
+        n, ps = seq.length - 1, self.block.page_size
+        n_vis, n_open = ev.visible(n), n % ev.window
+        sentinel_expect_recompile("read_eva")
+        pages = seq.pages[:n_vis // ps] \
+            + seq.pages[seq.n_sum:seq.n_sum + -(-n_open // ps)]
+        got = paged_read_rows(self._pools, ("k", "v"), pages,
+                              self.block.trash_page)
+        return np.concatenate([got["k"], got["v"]],
+                              axis=-1)[:, :n_vis + n_open]
+
     def export_sequence(self, uid: int) -> KVPageBundle:
         """Serialize an admitted sequence's KV pages + scheduling state
         into a :class:`KVPageBundle` (host arrays, bit-exact).  The
@@ -1192,6 +1276,11 @@ class InferenceEngineV2:
             raise NotImplementedError(
                 "KVPageBundle export: a bundle holds pages, and this model "
                 f"keeps recurrent state too ({sorted(self._state)})")
+        if self._eva is not None:
+            raise NotImplementedError(
+                "KVPageBundle export: a bundle's pages are a page a "
+                "page_size positions, and an 'eva' stack's are summaries and "
+                "an open window (ragged.EvaRows)")
         seq = self._find_slotted(uid)
         if self.blocks is not None:
             self.blocks.refuse_export()
@@ -1273,6 +1362,10 @@ class InferenceEngineV2:
         not enough pages are free (the caller tries another replica);
         raises ``ValueError`` on genuine incompatibility (different
         model geometry / page size / kv_quant / dtype)."""
+        if self._eva is not None:
+            raise NotImplementedError(
+                "KVPageBundle import: an 'eva' stack's pages are summaries "
+                "and an open window, not a page a page_size positions")
         self._check_bundle(bundle)
         slot = next((i for i, s in enumerate(self._slots) if s is None), None)
         if slot is None:
@@ -1650,7 +1743,7 @@ class InferenceEngineV2:
             self._m_state_preempt.inc()
         if seq.block is not None:  # a half-denoised block is redone
             self.blocks.drop(seq)
-        seq.slot, seq.pages, seq.prefilled = -1, [], 0
+        seq.slot, seq.pages, seq.prefilled, seq.n_sum = -1, [], 0, 0
         seq.page_keys, seq.registered_upto, seq.decode_entry = [], 0, False
         seq.cached_match, seq.match_gen, seq.match_evict_gen = None, -1, -1
         seq.queued_at = time.perf_counter()
@@ -1717,6 +1810,11 @@ class InferenceEngineV2:
                 shared, keys, _restored = self._tier_restore(
                     seq.tokens, shared, keys)
             n_total = -(-seq.length // ps)
+            if self._eva is not None:
+                # summary pages for the prompt's whole chunks and the open
+                # pages its prefill writes
+                eva_pages = self._eva_prefill_pages(seq.length)
+                n_total = sum(eva_pages)
             m = len(shared)
             # fully-cached prompt (page-aligned): the last cached page is
             # replaced by a private COPY-ON-WRITE duplicate — the decode
@@ -1793,7 +1891,11 @@ class InferenceEngineV2:
             seq.slot = i
             seq.admit_order = next(self._admit_counter)
             self._page_table[i, :] = self.block.trash_page
-            self._page_table[i, :len(seq.pages)] = seq.pages
+            if self._eva is None:
+                self._page_table[i, :len(seq.pages)] = seq.pages
+            else:
+                seq.n_sum = eva_pages[0]
+                self._eva_write_table(seq)
             tr = self._reqtrace(seq)
             if tr is not None:
                 # queue_wait closes here; "prefill" self-classifies as
@@ -1840,10 +1942,18 @@ class InferenceEngineV2:
             # dstpu-lint: allow[host-sync] host sampling of the prefix-end
             # logits: one [vocab] row per ADMISSION, not per decode step
             logits = np.asarray(logits, np.float32)
+        heads = None
+        if self.cfg.pred_heads > 1:  # head 0 samples; the others' picks ride
+            logits = logits.reshape(self.cfg.pred_heads, -1)
+            # dstpu-lint: allow[host-sync] ``logits`` is the host copy above
+            heads = [int(i) for i in np.argmax(logits[1:], axis=-1)]
+            logits = logits[0]
         tok = self._sample(seq, logits)
         seq.tokens.append(tok)
         self._note_tokens(seq)
         out[seq.uid] = {"tokens": [tok], "done": False}
+        if heads is not None:
+            out[seq.uid]["heads"] = [heads]
         self._maybe_finish(seq, tok)
         if seq.done:
             out[seq.uid]["done"] = True
@@ -1984,6 +2094,8 @@ class InferenceEngineV2:
         ps = self.block.page_size
         ids = np.zeros((C,), np.int32)
         ids[:c_n] = seq.tokens[start:start + c_n]
+        if self._eva is not None:
+            return self._run_eva_chunk(seq, ids, start, c_n, C)
         rows = np.full((C // ps,), self.block.trash_page, np.int32)
         npg = -(-c_n // ps)
         rows[:npg] = seq.pages[start // ps:start // ps + npg]
@@ -2055,6 +2167,15 @@ class InferenceEngineV2:
                 counts["queue_len"] = len(self._queue)
                 if self._state:
                     counts["state_slots_in_use"] = self.state_slots.in_use
+                if self._eva is not None:
+                    held = [self._eva.rows_held(s.prefilled)
+                            for s in self._slots if s is not None]
+                    counts["eva_summary_rows_in_use"] = sum(
+                        h[0] for h in held)
+                    counts["eva_window_rows_in_use"] = sum(h[1] for h in held)
+                    counts["eva_rows_in_use"] = sum(map(sum, held))
+                    counts["eva_pages_in_use"] = sum(
+                        len(s.pages) for s in self._slots if s is not None)
                 if self._latent or self._hybrid:
                     held = self.block.page_size * sum(
                         len(s.pages) for s in self._slots if s is not None)
@@ -2125,6 +2246,9 @@ class InferenceEngineV2:
                 # the cached positions the chunk attends
                 if self._latent or self._hybrid:
                     attrs["ctx_tokens"] = start
+                if self._eva is not None:  # summaries and open rows
+                    attrs["ctx_tokens"] = self._eva.visible(start) \
+                        + start % self._eva.window
                 if self._xdec:  # the cross-decoder runs for the last token
                     attrs["xdec_rows"] = int(start + c_n >= seq.length)
                     counts["xdec_rows"] = (counts.get("xdec_rows", 0)
@@ -2230,8 +2354,11 @@ class InferenceEngineV2:
             last, pos, act, temps, sids = self._decode_inputs(decode_seqs)
             self._decode_steps += 1
             counts["decode_rows"] += len(decode_seqs)
-            self._note_kv_blocks(np.where(act, pos + 1, 0))
-            self._note_state_rows(np.where(act, pos + 1, 0))
+            lengths = np.where(act, pos + 1, 0)
+            if self._eva is not None:  # what a row reads, not where it is
+                lengths = np.where(act, self._eva.rows_attended(pos), 0)
+            self._note_kv_blocks(lengths)
+            self._note_state_rows(lengths)
             with self._phase("decode", self._m_decode_h,
                              batch=len(decode_seqs)):
                 tokens, self._pools = self._dispatch(
@@ -2261,11 +2388,24 @@ class InferenceEngineV2:
 
             with self._step_span("step_emit"):
                 for seq in decode_seqs:
-                    tok = int(tokens[seq.slot])
+                    if self.cfg.pred_heads > 1:
+                        tok = int(tokens[seq.slot, 0])
+                        # dstpu-lint: allow[host-sync] ``tokens`` is the
+                        # host array the designed pull above brought
+                        further = [int(t) for t in tokens[seq.slot, 1:]]
+                        out.setdefault(seq.uid, {"tokens": [], "done": False}
+                                       ).setdefault("heads", []).append(
+                                           further)
+                    else:
+                        tok = int(tokens[seq.slot])
                     seq.tokens.append(tok)
                     self._note_tokens(seq)
                     # the decode step wrote KV for the token it consumed
                     seq.prefilled = seq.length - 1
+                    if (self._eva is not None
+                            and seq.prefilled % self._eva.window == 0):
+                        # the row's window closed inside the decode program
+                        self._eva_give_back(seq, 0, "decode")
                     if (self.prefix_cache is not None
                             and seq.prefilled % ps == 0):
                         # the decode write completed a page: publish it so
@@ -2293,9 +2433,18 @@ class InferenceEngineV2:
         scheduler holds requests back under KV pressure rather than
         failing); ``seq`` itself may be the one preempted (``seq.slot`` is
         then -1)."""
-        if pos // self.block.page_size != len(seq.pages):
+        if self._eva is not None:
+            # a page for the row at ``pos`` where it opens one, and a summary
+            # page where the chunk ``pos`` ends opens one
+            ev = self._eva
+            need_sum = ev.summary_pages(pos + 1) - seq.n_sum
+            need = need_sum + (pos % ev.window) // ev.page_size + 1 \
+                - (len(seq.pages) - seq.n_sum)
+        else:
+            need = int(pos // self.block.page_size == len(seq.pages))
+        if need <= 0:
             return
-        while self.allocator.free_pages < 1:
+        while self.allocator.free_pages < need:
             victims = [s for s in self._slots
                        if s is not None and s is not seq]
             # evict the lowest priority class first, then the
@@ -2313,9 +2462,96 @@ class InferenceEngineV2:
             self._preempt(victim)
             if victim is seq:
                 return
+        if self._eva is not None:
+            fresh = self.allocator.alloc(need)
+            seq.pages[seq.n_sum:seq.n_sum] = fresh[:need_sum]
+            seq.n_sum += need_sum
+            seq.pages += fresh[need_sum:]
+            self._eva_write_table(seq)
+            return
         page = self.allocator.alloc(1)[0]
         seq.pages.append(page)
         self._page_table[seq.slot, len(seq.pages) - 1] = page
+
+    # -- a stack of EVA-attention layers (ragged.EvaRows) ---------------------
+    def _eva_prefill_pages(self, length: int) -> Tuple[int, int]:
+        """(summary pages, open pages) a sequence of ``length`` positions is
+        admitted with: the summaries of its whole chunks, and the open pages
+        its chunks write — its last window's, or where a chunk is shorter
+        than a window and the prompt is not, a whole window's, which the
+        chunks of every window reuse and the last chunk trims."""
+        ev = self._eva
+        whole = length >= ev.window and self._chunk < ev.window
+        return (ev.summary_pages(length),
+                ev.open_cap if whole else ev.open_pages(length))
+
+    def _eva_write_table(self, seq: SequenceState) -> None:
+        """The host's table row of ``seq``: ``[summary pages | open pages]``,
+        trash elsewhere."""
+        row, opened = self._page_table[seq.slot], seq.pages[seq.n_sum:]
+        row[:] = self.block.trash_page
+        row[:seq.n_sum] = seq.pages[:seq.n_sum]
+        row[self._eva.sum_cap:self._eva.sum_cap + len(opened)] = opened
+
+    def _eva_give_back(self, seq: SequenceState, keep: int, where: str
+                       ) -> None:
+        """A window of ``seq`` has closed (or its prefill has ended): its
+        open pages but the first ``keep`` go back to the allocator."""
+        drop = seq.pages[seq.n_sum + keep:]
+        if drop:
+            self.allocator.free(drop)
+            del seq.pages[seq.n_sum + keep:]
+            self._eva_write_table(seq)
+        if seq.prefilled % self._eva.window == 0:
+            self._step_counts["eva_windows_closed"] = \
+                self._step_counts.get("eva_windows_closed", 0) + 1
+            record_event("eva_window_closed", cat="serve",
+                         step=self._step_id, uid=seq.uid, where=where,
+                         windows_closed=seq.prefilled // self._eva.window,
+                         pages_freed=len(drop),
+                         **({} if seq.trace_id is None
+                            else {"trace_id": seq.trace_id}))
+
+    def _run_eva_chunk(self, seq: SequenceState, ids: np.ndarray, start: int,
+                       c_n: int, C: int):
+        """``_run_prefill_chunk`` for a stack of EVA-attention layers: the
+        chunk's rows go to the open pages from ``(start % window) / ps`` on
+        (the trash page where the chunk closes its window: nothing reads them
+        again), its chunks' summaries to the summary pages from ``start / (ps
+        chunk)`` on, and it attends ``[the closed windows' summary pages | the
+        open window's earlier pages]`` right-aligned behind trash pages in a
+        table bucketed to a power of two."""
+        ev, ps, trash = self._eva, self.block.page_size, self.block.trash_page
+        if start % C:
+            raise RuntimeError(f"an 'eva' chunk starts at {start}, not at a "
+                               f"multiple of prefill_chunk {C}")
+        opened = seq.pages[seq.n_sum:]
+        closes = (start + c_n) % ev.window == 0
+        first = (start % ev.window) // ps
+        rows = np.full((C // ps + C // (ps * ev.chunk),), trash, np.int32)
+        if not closes:
+            take = opened[first:first + C // ps]
+            rows[:len(take)] = take
+        take = seq.pages[:seq.n_sum][start // (ps * ev.chunk):][
+            :C // (ps * ev.chunk)]
+        rows[C // ps:C // ps + len(take)] = take
+        before = seq.pages[:ev.visible(start) // ps] + opened[:first]
+        b = max(1, ev.open_cap // 4)
+        while b < len(before):
+            b *= 2
+        prev = np.full((b,), trash, np.int32)
+        if before:
+            prev[b - len(before):] = before
+        logits, self._pools = self._dispatch(
+            ("prefill_chunk", C, b), self._prefill_chunk,
+            (ids, rows, prev, np.int32(start), np.int32(c_n)),
+            phase="prefill")
+        seq.prefilled = start + c_n
+        if seq.prefilled >= seq.length:  # the last chunk: trim to the window
+            self._eva_give_back(seq, ev.open_pages(seq.prefilled), "prefill")
+        elif closes:  # the pages are the next window's chunks' too
+            self._eva_give_back(seq, len(opened), "prefill")
+        return logits
 
     def _pull(self, *arrays) -> List[np.ndarray]:
         """Host copies of a decode call's results.  With an expert share
@@ -2367,6 +2603,9 @@ class InferenceEngineV2:
         the blocks it walks for them (``latent_block_slots``: the kernel's
         own ``n_blocks`` times its block)."""
         counts, rows = self._step_counts, int((lengths > 0).sum())
+        if self._eva is not None:  # once, not a layer
+            counts["eva_rows_attended"] = counts.get("eva_rows_attended", 0) \
+                + int(lengths.sum())
         if self._latent:  # once, not a layer: the kernel's bytes are x layers
             # ... and the positions of the blocks the kernel walks for them,
             # the masked ones of a row's last block included

@@ -82,6 +82,21 @@ formulations cannot diverge.  With one stream the pair is ``x`` and ``x + y``
 and traces no operation of its own: a one-stream model's programs are what
 they were.
 
+A stack of EVA-attention layers (``eva``: EvaByte) keeps both of its caches in
+the K and V page leaves: the open window's keys and values, a row a position,
+and one pooled summary row a whole chunk of ``cfg.eva_chunk`` positions
+(``ragged.EvaRows``: a page is one chunk of exact rows or a page of summaries;
+the host's table row is ``[summary pages | open-window pages]``).  The decode
+program writes a row, pools the page it completed at a chunk's last position
+(``layer_types.eva_pool``) and attends ``[visible summary pages | open pages]``
+— a table composed on the device from the row's position, so a row whose
+window closes goes on in the same program with ``window / chunk`` more
+summaries visible and an empty window — through the paged decode kernel under
+the name ``dstpu_eva_decode``; the chunk program attends ``[visible summaries
+| the open window's rows | the chunk, causal]``, the first two gathered by a
+table the host right-aligns, through the flash kernel with the unused front
+masked (``k_first``).  The head is ``cfg.pred_heads`` heads wide, in float32.
+
 A model that generates by diffusion over blocks (``cfg.block_length``:
 SDAR-MoE) has ``paged_block_pass`` in ``paged_decode``'s place — a block of
 ``B`` positions a row, its K/V written in place to its slots of the row's page,
@@ -101,9 +116,9 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 
-from ...models.layer_types import (GqaShape, gqa_shape, latent_width,
-                                   layers_of, page_layers, run_config,
-                                   served_runs)
+from ...models.layer_types import (GqaShape, eva_pool, gqa_shape,
+                                   latent_width, layers_of, page_layers,
+                                   run_config, served_runs)
 from ...models.transformer import (MODEL_AXIS, TransformerConfig, _mm,
                                    _norm, _repeat_kv, alibi_slopes, attn_qkv,
                                    logits_fn, mlp_block, mlp_delta,
@@ -190,7 +205,7 @@ _EXPERT_MATRICES = ("w_gate", "w_up", "w_down")
 #: cache writes, page gathers, masks and the XLA forms of attention, or what
 #: surrounds a recurrent-state kernel
 _MIXER_GLUE = {"kda": "state_glue", "mamba": "state_glue",
-               "gmu": "state_glue"}
+               "gmu": "state_glue", "eva": "eva_glue"}
 
 
 def _experts_left_stacked(period_body, trees):
@@ -845,6 +860,43 @@ def _ring_write(pools, at, old, fresh, W: int, ps: int, start, n):
                 pools["win_" + nm].dtype), at) for nm, a in new.items()})
 
 
+def _eva_decode_rows(cfg: TransformerConfig, page_table, positions, active,
+                     ps: int, trash: int):
+    """What the decode form of the ``eva`` mixer reads of a step's rows, from
+    the host's table ``[B, sum_cap + window / ps]`` (``ragged.EvaRows``) and
+    the rows' positions alone — so a row whose window closed at its last step
+    attends ``window / chunk`` more summaries and an empty window with no
+    other program: ``table`` ``[B, MP]`` the visible summary pages then the
+    open pages (trash after them), ``attended`` ``[B]`` the rows a query sees
+    (itself included), ``open_page`` the page its key and value go to and
+    ``sum_page`` / ``sum_off`` where the summary of the chunk it ends goes
+    (the trash page for a row that ends none, or is not active)."""
+    from .ragged import EvaRows
+
+    B, MP = page_table.shape
+    W, C = cfg.eva_window, cfg.eva_chunk
+    # the host's own arithmetic (it refuses a page that is not one chunk),
+    # over the positions on the device
+    ev = EvaRows(W, C, ps, (MP - W // ps) * ps * C)
+    WP, sum_cap = ev.open_cap, ev.sum_cap
+    with region("eva_glue"):
+        rows = jnp.arange(B)
+        vis_pages = ev.visible(positions) // ps
+        i = jnp.arange(MP)[None]
+        behind = i - vis_pages[:, None]  # index among the open pages
+        src = jnp.where(behind < 0, i, jnp.minimum(sum_cap + behind, MP - 1))
+        table = jnp.where(behind < WP,
+                          jnp.take_along_axis(page_table, src, axis=1), trash)
+        open_page = jnp.where(
+            active, page_table[rows, sum_cap + positions % W // ps], trash)
+        c = positions // C
+        ends = active & (positions % C == C - 1)
+        sum_page = jnp.where(
+            ends, page_table[rows, jnp.minimum(c // ps, sum_cap - 1)], trash)
+    return {"table": table, "attended": ev.rows_attended(positions),
+            "open_page": open_page, "sum_page": sum_page, "sum_off": c % ps}
+
+
 class _Forms(dict):
     """A program's ``layer_fns``: a mixer it has no form of is refused by
     name when a stack asks for it."""
@@ -972,6 +1024,30 @@ def paged_gather_pages(pools, pages, kv_heads):
     for name in ("k", "v"):
         if name in out:  # a latent pool's rows have no heads to split
             out[name] = out[name].reshape(*out[name].shape[:3], kv_heads, -1)
+    return out
+
+
+@jax.jit
+def _pages_one_by_one(leaf, pages):
+    # a loop of page-sized slices: the working set is the result, where a
+    # gather of pages 128 KiB wide reserved 2.6 GB on the chip (PR 60)
+    return jax.lax.map(lambda p: jax.lax.dynamic_index_in_dim(
+        leaf, p, axis=1, keepdims=False), pages)
+
+
+def paged_read_rows(pools, names, pages, trash_page):
+    """Host copy of the rows of ``pages``, in that order, for each leaf of
+    ``names``: ``[L, len(pages) * page_size, width]`` — a checking aid's read
+    of wide pages beside an engine that nearly fills the device (the page
+    count is padded to a power of two: few programs)."""
+    import numpy as np
+
+    rows = jnp.asarray(np.asarray(pad_pages_pow2(pages, trash_page), np.int32))
+    out = {}
+    for name in names:
+        got = np.asarray(_pages_one_by_one(pools[name], rows))[:len(pages)]
+        out[name] = got.transpose(1, 0, 2, 3).reshape(
+            got.shape[1], -1, got.shape[-1])
     return out
 
 
@@ -1273,6 +1349,46 @@ def paged_prefill_chunk(cfg: TransformerConfig, params, pools,
                             W, ps, start, n)
         return _attn_out(cfg, layer, x, o, pools, read)
 
+    # ---- EVA attention: [visible summaries | the open window | the chunk]
+    def eva_fn(layer, l, x, pools):
+        # ``chunk_rows`` = [the open pages that take the chunk's rows | the
+        # summary pages that take its chunks' summaries]; ``prev_table`` the
+        # visible summary pages then the open window's earlier pages,
+        # right-aligned behind trash pages (``engine_v2._run_prefill_chunk``)
+        W, Cc, NH = cfg.eva_window, cfg.eva_chunk, cfg.n_heads
+        open_rows, sum_rows = chunk_rows[:C // ps], chunk_rows[C // ps:]
+        q, k, v = attn_qkv(cfg, layer, x, positions)
+        with region("eva_glue"):
+            pools = _pool_write(
+                pools, l, (open_rows,), k[0].reshape(C // ps, ps, *k.shape[2:]),
+                v[0].reshape(C // ps, ps, *v.shape[2:]))
+        ks, vs = eva_pool(layer["attn"],
+                          k[0].reshape(C // Cc, Cc, *k.shape[2:]),
+                          v[0].reshape(C // Cc, Cc, *v.shape[2:]))
+        with region("eva_pool"):
+            pools = _pool_write(
+                pools, l, (sum_rows,), ks.reshape(-1, ps, *ks.shape[1:]),
+                vs.reshape(-1, ps, *vs.shape[1:]))
+        with region("eva_glue"):
+            kp, vp = _pool_window(pools, l, prev_table, NH)
+            kk = jnp.concatenate([kp.astype(x.dtype)[None], k], axis=1)
+            vv = jnp.concatenate([vp.astype(x.dtype)[None], v], axis=1)
+            # what stands before the chunk: the closed windows' summaries and
+            # the open window's rows; the table's front is unused
+            k_first = S_prev - (start // W) * (W // Cc) - start % W
+            if use_kernel:
+                from ...ops.pallas.flash_attention import flash_attention
+
+                o = flash_attention(q, kk, vv, causal=True, q_offset=S_prev,
+                                    window=S_prev + C, k_first=k_first)
+            else:
+                cols = jnp.arange(S_prev + C)[None]
+                vis = (cols <= S_prev + jnp.arange(C)[:, None]) \
+                    & (cols >= k_first)
+                o = _gqa_softmax(q, kk, vv, vis[None],
+                                 1.0 / math.sqrt(cfg.head_dim))
+        return _attn_out(cfg, layer, x, o.reshape(1, C, -1), pools)
+
     last_pos = (start + n - 1).reshape(1)
 
     def attend_last(q, pools):
@@ -1301,7 +1417,7 @@ def paged_prefill_chunk(cfg: TransformerConfig, params, pools,
         _Forms("chunked prefill", attn=layer_fn, mla=mla_fn, kda=kda_fn,
                mamba=mamba_fn, swa=swa_fn, dattn=dattn_fn, gmu=_gmu_fn(cfg),
                xattn=_xattn_fn(cfg, attend_last), gqa_full=gqa_full_fn,
-               gqa_window=gqa_window_fn),
+               gqa_window=gqa_window_fn, eva=eva_fn),
         cross=({"mem": jnp.zeros((1, 1, cfg.ssm_inner), jnp.float32)}
                if cfg.ssm_inner else None),
         until=None if final or not xdec else "dattn")
@@ -1619,10 +1735,42 @@ def paged_decode(cfg: TransformerConfig, params, pools,
         return _attn_out(cfg, layer, x, o[:, None],
                          dict(pools, win_k=win_k, win_v=win_v), read)
 
+    # ---- EVA attention: a row, the page it completes pooled, and [visible
+    # summary pages | open pages] composed from the row's position
+    eva = _eva_decode_rows(cfg, page_table, positions, active, ps, trash) \
+        if cfg.eva_window else None
+
+    def eva_fn(layer, l, x, pools):
+        q, k, v = attn_qkv(cfg, layer, x, positions[:, None])
+        with region("eva_glue"):
+            pools = _pool_write(pools, l, (eva["open_page"], off), k[:, 0],
+                                v[:, 0])
+            # the chunk this position may end is the page just written to
+            page = [pools[nm][l, eva["open_page"]].reshape(B, ps, *k.shape[2:])
+                    for nm in ("k", "v")]
+        ks, vs = eva_pool(layer["attn"], *page)
+        with region("eva_pool"):
+            pools = _pool_write(pools, l, (eva["sum_page"], eva["sum_off"]),
+                                ks, vs)
+        with region("eva_glue"):
+            if use_kernel:
+                from ...ops.pallas.paged_attention import \
+                    paged_decode_attention
+
+                attn = paged_decode_attention(
+                    q[:, 0], pools["k"], pools["v"], eva["table"],
+                    eva["attended"] - 1, layer=l, active=active,
+                    name="dstpu_eva_decode").reshape(B, 1, -1)
+            else:
+                attn = _gather_window_attend(
+                    cfg, q, pools, l, eva["table"], positions[:, None],
+                    (slot_pos < eva["attended"][:, None])[:, None, :])
+        return _attn_out(cfg, layer, x, attn, pools)
+
     like = pools
     x, pools = _scan_layers(
         cfg, params, _ring_pages(pools, ps), x,
-        _Forms("decode", attn=layer_fn, mla=mla_fn, kda=kda_fn,
+        _Forms("decode", eva=eva_fn, attn=layer_fn, mla=mla_fn, kda=kda_fn,
                mamba=mamba_fn, swa=swa_fn, dattn=dattn_fn, gmu=_gmu_fn(cfg),
                xattn=_xattn_fn(cfg, attend_pages), gqa_full=gqa_full_fn,
                gqa_window=gqa_window_fn),

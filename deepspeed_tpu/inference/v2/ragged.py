@@ -625,6 +625,86 @@ class PagedKVCache:
         return pools
 
 
+@dataclasses.dataclass(frozen=True)
+class EvaRows:
+    """The page arithmetic of a sequence whose layers cache as EVA attention
+    does (``models/layer_types.EVA``): of ``n`` cached positions, the open
+    window's ``n % window`` exact rows, a row a position, and one summary row
+    a whole chunk of ``chunk`` positions — rows that advance once a chunk and
+    not once a token.  Both live in the same K and V page leaves.
+
+    A sequence's ``pages`` list is ``[summary pages | open-window pages]``,
+    the first ``SequenceState.n_sum`` of it the summary pages: summary ``c``
+    (positions ``[c chunk, (c + 1) chunk)``) is row ``c % page_size`` of
+    summary page ``c // page_size``; position ``t`` of the open window is row
+    ``t % page_size`` of open page ``(t % window) // page_size``.  The host's
+    table row has ``table_pages`` entries, ``[sum_cap summary pages | window
+    / page_size open pages]`` (``sum_cap``: what ``max_positions`` positions'
+    summaries take); the programs compose what a query attends —
+    the *visible* summary pages (those of closed windows: ``visible(t) / page
+    _size`` of them, whole pages because ``window / chunk`` is a whole number
+    of pages) then the open pages — from it.  When a window closes its open
+    pages go back to the allocator."""
+    window: int
+    chunk: int
+    page_size: int
+    #: the longest sequence, in positions (``KVBlockConfig.max_seq_len``)
+    max_positions: int
+
+    def __post_init__(self):
+        W, C, ps = self.window, self.chunk, self.page_size
+        if ps != C:
+            raise ValueError(
+                f"page_size {ps} is not eva_chunk {C}: a page is one chunk "
+                "of the open window's rows, which the decode program pools "
+                "at the chunk's last position")
+        if W % ps or (W // C) % ps:
+            raise ValueError(
+                f"page_size {ps} does not tile eva_window {W} and its "
+                f"{W // C} summaries: the open window and a closed window's "
+                "summaries are whole pages of the composed table")
+
+    @property
+    def open_cap(self) -> int:
+        return self.window // self.page_size
+
+    @property
+    def sum_cap(self) -> int:
+        return -(-(-(-self.max_positions // self.chunk)) // self.page_size)
+
+    @property
+    def table_pages(self) -> int:
+        return self.sum_cap + self.open_cap
+
+    @property
+    def max_pages(self) -> int:
+        """Pages the longest sequence holds at once."""
+        return self.summary_pages(self.max_positions) + self.open_cap
+
+    def summary_pages(self, n: int) -> int:
+        """Pages that hold the summaries of ``n`` cached positions' whole
+        chunks."""
+        return -(-(n // self.chunk) // self.page_size)
+
+    def open_pages(self, n: int) -> int:
+        """Pages that hold the open window's rows after ``n`` positions (a
+        window that has just closed holds none)."""
+        return -(-(n % self.window) // self.page_size)
+
+    def visible(self, n: int) -> int:
+        """Summary rows a query sees once ``n`` positions are cached: every
+        chunk of every closed window."""
+        return (n // self.window) * (self.window // self.chunk)
+
+    def rows_held(self, n: int) -> Tuple[int, int]:
+        """(summary rows written, open-window rows) after ``n`` positions."""
+        return n // self.chunk, n % self.window
+
+    def rows_attended(self, pos: int) -> int:
+        """Rows a decode query at position ``pos`` reads, a layer."""
+        return self.visible(pos) + pos % self.window + 1
+
+
 class StateSlots:
     """The host's book of the state slots: slot ``i`` belongs to decode row
     ``i`` and is held by the sequence scheduled there.  A slot is never
@@ -729,6 +809,10 @@ class SequenceState:
     eos_id: int | None
     slot: int = -1  # decode slot index, -1 = not scheduled
     pages: List[int] = dataclasses.field(default_factory=list)
+    #: a model whose layers cache as EVA attention does: how many of
+    #: ``pages``, from the front, hold summaries; the others are the open
+    #: window's (``EvaRows``).  0 for every other model
+    n_sum: int = 0
     done: bool = False
     admit_order: int = -1  # monotonic admission stamp (preemption policy)
     #: tokens of the prefix already prefilled (chunked prefill / cached

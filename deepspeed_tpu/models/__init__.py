@@ -1,4 +1,5 @@
 from .bert import bert_config, bert_model
+from .evabyte import evabyte_config, evabyte_model
 from .families import (bloom_config, bloom_model, falcon_config,
                        falcon_model, gpt_neox_config, gpt_neox_model,
                        mistral_config,
@@ -28,4 +29,5 @@ __all__ = ["bert_config", "bert_model", "gpt2_config", "gpt2_model",
            "mistral4_model", "mimo_v2_config", "mimo_v2_model",
            "sdar_moe_config", "sdar_moe_model", "laguna_config",
            "laguna_model", "xing4_config", "xing4_model",
+           "evabyte_config", "evabyte_model",
            "TransformerConfig"]

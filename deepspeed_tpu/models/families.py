@@ -32,7 +32,9 @@ different K/V head counts, keys wider than values, behind a dense first layer),
 ``laguna.py`` (the same two types with query heads, rotary table and rotated
 share by type, a gate a head, a shared expert), ``xing4.py`` (a residual of
 four streams mixed by hyper-connections round latent attention and a
-sigmoid-routed expert layer held whole, behind a dense first layer);
+sigmoid-routed expert layer held whole, behind a dense first layer),
+``evabyte.py`` (EVA attention: an exact window beside pooled summaries of the
+closed ones, a cache that grows a row a chunk, eight prediction heads of 320);
 ``lfm2_moe.py`` (short-convolution + GQA layers over an expert share) is
 trained and not served.
 """

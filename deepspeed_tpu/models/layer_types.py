@@ -43,8 +43,8 @@ import jax.numpy as jnp
 from ..runtime.activation_checkpointing.checkpointing import get_policy
 from ..telemetry.regions import region
 from .transformer import (MODEL_AXIS, TransformerConfig, _mm, _nrm, _norm,
-                          _rope, attn_mixer, init_layer_stack, mlp_block,
-                          yarn_inv_freq)
+                          _rope, attn_mixer, attn_qkv, init_layer_stack,
+                          mlp_block, yarn_inv_freq)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -494,8 +494,90 @@ GQA_FULL = LayerType("gqa_full", _init_gqa("gqa_full"), mixer="gqa_full",
 GQA_WINDOW = LayerType("gqa_window", _init_gqa("gqa_window"),
                        mixer="gqa_window", pages=_no_pages, state=_gqa_ring,
                        mix=_served_only("gqa_window", _NO_GQA_BWD))
+# ----------------------------------------------------- EVA attention (EvaByte)
+#: the scales at which a seeded EVA layer is drawn, so that the mechanism
+#: decides the output visibly at every width (the stack is seeded, not
+#: trained): queries and keys ``QK / sqrt(hidden)`` a weight, so that their
+#: lanes have a spread of ``QK`` over a normed input and a score ``sigma q . k``
+#: one of ``QK^2`` — a softmax over n rows then has an entropy near ``ln n -
+#: QK^4 / 2``, far from flat; values ``1 / sqrt(hidden)``; ``adaptive_phi``
+#: ``PHI`` a lane, so that a chunk's pooling argument ``sigma k . phi`` spreads
+#: by ``QK x PHI`` and the pooling is far from a mean; ``adaptive_mu_k`` ``MU``
+#: a lane, which moves a summary's score against a query by ``QK x MU`` and
+#: stands beside a pooled key's own lanes (``QK`` times the pooling weights'
+#: root sum of squares)
+EVA_QK_SCALE = 1.5
+EVA_PHI_SCALE = 1.0
+EVA_MU_SCALE = 0.5
+
+
+def _init_eva(cfg: TransformerConfig, rng, n: int) -> Dict[str, Any]:
+    keys = jax.random.split(rng, 32)
+    layers = init_layer_stack(cfg, keys, n)
+    H, NH, D = cfg.hidden_size, cfg.n_heads, cfg.head_dim
+    a = layers["attn"]
+    for j, (name, s) in enumerate((("wq", EVA_QK_SCALE), ("wk", EVA_QK_SCALE),
+                                   ("wv", 1.0))):
+        a[name] = _nrm(cfg, keys[18 + j], n, H, NH * D, s=s / math.sqrt(H))
+    a["adaptive_phi"] = _nrm(cfg, keys[16], n, NH, D, s=EVA_PHI_SCALE)
+    a["adaptive_mu_k"] = _nrm(cfg, keys[17], n, NH, D, s=EVA_MU_SCALE)
+    return layers
+
+
+def eva_pool(a, k, v):
+    """One summary a chunk: ``k``, ``v`` ``[..., C, NH, D]`` the chunk's keys
+    (rotated) and values -> ``(k~, v~)`` ``[..., NH, D]``, ``k~ = sum_m a_m
+    k_m + mu``, ``v~ = sum_m a_m v_m``, ``a = softmax_m(sigma k_m . phi)``, a
+    head at a time and in float32; ``a``: the layer's ``attn`` parameters."""
+    with region("eva_pool"):
+        kf, vf = k.astype(jnp.float32), v.astype(jnp.float32)
+        s = jnp.einsum("...cnd,nd->...cn", kf,
+                       a["adaptive_phi"].astype(jnp.float32)) \
+            / math.sqrt(k.shape[-1])
+        w = jax.nn.softmax(s, axis=-2)
+        ks = jnp.einsum("...cn,...cnd->...nd", w, kf) \
+            + a["adaptive_mu_k"].astype(jnp.float32)
+        vs = jnp.einsum("...cn,...cnd->...nd", w, vf)
+        return ks.astype(k.dtype), vs.astype(v.dtype)
+
+
+def eva_mix(cfg: TransformerConfig, layer, x, positions, mask, attn_fn):
+    """EVA attention over whole sequences that start at position 0, ``[B, S,
+    H]`` -> the delta the block adds: a query at ``t`` sees the keys of its
+    own window ``floor(t / W)`` up to itself and, of every earlier window, one
+    summary a chunk (``eva_pool``), under one float32 softmax.  The plain
+    form: ``[S, S / C + S]`` scores a head, for the tests and the CPU."""
+    del mask, attn_fn  # whole sequences without padding, its own attention
+    B, S, _ = x.shape
+    W, C, D = cfg.eva_window, cfg.eva_chunk, cfg.head_dim
+    q, k, v = attn_qkv(cfg, layer, x, positions)
+    with region("eva_glue"):
+        pad = ((0, 0), (0, -S % C), (0, 0), (0, 0))
+        ks, vs = eva_pool(layer["attn"], *(
+            jnp.pad(y, pad).reshape(B, -1, C, *y.shape[2:]) for y in (k, v)))
+        t = positions[:, :, None]
+        m = positions[:, None, :]
+        c = jnp.arange(ks.shape[1])[None, None, :]
+        vis = jnp.concatenate([(c // (W // C) < t // W),
+                               (m <= t) & (m // W == t // W)], axis=-1)
+        s = jnp.einsum("btnd,bsnd->bnts", q, jnp.concatenate([ks, k], axis=1)
+                       ).astype(jnp.float32) / math.sqrt(D)
+        p = jax.nn.softmax(jnp.where(vis[:, None], s, -1e30), axis=-1)
+        o = jnp.einsum("bnts,bsnd->btnd", p.astype(v.dtype),
+                       jnp.concatenate([vs, v], axis=1)).reshape(B, S, -1)
+    with region("attn_out"):
+        return _mm(cfg, o, layer["attn"]["wo"], MODEL_AXIS, None)
+
+
+#: EVA attention (EvaByte).  What a layer keeps of a sequence says two things
+#: at once, both in the K and V page leaves: the open window's keys and values
+#: (at most ``eva_window`` rows, given back when the window closes) and one
+#: summary row a whole chunk of ``eva_chunk`` positions (``ragged.EvaRows``:
+#: the page accounting; rows advance once a chunk, not once a token)
+EVA = LayerType("eva", _init_eva, mixer="eva", pages=_kv_pages,
+                state=lambda cfg: {}, mix=eva_mix)
 _TYPES = {"gqa_full": GQA_FULL, "gqa_window": GQA_WINDOW, "attn": ATTN, "kda": KDA, "conv": CONV, "mamba": MAMBA, "swa": SWA,
-          "dattn": DATTN, "gmu": GMU, "xattn": XATTN, "mla": MLA}
+          "dattn": DATTN, "gmu": GMU, "xattn": XATTN, "mla": MLA, "eva": EVA}
 
 
 def layer_type(kind: str) -> LayerType:

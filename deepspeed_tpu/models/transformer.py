@@ -289,6 +289,18 @@ class TransformerConfig:
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
     hc_clamp: float = 30.0
+    #: EVA attention (type "eva"; EvaByte): a query sees the positions of its
+    #: own window of ``eva_window`` exactly and every *closed* window through
+    #: one pooled summary a chunk of ``eva_chunk`` positions, under one softmax
+    #: (``layer_types.eva_mix``: the equations).  What a layer caches follows:
+    #: the open window's keys and values and a summary row a closed chunk
+    eva_window: int = 0
+    eva_chunk: int = 0
+    #: prediction heads (EvaByte's ``num_pred_heads``): the head is ``[hidden,
+    #: pred_heads * vocab_size]``, head ``i`` at position ``t`` predicts token
+    #: ``t + 1 + i``, and the logits are float32 (``fp32_logits``).  The serving
+    #: programs sample the next token from head 0 and return the others' picks
+    pred_heads: int = 1
 
     @property
     def kv_heads(self) -> int:
@@ -343,7 +355,7 @@ def init_embed_head(cfg: TransformerConfig, keys) -> Dict[str, Any]:
     if cfg.position == "learned":
         p["embed"]["pos"] = nrm(keys[1], cfg.max_seq_len, H)
     if not cfg.tie_embeddings:
-        p["lm_head"] = {"w": nrm(keys[2], H, V)}
+        p["lm_head"] = {"w": nrm(keys[2], H, V * cfg.pred_heads)}
     return p
 
 
@@ -1110,6 +1122,9 @@ def logits_fn(cfg: TransformerConfig, params, hidden):
         if cfg.tie_embeddings:
             return hidden @ params["embed"]["tok"].T
         w = params["lm_head"]["w"]
+        if cfg.pred_heads > 1:
+            # several prediction heads side by side, logits in float32
+            return jnp.dot(hidden, w, preferred_element_type=jnp.float32)
         if isinstance(w, dict):  # weight-only quantized head
             out = _mm(cfg, hidden, w)
         else:

@@ -58,6 +58,11 @@ REGIONS = (
                       # connections): the norm over the streams, the
                       # coefficients' projection, the Sinkhorn rounds, the
                       # read-in and the write-back
+    "eva_pool",       # EVA attention's summaries: a chunk's pooling softmax,
+                      # the pooled key and value, their writes to the pages
+    "eva_glue",       # what surrounds EVA attention's kernels: the composed
+                      # page table, the open window's row writes, the gathers
+                      # of summaries and open rows for a chunk, the XLA form
     "head",           # final norm and logits
     "loss",           # log-softmax, the pick, the mean
     "sample",         # arg-max, temperature sampling, the reveal rule

@@ -160,6 +160,7 @@ def test_bloom_paged_inference_matches_dense(monkeypatch):
 @pytest.mark.parametrize("name, why", [
     ("mistral4", "'mla' has no mix"),
     ("xing4", "'mla' has no mix"),
+    ("evabyte", "the backward of a window-plus-summaries attention"),
 ])
 def test_served_only_families_are_listed_and_refuse_training_by_name(name,
                                                                      why):

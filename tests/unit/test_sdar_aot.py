@@ -91,3 +91,37 @@ def test_latent_decode_at_a_latent_cells_block(v5e, compiled_kernels, rows,
         _arr(v5e, (rows, pages), jnp.int32), _arr(v5e, (rows,), jnp.int32),
         _arr(v5e, (rows,), jnp.bool_)).compile()
     assert "dstpu_mla_decode" in compiled.as_text()
+
+
+# Since PR 60 also the EvaByte cell's two kernels: the paged decode kernel at
+# MHA of 32 K/V heads x 128 (a 4096-wide key row and value row: a block of 8
+# pages) over a table of [summary pages | open-window pages] under its own
+# name, and the chunk form's flash over [summaries | the open window | the
+# chunk] with the table's unused front masked by ``k_first``.
+def test_eva_decode_at_a_4096_wide_row(v5e, compiled_kernels):
+    from deepspeed_tpu.ops.pallas.paged_attention import pages_per_block
+
+    assert pages_per_block(PS, 32 * D, 2) == 8
+    pool = _arr(v5e, (2, 1025, PS, 32 * D), jnp.bfloat16)
+    compiled = jax.jit(lambda q, k, v, table, last, act:
+                       paged_decode_attention(q, k, v, table, last, layer=1,
+                                              active=act,
+                                              name="dstpu_eva_decode")).lower(
+        _arr(v5e, (32, 32, D), jnp.bfloat16), pool, pool,
+        _arr(v5e, (32, 256), jnp.int32), _arr(v5e, (32,), jnp.int32),
+        _arr(v5e, (32,), jnp.bool_)).compile()
+    assert "dstpu_eva_decode" in compiled.as_text()
+
+
+@pytest.mark.parametrize("before", [512, 2048])
+def test_flash_over_summaries_an_open_window_and_a_chunk(v5e,
+                                                         compiled_kernels,
+                                                         before):
+    compiled = jax.jit(lambda q, k, v, first: flash_attention(
+        q, k, v, causal=True, q_offset=before, window=before + 2048,
+        k_first=first)).lower(
+        _arr(v5e, (1, 2048, 32, D), jnp.bfloat16),
+        _arr(v5e, (1, before + 2048, 32, D), jnp.bfloat16),
+        _arr(v5e, (1, before + 2048, 32, D), jnp.bfloat16),
+        _arr(v5e, (), jnp.int32)).compile()
+    assert "dstpu_flash_fwd" in compiled.as_text()

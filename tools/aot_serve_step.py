@@ -8,6 +8,7 @@
     python tools/aot_serve_step.py --config benchmark/configs/mimo-v2-flash-ep16-serve.json
     python tools/aot_serve_step.py --config benchmark/configs/laguna-s-2.1-ep8-serve.json
     python tools/aot_serve_step.py --config benchmark/configs/xing4-29b-a4b-pp7-serve.json
+    python tools/aot_serve_step.py --config benchmark/configs/evabyte-6.5b-pp4-serve.json
 
 Reads a serving configuration file of the benchmark (model widths, depth and
 the ``engine`` block: page size, ``num_pages``, ``max_seqs``, chunk), builds
@@ -197,7 +198,8 @@ def main() -> int:
         a.size * a.dtype.itemsize // a.shape[0] for a in pools.values()
         if a.size * a.dtype.itemsize >= 256 * 2 ** 20)
     block, ps, C = engine.block, engine.block.page_size, engine._chunk
-    B, MP = block.max_seqs, block.max_pages_per_seq
+    # (an 'eva' stack's table row is [summary pages | open-window pages])
+    B, MP = block.max_seqs, engine._page_table.shape[1]
     print(f"aot_serve_step: {device.device_kind!r}, {config['name']}: "
           f"{k.shape[0]} layers, pools "
           f"{ {n: a.shape for n, a in pools.items()} } = "
@@ -255,13 +257,24 @@ def main() -> int:
     windows = ([int(w) for w in args.windows.split(",")] if args.windows
                else [MP] if engine._xdec
                else sorted({max(1, C // ps), MP}))
+    rows = C // ps
+    if engine._eva is not None and not args.windows:
+        # a chunk's table: the closed windows' summary pages and the open
+        # window's earlier pages, in the engine's power-of-two buckets; its
+        # rows: the open pages it writes, then the summary pages
+        ev = engine._eva
+        rows += C // (ps * ev.chunk)
+        most = ev.visible(block.max_seq_len) // ps + (ev.window - C) // ps
+        windows = [max(1, ev.open_cap // 4)]
+        while windows[-1] < most:
+            windows.append(2 * windows[-1])
     # a model whose layers keep recurrent state is handed the sequence's slot
     slot = (arr((), i32),) if engine._state else ()
     for w in windows:
         results[f"chunk{w}"] = report(
             f"chunk [{C} tokens, window {w} pages]",
             engine._prefill_chunk.lower(
-                params, pools, arr((C,), i32), arr((C // ps,), i32),
+                params, pools, arr((C,), i32), arr((rows,), i32),
                 arr((w,), i32), arr((), i32), arr((), i32), *slot),
             pool_bytes, layer_pool_bytes, no_pool)
         if engine._xdec:

@@ -14,7 +14,7 @@ reference) or ``refused`` with the compiler's message.  Exit code 1 if any
 kernel was refused or disagreed.  ``sparse_attention`` and ``evoformer_attn``
 are not probed: nothing calls them (ROADMAP D8).  On the chip it also times
 the expert share's two row kernels (``--only moe_dispatch``), the paged
-decode kernel at its four geometries (``--only paged_decode``: the time a
+decode kernel at its five geometries (``--only paged_decode``: the time a
 call, a block, and the share of the bytes' roofline) and the latent decode
 kernel at its two (``--only mla_decode``: the same, and the kernel against
 its XLA form over 12 seeds, each call twice, bit for bit).
@@ -95,6 +95,30 @@ def cases():
                     f"D=128 {jnp.dtype(dt).name}", paged,
                     [((rows, nh, 128), bf), pool, pool, ((rows, mp), i32),
                      ((rows,), i32), ((rows,), jnp.bool_)] + scales, None, 0))
+
+    # EvaByte's cell: MHA of 32 K/V heads x 128 (a 4096-wide key row and
+    # value row: blocks of 8 pages) over a table composed of summary pages
+    # and open-window pages, under its own name; and the chunk form's flash
+    # over [summaries | the open window | the chunk], the front masked
+    def eva_decode(q, k, v, table, pos, act):
+        return paged_decode_attention(q, k, v, table, pos,
+                                      layer=jnp.int32(1), active=act,
+                                      name="dstpu_eva_decode")
+
+    pool = ((2, 1025, 16, 4096), bf)
+    out.append(("paged_decode 32x256 pages of 16, 32/32 heads D=128 as "
+                "dstpu_eva_decode", eva_decode,
+                [((32, 32, 128), bf), pool, pool, ((32, 256), i32),
+                 ((32,), i32), ((32,), jnp.bool_)], None, 0))
+
+    def eva_chunk(q, k, v, first):
+        return flash_attention(q, k, v, causal=True, q_offset=2048,
+                               window=4096, k_first=first)
+
+    out.append(("flash_attention eva chunk 2048 over 2048 + 2048, 32/32 "
+                "heads D=128, k_first", eva_chunk,
+                [((1, 2048, 32, 128), bf), ((1, 4096, 32, 128), bf),
+                 ((1, 4096, 32, 128), bf), ((), i32)], None, 0))
 
     # Phi-4-mini-flash's cell: the shared pool's decode (pairs: 40 query
     # heads of 128 over 10 K/V heads, F = 1280, blocks of 6 pages) and the
@@ -446,7 +470,7 @@ def _median_ms(fn, args, reps: int) -> float:
     return sorted(times)[2] / reps * 1e3
 
 
-#: the paged kernel's four geometries: (name in a trace, rows, table pages,
+#: the paged kernel's five geometries: (name in a trace, rows, table pages,
 #: query heads, K/V heads, scale, visible tokens of row i)
 PAGED_SHAPES = [
     ("dstpu_paged_decode", "Mistral chat", 64, 256, 32, 8, None,
@@ -456,12 +480,14 @@ PAGED_SHAPES = [
      0.125, lambda i: 160 + (i * 613) % 1900),
     ("dstpu_window_decode", "Phi-4 pairs, a ring of 512", 128, 32, 40, 10,
      0.125, lambda i: 512),
+    ("dstpu_eva_decode", "EvaByte, summaries + an open window", 32, 256, 32,
+     32, None, lambda i: 128 * (i % 6) + 16 + (i * 613) % 2032),
 ]
 
 
 def paged_rates() -> None:
     """On the chip: what a call of the paged decode kernel costs at each of
-    its four geometries (pages of 16 tokens, heads of 128, bfloat16), as 16
+    its five geometries (pages of 16 tokens, heads of 128, bfloat16), as 16
     calls chained in one program (a call's result is the next one's
     queries) by the host's clock: the time a call, a block of
     ``pages_per_block`` pages, and the visible pages' bytes against the
