@@ -119,13 +119,28 @@ import jax.numpy as jnp
 from ...models.layer_types import (GqaShape, eva_pool, gqa_shape,
                                    latent_width, layers_of, page_layers,
                                    run_config, served_runs)
+from ...models import transformer
 from ...models.transformer import (MODEL_AXIS, TransformerConfig, _mm,
-                                   _norm, _repeat_kv, alibi_slopes, attn_qkv,
+                                   _norm, _repeat_kv, alibi_slopes,
                                    logits_fn, mlp_block, mlp_delta,
                                    rope_interleaved, yarn_inv_freq)
 from ...telemetry.regions import region
 from ...ops.pallas.paged_attention import (merged_keys, split_keys,
                                            split_queries)
+
+
+def _by_head(cfg: TransformerConfig, h, leaf, heads: int, dim: int,
+             bias=None):
+    """A projection whose result is used by head, as every paged program
+    makes it: the plain product, pinned, and only then the view by head
+    (``transformer.head_projection``) — no copy of the weight a call."""
+    return transformer.head_projection(cfg, h, leaf, bias, heads, dim,
+                                       pinned=True)
+
+
+def attn_qkv(cfg: TransformerConfig, layer, x, positions):
+    """``transformer.attn_qkv`` over pinned products."""
+    return transformer.attn_qkv(cfg, layer, x, positions, pinned=True)
 
 
 def _use_paged_kernel() -> bool:
@@ -655,8 +670,7 @@ def _xattn_fn(cfg: TransformerConfig, attend):
         a = layer["attn"]
         h = _ln1(cfg, layer, x)
         with region("attn_qkv"):
-            q = (_mm(cfg, h, a["wq"], None, MODEL_AXIS)
-                 + a["bq"]).reshape(*x.shape[:2], cfg.n_heads, cfg.head_dim)
+            q = _by_head(cfg, h, a["wq"], cfg.n_heads, cfg.head_dim, a["bq"])
         x, aux = _diff_out(cfg, layer, x, attend(q, pools), i)
         return x, pools, aux, cross
     return xattn_fn
@@ -672,13 +686,12 @@ def _mla_project(cfg: TransformerConfig, layer, x, positions):
     zeros]``."""
     f32 = jnp.float32
     a = layer["attn"]
-    B, T, _ = x.shape
     NH, R = cfg.n_heads, cfg.kv_lora_rank
     dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     h = _ln1(cfg, layer, x)
     with region("attn_qkv"):
         cq = _norm(h @ a["w_dq"], a["q_norm"], None, "rmsnorm", cfg.norm_eps)
-        q = (cq @ a["w_uq"]).reshape(B, T, NH, dn + dr)
+        q = _by_head(cfg, cq, a["w_uq"], NH, dn + dr)
         ckv = h @ a["w_dkv"]
         c = _norm(ckv[..., :R], a["kv_norm"], None, "rmsnorm", cfg.norm_eps)
         inv_freq = yarn_inv_freq(dr, cfg.rope_theta, cfg.rope_factor,
@@ -772,10 +785,9 @@ def _gqa_qkv(cfg: TransformerConfig, sh: GqaShape, layer, x, positions):
     the type's table (``GqaShape.rotate``), and values ``[B, T, KVH, v_dim]``
     times ``attn_value_scale`` — keys and values as the cache keeps them."""
     a = layer["attn"]
-    B, T, _ = x.shape
     h = _ln1(cfg, layer, x)
     with region("attn_qkv"):
-        q, k, v = (_mm(cfg, h, a[w], None, MODEL_AXIS).reshape(B, T, n, d)
+        q, k, v = (_by_head(cfg, h, a[w], n, d)
                    for w, n, d in (("wq", sh.heads, sh.k_dim),
                                    ("wk", sh.kv_heads, sh.k_dim),
                                    ("wv", sh.kv_heads, sh.v_dim)))

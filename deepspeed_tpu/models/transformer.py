@@ -781,13 +781,36 @@ def _pick_attn(cfg: TransformerConfig) -> Callable:
     return xla_attention
 
 
-def attn_qkv(cfg: TransformerConfig, layer, x, positions):
+def head_projection(cfg: TransformerConfig, h, leaf, bias, heads: int,
+                    dim: int, pinned: bool = False):
+    """``h [..., H] @ W [H, heads * dim]`` (``+ bias`` unless None) viewed by
+    head: ``[..., heads, dim]``.
+
+    ``pinned`` (the paged programs, ``inference/v2/model_runner.py``): the
+    product stays a plain ``[rows, H] x [H, heads * dim]`` one behind an
+    optimization barrier.  Left free, XLA:TPU folds the reshape into the
+    product — the heads become a spatial dimension of a convolution, which
+    reads the weight ``[heads, dim, H]``: a slice of the stack into a buffer
+    and a transposition of all of it (33.5 MB a layer at Mistral-7B's
+    ``wq``) on every decode step.  Pinned, the weight is read once, as it is
+    stored, inside the product, like ``wo``.  The same operands and float32
+    accumulation either way; a differentiated program is left free."""
+    y = _mm(cfg, h, leaf, None, MODEL_AXIS)
+    if pinned:
+        y = jax.lax.optimization_barrier(y)
+    if bias is not None:
+        y = y + bias
+    return y.reshape(*h.shape[:-1], heads, dim)
+
+
+def attn_qkv(cfg: TransformerConfig, layer, x, positions,
+             pinned: bool = False):
     """norm1 + QKV projection + rope — shared by the training forward and the
-    paged inference programs (inference/v2/model_runner.py).
+    paged inference programs (inference/v2/model_runner.py), which alone pin
+    the products (``head_projection``).
 
     x: [B, T, H] -> q [B, T, NH, D], k/v [B, T, KVH, D] (pre-GQA-repeat).
     """
-    B, T, _ = x.shape
     NH, KVH, D = cfg.n_heads, cfg.kv_heads, cfg.head_dim
     a = layer["attn"]
     qb = cfg.use_bias or cfg.qkv_bias
@@ -798,9 +821,11 @@ def attn_qkv(cfg: TransformerConfig, layer, x, positions):
             x, layer["norm1"]["scale"], layer["norm1"].get("bias"), cfg.norm,
             cfg.norm_eps)
     with region("attn_qkv"):
-        q = (_mm(cfg, h, a["wq"], None, MODEL_AXIS) + (a["bq"] if qb else 0)).reshape(B, T, NH, D)
-        k = (_mm(cfg, h, a["wk"], None, MODEL_AXIS) + (a["bk"] if qb else 0)).reshape(B, T, KVH, D)
-        v = (_mm(cfg, h, a["wv"], None, MODEL_AXIS) + (a["bv"] if qb else 0)).reshape(B, T, KVH, D)
+        # (``+ 0`` without a bias: the training programs' text as it was)
+        q, k, v = (head_projection(cfg, h, a[w], a[b] if qb else 0, n, D,
+                                   pinned=pinned)
+                   for w, b, n in (("wq", "bq", NH), ("wk", "bk", KVH),
+                                   ("wv", "bv", KVH)))
         if cfg.qk_norm:
             q = _norm(q, a["q_norm"], None, "rmsnorm", cfg.norm_eps)
             k = _norm(k, a["k_norm"], None, "rmsnorm", cfg.norm_eps)

@@ -488,11 +488,15 @@ def _programs(model, chunk=8):
 #: the lowered text of the parent commit's programs (0f78d52, before the 'eva'
 #: forms), locations stripped: this file's ``_programs`` run in a checkout of
 #: that commit
+#: (PR 61 pinned every paged program's head projections — ``h @ wq``
+#: behind an optimization barrier, ``transformer.head_projection`` — a
+#: change these programs were meant to take: the hashes of the programs
+#: that hold one are its tree's, jax 0.9.0.)
 PARENT_PROGRAMS = {
-    "mistral": ("6056c3453928977c", "399f5f3f05852b26"),
-    "mistral4": ("f74ae56354158b3f", "e23ffe9739607640"),
-    "mimo_v2": ("b6aec0025d519a7b", "ac3d992eb44e415d"),
-    "phi4_flash": ("dde7af555d243507", "8b83f6aeb9addc31"),
+    "mistral": ("cc62c9d28da856c0", "b0a8be65dccd46cd"),
+    "mistral4": ("99a7e4c4266c9a8b", "855b7928d9e3cd10"),
+    "mimo_v2": ("649bb00efa1c0803", "f747549fbcb0bee7"),
+    "phi4_flash": ("eb955ed0a861ab9c", "c1e3ef0585c3d77d"),
 }
 _MODELS = {"mistral": mistral_model, "mistral4": mistral4_model,
            "mimo_v2": mimo_v2_model, "phi4_flash": phi4_flash_model}

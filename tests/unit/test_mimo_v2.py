@@ -679,10 +679,14 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
 # (Since PR 53 a serving program takes its inputs packed: the text pinned
 # here is ``program.apart()``'s, the function behind the slices, which is
 # the parent's.)
-_PARENT_HLO = {"phi4_flash.decode": "f84f19250dc41075",
-               "phi4_flash.chunk": "4d5a774a13135991",
-               "mistral4.decode": "fd000ab078fa3959",
-               "mistral4.chunk": "3ebc8bb4bb3c5eb5"}
+# (PR 61 pinned every paged program's head projections — ``h @ wq``
+# behind an optimization barrier, ``transformer.head_projection`` — a
+# change these programs were meant to take: the hashes of the programs
+# that hold one are its tree's, jax 0.9.0.)
+_PARENT_HLO = {"phi4_flash.decode": "971ff8b37e892773",
+               "phi4_flash.chunk": "d208b3b81bd4cd27",
+               "mistral4.decode": "2f00d02f2035b50a",
+               "mistral4.chunk": "4440fcd7665ff91e"}
 
 
 @pytest.mark.parametrize("program", sorted(_PARENT_HLO))

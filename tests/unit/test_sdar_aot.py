@@ -125,3 +125,73 @@ def test_flash_over_summaries_an_open_window_and_a_chunk(v5e,
         _arr(v5e, (1, before + 2048, 32, D), jnp.bfloat16),
         _arr(v5e, (), jnp.int32)).compile()
     assert "dstpu_flash_fwd" in compiled.as_text()
+
+
+# Since PR 61 also two whole serving engines' programs, compiled through the
+# engine's own jitted functions: a projection whose result is used by head is
+# pinned as a plain product (``transformer.head_projection``), so the compiled
+# program reads ``wq`` / ``wk`` / ``wv`` in place, as stored — no copy of a
+# projection weight (whole, a layer's slice of the stack, or an async copy of
+# either) and no product over the heads as a window.  A scanned homogeneous
+# stack (the chat cell's case: the slice + ``{1,2,0}`` copy of ``copy.35``) and
+# a typed stack whose period runs once, unrolled (MiMo's: a copy of the
+# argument itself).
+def _scanned_stack():
+    from deepspeed_tpu.models import mistral_model
+
+    return mistral_model("tiny", max_seq_len=256, hidden_size=1024, n_heads=8,
+                         n_kv_heads=2, intermediate_size=2048, n_layers=2,
+                         vocab_size=1024)
+
+
+def _unrolled_stack():
+    from deepspeed_tpu.models import mimo_v2_model
+
+    return mimo_v2_model("tiny", max_seq_len=256, hidden_size=1024, n_heads=8,
+                         head_dim_override=192, v_head_dim=128, n_kv_heads=2,
+                         swa_kv_heads=4, sliding_window=128, vocab_size=1024,
+                         dense_ffn_size=2048, intermediate_size=256)
+
+
+@pytest.fixture(scope="module", params=["scanned", "unrolled"])
+def serving_programs(request):
+    """A small lane-aligned engine with real weights on the CPU; the tests
+    lower its own programs for the described chip over abstract arguments."""
+    from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
+                                            RaggedInferenceConfig)
+
+    model = {"scanned": _scanned_stack,
+             "unrolled": _unrolled_stack}[request.param]()
+    return InferenceEngineV2(model, RaggedInferenceConfig(
+        dtype="bf16", page_size=16, max_pages_per_seq=16, prefill_chunk=128,
+        max_seqs=16, num_pages=64), seed=0)
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_a_serving_program_copies_no_projection_weight(
+        v5e, compiled_kernels, serving_programs, program):
+    from tools.aot_serve_step import weight_copies
+
+    eng = serving_programs
+    on_chip = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: _arr(v5e, a.shape, a.dtype), tree)
+    params, pools = on_chip(eng.params), on_chip(eng._pools)
+    i32, B, MP = jnp.int32, 16, 16
+    if program == "decode":
+        lowered = eng._decode.lower(
+            params, pools, _arr(v5e, (B,), i32), _arr(v5e, (B,), i32),
+            _arr(v5e, (B, MP), i32), _arr(v5e, (B,), jnp.bool_),
+            _arr(v5e, (B,), jnp.float32), _arr(v5e, (B,), i32),
+            _arr(v5e, (2,), jnp.uint32))
+    else:
+        slot = (_arr(v5e, (), i32),) if eng._state else ()
+        lowered = eng._prefill_chunk.lower(
+            params, pools, _arr(v5e, (128,), i32), _arr(v5e, (8,), i32),
+            _arr(v5e, (MP,), i32), _arr(v5e, (), i32), _arr(v5e, (), i32),
+            *slot)
+    hlo = lowered.compile().as_text()
+    products = [line for line in hlo.splitlines()
+                if " convolution(" in line and "region.attn_qkv" in line]
+    assert products, "the projections are products the compiler names"
+    assert not [line for line in products if "window=" in line]
+    assert not [c for c in weight_copies(hlo, floor=0) if "['attn']" in c[4]]

@@ -472,13 +472,17 @@ def test_what_a_model_with_state_refuses(what):
 # (Since PR 53 a serving program takes its inputs packed: the text pinned
 # here is ``program.apart()``'s, the function behind the slices, which is
 # the parent's.)
+# (PR 61 pinned every paged program's head projections — ``h @ wq``
+# behind an optimization barrier, ``transformer.head_projection`` — a
+# change these programs were meant to take: the hashes of the programs
+# that hold one are its tree's, jax 0.9.0.)
 _PARENT_HLO = {
-    ("mistral", "decode"): "6b1fa57f488db398",
-    ("mistral", "chunk"): "0c5edc758fc8ef28",
-    ("mistral", "prefill"): "a4152d0fc8243332",
-    ("opt", "decode"): "64518f36cedec060",
-    ("opt", "chunk"): "aca7bf0224dd5a89",
-    ("opt", "prefill"): "bea5999055e19e48",
+    ("mistral", "decode"): "d0b0017a588b453f",
+    ("mistral", "chunk"): "dad9799eed8e03a7",
+    ("mistral", "prefill"): "7450ab949f1579f2",
+    ("opt", "decode"): "ac1ede5ce6b7906f",
+    ("opt", "chunk"): "954e2fdb407d33b2",
+    ("opt", "prefill"): "dbbe2de358dba036",
 }
 
 

@@ -437,9 +437,13 @@ def _one_stream_programs(model, chunk):
 #: the lowered text of the parent commit's programs (c59049c, before any
 #: stream was carried), locations stripped: this file's ``_lowered_hash``
 #: run in a checkout of that commit
+#: (PR 61 pinned every paged program's head projections — ``h @ wq``
+#: behind an optimization barrier, ``transformer.head_projection`` — a
+#: change these programs were meant to take: the hashes of the programs
+#: that hold one are its tree's, jax 0.9.0.)
 PARENT_PROGRAMS = {
-    "mistral": ("6056c3453928977c", "399f5f3f05852b26"),
-    "mistral4": ("f74ae56354158b3f", "e23ffe9739607640"),
+    "mistral": ("cc62c9d28da856c0", "b0a8be65dccd46cd"),
+    "mistral4": ("99a7e4c4266c9a8b", "855b7928d9e3cd10"),
 }
 
 

@@ -26,6 +26,15 @@ slots ("pool-sized" is then one layer of the smallest large leaf).
 ``--num-pages`` / ``--max-seqs`` ask what another size would cost.  Exit 1
 when a program does not keep the pools in place.
 
+Beside them, every ``copy`` of at least 4 MB whose operand is a leaf of
+``params['layers']``, a slice of one or an async copy of one (name, shape,
+layout, MB, the leaf) and their sum: a weight the program re-lays out on every
+call (``weight_copies``; a loop body counts once).  A projection whose result
+is used by head is pinned as a plain product for that
+(``models/transformer.py::head_projection``): 0 in every decode program.
+``--hlo-dir`` writes each program's optimized HLO to a file there.  Neither
+moves the exit code.
+
 It compiles; it does not run.  No time or numeric result comes from here.
 """
 
@@ -86,6 +95,107 @@ def big_instructions(hlo: str, floor: int, no_pool=None):
     return out
 
 
+#: `%name = <shape> opcode(<operands>)<attributes>` of any instruction, a
+#: tuple-shaped one included
+_ANY_INSTR = re.compile(r"^\s*(ROOT )?%([\w.\-]+) = (.*?) ([a-z][\w\-]*)\("
+                        r"([^)]*)\)(.*)$")
+_ARRAY = re.compile(r"^(\w+)\[([\d,]*)\](\{[\d,]*)?")
+_CALLED = re.compile(r"(?:calls|body|to_apply)=%([\w.\-]+)")
+#: opcodes whose result is still "the weight, or a slice of it"
+_SAME_BYTES = ("bitcast", "dynamic-slice", "slice", "copy-start", "copy-done")
+WEIGHT_COPY_FLOOR = 4 * 2 ** 20
+
+
+def _computations(hlo: str):
+    """``{computation: {instruction: (opcode, operand names, operand text,
+    shape text, attributes, is_root)}}`` of an optimized HLO module, the
+    entry computation's name, and ``{callee: (caller, instruction)}``."""
+    comps, callers, entry, comp = {}, {}, None, None
+    for line in hlo.splitlines():
+        c = _COMPUTATION.match(line)
+        if c:
+            comp = c.group(1)
+            comps[comp] = {}
+            if line.startswith("ENTRY"):
+                entry = comp
+            continue
+        m = _ANY_INSTR.match(line) if comp else None
+        if not m:
+            continue
+        root, name, shape, op, args, attrs = m.groups()
+        comps[comp][name] = (op, re.findall(r"%([\w.\-]+)", args), args,
+                             shape, attrs, bool(root))
+        for callee in _CALLED.findall(attrs):
+            callers[callee] = (comp, name)
+    return comps, entry, callers
+
+
+def _weight_of(comps, entry, callers, comp: str, name: str):
+    """The path of the ``params['layers']`` leaf that ``name`` (in ``comp``)
+    is — whole, sliced, or copied asynchronously — or None.  Followed through
+    a fusion that only slices, a fused computation's parameters and a loop's
+    carried tuple back to the entry computation's parameters."""
+    for _ in range(64):
+        if name not in comps.get(comp, {}):
+            return None
+        op, operands, args, _, attrs, _ = comps[comp][name]
+        if op == "parameter" and comp == entry:
+            path = re.search(r'op_name="([^"]*)"', attrs)
+            path = path.group(1).replace("\\'", "'") if path else name
+            return path if "params['layers']" in path else None
+        if op == "parameter":
+            comp, site = callers.get(comp, (None, None))
+            if comp is None or comps[comp][site][0] not in ("fusion", "call"):
+                return None
+            name = comps[comp][site][1][int(args)]
+        elif op in ("fusion", "call"):
+            callee = _CALLED.search(attrs).group(1)
+            body = comps.get(callee, {})
+            if any(i[0] not in _SAME_BYTES + ("parameter", "constant")
+                   for i in body.values()):
+                return None
+            comp, name = callee, next(n for n, i in body.items() if i[5])
+        elif op == "get-tuple-element":
+            if comps[comp][operands[0]][0] != "parameter" \
+                    or comp not in callers:
+                return None
+            # a loop body's carried tuple: what the loop was given
+            index = int(re.search(r"index=(\d+)", attrs).group(1))
+            comp, loop = callers[comp]
+            given = comps[comp][comps[comp][loop][1][0]]
+            if comps[comp][loop][0] != "while" or given[0] != "tuple":
+                return None
+            name = given[1][index]
+        elif op in _SAME_BYTES and operands:
+            name = operands[0]
+        else:
+            return None
+    return None
+
+
+def weight_copies(hlo: str, floor: int = WEIGHT_COPY_FLOOR):
+    """(bytes, name, shape, layout, leaf) of every ``copy`` in the optimized
+    HLO — inside a fusion's body too — of at least ``floor`` bytes whose
+    operand is a leaf of ``params['layers']``, a slice of one or an async
+    copy of one: a weight re-laid out by the program on every call."""
+    comps, entry, callers = _computations(hlo)
+    out = []
+    for comp, instrs in comps.items():
+        for name, (op, operands, _, shape, _, _) in instrs.items():
+            m = _ARRAY.match(shape) if op == "copy" and operands else None
+            if not m or m.group(1) not in _DTYPE_BYTES:
+                continue
+            dims = [int(d) for d in m.group(2).split(",") if d]
+            nbytes = (int(np.prod(dims, dtype=np.int64))
+                      * _DTYPE_BYTES[m.group(1)])
+            leaf = nbytes >= floor and _weight_of(
+                comps, entry, callers, comp, operands[0])
+            if leaf:
+                out.append((nbytes, name, f"{m.group(1)}[{m.group(2)}]",
+                            (m.group(3) or "{") + "}", leaf))
+    return out
+
+
 def abstract_engine(config: dict, engine_overrides: dict):
     """``InferenceEngineV2`` of a benchmark serving config with no array
     behind it: weights and pools are ``ShapeDtypeStruct`` leaves."""
@@ -121,9 +231,15 @@ def abstract_engine(config: dict, engine_overrides: dict):
 
 
 def report(name: str, lowered, pool_bytes: int, layer_pool_bytes: int,
-           no_pool=None) -> dict:
+           no_pool=None, hlo_dir: str = "") -> dict:
     t0 = time.time()
     compiled = lowered.compile()
+    hlo = compiled.as_text()
+    if hlo_dir:
+        os.makedirs(hlo_dir, exist_ok=True)
+        with open(os.path.join(hlo_dir, re.sub(
+                r"[^\w.]+", "_", name).strip("_") + ".hlo.txt"), "w") as f:
+            f.write(hlo)
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
@@ -137,13 +253,20 @@ def report(name: str, lowered, pool_bytes: int, layer_pool_bytes: int,
           f"{total / GIB:.2f} GiB of 15.75")
     print(f"  Mosaic kernels: "
           f"{sorted(set(re.findall(r'dstpu_[a-z_]+', lowered.as_text())))}")
-    big = big_instructions(compiled.as_text(), layer_pool_bytes, no_pool)
+    big = big_instructions(hlo, layer_pool_bytes, no_pool)
     print(f"  instructions that materialize >= one layer of a pool "
           f"({layer_pool_bytes / 1e6:.1f} MB): {len(big)}")
     for nbytes, iname, op, shape, in_place in sorted(big, reverse=True):
         print(f"    {nbytes / 1e6:9.1f} MB  {op:10s} {iname}  {shape}  "
               + ("in place: shares its operand's buffer" if in_place
                  else "A BUFFER OF ITS OWN"))
+    copies = weight_copies(hlo)
+    print(f"  copies of a layer weight (>= {WEIGHT_COPY_FLOOR / 1e6:.1f} MB, "
+          f"once a loop body): {len(copies)} = "
+          f"{sum(c[0] for c in copies) / 1e6:.1f} MB")
+    for nbytes, iname, shape, layout, leaf in sorted(copies, reverse=True):
+        print(f"    {nbytes / 1e6:9.1f} MB  copy       {iname}  {shape}"
+              f"{layout}  of {leaf}")
     return {"temp": mem.temp_size_in_bytes, "alias": mem.alias_size_in_bytes,
             "copied": [b for b in big if not b[4]]}
 
@@ -160,6 +283,8 @@ def main() -> int:
                     help="chunk-program window buckets in pages, a,b,...; "
                     "default: the smallest that holds a chunk and "
                     "max_pages_per_seq")
+    ap.add_argument("--hlo-dir", default="",
+                    help="write each program's optimized HLO to a file here")
     args = ap.parse_args()
 
     from jax.experimental import topologies
@@ -242,7 +367,7 @@ def main() -> int:
                 params, pools, arr((B, Bk), i32), arr((B, Bk), jnp.bool_),
                 arr((B,), i32), arr((B, MP), i32), arr((B,), jnp.bool_),
                 arr((B,), i32)),
-            pool_bytes, layer_pool_bytes, no_pool)}
+            pool_bytes, layer_pool_bytes, no_pool, args.hlo_dir)}
     else:
         results = {"decode": report(
             f"decode [{B} rows x {MP} pages]",
@@ -250,7 +375,7 @@ def main() -> int:
                 params, pools, arr((B,), i32), arr((B,), i32),
                 arr((B, MP), i32), arr((B,), jnp.bool_),
                 arr((B,), jnp.float32), arr((B,), i32), key),
-            pool_bytes, layer_pool_bytes, no_pool)}
+            pool_bytes, layer_pool_bytes, no_pool, args.hlo_dir)}
     # a stack with a cross-decoder is handed the whole table row (one query
     # reads it through the decode kernel): one shape, and a program of its
     # own for the chunks that are not a prompt's last
@@ -276,14 +401,14 @@ def main() -> int:
             engine._prefill_chunk.lower(
                 params, pools, arr((C,), i32), arr((rows,), i32),
                 arr((w,), i32), arr((), i32), arr((), i32), *slot),
-            pool_bytes, layer_pool_bytes, no_pool)
+            pool_bytes, layer_pool_bytes, no_pool, args.hlo_dir)
         if engine._xdec:
             results[f"chunk{w}.part"] = report(
                 f"chunk, not a prompt's last [{C} tokens, window {w} pages]",
                 engine._prefill_chunk_part.lower(
                     params, pools, arr((C,), i32), arr((C // ps,), i32),
                     arr((w,), i32), arr((), i32), arr((), i32), *slot),
-                pool_bytes, layer_pool_bytes, no_pool)
+                pool_bytes, layer_pool_bytes, no_pool, args.hlo_dir)
     ok = all(r["temp"] < GIB and r["alias"] >= pool_bytes
              and not r["copied"] for r in results.values())
     print("aot_serve_step: " + (
