@@ -129,7 +129,6 @@ WALL_CLOCK_DIRS = (
     os.path.join("deepspeed_tpu", "inference"),
     os.path.join("deepspeed_tpu", "serving"),
     os.path.join("deepspeed_tpu", "resilience"),
-    os.path.join("deepspeed_tpu", "autotuning"),
     os.path.join("deepspeed_tpu", "elasticity"),
     os.path.join("deepspeed_tpu", "comm"),
 )

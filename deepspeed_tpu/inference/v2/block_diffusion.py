@@ -74,37 +74,32 @@ def block_policy(engine, proposer) -> Optional["BlockPolicy"]:
     if not 0 <= cfg.mask_token_id < cfg.vocab_size:
         raise ValueError(f"mask_token_id {cfg.mask_token_id} is not a row of "
                          f"the vocabulary of {cfg.vocab_size}")
-    if engine._state or engine._latent:
+    if "block_generation" in engine.unsupported:
         raise NotImplementedError(
-            f"{why}; the block program has a form of the 'attn' mixer over "
-            "K and V pages alone, not of recurrent state, rings or a latent")
-    if proposer is not None or config.speculative.mode != "off":
-        raise ValueError(
-            f"speculative decoding: {why} — a pass already scores a block of "
-            "positions at once, and a draft of next tokens has no meaning "
-            "under a mask that is bidirectional inside a block")
-    if config.decode_horizon > 1:
-        raise ValueError(
-            f"decode_horizon {config.decode_horizon}: {why}; which pass "
-            "commits, what is delivered and which page a block needs are "
-            "decided on the host between passes")
-    if config.kv_quant:
-        raise ValueError(
-            f"kv_quant: {why}; a block's K/V are rewritten in place pass by "
-            "pass and read back by its own queries, and int8 pages would put "
-            "every pass through the round-trip — serve it with kv_quant off")
-    if config.enable_prefix_cache:
-        raise ValueError(
-            f"enable_prefix_cache: {why}. A full page's K/V do depend only "
-            "on the tokens up to the page's end (a block never straddles a "
-            "page), so a cached page would be valid — but a fully cached "
-            "prompt enters through the one-token decode program, which this "
-            "model does not have; serve it with the prefix cache off")
-    if config.prefill_chunk <= 0:
-        raise ValueError(
-            f"prefill_chunk 0: {why}, and its prompts are prefilled through "
-            "the chunk program, which has the block mask; whole-prompt "
-            "prefill is causal; set prefill_chunk > 0")
+            f"{why}; {engine.unsupported['block_generation']}")
+    # what the configuration may not ask of such a model, refused by the
+    # engine's one routine in the model's words
+    engine._refuse_asked({
+        "speculation": f"{why} — a pass already scores a block of positions "
+                       "at once, and a draft of next tokens has no meaning "
+                       "under a mask that is bidirectional inside a block",
+        "decode_horizon": f"{why}; which pass commits, what is delivered and "
+                          "which page a block needs are decided on the host "
+                          "between passes",
+        "kv_quant": f"{why}; a block's K/V are rewritten in place pass by "
+                    "pass and read back by its own queries, and int8 pages "
+                    "would put every pass through the round-trip — serve it "
+                    "with kv_quant off",
+        "prefix_cache": f"{why}. A full page's K/V do depend only on the "
+                        "tokens up to the page's end (a block never straddles "
+                        "a page), so a cached page would be valid — but a "
+                        "fully cached prompt enters through the one-token "
+                        "decode program, which this model does not have; "
+                        "serve it with the prefix cache off",
+        "whole_prompt_prefill": f"{why}, and its prompts are prefilled "
+                                "through the chunk program, which has the "
+                                "block mask; whole-prompt prefill is causal; "
+                                "set prefill_chunk > 0"}, proposer)
     return BlockPolicy(engine)
 
 

@@ -17,9 +17,11 @@ logits to the host every step moves ~1MB where 32 bytes suffice.  Prefill
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import itertools
 import time
+import types
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -27,12 +29,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...compile.deep_frame import first_call_beneath
-from ...models.layer_types import (gqa_shape, layers_of, page_layers,
-                                   page_leaves, served_runs, state_leaves)
+from ...models.layer_types import (FEATURES, bundle_signature,
+                                   chunk_stops_early, gqa_shape, page_block,
+                                   page_layers, page_leaves, served_runs,
+                                   state_leaves, step_touches, unsupported)
 from ...models.transformer import TransformerConfig
 from ...moe.sharded_moe import MOE_COUNTERS
-from ...ops.pallas.mla_attention import latent_pages_per_block
-from ...ops.pallas.paged_attention import n_blocks, pages_per_block
+from ...ops.pallas.paged_attention import n_blocks
 from ...runtime.config_utils import ConfigModel
 from ...telemetry import get_registry
 from ...telemetry.compile_sentinel import (RecompileSentinel,
@@ -54,9 +57,9 @@ from .model_runner import (pad_pages_pow2, paged_copy_page, paged_decode,
                            paged_read_rows, paged_scatter_pages, paged_verify,
                            sample_tokens)
 from .packed_inputs import PackedProgram, pack_inputs
-from .ragged import (PRIORITY_NORMAL, BlockAllocator, EvaRows, KVBlockConfig,
+from .ragged import (PRIORITY_NORMAL, BlockAllocator, KVBlockConfig,
                      KVPageBundle, PagedKVCache, PrefixCache, RejectedError,
-                     SequenceState, StateSlots)
+                     SequenceState, StateSlots, page_rows)
 from .block_diffusion import block_policy
 from .speculative import (SpeculativeConfig, build_proposer, longest_accepted)
 
@@ -290,34 +293,13 @@ class InferenceEngineV2:
             self.cfg.moe_drop_tokens = False
         #: fixed-size per-sequence state some layer types keep beside pages
         self._state = state_leaves(self.cfg)
-        if self._state:
-            self._refuse_with_state(proposer)
-        #: the page format the layer types declare: K and V of ``kv_heads *
-        #: head_dim``, or one latent row a token (``mla``)
-        self._pages = page_leaves(self.cfg)
-        self._latent = "latent" in self._pages
-        if self._latent:
-            self._refuse_with_latent(proposer)
+        #: {feature: why} of what the stack's layer types cannot serve
+        self.unsupported = unsupported(self.cfg)
+        self._refuse_asked(self.unsupported, proposer)
         block = self.config.block
-        #: the page arithmetic of a stack of EVA-attention layers (an open
-        #: window's rows and a summary row a chunk in the same pages), None
-        #: for every other model
-        self._eva: Optional[EvaRows] = None
-        if layers_of(self.cfg, "eva"):
-            self._refuse_with_eva(proposer)
-            self._eva = EvaRows(self.cfg.eva_window, self.cfg.eva_chunk,
-                                block.page_size, block.max_seq_len)
-            if block.num_pages < self._eva.max_pages:
-                raise ValueError(
-                    f"num_pages ({block.num_pages}) < the {self._eva.max_pages}"
-                    f" pages a sequence of {block.max_seq_len} positions "
-                    "holds (its summaries and an open window): one sequence "
-                    "could never run to completion even with the whole pool")
-        elif block.num_pages < block.max_pages_per_seq:
-            raise ValueError(
-                f"num_pages ({block.num_pages}) < max_pages_per_seq "
-                f"({block.max_pages_per_seq}): one sequence could never run to "
-                "completion even with the whole pool")
+        #: how a sequence's pages grow over what the stack's layers cache
+        #: (``ragged.PageRows``, or the class the layer types declare)
+        self.rows = page_rows(self.cfg, block, self.config.prefill_chunk)
         if params is None:
             params = model.init_params(jax.random.PRNGKey(seed))
         # deferred: runtime.precision pulls runtime.config, which imports
@@ -347,14 +329,20 @@ class InferenceEngineV2:
             kv_quant=self.config.kv_quant, state=self._state,
             counters=({"moe_stats": len(MOE_COUNTERS)}
                       if self.cfg.moe_held_count else None),
-            pages=self._pages)
-        #: pages a block of the decode kernel holds — the paged kernel's, or
-        #: the latent kernel's over a latent pool — from the pool's own
-        #: geometry as that kernel takes it (``decode_kv_blocks``)
-        page_leaf = self._pools["latent" if self._latent else "k"]
-        self._kv_block_pages = (
-            latent_pages_per_block if self._latent else pages_per_block)(
-                block.page_size, page_leaf.shape[-1], page_leaf.dtype.itemsize)
+            pages=page_leaves(self.cfg))
+        #: pages a block of the decode kernel holds, from the pool's own
+        #: geometry as the kernel of the stack's page format takes it
+        #: (``decode_kv_blocks``)
+        leaf, block_pages = page_block(self.cfg)
+        page_leaf = self._pools[leaf]
+        self._kv_block_pages = block_pages(
+            block.page_size, page_leaf.shape[-1], page_leaf.dtype.itemsize)
+        #: what a step over the stack's caches counts, by when
+        #: (``layer_types.Touch``), and the geometry its counts read
+        self._touches = step_touches(self.cfg)
+        self._geometry = types.SimpleNamespace(
+            page_size=block.page_size, block_pages=self._kv_block_pages,
+            long_row_tokens=LONG_ROW_TOKENS)
         self.state_slots = StateSlots(block.max_seqs if self._state else 0)
         #: the expert share's counters as the device last reported them
         #: (``moe_stats`` wraps at 2**32; the host adds differences)
@@ -407,9 +395,9 @@ class InferenceEngineV2:
                         "spec_verify_calls": 0, "spec_rollback_pages": 0,
                         "spec_fallback_requests": 0,
                         **dict.fromkeys(MOE_COUNTERS, 0),
-                        "state_slot_preemptions": 0}
-        if self._latent:
-            self._dstats.update(latent_kv_tokens=0, latent_block_slots=0)
+                        "state_slot_preemptions": 0,
+                        **{c.name: 0 for at in self._touches.values()
+                           for c in at if c.cumulative}}
         self._init_serving_metrics()
         #: the one question asked of the model: does it generate by blocks
         #: (``block_diffusion.BlockPolicy``, which owns the decode phase of
@@ -425,10 +413,8 @@ class InferenceEngineV2:
         #: set by drain(): the engine is retiring, put() refuses admissions
         self._draining = False
         # host mirror of the device page tables, trash-filled
-        # (an 'eva' stack's row is [summary pages | open-window pages])
-        self._page_table = np.full(
-            (block.max_seqs, block.max_pages_per_seq if self._eva is None
-             else self._eva.table_pages), block.trash_page, dtype=np.int32)
+        self._page_table = np.full((block.max_seqs, self.rows.table_pages),
+                                   block.trash_page, dtype=np.int32)
 
         cfg = self.cfg
 
@@ -458,19 +444,13 @@ class InferenceEngineV2:
         self._prefill = PackedProgram(lambda *a: paged_prefill(cfg, *a))
         self._prefill_chunk = PackedProgram(
             lambda *a: paged_prefill_chunk(cfg, *a))
-        #: a stack with a cross-decoder (a ``dattn`` layer, whose pages the
-        #: layers after it read) runs that decoder for a prompt's last token
-        #: only: a chunk that is not the prompt's last stops at that layer's
-        #: K/V write, in a program of its own
-        self._xdec = layers_of(cfg, "dattn") > 0
+        #: a stack with a cross-decoder (whose pages the layers after it
+        #: read) runs that decoder for a prompt's last token only: a chunk
+        #: that is not the prompt's last stops at that layer's K/V write, in
+        #: a program of its own
+        self._chunk_stops_early = chunk_stops_early(cfg)
         self._prefill_chunk_part = PackedProgram(
             lambda *a: paged_prefill_chunk(cfg, *a, final=False))
-        #: what a window layer's ring holds of a context, at most
-        self._window = cfg.sliding_window if layers_of(cfg, "swa") else 0
-        #: a stack of full layers with pages and window layers with rings
-        #: whose shapes follow the type: its step counts what each reads
-        self._hybrid = layers_of(cfg, "gqa_full") > 0
-        self._hybrid_window = gqa_shape(cfg, "gqa_window").window
         self._copy_page = jax.jit(paged_copy_page, donate_argnums=(0,))
         ps = self.block.page_size
         self._chunk = (-(-self.config.prefill_chunk // ps) * ps
@@ -575,113 +555,31 @@ class InferenceEngineV2:
         setup_span("serve_engine_init", t_init)
         publish_setup_seconds()
 
-    def _refuse_with_state(self, proposer: Any) -> None:
-        """What a model with recurrent state cannot be served with: each
-        would answer wrongly (a cached or exported page says nothing of
-        the state that goes with it; a rejected draft cannot be rolled out
-        of a state), so each is refused here, by name."""
-        kinds = sorted(self._state)
-        if self.config.enable_prefix_cache:
-            raise ValueError(
-                f"enable_prefix_cache: this model keeps recurrent state or "
-                f"a window's ring in its slots ({kinds}) and a cached page "
-                "carries none of it; serve it with the prefix cache off")
-        if self.config.prefill_chunk <= 0:
-            raise ValueError(
-                f"prefill_chunk 0: a model with recurrent state or a "
-                f"window's ring ({kinds}) is prefilled through the chunk "
-                "program, which carries both from chunk to chunk; set "
-                "prefill_chunk > 0")
-        if proposer is not None or self.config.speculative.mode != "off":
-            raise ValueError(
-                f"speculative decoding: paged_verify cannot roll a rejected "
-                f"draft out of recurrent state or a window's ring ({kinds})")
-        if self.config.kv_quant and "win_k" in self._state:
-            raise ValueError(
-                "kv_quant: the window layers read keys and values from a "
-                f"window's ring ({kinds}) as they are stored (the "
-                "differential layers in pairs of heads, a key wider than "
-                "its value split); serve it with kv_quant off")
-        if self.cfg.sliding_window % self.config.block.page_size and \
-                "win_k" in self._state:
-            raise ValueError(
-                f"sliding_window {self.cfg.sliding_window} is not a whole "
-                f"number of pages of {self.config.block.page_size}: the "
-                "decode kernel reads a window's ring as pages")
-
-    def _refuse_with_eva(self, proposer: Any) -> None:
-        """What a stack of EVA-attention layers cannot be served with, each
-        by name: every one of them would answer wrongly over a cache whose
-        rows are pooled chunks and a window that empties."""
-        cfg, conf = self.cfg, self.config
-        W, C, ps = cfg.eva_window, cfg.eva_chunk, conf.block.page_size
-        if layers_of(cfg, "eva") != cfg.n_layers:
-            raise NotImplementedError(
-                "a stack that mixes 'eva' layers with others: the page "
-                "accounting of a sequence (ragged.EvaRows) is one for all of "
-                "its layers")
-        if conf.enable_prefix_cache:
-            raise ValueError(
-                "enable_prefix_cache: an 'eva' layer's pages hold a window's "
-                "rows that are given back when it closes and summaries that "
-                "are visible only past their window; a cached page keyed by "
-                "its tokens says neither; serve it with the prefix cache off")
-        if proposer is not None or conf.speculative.mode != "off":
-            raise ValueError(
-                "speculative decoding: paged_verify cannot roll a rejected "
-                "draft out of a pooled chunk or a closed window ('eva')")
-        if conf.kv_quant:
-            raise ValueError(
-                "kv_quant: an 'eva' layer pools a chunk's keys and values "
-                "from the rows as they are stored; int8 codes have no form "
-                "of it; serve it with kv_quant off")
-        if conf.decode_horizon > 1:
-            raise ValueError(
-                f"decode_horizon {conf.decode_horizon}: a row's open pages "
-                "go back to the allocator when its window closes, between "
-                "steps; the fused scan reserves pages by position; serve an "
-                "'eva' stack with decode_horizon 1")
-        chunk = conf.prefill_chunk
-        if chunk <= 0 or W % chunk or chunk % (ps * C):
-            raise ValueError(
-                f"prefill_chunk {chunk}: an 'eva' stack is prefilled through "
-                f"the chunk program in chunks that tile eva_window {W} (a "
-                "chunk never straddles a window) and are whole pages of "
-                f"summaries ({ps} x eva_chunk {C} = {ps * C} positions)")
-
-    def _refuse_with_latent(self, proposer: Any) -> None:
-        """What a model that caches a latent cannot be served with, by name.
-        The prefix cache, copy-on-write and bundle export work over latent
-        pages as over any page (a cached page holds its positions' latents
-        and rotated keys, valid for every request that shares the prefix)."""
-        if self.config.prefill_chunk <= 0:
-            raise ValueError(
-                "prefill_chunk 0: a latent-attention model is prefilled "
-                "through the chunk program, which expands keys and values "
-                "from the window's latents; whole-prompt prefill has no form "
-                "of the 'mla' mixer; set prefill_chunk > 0")
-        if proposer is not None or self.config.speculative.mode != "off":
-            raise ValueError(
-                "speculative decoding: paged_verify has no form of the "
-                "'mla' mixer (a window of queries against latent pages)")
-        if self.config.kv_quant:
-            raise ValueError(
-                "kv_quant: int8 codes and per-head scales exist for K and V "
-                "pools; a latent pool has no heads to scale, serve it with "
-                "kv_quant off")
-        tier = self.config.kv_tier
-        if tier is not None and (tier.get("enabled") if isinstance(tier, dict)
-                                 else tier.enabled):
-            raise ValueError(
-                "kv_tier: the host tier's page format is K and V; a latent "
-                "pool is not spilled")
-
-    def _model_sig(self) -> Tuple[int, int, int]:
-        """(layers, heads, width) of a page as a bundle carries it."""
-        if self._latent:
-            layers, width = self._pages["latent"]
-            return (layers, 1, width)
-        return (self.cfg.n_layers, self.cfg.kv_heads, self.cfg.head_dim)
+    def _refuse_asked(self, unsupported: Dict[str, str], proposer: Any
+                      ) -> None:
+        """What this configuration asks for that ``unsupported`` (``{feature:
+        why}``: what the stack's layer types declare, ``layer_types
+        .unsupported``, or a policy of the step) cannot serve: each would
+        answer wrongly, so the first is refused here, by name — how it was
+        asked for, then the reason."""
+        conf, tier = self.config, self.config.kv_tier
+        asked = {
+            "prefix_cache": conf.enable_prefix_cache and "enable_prefix_cache",
+            "whole_prompt_prefill": conf.prefill_chunk <= 0
+            and "prefill_chunk 0",
+            "speculation": (proposer is not None
+                            or conf.speculative.mode != "off")
+            and "speculative decoding",
+            "kv_quant": conf.kv_quant and "kv_quant",
+            "kv_tier": tier is not None and (
+                tier.get("enabled") if isinstance(tier, dict)
+                else tier.enabled) and "kv_tier",
+            "decode_horizon": conf.decode_horizon > 1
+            and f"decode_horizon {conf.decode_horizon}",
+        }
+        for feature in FEATURES:
+            if asked.get(feature) and feature in unsupported:
+                raise ValueError(f"{asked[feature]}: {unsupported[feature]}")
 
     def _wire_memory_ledger(self) -> None:
         """Attach the serving engine's HBM residents to the process
@@ -1208,22 +1106,22 @@ class InferenceEngineV2:
         n, ps = seq.length - 1, self.block.page_size
         sentinel_expect_recompile("read_kv")
         pages = paged_gather_pages(self._pools, seq.pages[:-(-n // ps)], 1)
-        out, seen = [], {"gqa_full": 0, "gqa_window": 0}
+        out, seen = [], collections.Counter()
         for types, reps in served_runs(self.cfg):
             for _ in range(reps):
                 for t in types:
                     sh, l = gqa_shape(self.cfg, t.mixer), seen[t.mixer]
                     seen[t.mixer] += 1
-                    if sh.window:
+                    if sh.window:  # the type's ring leaves, keys then values
                         m = min(n, sh.window)
                         rows = (np.arange(n - m, n)) % sh.window
                         # dstpu-lint: allow[host-sync] a checking aid
                         k, v = (np.asarray(self._pools[nm][l, seq.slot])[rows]
-                                for nm in ("win_k", "win_v"))
-                    else:
+                                for nm in t.state(self.cfg))
+                    else:  # its page leaves
                         m = n
                         k, v = (pages[nm][l].reshape(-1, pages[nm].shape[-1])
-                                [:n] for nm in ("k", "v"))
+                                [:n] for nm in t.pages(self.cfg))
                     out.append({
                         "first": n - m,
                         "k": np.asarray(merged_keys(
@@ -1243,8 +1141,8 @@ class InferenceEngineV2:
         n, ps = seq.length - 1, self.block.page_size
         # the gather runs op-by-op outside the step programs, as an export's
         sentinel_expect_recompile("read_latent")
-        rows = paged_gather_pages(self._pools, seq.pages[:-(-n // ps)],
-                                  self.cfg.kv_heads)["latent"]
+        (rows,) = paged_gather_pages(self._pools, seq.pages[:-(-n // ps)],
+                                     self.cfg.kv_heads).values()
 
         width = self.cfg.kv_lora_rank + self.cfg.qk_rope_head_dim
         return rows.reshape(rows.shape[0], -1, rows.shape[-1])[:, :n, :width]
@@ -1256,15 +1154,16 @@ class InferenceEngineV2:
         window's rows in position order — a host copy, for a check against a
         reference.  After ``m`` returned tokens the cache holds the prompt
         and the first ``m - 1`` of them."""
-        seq, ev = self._find_slotted(uid), self._eva
+        seq, ev = self._find_slotted(uid), self.rows
         n, ps = seq.length - 1, self.block.page_size
         n_vis, n_open = ev.visible(n), n % ev.window
         sentinel_expect_recompile("read_eva")
         pages = seq.pages[:n_vis // ps] \
             + seq.pages[seq.n_sum:seq.n_sum + -(-n_open // ps)]
-        got = paged_read_rows(self._pools, ("k", "v"), pages,
+        leaves = tuple(page_leaves(self.cfg))  # keys, then values
+        got = paged_read_rows(self._pools, leaves, pages,
                               self.block.trash_page)
-        return np.concatenate([got["k"], got["v"]],
+        return np.concatenate([got[nm] for nm in leaves],
                               axis=-1)[:, :n_vis + n_open]
 
     def export_sequence(self, uid: int) -> KVPageBundle:
@@ -1272,15 +1171,9 @@ class InferenceEngineV2:
         into a :class:`KVPageBundle` (host arrays, bit-exact).  The
         sequence KEEPS running here — callers release it only after a
         successful import elsewhere, so a failed handoff loses nothing."""
-        if self._state:
-            raise NotImplementedError(
-                "KVPageBundle export: a bundle holds pages, and this model "
-                f"keeps recurrent state too ({sorted(self._state)})")
-        if self._eva is not None:
-            raise NotImplementedError(
-                "KVPageBundle export: a bundle's pages are a page a "
-                "page_size positions, and an 'eva' stack's are summaries and "
-                "an open window (ragged.EvaRows)")
+        if "bundle_export" in self.unsupported:
+            raise NotImplementedError("KVPageBundle export: "
+                                      + self.unsupported["bundle_export"])
         seq = self._find_slotted(uid)
         if self.blocks is not None:
             self.blocks.refuse_export()
@@ -1296,7 +1189,7 @@ class InferenceEngineV2:
             src_pages=self.allocator.export_meta(seq.pages),
             arrays=paged_gather_pages(self._pools, seq.pages,
                                       self.cfg.kv_heads),
-            model_sig=self._model_sig(),
+            model_sig=bundle_signature(self.cfg),
             kv_quant=bool(self.config.kv_quant), dtype=self.config.dtype)
         tr = self._reqtrace(seq)
         if tr is not None:
@@ -1321,11 +1214,10 @@ class InferenceEngineV2:
         return bundle
 
     def _check_bundle(self, b: KVPageBundle) -> None:
-        if self._state:
-            raise ValueError(
-                "KVPageBundle import: a bundle holds pages, and this model "
-                f"keeps recurrent state too ({sorted(self._state)})")
-        sig = self._model_sig()
+        if "bundle_import" in self.unsupported:
+            raise ValueError("KVPageBundle import: "
+                             + self.unsupported["bundle_import"])
+        sig = bundle_signature(self.cfg)
         if tuple(b.model_sig) != sig:
             raise ValueError(f"bundle model_sig {tuple(b.model_sig)} != "
                              f"engine {sig}")
@@ -1362,10 +1254,6 @@ class InferenceEngineV2:
         not enough pages are free (the caller tries another replica);
         raises ``ValueError`` on genuine incompatibility (different
         model geometry / page size / kv_quant / dtype)."""
-        if self._eva is not None:
-            raise NotImplementedError(
-                "KVPageBundle import: an 'eva' stack's pages are summaries "
-                "and an open window, not a page a page_size positions")
         self._check_bundle(bundle)
         slot = next((i for i, s in enumerate(self._slots) if s is None), None)
         if slot is None:
@@ -1809,12 +1697,8 @@ class InferenceEngineV2:
                 # like the claimed matches above.
                 shared, keys, _restored = self._tier_restore(
                     seq.tokens, shared, keys)
-            n_total = -(-seq.length // ps)
-            if self._eva is not None:
-                # summary pages for the prompt's whole chunks and the open
-                # pages its prefill writes
-                eva_pages = self._eva_prefill_pages(seq.length)
-                n_total = sum(eva_pages)
+            n_sum, n_rest = self.rows.admit_pages(seq.length)
+            n_total = n_sum + n_rest
             m = len(shared)
             # fully-cached prompt (page-aligned): the last cached page is
             # replaced by a private COPY-ON-WRITE duplicate — the decode
@@ -1890,12 +1774,9 @@ class InferenceEngineV2:
             self._m_computed.inc(seq.length - seq.prefilled)
             seq.slot = i
             seq.admit_order = next(self._admit_counter)
-            self._page_table[i, :] = self.block.trash_page
-            if self._eva is None:
-                self._page_table[i, :len(seq.pages)] = seq.pages
-            else:
-                seq.n_sum = eva_pages[0]
-                self._eva_write_table(seq)
+            seq.n_sum = n_sum
+            self.rows.write_table(seq, self._page_table[i],
+                                  self.block.trash_page)
             tr = self._reqtrace(seq)
             if tr is not None:
                 # queue_wait closes here; "prefill" self-classifies as
@@ -2091,30 +1972,12 @@ class InferenceEngineV2:
         [start, start+c_n) in a C-token program (C a page multiple) —
         shared by chunked prefill and the cached-prefix suffix path.
         Returns the logits of token start+c_n-1."""
-        ps = self.block.page_size
         ids = np.zeros((C,), np.int32)
         ids[:c_n] = seq.tokens[start:start + c_n]
-        if self._eva is not None:
-            return self._run_eva_chunk(seq, ids, start, c_n, C)
-        rows = np.full((C // ps,), self.block.trash_page, np.int32)
-        npg = -(-c_n // ps)
-        rows[:npg] = seq.pages[start // ps:start // ps + npg]
-        # bucket the window THROUGH this chunk (power-of-two
-        # page counts): early chunks of a long prompt must not
-        # gather the full max window, and the kernel path needs
-        # the chunk's own pages in the table (pool-slot index ==
-        # global position); few shapes -> few compiles
-        used = -(-(start + c_n) // ps)
-        b = 1
-        while b < max(used, 1):
-            b *= 2
-        if self._xdec:
-            # one query reads the pages, through the decode kernel, which
-            # walks the pages a row has: the whole row, one shape
-            b = self.block.max_pages_per_seq
-        prev = self._page_table[seq.slot][:min(
-            b, self.block.max_pages_per_seq)]
-        final = not self._xdec or start + c_n >= seq.length
+        rows, prev = self.rows.chunk_tables(
+            seq, self._page_table[seq.slot], start, c_n, C,
+            self.block.trash_page)
+        final = not self._chunk_stops_early or start + c_n >= seq.length
         part = (("prefill_chunk", C, int(prev.shape[0]))
                 + (() if final else ("part",)))
         inputs = (ids, rows, prev, np.int32(start), np.int32(c_n))
@@ -2125,6 +1988,7 @@ class InferenceEngineV2:
                                              phase="prefill")
         seq.prefilled = start + c_n
         self._register_pages(seq)
+        self._give_back(seq, "prefill")
         return logits
 
     # -- the engine step -----------------------------------------------------
@@ -2167,20 +2031,12 @@ class InferenceEngineV2:
                 counts["queue_len"] = len(self._queue)
                 if self._state:
                     counts["state_slots_in_use"] = self.state_slots.in_use
-                if self._eva is not None:
-                    held = [self._eva.rows_held(s.prefilled)
-                            for s in self._slots if s is not None]
-                    counts["eva_summary_rows_in_use"] = sum(
-                        h[0] for h in held)
-                    counts["eva_window_rows_in_use"] = sum(h[1] for h in held)
-                    counts["eva_rows_in_use"] = sum(map(sum, held))
-                    counts["eva_pages_in_use"] = sum(
-                        len(s.pages) for s in self._slots if s is not None)
-                if self._latent or self._hybrid:
-                    held = self.block.page_size * sum(
-                        len(s.pages) for s in self._slots if s is not None)
-                    counts["latent_tokens_in_use" if self._latent
-                           else "page_tokens_in_use"] = held
+                if self._touches["held"]:
+                    # what the sequences in slots have cached and hold
+                    counts.update(self._touched("held", np.array(
+                        [[s.prefilled, len(s.pages)]
+                         for s in self._slots if s is not None],
+                        np.int64).reshape(-1, 2).T))
                 step_attrs.update(counts)
         except Exception as e:
             dump_on_exception("engine_v2.step", e)
@@ -2242,21 +2098,13 @@ class InferenceEngineV2:
                 c_n = min(self._chunk, end - start)
                 counts["chunks"] += 1
                 counts["prefill_tokens"] += c_n
-                attrs = {}
-                # the cached positions the chunk attends
-                if self._latent or self._hybrid:
-                    attrs["ctx_tokens"] = start
-                if self._eva is not None:  # summaries and open rows
-                    attrs["ctx_tokens"] = self._eva.visible(start) \
-                        + start % self._eva.window
-                if self._xdec:  # the cross-decoder runs for the last token
-                    attrs["xdec_rows"] = int(start + c_n >= seq.length)
-                    counts["xdec_rows"] = (counts.get("xdec_rows", 0)
-                                           + attrs["xdec_rows"])
-                    # that one row reads the pages of the whole prompt
-                    counts["shared_kv_pages"] = (
-                        counts.get("shared_kv_pages", 0)
-                        + attrs["xdec_rows"] * -(-(start + c_n) // ps))
+                # the cached rows the chunk attends and, where it is the
+                # prompt's last, what its one decoding row reads
+                last = start + c_n if start + c_n >= seq.length else 0
+                attrs = {**self._touched("chunk", np.array(
+                             [self.rows.context(start)])),
+                         **self._touched("last_chunk", np.array([last]),
+                                         note=True)}
                 with self._phase("prefill", self._m_prefill_h, uid=seq.uid,
                                  start=start, tokens=c_n, **attrs):
                     logits = self._run_prefill_chunk(seq, start, c_n,
@@ -2354,11 +2202,10 @@ class InferenceEngineV2:
             last, pos, act, temps, sids = self._decode_inputs(decode_seqs)
             self._decode_steps += 1
             counts["decode_rows"] += len(decode_seqs)
-            lengths = np.where(act, pos + 1, 0)
-            if self._eva is not None:  # what a row reads, not where it is
-                lengths = np.where(act, self._eva.rows_attended(pos), 0)
+            # what a row reads, not where it is
+            lengths = np.where(act, self.rows.rows_attended(pos), 0)
             self._note_kv_blocks(lengths)
-            self._note_state_rows(lengths)
+            self._touched("decode", lengths, note=True)
             with self._phase("decode", self._m_decode_h,
                              batch=len(decode_seqs)):
                 tokens, self._pools = self._dispatch(
@@ -2402,10 +2249,7 @@ class InferenceEngineV2:
                     self._note_tokens(seq)
                     # the decode step wrote KV for the token it consumed
                     seq.prefilled = seq.length - 1
-                    if (self._eva is not None
-                            and seq.prefilled % self._eva.window == 0):
-                        # the row's window closed inside the decode program
-                        self._eva_give_back(seq, 0, "decode")
+                    self._give_back(seq, "decode")
                     if (self.prefix_cache is not None
                             and seq.prefilled % ps == 0):
                         # the decode write completed a page: publish it so
@@ -2433,15 +2277,7 @@ class InferenceEngineV2:
         scheduler holds requests back under KV pressure rather than
         failing); ``seq`` itself may be the one preempted (``seq.slot`` is
         then -1)."""
-        if self._eva is not None:
-            # a page for the row at ``pos`` where it opens one, and a summary
-            # page where the chunk ``pos`` ends opens one
-            ev = self._eva
-            need_sum = ev.summary_pages(pos + 1) - seq.n_sum
-            need = need_sum + (pos % ev.window) // ev.page_size + 1 \
-                - (len(seq.pages) - seq.n_sum)
-        else:
-            need = int(pos // self.block.page_size == len(seq.pages))
+        need = self.rows.needs(seq, pos)
         if need <= 0:
             return
         while self.allocator.free_pages < need:
@@ -2462,96 +2298,27 @@ class InferenceEngineV2:
             self._preempt(victim)
             if victim is seq:
                 return
-        if self._eva is not None:
-            fresh = self.allocator.alloc(need)
-            seq.pages[seq.n_sum:seq.n_sum] = fresh[:need_sum]
-            seq.n_sum += need_sum
-            seq.pages += fresh[need_sum:]
-            self._eva_write_table(seq)
-            return
-        page = self.allocator.alloc(1)[0]
-        seq.pages.append(page)
-        self._page_table[seq.slot, len(seq.pages) - 1] = page
+        self.rows.take(seq, pos, self.allocator.alloc(need),
+                       self._page_table[seq.slot], self.block.trash_page)
 
-    # -- a stack of EVA-attention layers (ragged.EvaRows) ---------------------
-    def _eva_prefill_pages(self, length: int) -> Tuple[int, int]:
-        """(summary pages, open pages) a sequence of ``length`` positions is
-        admitted with: the summaries of its whole chunks, and the open pages
-        its chunks write — its last window's, or where a chunk is shorter
-        than a window and the prompt is not, a whole window's, which the
-        chunks of every window reuse and the last chunk trims."""
-        ev = self._eva
-        whole = length >= ev.window and self._chunk < ev.window
-        return (ev.summary_pages(length),
-                ev.open_cap if whole else ev.open_pages(length))
-
-    def _eva_write_table(self, seq: SequenceState) -> None:
-        """The host's table row of ``seq``: ``[summary pages | open pages]``,
-        trash elsewhere."""
-        row, opened = self._page_table[seq.slot], seq.pages[seq.n_sum:]
-        row[:] = self.block.trash_page
-        row[:seq.n_sum] = seq.pages[:seq.n_sum]
-        row[self._eva.sum_cap:self._eva.sum_cap + len(opened)] = opened
-
-    def _eva_give_back(self, seq: SequenceState, keep: int, where: str
-                       ) -> None:
-        """A window of ``seq`` has closed (or its prefill has ended): its
-        open pages but the first ``keep`` go back to the allocator."""
-        drop = seq.pages[seq.n_sum + keep:]
+    def _give_back(self, seq: SequenceState, where: str) -> None:
+        """After a chunk (``where`` = ``prefill``) or a decode step wrote
+        ``seq``'s rows: the pages it no longer needs go back to the allocator
+        (``rows.give_back``: an open window's when it closes), and a window
+        that closed leaves its count on the step and an event."""
+        drop, closed = self.rows.give_back(seq, where == "prefill")
         if drop:
             self.allocator.free(drop)
-            del seq.pages[seq.n_sum + keep:]
-            self._eva_write_table(seq)
-        if seq.prefilled % self._eva.window == 0:
-            self._step_counts["eva_windows_closed"] = \
-                self._step_counts.get("eva_windows_closed", 0) + 1
-            record_event("eva_window_closed", cat="serve",
-                         step=self._step_id, uid=seq.uid, where=where,
-                         windows_closed=seq.prefilled // self._eva.window,
+            self.rows.write_table(seq, self._page_table[seq.slot],
+                                  self.block.trash_page)
+        if closed:
+            count, event = self.rows.CLOSED
+            self._step_counts[count] = self._step_counts.get(count, 0) + 1
+            record_event(event, cat="serve", step=self._step_id, uid=seq.uid,
+                         where=where, windows_closed=closed,
                          pages_freed=len(drop),
                          **({} if seq.trace_id is None
                             else {"trace_id": seq.trace_id}))
-
-    def _run_eva_chunk(self, seq: SequenceState, ids: np.ndarray, start: int,
-                       c_n: int, C: int):
-        """``_run_prefill_chunk`` for a stack of EVA-attention layers: the
-        chunk's rows go to the open pages from ``(start % window) / ps`` on
-        (the trash page where the chunk closes its window: nothing reads them
-        again), its chunks' summaries to the summary pages from ``start / (ps
-        chunk)`` on, and it attends ``[the closed windows' summary pages | the
-        open window's earlier pages]`` right-aligned behind trash pages in a
-        table bucketed to a power of two."""
-        ev, ps, trash = self._eva, self.block.page_size, self.block.trash_page
-        if start % C:
-            raise RuntimeError(f"an 'eva' chunk starts at {start}, not at a "
-                               f"multiple of prefill_chunk {C}")
-        opened = seq.pages[seq.n_sum:]
-        closes = (start + c_n) % ev.window == 0
-        first = (start % ev.window) // ps
-        rows = np.full((C // ps + C // (ps * ev.chunk),), trash, np.int32)
-        if not closes:
-            take = opened[first:first + C // ps]
-            rows[:len(take)] = take
-        take = seq.pages[:seq.n_sum][start // (ps * ev.chunk):][
-            :C // (ps * ev.chunk)]
-        rows[C // ps:C // ps + len(take)] = take
-        before = seq.pages[:ev.visible(start) // ps] + opened[:first]
-        b = max(1, ev.open_cap // 4)
-        while b < len(before):
-            b *= 2
-        prev = np.full((b,), trash, np.int32)
-        if before:
-            prev[b - len(before):] = before
-        logits, self._pools = self._dispatch(
-            ("prefill_chunk", C, b), self._prefill_chunk,
-            (ids, rows, prev, np.int32(start), np.int32(c_n)),
-            phase="prefill")
-        seq.prefilled = start + c_n
-        if seq.prefilled >= seq.length:  # the last chunk: trim to the window
-            self._eva_give_back(seq, ev.open_pages(seq.prefilled), "prefill")
-        elif closes:  # the pages are the next window's chunks' too
-            self._eva_give_back(seq, len(opened), "prefill")
-        return logits
 
     def _pull(self, *arrays) -> List[np.ndarray]:
         """Host copies of a decode call's results.  With an expert share
@@ -2590,53 +2357,19 @@ class InferenceEngineV2:
         self._dstats["decode_kv_blocks"] += n
         self._m_kv_blocks.inc(n)
 
-    def _note_state_rows(self, lengths: np.ndarray) -> None:
-        """What the decode program's state-space, window and cross-decoder
-        (and, for a stack of full and window layers by type, its attention)
-        layers touch in one layer call over rows of ``lengths`` visible
-        tokens (0 = the row is not active), from the host's own book: the
-        rows whose state the step kernel moves, the cached positions the
-        window decode reads (a ring holds ``sliding_window`` at most), the
-        visible pages of the one pool layer (once, however many layers read
-        them), the rows the cross-decoder runs, and the cached positions a
-        latent-attention layer's decode kernel reads beside the positions of
-        the blocks it walks for them (``latent_block_slots``: the kernel's
-        own ``n_blocks`` times its block)."""
-        counts, rows = self._step_counts, int((lengths > 0).sum())
-        if self._eva is not None:  # once, not a layer
-            counts["eva_rows_attended"] = counts.get("eva_rows_attended", 0) \
-                + int(lengths.sum())
-        if self._latent:  # once, not a layer: the kernel's bytes are x layers
-            # ... and the positions of the blocks the kernel walks for them,
-            # the masked ones of a row's last block included
-            block = self._kv_block_pages * self.block.page_size
-            for name, n in (
-                    ("latent_kv_tokens", int(lengths.sum())),
-                    ("latent_block_slots", block * int(n_blocks(
-                        lengths, self.block.page_size,
-                        self._kv_block_pages).sum()))):
-                counts[name] = counts.get(name, 0) + n
-                self._dstats[name] += n
-        if self._hybrid:
-            # the positions the full layers' kernel reads (once, not a
-            # layer), those the rings hold, and the rows with a long context
-            window = self._hybrid_window
-            counts["full_kv_tokens"] = counts.get("full_kv_tokens", 0) \
-                + int(lengths.sum())
-            counts["window_kv_tokens"] = counts.get("window_kv_tokens", 0) \
-                + int(np.minimum(lengths, window).sum())
-            counts["long_rows"] = counts.get("long_rows", 0) \
-                + int((lengths > LONG_ROW_TOKENS).sum())
-        if "ssm_s" in self._state:
-            counts["ssm_rows"] = counts.get("ssm_rows", 0) + rows
-        if self._window:
-            counts["window_tokens"] = counts.get("window_tokens", 0) + int(
-                np.minimum(lengths, self._window).sum())
-        if self._xdec:
-            ps = self.block.page_size
-            counts["shared_kv_pages"] = counts.get("shared_kv_pages", 0) \
-                + int((-(-lengths // ps)).sum())
-            counts["xdec_rows"] = counts.get("xdec_rows", 0) + rows
+    def _touched(self, at: str, n: np.ndarray, note: bool = False
+                 ) -> Dict[str, int]:
+        """What the stack's layer types declare a step counts ``at`` that
+        moment (``layer_types.Touch``) over ``n`` — what each row reads or
+        holds, from the host's own book; ``note``: added to the step's counts
+        (and to ``decode_stats()`` where the count is cumulative)."""
+        got = {c.name: c.count(n, self._geometry) for c in self._touches[at]}
+        for c in self._touches[at] if note else ():
+            self._step_counts[c.name] = \
+                self._step_counts.get(c.name, 0) + got[c.name]
+            if c.cumulative:
+                self._dstats[c.name] += got[c.name]
+        return got
 
     def _decode_inputs(self, seqs: List[SequenceState]):
         """Dense ``[max_seqs]`` dispatch arrays for a decode-phase

@@ -28,6 +28,7 @@ from collections import OrderedDict
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
+import numpy as np
 
 #: request priority classes (smaller = more urgent).  Priorities order
 #: admission (the scheduler admits the highest class first), choose
@@ -626,6 +627,100 @@ class PagedKVCache:
 
 
 @dataclasses.dataclass(frozen=True)
+class PageRows:
+    """How a sequence's pages grow, the plain case: position ``t`` is row ``t
+    % page_size`` of page ``t // page_size``, and the host's table row is the
+    sequence's pages in order.
+
+    The engine always has one such object (``InferenceEngineV2.rows``, built
+    by ``page_rows`` from what the stack's layer types declare) — this class
+    or ``EvaRows``, two that share no logic under the same methods.  The
+    object says how many pages and where; the engine allocates, frees and
+    preempts.  ``admit_pages(length)``: ``(pages in front of SequenceState
+    .n_sum, the others)`` a prompt is admitted with; ``needs`` / ``take``: the
+    fresh pages position ``pos`` needs beyond what ``seq`` holds, and where
+    they go in ``seq.pages`` and the host's table ``row``; ``chunk_tables``:
+    the pages a chunk call writes and the table it attends, bucketed to few
+    shapes; ``context`` / ``rows_attended``: the cached rows a chunk that
+    starts at ``start``, and a decode query at ``pos``, read; ``give_back``:
+    after a chunk or a decode step, the pages ``seq`` no longer needs (cut
+    out of ``seq.pages``) and, where a window has just closed, how many
+    have."""
+    page_size: int
+    max_pages_per_seq: int
+    #: the pool's pages (0: not given — the arithmetic alone)
+    num_pages: int = 0
+    #: positions of the window layers' rings in a sequence's slot (0: none)
+    ring: int = 0
+    #: a chunk attends the whole table row, one shape (a cross-decoder's one
+    #: query reads it through the decode kernel, which walks a row's pages)
+    whole_row: bool = False
+    CLOSED = None  # (no window ever closes)
+
+    def __post_init__(self):
+        if self.ring % self.page_size:
+            raise ValueError(
+                f"sliding_window {self.ring} is not a whole number of pages "
+                f"of {self.page_size}: the decode kernel reads a window's "
+                "ring as pages")
+        if 0 < self.num_pages < self.max_pages_per_seq:
+            raise ValueError(
+                f"num_pages ({self.num_pages}) < max_pages_per_seq "
+                f"({self.max_pages_per_seq}): one sequence could never run "
+                "to completion even with the whole pool")
+
+    @property
+    def table_pages(self) -> int:
+        return self.max_pages_per_seq
+
+    @property
+    def max_pages(self) -> int:
+        return self.max_pages_per_seq
+
+    def admit_pages(self, length: int) -> Tuple[int, int]:
+        return 0, -(-length // self.page_size)
+
+    def needs(self, seq: "SequenceState", pos: int) -> int:
+        return int(pos // self.page_size == len(seq.pages))
+
+    def take(self, seq: "SequenceState", pos: int, fresh: List[int], row,
+             trash: int) -> None:
+        row[len(seq.pages):len(seq.pages) + len(fresh)] = fresh
+        seq.pages += fresh
+
+    def write_table(self, seq: "SequenceState", row, trash: int) -> None:
+        row[:] = trash
+        row[:len(seq.pages)] = seq.pages
+
+    def chunk_tables(self, seq: "SequenceState", row, start: int, c_n: int,
+                     C: int, trash: int):
+        ps = self.page_size
+        rows = np.full((C // ps,), trash, np.int32)
+        npg = -(-c_n // ps)
+        rows[:npg] = seq.pages[start // ps:start // ps + npg]
+        # bucket the window THROUGH this chunk (power-of-two page counts):
+        # early chunks of a long prompt must not gather the full max window,
+        # and the kernel path needs the chunk's own pages in the table
+        # (pool-slot index == global position); few shapes -> few compiles
+        b = 1
+        while b < -(-(start + c_n) // ps):
+            b *= 2
+        if self.whole_row:
+            b = self.max_pages_per_seq
+        return rows, row[:min(b, self.max_pages_per_seq)]
+
+    def context(self, start: int) -> int:
+        return start
+
+    def rows_attended(self, pos):
+        return pos + 1
+
+    def give_back(self, seq: "SequenceState", after_chunk: bool
+                  ) -> Tuple[List[int], int]:
+        return [], 0
+
+
+@dataclasses.dataclass(frozen=True)
 class EvaRows:
     """The page arithmetic of a sequence whose layers cache as EVA attention
     does (``models/layer_types.EVA``): of ``n`` cached positions, the open
@@ -650,6 +745,12 @@ class EvaRows:
     page_size: int
     #: the longest sequence, in positions (``KVBlockConfig.max_seq_len``)
     max_positions: int
+    #: the pool's pages and the positions of a prefill chunk (0: not given —
+    #: the arithmetic of positions alone)
+    num_pages: int = 0
+    prefill_chunk: int = 0
+    #: what a window that closes leaves on a step: its count and its event
+    CLOSED = ("eva_windows_closed", "eva_window_closed")
 
     def __post_init__(self):
         W, C, ps = self.window, self.chunk, self.page_size
@@ -663,6 +764,19 @@ class EvaRows:
                 f"page_size {ps} does not tile eva_window {W} and its "
                 f"{W // C} summaries: the open window and a closed window's "
                 "summaries are whole pages of the composed table")
+        chunk = self.prefill_chunk
+        if chunk and (W % chunk or chunk % (ps * C)):
+            raise ValueError(
+                f"prefill_chunk {chunk}: an 'eva' stack is prefilled through "
+                f"the chunk program in chunks that tile eva_window {W} (a "
+                "chunk never straddles a window) and are whole pages of "
+                f"summaries ({ps} x eva_chunk {C} = {ps * C} positions)")
+        if 0 < self.num_pages < self.max_pages:
+            raise ValueError(
+                f"num_pages ({self.num_pages}) < the {self.max_pages}"
+                f" pages a sequence of {self.max_positions} positions "
+                "holds (its summaries and an open window): one sequence "
+                "could never run to completion even with the whole pool")
 
     @property
     def open_cap(self) -> int:
@@ -703,6 +817,102 @@ class EvaRows:
     def rows_attended(self, pos: int) -> int:
         """Rows a decode query at position ``pos`` reads, a layer."""
         return self.visible(pos) + pos % self.window + 1
+
+    def context(self, start: int) -> int:
+        """Rows a chunk that starts at ``start`` attends before itself: the
+        visible summaries and the open window's earlier rows."""
+        return self.visible(start) + start % self.window
+
+    def admit_pages(self, length: int) -> Tuple[int, int]:
+        """(summary pages, open pages) a sequence of ``length`` positions is
+        admitted with: the summaries of its whole chunks, and the open pages
+        its chunks write — its last window's, or where a chunk is shorter
+        than a window and the prompt is not, a whole window's, which the
+        chunks of every window reuse and the last chunk trims."""
+        whole = length >= self.window and self.prefill_chunk < self.window
+        return (self.summary_pages(length),
+                self.open_cap if whole else self.open_pages(length))
+
+    def needs(self, seq: "SequenceState", pos: int) -> int:
+        """A page for the row at ``pos`` where it opens one, and a summary
+        page where the chunk ``pos`` ends opens one."""
+        return self.summary_pages(pos + 1) + (pos % self.window) \
+            // self.page_size + 1 - len(seq.pages)
+
+    def take(self, seq: "SequenceState", pos: int, fresh: List[int], row,
+             trash: int) -> None:
+        need_sum = self.summary_pages(pos + 1) - seq.n_sum
+        seq.pages[seq.n_sum:seq.n_sum] = fresh[:need_sum]
+        seq.n_sum += need_sum
+        seq.pages += fresh[need_sum:]
+        self.write_table(seq, row, trash)
+
+    def write_table(self, seq: "SequenceState", row, trash: int) -> None:
+        """``[summary pages | open pages]``, trash elsewhere."""
+        opened = seq.pages[seq.n_sum:]
+        row[:] = trash
+        row[:seq.n_sum] = seq.pages[:seq.n_sum]
+        row[self.sum_cap:self.sum_cap + len(opened)] = opened
+
+    def chunk_tables(self, seq: "SequenceState", row, start: int, c_n: int,
+                     C: int, trash: int):
+        """The chunk's rows go to the open pages from ``(start % window) /
+        ps`` on (the trash page where the chunk closes its window: nothing
+        reads them again), its chunks' summaries to the summary pages from
+        ``start / (ps chunk)`` on, and it attends ``[the closed windows'
+        summary pages | the open window's earlier pages]`` right-aligned
+        behind trash pages in a table bucketed to a power of two."""
+        ps = self.page_size
+        if start % C:
+            raise RuntimeError(f"an 'eva' chunk starts at {start}, not at a "
+                               f"multiple of prefill_chunk {C}")
+        opened = seq.pages[seq.n_sum:]
+        first = (start % self.window) // ps
+        rows = np.full((C // ps + C // (ps * self.chunk),), trash, np.int32)
+        if (start + c_n) % self.window:
+            take = opened[first:first + C // ps]
+            rows[:len(take)] = take
+        take = seq.pages[:seq.n_sum][start // (ps * self.chunk):][
+            :C // (ps * self.chunk)]
+        rows[C // ps:C // ps + len(take)] = take
+        before = seq.pages[:self.visible(start) // ps] + opened[:first]
+        b = max(1, self.open_cap // 4)
+        while b < len(before):
+            b *= 2
+        prev = np.full((b,), trash, np.int32)
+        if before:
+            prev[b - len(before):] = before
+        return rows, prev
+
+    def give_back(self, seq: "SequenceState", after_chunk: bool
+                  ) -> Tuple[List[int], int]:
+        """After a prompt's last chunk the open pages but the open window's
+        go back (an earlier chunk that closes a window keeps them: they are
+        the next window's chunks' too); after a decode step that closed the
+        row's window, all of them."""
+        n, drop = seq.prefilled, []
+        closed = n % self.window == 0
+        if n >= seq.length if after_chunk else closed:
+            first = seq.n_sum + self.open_pages(n)
+            drop = seq.pages[first:]
+            del seq.pages[first:]
+        return drop, n // self.window if closed else 0
+
+
+def page_rows(cfg, block: KVBlockConfig, prefill_chunk: int):
+    """The rows object the stack of ``cfg`` declares, over ``block``'s pool
+    (it refuses a geometry it cannot keep)."""
+    # (deferred: the engine imports models/, this module does not)
+    from ...models.layer_types import (chunk_stops_early, pooled_rows,
+                                       ring_positions)
+
+    pooled = pooled_rows(cfg)
+    if pooled:
+        return EvaRows(*pooled, block.page_size, block.max_seq_len,
+                       num_pages=block.num_pages, prefill_chunk=prefill_chunk)
+    return PageRows(block.page_size, block.max_pages_per_seq,
+                    num_pages=block.num_pages, ring=ring_positions(cfg),
+                    whole_row=chunk_stops_early(cfg))
 
 
 class StateSlots:

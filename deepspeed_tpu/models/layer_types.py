@@ -29,6 +29,11 @@ feed-forward part, the others the configuration's.  Such a stack is cut into
 run's parameters stacked and scanned; ``run_stack`` is the training forward's
 layer loop (``transformer_forward``), and each layer is ``x + mix(x)`` then
 ``mlp_block`` — the definitions here and no other copy.
+
+A type also answers the serving engine: which of its features the type's
+cache cannot serve and why (``refuses``), how a sequence's pages grow over it
+(``pooled``, ``ring``: ``inference/v2/ragged.page_rows``) and what a step over
+it counts (``touches``).  The engine reads the folds below and names no mixer.
 """
 
 from __future__ import annotations
@@ -39,12 +44,43 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from ..ops.pallas.mla_attention import latent_pages_per_block
+from ..ops.pallas.paged_attention import n_blocks, pages_per_block
 from ..runtime.activation_checkpointing.checkpointing import get_policy
 from ..telemetry.regions import region
 from .transformer import (MODEL_AXIS, TransformerConfig, _mm, _nrm, _norm,
                           _rope, attn_mixer, attn_qkv, init_layer_stack,
                           mlp_block, yarn_inv_freq)
+
+
+#: the serving engine's features a cache may be unable to serve, one name each
+FEATURES = ("prefix_cache", "whole_prompt_prefill", "speculation", "kv_quant",
+            "kv_tier", "decode_horizon", "bundle_export", "bundle_import",
+            "block_generation")
+
+
+@dataclasses.dataclass(frozen=True)
+class Touch:
+    """One count of what a step over a type's cache touches, ``count(n, g)``
+    with ``g`` the pool's geometry (``page_size``, ``block_pages`` of the
+    decode kernel, ``long_row_tokens``).  ``at`` says when, and what ``n`` is:
+    ``decode`` — the rows each decode row attends (0: not active), added to
+    the step a decode call; ``last_chunk`` — the same for the one row of a
+    prompt's last chunk (0 on an earlier one), on the step and the chunk's
+    span; ``chunk`` — the cached rows a chunk attends, on its span alone;
+    ``held`` — ``[2, sequences in slots]``, the positions each has cached and
+    the pages it holds, set at the step's end.  ``cumulative``: summed in
+    ``decode_stats()`` too."""
+    name: str
+    count: Callable[[Any, Any], int]
+    at: Tuple[str, ...] = ("decode",)
+    cumulative: bool = False
+
+
+def _total(n, g) -> int:
+    return int(n.sum())
 
 
 @dataclasses.dataclass(frozen=True)
@@ -67,6 +103,24 @@ class LayerType:
     #: the pools (``model_runner._scan_layers``: the stack's layer index, the
     #: memory a state-space layer leaves for the gated memory units)
     crosses: bool = False
+    #: {feature of ``FEATURES``: why this type's cache cannot serve it}, the
+    #: engine's words (``{kinds}``: the stack's state leaves)
+    refuses: Dict[str, str] = dataclasses.field(default_factory=dict,
+                                                hash=False, compare=False)
+    #: cfg -> (window, chunk) where pages hold an open window's rows and a
+    #: pooled row a chunk (``ragged.EvaRows``); None: a row a position
+    pooled: Optional[Callable[[TransformerConfig], Tuple[int, int]]] = None
+    #: cfg -> positions of a ring in the slot, which the decode kernel reads
+    #: as whole pages; None: no ring
+    ring: Optional[Callable[[TransformerConfig], int]] = None
+    #: the layers after it read its pages and run for a prompt's last token
+    #: only: an earlier chunk stops at its K/V write
+    cross_decoder: bool = False
+    #: (page_size, row values, itemsize) -> pages a block of its decode kernel
+    block_pages: Callable[[int, int, int], int] = pages_per_block
+    #: cfg -> what a step over a layer of this type counts
+    touches: Callable[[TransformerConfig], Tuple[Touch, ...]] = \
+        lambda cfg: ()
 
 
 def _init_attn(cfg: TransformerConfig, rng, n: int) -> Dict[str, Any]:
@@ -309,12 +363,55 @@ def _window_state(cfg: TransformerConfig) -> Dict[str, Tuple[tuple, Any]]:
     return {"win_k": (shape, None), "win_v": (shape, None)}
 
 
+_NO_BLOCK_FORM = ("the block program has a form of the 'attn' mixer over K "
+                  "and V pages alone, not of recurrent state, rings or a "
+                  "latent")
+#: what state in a sequence's slot cannot be served with: each would answer
+#: wrongly (a cached or exported page says nothing of the state that goes
+#: with it; a rejected draft cannot be rolled out of a state)
+_STATE_REFUSES = {
+    "prefix_cache": "this model keeps recurrent state or a window's ring in "
+                    "its slots ({kinds}) and a cached page carries none of "
+                    "it; serve it with the prefix cache off",
+    "whole_prompt_prefill": "a model with recurrent state or a window's ring "
+                            "({kinds}) is prefilled through the chunk "
+                            "program, which carries both from chunk to "
+                            "chunk; set prefill_chunk > 0",
+    "speculation": "paged_verify cannot roll a rejected draft out of "
+                   "recurrent state or a window's ring ({kinds})",
+    "block_generation": _NO_BLOCK_FORM,
+    **dict.fromkeys(("bundle_export", "bundle_import"),
+                    "a bundle holds pages, and this model keeps recurrent "
+                    "state too ({kinds})"),
+}
+_RING_REFUSES = dict(
+    _STATE_REFUSES,
+    kv_quant="the window layers read keys and values from a window's ring "
+             "({kinds}) as they are stored (the differential layers in pairs "
+             "of heads, a key wider than its value split); serve it with "
+             "kv_quant off")
+
+
+def _pages_held(name: str) -> Touch:
+    """``name``: the positions of the pages the sequences in slots hold."""
+    return Touch(name, lambda n, g: g.page_size * int(n[1].sum()),
+                 at=("held",))
+
+
+def _window_touch(name: str):
+    """``name``: the cached positions a window layer's decode reads, a ring
+    holding ``sliding_window`` of a context at most."""
+    return lambda cfg: (Touch(name, lambda n, g: int(
+        np.minimum(n, cfg.sliding_window).sum())),)
+
+
 ATTN = LayerType("attn", _init_attn, mixer="attn", pages=_kv_pages,
                  state=lambda cfg: {}, mix=attn_mixer)
 KDA = LayerType("kda", _init_kda, mixer="kda", pages=_no_pages,
                 state=_kda_state,
                 mix=_served_only("kda", "the backward of the delta-rule scan "
-                                 "(ops/pallas/kda.py: dstpu_kda_chunk)"))
+                                 "(ops/pallas/kda.py: dstpu_kda_chunk)"),
+                refuses=_STATE_REFUSES)
 #: trained only: the paged programs have no form of this mixer yet; the type
 #: declares the state a serving PR has to keep
 CONV = LayerType("conv", _init_conv, mixer="conv", pages=_no_pages,
@@ -324,19 +421,33 @@ CONV = LayerType("conv", _init_conv, mixer="conv", pages=_no_pages,
 #: last one's scan output is the memory the gated memory units read
 MAMBA = LayerType("mamba", _init_mamba, mixer="mamba", pages=_no_pages,
                   state=_mamba_state, mix=_served_only("mamba", _NO_SCAN_BWD),
-                  crosses=True)
+                  crosses=True, refuses=_STATE_REFUSES,
+                  # the rows whose state the step kernel moves
+                  touches=lambda cfg: (Touch("ssm_rows", lambda n, g: int(
+                      (n > 0).sum())),))
 #: differential attention over the last ``sliding_window`` positions, kept as
 #: a ring in the sequence's slot: no pages, no page accounting
 SWA = LayerType("swa", _init_dattn, mixer="swa", pages=_no_pages,
                 state=_window_state,
                 mix=_served_only("swa", "a window mask in the flash backward"),
-                crosses=True)
+                crosses=True, refuses=_RING_REFUSES,
+                ring=lambda cfg: cfg.sliding_window,
+                touches=_window_touch("window_tokens"))
 #: differential attention over the whole context: the one layer that writes
 #: pages, which the cross-attention layers after it read
 DATTN = LayerType("dattn", _init_dattn, mixer="dattn", pages=_kv_pages,
                   state=lambda cfg: {},
                   mix=_served_only("dattn", "the differential form in the "
-                                   "training forward"), crosses=True)
+                                   "training forward"), crosses=True,
+                  cross_decoder=True,
+                  # the visible pages of the one pool layer (once, however
+                  # many layers read them) and the rows the cross-decoder runs
+                  touches=lambda cfg: (
+                      Touch("shared_kv_pages", lambda n, g: int(
+                          (-(-n // g.page_size)).sum()),
+                          at=("decode", "last_chunk")),
+                      Touch("xdec_rows", lambda n, g: int((n > 0).sum()),
+                            at=("decode", "last_chunk"))))
 #: a gated memory unit: the last state-space layer's scan output, gated
 GMU = LayerType("gmu", _init_gmu, mixer="gmu", pages=_no_pages,
                 state=lambda cfg: {}, mix=_served_only("gmu", _NO_SCAN_BWD),
@@ -356,7 +467,39 @@ MLA = LayerType("mla", _init_mla, mixer="mla",
                 state=lambda cfg: {},
                 mix=_served_only("mla", "the latent form in the training "
                                  "forward (and a cut of this family that "
-                                 "fits a chip at 16 B a parameter)"))
+                                 "fits a chip at 16 B a parameter)"),
+                # (the prefix cache, copy-on-write and bundles work over
+                # latent pages as over any page: a cached page holds its
+                # positions' latents and rotated keys, valid for every
+                # request that shares the prefix)
+                refuses={
+                    "whole_prompt_prefill":
+                        "a latent-attention model is prefilled through the "
+                        "chunk program, which expands keys and values from "
+                        "the window's latents; whole-prompt prefill has no "
+                        "form of the 'mla' mixer; set prefill_chunk > 0",
+                    "speculation": "paged_verify has no form of the 'mla' "
+                                   "mixer (a window of queries against "
+                                   "latent pages)",
+                    "kv_quant": "int8 codes and per-head scales exist for K "
+                                "and V pools; a latent pool has no heads to "
+                                "scale, serve it with kv_quant off",
+                    "kv_tier": "the host tier's page format is K and V; a "
+                               "latent pool is not spilled",
+                    "block_generation": _NO_BLOCK_FORM},
+                block_pages=latent_pages_per_block,
+                # once, not a layer (the kernel's bytes are x layers): the
+                # cached positions the decode kernel reads, and the positions
+                # of the blocks it walks for them, the masked ones of a row's
+                # last block included
+                touches=lambda cfg: (
+                    Touch("latent_kv_tokens", _total, cumulative=True),
+                    Touch("latent_block_slots", lambda n, g: int(
+                        g.block_pages * g.page_size * n_blocks(
+                            n, g.page_size, g.block_pages).sum()),
+                        cumulative=True),
+                    Touch("ctx_tokens", _total, at=("chunk",)),
+                    _pages_held("latent_tokens_in_use")))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -487,13 +630,24 @@ _NO_GQA_BWD = ("a key wider than its value, a sink and a window in the flash "
 #: narrower value row (scaled)
 GQA_FULL = LayerType("gqa_full", _init_gqa("gqa_full"), mixer="gqa_full",
                      pages=_gqa_pages, state=lambda cfg: {},
-                     mix=_served_only("gqa_full", _NO_GQA_BWD))
+                     mix=_served_only("gqa_full", _NO_GQA_BWD),
+                     # the positions the full layers' kernel reads (once, not
+                     # a layer) and the rows with a long context
+                     touches=lambda cfg: (
+                         Touch("full_kv_tokens", _total),
+                         Touch("long_rows", lambda n, g: int(
+                             (n > g.long_row_tokens).sum())),
+                         Touch("ctx_tokens", _total, at=("chunk",)),
+                         _pages_held("page_tokens_in_use")))
 #: its window layer: ``swa_kv_heads`` heads, ``swa_rope_theta``, a learned
 #: sink a query head, the last ``sliding_window`` positions as a ring in the
 #: sequence's slot: no pages, no page accounting
 GQA_WINDOW = LayerType("gqa_window", _init_gqa("gqa_window"),
                        mixer="gqa_window", pages=_no_pages, state=_gqa_ring,
-                       mix=_served_only("gqa_window", _NO_GQA_BWD))
+                       mix=_served_only("gqa_window", _NO_GQA_BWD),
+                       refuses=_RING_REFUSES,
+                       ring=lambda cfg: cfg.sliding_window,
+                       touches=_window_touch("window_kv_tokens"))
 # ----------------------------------------------------- EVA attention (EvaByte)
 #: the scales at which a seeded EVA layer is drawn, so that the mechanism
 #: decides the output visibly at every width (the stack is seeded, not
@@ -574,8 +728,51 @@ def eva_mix(cfg: TransformerConfig, layer, x, positions, mask, attn_fn):
 #: (at most ``eva_window`` rows, given back when the window closes) and one
 #: summary row a whole chunk of ``eva_chunk`` positions (``ragged.EvaRows``:
 #: the page accounting; rows advance once a chunk, not once a token)
-EVA = LayerType("eva", _init_eva, mixer="eva", pages=_kv_pages,
-                state=lambda cfg: {}, mix=eva_mix)
+def _eva_touches(cfg: TransformerConfig) -> Tuple[Touch, ...]:
+    W, C = cfg.eva_window, cfg.eva_chunk
+    # of (positions cached, pages held): summary rows written and open-window
+    # rows, a layer; their sum; the pages
+    held = {"eva_summary_rows_in_use": lambda n, p: n // C,
+            "eva_window_rows_in_use": lambda n, p: n % W,
+            "eva_rows_in_use": lambda n, p: n // C + n % W,
+            "eva_pages_in_use": lambda n, p: p}
+    return (Touch("eva_rows_attended", _total),  # once, not a layer
+            Touch("ctx_tokens", _total, at=("chunk",)),
+            *(Touch(name, lambda n, g, f=f: int(f(*n).sum()), at=("held",))
+              for name, f in held.items()))
+
+
+EVA = LayerType(
+    "eva", _init_eva, mixer="eva", pages=_kv_pages, state=lambda cfg: {},
+    mix=eva_mix, pooled=lambda cfg: (cfg.eva_window, cfg.eva_chunk),
+    touches=_eva_touches,
+    # each would answer wrongly over a cache whose rows are pooled chunks and
+    # a window that empties
+    refuses={
+        "prefix_cache": "an 'eva' layer's pages hold a window's rows that "
+                        "are given back when it closes and summaries that "
+                        "are visible only past their window; a cached page "
+                        "keyed by its tokens says neither; serve it with the "
+                        "prefix cache off",
+        "whole_prompt_prefill": "an 'eva' stack is prefilled through the "
+                                "chunk program, in chunks that tile "
+                                "eva_window (a chunk never straddles a "
+                                "window) and are whole pages of summaries; "
+                                "set prefill_chunk > 0",
+        "speculation": "paged_verify cannot roll a rejected draft out of a "
+                       "pooled chunk or a closed window ('eva')",
+        "kv_quant": "an 'eva' layer pools a chunk's keys and values from the "
+                    "rows as they are stored; int8 codes have no form of it; "
+                    "serve it with kv_quant off",
+        "decode_horizon": "a row's open pages go back to the allocator when "
+                          "its window closes, between steps; the fused scan "
+                          "reserves pages by position; serve an 'eva' stack "
+                          "with decode_horizon 1",
+        "bundle_export": "a bundle's pages are a page a page_size positions, "
+                         "and an 'eva' stack's are summaries and an open "
+                         "window (ragged.EvaRows)",
+        "bundle_import": "an 'eva' stack's pages are summaries and an open "
+                         "window, not a page a page_size positions"})
 _TYPES = {"gqa_full": GQA_FULL, "gqa_window": GQA_WINDOW, "attn": ATTN, "kda": KDA, "conv": CONV, "mamba": MAMBA, "swa": SWA,
           "dattn": DATTN, "gmu": GMU, "xattn": XATTN, "mla": MLA, "eva": EVA}
 
@@ -773,10 +970,79 @@ def state_leaves(cfg: TransformerConfig) -> Dict[str, Tuple[int, tuple, Any]]:
     """{pool leaf: (layers that keep it, per-sequence shape, dtype)} over the
     whole model; empty for a model that keeps only pages."""
     out: Dict[str, Tuple[int, tuple, Any]] = {}
-    for t in {t for types, _ in served_runs(cfg) for t in types}:
+    for t in _served_types(cfg):
         for name, (shape, dtype) in t.state(cfg).items():
             out[name] = (layers_of(cfg, t.mixer), shape, dtype)
     return out
+
+
+def _served_types(cfg: TransformerConfig) -> Tuple[LayerType, ...]:
+    """The stack's types, each once, in the order the stack meets them."""
+    return tuple(dict.fromkeys(
+        t for types, _ in served_runs(cfg) for t in types))
+
+
+def unsupported(cfg: TransformerConfig) -> Dict[str, str]:
+    """{engine feature: why this stack's caches cannot serve it}: what its
+    types refuse, the first to name a feature giving the reason."""
+    kinds, out = sorted(state_leaves(cfg)), {}
+    for t in _served_types(cfg):
+        for feature, why in t.refuses.items():
+            out.setdefault(feature, why.format(kinds=kinds))
+    return out
+
+
+def step_touches(cfg: TransformerConfig) -> Dict[str, Tuple[Touch, ...]]:
+    """{when (``Touch.at``): what a step over this stack counts then}, each
+    name once."""
+    named = {c.name: c for t in _served_types(cfg) for c in t.touches(cfg)}
+    return {at: tuple(c for c in named.values() if at in c.at)
+            for at in ("decode", "last_chunk", "chunk", "held")}
+
+
+def pooled_rows(cfg: TransformerConfig) -> Optional[Tuple[int, int]]:
+    """(window, chunk) of a stack whose pages hold pooled chunks beside an
+    open window, None for one whose pages grow a row a position."""
+    types = _served_types(cfg)
+    pooled = [t for t in types if t.pooled]
+    if not pooled:
+        return None
+    if len(types) > 1:
+        raise NotImplementedError(
+            f"a stack that mixes {pooled[0].name!r} layers with others: the "
+            "page accounting of a sequence (ragged.EvaRows) is one for all "
+            "of its layers")
+    return pooled[0].pooled(cfg)
+
+
+def ring_positions(cfg: TransformerConfig) -> int:
+    """The positions of the rings the stack's window layers keep in a
+    sequence's slot, 0 for a stack without."""
+    return max((t.ring(cfg) for t in _served_types(cfg) if t.ring), default=0)
+
+
+def chunk_stops_early(cfg: TransformerConfig) -> bool:
+    """Whether a chunk that is not a prompt's last stops inside the stack (at
+    a cross-decoder's K/V write), in a program of its own."""
+    return any(t.cross_decoder for t in _served_types(cfg))
+
+
+def page_block(cfg: TransformerConfig
+               ) -> Tuple[str, Callable[[int, int, int], int]]:
+    """(the pool leaf that is a page of the decode kernel, ``(page_size, its
+    row's values, itemsize) -> pages a block of that kernel holds``)."""
+    paged = next(t for t in _served_types(cfg) if t.pages(cfg))
+    return next(iter(paged.pages(cfg))), paged.block_pages
+
+
+def bundle_signature(cfg: TransformerConfig) -> Tuple[int, int, int]:
+    """(layers, heads, width) of a page as a ``KVPageBundle`` carries it: K
+    and V by head, or the one leaf of a format that has no heads."""
+    pages = page_leaves(cfg)
+    if set(pages) == {"k", "v"}:
+        return (cfg.n_layers, cfg.kv_heads, cfg.head_dim)
+    (layers, width), = pages.values()
+    return (layers, 1, width)
 
 
 def stack_matmul_params(cfg: TransformerConfig, active: bool) -> float:

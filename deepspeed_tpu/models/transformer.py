@@ -69,7 +69,7 @@ class TransformerConfig:
     # post-norm (original-transformer/BERT ordering): norm AFTER each
     # residual add — norm1(x + attn(x)), norm2(h + ffn(h)); embeddings get
     # their own LayerNorm and there is no final norm.  Encoder-style: the
-    # generative engines (KV cache, pipeline, domino) reject it.
+    # generative engines (KV cache, pipeline) reject it.
     post_norm: bool = False
     # segment-embedding table size for post-norm encoders (BERT
     # type_vocab_size); 0 disables the table

@@ -8,8 +8,7 @@ Reference parity: ``LinearLayer`` / ``LinearAllreduce``
   a ``with_sharding_constraint``; inside ``jit`` under a mesh, XLA inserts
   the reduce the reference does with an explicit ``all_reduce``.
 * Explicit form (``*_explicit``): for use inside ``shard_map`` where
-  collectives are written by hand (``jax.lax.psum``) — the building block
-  for Domino-style overlap (runtime/domino/).
+  collectives are written by hand (``jax.lax.psum``).
 """
 
 from __future__ import annotations

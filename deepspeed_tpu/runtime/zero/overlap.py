@@ -5,9 +5,8 @@ block: the backward scan accumulates every layer's cotangent into the
 stacked gradient buffer and GSPMD places the data-axis reduce wherever
 its propagation lands it — in practice hoisted out of the layer loops,
 serialized against nothing.  That is the exposed-communication problem
-T3 (PAPERS.md) attacks with fine-grained tracking/triggering, and the
-in-tree Domino module solves for TP by making the overlap *be* the
-dataflow graph.
+T3 (PAPERS.md) attacks with fine-grained tracking/triggering, and Domino
+solves for TP by making the overlap *be* the dataflow graph.
 
 This module is the ZeRO-side analogue.  Sharding *constraints* cannot
 pin a reduction point (GSPMD folds them into propagation — measured:

@@ -1,5 +1,4 @@
-"""Tests: compressed comm, curriculum/data pipeline, compression, LoRA,
-eigenvalue."""
+"""Tests: compressed comm, curriculum/data pipeline, compression, LoRA."""
 
 import json
 import os
@@ -24,7 +23,6 @@ from deepspeed_tpu.runtime.config import MeshConfig
 from deepspeed_tpu.runtime.data_pipeline.curriculum import (
     CurriculumConfig, CurriculumScheduler, VariableBatchConfig,
     apply_seqlen_curriculum, batch_by_token_budget)
-from deepspeed_tpu.runtime.eigenvalue import top_eigenvalue
 
 
 def test_compressed_allreduce_error_feedback(devices8):
@@ -135,17 +133,6 @@ def test_lora_quantized_base():
                               quantize=QuantizationConfig())
     out = lora_linear(params, jnp.ones((2, 16)), lora)
     assert out.shape == (2, 8)
-
-
-def test_eigenvalue_power_iteration():
-    # quadratic loss: 0.5 x^T A x has hessian A; top |eig| of diag(1..4) = 4
-    A = jnp.diag(jnp.asarray([1.0, 2.0, 3.0, 4.0]))
-
-    def loss(x):
-        return 0.5 * x @ A @ x
-
-    eig = top_eigenvalue(loss, jnp.ones(4), jax.random.PRNGKey(0), max_iters=50)
-    np.testing.assert_allclose(float(eig), 4.0, rtol=1e-3)
 
 
 def test_structured_pruning_and_physical_clean():

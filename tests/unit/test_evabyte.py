@@ -389,7 +389,7 @@ def test_pages_follow_the_rows_and_go_back_when_a_window_closes(eng):
     """After every step: pages held = the summaries' + the open window's; a
     closed window leaves ``W / C`` summary rows a layer and no exact row; the
     pages a close gives back are taken again."""
-    ev, seen, taken = eng._eva, [], set()
+    ev, seen, taken = eng.rows, [], set()
 
     def audit(e):
         for s in e._slots:

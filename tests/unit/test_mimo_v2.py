@@ -39,8 +39,9 @@ from deepspeed_tpu.inference.v2 import engine_v2, model_runner  # noqa: E402
 from deepspeed_tpu.inference.v2.speculative import SpeculativeConfig  # noqa: E402
 from deepspeed_tpu.models import (mimo_v2_config, mimo_v2_model,  # noqa: E402
                                   mistral4_model, phi4_flash_model)
-from deepspeed_tpu.models.layer_types import (gqa_shape,  # noqa: E402
-                                              layer_type, page_layers,
+from deepspeed_tpu.models.layer_types import (chunk_stops_early,  # noqa: E402
+                                              gqa_shape, layer_type,
+                                              page_layers,
                                               page_leaves, served_run_configs,
                                               served_runs, state_leaves)
 from deepspeed_tpu.models.mimo_v2 import mimo_v2_runs  # noqa: E402
@@ -710,6 +711,7 @@ def test_models_without_a_prologue_lower_as_before(program):
         slot = (S((), i32),) if eng._state else ()
         low = eng._prefill_chunk.apart().lower(
             eng.params, eng._pools, S((16,), i32), S((16 // ps,), i32),
-            S((MP_ if eng._xdec else 4,), i32), S((), i32), S((), i32), *slot)
+            S((MP_ if chunk_stops_early(eng.cfg) else 4,), i32), S((), i32),
+            S((), i32), *slot)
     got = hashlib.sha256(low.as_text().encode()).hexdigest()[:16]
     assert got == _PARENT_HLO[program]

@@ -1,5 +1,4 @@
-"""1-bit optimizers + evoformer attention + checkpoint engine flavors
-(reference: tests/onebit/, tests/unit/ops/deepspeed4science/)."""
+"""1-bit optimizers + checkpoint engine flavors (reference: tests/onebit/)."""
 
 import jax
 import jax.numpy as jnp
@@ -7,7 +6,6 @@ import numpy as np
 import pytest
 
 import deepspeed_tpu
-from deepspeed_tpu.ops.evoformer_attn import DS4Sci_EvoformerAttention
 from deepspeed_tpu.runtime.fp16.onebit import (one_bit_adam, one_bit_lamb,
                                                zero_one_adam)
 from tests.unit.simple_model import random_batch, simple_mlp_spec
@@ -90,104 +88,6 @@ def test_onebit_error_feedback_accumulates():
     assert float(jnp.abs(s.error["w"]).max()) == 0.0
     _, s = ob.update(g, s, params)  # compressed step: residual retained
     assert float(jnp.abs(s.error["w"]).max()) > 0.0
-
-
-# ------------------------------------------------------------ evoformer
-def test_evoformer_matches_naive():
-    rng = np.random.RandomState(0)
-    B, S, N, H, D = 2, 3, 8, 2, 4
-    q = jnp.asarray(rng.randn(B, S, N, H, D), jnp.float32)
-    k = jnp.asarray(rng.randn(B, S, N, H, D), jnp.float32)
-    v = jnp.asarray(rng.randn(B, S, N, H, D), jnp.float32)
-    bias1 = jnp.asarray(rng.randn(B, S, 1, 1, N), jnp.float32)  # mask bias
-    bias2 = jnp.asarray(rng.randn(B, 1, H, N, N), jnp.float32)  # pair bias
-
-    out = DS4Sci_EvoformerAttention(q, k, v, [bias1, bias2])
-    # naive per-element
-    s = np.einsum("bsqhd,bskhd->bshqk", q, k) / np.sqrt(D)
-    s = s + np.asarray(bias1) + np.asarray(bias2)
-    p = jax.nn.softmax(jnp.asarray(s), axis=-1)
-    want = np.einsum("bshqk,bskhd->bsqhd", np.asarray(p), v)
-    np.testing.assert_allclose(np.asarray(out), want, rtol=1e-5, atol=1e-5)
-    assert out.shape == (B, S, N, H, D)
-
-
-def test_evoformer_pallas_matches_xla():
-    """Fused Pallas kernels (interpret mode on CPU) vs the unfused XLA
-    path: values AND all five gradients, incl. both bias grads — the part
-    the reference hand-writes in kernel_backward.h."""
-    from deepspeed_tpu.ops.evoformer_attn import evoformer_attention_xla
-    from deepspeed_tpu.ops.pallas.evoformer_attn import (
-        evoformer_attention_pallas)
-
-    rng = np.random.RandomState(1)
-    B, S, N, H, D = 2, 3, 20, 2, 16  # N=20 vs block 8 -> padded tail blocks
-    q = jnp.asarray(rng.randn(B, S, N, H, D), jnp.float32)
-    k = jnp.asarray(rng.randn(B, S, N, H, D), jnp.float32)
-    v = jnp.asarray(rng.randn(B, S, N, H, D), jnp.float32)
-    b1 = jnp.asarray(rng.randn(B, S, 1, 1, N), jnp.float32)
-    b2 = jnp.asarray(rng.randn(B, 1, H, N, N), jnp.float32)
-
-    for biases in ([], [b1], [b1, b2], [None, b2]):
-        out_p = evoformer_attention_pallas(q, k, v, biases, block_q=8, block_k=8)
-        out_x = evoformer_attention_xla(q, k, v, biases)
-        np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_x),
-                                   rtol=2e-4, atol=2e-4)
-
-    # gradient parity WITHOUT biases (the default autodiff path must not
-    # assume the bias-grad outputs exist)
-    g_nb_p = jax.grad(lambda q: jnp.sum(jnp.square(
-        evoformer_attention_pallas(q, k, v, [], block_q=8, block_k=8))))(q)
-    g_nb_x = jax.grad(lambda q: jnp.sum(jnp.square(
-        evoformer_attention_xla(q, k, v, []))))(q)
-    np.testing.assert_allclose(np.asarray(g_nb_p), np.asarray(g_nb_x),
-                               rtol=2e-3, atol=2e-3, err_msg="no-bias dq")
-    # and with only the pair bias in slot 1
-    g_b2_p = jax.grad(lambda b2: jnp.sum(jnp.square(
-        evoformer_attention_pallas(q, k, v, [None, b2], block_q=8, block_k=8))))(b2)
-    g_b2_x = jax.grad(lambda b2: jnp.sum(jnp.square(
-        evoformer_attention_xla(q, k, v, [None, b2]))))(b2)
-    np.testing.assert_allclose(np.asarray(g_b2_p), np.asarray(g_b2_x),
-                               rtol=2e-3, atol=2e-3, err_msg="lone dbias2")
-
-    def loss_p(q, k, v, b1, b2):
-        return jnp.sum(jnp.square(evoformer_attention_pallas(
-            q, k, v, [b1, b2], block_q=8, block_k=8)))
-
-    def loss_x(q, k, v, b1, b2):
-        return jnp.sum(jnp.square(evoformer_attention_xla(q, k, v, [b1, b2])))
-
-    gp = jax.grad(loss_p, argnums=(0, 1, 2, 3, 4))(q, k, v, b1, b2)
-    gx = jax.grad(loss_x, argnums=(0, 1, 2, 3, 4))(q, k, v, b1, b2)
-    for name, a, b in zip("q k v bias1 bias2".split(), gp, gx):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-3, atol=2e-3,
-                                   err_msg=f"grad mismatch: {name}")
-
-
-def test_evoformer_lone_pair_bias_broadcasts():
-    """A pair-shaped bias in slot 0 must take the broadcasting XLA path
-    under impl='auto' (the kernel's positional bias1 would reject it)."""
-    from deepspeed_tpu.ops.evoformer_attn import (evoformer_attention,
-                                                  evoformer_attention_xla)
-
-    rng = np.random.RandomState(4)
-    B, S, N, H, D = 1, 2, 8, 2, 16  # D=16 would qualify for pallas
-    q = jnp.asarray(rng.randn(B, S, N, H, D), jnp.float32)
-    pair = jnp.asarray(rng.randn(B, 1, H, N, N), jnp.float32)
-    out = evoformer_attention(q, q, q, [pair])  # must not raise
-    want = evoformer_attention_xla(q, q, q, [pair])
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
-                               rtol=1e-5, atol=1e-5)
-
-
-def test_evoformer_grad_and_bias_validation():
-    q = jnp.ones((1, 2, 4, 1, 4))
-    loss = lambda q: DS4Sci_EvoformerAttention(q, q, q).sum()  # noqa: E731
-    g = jax.grad(loss)(q)
-    assert np.isfinite(np.asarray(g)).all()
-    with pytest.raises(ValueError):
-        DS4Sci_EvoformerAttention(q, q, q, [None, None, None])
 
 
 # ------------------------------------------------- checkpoint engine flavors

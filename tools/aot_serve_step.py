@@ -379,15 +379,20 @@ def main() -> int:
     # a stack with a cross-decoder is handed the whole table row (one query
     # reads it through the decode kernel): one shape, and a program of its
     # own for the chunks that are not a prompt's last
+    from deepspeed_tpu.models.layer_types import chunk_stops_early
+
+    stops_early = chunk_stops_early(engine.cfg)
     windows = ([int(w) for w in args.windows.split(",")] if args.windows
-               else [MP] if engine._xdec
+               else [MP] if stops_early
                else sorted({max(1, C // ps), MP}))
     rows = C // ps
-    if engine._eva is not None and not args.windows:
+    from deepspeed_tpu.inference.v2.ragged import EvaRows
+
+    if isinstance(engine.rows, EvaRows) and not args.windows:
         # a chunk's table: the closed windows' summary pages and the open
         # window's earlier pages, in the engine's power-of-two buckets; its
         # rows: the open pages it writes, then the summary pages
-        ev = engine._eva
+        ev = engine.rows
         rows += C // (ps * ev.chunk)
         most = ev.visible(block.max_seq_len) // ps + (ev.window - C) // ps
         windows = [max(1, ev.open_cap // 4)]
@@ -402,7 +407,7 @@ def main() -> int:
                 params, pools, arr((C,), i32), arr((rows,), i32),
                 arr((w,), i32), arr((), i32), arr((), i32), *slot),
             pool_bytes, layer_pool_bytes, no_pool, args.hlo_dir)
-        if engine._xdec:
+        if stops_early:
             results[f"chunk{w}.part"] = report(
                 f"chunk, not a prompt's last [{C} tokens, window {w} pages]",
                 engine._prefill_chunk_part.lower(

@@ -11,8 +11,7 @@ time.  It says nothing about numerics — only a run on the chip does.
 
 One line per kernel: ``compiles`` (and, on the chip, the error against its XLA
 reference) or ``refused`` with the compiler's message.  Exit code 1 if any
-kernel was refused or disagreed.  ``sparse_attention`` and ``evoformer_attn``
-are not probed: nothing calls them (ROADMAP D8).  On the chip it also times
+kernel was refused or disagreed.  On the chip it also times
 the expert share's two row kernels (``--only moe_dispatch``), the paged
 decode kernel at its five geometries (``--only paged_decode``: the time a
 call, a block, and the share of the bytes' roofline) and the latent decode
